@@ -42,6 +42,9 @@ ENV_ALLOWLIST = {
     "RAY_TPU_FAKE_MEMORY_USAGE": "memory-monitor test double",
     "RAY_TPU_FAKE_MEMORY_USAGE_FILE": "memory-monitor test double",
     "RAY_TPU_FAKE_DISK_USAGE": "fs-monitor test double",
+    # the CPU test substrate's request for the Pallas interpreter, set by
+    # _private/platform.force_cpu_platform and inherited by its workers
+    "RAY_TPU_PALLAS_INTERPRET": "CPU test substrate marker",
     # markers injected INTO a container's env (written, not read as config)
     "RAY_TPU_CONTAINER_IMAGE": "container-env marker for tests",
     "RAY_TPU_CONTAINER_ARGS": "container-env marker for tests",

@@ -87,7 +87,10 @@ _d = RayConfig.define
 
 # --- Timeouts & heartbeats (ms unless noted) ---
 _d("heartbeat_interval_ms", int, 500, "nodelet -> GCS resource/health report period")
-_d("health_check_timeout_ms", int, 10_000, "GCS marks a node dead after this silence")
+_d("health_check_timeout_ms", int, 30_000,
+   "GCS marks a node dead after this silence.  Must outlast a TPU host's "
+   "start-up stall: a four-chip v5e host froze for 13 s while the runtime "
+   "came up")
 _d("gcs_rpc_timeout_s", float, 30.0, "client-side timeout for GCS RPCs")
 _d("worker_register_timeout_s", float, 60.0, "worker must register with nodelet within this")
 _d("wait_poll_interval_ms", int, 20, "poll granularity for ray.wait fallbacks")

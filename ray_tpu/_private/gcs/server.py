@@ -321,12 +321,22 @@ class GcsServer:
     async def _health_check_loop(self):
         interval = RayConfig.heartbeat_interval_ms / 1000.0
         timeout = RayConfig.health_check_timeout_ms / 1000.0
+        woke = time.monotonic()
         while True:
             await asyncio.sleep(interval * 4)
             now = time.monotonic()
+            # Time this loop itself lost is not the nodes' silence: while the
+            # GCS was frozen it could not have seen a heartbeat.  A whole
+            # host stalls like this when the TPU runtime starts up (observed:
+            # every process on a four-chip v5e host frozen for 13 s), and the
+            # GCS must not wake first and reap its own healthy node.
+            overslept = now - woke - interval * 4
+            woke = now
             for info in list(self.nodes.values()):
                 if not info.alive:
                     continue
+                if overslept > interval:
+                    info.last_seen += overslept
                 if now - info.last_seen > timeout:
                     await self._mark_node_dead(info.node_id, "health check timed out")
 

@@ -151,7 +151,9 @@ class Node:
         for proc in (self.nodelet_proc, self.gcs_proc):
             if proc is not None and proc.poll() is None:
                 proc.terminate()
-        deadline = time.monotonic() + 3
+        # the nodelet reaps its workers before it exits (up to 10 s when a
+        # worker held the TPU)
+        deadline = time.monotonic() + 12
         for proc in (self.nodelet_proc, self.gcs_proc):
             if proc is None:
                 continue

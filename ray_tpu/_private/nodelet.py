@@ -42,6 +42,16 @@ from ray_tpu.exceptions import ObjectStoreFullError
 logger = logging.getLogger(__name__)
 
 
+def _reap(procs, timeout_s: float) -> None:
+    """Wait (bounded) for killed worker processes to be gone."""
+    deadline = time.monotonic() + timeout_s
+    for proc in procs:
+        try:
+            proc.wait(timeout=max(0.0, deadline - time.monotonic()))
+        except subprocess.TimeoutExpired:
+            pass
+
+
 class _LeaseCancelled(Exception):
     """A queued lease request was cancelled by its client."""
 
@@ -283,8 +293,14 @@ class Nodelet:
         self._shutting_down = True
         for t in self._bg:
             t.cancel()
+        procs = [w.proc for w in self.workers.values() if w.proc is not None]
         for w in list(self.workers.values()):
             self._kill_worker_proc(w)
+        # Reap them before this node counts as stopped: a killed worker that
+        # held the TPU keeps /dev/vfio busy until the kernel has torn the
+        # process down, and the next process on this host needs the chips.
+        await asyncio.get_running_loop().run_in_executor(
+            None, _reap, procs, 10.0)
         await self.server.stop()
         if self.gcs is not None:
             await self.gcs.close()

@@ -5,8 +5,10 @@ python/ray/_private/accelerators/tpu.py:71-397):
 
 - chip detection via ``/dev/accel*`` and ``/dev/vfio`` device files (tpu.py:98-117)
 - pod type / worker id / pod name from TPU-VM env or GCE metadata (tpu.py:48-68,
-  198-271); here env vars take precedence and the metadata server is only polled
-  when reachable (zero-egress test environments never block)
+  198-271); here env vars take precedence, ``TPU_SKIP_MDS_QUERY`` (libtpu's own
+  switch) turns the metadata server off, and it is addressed by its link-local
+  IP so a machine with no network pays at most the connect timeout, never a
+  DNS stall
 - ``TPU_VISIBLE_CHIPS`` visibility for workers (tpu.py:155-195)
 - gang-scheduling resources: ``TPU-{pod_type}-head`` advertised only by worker 0
   of a slice, plus a per-slice name resource, so a placement group of
@@ -31,11 +33,16 @@ from ray_tpu.accelerators.accelerator import AcceleratorManager
 logger = logging.getLogger(__name__)
 
 VALID_CHIPS_PER_HOST = (1, 2, 4, 8)
-GCE_METADATA_URL = "http://metadata.google.internal/computeMetadata/v1/instance"
+# metadata.google.internal by its fixed link-local address: resolving the name
+# is the one step urlopen's timeout does not bound.
+GCE_METADATA_URL = "http://169.254.169.254/computeMetadata/v1/instance"
 
 
 def _metadata(path: str) -> Optional[str]:
-    """Poll GCE instance metadata; None when unreachable (non-GCE / sandbox)."""
+    """Poll GCE instance metadata; None when unreachable (non-GCE / sandbox)
+    or switched off with ``TPU_SKIP_MDS_QUERY``."""
+    if os.environ.get("TPU_SKIP_MDS_QUERY", "").lower() in ("1", "true"):
+        return None
     try:
         import urllib.request
 

@@ -66,7 +66,9 @@ class GPT2Runner:
         self.wpe = a(params["wpe"]["embedding"])          # [P, E]
         self.lnf_s = a(params["ln_f"]["scale"])
         self.lnf_b = a(params["ln_f"]["bias"])
-        self.lm_head = a(params["lm_head"]["kernel"])     # [E, V]
+        # [E, V]: the flax tables are padded to a lane multiple
+        # (models/gpt2.padded_vocab); the pad columns are not tokens
+        self.lm_head = a(params["lm_head"]["kernel"])[:, :config.vocab_size]
         self.layers: List[_LayerParams] = []
         for i in range(config.n_layer):
             blk = params[f"h_{i}"]

@@ -9,6 +9,13 @@ allreduce).  Design choices for the MXU/HBM:
   ``ring_attention`` when the batch is sequence-sharded over an ``sp`` axis;
 - parameter names line up with ``parallel.sharding.gpt_partition_rules`` so
   dp/fsdp/tp shardings apply by regex;
+- the two vocab-sized tables (``wte``, ``lm_head``) are stored padded to a
+  multiple of 128 rows so the vocab dim splits over ``tp`` at any vocabulary
+  (50257 is odd); the pad columns of the logits are masked to ``NEG_INF``, so
+  they carry no probability and no gradient and the loss is the unpadded one;
+- the residual stream is pinned to the batch's layout
+  (``parallel.sharding.constrain_residual``) so ``fsdp`` splits compute, not
+  only storage;
 - no data-dependent Python control flow — the whole step is one jit region.
 """
 
@@ -22,11 +29,31 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import (
+    NEG_INF,
     flash_attention,
     mha_reference,
     ring_attention,
     ring_attention_sharded,
 )
+from ray_tpu.parallel.sharding import constrain_residual
+
+VOCAB_ALIGN = 128  # one lane tile; also divisible by every tp size in use
+
+
+def padded_vocab(vocab_size: int) -> int:
+    """Rows of the vocab-sized tables: ``vocab_size`` rounded up to
+    ``VOCAB_ALIGN``."""
+    return -(-vocab_size // VOCAB_ALIGN) * VOCAB_ALIGN
+
+
+def mask_vocab_padding(logits, vocab_size: int):
+    """Set the pad columns of ``(..., padded_vocab)`` logits to ``NEG_INF``:
+    zero probability under softmax, never the argmax, zero gradient into the
+    pad rows of the tables."""
+    if logits.shape[-1] == vocab_size:
+        return logits
+    col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    return jnp.where(col < vocab_size, logits, NEG_INF)
 
 
 @dataclass(frozen=True)
@@ -135,12 +162,15 @@ class GPT2LMModel(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, *, deterministic: bool = True):
+        """(B, S) token ids -> (B, S, padded_vocab(vocab_size)) logits, pad
+        columns at ``NEG_INF``."""
         cfg = self.config
         B, S = input_ids.shape
         pos = jnp.arange(S)[None, :]
-        tok = nn.Embed(cfg.vocab_size, cfg.n_embd, dtype=cfg.dtype, name="wte")(input_ids)
+        tok = nn.Embed(padded_vocab(cfg.vocab_size), cfg.n_embd,
+                       dtype=cfg.dtype, name="wte")(input_ids)
         pe = nn.Embed(cfg.n_positions, cfg.n_embd, dtype=cfg.dtype, name="wpe")(pos)
-        x = tok + pe
+        x = constrain_residual(tok + pe)
         if cfg.remat_policy not in ("full", "dots"):
             raise ValueError(f"unknown remat_policy: {cfg.remat_policy!r} "
                              "(expected 'full' or 'dots')")
@@ -157,12 +187,12 @@ class GPT2LMModel(nn.Module):
             # backward) — the standard TPU memory/bandwidth trade.
             use_moe = cfg.moe_every > 0 and (i % cfg.moe_every
                                              == cfg.moe_every - 1)
-            x = block_cls(cfg, use_moe, name=f"h_{i}")(
-                x, deterministic=deterministic)
+            x = constrain_residual(block_cls(cfg, use_moe, name=f"h_{i}")(
+                x, deterministic=deterministic))
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f")(x)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                          name="lm_head")(x)
-        return logits
+        logits = nn.Dense(padded_vocab(cfg.vocab_size), use_bias=False,
+                          dtype=cfg.dtype, name="lm_head")(x)
+        return mask_vocab_padding(logits, cfg.vocab_size)
 
 
 def lm_loss(logits, targets, mask=None):

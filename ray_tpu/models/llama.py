@@ -21,8 +21,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.models.gpt2 import mask_vocab_padding, padded_vocab
 from ray_tpu.ops.attention import (flash_attention, mha_reference,
                                    ring_attention_sharded)
+from ray_tpu.parallel.sharding import constrain_residual
 
 
 @dataclass(frozen=True)
@@ -139,8 +141,9 @@ class LlamaLMModel(nn.Module):
     def __call__(self, input_ids, *, deterministic: bool = True):
         cfg = self.config
         B, S = input_ids.shape
-        x = nn.Embed(cfg.vocab_size, cfg.d_model, dtype=cfg.dtype,
-                     name="wte")(input_ids)
+        x = constrain_residual(
+            nn.Embed(padded_vocab(cfg.vocab_size), cfg.d_model,
+                     dtype=cfg.dtype, name="wte")(input_ids))
         positions = jnp.arange(S)
         if cfg.remat:
             policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
@@ -149,10 +152,11 @@ class LlamaLMModel(nn.Module):
         else:
             block_cls = LlamaBlock
         for i in range(cfg.n_layer):
-            x = block_cls(cfg, name=f"h_{i}")(x, positions)
+            x = constrain_residual(block_cls(cfg, name=f"h_{i}")(x, positions))
         x = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype, name="norm_f")(x)
-        return nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
-                        name="lm_head")(x)
+        logits = nn.Dense(padded_vocab(cfg.vocab_size), use_bias=False,
+                          dtype=cfg.dtype, name="lm_head")(x)
+        return mask_vocab_padding(logits, cfg.vocab_size)
 
 
 def llama_partition_rules():

@@ -27,7 +27,8 @@ import jax.numpy as jnp
 import numpy as np
 import optax
 
-from ray_tpu.models.gpt2 import Block, GPT2Config, GPT2LMModel, lm_loss
+from ray_tpu.models.gpt2 import (Block, GPT2Config, GPT2LMModel, lm_loss,
+                                 mask_vocab_padding)
 from ray_tpu.models.pretrain import make_optimizer
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.parallel.pipeline import pipeline_apply
@@ -179,7 +180,8 @@ class PipelinedPretrainer:
         var = ((y - mean) ** 2).mean(-1, keepdims=True)
         y = (y - mean) * jax.lax.rsqrt(var + 1e-6)
         y = y * ln["scale"] + ln["bias"]
-        return y.astype(cfg.dtype) @ outer["lm_head"]["kernel"].astype(cfg.dtype)
+        logits = y.astype(cfg.dtype) @ outer["lm_head"]["kernel"].astype(cfg.dtype)
+        return mask_vocab_padding(logits, cfg.vocab_size)
 
     def shard_batch(self, batch):
         return {k: jax.device_put(jnp.asarray(v), self.batch_sharding[k])

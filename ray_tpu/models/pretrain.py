@@ -10,19 +10,19 @@ ppermute for sp attention).
 from __future__ import annotations
 
 import dataclasses
-import functools
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import optax
+from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu.models.gpt2 import GPT2Config, GPT2LMModel, lm_loss
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.parallel.sharding import (
     gpt_partition_rules,
+    host_to_global,
     match_partition_rules,
-    shard_pytree,
 )
 
 
@@ -82,46 +82,73 @@ def train_step(model, tx, state, batch):
     return (params, opt_state), loss
 
 
+class ShardedStep(NamedTuple):
+    step: Any         # jitted (state, batch) -> (state, loss)
+    model: Any        # the module the step applies (attention impl resolved)
+    init_state: Any   # () -> (params, opt_state), seeded; jit it into `state`'s shardings
+    state: Any        # (params, opt_state) as ShapeDtypeStructs with shardings
+    batch_sharding: Dict[str, NamedSharding]
+
+
+def sharded_train_step(config, mesh, tx) -> ShardedStep:
+    """The jitted train step for ``config`` on ``mesh`` and the layouts it
+    runs under, built from shapes alone — nothing is allocated on a device.
+
+    ``ShardedPretrainer`` fills the state in and steps it; the compile-only
+    pre-flight (tests/test_chip_compile.py) lowers the very same step against
+    a TPU topology with no chip attached.  Trace/run it under
+    ``jax.set_mesh(mesh)``: the attention kernels and the residual-stream
+    constraint read the ambient mesh.
+    """
+    if mesh.shape.get("sp", 1) > 1 and config.attention_impl == "flash":
+        # sequence sharding needs the ring kernel
+        config = dataclasses.replace(config, attention_impl="ring")
+    model_cls, rules_fn = _model_family(config)
+    model = model_cls(config)
+
+    def init_state():
+        params = init_params(config)[1]
+        return params, tx.init(params)
+
+    shapes = jax.eval_shape(init_state)
+    state = jax.tree_util.tree_map(
+        lambda x, spec: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec)),
+        shapes, match_partition_rules(rules_fn(), shapes))
+    state_shardings = jax.tree_util.tree_map(lambda a: a.sharding, state)
+    batch_sharding = {k: NamedSharding(mesh, P(("dp", "fsdp"), "sp"))
+                      for k in ("input_ids", "targets")}
+
+    def pretrain_step(state, batch):  # the name the compiled program carries
+        return train_step(model, tx, state, batch)
+
+    step = jax.jit(
+        pretrain_step,
+        in_shardings=(state_shardings, batch_sharding),
+        out_shardings=(state_shardings, None),
+        donate_argnums=(0,),
+    )
+    return ShardedStep(step, model, init_state, state, batch_sharding)
+
+
 class ShardedPretrainer:
     """Owns mesh + sharded state + compiled step for one jax (multi-)process."""
 
     def __init__(self, config, mesh_config: Optional[MeshConfig] = None,
                  lr: float = 3e-4, devices=None, total_steps: int = 10_000):
-        self.config = config
         self.mesh = build_mesh(mesh_config or MeshConfig(), devices=devices)
-        if self.mesh.shape.get("sp", 1) > 1 and config.attention_impl == "flash":
-            # sequence sharding needs the ring kernel
-            config = dataclasses.replace(config, attention_impl="ring")
-            self.config = config
-        self.model, params = init_params(config)
         self.tx = make_optimizer(lr, total_steps=total_steps)
-        rules = _model_family(config)[1]()
-        self.param_specs = match_partition_rules(rules, params)
-        opt_state = self.tx.init(params)
-        self.opt_specs = match_partition_rules(rules, opt_state)
-        with self.mesh:
-            params = shard_pytree(params, self.param_specs, self.mesh)
-            opt_state = shard_pytree(opt_state, self.opt_specs, self.mesh)
-        self.state = (params, opt_state)
-
-        from jax.sharding import NamedSharding, PartitionSpec as P
-
-        batch_spec = {
-            "input_ids": P(("dp", "fsdp"), "sp"),
-            "targets": P(("dp", "fsdp"), "sp"),
-        }
-        self.batch_sharding = {
-            k: NamedSharding(self.mesh, s) for k, s in batch_spec.items()}
-        state_shardings = (
-            jax.tree_util.tree_map(lambda s: NamedSharding(self.mesh, s), self.param_specs),
-            jax.tree_util.tree_map(lambda s: NamedSharding(self.mesh, s), self.opt_specs),
-        )
-        self._step = jax.jit(
-            functools.partial(train_step, self.model, self.tx),
-            in_shardings=(state_shardings, self.batch_sharding),
-            out_shardings=(state_shardings, None),
-            donate_argnums=(0,),
-        )
+        self._step, self.model, init_state, layout, self.batch_sharding = \
+            sharded_train_step(config, self.mesh, self.tx)
+        self.config = self.model.config
+        self.param_specs, self.opt_specs = jax.tree_util.tree_map(
+            lambda a: a.sharding.spec, layout)
+        # Initialized under jit straight into its shards: no device ever
+        # holds the whole state, and every process of a multi-host mesh
+        # builds only what it addresses (same seed, same values: the RNG
+        # does not depend on the layout).
+        self.state = jax.jit(init_state, out_shardings=jax.tree_util.tree_map(
+            lambda a: a.sharding, layout))()
 
     # -------------------------------------------------- sharded checkpoints
     def save_checkpoint(self, path: str) -> None:
@@ -158,15 +185,19 @@ class ShardedPretrainer:
         ckptr.close()
 
     def shard_batch(self, batch: Dict[str, Any]):
-        from ray_tpu.parallel.sharding import host_to_global
-
         return {k: host_to_global(jnp.asarray(v), self.batch_sharding[k])
                 for k, v in batch.items() if k in self.batch_sharding}
 
     def step(self, batch: Dict[str, Any]):
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             self.state, loss = self._step(self.state, self.shard_batch(batch))
         return loss
+
+    def lower(self, batch: Dict[str, Any]):
+        """The step lowered for this batch's shapes: ``.compile()`` it for the
+        HLO text and memory analysis of what ``step`` runs."""
+        with jax.set_mesh(self.mesh):
+            return self._step.lower(self.state, self.shard_batch(batch))
 
     def tokens_per_batch(self, batch) -> int:
         return int(batch["input_ids"].size)

@@ -13,8 +13,13 @@ Greenfield relative to the reference — it has no sequence parallelism anywhere
   rotates KV around the ring with ``jax.lax.ppermute`` (ICI neighbor traffic),
   merging partial results with the online-softmax combine.  Causal masking uses
   global offsets so the math matches unsharded attention exactly.
-- Off-TPU (tests: the 8-device CPU mesh) the same Pallas kernel runs in
-  interpreter mode; ``mha_reference`` is the ground truth.
+- Under an ambient mesh (``jax.set_mesh``) ``flash_attention`` runs the kernel
+  inside a ``shard_map`` (batch over dp/fsdp, heads over tp): Mosaic kernels
+  cannot be partitioned by GSPMD, so each device must see a whole local call.
+- The kernel compiles for the TPU unless the process ASKED for the Pallas
+  interpreter (``RAY_TPU_PALLAS_INTERPRET=1``, set by the CPU test substrate in
+  ``_private/platform.py``); it never falls into interpret mode on its own.
+  ``mha_reference`` is the ground truth.
 
 Block sizes default to MXU-friendly (128, 128); head_dim should be a multiple
 of 128 for peak MXU utilization but any size compiles.
@@ -23,11 +28,15 @@ of 128 for peak MXU utilization but any size compiles.
 from __future__ import annotations
 
 import functools
+import os
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.parallel.mesh import ambient_mesh
 
 DEFAULT_BLOCK_Q = 128
 DEFAULT_BLOCK_K = 128
@@ -40,11 +49,12 @@ NEG_INF = -1e30
 LANES = 128
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:
-        return False
+def _interpret() -> bool:
+    """Whether this process asked for the Pallas interpreter (read at trace
+    time).  Without the request the kernel lowers through Mosaic, and on a
+    backend that cannot run it Pallas raises — a CPU run never passes for a
+    kernel run by accident."""
+    return os.environ.get("RAY_TPU_PALLAS_INTERPRET") == "1"
 
 
 # =========================================================== XLA reference
@@ -252,13 +262,13 @@ def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, q_offset, k_offset,
 def _flash_attention(q, k, v, causal, sm_scale, q_offset, k_offset,
                      block_q, block_k):
     out, _ = _flash_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
-                            block_q, block_k, interpret=not _on_tpu())
+                            block_q, block_k, interpret=_interpret())
     return out
 
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, q_offset, k_offset, block_q, block_k):
     out, lse = _flash_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
-                              block_q, block_k, interpret=not _on_tpu())
+                              block_q, block_k, interpret=_interpret())
     return out, (q, k, v, out, lse)
 
 
@@ -273,14 +283,36 @@ def _flash_bwd_rule(causal, sm_scale, q_offset, k_offset, block_q, block_k,
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
+def _bhsd_spec(mesh, batch_axes, head_axis, seq_axis=None):
+    """PartitionSpec of a (B, H, S, D) tensor over whichever of the named
+    axes ``mesh`` has."""
+    return P(tuple(a for a in batch_axes if a in mesh.shape) or None,
+             head_axis if head_axis in mesh.shape else None,
+             seq_axis, None)
+
+
 def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = None,
                     q_offset: int = 0, k_offset: int = 0,
                     block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K):
-    """Blockwise (flash) attention. q,k,v: (B, H, S, D) -> (B, H, S, D)."""
+    """Blockwise (flash) attention. q,k,v: (B, H, S, D) -> (B, H, S, D).
+
+    Under an ambient mesh of more than one device the kernel runs inside a
+    ``shard_map`` — batch over dp/fsdp, heads over tp, the sequence whole on
+    every device (sequence sharding is ring attention's job).  Without one,
+    or on a one-device mesh, it is the plain call.
+    """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    return _flash_attention(q, k, v, causal, float(sm_scale),
-                            int(q_offset), int(k_offset), block_q, block_k)
+    f = functools.partial(
+        _flash_attention, causal=causal, sm_scale=float(sm_scale),
+        q_offset=int(q_offset), k_offset=int(k_offset),
+        block_q=block_q, block_k=block_k)
+    mesh = ambient_mesh()
+    if mesh is None or mesh.size == 1:
+        return f(q, k, v)
+    spec = _bhsd_spec(mesh, ("dp", "fsdp"), "tp")
+    return jax.shard_map(f, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)(q, k, v)
 
 
 # ======================================================== ring attention
@@ -325,9 +357,7 @@ def ring_attention(q, k, v, *, axis_name: str = "sp", causal: bool = True,
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
-    # psum of a literal constant-folds to the axis size as a static int
-    # (jax.lax.axis_size only exists on newer jax releases).
-    ring = jax.lax.psum(1, axis_name)
+    ring = jax.lax.axis_size(axis_name)
     me = jax.lax.axis_index(axis_name)
     chunk = q.shape[2]
     b, h, _, d = q.shape
@@ -358,14 +388,6 @@ def ring_attention(q, k, v, *, axis_name: str = "sp", causal: bool = True,
     return (acc / l_safe[..., None]).astype(q.dtype)
 
 
-def ambient_mesh():
-    """The mesh activated by ``with mesh:`` around the current trace, if any."""
-    from jax.interpreters import pxla
-
-    m = pxla.thread_resources.env.physical_mesh
-    return None if m.empty else m
-
-
 def ring_attention_sharded(q, k, v, *, mesh=None, causal: bool = True,
                            sm_scale: Optional[float] = None,
                            batch_axes=("dp", "fsdp"), head_axis: str = "tp",
@@ -376,22 +398,13 @@ def ring_attention_sharded(q, k, v, *, mesh=None, causal: bool = True,
     q,k,v: (B, H, S, D) sharded (batch_axes, head_axis, seq_axis, None).
     Differentiable (shard_map + ppermute have transposition rules).
     """
-    from jax.sharding import PartitionSpec as P
-
-    try:
-        from jax.experimental.shard_map import shard_map
-    except ImportError:  # newer jax
-        from jax.sharding import shard_map  # type: ignore
-
     mesh = mesh or ambient_mesh()
     if mesh is None:
         raise ValueError("ring_attention_sharded needs a mesh (pass mesh= or "
-                         "activate one with `with mesh:`)")
-    spec = P(tuple(a for a in batch_axes if a in mesh.shape),
-             head_axis if head_axis in mesh.shape else None,
-             seq_axis, None)
-    f = shard_map(
+                         "activate one with `jax.set_mesh`)")
+    spec = _bhsd_spec(mesh, batch_axes, head_axis, seq_axis)
+    f = jax.shard_map(
         functools.partial(ring_attention, axis_name=seq_axis, causal=causal,
                           sm_scale=sm_scale),
-        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_rep=False)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
     return f(q, k, v)

@@ -23,14 +23,14 @@ from ray_tpu.parallel.mesh import (
 )
 from ray_tpu.parallel.sharding import (
     PartitionRules,
+    constrain_residual,
     gpt_partition_rules,
     match_partition_rules,
     shard_pytree,
-    with_sharding_constraint,
 )
 
 __all__ = [
     "MeshConfig", "build_mesh", "local_mesh", "mesh_shape_for",
-    "PartitionRules", "gpt_partition_rules", "match_partition_rules",
-    "shard_pytree", "with_sharding_constraint",
+    "PartitionRules", "constrain_residual", "gpt_partition_rules",
+    "match_partition_rules", "shard_pytree",
 ]

@@ -114,6 +114,7 @@ def build_mesh(config: Optional[MeshConfig] = None, devices=None):
     stays inside one slice.
     """
     import jax
+    from jax.experimental import mesh_utils
     from jax.sharding import Mesh
 
     if devices is None:
@@ -124,12 +125,10 @@ def build_mesh(config: Optional[MeshConfig] = None, devices=None):
     ici_shape = tuple(sizes[a] for a in order)
 
     def slice_mesh(devs):
-        try:
-            from jax.experimental import mesh_utils
-
-            return mesh_utils.create_device_mesh(ici_shape, devices=devs)
-        except Exception:
-            return np.asarray(devs).reshape(ici_shape)
+        # On TPU this picks the ICI-aware device order and raises when the
+        # shape does not fit the physical topology — no flat-reshape fallback,
+        # which would be a wrong ICI order taken in silence.
+        return mesh_utils.create_device_mesh(ici_shape, devices=devs)
 
     if config.n_slices == 1:
         return Mesh(slice_mesh(devices), order)
@@ -145,6 +144,16 @@ def build_mesh(config: Optional[MeshConfig] = None, devices=None):
     final_shape = (config.dcn_pp * sizes["pp"], config.dcn_dp * sizes["dp"]) \
         + ici_shape[2:]
     return Mesh(stack.reshape(final_shape), order)
+
+
+def ambient_mesh():
+    """The mesh activated by ``jax.set_mesh`` around the current trace, if
+    any.  The attention kernels and the residual-stream constraint lay
+    themselves out over it."""
+    import jax
+
+    m = jax.sharding.get_abstract_mesh()
+    return None if m.empty else m
 
 
 def local_mesh(axis: str = "dp"):

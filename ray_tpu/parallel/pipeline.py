@@ -41,11 +41,6 @@ def pipeline_apply(stage_fn: Callable, stacked_params, microbatches,
     """
     from jax.sharding import PartitionSpec as P
 
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-
     S = mesh.shape[axis]
     M = microbatches.shape[0]
     T = M + S - 1
@@ -53,25 +48,6 @@ def pipeline_apply(stage_fn: Callable, stacked_params, microbatches,
 
     param_specs = jax.tree_util.tree_map(
         lambda _: P(axis), stacked_params)
-
-    def _smap_variants(fn):
-        # Partial-manual shard_map (jax >= 0.8/0.9): ONLY the pp axis is
-        # manual, so dp/fsdp/tp/sp shardings of the activations stay under
-        # GSPMD and compose with the pipeline untouched.  Partial-manual is
-        # rejected outside jit (and by older jax), so a full-manual variant
-        # follows — correct when the other mesh axes carry no sharding.
-        try:
-            yield shard_map(fn, mesh=mesh, in_specs=(param_specs, P()),
-                            out_specs=P(), check_vma=False,
-                            axis_names={axis})
-        except TypeError:
-            pass
-        try:
-            yield shard_map(fn, mesh=mesh, in_specs=(param_specs, P()),
-                            out_specs=P(), check_vma=False)
-        except TypeError:
-            yield shard_map(fn, mesh=mesh, in_specs=(param_specs, P()),
-                            out_specs=P(), check_rep=False)
 
     def run(params_local, xs):
         rank = jax.lax.axis_index(axis)
@@ -102,13 +78,10 @@ def pipeline_apply(stage_fn: Callable, stacked_params, microbatches,
         # zero) copies replicates it without a separate broadcast.
         return jax.lax.psum(outs, axis)
 
-    err = None
-    for mapped in _smap_variants(run):
-        try:
-            return mapped(stacked_params, microbatches)
-        except ValueError as e:
-            # partial-manual rejected (e.g. eager call outside jit): try the
-            # full-manual variant
-            err = e
-            continue
-    raise err
+    # Partial-manual: ONLY the pp axis is manual, so dp/fsdp/tp/sp shardings
+    # of the activations stay under GSPMD and compose with the pipeline
+    # untouched.  Partial-manual shard_map is rejected outside jit, hence the
+    # jit here (a no-op when the caller is already tracing).
+    mapped = jax.shard_map(run, mesh=mesh, in_specs=(param_specs, P()),
+                           out_specs=P(), axis_names={axis}, check_vma=False)
+    return jax.jit(mapped)(stacked_params, microbatches)

@@ -136,11 +136,24 @@ def shard_pytree(params, specs, mesh):
         lambda x, s: host_to_global(x, NamedSharding(mesh, s)), params, specs)
 
 
-def with_sharding_constraint(x, spec, mesh=None):
-    """Annotate an intermediate value's sharding (inside jit)."""
-    import jax
-    from jax.sharding import NamedSharding
+def constrain_residual(x):
+    """Pin a (batch, seq, embed) activation to the batch's own layout,
+    ``P(("dp", "fsdp"), "sp", None)``, under the ambient mesh
+    (``jax.set_mesh``); identity without one.
 
-    if mesh is not None:
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    return jax.lax.with_sharding_constraint(x, spec)
+    The model stacks call this on the residual stream.  Without it GSPMD
+    follows the fsdp-sharded *weights* and replicates the batch's compute
+    over ``fsdp`` — parameters stored in quarters, every chip doing the whole
+    batch.  With it the weights are all-gathered on use and the activations
+    stay split, which is what the axis is for.
+    """
+    import jax
+
+    from ray_tpu.parallel.mesh import ambient_mesh
+
+    mesh = ambient_mesh()
+    if mesh is None or mesh.size == 1:
+        return x
+    batch = tuple(a for a in ("dp", "fsdp") if a in mesh.shape) or None
+    seq = "sp" if "sp" in mesh.shape else None
+    return jax.lax.with_sharding_constraint(x, _spec(batch, seq, None))

@@ -79,8 +79,8 @@ class TrainWorker:
 def _local_ip() -> str:
     # Best source: the local address of this worker's live GCS connection —
     # a route PROVEN to reach the cluster (the 8.8.8.8 UDP trick can return
-    # an unroutable interface, e.g. a TEST-NET tunnel address, and loopback
-    # as a coordinator address breaks every nonzero-rank host).
+    # an unroutable interface, e.g. a TEST-NET address, and loopback as a
+    # coordinator address breaks every nonzero-rank host).
     try:
         from ray_tpu._private import worker as worker_mod
 
@@ -115,6 +115,7 @@ class WorkerGroup:
                  placement_strategy: str = "PACK",
                  ready_timeout_s: float = 60.0):
         self.num_workers = num_workers
+        self.resources_per_worker = dict(resources_per_worker)
         bundles = [dict(resources_per_worker) for _ in range(num_workers)]
         self._pg: Optional[PlacementGroup] = placement_group(
             bundles, strategy=placement_strategy, name="train-worker-group")

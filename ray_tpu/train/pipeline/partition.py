@@ -225,7 +225,8 @@ class GPT2StageModule:
         import jax.numpy as jnp
         from flax import linen as nn
 
-        from ray_tpu.models.gpt2 import lm_loss
+        from ray_tpu.models.gpt2 import (lm_loss, mask_vocab_padding,
+                                         padded_vocab)
 
         cfg = self.config
         if self.is_first:
@@ -240,9 +241,11 @@ class GPT2StageModule:
             return x
         x = nn.LayerNorm(dtype=cfg.dtype, name="ln_f").apply(
             {"params": params["ln_f"]}, x)
-        logits = nn.Dense(cfg.vocab_size, use_bias=False, dtype=cfg.dtype,
+        logits = nn.Dense(padded_vocab(cfg.vocab_size), use_bias=False,
+                          dtype=cfg.dtype,
                           name="lm_head").apply({"params": params["lm_head"]}, x)
-        return lm_loss(logits, batch["targets"], batch.get("mask"))
+        return lm_loss(mask_vocab_padding(logits, cfg.vocab_size),
+                       batch["targets"], batch.get("mask"))
 
     # ---------------------------------------------------------- sharding
     def specs(self, params):

@@ -277,14 +277,9 @@ class TestXlaLowering:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:
-            from jax.sharding import shard_map
-
         mesh = self._mesh(n)
-        return jax.jit(shard_map(fn, mesh=mesh, in_specs=(P("dp"),),
-                                 out_specs=P("dp")))(x)
+        return jax.jit(jax.shard_map(fn, mesh=mesh, in_specs=(P("dp"),),
+                                     out_specs=P("dp")))(x)
 
     def test_allreduce(self):
         from ray_tpu.util.collective import xla
@@ -299,16 +294,11 @@ class TestXlaLowering:
         import jax
         from jax.sharding import PartitionSpec as P
 
-        try:
-            from jax.experimental.shard_map import shard_map
-        except ImportError:
-            from jax.sharding import shard_map
-
         from ray_tpu.util.collective import xla
 
         x = np.arange(16, dtype=np.float32)
         mesh = self._mesh(4)
-        out = jax.jit(shard_map(
+        out = jax.jit(jax.shard_map(
             lambda s: xla.reducescatter(s, "dp"),
             mesh=mesh, in_specs=(P("dp"),), out_specs=P("dp")))(x)
         shards = x.reshape(4, 4)
