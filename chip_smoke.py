@@ -16,9 +16,12 @@ run: there is no path from a failed phase to exit code 0.
 
 This driver process never initializes a JAX backend: a chip belongs to one
 process, and that process is the train worker.  With no chip the script exits
-non-zero and takes no step on the CPU.  The last line of stdout is one JSON
-object, ``{"ok": true, "device": {...}, ...}``.  Timings in it are smoke
-timings of one short run, not benchmark metrics.
+non-zero and takes no step on the CPU.  The last two lines of stdout are JSON
+objects: first the report (model, phases, attention check, compile cache,
+``"claim": null``), whose timings are smoke timings of one short run and not
+benchmark metrics; then, last, the verdict and nothing else,
+``{"ok": true, "device": {"platform": "tpu", "kind": "...", "count": N}}``,
+with the device as the worker's JAX reports it.
 """
 
 from __future__ import annotations
@@ -323,7 +326,6 @@ def main() -> int:
     _require(not _driver_touched_backend(),
              "the driver process initialized a JAX backend")
     print(json.dumps({
-        "ok": True,
         "device": device,
         "model": report["model"],
         "phases": report["phases"],
@@ -332,6 +334,10 @@ def main() -> int:
         "driver_backend_initialized": False,
         "claim": None,
     }))
+    # the verdict, alone on the last line: exactly these keys
+    print(json.dumps({"ok": True, "device": {
+        "platform": str(device["platform"]), "kind": str(device["kind"]),
+        "count": int(device["count"])}}), flush=True)
     return 0
 
 

@@ -23,6 +23,17 @@ _DEFAULT_COMPILE_CACHE = os.path.join(
     os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__)))),
     ".jax_cache")
 
+# glibc gives every thread but the first its own arena, built from 64 MiB
+# heaps, and unmaps such a heap the moment it is entirely free unless
+# M_TOP_PAD covers it.  Loading a compiled program from the cache allocates
+# and frees tens of MB over and over; off the main thread — where every
+# ray_tpu worker runs its tasks — each round unmapped and re-faulted the heap:
+# 7.0 s to load the GPT-2-small step against 2.5 s on the main thread, 2.05 s
+# with this pad on any thread (chip runs, PR 21).  Costs address space, and
+# at most this much freed memory kept per arena.
+_M_TOP_PAD = -2  # <malloc.h>
+_HEAP_PAD_BYTES = 64 << 20
+
 
 def force_cpu_platform(n_devices: int = 8) -> None:
     """Force JAX onto ``n_devices`` virtual CPU devices, with Pallas kernels
@@ -50,10 +61,14 @@ def enable_compile_cache() -> str:
     Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX's own reading of it stands
     and nothing is set here; otherwise the cache lives in
     ``<checkout>/.jax_cache``.  Call it in the process that compiles, before
-    the first compile.
+    the first compile.  Either way the allocator is told to keep the loader's
+    freed heaps (see ``_HEAP_PAD_BYTES``).
     """
+    import ctypes
+
     import jax
 
+    ctypes.CDLL(None).mallopt(_M_TOP_PAD, _HEAP_PAD_BYTES)
     from_env = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if from_env:
         return from_env
