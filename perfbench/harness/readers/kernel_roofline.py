@@ -1,0 +1,35 @@
+"""A kernel's share of its roofline: the least time the chip could take for
+the calls the trace shows (``flops.py``: FLOPs over the bf16 peak or bytes over
+the HBM bandwidth, whichever is larger) over the time they took.  ``work`` names the function
+that counts one call on one device, as ``<module of harness/>.<function>``
+(``flops.flash_fwd_call``: a later kernel brings its count in a module of its
+own); ``op``, ``path`` and ``not_path`` select the calls, as in
+``trace_ops``."""
+
+import importlib
+
+from perfbench.harness import flops
+from perfbench.harness.readers.trace_ops import selected
+
+
+def read(ctx, work: str, op=None, path=None, not_path=None):
+    if ctx.trace is None or not ctx.devices:
+        return None
+    traffic = ctx.cell.traffic
+    mesh = traffic["mesh"]
+    # one device's share: its replica's rows, its tp share of the heads
+    model_parallel = mesh.get("tp", 1) * mesh.get("sp", 1) * mesh.get("pp", 1)
+    rows = traffic["rows_per_step"] // (ctx.cell.chips // model_parallel)
+    module, _, function = work.rpartition(".")
+    count = getattr(importlib.import_module(f"perfbench.harness.{module}"),
+                    function)
+    call = {k: v / mesh.get("tp", 1) for k, v in count(
+        ctx.cell.config, ctx.cell.chips, rows, traffic["seq"]).items()}
+    least, _bound = flops.roofline_seconds(call, ctx.peak)
+    shares = []
+    for d in ctx.devices:
+        found = selected(ctx.trace.ops[d], path, not_path, op)
+        if not found:
+            return None
+        shares.append(100.0 * least * len(found) / sum(s for _, s in found))
+    return sum(shares) / len(shares)
