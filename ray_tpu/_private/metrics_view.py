@@ -203,9 +203,11 @@ def summarize_serve(samples: List[Sample]) -> Dict[str, Dict[str, float]]:
 # ------------------------------------------------------------- data view
 
 def summarize_data(samples: List[Sample]) -> Dict[str, Dict]:
-    """Data view: per-operator counters/queues plus per-pipeline byte budget
-    state: {"operators": {"dataset/op": {...}}, "pipelines": {dataset:
-    {buffered_bytes, backpressure}}}."""
+    """Data view: per-operator counters/queues, per-pipeline byte budget
+    state, and how long consumers waited inside their batch iterators:
+    {"operators": {"dataset/op": {...}}, "pipelines": {dataset:
+    {buffered_bytes, backpressure}}, "iterator": {batches, wait mean/p50/p95
+    (s)}}."""
     keys = ("dataset", "operator")
     rows = _sum_by(samples, "ray_tpu_data_rows_output_total", keys)
     blocks = _sum_by(samples, "ray_tpu_data_blocks_output_total", keys)
@@ -227,7 +229,13 @@ def summarize_data(samples: List[Sample]) -> Dict[str, Dict]:
                "backpressure": gated.get(k, 0.0)}
         for k in set(buffered) | set(gated)
     }
-    return {"operators": operators, "pipelines": pipelines}
+    wait = _hist_by(samples, "ray_tpu_data_iter_wait_seconds", ()).get((), {})
+    iterator = {"batches": wait.get("count", 0.0),
+                "wait_mean_s": wait.get("mean", 0.0),
+                "wait_p50_s": wait.get("p50", 0.0),
+                "wait_p95_s": wait.get("p95", 0.0)}
+    return {"operators": operators, "pipelines": pipelines,
+            "iterator": iterator}
 
 
 # ------------------------------------------------------------ train view
@@ -240,7 +248,8 @@ _GANG_NAMES = {v: k for k, v in GANG_STATES.items()}
 
 def summarize_train(samples: List[Sample]) -> Dict[str, Dict]:
     """Per-experiment Train view: gang state/size, report()
-    throughput counters, checkpoint-persist latency stats."""
+    throughput counters, checkpoint-persist latency stats, and how long
+    report() waited for its lockstep hand-off."""
     keys = ("experiment",)
     reports = _sum_by(samples, "ray_tpu_train_report_total", keys)
     rounds = _sum_by(samples, "ray_tpu_train_report_rounds_total", keys)
@@ -248,6 +257,7 @@ def summarize_train(samples: List[Sample]) -> Dict[str, Dict]:
     workers = _max_by(samples, "ray_tpu_train_gang_workers", keys)
     skew = _max_by(samples, "ray_tpu_train_gang_step_skew", keys)
     ckpt = _hist_by(samples, "ray_tpu_train_checkpoint_persist_seconds", keys)
+    wait = _hist_by(samples, "ray_tpu_train_report_wait_seconds", keys)
     # per-rank step heartbeats: derive skew directly from the rank gauges
     # too, so the view names stragglers even before (or without) the
     # driver-folded skew gauge landing on a scrape
@@ -273,6 +283,8 @@ def summarize_train(samples: List[Sample]) -> Dict[str, Dict]:
             "checkpoint_mean_s": stats.get("mean", 0.0),
             "checkpoint_p50_s": stats.get("p50", 0.0),
             "checkpoint_p95_s": stats.get("p95", 0.0),
+            "report_wait_mean_s": wait.get(k, {}).get("mean", 0.0),
+            "report_wait_p95_s": wait.get(k, {}).get("p95", 0.0),
         }
     return out
 

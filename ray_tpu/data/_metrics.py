@@ -46,5 +46,10 @@ def data_metrics() -> Dict[str, M.Metric]:
                         "data_backpressure",
                         "1 while the byte budget is gating source "
                         "admission, per dataset"),
+                    "iter_wait": M.Histogram(
+                        "data_iter_wait_seconds",
+                        "time a consumer's next() spent inside a batch "
+                        "iterator, per yielded batch",
+                        boundaries=M.PHASE_SECONDS_BOUNDARIES),
                 }
     return _metrics

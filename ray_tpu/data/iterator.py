@@ -11,12 +11,34 @@ multi-device mesh.
 from __future__ import annotations
 
 import collections
+import functools
+import time
 from typing import Any, Dict, Iterator, List, Optional
 
 import numpy as np
 
 import ray_tpu
+from ray_tpu.data._metrics import data_metrics
 from ray_tpu.data.block import Block, BlockAccessor, format_batch
+from ray_tpu.util.tracing import profiler_span
+
+
+def _observes_wait(iter_batches):
+    """A batch iterator that also observes, per yielded batch, how long the
+    consumer's ``next()`` spent inside it (``data_iter_wait_seconds``): what
+    tells an input-bound loop from a report-bound one with no profiler
+    attached."""
+
+    @functools.wraps(iter_batches)
+    def observed(*args, **kwargs):
+        wait = data_metrics()["iter_wait"]
+        t0 = time.perf_counter()
+        for batch in iter_batches(*args, **kwargs):
+            wait.observe(time.perf_counter() - t0)
+            yield batch
+            t0 = time.perf_counter()
+
+    return observed
 
 
 class DataIterator:
@@ -36,6 +58,7 @@ class DataIterator:
             yield from BlockAccessor.iter_rows(block)
 
     # ------------------------------------------------------------ batches
+    @_observes_wait
     def iter_batches(self, *, batch_size: Optional[int] = 256,
                      batch_format: Optional[str] = "numpy",
                      drop_last: bool = False,
@@ -46,27 +69,29 @@ class DataIterator:
                               local_shuffle_buffer_size, rng):
             yield format_batch(block, batch_format)
 
+    @_observes_wait
     def iter_jax_batches(self, *, batch_size: Optional[int] = 256,
                          drop_last: bool = True, device=None, sharding=None,
                          prefetch: int = 2, dtypes=None) -> Iterator[Any]:
         import jax
 
         def put(batch: Block):
-            batch = BlockAccessor.to_numpy_block(batch)
-            out = {}
-            for k, v in batch.items():
-                if v.dtype.kind == "O":
-                    out[k] = v          # leave object columns on host
-                    continue
-                if dtypes and k in dtypes:
-                    v = v.astype(dtypes[k])
-                if sharding is not None:
-                    out[k] = jax.device_put(v, sharding)
-                elif device is not None:
-                    out[k] = jax.device_put(v, device)
-                else:
-                    out[k] = jax.device_put(v)
-            return out
+            with profiler_span("data/device_put"):
+                batch = BlockAccessor.to_numpy_block(batch)
+                out = {}
+                for k, v in batch.items():
+                    if v.dtype.kind == "O":
+                        out[k] = v          # leave object columns on host
+                        continue
+                    if dtypes and k in dtypes:
+                        v = v.astype(dtypes[k])
+                    if sharding is not None:
+                        out[k] = jax.device_put(v, sharding)
+                    elif device is not None:
+                        out[k] = jax.device_put(v, device)
+                    else:
+                        out[k] = jax.device_put(v)
+                return out
 
         # Depth-`prefetch` pipeline: device transfers for upcoming batches are
         # issued before the current batch is consumed, hiding host->HBM copy
@@ -80,6 +105,7 @@ class DataIterator:
         while queue:
             yield queue.popleft()
 
+    @_observes_wait
     def iter_torch_batches(self, *, batch_size: Optional[int] = 256,
                            drop_last: bool = False, device=None,
                            dtypes=None,
@@ -124,10 +150,26 @@ def _rebatch(blocks: Iterator[Block], batch_size: Optional[int],
     Shuffle path: the buffer is merged + permuted once per REFILL and then
     emitted as slices — permuting the whole buffer per emitted batch would
     cost O(buffer) memcpy per batch (reference: shuffling batcher semantics).
+
+    The ``data/rebatch`` spans cover the merge and each slice: the work done
+    here between two yields, not the wait for the next block.
     """
     if batch_size is None:
         yield from (b for b in blocks if BlockAccessor.num_rows(b))
         return
+
+    def merge(buf: List[Block]) -> Block:
+        with profiler_span("data/rebatch"):
+            merged = BlockAccessor.concat(buf)
+            if shuffle_buffer:
+                perm = rng.permutation(BlockAccessor.num_rows(merged))
+                merged = BlockAccessor.take_idx(merged, perm)
+            return merged
+
+    def cut(merged: Block, lo: int, hi: int) -> Block:
+        with profiler_span("data/rebatch"):
+            return BlockAccessor.slice(merged, lo, hi)
+
     buf: List[Block] = []
     buffered = 0
     min_buf = shuffle_buffer or 0
@@ -138,31 +180,25 @@ def _rebatch(blocks: Iterator[Block], batch_size: Optional[int],
         buf.append(block)
         buffered += n
         if buffered >= batch_size + min_buf:
-            merged = BlockAccessor.concat(buf)
-            if shuffle_buffer:
-                perm = rng.permutation(BlockAccessor.num_rows(merged))
-                merged = BlockAccessor.take_idx(merged, perm)
+            merged = merge(buf)
             # emit whole batches down to the shuffle floor, keep the tail
             pos = 0
             total = BlockAccessor.num_rows(merged)
             while total - pos >= batch_size + min_buf:
-                yield BlockAccessor.slice(merged, pos, pos + batch_size)
+                yield cut(merged, pos, pos + batch_size)
                 pos += batch_size
-            rest = BlockAccessor.slice(merged, pos, total)
+            rest = cut(merged, pos, total)
             buf = [rest] if BlockAccessor.num_rows(rest) else []
             buffered = total - pos
     if buffered:
-        merged = BlockAccessor.concat(buf)
-        if shuffle_buffer:
-            perm = rng.permutation(BlockAccessor.num_rows(merged))
-            merged = BlockAccessor.take_idx(merged, perm)
+        merged = merge(buf)
         pos = 0
         total = BlockAccessor.num_rows(merged)
         while total - pos >= batch_size:
-            yield BlockAccessor.slice(merged, pos, pos + batch_size)
+            yield cut(merged, pos, pos + batch_size)
             pos += batch_size
         if pos < total and not drop_last:
-            yield BlockAccessor.slice(merged, pos, total)
+            yield cut(merged, pos, total)
 
 
 # ===================================================== streaming split
@@ -280,20 +316,27 @@ class _SplitIterator(DataIterator):
         self._epoch = -1
         super().__init__(self._pull_blocks)
 
-    def _pull_blocks(self):
-        import time
-
-        self._epoch += 1
+    def _pull_block(self) -> Optional[Block]:
+        """This split's next block of the epoch, ``None`` when it is done."""
         while True:
             item = ray_tpu.get(
                 self._coord.get_next.remote(self._idx, self._epoch))
             if item is None:
-                return
+                return None
             if item == "wait":  # epoch barrier: others still draining
                 time.sleep(0.05)
                 continue
             ref, _rows = item
-            yield ray_tpu.get(ref)
+            return ray_tpu.get(ref)
+
+    def _pull_blocks(self):
+        self._epoch += 1
+        while True:
+            with profiler_span("data/pull_block"):
+                block = self._pull_block()
+            if block is None:
+                return
+            yield block
 
 
 def build_streaming_split(ds, n: int, *, equal: bool = False):

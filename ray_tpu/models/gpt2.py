@@ -197,10 +197,11 @@ class GPT2LMModel(nn.Module):
 
 def lm_loss(logits, targets, mask=None):
     """Mean next-token cross entropy in f32."""
-    logits = logits.astype(jnp.float32)
-    logp = jax.nn.log_softmax(logits, axis=-1)
-    ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
-    if mask is None:
-        return -jnp.mean(ll)
-    mask = mask.astype(jnp.float32)
-    return -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+    with jax.named_scope("lm_loss"):
+        logits = logits.astype(jnp.float32)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        if mask is None:
+            return -jnp.mean(ll)
+        mask = mask.astype(jnp.float32)
+        return -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)

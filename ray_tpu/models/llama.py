@@ -87,13 +87,15 @@ class LlamaAttention(nn.Module):
         q = q.reshape(B, S, H, D).transpose(0, 2, 1, 3)
         k = k.reshape(B, S, KV, D).transpose(0, 2, 1, 3)
         v = v.reshape(B, S, KV, D).transpose(0, 2, 1, 3)
-        cos, sin = rope_frequencies(D, positions, cfg.rope_theta)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
+        with jax.named_scope("rope"):
+            cos, sin = rope_frequencies(D, positions, cfg.rope_theta)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
         if KV != H:  # GQA: each kv head serves H/KV query heads
             rep = H // KV
-            k = jnp.repeat(k, rep, axis=1)
-            v = jnp.repeat(v, rep, axis=1)
+            with jax.named_scope("kv_repeat"):
+                k = jnp.repeat(k, rep, axis=1)
+                v = jnp.repeat(v, rep, axis=1)
         if cfg.attention_impl == "ring":
             out = ring_attention_sharded(q, k, v, causal=True,
                                          seq_axis=cfg.ring_axis)

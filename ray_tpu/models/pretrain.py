@@ -24,6 +24,7 @@ from ray_tpu.parallel.sharding import (
     host_to_global,
     match_partition_rules,
 )
+from ray_tpu.util.tracing import profiler_span
 
 
 def make_optimizer(lr: float = 3e-4, weight_decay: float = 0.1,
@@ -77,8 +78,9 @@ def train_step(model, tx, state, batch):
         return loss_fn(model, p, batch)
 
     loss, grads = jax.value_and_grad(_loss)(params)
-    updates, opt_state = tx.update(grads, opt_state, params)
-    params = optax.apply_updates(params, updates)
+    with jax.named_scope("optimizer"):
+        updates, opt_state = tx.update(grads, opt_state, params)
+        params = optax.apply_updates(params, updates)
     return (params, opt_state), loss
 
 
@@ -149,6 +151,7 @@ class ShardedPretrainer:
         # does not depend on the layout).
         self.state = jax.jit(init_state, out_shardings=jax.tree_util.tree_map(
             lambda a: a.sharding, layout))()
+        self._steps = 0     # calls of step(): the profiler's step number
 
     # -------------------------------------------------- sharded checkpoints
     def save_checkpoint(self, path: str) -> None:
@@ -185,12 +188,22 @@ class ShardedPretrainer:
         ckptr.close()
 
     def shard_batch(self, batch: Dict[str, Any]):
-        return {k: host_to_global(jnp.asarray(v), self.batch_sharding[k])
-                for k, v in batch.items() if k in self.batch_sharding}
+        with profiler_span("step/shard_batch"):
+            return {k: host_to_global(jnp.asarray(v), self.batch_sharding[k])
+                    for k, v in batch.items() if k in self.batch_sharding}
 
     def step(self, batch: Dict[str, Any]):
-        with jax.set_mesh(self.mesh):
-            self.state, loss = self._step(self.state, self.shard_batch(batch))
+        """One step, enqueued: the loss comes back as a device array.  In a
+        profiler session each call is one step of the trace's Steps line,
+        with the host's two parts of it — laying the batch out, and the call
+        that enqueues the compiled program — as spans under it."""
+        with jax.profiler.StepTraceAnnotation("ray_tpu/step",
+                                              step_num=self._steps), \
+                jax.set_mesh(self.mesh):
+            self._steps += 1
+            batch = self.shard_batch(batch)
+            with profiler_span("step/dispatch"):
+                self.state, loss = self._step(self.state, batch)
         return loss
 
     def lower(self, batch: Dict[str, Any]):

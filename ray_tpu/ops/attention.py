@@ -169,27 +169,30 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset, k_offset,
     from jax.experimental.pallas import tpu as pltpu
 
     grid = (b * h, s_q_pad // block_q)
-    out, lse = pl.pallas_call(
-        functools.partial(_flash_fwd_kernel, block_k=block_k,
-                          sm_scale=sm_scale, causal=causal, s_k_real=s_k),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, iq: (bh, iq, 0)),
-            pl.BlockSpec((None, s_k_pad, d), lambda bh, iq: (bh, 0, 0)),
-            pl.BlockSpec((None, s_k_pad, d), lambda bh, iq: (bh, 0, 0)),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-            pl.BlockSpec(memory_space=pltpu.SMEM),
-        ],
-        out_specs=[
-            pl.BlockSpec((None, block_q, d), lambda bh, iq: (bh, iq, 0)),
-            pl.BlockSpec((None, block_q, LANES), lambda bh, iq: (bh, iq, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype),
-            jax.ShapeDtypeStruct((b * h, s_q_pad, LANES), jnp.float32),
-        ],
-        interpret=interpret,
-    )(qr, kr, vr, qo, ko)
+    with jax.named_scope("flash_fwd"):
+        out, lse = pl.pallas_call(
+            functools.partial(_flash_fwd_kernel, block_k=block_k,
+                              sm_scale=sm_scale, causal=causal, s_k_real=s_k),
+            grid=grid,
+            in_specs=[
+                pl.BlockSpec((None, block_q, d), lambda bh, iq: (bh, iq, 0)),
+                pl.BlockSpec((None, s_k_pad, d), lambda bh, iq: (bh, 0, 0)),
+                pl.BlockSpec((None, s_k_pad, d), lambda bh, iq: (bh, 0, 0)),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+                pl.BlockSpec(memory_space=pltpu.SMEM),
+            ],
+            out_specs=[
+                pl.BlockSpec((None, block_q, d), lambda bh, iq: (bh, iq, 0)),
+                pl.BlockSpec((None, block_q, LANES),
+                             lambda bh, iq: (bh, iq, 0)),
+            ],
+            out_shape=[
+                jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype),
+                jax.ShapeDtypeStruct((b * h, s_q_pad, LANES), jnp.float32),
+            ],
+            interpret=interpret,
+            name="flash_fwd",
+        )(qr, kr, vr, qo, ko)
     out = out[:, :s_q]
     lse = lse[:, :s_q, 0]
     return out.reshape(b, h, s_q, d), lse.reshape(b, h, s_q)
@@ -275,9 +278,9 @@ def _flash_fwd_rule(q, k, v, causal, sm_scale, q_offset, k_offset, block_q, bloc
 def _flash_bwd_rule(causal, sm_scale, q_offset, k_offset, block_q, block_k,
                     residuals, g):
     q, k, v, out, lse = residuals
-    dq, dk, dv = _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                                 q_offset, k_offset, block_k)
-    return dq, dk, dv
+    with jax.named_scope("flash_bwd"):
+        return _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
+                               q_offset, k_offset, block_k)
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -355,6 +355,11 @@ def _print_data_summary(summary: dict) -> None:
         gated = "BACKPRESSURED" if p["backpressure"] else "flowing"
         print(f"pipeline {ds}: buffered "
               f"{p['buffered_bytes']/2**20:.1f} MiB, {gated}")
+    it = summary.get("iterator") or {}
+    if it.get("batches"):
+        print(f"batch iterators: {it['batches']:g} batches, consumer waited "
+              f"{it['wait_mean_s']*1e3:.3f} ms mean, "
+              f"{it['wait_p95_s']*1e3:.3f} ms p95")
 
 
 def _print_train_summary(summary: dict) -> None:
@@ -362,12 +367,14 @@ def _print_train_summary(summary: dict) -> None:
         print("no train metrics recorded yet")
         return
     print(f"{'experiment':40} {'state':>9} {'workers':>8} {'reports':>8} "
-          f"{'rounds':>7} {'skew':>5} {'ckpts':>6} {'ckpt p50 s':>11}")
+          f"{'rounds':>7} {'skew':>5} {'ckpts':>6} {'ckpt p50 s':>11} "
+          f"{'report wait ms':>15}")
     for name, d in sorted(summary.items()):
         print(f"{name:40} {d['gang_state']:>9} {d['workers']:>8g} "
               f"{d['reports']:>8g} {d['report_rounds']:>7g} "
               f"{d.get('step_skew', 0):>5g} "
-              f"{d['checkpoints']:>6g} {d['checkpoint_p50_s']:>11.3f}")
+              f"{d['checkpoints']:>6g} {d['checkpoint_p50_s']:>11.3f} "
+              f"{d.get('report_wait_mean_s', 0) * 1e3:>15.3f}")
 
 
 def _cmd_memory(args) -> int:

@@ -60,6 +60,12 @@ def train_metrics() -> Dict[str, M.Metric]:
                         "report()-side checkpoint persist duration, per "
                         "experiment",
                         boundaries=CHECKPOINT_SECONDS_BOUNDARIES),
+                    "report_wait": M.Histogram(
+                        "train_report_wait_seconds",
+                        "time a worker's report() waited for the lockstep "
+                        "hand-off (result queued until the actor thread "
+                        "took it), per experiment",
+                        boundaries=M.PHASE_SECONDS_BOUNDARIES),
                     "ckpt_restore": M.Histogram(
                         "train_checkpoint_restore_seconds",
                         "checkpoint download/materialize duration",

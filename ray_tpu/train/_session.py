@@ -123,28 +123,37 @@ class _TrainSession:
         import time as _time
 
         from ray_tpu.train._metrics import train_metrics
+        from ray_tpu.util.tracing import profiler_span
 
-        m = train_metrics()
-        labels = {"experiment": self.context.experiment_name or ""}
-        m["reports"].inc(1, labels)
-        self._step += 1
-        m["rank_step"].set(self._step, {
-            **labels, "rank": str(self.context.world_rank)})
-        self._stamp_heartbeat()
-        persisted = None
-        if checkpoint is not None:
+        with profiler_span("train/report"):
+            m = train_metrics()
+            labels = {"experiment": self.context.experiment_name or ""}
+            m["reports"].inc(1, labels)
+            self._step += 1
+            m["rank_step"].set(self._step, {
+                **labels, "rank": str(self.context.world_rank)})
+            with profiler_span("train/report/heartbeat"):
+                self._stamp_heartbeat()
+            persisted = None
+            if checkpoint is not None:
+                t0 = _time.perf_counter()
+                with profiler_span("train/report/persist"):
+                    persisted = self._persist_checkpoint(checkpoint)
+                m["ckpt_persist"].observe(_time.perf_counter() - t0, labels)
+            if fault_injection.ENABLED and fault_injection.hit(
+                    "train.report",
+                    detail=self.context.experiment_name or "") == "kill":
+                # dies AFTER the checkpoint persisted but before the result
+                # reaches the driver: the restore path must treat the
+                # persisted dir as durable only once every rank's report
+                # round-tripped
+                fault_injection.kill_self()
             t0 = _time.perf_counter()
-            persisted = self._persist_checkpoint(checkpoint)
-            m["ckpt_persist"].observe(_time.perf_counter() - t0, labels)
-        if fault_injection.ENABLED and fault_injection.hit(
-                "train.report",
-                detail=self.context.experiment_name or "") == "kill":
-            # dies AFTER the checkpoint persisted but before the result
-            # reaches the driver: the restore path must treat the persisted
-            # dir as durable only once every rank's report round-tripped
-            fault_injection.kill_self()
-        self._result_q.put(_TrainingResult(dict(metrics), persisted))
-        self._consumed.acquire()  # lockstep with the driver (reference :403)
+            with profiler_span("train/report/handoff_wait"):
+                self._result_q.put(_TrainingResult(dict(metrics), persisted))
+                # lockstep with the driver (reference :403)
+                self._consumed.acquire()
+            m["report_wait"].observe(_time.perf_counter() - t0, labels)
 
     def _stamp_heartbeat(self) -> None:
         """Per-rank step heartbeat into gang state (GCS KV, fire-and-forget):

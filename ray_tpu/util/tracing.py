@@ -13,14 +13,22 @@ task-event pipeline), so this module adds the two user-visible pieces:
 - :func:`export_otlp` — serialize one trace (or all traces) to an
   OTLP/JSON file (`resourceSpans` shape) that any OpenTelemetry collector
   or Jaeger/Tempo ingester accepts — no otel SDK dependency.
+
+and the one the runtime's own per-step paths use:
+
+- :func:`profiler_span` — a span in the JAX profiler's trace, on the device
+  trace's clock.  It costs nothing to speak of unless a profiler session is
+  running, so it may sit on a path that runs every step; ``trace_span``
+  (two events through the task-event pipeline and a GCS flush) may not.
 """
 
 from __future__ import annotations
 
 import json
 import logging
+import sys
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from typing import Any, Dict, List, Optional
 
 from ray_tpu._private.ids import _fast_unique
@@ -84,9 +92,35 @@ def _emit_span_event(core, span: Span, state: str, ts: float,
     core.emit_raw_event(ev, terminal=state in ("FINISHED", "FAILED"))
 
 
+_NO_SPAN = nullcontext()    # reusable and reentrant: one for every caller
+
+
+def profiler_span(name: str):
+    """``with profiler_span("train/report"):`` — the region as the span
+    ``ray_tpu/train/report`` of the JAX profiler's trace, on the thread that
+    runs it and on the clock of the device's operations, nested by ``with``.
+
+    The profiler session is the switch (``jax.profiler.start_trace`` ...
+    ``stop_trace``): without one the annotation is inactive and records
+    nothing.  JAX is never imported from here — a driver or nodelet that has
+    not loaded it gets one shared no-op, and stays without it.  The names are
+    read letter for letter by ``perfbench/harness/readers/program_span.py``.
+    For cluster-level spans that parent tasks and reach the dashboard and
+    OTLP, use :func:`trace_span`; that one is too dear for a per-step path.
+    """
+    profiler = sys.modules.get("jax.profiler")
+    if profiler is None:
+        return _NO_SPAN
+    return profiler.TraceAnnotation("ray_tpu/" + name)
+
+
 @contextmanager
 def trace_span(name: str, attributes: Optional[Dict[str, Any]] = None):
     """Annotate a code region as a span of the ambient trace.
+
+    For spans of the cluster's work — minutes, not microseconds: each costs
+    two events through the task-event pipeline and a flush to the GCS.  A
+    region that runs every training step takes :func:`profiler_span`.
 
     Inside a task, the span parents under the task's span; at the driver
     with no active trace, a fresh trace starts.  Tasks/actor calls submitted

@@ -147,7 +147,7 @@ def test_history_endpoint_shapes(cluster):
     # library view endpoints: well-formed shells on an idle cluster
     assert isinstance(get("/api/serve"), dict)
     data_view = get("/api/data")
-    assert set(data_view) == {"operators", "pipelines"}
+    assert set(data_view) == {"operators", "pipelines", "iterator"}
     assert isinstance(get("/api/train"), dict)
     assert isinstance(get("/api/llm"), dict)
 
