@@ -115,9 +115,10 @@ def _train_phase(config, mesh_config, devices, base_batch, seen):
     compiled = trainer.lower(batch).compile()
     mosaic_calls = len(re.findall(r'custom_call_target="tpu_custom_call"',
                                   compiled.as_text()))
-    _require(mosaic_calls == config.n_layer,
+    _require(mosaic_calls == 3 * config.n_layer,
              f"{mosaic_calls} tpu_custom_calls in the compiled step, expected "
-             f"one flash forward per layer = {config.n_layer}")
+             f"a flash forward and the backward's two kernels per layer = "
+             f"{3 * config.n_layer}")
     mem = compiled.memory_analysis()
 
     out = {

@@ -6,8 +6,11 @@ Greenfield relative to the reference — it has no sequence parallelism anywhere
 - ``flash_attention``: online-softmax blockwise attention.  Forward is a Pallas
   kernel (grid over (batch*heads, q blocks); KV streamed from VMEM block by
   block with running (m, l, acc) accumulators — the standard flash recurrence).
-  Backward recomputes attention blockwise in XLA using the saved logsumexp, so
-  memory stays O(S·d) rather than O(S²).
+  Backward is two Pallas kernels as well (dK/dV, then dQ) that recompute P
+  tile by tile from the saved logsumexp: operands in the inputs' dtype on the
+  MXU, float32 accumulators in VMEM, no tile above the causal diagonal, so
+  memory stays O(S·d) rather than O(S²) and nothing but the gradients is
+  written to HBM.
 - ``ring_attention``: shard_map over the ``sp`` mesh axis; each step computes
   blockwise attention of the local Q shard against the resident KV shard, then
   rotates KV around the ring with ``jax.lax.ppermute`` (ICI neighbor traffic),
@@ -21,8 +24,10 @@ Greenfield relative to the reference — it has no sequence parallelism anywhere
   ``_private/platform.py``); it never falls into interpret mode on its own.
   ``mha_reference`` is the ground truth.
 
-Block sizes default to MXU-friendly (128, 128); head_dim should be a multiple
-of 128 for peak MXU utilization but any size compiles.
+The forward's block sizes default to MXU-friendly (128, 128) and are the
+public op's ``block_q`` / ``block_k``; the backward picks its own tile edge
+from the sequence length (``_bwd_block``).  head_dim should be a multiple of
+128 for peak MXU utilization but any size compiles.
 """
 
 from __future__ import annotations
@@ -33,6 +38,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
@@ -198,66 +204,259 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset, k_offset,
     return out.reshape(b, h, s_q, d), lse.reshape(b, h, s_q)
 
 
-# ===================================================== blockwise backward
-def _flash_backward(q, k, v, out, lse, g, causal, sm_scale, q_offset, k_offset,
-                    block_k: int):
-    """Memory-efficient backward: recompute P blockwise from saved lse (XLA;
-    scan over kv blocks keeps peak memory at O(S·block)."""
+# ======================================================== pallas backward
+# The flash backward as two Mosaic kernels over (block_q, block_k) tiles of
+# the score square.  Both recompute P = exp(S - lse) from the saved logsumexp
+# and form dS = P * (dP - delta); MXU operands stay in the inputs' dtype, every
+# dot accumulates in float32, and sm_scale meets S after its dot and dQ / dK
+# once, as the accumulator is written out.
+#
+# - ``flash_bwd_dkv``: grid (b*h, k blocks, q blocks).  Works on the transposed
+#   tile S^T (block_k, block_q), so dV += P^T dO and dK += dS^T Q are plain
+#   matmuls and lse / delta are (1, block_q) rows that broadcast down sublanes.
+# - ``flash_bwd_dq``: grid (b*h, q blocks, k blocks).  Works on S
+#   (block_q, block_k), dQ += dS K; lse / delta arrive as the same rows and are
+#   turned into lane-replicated columns once per q block.
+#
+# The last grid axis is the reduction ("arbitrary"): the accumulator is zeroed
+# on its first step and written out on its last.  Under a causal mask a tile
+# wholly above the diagonal does no work, and the index maps clamp its block
+# index to the nearest live tile, so nothing is fetched for it either.  Tiles
+# that the diagonal or the key padding crosses take a masked body (on the
+# diagonal, where the tiling allows, chunk by chunk: ``_bwd_on_tiles``), all
+# others the bare one.
+_NT = (((1,), (1,)), ((), ()))  # a @ b.T
+_NN = (((1,), (0,)), ((), ()))  # a @ b
+
+
+def _dot(a, b, dims):
+    return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
+
+
+# Queries of a diagonal tile are taken this many at a time, each chunk against
+# only the keys up to its last query: 10/16 of a 512 tile's square, 36/64 of a
+# 1024 one.  128 beat 256 on the chip (PERF.md, PR 24).
+_BWD_DIAG_CHUNK = LANES
+
+
+def _bwd_block(s: int, d: int, dtype) -> int:
+    """Edge of the backward kernels' tiles along a sequence of length ``s``:
+    a multiple of LANES, so every tile is whole.  The largest the chip had room
+    for ran fastest at every shape tried (1024 at head widths 64 and 128 in
+    bf16: PERF.md, PR 24), so take it unless padding ``s`` to it adds more
+    than an eighth to the rows the lanes force anyway."""
+    rows = _round_up(s, LANES)
+    for block in (1024, 512, 256) if d * jnp.dtype(dtype).itemsize <= 512 \
+            else (512, 256):
+        if _round_up(s, block) * 8 <= rows * 9:
+            return block
+    return LANES
+
+
+def _bwd_p(s, lse, q_dim: int, thresh, k_limit):
+    """P = exp(s - lse) of a tile of scaled scores whose dim ``q_dim`` runs
+    over queries r and whose other dim runs over keys c, with zeros where
+    r - c < thresh (the key is past the query) or c >= k_limit (the key is
+    padding); None: no such mask.  (``jax.lax`` throughout the tile bodies:
+    they are traced once per chunk of every diagonal tile, and ``jnp``'s
+    wrappers cost several times the primitive to trace.)"""
+    valid = None
+    if thresh is not None or k_limit is not None:
+        c = lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_dim)
+    if thresh is not None:
+        r = lax.broadcasted_iota(jnp.int32, s.shape, q_dim)
+        valid = lax.ge(lax.sub(r, c), thresh)
+    if k_limit is not None:
+        in_k = lax.lt(c, k_limit)
+        valid = in_k if valid is None else lax.bitwise_and(valid, in_k)
+    if valid is not None:
+        s = lax.select(valid, s, lax.full_like(s, NEG_INF))
+    return lax.exp(lax.sub(s, lse))
+
+
+def _bwd_on_tiles(iq, ik, block_q: int, block_k: int, causal: bool, offset: int,
+                  k_pad_from, tri: int, part):
+    """Run ``part(q_slice, k_slice, thresh, k_limit)`` (see ``_bwd_p``)
+    over tile (iq, ik) as its kind needs: not at all above the diagonal, bare
+    below it, masked where the diagonal or the key padding (keys from
+    ``k_pad_from`` on, None if there is none) passes through.  With ``tri``, a
+    tile on the diagonal is square and aligned to it, and is done in chunks of
+    ``tri`` queries against only the keys up to each chunk's last query."""
+    whole = slice(None)
+
+    def bare():
+        part(whole, whole, None, None)
+
+    k_limit = None if k_pad_from is None else k_pad_from - ik * block_k
+    padded = False if k_limit is None else k_limit < block_k
+    if not causal:
+        if padded is False:
+            return bare()
+        pl.when(padded)(lambda: part(whole, whole, None, k_limit))
+        pl.when(jnp.logical_not(padded))(bare)
+        return
+    thresh = ik * block_k - iq * block_q - offset
+    live = thresh <= block_q - 1
+    crossing = thresh > 1 - block_k
+    if tri:
+        def masked():
+            for j in range(block_q // tri):
+                part(slice(j * tri, (j + 1) * tri), slice(0, (j + 1) * tri),
+                     -j * tri, None)
+        is_masked = crossing
+    else:
+        def masked():
+            part(whole, whole, thresh, k_limit)
+        is_masked = jnp.logical_or(crossing, padded)
+    pl.when(jnp.logical_and(live, is_masked))(masked)
+    pl.when(jnp.logical_and(live, jnp.logical_not(is_masked)))(bare)
+
+
+def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                          dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale: float,
+                          causal: bool, offset: int, k_pad_from, tri: int):
+    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
+    ik, iq = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(iq == 0)
+    def _():
+        dk_acc[...] = jnp.zeros_like(dk_acc)
+        dv_acc[...] = jnp.zeros_like(dv_acc)
+
+    def part(qs, ks, thresh, k_limit):
+        q, do = q_ref[qs, :], do_ref[qs, :]
+        st = lax.mul(_dot(k_ref[ks, :], q, _NT), sm_scale)
+        pt = _bwd_p(st, lse_ref[:, qs], 1, thresh, k_limit)
+        dv_acc[ks, :] += _dot(pt.astype(do.dtype), do, _NN)
+        dst = lax.mul(pt, lax.sub(_dot(v_ref[ks, :], do, _NT),
+                                  delta_ref[:, qs]))
+        dk_acc[ks, :] += _dot(dst.astype(q.dtype), q, _NN)
+
+    _bwd_on_tiles(iq, ik, block_q, block_k, causal, offset, k_pad_from, tri, part)
+
+    @pl.when(iq == pl.num_programs(2) - 1)
+    def _():
+        dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+
+
+def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                         dq_ref, dq_acc, lse_col, delta_col, *, sm_scale: float,
+                         causal: bool, offset: int, k_pad_from, tri: int):
+    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
+    iq, ik = pl.program_id(1), pl.program_id(2)
+
+    @pl.when(ik == 0)
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
+        # (1, block_q) rows -> (block_q, LANES) columns, every lane the same
+        lse_col[...] = jnp.broadcast_to(lse_ref[...], (LANES, block_q)).T
+        delta_col[...] = jnp.broadcast_to(delta_ref[...], (LANES, block_q)).T
+
+    def part(qs, ks, thresh, k_limit):
+        k = k_ref[ks, :]
+        s = lax.mul(_dot(q_ref[qs, :], k, _NT), sm_scale)
+        reps = (1, k.shape[0] // LANES)
+        p = _bwd_p(s, jnp.tile(lse_col[qs, :], reps), 0, thresh, k_limit)
+        ds = lax.mul(p, lax.sub(_dot(do_ref[qs, :], v_ref[ks, :], _NT),
+                                jnp.tile(delta_col[qs, :], reps)))
+        dq_acc[qs, :] += _dot(ds.astype(k.dtype), k, _NN)
+
+    _bwd_on_tiles(iq, ik, block_q, block_k, causal, offset, k_pad_from, tri, part)
+
+    @pl.when(ik == pl.num_programs(2) - 1)
+    def _():
+        dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10), inline=True)
+def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
+                    q_offset: int, k_offset: int, interpret: bool):
+    """dq, dk, dv of ``_flash_attention`` from its residuals and ``g``.
+
+    Jitted and inlined so that a model's layers, which call it with the same
+    shapes, share one trace of the two kernels: the equations land in the
+    caller's jaxpr under the caller's scopes, as if written there."""
+    from jax.experimental.pallas import tpu as pltpu
+
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
-    qf = q.astype(jnp.float32) * sm_scale
-    gf = g.astype(jnp.float32)
-    of = out.astype(jnp.float32)
-    delta = jnp.sum(of * gf, axis=-1)  # (b,h,s_q)
+    offset = q_offset - k_offset
+    block_q, block_k = _bwd_block(s_q, d, q.dtype), _bwd_block(s_k, d, q.dtype)
+    s_q_pad, s_k_pad = _round_up(s_q, block_q), _round_up(s_k, block_k)
+    nq, nk = s_q_pad // block_q, s_k_pad // block_k
+    # Square tiles that the diagonal meets corner to corner, and no real query
+    # that sees a padding key: a tile on the diagonal can go chunk by chunk.
+    tri = _BWD_DIAG_CHUNK if (
+        causal and block_q == block_k and offset % block_q == 0
+        and (s_k_pad == s_k or s_q + offset <= s_k)) else 0
 
-    # Mirror the forward's clamping, and pad s_k to a block multiple so the
-    # reshape below is always valid (the round-1 advisor crash: any s_k not a
-    # multiple of the user block_k, e.g. every sequence shorter than 128).
-    block_k = min(block_k, s_k)
-    s_k_pad = _round_up(s_k, block_k)
-    if s_k_pad != s_k:
-        k = jnp.pad(k, ((0, 0), (0, 0), (0, s_k_pad - s_k), (0, 0)))
-        v = jnp.pad(v, ((0, 0), (0, 0), (0, s_k_pad - s_k), (0, 0)))
-    num_kv = s_k_pad // block_k
-    kb = k.reshape(b, h, num_kv, block_k, d).astype(jnp.float32)
-    vb = v.reshape(b, h, num_kv, block_k, d).astype(jnp.float32)
+    def seq(x, s_pad):
+        # Zero padding up to whole blocks: a padded key is masked by the real
+        # length, a padded query has p == 0 through its lse.
+        x = x.reshape(b * h, x.shape[2], d)
+        return jnp.pad(x, ((0, 0), (0, s_pad - x.shape[1]), (0, 0)))
 
-    q_pos = jnp.arange(s_q) + q_offset
-    # Rows with an empty (fully-masked) softmax have lse == NEG_INF; their
-    # exp(s - lse) would blow up — zero them instead.
-    live_row = (lse > NEG_INF / 2)[..., None]
+    def row(x, fill):
+        return jnp.pad(x.reshape(b * h, 1, s_q),
+                       ((0, 0), (0, 0), (0, s_q_pad - s_q)),
+                       constant_values=fill)
 
-    def one_block(j):
-        kj = kb[:, :, j]  # (b,h,block_k,d)
-        vj = vb[:, :, j]
-        s = jnp.einsum("bhqd,bhkd->bhqk", qf, kj)
-        k_idx = jnp.arange(block_k) + j * block_k
-        valid = (k_idx < s_k)[None, :]
-        if causal:
-            k_pos = k_idx + k_offset
-            valid = valid & (q_pos[:, None] >= k_pos[None, :])
-        s = jnp.where(valid, s, NEG_INF)
-        p = jnp.where(live_row, jnp.exp(s - lse[..., None]), 0.0)  # (b,h,q,block_k)
-        dv_j = jnp.einsum("bhqk,bhqd->bhkd", p, gf)
-        dp = jnp.einsum("bhqd,bhkd->bhqk", gf, vj)
-        ds = p * (dp - delta[..., None])
-        dq_j = jnp.einsum("bhqk,bhkd->bhqd", ds, kj)
-        dk_j = jnp.einsum("bhqk,bhqd->bhkd", ds, qf)
-        return dq_j, dk_j, dv_j
+    delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
+    # A row with an empty (fully masked) softmax has lse == NEG_INF, and
+    # exp(s - lse) would blow up: such rows, like the padding rows, get an lse
+    # under which every p is zero.
+    lse = jnp.where(lse > NEG_INF / 2, lse, -NEG_INF)
+    operands = (seq(q, s_q_pad), seq(g, s_q_pad), row(lse, -NEG_INF),
+                row(delta, 0.0), seq(k, s_k_pad), seq(v, s_k_pad))
 
-    def scan_body(carry, j):
-        dq = carry
-        dq_j, dk_j, dv_j = one_block(j)
-        return dq + dq_j, (dk_j, dv_j)
+    def call(kernel, name, q_is_inner, scratch):
+        n_out = 2 if q_is_inner else 1  # dK and dV, or dQ
+        def tile_of(i, j):
+            """Grid position -> (iq, ik), the inner one clamped to the
+            nearest tile that does work."""
+            iq, ik = (j, i) if q_is_inner else (i, j)
+            if not causal:
+                return iq, ik
+            if q_is_inner:
+                first = lax.div(jnp.maximum(ik * block_k - offset, 0),
+                                jnp.int32(block_q))
+                return jnp.clip(iq, first, nq - 1), ik
+            last = lax.div(
+                jnp.maximum(iq * block_q + block_q - 1 + offset, 0),
+                jnp.int32(block_k))
+            return iq, jnp.minimum(ik, jnp.minimum(last, nk - 1))
 
-    dq0 = jnp.zeros((b, h, s_q, d), jnp.float32)
-    dq, (dk_blocks, dv_blocks) = jax.lax.scan(scan_body, dq0, jnp.arange(num_kv))
-    dk = jnp.moveaxis(dk_blocks, 0, 2).reshape(b, h, s_k_pad, d)[:, :, :s_k]
-    # s = (q*sm_scale)·kᵀ, so dL/dq needs the extra sm_scale while dL/dk
-    # already carries it through qf.
-    dq = dq * sm_scale
-    dv = jnp.moveaxis(dv_blocks, 0, 2).reshape(b, h, s_k_pad, d)[:, :, :s_k]
-    return dq.astype(q.dtype), dk.astype(k.dtype), dv.astype(v.dtype)
+        q_spec = pl.BlockSpec((None, block_q, d),
+                              lambda bh, i, j: (bh, tile_of(i, j)[0], 0))
+        row_spec = pl.BlockSpec((None, 1, block_q),
+                                lambda bh, i, j: (bh, 0, tile_of(i, j)[0]))
+        k_spec = pl.BlockSpec((None, block_k, d),
+                              lambda bh, i, j: (bh, tile_of(i, j)[1], 0))
+        out_block, out_pad, out_len = ((block_k, s_k_pad, s_k) if q_is_inner
+                                       else (block_q, s_q_pad, s_q))
+        outs = pl.pallas_call(
+            functools.partial(kernel, sm_scale=sm_scale, causal=causal,
+                              offset=offset, tri=tri,
+                              k_pad_from=None if s_k_pad == s_k else s_k),
+            grid=(b * h, nk, nq) if q_is_inner else (b * h, nq, nk),
+            in_specs=[q_spec, q_spec, row_spec, row_spec, k_spec, k_spec],
+            out_specs=[pl.BlockSpec((None, out_block, d),
+                                    lambda bh, i, j: (bh, i, 0))] * n_out,
+            out_shape=[jax.ShapeDtypeStruct((b * h, out_pad, d), q.dtype)] * n_out,
+            scratch_shapes=[pltpu.VMEM((out_block, d), jnp.float32)] * n_out
+            + scratch,
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name=name,
+        )(*operands)
+        return [o[:, :out_len].reshape(b, h, out_len, d) for o in outs]
+
+    dk, dv = call(_flash_bwd_dkv_kernel, "flash_bwd_dkv", True, [])
+    dq, = call(_flash_bwd_dq_kernel, "flash_bwd_dq", False,
+               [pltpu.VMEM((block_q, LANES), jnp.float32)] * 2)
+    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
 # ============================================================= public op
@@ -280,7 +479,7 @@ def _flash_bwd_rule(causal, sm_scale, q_offset, k_offset, block_q, block_k,
     q, k, v, out, lse = residuals
     with jax.named_scope("flash_bwd"):
         return _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                               q_offset, k_offset, block_k)
+                               q_offset, k_offset, _interpret())
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)

@@ -104,8 +104,9 @@ def test_flash_step_lowers_for_a_sharded_v5e_mesh():
 def test_train_step_compiles_on_one_chip_and_every_four_chip_mesh():
     rows = _child(list(CASES), compile_=True)
     for case, row in rows.items():
-        # one flash forward per layer, as a Mosaic call, not interpreted
-        assert row["tpu_custom_calls"] == N_LAYER, (case, row)
+        # per layer one flash forward and the two kernels of its backward
+        # (dK/dV, dQ), as Mosaic calls, not interpreted
+        assert row["tpu_custom_calls"] == 3 * N_LAYER, (case, row)
     # the fsdp axis splits the batch's compute, not only parameter storage:
     # at the same global batch a device holds about what it holds under dp
     assert rows["fsdp4"]["temp_bytes"] <= 1.3 * rows["dp4"]["temp_bytes"], rows

@@ -95,6 +95,77 @@ class TestFlashAttention:
         for a, b in zip(g1, g2):
             np.testing.assert_allclose(a, b, atol=5e-3, rtol=5e-3)
 
+    # id -> (causal, d, dtype, s_q, s_k, q_offset, k_offset).  The backward
+    # picks its tile edge from the length (``_bwd_block``): 1024 and 2048 run
+    # in tiles of 1024 (one on the diagonal; four, one of them bare and one
+    # skipped), 640 in 5 x 5 tiles of 128, 1100 in 5 x 5 of 256 with a padded
+    # last block.  Unequal tiles or an offset that is no tile multiple take
+    # the masked body in place of the diagonal's chunks.
+    BWD_CASES = {
+        "causal-d64-f32": (True, 64, jnp.float32, 1024, 1024, 0, 0),
+        "causal-d128-bf16": (True, 128, jnp.bfloat16, 1024, 1024, 0, 0),
+        "causal-d64-bf16-four-tiles": (True, 64, jnp.bfloat16, 2048, 2048, 0, 0),
+        "causal-d64-bf16-small-tiles": (True, 64, jnp.bfloat16, 640, 640, 0, 0),
+        "causal-d128-f32-not-a-block-multiple": (True, 128, jnp.float32, 1100, 1100, 0, 0),
+        "full-d128-f32": (False, 128, jnp.float32, 256, 256, 0, 0),
+        "full-d64-bf16-small-tiles": (False, 64, jnp.bfloat16, 640, 640, 0, 0),
+        "full-d64-bf16-not-a-block-multiple": (False, 64, jnp.bfloat16, 1100, 1100, 0, 0),
+        "causal-shorter-than-a-block": (True, 64, jnp.float32, 200, 200, 0, 0),
+        "full-shorter-than-a-block": (False, 128, jnp.bfloat16, 72, 72, 0, 0),
+        "causal-sq-ne-sk-q-offset": (True, 64, jnp.float32, 256, 768, 512, 0),
+        "causal-sq-ne-sk-both-offsets": (True, 128, jnp.float32, 640, 1100, 560, 100),
+        "causal-empty-softmax-rows": (True, 64, jnp.float32, 256, 768, 100, 300),
+    }
+
+    @pytest.mark.parametrize("case", list(BWD_CASES))
+    def test_backward_kernel_matches_reference_gradients(self, case):
+        causal, d, dtype, s_q, s_k, q_off, k_off = self.BWD_CASES[case]
+        ks = jax.random.split(jax.random.PRNGKey(3), 4)
+        q = jax.random.normal(ks[0], (1, 2, s_q, d), dtype)
+        k = jax.random.normal(ks[1], (1, 2, s_k, d), dtype)
+        v = jax.random.normal(ks[2], (1, 2, s_k, d), dtype)
+        w = jax.random.normal(ks[3], (1, 2, s_q, d), jnp.float32)
+        # a query before every key has an empty softmax: the kernel gives it
+        # no output and no gradient, where the reference averages the values
+        live = (jnp.arange(s_q) + q_off >= k_off) | (not causal)
+        w_live = w * live[:, None]
+
+        def loss(fn, w):
+            return lambda q, k, v: jnp.sum(
+                fn(q, k, v, causal=causal, q_offset=q_off, k_offset=k_off)
+                .astype(jnp.float32) * w)
+
+        got = jax.grad(loss(flash_attention, w), argnums=(0, 1, 2))(q, k, v)
+        want = jax.grad(loss(mha_reference, w_live), argnums=(0, 1, 2))(
+            *(x.astype(jnp.float32) for x in (q, k, v)))
+        tol = 5e-5 if dtype == jnp.float32 else 4e-2
+        for a, b in zip(got, want):
+            assert a.dtype == dtype
+            np.testing.assert_allclose(a.astype(jnp.float32), b, atol=tol, rtol=tol)
+        assert not bool(jnp.any(got[0][:, :, ~live]))
+
+    def test_gradient_runs_pallas_kernels_and_no_loop(self):
+        """The mechanism engages: the backward of the op is Pallas calls, and
+        no XLA scan / while is left beside them."""
+        q, k, v = _qkv(b=1, h=1, s=256, d=64)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(flash_attention(q, k, v)),
+            argnums=(0, 1, 2)))(q, k, v)
+        kernels, others = [], []
+
+        def walk(j):
+            for eqn in j.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    kernels.append(eqn.params["name"])  # the kernel's own loops stay inside
+                    continue
+                others.append(eqn.primitive.name)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub)
+
+        walk(jaxpr.jaxpr)
+        assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+        assert not {"scan", "while"} & set(others), others
+
     def test_offsets_shift_mask(self):
         # with q_offset = S_k, every key is visible (no masking)
         q, k, v = _qkv(s=64)
