@@ -3,7 +3,13 @@
 Second model family beside GPT-2 (models/gpt2.py), covering the modern
 pretraining recipe: rotary position embeddings (no learned positions),
 pre-RMSNorm blocks, SwiGLU MLPs, grouped-query attention (n_kv_heads <
-n_heads), untied LM head.  Same TPU discipline as the GPT stack —
+n_heads), untied LM head.  One block serves dense and sparse models: a
+layer's feed-forward is the dense ``SwiGLU`` (``mlp``) or, in every
+``moe_every``-th layer, the dropless routed experts of ``models/moe.py``
+(``moe``: ``n_experts`` SwiGLU experts of width ``d_expert``,
+``moe_top_k`` per token), and ``qk_norm`` puts an RMSNorm over the whole
+query and key projections before the heads are split and rotated
+(OLMoE-1B-7B is this block with both).  Same TPU discipline as the GPT stack —
 bfloat16 activations, fused QKV-free layout matched to
 ``llama_partition_rules`` so tp/fsdp shardings apply by regex, attention
 via the Pallas flash kernel (``ray_tpu.ops.flash_attention``) or ring
@@ -22,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.gpt2 import mask_vocab_padding, padded_vocab
+from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
 from ray_tpu.ops.attention import (flash_attention, mha_reference,
                                    ring_attention_sharded)
 from ray_tpu.parallel.sharding import constrain_residual
@@ -43,7 +50,16 @@ class LlamaConfig:
     ring_axis: str = "sp"
     remat: bool = True
     remat_policy: str = "full"
-    moe_every: int = 0               # pretrainer compatibility (dense only)
+    qk_norm: bool = False            # RMSNorm over the q and k projections
+    # every moe_every-th layer (the last of each period) routes its tokens
+    # through n_experts SwiGLU experts of width d_expert; 0: all dense
+    moe_every: int = 0
+    n_experts: int = 0
+    moe_top_k: int = 0
+    d_expert: int = 0
+    norm_topk_prob: bool = False     # renormalise the chosen probabilities
+    router_aux_weight: float = 0.01  # x load-balancing loss, in the objective
+    router_z_weight: float = 1e-3    # x router z-loss
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -84,6 +100,11 @@ class LlamaAttention(nn.Module):
         q = nn.Dense(H * D, use_bias=False, dtype=cfg.dtype, name="wq")(x)
         k = nn.Dense(KV * D, use_bias=False, dtype=cfg.dtype, name="wk")(x)
         v = nn.Dense(KV * D, use_bias=False, dtype=cfg.dtype, name="wv")(x)
+        if cfg.qk_norm:
+            q = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
+                           name="q_norm")(q)
+            k = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
+                           name="k_norm")(k)
         q = q.reshape(B, S, H, D).transpose(0, 2, 1, 3)
         k = k.reshape(B, S, KV, D).transpose(0, 2, 1, 3)
         v = v.reshape(B, S, KV, D).transpose(0, 2, 1, 3)
@@ -123,6 +144,7 @@ class SwiGLU(nn.Module):
 
 class LlamaBlock(nn.Module):
     config: LlamaConfig
+    routed: bool = False    # this layer's feed-forward: routed experts
 
     @nn.compact
     def __call__(self, x, positions):
@@ -130,10 +152,15 @@ class LlamaBlock(nn.Module):
         x = x + LlamaAttention(cfg, name="attn")(
             nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
                        name="attn_norm")(x), positions)
-        x = x + SwiGLU(cfg, name="mlp")(
-            nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
-                       name="mlp_norm")(x))
-        return x
+        y = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
+                       name="mlp_norm")(x)
+        if self.routed:
+            return x + RoutedSwiGLU(RoutedConfig(
+                n_experts=cfg.n_experts, top_k=cfg.moe_top_k,
+                d_model=cfg.d_model, d_ff=cfg.d_expert,
+                norm_topk_prob=cfg.norm_topk_prob, dtype=cfg.dtype),
+                name="moe")(y)
+        return x + SwiGLU(cfg, name="mlp")(y)
 
 
 class LlamaLMModel(nn.Module):
@@ -154,7 +181,9 @@ class LlamaLMModel(nn.Module):
         else:
             block_cls = LlamaBlock
         for i in range(cfg.n_layer):
-            x = constrain_residual(block_cls(cfg, name=f"h_{i}")(x, positions))
+            routed = cfg.moe_every > 0 and i % cfg.moe_every == cfg.moe_every - 1
+            x = constrain_residual(
+                block_cls(cfg, routed, name=f"h_{i}")(x, positions))
         x = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype, name="norm_f")(x)
         logits = nn.Dense(padded_vocab(cfg.vocab_size), use_bias=False,
                           dtype=cfg.dtype, name="lm_head")(x)

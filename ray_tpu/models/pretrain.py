@@ -59,33 +59,46 @@ def init_params(config, rng=None):
 
 
 def loss_fn(model: GPT2LMModel, params, batch):
-    if model.config.moe_every > 0:
-        from ray_tpu.models.moe import collect_moe_aux_loss
-
-        logits, state = model.apply({"params": params}, batch["input_ids"],
-                                    mutable=["intermediates"])
-        aux = collect_moe_aux_loss(state["intermediates"])
-        return lm_loss(logits, batch["targets"], batch.get("mask")) + aux
+    """The language-model cross entropy, for every model."""
     logits = model.apply({"params": params}, batch["input_ids"])
     return lm_loss(logits, batch["targets"], batch.get("mask"))
 
 
+def objective_fn(model, params, batch):
+    """What ``train_step`` differentiates, and what it reports beside it:
+    (cross entropy + the MoE layers' auxiliary terms, (cross entropy, the
+    step's MoE statistics)).  A dense model has no such terms and no
+    statistics, and its objective is ``loss_fn``."""
+    if model.config.moe_every <= 0:
+        loss = loss_fn(model, params, batch)
+        return loss, (loss, {})
+    from ray_tpu.models.moe import collect_aux
+
+    cfg = model.config
+    logits, sown = model.apply({"params": params}, batch["input_ids"],
+                               mutable=["intermediates"])
+    loss = lm_loss(logits, batch["targets"], batch.get("mask"))
+    aux, stats = collect_aux(sown["intermediates"],
+                             getattr(cfg, "router_aux_weight", 0.0),
+                             getattr(cfg, "router_z_weight", 0.0))
+    return loss + aux, (loss, stats)
+
+
 def train_step(model, tx, state, batch):
-    """state = (params, opt_state). One fused fwd+bwd+update."""
+    """state = (params, opt_state). One fused fwd+bwd+update ->
+    (state, the cross entropy, the step's MoE statistics: ``{}`` for a dense
+    model, else ``load_balance``, ``z``, ``max_load`` as device scalars)."""
     params, opt_state = state
-
-    def _loss(p):
-        return loss_fn(model, p, batch)
-
-    loss, grads = jax.value_and_grad(_loss)(params)
+    (_, (loss, stats)), grads = jax.value_and_grad(
+        lambda p: objective_fn(model, p, batch), has_aux=True)(params)
     with jax.named_scope("optimizer"):
         updates, opt_state = tx.update(grads, opt_state, params)
         params = optax.apply_updates(params, updates)
-    return (params, opt_state), loss
+    return (params, opt_state), loss, stats
 
 
 class ShardedStep(NamedTuple):
-    step: Any         # jitted (state, batch) -> (state, loss)
+    step: Any         # jitted (state, batch) -> (state, loss, MoE statistics)
     model: Any        # the module the step applies (attention impl resolved)
     init_state: Any   # () -> (params, opt_state), seeded; jit it into `state`'s shardings
     state: Any        # (params, opt_state) as ShapeDtypeStructs with shardings
@@ -127,7 +140,7 @@ def sharded_train_step(config, mesh, tx) -> ShardedStep:
     step = jax.jit(
         pretrain_step,
         in_shardings=(state_shardings, batch_sharding),
-        out_shardings=(state_shardings, None),
+        out_shardings=(state_shardings, None, None),
         donate_argnums=(0,),
     )
     return ShardedStep(step, model, init_state, state, batch_sharding)
@@ -152,6 +165,9 @@ class ShardedPretrainer:
         self.state = jax.jit(init_state, out_shardings=jax.tree_util.tree_map(
             lambda a: a.sharding, layout))()
         self._steps = 0     # calls of step(): the profiler's step number
+        # the last step's MoE statistics (load_balance, z, max_load), device
+        # scalars that nothing has synchronised on; {} for a dense model
+        self.moe_stats: Dict[str, Any] = {}
 
     # -------------------------------------------------- sharded checkpoints
     def save_checkpoint(self, path: str) -> None:
@@ -203,7 +219,8 @@ class ShardedPretrainer:
             self._steps += 1
             batch = self.shard_batch(batch)
             with profiler_span("step/dispatch"):
-                self.state, loss = self._step(self.state, batch)
+                self.state, loss, self.moe_stats = self._step(self.state,
+                                                              batch)
         return loss
 
     def lower(self, batch: Dict[str, Any]):

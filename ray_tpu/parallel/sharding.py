@@ -74,6 +74,13 @@ def llama_partition_rules() -> PartitionRules:
         (r"attn/wo/kernel", _spec("tp", "fsdp")),
         (r"mlp/(gate_proj|up_proj)/kernel", _spec("fsdp", "tp")),
         (r"mlp/down_proj/kernel", _spec("tp", "fsdp")),
+        # routed layers (models/moe.py::RoutedSwiGLU as h_<n>/moe): the
+        # router replicated; expert arrays (E, ., .) over ep, then as the
+        # dense MLP's
+        (r"moe/router/kernel", _spec()),
+        (r"moe/(gate_proj|up_proj)", _spec("ep", "fsdp", "tp")),
+        (r"moe/down_proj", _spec("ep", "tp", "fsdp")),
+        # attn_norm, mlp_norm, norm_f and the q_norm / k_norm scales
         (r"norm|scale", _spec()),
         (r"lm_head/kernel", _spec("fsdp", "tp")),
     ])

@@ -6,7 +6,12 @@ libtpu compiles for a ``v5e:2x2`` topology with no chip attached — the same
 compiler the chip machine has, so its refusals are real.  Here the real
 ``train_step`` of GPT-2-small at full width (768 wide, vocab 50257, seq 1024,
 bf16, flash attention; depth cut to 2 to stay cheap) is lowered and compiled
-for one device and for the four-chip meshes.
+for one device and for the four-chip meshes.  So is the one-chip step of
+OLMoE-1B-7B at published widths (2048 wide, 64 experts of 1024, top-8, vocab
+50304, seq 4096, remat; depth cut to the benchmark's one layer): the grouped
+matmul's Mosaic kernels meet the compiler here, and the full compile's memory
+analysis for 1, 2 and 4 rows a step is where ``olmoe-s4k-1chip``'s
+``rows_per_step`` was decided.
 
 Every case runs in a subprocess (this file, as a script): the libtpu client
 must never meet the forced-CPU test process, and the child must NOT inherit
@@ -37,6 +42,16 @@ CASES = {
 }
 
 
+def _olmoe_cell():
+    """(model configuration, seq) of the benchmark's ``olmoe-s4k-1chip``:
+    OLMoE-1B-7B-0125 at published widths, one layer."""
+    from perfbench.harness import families, manifest
+
+    cell = manifest.cell("olmoe-s4k-1chip")
+    return (families.of(cell.config).model_config(cell.config, 1),
+            cell.traffic["seq"])
+
+
 def _build(case: str, compile_: bool) -> dict:
     """In the child: lower (and compile) the step for one case."""
     import re
@@ -49,17 +64,23 @@ def _build(case: str, compile_: bool) -> dict:
     from ray_tpu.models.pretrain import make_optimizer, sharded_train_step
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
-    mesh_kwargs, n_devices = CASES[case]
     topo = topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2",
         chips_per_host_bounds=[2, 2, 1], num_slices=1)
-    mesh = build_mesh(MeshConfig(**mesh_kwargs),
-                      devices=topo.devices[:n_devices])
-    config = GPT2Config(n_layer=N_LAYER, remat=False)
-    assert config.vocab_size == 50257 and config.attention_impl == "flash"
+    if case.startswith("olmoe_b"):
+        mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+        (config, seq), rows = _olmoe_cell(), int(case[len("olmoe_b"):])
+        assert config.n_experts == 64 and config.d_model == 2048
+    else:
+        mesh_kwargs, n_devices = CASES[case]
+        mesh = build_mesh(MeshConfig(**mesh_kwargs),
+                          devices=topo.devices[:n_devices])
+        config, rows, seq = GPT2Config(n_layer=N_LAYER, remat=False), \
+            GLOBAL_BATCH, SEQ
+        assert config.vocab_size == 50257
+    assert config.attention_impl == "flash"
     s = sharded_train_step(config, mesh, make_optimizer())
-    batch = {k: jax.ShapeDtypeStruct((GLOBAL_BATCH, SEQ), jnp.int32,
-                                     sharding=sh)
+    batch = {k: jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=sh)
              for k, sh in s.batch_sharding.items()}
     with jax.set_mesh(mesh):
         lowered = s.step.trace(s.state, batch).lower(
@@ -67,7 +88,11 @@ def _build(case: str, compile_: bool) -> dict:
     out = {"case": case,
            "lowered_has_mosaic": "tpu_custom_call" in lowered.as_text()}
     if compile_:
-        compiled = lowered.compile()
+        try:
+            compiled = lowered.compile()
+        except Exception as e:  # e.g. the program does not fit the chip
+            out["refused"] = str(e)[-600:]
+            return out
         mem = compiled.memory_analysis()
         out["tpu_custom_calls"] = len(re.findall(
             r'custom_call_target="tpu_custom_call"', compiled.as_text()))
@@ -112,6 +137,33 @@ def test_train_step_compiles_on_one_chip_and_every_four_chip_mesh():
     assert rows["fsdp4"]["temp_bytes"] <= 1.3 * rows["dp4"]["temp_bytes"], rows
     # ... and it does shard the parameters
     assert rows["fsdp4"]["argument_bytes"] < 0.5 * rows["dp4"]["argument_bytes"]
+
+
+def test_olmoe_step_lowers_for_one_v5e_chip():
+    """Tier-1: the one-chip OLMoE step at published widths (one layer, seq
+    4096 x 2 rows) lowers for the TPU with its Mosaic kernels in it: the flash
+    attention's and the routed experts' grouped matmul."""
+    row = _child(["olmoe_b2"], compile_=False)["olmoe_b2"]
+    assert row["lowered_has_mosaic"]
+
+
+@pytest.mark.slow
+def test_olmoe_step_compiles_and_says_how_many_rows_fit():
+    """The TPU compiler takes the grouped matmul's shapes, and its memory
+    analysis says what a step holds at 1, 2 and 4 rows of 4096 (PR 25:
+    7.51 GB of arguments + 2.43 / 3.40 / 5.72 GB of temporaries)."""
+    rows = _child(["olmoe_b1", "olmoe_b2", "olmoe_b4"], compile_=True)
+    for case, row in rows.items():
+        print(case, {k: row.get(k) for k in
+                     ("argument_bytes", "temp_bytes", "refused")})
+    for case in ("olmoe_b1", "olmoe_b2"):
+        row = rows[case]
+        assert "refused" not in row, row
+        # flash forward, its recomputation, the backward's two kernels; the
+        # grouped matmul: gate, up, down forward, recomputed, and two
+        # backward calls each
+        assert row["tpu_custom_calls"] == 4 + 12, row
+        assert row["argument_bytes"] + row["temp_bytes"] < 14e9, row
 
 
 if __name__ == "__main__":
