@@ -8,8 +8,9 @@ Three phases:
   1. Correctness: ``ops.attention.flash_attention`` forward AND backward vs
      ``mha_reference`` (fp32 ground truth) on-chip, causal + non-causal,
      ragged seq lengths (non-block-multiple), bf16 inputs.
-  2. Block-size tuning: sweep (block_q, block_k) on the GPT-2 shape and a
-     long-context shape; report the best and the default's gap.
+  2. Tile sweep: the forward alone over square tiles of 256 / 512 / 1024 at
+     the shapes the benchmark's cells run, beside the rule's own choice
+     (``attention._block``), and fwd+bwd at the rule's tile.
   3. flash vs XLA attention: fwd and fwd+bwd wall time + achieved FLOPs at
      several sequence lengths, bf16.
 
@@ -34,7 +35,7 @@ import jax.numpy as jnp
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from ray_tpu.ops.attention import flash_attention, mha_reference  # noqa: E402
+from ray_tpu.ops.attention import _block, flash_attention, mha_reference  # noqa: E402
 
 
 def _time_fn(fn, q, k, v, iters=20, warmup=2):
@@ -112,50 +113,51 @@ def phase_correctness(report):
 
 
 def phase_tuning(report, quick):
-    shapes = [("gpt2 b8 h12 s1024 d64", 8, 12, 1024, 64)]
+    """The forward alone over square tiles, at the shapes the benchmark's cells
+    run (PERF.md, PR 26, has the runs that chose ``attention._block``).  The
+    backward takes the rule's tile only, so its column is one number a shape."""
+    shapes = [("gpt2 b24 h12 s1024 d64", 24, 12, 1024, 64),
+              ("gpt2 b8 h12 s1024 d64", 8, 12, 1024, 64)]
     if not quick:
-        shapes.append(("longctx b1 h8 s8192 d128", 1, 8, 8192, 128))
-    blocks = [128, 256, 512] if not quick else [128, 256]
+        shapes += [("mistral b1 h32 s8192 d128", 1, 32, 8192, 128),
+                   ("olmoe b2 h16 s4096 d128", 2, 16, 4096, 128)]
+    tiles = [256, 512, 1024]
     best_cfg = {}
-    report.append("## 2. Block-size sweep (fwd+bwd step time, causal bf16)\n")
+    report.append("## 2. Tile sweep (forward alone, causal bf16; "
+                  "`rule` is what `block_q=None` picks)\n")
     for name, b, h, s, d in shapes:
         key = jax.random.PRNGKey(1)
         k1, k2, k3 = jax.random.split(key, 3)
         q = jax.random.normal(k1, (b, h, s, d), jnp.bfloat16)
         k = jax.random.normal(k2, (b, h, s, d), jnp.bfloat16)
         v = jax.random.normal(k3, (b, h, s, d), jnp.bfloat16)
-        report.append(f"### {name}\n")
-        report.append("| block_q | block_k | fwd ms | fwd+bwd ms | fwd TFLOP/s |")
-        report.append("|---|---|---|---|---|")
+        rule = _block(s, d, jnp.bfloat16)
+        report.append(f"### {name} (rule: {rule})\n")
+        report.append("| tile | fwd ms | fwd TFLOP/s |")
+        report.append("|---|---|---|")
         results = []
-        for bq in blocks:
-            for bk in blocks:
-                if bq > s or bk > s:
-                    continue
-                f = jax.jit(functools.partial(
-                    flash_attention, causal=True, block_q=bq, block_k=bk))
-
-                def lf(q, k, v, _f=f):
-                    return jnp.sum(_f(q, k, v).astype(jnp.float32) ** 2)
-
-                _g = jax.jit(jax.grad(lf, argnums=(0, 1, 2)))
-                # chainable forms: output feeds the next call's q
-                gf = lambda q, k, v, _g=_g: _g(q, k, v)[0]  # noqa: E731
-                try:
-                    t_f, _ = _time_fn(f, q, k, v, iters=10)
-                    t_b, _ = _time_fn(gf, q, k, v, iters=10)
-                except Exception as e:  # compile failure at this block size
-                    report.append(f"| {bq} | {bk} | ERR {type(e).__name__} | | |")
-                    continue
-                tf = attn_flops(b, h, s, s, d, True) / t_f / 1e12
-                results.append((t_b, bq, bk, t_f, tf))
-                report.append(
-                    f"| {bq} | {bk} | {t_f*1e3:.2f} | {t_b*1e3:.2f} | {tf:.1f} |")
+        for tile in tiles:
+            if tile > s:
+                continue
+            f = jax.jit(functools.partial(
+                flash_attention, causal=True, block_q=tile, block_k=tile))
+            try:
+                t_f, _ = _time_fn(f, q, k, v, iters=10)
+            except Exception as e:  # compile failure at this tile
+                report.append(f"| {tile} | ERR {type(e).__name__} | |")
+                continue
+            tf = attn_flops(b, h, s, s, d, True) / t_f / 1e12
+            results.append((t_f, tile))
+            report.append(f"| {tile} | {t_f*1e3:.2f} | {tf:.1f} |")
+        f = jax.jit(functools.partial(flash_attention, causal=True))
+        _g = jax.jit(jax.grad(
+            lambda q, k, v: jnp.sum(f(q, k, v).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2)))
+        t_b, _ = _time_fn(lambda q, k, v: _g(q, k, v)[0], q, k, v, iters=10)
+        report.append(f"\nfwd+bwd at the rule's tile: {t_b*1e3:.2f} ms\n")
         if results:
-            results.sort()
-            _, bq, bk, _, _ = results[0]
-            best_cfg[name] = (bq, bk)
-            report.append(f"\nBest (fwd+bwd): block_q={bq}, block_k={bk}\n")
+            best_cfg[name] = (min(results)[1], rule)
+            report.append(f"Fastest forward: tile {best_cfg[name][0]}\n")
     return best_cfg
 
 
@@ -222,7 +224,7 @@ def main():
     print(f"phase 1 done ({time.time()-t0:.0f}s); phase 2: block sweep...",
           flush=True)
     best = phase_tuning(report, args.quick)
-    summary["best_blocks"] = {k: list(v) for k, v in best.items()}
+    summary["fastest_tile_and_rule"] = {k: list(v) for k, v in best.items()}
     print(f"phase 2 done ({time.time()-t0:.0f}s); phase 3: vs XLA...",
           flush=True)
     phase_vs_xla(report, args.quick, summary)

@@ -37,6 +37,7 @@ SEED = 0
 # flash vs mha_reference at the GPT-2 shape (b16 h12 s1024 d64 bf16), max abs
 ATTN_FWD_TOL = 2e-2
 ATTN_BWD_TOL = 1e-1
+LONG_K, LONG_Q = 32768, 512  # the forward past the old whole-K/V-in-VMEM ceiling
 
 
 def _require(ok: bool, what: str) -> None:
@@ -192,11 +193,17 @@ def _layout_checks(trainer, batch, devices, peaks):
 
 def _attention_check():
     """Flash forward and backward against ``mha_reference`` at the GPT-2
-    shape, on the chip."""
+    shape, and one forward over 32768 keys (past what a head's whole K/V in
+    VMEM allowed: the kernel's KV grid axis at a length no cell has), on the
+    chip."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu.ops.attention import flash_attention, mha_reference
+
+    def max_abs_err(a, b):
+        return float(jnp.max(jnp.abs(a.astype(jnp.float32)
+                                     - b.astype(jnp.float32))))
 
     shape = (BATCH_PER_REPLICA, 12, SEQ, 64)
     q, k, v, g = (jax.random.normal(key, shape, jnp.bfloat16)
@@ -208,14 +215,27 @@ def _attention_check():
 
     got = jax.jit(lambda: fwd_bwd(flash_attention))()
     want = jax.jit(lambda: fwd_bwd(mha_reference))()
-    errs = [float(jnp.max(jnp.abs(a.astype(jnp.float32)
-                                  - b.astype(jnp.float32))))
-            for a, b in zip(got, want)]
+    errs = [max_abs_err(a, b) for a, b in zip(got, want)]
     _require(errs[0] <= ATTN_FWD_TOL and max(errs[1:]) <= ATTN_BWD_TOL,
              f"flash vs reference max abs err fwd {errs[0]:.3g} "
              f"(tol {ATTN_FWD_TOL}), dq/dk/dv {errs[1:]} (tol {ATTN_BWD_TOL})")
+
+    # the last LONG_Q queries of a LONG_K-key sequence, through q_offset
+    long_shape = (1, 2, LONG_K, 128)
+    lq, lk, lv = (jax.random.normal(key, long_shape, jnp.bfloat16)
+                  for key in jax.random.split(jax.random.PRNGKey(SEED + 1), 3))
+    lq = lq[:, :, -LONG_Q:]
+    long_err = max_abs_err(*(
+        jax.jit(lambda attn=attn: attn(lq, lk, lv, causal=True,
+                                       q_offset=LONG_K - LONG_Q))()
+        for attn in (flash_attention, mha_reference)))
+    _require(long_err <= ATTN_FWD_TOL,
+             f"flash vs reference over {LONG_K} keys: max abs err "
+             f"{long_err:.3g} (tol {ATTN_FWD_TOL})")
     return {"shape_bhsd": list(shape), "dtype": "bfloat16",
             "fwd_max_abs_err": errs[0], "bwd_max_abs_err": max(errs[1:]),
+            "long_shape_bhsd": list(long_shape), "long_queries": LONG_Q,
+            "long_fwd_max_abs_err": long_err,
             "fwd_tol": ATTN_FWD_TOL, "bwd_tol": ATTN_BWD_TOL}
 
 

@@ -3,14 +3,16 @@
 Greenfield relative to the reference — it has no sequence parallelism anywhere
 (SURVEY §5.7; no ring/blockwise attention hits in the reference tree).  Design:
 
-- ``flash_attention``: online-softmax blockwise attention.  Forward is a Pallas
-  kernel (grid over (batch*heads, q blocks); KV streamed from VMEM block by
-  block with running (m, l, acc) accumulators — the standard flash recurrence).
-  Backward is two Pallas kernels as well (dK/dV, then dQ) that recompute P
-  tile by tile from the saved logsumexp: operands in the inputs' dtype on the
-  MXU, float32 accumulators in VMEM, no tile above the causal diagonal, so
-  memory stays O(S·d) rather than O(S²) and nothing but the gradients is
-  written to HBM.
+- ``flash_attention``: online-softmax blockwise attention as Pallas (Mosaic)
+  kernels, forward and backward: grids over (batch*heads, q blocks, k blocks)
+  with the k (or q) axis the reduction, K / V streamed from HBM tile by tile
+  through their BlockSpecs, the running (m, l, acc) of the flash recurrence in
+  VMEM scratch.  Operands go to the MXU in the inputs' dtype, accumulation is
+  float32, no tile above the causal diagonal is fetched or computed.  The
+  backward (dK/dV, then dQ) recomputes P tile by tile from the saved
+  logsumexp, so memory stays O(S·d) rather than O(S²), nothing in VMEM scales
+  with the sequence, and nothing but the output, one float32 per query and
+  the gradients is written to HBM.
 - ``ring_attention``: shard_map over the ``sp`` mesh axis; each step computes
   blockwise attention of the local Q shard against the resident KV shard, then
   rotates KV around the ring with ``jax.lax.ppermute`` (ICI neighbor traffic),
@@ -24,17 +26,18 @@ Greenfield relative to the reference — it has no sequence parallelism anywhere
   ``_private/platform.py``); it never falls into interpret mode on its own.
   ``mha_reference`` is the ground truth.
 
-The forward's block sizes default to MXU-friendly (128, 128) and are the
-public op's ``block_q`` / ``block_k``; the backward picks its own tile edge
-from the sequence length (``_bwd_block``).  head_dim should be a multiple of
-128 for peak MXU utilization but any size compiles.
+The kernels share one tile edge, picked from the sequence length, the head
+width and the dtype by what the chip's clock chose (``_block``: 1024 where it
+fits); the public op's ``block_q`` / ``block_k`` override it for the forward
+(tests force many tiles at small sizes that way).  head_dim should be a
+multiple of 128 for peak MXU utilization but any size compiles.
 """
 
 from __future__ import annotations
 
 import functools
 import os
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
@@ -44,14 +47,10 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.parallel.mesh import ambient_mesh
 
-DEFAULT_BLOCK_Q = 128
-DEFAULT_BLOCK_K = 128
 NEG_INF = -1e30
-# Mosaic requires the last two dims of every block to tile as (8, 128) (or
-# equal the full array dim).  The lse output is logically (b*h, s_q) — rank-1
-# per grid step — so it is materialized with a trailing 128-lane dim and
-# sliced back to lane 0 after the call (same layout trick as
-# jax.experimental.pallas.ops.tpu.flash_attention's l/m residuals).
+# Mosaic tiles the last two dims of every block as (8, 128): every tile edge
+# along a sequence is a multiple of LANES, and a per-query statistic lives in
+# VMEM as a (rows, LANES) column with every lane the same.
 LANES = 128
 
 
@@ -78,153 +77,34 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = N
     return jnp.einsum("bhqk,bhkd->bhqd", w.astype(v.dtype), v)
 
 
-# ======================================================== pallas forward
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, qo_ref, ko_ref, o_ref, lse_ref,
-                      *, block_k: int, sm_scale: float, causal: bool,
-                      s_k_real: int):
-    # q_ref: (block_q, d); k_ref/v_ref: (S_k padded, d) for this (b,h).
-    # s_k_real: the unpadded key length — columns >= s_k_real are padding and
-    # always masked out (the S_k buffer is padded to a block_k multiple so
-    # pl.ds never clamps/re-reads earlier keys).
-    block_q, d = q_ref.shape
-    s_k = k_ref.shape[0]
-    iq = pl.program_id(1)
-    q = q_ref[:].astype(jnp.float32) * sm_scale
-    q_pos = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 0) \
-        + iq * block_q + qo_ref[0]
-
-    num_kv = pl.cdiv(s_k, block_k)
-
-    def body(j, carry):
-        m_prev, l_prev, acc = carry
-        k = k_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        vblk = v_ref[pl.ds(j * block_k, block_k), :].astype(jnp.float32)
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        k_idx = jax.lax.broadcasted_iota(jnp.int32, (block_q, block_k), 1) \
-            + j * block_k
-        valid = k_idx < s_k_real
-        if causal:
-            k_pos = k_idx + ko_ref[0]
-            valid = valid & (q_pos >= k_pos)
-        s = jnp.where(valid, s, NEG_INF)
-        m_cur = jnp.max(s, axis=-1)
-        m_new = jnp.maximum(m_prev, m_cur)
-        # Fully-masked row so far (m_new == NEG_INF): exp(s - m) would be
-        # exp(0) = 1 per column; force p = 0 so such rows stay empty.
-        p = jnp.where(m_new[:, None] <= NEG_INF / 2, 0.0,
-                      jnp.exp(s - m_new[:, None]))
-        alpha = jnp.exp(m_prev - m_new)
-        l_new = l_prev * alpha + jnp.sum(p, axis=-1)
-        acc = acc * alpha[:, None] + jax.lax.dot_general(
-            p, vblk, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        return m_new, l_new, acc
-
-    m0 = jnp.full((block_q,), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((block_q,), jnp.float32)
-    acc0 = jnp.zeros((block_q, d), jnp.float32)
-
-    if causal:
-        # Only kv blocks at or before the diagonal contribute; assumes the
-        # common layout q_global >= k_global within a shard pair (ring steps
-        # with kv entirely after q are skipped by the caller).
-        def guarded(j, carry):
-            first_q_pos = iq * block_q + qo_ref[0]
-            blk_start_kpos = j * block_k + ko_ref[0]
-            return jax.lax.cond(
-                blk_start_kpos <= first_q_pos + block_q - 1,
-                lambda c: body(j, c), lambda c: c, carry)
-
-        m, l, acc = jax.lax.fori_loop(0, num_kv, guarded, (m0, l0, acc0))
-    else:
-        m, l, acc = jax.lax.fori_loop(0, num_kv, body, (m0, l0, acc0))
-
-    l_safe = jnp.where(l == 0.0, 1.0, l)
-    o_ref[:] = (acc / l_safe[:, None]).astype(o_ref.dtype)
-    lse = jnp.where(l == 0.0, NEG_INF, m + jnp.log(l_safe))
-    lse_ref[:] = jnp.broadcast_to(lse[:, None], (block_q, LANES))
-
-
-def _round_up(x: int, m: int) -> int:
-    return (x + m - 1) // m * m
-
-
-def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset, k_offset,
-                   block_q: int, block_k: int, interpret: bool):
-    b, h, s_q, d = q.shape
-    s_k = k.shape[2]
-    block_q = min(block_q, s_q)
-    block_k = min(block_k, s_k)
-    # Pad both sequence dims to block multiples: pl.ds with a clamped start
-    # would silently re-read earlier rows under mislabeled positions (the
-    # round-1 advisor bug).  Padded q rows are dropped on return; padded kv
-    # columns are masked inside the kernel via s_k_real.
-    s_q_pad = _round_up(s_q, block_q)
-    s_k_pad = _round_up(s_k, block_k)
-    qr = q.reshape(b * h, s_q, d)
-    kr = k.reshape(b * h, s_k, d)
-    vr = v.reshape(b * h, s_k, d)
-    if s_q_pad != s_q:
-        qr = jnp.pad(qr, ((0, 0), (0, s_q_pad - s_q), (0, 0)))
-    if s_k_pad != s_k:
-        kr = jnp.pad(kr, ((0, 0), (0, s_k_pad - s_k), (0, 0)))
-        vr = jnp.pad(vr, ((0, 0), (0, s_k_pad - s_k), (0, 0)))
-    qo = jnp.asarray([q_offset], jnp.int32)
-    ko = jnp.asarray([k_offset], jnp.int32)
-
-    from jax.experimental.pallas import tpu as pltpu
-
-    grid = (b * h, s_q_pad // block_q)
-    with jax.named_scope("flash_fwd"):
-        out, lse = pl.pallas_call(
-            functools.partial(_flash_fwd_kernel, block_k=block_k,
-                              sm_scale=sm_scale, causal=causal, s_k_real=s_k),
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((None, block_q, d), lambda bh, iq: (bh, iq, 0)),
-                pl.BlockSpec((None, s_k_pad, d), lambda bh, iq: (bh, 0, 0)),
-                pl.BlockSpec((None, s_k_pad, d), lambda bh, iq: (bh, 0, 0)),
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-                pl.BlockSpec(memory_space=pltpu.SMEM),
-            ],
-            out_specs=[
-                pl.BlockSpec((None, block_q, d), lambda bh, iq: (bh, iq, 0)),
-                pl.BlockSpec((None, block_q, LANES),
-                             lambda bh, iq: (bh, iq, 0)),
-            ],
-            out_shape=[
-                jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype),
-                jax.ShapeDtypeStruct((b * h, s_q_pad, LANES), jnp.float32),
-            ],
-            interpret=interpret,
-            name="flash_fwd",
-        )(qr, kr, vr, qo, ko)
-    out = out[:, :s_q]
-    lse = lse[:, :s_q, 0]
-    return out.reshape(b, h, s_q, d), lse.reshape(b, h, s_q)
-
-
-# ======================================================== pallas backward
-# The flash backward as two Mosaic kernels over (block_q, block_k) tiles of
-# the score square.  Both recompute P = exp(S - lse) from the saved logsumexp
-# and form dS = P * (dP - delta); MXU operands stay in the inputs' dtype, every
-# dot accumulates in float32, and sm_scale meets S after its dot and dQ / dK
-# once, as the accumulator is written out.
+# ========================================================== pallas kernels
+# Flash attention as three Mosaic kernels over (block_q, block_k) tiles of the
+# score square: the forward, and the backward as dK/dV and dQ.  MXU operands
+# stay in the inputs' dtype, every dot accumulates in float32, and sm_scale
+# meets S after its dot (and dQ / dK once, as the accumulator is written out).
 #
-# - ``flash_bwd_dkv``: grid (b*h, k blocks, q blocks).  Works on the transposed
-#   tile S^T (block_k, block_q), so dV += P^T dO and dK += dS^T Q are plain
-#   matmuls and lse / delta are (1, block_q) rows that broadcast down sublanes.
-# - ``flash_bwd_dq``: grid (b*h, q blocks, k blocks).  Works on S
-#   (block_q, block_k), dQ += dS K; lse / delta arrive as the same rows and are
-#   turned into lane-replicated columns once per q block.
+# - ``flash_fwd``: grid (b*h, q blocks, k blocks).  Works on S
+#   (block_q, block_k); the running max, the running sum and the output
+#   accumulator of the online softmax live in VMEM scratch, the statistics as
+#   lane-replicated columns; on the last k block the output is normalised and
+#   the logsumexp written as a (1, block_q) row, the layout the backward reads.
+# - ``flash_bwd_dkv``: grid (b*h, k blocks, q blocks).  Recomputes
+#   P = exp(S - lse) on the transposed tile S^T (block_k, block_q), so
+#   dV += P^T dO and dK += dS^T Q (dS = P * (dP - delta)) are plain matmuls and
+#   lse / delta are (1, block_q) rows that broadcast down sublanes.
+# - ``flash_bwd_dq``: grid (b*h, q blocks, k blocks).  Works on S, dQ += dS K;
+#   lse / delta arrive as the same rows and are turned into lane-replicated
+#   columns once per q block.
 #
-# The last grid axis is the reduction ("arbitrary"): the accumulator is zeroed
-# on its first step and written out on its last.  Under a causal mask a tile
-# wholly above the diagonal does no work, and the index maps clamp its block
-# index to the nearest live tile, so nothing is fetched for it either.  Tiles
-# that the diagonal or the key padding crosses take a masked body (on the
-# diagonal, where the tiling allows, chunk by chunk: ``_bwd_on_tiles``), all
-# others the bare one.
+# The last grid axis is the reduction ("arbitrary"): the accumulators are reset
+# on its first step and written out on its last, and K / V (or Q / dO) arrive
+# tile by tile through their BlockSpecs, so nothing in VMEM scales with the
+# sequence.  Under a causal mask a tile wholly above the diagonal does no work,
+# and the index maps clamp its block index to the nearest live tile, so nothing
+# is fetched for it either (``_Tiles.tile_of``).  Tiles that the diagonal or
+# the key padding crosses take a masked body (on the diagonal, where the tiling
+# allows, chunk by chunk: ``_on_tiles``), all others the bare one.  The mask is
+# a function of the tile's indices alone.
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
 
@@ -233,18 +113,28 @@ def _dot(a, b, dims):
     return lax.dot_general(a, b, dims, preferred_element_type=jnp.float32)
 
 
+def _round_up(x: int, m: int) -> int:
+    return (x + m - 1) // m * m
+
+
 # Queries of a diagonal tile are taken this many at a time, each chunk against
-# only the keys up to its last query: 10/16 of a 512 tile's square, 36/64 of a
-# 1024 one.  128 beat 256 on the chip (PERF.md, PR 24).
+# only the keys up to its last query: at 128, 10/16 of a 512 tile's square and
+# 36/64 of a 1024 one.  By the chip's clock (PERF.md, PR 24 and PR 26): 128 for
+# the backward, 256 for the forward, where a chunk's fixed cost weighs more
+# than the keys it spares.
+_FWD_DIAG_CHUNK = 2 * LANES
 _BWD_DIAG_CHUNK = LANES
 
 
-def _bwd_block(s: int, d: int, dtype) -> int:
-    """Edge of the backward kernels' tiles along a sequence of length ``s``:
-    a multiple of LANES, so every tile is whole.  The largest the chip had room
-    for ran fastest at every shape tried (1024 at head widths 64 and 128 in
-    bf16: PERF.md, PR 24), so take it unless padding ``s`` to it adds more
-    than an eighth to the rows the lanes force anyway."""
+def _block(s: int, d: int, dtype, block: Optional[int] = None) -> int:
+    """Edge of the kernels' tiles along a sequence of length ``s``: a multiple
+    of LANES, so every tile is whole.  The largest the chip had room for ran
+    fastest at every shape tried, in both directions (1024 at head widths 64
+    and 128 in bf16: PERF.md, PR 24 and PR 26), so take it unless padding ``s``
+    to it adds more than an eighth to the rows the lanes force anyway.  An
+    explicit ``block`` is taken, rounded up to whole lanes."""
+    if block is not None:
+        return _round_up(min(block, s), LANES)
     rows = _round_up(s, LANES)
     for block in (1024, 512, 256) if d * jnp.dtype(dtype).itemsize <= 512 \
             else (512, 256):
@@ -253,56 +143,119 @@ def _bwd_block(s: int, d: int, dtype) -> int:
     return LANES
 
 
-def _bwd_p(s, lse, q_dim: int, thresh, k_limit):
-    """P = exp(s - lse) of a tile of scaled scores whose dim ``q_dim`` runs
-    over queries r and whose other dim runs over keys c, with zeros where
-    r - c < thresh (the key is past the query) or c >= k_limit (the key is
-    padding); None: no such mask.  (``jax.lax`` throughout the tile bodies:
-    they are traced once per chunk of every diagonal tile, and ``jnp``'s
-    wrappers cost several times the primitive to trace.)"""
+class _Tiles(NamedTuple):
+    """How one call's score square is cut: shared by the three kernels."""
+    causal: bool
+    offset: int      # q_offset - k_offset: query r sees key c iff r + offset >= c
+    block_q: int
+    block_k: int
+    nq: int
+    nk: int
+    k_pad_from: Optional[int]   # first padding key, None if there is none
+    tri: int         # chunk of a diagonal tile's queries; 0: whole-tile mask
+
+    @classmethod
+    def of(cls, s_q, s_k, d, dtype, causal, offset, diag_chunk,
+           block_q=None, block_k=None):
+        block_q = _block(s_q, d, dtype, block_q)
+        block_k = _block(s_k, d, dtype, block_k)
+        s_k_pad = _round_up(s_k, block_k)
+        # Square tiles that the diagonal meets corner to corner, and no real
+        # query that sees a padding key: a tile on the diagonal can go chunk
+        # by chunk.
+        tri = 0
+        if (causal and block_q == block_k and offset % block_q == 0
+                and (s_k_pad == s_k or s_q + offset <= s_k)):
+            tri = diag_chunk if block_q % diag_chunk == 0 else LANES
+        return cls(causal, offset, block_q, block_k,
+                   _round_up(s_q, block_q) // block_q, s_k_pad // block_k,
+                   None if s_k_pad == s_k else s_k, tri)
+
+    def tile_of(self, i, j, q_is_inner: bool):
+        """Grid position -> (iq, ik), the inner one clamped to the nearest
+        tile that does work."""
+        iq, ik = (j, i) if q_is_inner else (i, j)
+        if not self.causal:
+            return iq, ik
+        if q_is_inner:
+            first = lax.div(jnp.maximum(ik * self.block_k - self.offset, 0),
+                            jnp.int32(self.block_q))
+            return jnp.clip(iq, first, self.nq - 1), ik
+        last = lax.div(
+            jnp.maximum(iq * self.block_q + self.block_q - 1 + self.offset, 0),
+            jnp.int32(self.block_k))
+        return iq, jnp.minimum(ik, jnp.minimum(last, self.nk - 1))
+
+    def specs(self, d: int, q_is_inner: bool):
+        """BlockSpecs of a (b*h, s_q, d) operand, a (b*h, 1, s_q) row of
+        per-query statistics and a (b*h, s_k, d) operand, following
+        ``tile_of``."""
+        def at(i, j):
+            return self.tile_of(i, j, q_is_inner)
+        return (pl.BlockSpec((None, self.block_q, d),
+                             lambda bh, i, j: (bh, at(i, j)[0], 0)),
+                pl.BlockSpec((None, 1, self.block_q),
+                             lambda bh, i, j: (bh, 0, at(i, j)[0])),
+                pl.BlockSpec((None, self.block_k, d),
+                             lambda bh, i, j: (bh, at(i, j)[1], 0)))
+
+
+def _rows(x, s_pad: int):
+    """(b, h, s, d) -> (b*h, s_pad, d), zero padded up to whole blocks: a
+    padded key is masked by the real length, a padded query is dropped (the
+    forward) or has p == 0 through its lse (the backward)."""
+    b, h, s, d = x.shape
+    return jnp.pad(x.reshape(b * h, s, d), ((0, 0), (0, s_pad - s), (0, 0)))
+
+
+def _masked(s, q_dim: int, thresh, k_limit):
+    """A tile of scaled scores whose dim ``q_dim`` runs over queries r and
+    whose other dim runs over keys c, with NEG_INF where r - c < thresh (the
+    key is past the query) or c >= k_limit (the key is padding); None: no such
+    mask.  (``jax.lax`` throughout the tile bodies: they are traced once per
+    chunk of every diagonal tile, and ``jnp``'s wrappers cost several times
+    the primitive to trace.)"""
+    if thresh is None and k_limit is None:
+        return s
+    c = lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_dim)
     valid = None
-    if thresh is not None or k_limit is not None:
-        c = lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_dim)
     if thresh is not None:
         r = lax.broadcasted_iota(jnp.int32, s.shape, q_dim)
         valid = lax.ge(lax.sub(r, c), thresh)
     if k_limit is not None:
         in_k = lax.lt(c, k_limit)
         valid = in_k if valid is None else lax.bitwise_and(valid, in_k)
-    if valid is not None:
-        s = lax.select(valid, s, lax.full_like(s, NEG_INF))
-    return lax.exp(lax.sub(s, lse))
+    return lax.select(valid, s, lax.full_like(s, NEG_INF))
 
 
-def _bwd_on_tiles(iq, ik, block_q: int, block_k: int, causal: bool, offset: int,
-                  k_pad_from, tri: int, part):
-    """Run ``part(q_slice, k_slice, thresh, k_limit)`` (see ``_bwd_p``)
-    over tile (iq, ik) as its kind needs: not at all above the diagonal, bare
-    below it, masked where the diagonal or the key padding (keys from
-    ``k_pad_from`` on, None if there is none) passes through.  With ``tri``, a
-    tile on the diagonal is square and aligned to it, and is done in chunks of
-    ``tri`` queries against only the keys up to each chunk's last query."""
+def _on_tiles(t: _Tiles, iq, ik, part):
+    """Run ``part(q_slice, k_slice, thresh, k_limit)`` (see ``_masked``) over
+    tile (iq, ik) as its kind needs: not at all above the diagonal, bare below
+    it, masked where the diagonal or the key padding passes through.  With
+    ``t.tri``, a tile on the diagonal is square and aligned to it, and is done
+    in chunks of ``t.tri`` queries against only the keys up to each chunk's
+    last query."""
     whole = slice(None)
 
     def bare():
         part(whole, whole, None, None)
 
-    k_limit = None if k_pad_from is None else k_pad_from - ik * block_k
-    padded = False if k_limit is None else k_limit < block_k
-    if not causal:
+    k_limit = None if t.k_pad_from is None else t.k_pad_from - ik * t.block_k
+    padded = False if k_limit is None else k_limit < t.block_k
+    if not t.causal:
         if padded is False:
             return bare()
         pl.when(padded)(lambda: part(whole, whole, None, k_limit))
         pl.when(jnp.logical_not(padded))(bare)
         return
-    thresh = ik * block_k - iq * block_q - offset
-    live = thresh <= block_q - 1
-    crossing = thresh > 1 - block_k
-    if tri:
+    thresh = ik * t.block_k - iq * t.block_q - t.offset
+    live = thresh <= t.block_q - 1
+    crossing = thresh > 1 - t.block_k
+    if t.tri:
         def masked():
-            for j in range(block_q // tri):
-                part(slice(j * tri, (j + 1) * tri), slice(0, (j + 1) * tri),
-                     -j * tri, None)
+            for j in range(t.block_q // t.tri):
+                part(slice(j * t.tri, (j + 1) * t.tri),
+                     slice(0, (j + 1) * t.tri), -j * t.tri, None)
         is_masked = crossing
     else:
         def masked():
@@ -312,10 +265,107 @@ def _bwd_on_tiles(iq, ik, block_q: int, block_k: int, causal: bool, offset: int,
     pl.when(jnp.logical_and(live, jnp.logical_not(is_masked)))(bare)
 
 
+def _across(col, n: int):
+    """A lane-replicated (rows, LANES) column as (rows, n)."""
+    if n > LANES:
+        col = jnp.tile(col, (1, -(-n // LANES)))
+    return col if col.shape[1] == n else col[:, :n]
+
+
+# -------------------------------------------------------------- forward
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_col, l_col, acc,
+                      *, sm_scale: float, t: _Tiles):
+    iq, ik = pl.program_id(1), pl.program_id(2)
+    d = q_ref.shape[1]
+
+    @pl.when(ik == 0)
+    def _():
+        m_col[...] = jnp.full_like(m_col, NEG_INF)
+        l_col[...] = jnp.zeros_like(l_col)
+        acc[...] = jnp.zeros_like(acc)
+
+    def part(qs, ks, thresh, k_limit):
+        v = v_ref[ks, :]
+        s = _masked(lax.mul(_dot(q_ref[qs, :], k_ref[ks, :], _NT), sm_scale),
+                    0, thresh, k_limit)
+        lanes = (s.shape[0], LANES)
+        m_old = m_col[qs, :]
+        m_new = lax.max(m_old, jnp.broadcast_to(
+            jnp.max(s, axis=1, keepdims=True), lanes))
+        m_col[qs, :] = m_new
+        alpha = lax.exp(lax.sub(m_old, m_new))
+        if thresh is not None or k_limit is not None:
+            # A row with every key masked so far has m == NEG_INF, and
+            # exp(s - m) would be 1 per column: take its exp against 0.
+            m_new = lax.select(lax.gt(m_new, NEG_INF / 2), m_new,
+                               lax.full_like(m_new, 0.0))
+        p = lax.exp(lax.sub(s, _across(m_new, s.shape[1])))
+        l_col[qs, :] = lax.add(lax.mul(alpha, l_col[qs, :]), jnp.broadcast_to(
+            jnp.sum(p, axis=1, keepdims=True), lanes))
+        acc[qs, :] = lax.add(lax.mul(_across(alpha, d), acc[qs, :]),
+                             _dot(p.astype(v.dtype), v, _NN))
+
+    _on_tiles(t, iq, ik, part)
+
+    @pl.when(ik == t.nk - 1)
+    def _():
+        l = l_col[...]
+        empty = lax.eq(l, 0.0)  # no key seen: output 0, lse NEG_INF
+        l = lax.select(empty, lax.full_like(l, 1.0), l)
+        o_ref[...] = lax.div(acc[...], _across(l, d)).astype(o_ref.dtype)
+        lse = lax.select(empty, lax.full_like(l, NEG_INF),
+                         lax.add(m_col[...], lax.log(l)))
+        # (block_q, LANES) column, every lane the same -> (1, block_q) row
+        lse_ref[...] = lse.T[:1, :]
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9), inline=True)
+def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
+                   k_offset: int, block_q: Optional[int],
+                   block_k: Optional[int], interpret: bool):
+    """``out`` (b, h, s_q, d) and the logsumexp of every query's scaled
+    scores as (b*h, 1, s_q) rows, NEG_INF where a query sees no key.
+
+    Jitted and inlined so that a model's layers, which call it with the same
+    shapes, share one trace of the kernel: the equations land in the caller's
+    jaxpr under the caller's scopes, as if written there."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    b, h, s_q, d = q.shape
+    s_k = k.shape[2]
+    t = _Tiles.of(s_q, s_k, d, q.dtype, causal, q_offset - k_offset,
+                  _FWD_DIAG_CHUNK, block_q, block_k)
+    s_q_pad = t.nq * t.block_q
+    q_spec, row_spec, k_spec = t.specs(d, q_is_inner=False)
+    with jax.named_scope("flash_fwd"):
+        out, lse = pl.pallas_call(
+            functools.partial(_flash_fwd_kernel, sm_scale=sm_scale, t=t),
+            grid=(b * h, t.nq, t.nk),
+            in_specs=[q_spec, k_spec, k_spec],
+            out_specs=[q_spec, row_spec],
+            out_shape=[jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype),
+                       jax.ShapeDtypeStruct((b * h, 1, s_q_pad), jnp.float32)],
+            scratch_shapes=[pltpu.VMEM((t.block_q, LANES), jnp.float32)] * 2
+            + [pltpu.VMEM((t.block_q, d), jnp.float32)],
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary")),
+            interpret=interpret,
+            name="flash_fwd",
+        )(_rows(q, s_q_pad), _rows(k, t.nk * t.block_k),
+          _rows(v, t.nk * t.block_k))
+    return out[:, :s_q].reshape(b, h, s_q, d), lse[:, :, :s_q]
+
+
+# ------------------------------------------------------------- backward
+def _bwd_p(s, lse, q_dim: int, thresh, k_limit):
+    """P = exp(s - lse) of a tile of scaled scores, zero where ``_masked``
+    masks."""
+    return lax.exp(lax.sub(_masked(s, q_dim, thresh, k_limit), lse))
+
+
 def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale: float,
-                          causal: bool, offset: int, k_pad_from, tri: int):
-    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
+                          t: _Tiles):
     ik, iq = pl.program_id(1), pl.program_id(2)
 
     @pl.when(iq == 0)
@@ -332,7 +382,7 @@ def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                                   delta_ref[:, qs]))
         dk_acc[ks, :] += _dot(dst.astype(q.dtype), q, _NN)
 
-    _bwd_on_tiles(iq, ik, block_q, block_k, causal, offset, k_pad_from, tri, part)
+    _on_tiles(t, iq, ik, part)
 
     @pl.when(iq == pl.num_programs(2) - 1)
     def _():
@@ -342,8 +392,8 @@ def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 
 def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                          dq_ref, dq_acc, lse_col, delta_col, *, sm_scale: float,
-                         causal: bool, offset: int, k_pad_from, tri: int):
-    block_q, block_k = q_ref.shape[0], k_ref.shape[0]
+                         t: _Tiles):
+    block_q = q_ref.shape[0]
     iq, ik = pl.program_id(1), pl.program_id(2)
 
     @pl.when(ik == 0)
@@ -362,7 +412,7 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                                 jnp.tile(delta_col[qs, :], reps)))
         dq_acc[qs, :] += _dot(ds.astype(k.dtype), k, _NN)
 
-    _bwd_on_tiles(iq, ik, block_q, block_k, causal, offset, k_pad_from, tri, part)
+    _on_tiles(t, iq, ik, part)
 
     @pl.when(ik == pl.num_programs(2) - 1)
     def _():
@@ -372,34 +422,20 @@ def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
 @functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10), inline=True)
 def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
                     q_offset: int, k_offset: int, interpret: bool):
-    """dq, dk, dv of ``_flash_attention`` from its residuals and ``g``.
+    """dq, dk, dv of ``_flash_attention`` from its residuals (``lse`` as the
+    forward leaves it: (b*h, 1, s_q) rows) and ``g``.
 
-    Jitted and inlined so that a model's layers, which call it with the same
-    shapes, share one trace of the two kernels: the equations land in the
-    caller's jaxpr under the caller's scopes, as if written there."""
+    Jitted and inlined for the reason ``_flash_forward`` is."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s_q, d = q.shape
     s_k = k.shape[2]
-    offset = q_offset - k_offset
-    block_q, block_k = _bwd_block(s_q, d, q.dtype), _bwd_block(s_k, d, q.dtype)
-    s_q_pad, s_k_pad = _round_up(s_q, block_q), _round_up(s_k, block_k)
-    nq, nk = s_q_pad // block_q, s_k_pad // block_k
-    # Square tiles that the diagonal meets corner to corner, and no real query
-    # that sees a padding key: a tile on the diagonal can go chunk by chunk.
-    tri = _BWD_DIAG_CHUNK if (
-        causal and block_q == block_k and offset % block_q == 0
-        and (s_k_pad == s_k or s_q + offset <= s_k)) else 0
-
-    def seq(x, s_pad):
-        # Zero padding up to whole blocks: a padded key is masked by the real
-        # length, a padded query has p == 0 through its lse.
-        x = x.reshape(b * h, x.shape[2], d)
-        return jnp.pad(x, ((0, 0), (0, s_pad - x.shape[1]), (0, 0)))
+    t = _Tiles.of(s_q, s_k, d, q.dtype, causal, q_offset - k_offset,
+                  _BWD_DIAG_CHUNK)
+    s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
 
     def row(x, fill):
-        return jnp.pad(x.reshape(b * h, 1, s_q),
-                       ((0, 0), (0, 0), (0, s_q_pad - s_q)),
+        return jnp.pad(x, ((0, 0), (0, 0), (0, s_q_pad - s_q)),
                        constant_values=fill)
 
     delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
@@ -407,39 +443,18 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
     # exp(s - lse) would blow up: such rows, like the padding rows, get an lse
     # under which every p is zero.
     lse = jnp.where(lse > NEG_INF / 2, lse, -NEG_INF)
-    operands = (seq(q, s_q_pad), seq(g, s_q_pad), row(lse, -NEG_INF),
-                row(delta, 0.0), seq(k, s_k_pad), seq(v, s_k_pad))
+    operands = (_rows(q, s_q_pad), _rows(g, s_q_pad), row(lse, -NEG_INF),
+                row(delta.reshape(b * h, 1, s_q), 0.0),
+                _rows(k, s_k_pad), _rows(v, s_k_pad))
 
     def call(kernel, name, q_is_inner, scratch):
         n_out = 2 if q_is_inner else 1  # dK and dV, or dQ
-        def tile_of(i, j):
-            """Grid position -> (iq, ik), the inner one clamped to the
-            nearest tile that does work."""
-            iq, ik = (j, i) if q_is_inner else (i, j)
-            if not causal:
-                return iq, ik
-            if q_is_inner:
-                first = lax.div(jnp.maximum(ik * block_k - offset, 0),
-                                jnp.int32(block_q))
-                return jnp.clip(iq, first, nq - 1), ik
-            last = lax.div(
-                jnp.maximum(iq * block_q + block_q - 1 + offset, 0),
-                jnp.int32(block_k))
-            return iq, jnp.minimum(ik, jnp.minimum(last, nk - 1))
-
-        q_spec = pl.BlockSpec((None, block_q, d),
-                              lambda bh, i, j: (bh, tile_of(i, j)[0], 0))
-        row_spec = pl.BlockSpec((None, 1, block_q),
-                                lambda bh, i, j: (bh, 0, tile_of(i, j)[0]))
-        k_spec = pl.BlockSpec((None, block_k, d),
-                              lambda bh, i, j: (bh, tile_of(i, j)[1], 0))
-        out_block, out_pad, out_len = ((block_k, s_k_pad, s_k) if q_is_inner
-                                       else (block_q, s_q_pad, s_q))
+        q_spec, row_spec, k_spec = t.specs(d, q_is_inner)
+        out_block, out_pad, out_len = ((t.block_k, s_k_pad, s_k) if q_is_inner
+                                       else (t.block_q, s_q_pad, s_q))
         outs = pl.pallas_call(
-            functools.partial(kernel, sm_scale=sm_scale, causal=causal,
-                              offset=offset, tri=tri,
-                              k_pad_from=None if s_k_pad == s_k else s_k),
-            grid=(b * h, nk, nq) if q_is_inner else (b * h, nq, nk),
+            functools.partial(kernel, sm_scale=sm_scale, t=t),
+            grid=(b * h, t.nk, t.nq) if q_is_inner else (b * h, t.nq, t.nk),
             in_specs=[q_spec, q_spec, row_spec, row_spec, k_spec, k_spec],
             out_specs=[pl.BlockSpec((None, out_block, d),
                                     lambda bh, i, j: (bh, i, 0))] * n_out,
@@ -455,7 +470,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
 
     dk, dv = call(_flash_bwd_dkv_kernel, "flash_bwd_dkv", True, [])
     dq, = call(_flash_bwd_dq_kernel, "flash_bwd_dq", False,
-               [pltpu.VMEM((block_q, LANES), jnp.float32)] * 2)
+               [pltpu.VMEM((t.block_q, LANES), jnp.float32)] * 2)
     return dq, dk.astype(k.dtype), dv.astype(v.dtype)
 
 
@@ -464,13 +479,13 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
 def _flash_attention(q, k, v, causal, sm_scale, q_offset, k_offset,
                      block_q, block_k):
     out, _ = _flash_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
-                            block_q, block_k, interpret=_interpret())
+                            block_q, block_k, _interpret())
     return out
 
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, q_offset, k_offset, block_q, block_k):
     out, lse = _flash_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
-                              block_q, block_k, interpret=_interpret())
+                              block_q, block_k, _interpret())
     return out, (q, k, v, out, lse)
 
 
@@ -495,7 +510,7 @@ def _bhsd_spec(mesh, batch_axes, head_axis, seq_axis=None):
 
 def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = None,
                     q_offset: int = 0, k_offset: int = 0,
-                    block_q: int = DEFAULT_BLOCK_Q, block_k: int = DEFAULT_BLOCK_K):
+                    block_q: Optional[int] = None, block_k: Optional[int] = None):
     """Blockwise (flash) attention. q,k,v: (B, H, S, D) -> (B, H, S, D).
 
     Under an ambient mesh of more than one device the kernel runs inside a

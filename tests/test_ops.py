@@ -8,7 +8,9 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops import attention
 from ray_tpu.ops.attention import (
+    NEG_INF,
     flash_attention,
     mha_reference,
     ring_attention,
@@ -96,7 +98,7 @@ class TestFlashAttention:
             np.testing.assert_allclose(a, b, atol=5e-3, rtol=5e-3)
 
     # id -> (causal, d, dtype, s_q, s_k, q_offset, k_offset).  The backward
-    # picks its tile edge from the length (``_bwd_block``): 1024 and 2048 run
+    # picks its tile edge from the length (``_block``): 1024 and 2048 run
     # in tiles of 1024 (one on the diagonal; four, one of them bare and one
     # skipped), 640 in 5 x 5 tiles of 128, 1100 in 5 x 5 of 256 with a padded
     # last block.  Unequal tiles or an offset that is no tile multiple take
@@ -143,6 +145,95 @@ class TestFlashAttention:
             assert a.dtype == dtype
             np.testing.assert_allclose(a.astype(jnp.float32), b, atol=tol, rtol=tol)
         assert not bool(jnp.any(got[0][:, :, ~live]))
+
+    # id -> (causal, d, dtype, s_q, s_k, q_offset, k_offset, block_q, block_k);
+    # None: the rule's own choice (``_block``).  One k tile is the plain
+    # softmax, several carry the running state; square tiles that the diagonal
+    # meets corner to corner go chunk by chunk, the others (unequal tiles, an
+    # offset that is no tile multiple) take the whole-tile mask.
+    FWD_CASES = {
+        "bf16-d64-rule-one-tile": (True, 64, jnp.bfloat16, 1024, 1024, 0, 0, None, None),
+        "f32-d128-rule-four-tiles": (True, 128, jnp.float32, 2048, 2048, 0, 0, None, None),
+        "bf16-d128-4x4-tiles": (True, 128, jnp.bfloat16, 512, 512, 0, 0, 128, 128),
+        "f32-d64-unequal-tiles": (True, 64, jnp.float32, 512, 512, 0, 0, 256, 128),
+        "f32-d64-not-a-tile-multiple": (True, 64, jnp.float32, 600, 600, 0, 0, 256, 256),
+        "bf16-d128-full-not-a-tile-multiple": (False, 128, jnp.bfloat16, 600, 600, 0, 0, 256, 256),
+        "f32-d64-full-rule-padded": (False, 64, jnp.float32, 1100, 1100, 0, 0, None, None),
+        "bf16-d64-shorter-than-a-tile": (True, 64, jnp.bfloat16, 72, 72, 0, 0, None, None),
+        "bf16-d128-full-sq-ne-sk": (False, 128, jnp.bfloat16, 256, 1024, 0, 0, 128, 256),
+        "f32-d64-q-offset-a-tile-multiple": (True, 64, jnp.float32, 256, 768, 512, 0, 128, 128),
+        "f32-d128-q-offset-no-tile-multiple": (True, 128, jnp.float32, 384, 1100, 560, 100, 128, 128),
+        "f32-d64-empty-softmax-rows": (True, 64, jnp.float32, 256, 768, 100, 300, 128, 128),
+        "bf16-d64-empty-softmax-rows-rule": (True, 64, jnp.bfloat16, 256, 768, 100, 300, None, None),
+        "f32-d64-whole-q-tiles-before-every-key": (True, 64, jnp.float32, 384, 384, 0, 256, 128, 128),
+    }
+
+    @pytest.mark.parametrize("case", list(FWD_CASES))
+    def test_forward_kernel_matches_reference_and_logsumexp(self, case):
+        causal, d, dtype, s_q, s_k, q_off, k_off, bq, bk = self.FWD_CASES[case]
+        ks = jax.random.split(jax.random.PRNGKey(5), 3)
+        q = jax.random.normal(ks[0], (1, 2, s_q, d), dtype)
+        k = jax.random.normal(ks[1], (1, 2, s_k, d), dtype)
+        v = jax.random.normal(ks[2], (1, 2, s_k, d), dtype)
+        out, lse = attention._flash_forward(
+            q, k, v, causal, d ** -0.5, q_off, k_off, bq, bk, True)
+        assert out.dtype == dtype and out.shape == q.shape
+        assert lse.shape == (2, 1, s_q) and lse.dtype == jnp.float32
+
+        q32, k32, v32 = (x.astype(jnp.float32) for x in (q, k, v))
+        want = mha_reference(q32, k32, v32, causal=causal, q_offset=q_off,
+                             k_offset=k_off)
+        scores = jnp.einsum("bhqd,bhkd->bhqk", q32, k32,
+                            precision="highest") * d ** -0.5
+        # a query before every key has an empty softmax: output 0 and
+        # lse NEG_INF, where the reference averages the values
+        live = (jnp.arange(s_q) + q_off >= k_off) | (not causal)
+        if causal:
+            seen = (jnp.arange(s_q)[:, None] + q_off
+                    >= jnp.arange(s_k)[None, :] + k_off)
+            scores = jnp.where(seen, scores, -jnp.inf)
+        want_lse = jax.nn.logsumexp(scores[:, :, live], axis=-1)
+        tol = 2e-5 if dtype == jnp.float32 else 2e-2
+        np.testing.assert_allclose(out.astype(jnp.float32)[:, :, live],
+                                   want[:, :, live], atol=tol, rtol=tol)
+        np.testing.assert_allclose(lse.reshape(1, 2, s_q)[:, :, live], want_lse,
+                                   atol=tol, rtol=tol)
+        assert not bool(jnp.any(out[:, :, ~live]))
+        assert bool(jnp.all(lse.reshape(1, 2, s_q)[:, :, ~live] == NEG_INF))
+        if "empty" in case or "before-every-key" in case:
+            assert int(jnp.sum(~live)) > 0
+
+    def test_forward_is_one_kernel_over_a_kv_grid_with_bf16_dots(self):
+        """The mechanism engages: the forward of the op is one Pallas call
+        whose grid has a KV axis, and with bf16 inputs every dot in it takes
+        bf16 operands (no q / k / v tile is upcast on its way to the MXU) and
+        accumulates in float32."""
+        q, k, v = _qkv(b=1, h=2, s=2048, d=128, dtype=jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention(q, k, v))(q, k, v)
+        calls, dots = [], []
+
+        def walk(j, inside):
+            for eqn in j.eqns:
+                if eqn.primitive.name == "pallas_call":
+                    calls.append(eqn)
+                    walk(eqn.params["jaxpr"], True)
+                    continue
+                if inside and eqn.primitive.name == "dot_general":
+                    dots.append(eqn)
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub, inside)
+
+        walk(jaxpr.jaxpr, False)
+        assert [c.params["name"] for c in calls] == ["flash_fwd"]
+        assert tuple(calls[0].params["grid_mapping"].grid) == (2, 2, 2)
+        assert len(dots) >= 2
+        for eqn in dots:
+            assert [x.aval.dtype for x in eqn.invars] == [jnp.bfloat16] * 2
+            assert eqn.outvars[0].aval.dtype == jnp.float32
+        # and nothing in VMEM scales with the sequence: every block and
+        # scratch buffer is a tile
+        kernel_avals = [x.aval for x in calls[0].params["jaxpr"].invars]
+        assert max(max(a.shape) for a in kernel_avals) == 1024
 
     def test_gradient_runs_pallas_kernels_and_no_loop(self):
         """The mechanism engages: the backward of the op is Pallas calls, and
