@@ -241,8 +241,7 @@ _d("collective_liveness_interval_s", float, 2.0,
    "recv keeps waiting (probes are sockets + KV reads; don't spam them)")
 _d("collective_pipeline", bool, True,
    "pipelined ring data path: fire-and-forget chunked sends overlapped "
-   "with recv+reduce; off = the legacy serial blocking-send ring "
-   "(kept for interleaved A/B benchmarking)")
+   "with recv+reduce; off = the serial blocking-send ring")
 _d("collective_chunk_bytes", int, 2 * 1024 * 1024,
    "wire chunk size for pipelined ring collectives; each ring step's "
    "payload is split into chunks this size so send, recv, and reduce "
@@ -262,12 +261,12 @@ _d("collective_hier_min_bytes", int, 64 * 1024,
    "payload size when ranks span multiple nodes; below it the flat ring's "
    "fewer hops win")
 _d("collective_virtual_nodes", int, 0,
-   "test/bench knob: partition ranks into this many synthetic nodes for "
+   "test knob: partition ranks into this many synthetic nodes for "
    "hierarchical topology (>0 overrides real node placement, so a "
    "single-host world can exercise the two-level path)")
 
 # --- Train: 3D-parallel dp gradient exchange (train/pipeline/dp_sync.py;
-# --- env re-read at DpGradSync construction so tests/benches can retune a
+# --- env re-read at DpGradSync construction so tests can retune a
 # --- trainer mid-process, but declared here for dump/propagation)
 _d("train_grad_bucket_bytes", int, 4 * 1024 * 1024,
    "size cap (fp32 bytes) for gradient allreduce buckets in dp-composed "
@@ -284,18 +283,6 @@ _d("train_dp_quorum", int, 0,
    "into the next step (sum/mean semantics preserved cumulatively); "
    "0 = full participation.  The stage-0 commit-frame scalar allreduce "
    "always runs full-participation so clip/loss stay replica-consistent")
-
-# --- Bench rig (_private/bench_rig.py; read via os.environ each call so
-# --- benches can toggle mid-process, but declared here for dump/propagation)
-_d("bench_rig", bool, True,
-   "pin bench workers to dedicated cores where the box allows it; "
-   "0 = unpinned fallback everywhere, rows stamped pinned=false")
-_d("bench_pin_cpus", str, "",
-   "comma-separated CPU pool bench-run workers pin themselves to at "
-   "startup (exported by bench.py; empty = no pinning)")
-_d("bench_serve_streams", int, 256,
-   "concurrent SSE streams the serve_load bench drives against the "
-   "2-replica llm_deployment")
 
 # --- Runtime environments ---
 _d("runtime_env_pip_no_index", bool, False,

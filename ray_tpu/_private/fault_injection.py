@@ -31,9 +31,9 @@ the same workload yields the same injection trace (``injection_trace()``,
 optionally appended to ``chaos_trace_file`` for cross-process assertions).
 
 Disabled (the default: empty schedule) the only cost at a call site is one
-module-attribute check (``if fault_injection.ENABLED``), keeping the A/B
-bench rows clean.  Schedules propagate to spawned workers/nodelets through
-the environment like every other config flag (config.overrides_as_env).
+module-attribute check (``if fault_injection.ENABLED``).  Schedules
+propagate to spawned workers/nodelets through the environment like every
+other config flag (config.overrides_as_env).
 """
 
 from __future__ import annotations

@@ -18,7 +18,7 @@ through them, and ``close()`` turns the stamps into a timeline —
 - the closed record is published to the GCS (``incident_report`` notify) so
   ``state.list_incidents()`` / ``ray_tpu incidents`` / the dashboard see a
   cluster-wide ledger, and kept in a local bounded ledger for in-process
-  consumers (the recovery bench reads its own rank's incident).
+  consumers (``list_local()``).
 
 Canonical phase order: detect -> quarantine -> rebuild -> restore ->
 resume.  Subsystems stamp the subset that exists in their recovery path.

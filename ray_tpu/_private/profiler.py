@@ -49,8 +49,8 @@ _samples_total = None  # lazily-registered Counter (sampler thread only)
 
 def resolve_hz() -> float:
     """Env-first: a live ``RAY_TPU_PROFILE_HZ`` beats the cached flag so
-    bench subprocesses (and operators flipping profiling on a running
-    job's children) control it without re-initing config."""
+    operators flipping profiling on a running job's children control it
+    without re-initing config."""
     raw = os.environ.get("RAY_TPU_PROFILE_HZ")
     if raw is not None:
         try:

@@ -1,22 +1,12 @@
-"""Serving-at-scale bench: SSE load harness + prefix/chunking A/Bs.
+"""SSE load generator for an `llm_deployment` behind the real HTTP proxy.
 
-Three rows for bench.py's ``serve_load`` section (gate
-``RAY_TPU_BENCH_SERVE=0``):
-
-* ``prefix_ab`` — in-process EngineCore A/B on a shared-system-prompt,
-  multi-turn mix (16 requests): prefilled-token reduction from the radix
-  prefix cache, with bit-identical outputs asserted against the cache-off
-  arm.
-* ``chunked_prefill_ab`` — one 4k-token prompt admitted while 8 streams
-  decode, chunked vs unchunked on the same interleaved schedule: max
-  observed ITL across the live streams, per arm.
-* ``sse_load`` — hundreds of concurrent SSE streams (default 256; env
-  ``RAY_TPU_BENCH_SERVE_STREAMS``) against a 2-replica `llm_deployment`
-  through the real HTTP proxy: TTFT/ITL percentiles, goodput (completed
-  tokens/s), shed count, half-stream count (must be 0), prefix-hit rate.
-
-The SSE part owns a serve app inside the caller's runtime; bench.py runs
-this module in a subprocess with its own ``ray_tpu.init``.
+:func:`run_sse_load` deploys a replicated engine inside the caller's
+runtime, opens ``num_streams`` concurrent server-sent-event streams against
+it (half extend a shared system prompt, half are unique; two tenants) and
+returns what a client saw: completed, shed and half-finished streams, TTFT
+and ITL percentiles on the host's clock, completed tokens per second and
+the engines' own prefix-hit counters.  ``tests/test_llm_prefix.py`` drives
+a tier-1-sized slice of it.
 """
 
 from __future__ import annotations
@@ -25,8 +15,6 @@ import asyncio
 import json
 import time
 from typing import Dict, List
-
-# ----------------------------------------------------------------- utils
 
 
 def _pct(values: List[float], q: float) -> float:
@@ -43,105 +31,6 @@ def _latency_row(values: List[float]) -> Dict[str, float]:
         "p95_ms": round(_pct(values, 0.95) * 1e3, 3),
         "p99_ms": round(_pct(values, 0.99) * 1e3, 3),
     }
-
-
-# ----------------------------------------------------- prefix caching A/B
-
-
-def _prefix_workload():
-    """Shared-system-prompt, multi-turn mix: 8 conversations whose first
-    turn is a 32-token system prompt (50% of the prompt) + a 32-token
-    unique user turn; each conversation then issues a follow-up that
-    resends the whole first exchange plus 16 new tokens — the radix-cache
-    sweet spot (16 requests total)."""
-    system = [7 + (i % 40) for i in range(32)]
-    turns = []
-    for c in range(8):
-        user = [60 + c * 3 + (i % 50) for i in range(32)]
-        turns.append(system + user)
-    return turns
-
-
-def _run_prefix_arm(enable: bool) -> Dict[str, object]:
-    from ray_tpu.llm import EngineCore
-
-    # sequential generate() staggers admissions naturally: each request
-    # completes (and populates the trie) before the next one admits
-    core = EngineCore(seed=0, num_pages=512, page_size=8,
-                      max_batch_tokens=128,
-                      engine_name="bench-prefix",
-                      enable_prefix_cache=enable)
-    first = [core.generate(p, {"max_tokens": 8}) for p in _prefix_workload()]
-    second = []
-    for conv, res in zip(_prefix_workload(), first):
-        followup = conv + res["tokens"] + [200 + (i % 30) for i in range(16)]
-        second.append(core.generate(followup, {"max_tokens": 8}))
-    core.cache.check_leaks()
-    return {
-        "outputs": [r["tokens"] for r in first + second],
-        "prefilled_tokens": core.scheduler.prefilled_tokens,
-        "prefix_hit_tokens": core.scheduler.prefix_hit_tokens,
-    }
-
-
-def run_prefix_ab() -> Dict[str, object]:
-    off = _run_prefix_arm(False)
-    on = _run_prefix_arm(True)
-    assert on["outputs"] == off["outputs"], \
-        "prefix cache changed sampled outputs"
-    ratio = off["prefilled_tokens"] / max(on["prefilled_tokens"], 1)
-    return {
-        "requests": 16,
-        "prefilled_tokens_off": off["prefilled_tokens"],
-        "prefilled_tokens_on": on["prefilled_tokens"],
-        "prefill_reduction_x": round(ratio, 2),
-        "prefix_hit_tokens": on["prefix_hit_tokens"],
-        "outputs_identical": True,
-    }
-
-
-# ---------------------------------------------------- chunked prefill A/B
-
-
-def _run_chunked_arm(chunk: int, long_len: int) -> Dict[str, float]:
-    from ray_tpu.llm import EngineCore
-    from ray_tpu.models.gpt2 import GPT2Config
-
-    cfg = GPT2Config(vocab_size=512, n_positions=long_len + 256,
-                     n_embd=64, n_layer=2, n_head=4)
-    core = EngineCore(cfg, seed=0, num_pages=(long_len + 512) // 16 + 64,
-                      page_size=16,
-                      max_batch_tokens=max(long_len + 64, 64),
-                      engine_name="bench-chunk",
-                      prefill_chunk_tokens=chunk)
-    rids = [core.submit([3 + i] * 8, {"max_tokens": 48})
-            for i in range(8)]
-    # let the 8 streams reach steady-state decode, then drop the long
-    # prompt into the running batch
-    for _ in range(6):
-        core.step()
-    long_rid = core.submit([5 + (i % 400) for i in range(long_len)],
-                           {"max_tokens": 4})
-    core.run_until_done(rids + [long_rid])
-    itls = [core.result(r)["max_itl"] for r in rids]
-    return {"max_itl_s": max(itls)}
-
-
-def run_chunked_ab(long_len: int = 4096) -> Dict[str, object]:
-    unchunked = _run_chunked_arm(0, long_len)
-    chunked = _run_chunked_arm(256, long_len)
-    return {
-        "long_prompt_tokens": long_len,
-        "decode_streams": 8,
-        "prefill_chunk_tokens": 256,
-        "max_itl_unchunked_ms": round(unchunked["max_itl_s"] * 1e3, 2),
-        "max_itl_chunked_ms": round(chunked["max_itl_s"] * 1e3, 2),
-        "itl_ratio": round(chunked["max_itl_s"]
-                           / max(unchunked["max_itl_s"], 1e-9), 3),
-    }
-
-
-# ------------------------------------------------------- SSE load harness
 
 
 async def _drive_stream(session, url: str, prompt: List[int], tenant: str,
@@ -219,7 +108,7 @@ def run_sse_load(num_streams: int = 256, num_replicas: int = 2,
 
     engine_kwargs = dict(num_pages=256, page_size=8, max_batch_tokens=256,
                          max_running=32, seed=0,
-                         engine_name="bench-serve",
+                         engine_name="sse-load",
                          enable_prefix_cache=True,
                          prefill_chunk_tokens=64)
     app = llm_deployment(engine_kwargs=engine_kwargs,
@@ -250,7 +139,7 @@ def run_sse_load(num_streams: int = 256, num_replicas: int = 2,
         view: Dict[str, float] = {}
         deadline = time.monotonic() + metrics_wait_s
         while time.monotonic() < deadline:
-            view = state.summarize_llm().get("bench-serve", {})
+            view = state.summarize_llm().get("sse-load", {})
             if view.get("requests", 0) >= len(completed):
                 break
             time.sleep(1.0)
@@ -270,17 +159,3 @@ def run_sse_load(num_streams: int = 256, num_replicas: int = 2,
         }
     finally:
         serve.delete("llm-load")
-
-
-# --------------------------------------------------------------- section
-
-
-def run_serve_load_bench() -> Dict[str, object]:
-    from ray_tpu._private.config import RayConfig
-
-    streams = RayConfig.bench_serve_streams
-    out: Dict[str, object] = {}
-    out["prefix_ab"] = run_prefix_ab()
-    out["chunked_prefill_ab"] = run_chunked_ab()
-    out["sse_load"] = run_sse_load(num_streams=streams)
-    return out

@@ -39,14 +39,9 @@ def main(argv=None):
     # rather than a bare MainThread parked on the shutdown event
     threading.current_thread().name = "worker-main-wait"
 
-    from ray_tpu._private import bench_rig
     from ray_tpu._private import worker as worker_mod
     from ray_tpu._private.core_worker import CoreWorker
     from ray_tpu._private.ids import NodeID, WorkerID
-
-    # Bench rig: when the driver exported a pin pool, take a core before
-    # any threads start (no-op outside rig runs / on 1-core boxes).
-    bench_rig.maybe_pin_from_env()
 
     core = CoreWorker(
         mode="worker",

@@ -1,10 +1,10 @@
-"""Sharded GPT pretraining step: the flagship multi-chip program.
+"""Sharded pretraining step: the program every cell of ``BENCHMARK.json`` runs.
 
-Everything BASELINE.json config #3 needs: build a (dp, fsdp, sp, tp) mesh,
-shard params by ``gpt_partition_rules``, and run a fused
-forward+backward+optimizer step under one jit.  XLA/GSPMD inserts the ICI
-collectives (grad reduce over dp/fsdp, weight all-gathers for tp/fsdp, ring
-ppermute for sp attention).
+Build a (dp, fsdp, sp, tp) mesh, shard params by ``gpt_partition_rules``, and
+run a fused forward+backward+optimizer step under one jit.  XLA/GSPMD inserts
+the ICI collectives (grad reduce over dp/fsdp, weight all-gathers for
+tp/fsdp, ring ppermute for sp attention).  ``ShardedPretrainer`` is what a
+train worker holds.
 """
 
 from __future__ import annotations

@@ -11,7 +11,7 @@ subsystem (rllib/podracer/):
   refs the moment each is sealed, weights travel through the versioned
   mailbox (one put per version, N runner gets), and a SIGKILLed runner is
   respawned mid-stream without stalling the survivors;
-- **relaunch (``async_stream=False``, kept for bench A/B)**: the PR-8-era
+- **relaunch (``async_stream=False``)**: the PR-8-era
   loop — one in-flight ``sample()`` per runner, relaunched per fragment —
   except weights now also come from the mailbox instead of riding every
   sample call as an argument;
@@ -204,7 +204,7 @@ class IMPALA(Algorithm):
         return self._result(len(frags), stats_list)
 
     def _relaunch_step(self) -> Dict[str, Any]:
-        """PR-8-era control flow, kept as the bench A/B baseline: consume
+        """PR-8-era control flow (``async_stream=False``): consume
         whatever finished (no barrier), update, relaunch the drained
         runners — one actor round trip per fragment."""
         import ray_tpu

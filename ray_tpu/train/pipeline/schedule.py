@@ -14,8 +14,8 @@ exact across stages: grad-norm partials ride the upstream grad frames, stage
 0 reduces them (and the microbatch losses) and broadcasts one commit frame
 downstream so every stage applies the identical clip scale.  Per-op wall
 clock is split into compute / transfer / wait buckets feeding
-``ray_tpu_pipeline_bubble_seconds`` and the overlap accounting bench.py
-reports on boxes that serialize the stages.
+``ray_tpu_pipeline_bubble_seconds`` and each step report's
+``overlap_fraction``.
 """
 
 from __future__ import annotations

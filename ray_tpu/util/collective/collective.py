@@ -13,8 +13,8 @@ Data path (the fast-collectives stack, ROADMAP item 3):
   riding the RPC layer's coalesced batch (`notify_coalesced_threadsafe`), so
   send, recv, and reduce overlap instead of alternating one blocking
   ``call_sync`` per hop.  A slice is forwarded the moment it is reduced —
-  the 2(N-1)-step allreduce streams.  ``collective_pipeline=False`` restores
-  the legacy serial blocking-send ring for interleaved A/B benchmarking.
+  the 2(N-1)-step allreduce streams.  ``collective_pipeline=False`` selects
+  the serial blocking-send ring (``tests/test_collective.py`` runs both).
   When sender and receiver share a node, bulk chunks ride a per-group
   shared-memory arena (``shm_channel.py``) and only a tiny descriptor
   crosses the RPC — the receiver reduces straight out of the mapped
@@ -187,7 +187,7 @@ class Group:
         self._op_qerr = 0.0
         # Incident bookkeeping: the op start the current failure interrupted
         # (backdates the detect phase) + the open incident + the last closed
-        # record (the recovery bench reads its per-phase timeline from here).
+        # record (``last_incident``: the per-phase timeline of this rank).
         self._op_started_at = 0.0
         self._incident: Optional[incidents.Incident] = None
         self.last_incident: Optional[dict] = None

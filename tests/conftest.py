@@ -1,8 +1,9 @@
 """Shared test config.
 
 Force JAX onto a virtual 8-device CPU platform (multi-chip sharding is tested on a
-host-device mesh; real TPU runs happen in bench.py, not pytest) — mirrors how the
-reference tests TPU scheduling on CPU by faking topology (reference:
+host-device mesh; real TPU runs are `perfbench/run.py` and `chip_smoke.py`
+through `chiprun`, not pytest) — mirrors how the reference tests TPU
+scheduling on CPU by faking topology (reference:
 python/ray/tests/accelerators/test_tpu.py).
 """
 

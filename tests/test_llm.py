@@ -458,19 +458,21 @@ def test_llm_serve_streaming_e2e(serve_instance):
     from ray_tpu.llm import EngineCore, llm_deployment
     from ray_tpu.util import state
 
-    # 14 pages x 4 slots = 56 token slots; 8 requests x ~17 tokens needs
-    # ~2.4x that, so admission overlaps AND preemption must trigger.  The
-    # per-step floor keeps the batch resident long enough that requests
-    # really overlap (the tiny model would otherwise finish each request
-    # faster than the next one arrives).
-    engine_kwargs = dict(num_pages=14, page_size=4, max_batch_tokens=128,
+    # One request ends at 5 + 32 = 37 tokens = 10 pages of 4 slots and the
+    # pool has 12: any two requests that overlap for a few steps cannot both
+    # finish, so preemption MUST trigger; it does not take three or four
+    # arrivals to land together.  The per-step floor makes a request live
+    # for ~1.6 s (the tiny model would otherwise finish each one before
+    # the next arrives), and all eight are submitted before any stream is
+    # awaited.
+    engine_kwargs = dict(num_pages=12, page_size=4, max_batch_tokens=128,
                          seed=0, engine_name="serve-e2e",
-                         step_delay_s=0.02)
+                         step_delay_s=0.05)
     app = llm_deployment(engine_kwargs=engine_kwargs)
     h = serve.run(app, name="llmapp", route_prefix="/llm")
     try:
         prompts = [[i + 1, i + 2, i + 3, i + 4, i + 5] for i in range(8)]
-        max_tokens = 12
+        max_tokens = 32
 
         # expected outputs: same weights (seed=0), ample cache, no serving
         ample = EngineCore(seed=0, num_pages=256, page_size=8,
@@ -478,9 +480,9 @@ def test_llm_serve_streaming_e2e(serve_instance):
         expected = [ample.generate(p, {"max_tokens": max_tokens})["tokens"]
                     for p in prompts]
 
-        streams = [h.remote({"prompt_ids": p, "max_tokens": max_tokens,
-                             "stream": True}).result(60)
-                   for p in prompts]
+        responses = [h.remote({"prompt_ids": p, "max_tokens": max_tokens,
+                               "stream": True}) for p in prompts]
+        streams = [r.result(60) for r in responses]
         results = [None] * len(streams)
         errors = []
 
@@ -516,8 +518,9 @@ def test_llm_serve_streaming_e2e(serve_instance):
         while time.monotonic() < deadline:
             view = state.summarize_llm().get("serve-e2e", {})
             if view.get("requests", 0) >= 8 and \
-                    view.get("tokens_per_second", 0) > 0:
-                break
+                    view.get("tokens_per_second", 0) > 0 and \
+                    view.get("generated_tokens", 0) >= 8 * max_tokens:
+                break  # the counters arrive push by push, not together
             time.sleep(0.5)
         assert view.get("requests", 0) >= 8, view
         assert view.get("ttft_p50_s", 0) > 0, view

@@ -453,10 +453,10 @@ def test_serve_shed_429_and_sse_error_never_hang(serve_instance):
 
 
 def test_sse_load_smoke_8_streams(serve_instance):
-    """Tier-1-sized slice of the serve_load bench harness: 8 concurrent
+    """Tier-1-sized slice of the SSE load generator: 8 concurrent
     SSE streams over 2 replicas through the real proxy — all complete,
     none half-delivered."""
-    from ray_tpu._private.serve_load_bench import run_sse_load
+    from ray_tpu._private.sse_load import run_sse_load
 
     out = run_sse_load(num_streams=8, num_replicas=2, max_tokens=6,
                        metrics_wait_s=0.0)
