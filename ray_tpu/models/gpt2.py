@@ -195,13 +195,54 @@ class GPT2LMModel(nn.Module):
         return mask_vocab_padding(logits, cfg.vocab_size)
 
 
-def lm_loss(logits, targets, mask=None):
-    """Mean next-token cross entropy in f32."""
+def _is_target(logits, targets):
+    """Where each row's target column is, as booleans of the logits' shape."""
+    col = jax.lax.broadcasted_iota(jnp.int32, logits.shape, logits.ndim - 1)
+    return col == targets[..., None]
+
+
+@jax.custom_vjp
+def _token_nll(logits, targets):
+    """``-log_softmax(logits)[target]`` per row, float32, from logits in any
+    float dtype.  Written for the memory system: the logits are read in the
+    dtype they arrive in and upcast inside the reductions, and nothing of the
+    logits' shape is written in float32, here or for the backward."""
+    return _token_nll_fwd(logits, targets)[0]
+
+
+def _token_nll_fwd(logits, targets):
     with jax.named_scope("lm_loss"):
-        logits = logits.astype(jnp.float32)
-        logp = jax.nn.log_softmax(logits, axis=-1)
-        ll = jnp.take_along_axis(logp, targets[..., None], axis=-1)[..., 0]
+        x = logits.astype(jnp.float32)
+        top = jnp.max(x, axis=-1)
+        # the target's logit by a select in the pass that sums the
+        # exponentials: no gather, and nothing to fetch across ``tp`` shards
+        # of the vocabulary
+        hit = jnp.sum(jnp.where(_is_target(x, targets), x, 0.0), axis=-1)
+        lse = top + jnp.log(jnp.sum(jnp.exp(x - top[..., None]), axis=-1))
+        return lse - hit, (logits, lse, targets)
+
+
+def _token_nll_bwd(res, g):
+    logits, lse, targets = res
+    # a custom rule's backward is outside the scope its forward was traced in
+    with jax.named_scope("lm_loss"):
+        x = logits.astype(jnp.float32)
+        p = jnp.exp(x - lse[..., None])
+        d = (p - _is_target(x, targets)) * g[..., None]
+        # rounded once, to the logits' dtype: where the cotangent of
+        # ``logits.astype(float32)`` was rounded before
+        return d.astype(logits.dtype), None
+
+
+_token_nll.defvjp(_token_nll_fwd, _token_nll_bwd)
+
+
+def lm_loss(logits, targets, mask=None):
+    """Mean next-token cross entropy in f32 (max, sum, log and mean), over
+    the rows ``mask`` keeps; 0 where it keeps none."""
+    nll = _token_nll(logits, targets)
+    with jax.named_scope("lm_loss"):
         if mask is None:
-            return -jnp.mean(ll)
+            return jnp.mean(nll)
         mask = mask.astype(jnp.float32)
-        return -jnp.sum(ll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+        return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)

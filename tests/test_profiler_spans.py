@@ -255,6 +255,27 @@ def test_the_step_carries_its_scopes_in_op_name(toy_step):
     assert not any("optimizer" in n or "/flash_bwd/" in n for n in bare_names)
 
 
+def test_the_loss_rules_carry_lm_loss_forward_and_backward(toy_step):
+    """``lm_loss``'s cross entropy is a ``custom_vjp``: its backward rule is
+    traced outside the scope its forward ran in and opens the scope itself,
+    or its pass over the logits would read as unscoped device time."""
+    _, scoped, bare = toy_step
+    op_names = set(re.findall(r'op_name="([^"]+)"', scoped))
+    forward = {n for n in op_names if re.search(r"/jvp\(lm_loss\)/", n)
+               and "transpose(" not in n}
+    backward = {n for n in op_names
+                if re.search(r"/transpose\(jvp\(lm_loss\)\)/", n)}
+    # the two reductions of the forward, the softmax of the backward
+    assert any(n.endswith("/reduce_max") for n in forward), forward
+    assert any(n.endswith("/exp") for n in forward), forward
+    assert any(n.endswith("/exp") for n in backward), backward
+    # and no exponential of the step outside a module lacks the scope
+    assert not [n for n in op_names if n.endswith("/exp")
+                and not re.search(r"/(h_\d+)/|[/(]lm_loss[/)]", n)]
+    assert not any("lm_loss" in n
+                   for n in re.findall(r'op_name="([^"]+)"', bare))
+
+
 def test_scopes_change_metadata_and_nothing_else(toy_step):
     _, scoped, bare = toy_step
     assert scoped != bare
