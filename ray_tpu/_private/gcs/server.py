@@ -782,6 +782,23 @@ class GcsServer:
                 best, best_score = info, score
         return best
 
+    def _hint_lease_reclaim(self, resources: Dict[str, float]) -> None:
+        """No node has ``resources`` free: the nodes that have them in total
+        may be holding them for clients' cached idle leases.  The nodelet
+        hints its clients only when a request queues on it, and an actor
+        that waits here never reaches it — so ask (fire and forget; the
+        nodelet throttles)."""
+        async def hint(conn):
+            try:
+                await conn.notify("hint_lease_reclaim", None)
+            except (ConnectionError, asyncio.TimeoutError):
+                pass
+
+        for info in self.nodes.values():
+            if info.alive and all(info.resources_total.get(k, 0.0) >= v
+                                  for k, v in resources.items() if v > 0):
+                asyncio.get_event_loop().create_task(hint(info.conn))
+
     async def rpc_create_actor(self, conn, msg):
         import pickle
 
@@ -873,6 +890,8 @@ class GcsServer:
                     return
             if info.state not in ("PENDING_CREATION", "RESTARTING"):
                 return  # killed / job-reclaimed while we were waiting
+            if target is None:
+                self._hint_lease_reclaim(spec.resources)
             await asyncio.sleep(delay)
             delay = min(delay * 1.5, 2.0)
 

@@ -1615,6 +1615,14 @@ class Nodelet:
                 except ConnectionError:
                     pass
 
+    async def rpc_hint_lease_reclaim(self, conn, msg):
+        """GCS: an actor is pending behind resources that this node has in
+        total but not free — they may be held by clients' cached idle
+        leases, and a request that never reaches this node queues nothing
+        here that would send the hint."""
+        self._hint_lease_reclaim()
+        return True
+
     def _broadcast_extent_reclaim(self) -> None:
         """Store hit full during an extent lease: ask clients to hand back
         idle leased extents before the requester's retry."""

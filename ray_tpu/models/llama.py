@@ -3,13 +3,23 @@
 Second model family beside GPT-2 (models/gpt2.py), covering the modern
 pretraining recipe: rotary position embeddings (no learned positions),
 pre-RMSNorm blocks, SwiGLU MLPs, grouped-query attention (n_kv_heads <
-n_heads), untied LM head.  One block serves dense and sparse models: a
-layer's feed-forward is the dense ``SwiGLU`` (``mlp``) or, in every
-``moe_every``-th layer, the dropless routed experts of ``models/moe.py``
+n_heads), untied LM head by default.  One block serves dense, sparse and
+hybrid models: a layer's feed-forward is the dense ``SwiGLU`` (``mlp``) or,
+in every ``moe_every``-th layer, the dropless routed experts of
+``models/moe.py``
 (``moe``: ``n_experts`` SwiGLU experts of width ``d_expert``,
 ``moe_top_k`` per token), and ``qk_norm`` puts an RMSNorm over the whole
 query and key projections before the heads are split and rotated
-(OLMoE-1B-7B is this block with both).  Same TPU discipline as the GPT stack —
+(OLMoE-1B-7B is this block with both).  A layer's token mixer is a kind
+too: ``layer_types`` names each layer ``"attention"`` (``attn``) or
+``"mamba"`` (``mamba``: the Mamba-2 mixer of ``models/mamba.py`` over the
+chunked scan of ``ops/ssd.py``); attention may go without RoPE (``rope``)
+and take a score scale of its own (``attn_scale``); the embedding, each
+branch before its residual add and the logits take constant multipliers;
+and ``tie_embeddings`` makes the head the embedding table itself, its matmul
+still under the name path ``lm_head`` (Granite 4.0-H is this block with all
+of these).  Every such field at its default leaves the program the dense
+Llama it was.  Same TPU discipline as the GPT stack —
 bfloat16 activations, fused QKV-free layout matched to
 ``llama_partition_rules`` so tp/fsdp shardings apply by regex, attention
 via the Pallas flash kernel (``ray_tpu.ops.flash_attention``) or ring
@@ -21,13 +31,14 @@ libs; the in-repo flagship models are this framework's own).
 from __future__ import annotations
 
 from dataclasses import dataclass
-from typing import Any
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.gpt2 import mask_vocab_padding, padded_vocab
+from ray_tpu.models.mamba import Mamba2Mixer
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
 from ray_tpu.ops.attention import (flash_attention, mha_reference,
                                    ring_attention_sharded)
@@ -60,6 +71,21 @@ class LlamaConfig:
     norm_topk_prob: bool = False     # renormalise the chosen probabilities
     router_aux_weight: float = 0.01  # x load-balancing loss, in the objective
     router_z_weight: float = 1e-3    # x router z-loss
+    # each layer's token mixer, "attention" or "mamba" (models/mamba.py), one
+    # entry a layer; empty: attention in every layer
+    layer_types: Tuple[str, ...] = ()
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0           # N: a head's state is d_head x d_state
+    mamba_n_groups: int = 1          # groups of heads that share B and C
+    mamba_d_conv: int = 4
+    mamba_chunk: int = 256
+    rope: bool = True                # False: no position encoding (NoPE)
+    attn_scale: Optional[float] = None   # x the scores; None: 1/sqrt(head)
+    embedding_multiplier: float = 1.0    # x the embedding
+    residual_multiplier: float = 1.0     # x each branch, before its residual add
+    logits_scaling: float = 1.0          # the logits are divided by it
+    tie_embeddings: bool = False         # the head is the embedding table
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -108,10 +134,11 @@ class LlamaAttention(nn.Module):
         q = q.reshape(B, S, H, D).transpose(0, 2, 1, 3)
         k = k.reshape(B, S, KV, D).transpose(0, 2, 1, 3)
         v = v.reshape(B, S, KV, D).transpose(0, 2, 1, 3)
-        with jax.named_scope("rope"):
-            cos, sin = rope_frequencies(D, positions, cfg.rope_theta)
-            q = apply_rope(q, cos, sin)
-            k = apply_rope(k, cos, sin)
+        if cfg.rope:
+            with jax.named_scope("rope"):
+                cos, sin = rope_frequencies(D, positions, cfg.rope_theta)
+                q = apply_rope(q, cos, sin)
+                k = apply_rope(k, cos, sin)
         if KV != H:  # GQA: each kv head serves H/KV query heads
             rep = H // KV
             with jax.named_scope("kv_repeat"):
@@ -119,11 +146,13 @@ class LlamaAttention(nn.Module):
                 v = jnp.repeat(v, rep, axis=1)
         if cfg.attention_impl == "ring":
             out = ring_attention_sharded(q, k, v, causal=True,
+                                         sm_scale=cfg.attn_scale,
                                          seq_axis=cfg.ring_axis)
         elif cfg.attention_impl == "reference":
-            out = mha_reference(q, k, v, causal=True)
+            out = mha_reference(q, k, v, causal=True, sm_scale=cfg.attn_scale)
         else:
-            out = flash_attention(q, k, v, causal=True)
+            out = flash_attention(q, k, v, causal=True,
+                                  sm_scale=cfg.attn_scale)
         out = out.transpose(0, 2, 1, 3).reshape(B, S, H * D)
         return nn.Dense(E, use_bias=False, dtype=cfg.dtype, name="wo")(out)
 
@@ -145,22 +174,35 @@ class SwiGLU(nn.Module):
 class LlamaBlock(nn.Module):
     config: LlamaConfig
     routed: bool = False    # this layer's feed-forward: routed experts
+    mixer: str = "attention"    # this layer's token mixer, or "mamba"
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.config
-        x = x + LlamaAttention(cfg, name="attn")(
-            nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
-                       name="attn_norm")(x), positions)
+
+        def add(x, branch):
+            if cfg.residual_multiplier != 1.0:
+                branch = branch * cfg.residual_multiplier
+            return x + branch
+
+        y = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
+                       name="attn_norm")(x)
+        if self.mixer == "mamba":
+            x = add(x, Mamba2Mixer(cfg, name="mamba")(y))
+        elif self.mixer == "attention":
+            x = add(x, LlamaAttention(cfg, name="attn")(y, positions))
+        else:
+            raise ValueError(f"unknown layer type {self.mixer!r} (expected "
+                             "'attention' or 'mamba')")
         y = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
                        name="mlp_norm")(x)
         if self.routed:
-            return x + RoutedSwiGLU(RoutedConfig(
+            return add(x, RoutedSwiGLU(RoutedConfig(
                 n_experts=cfg.n_experts, top_k=cfg.moe_top_k,
                 d_model=cfg.d_model, d_ff=cfg.d_expert,
                 norm_topk_prob=cfg.norm_topk_prob, dtype=cfg.dtype),
-                name="moe")(y)
-        return x + SwiGLU(cfg, name="mlp")(y)
+                name="moe")(y))
+        return add(x, SwiGLU(cfg, name="mlp")(y))
 
 
 class LlamaLMModel(nn.Module):
@@ -170,9 +212,15 @@ class LlamaLMModel(nn.Module):
     def __call__(self, input_ids, *, deterministic: bool = True):
         cfg = self.config
         B, S = input_ids.shape
-        x = constrain_residual(
-            nn.Embed(padded_vocab(cfg.vocab_size), cfg.d_model,
-                     dtype=cfg.dtype, name="wte")(input_ids))
+        if cfg.layer_types and len(cfg.layer_types) != cfg.n_layer:
+            raise ValueError(f"layer_types names {len(cfg.layer_types)} "
+                             f"layers, n_layer is {cfg.n_layer}")
+        wte = nn.Embed(padded_vocab(cfg.vocab_size), cfg.d_model,
+                       dtype=cfg.dtype, name="wte")
+        x = wte(input_ids)
+        if cfg.embedding_multiplier != 1.0:
+            x = x * cfg.embedding_multiplier
+        x = constrain_residual(x)
         positions = jnp.arange(S)
         if cfg.remat:
             policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
@@ -182,11 +230,23 @@ class LlamaLMModel(nn.Module):
             block_cls = LlamaBlock
         for i in range(cfg.n_layer):
             routed = cfg.moe_every > 0 and i % cfg.moe_every == cfg.moe_every - 1
+            mixer = cfg.layer_types[i] if cfg.layer_types else "attention"
             x = constrain_residual(
-                block_cls(cfg, routed, name=f"h_{i}")(x, positions))
+                block_cls(cfg, routed, mixer, name=f"h_{i}")(x, positions))
         x = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype, name="norm_f")(x)
-        logits = nn.Dense(padded_vocab(cfg.vocab_size), use_bias=False,
-                          dtype=cfg.dtype, name="lm_head")(x)
+        if cfg.logits_scaling != 1.0:
+            # on the narrow side of the head's matmul: the logits stay bf16
+            x = x / cfg.logits_scaling
+        if cfg.tie_embeddings:
+            # the head's matmul against the embedding table itself, under the
+            # name path an untied head has; the table's gradient is the sum
+            # of the gather's and this matmul's
+            with jax.named_scope("lm_head"):
+                logits = jnp.einsum("bsd,vd->bsv", x,
+                                    wte.embedding.astype(cfg.dtype))
+        else:
+            logits = nn.Dense(padded_vocab(cfg.vocab_size), use_bias=False,
+                              dtype=cfg.dtype, name="lm_head")(x)
         return mask_vocab_padding(logits, cfg.vocab_size)
 
 

@@ -1,0 +1,106 @@
+"""The Mamba-2 mixer (Dao and Gu 2024) as the published hybrid models run it
+(``GraniteMoeHybridMambaLayer``): a layer's token mixer in place of attention,
+inside ``models/llama.py``'s block.  With ``n`` the block's normed input:
+
+    z | xBC | dt = W_in n
+    xBC = silu(causal depthwise conv1d(xBC, width d_conv) + b)    (no other bias)
+    X | B | C = xBC                      X: heads x d_head; B, C: groups x d_state
+    dt = softplus(dt + dt_bias);  a_t = exp(dt_t * A),  A = -exp(A_log)
+    h_t = a_t h_{t-1} + dt_t X_t B_t^T;  y_t = h_t C_t + D X_t
+    out = W_out RMSNorm_w(y * silu(z))   the norm over all heads, after the gate
+
+The recurrence is ``ops/ssd.py``'s chunked scan.  The ``jax.named_scope``s
+``conv``, ``ssd`` and ``gated_norm`` and the ``Dense`` children ``in_proj``
+and ``out_proj`` are what the benchmark's per-layer metrics read.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.ops.ssd import ssd_scan
+
+
+def causal_conv(x, kernel, bias):
+    """Depthwise causal convolution over the sequence, as shifted
+    multiply-adds: ``y_t = bias + sum_k kernel[k] * x_{t - (width-1) + k}``,
+    positions before the sequence's start reading zero.  ``x``: (B, S, C);
+    ``kernel``: (width, C)."""
+    width, seq = kernel.shape[0], x.shape[1]
+    padded = jnp.pad(x, ((0, 0), (width - 1, 0), (0, 0)))
+    return bias + sum(padded[:, k:k + seq] * kernel[k] for k in range(width))
+
+
+def _dt_bias_init(key, shape, dtype=jnp.float32):
+    """The published Mamba-2 convention: ``softplus(dt_bias)`` log-uniform in
+    [0.001, 0.1], with a floor of 1e-4."""
+    low, high = math.log(1e-3), math.log(0.1)
+    dt = jnp.maximum(jnp.exp(jax.random.uniform(key, shape, dtype, low, high)),
+                     1e-4)
+    return dt + jnp.log(-jnp.expm1(-dt))    # softplus's inverse
+
+
+def _conv_init(width: int):
+    """PyTorch's ``Conv1d`` default for a depthwise kernel and its bias:
+    uniform in +-1/sqrt(width)."""
+    bound = 1.0 / math.sqrt(width)
+
+    def init(key, shape, dtype=jnp.float32):
+        return jax.random.uniform(key, shape, dtype, -bound, bound)
+    return init
+
+
+def _a_log_init(key, shape, dtype=jnp.float32):
+    """``A = -exp(A_log)`` with ``-A`` uniform in [1, 16]."""
+    return jnp.log(jax.random.uniform(key, shape, dtype, 1.0, 16.0))
+
+
+class Mamba2Mixer(nn.Module):
+    config: Any     # LlamaConfig: d_model, dtype, rms_eps and the mamba_* sizes
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        heads, p = cfg.mamba_n_heads, cfg.mamba_d_head
+        groups, n = cfg.mamba_n_groups, cfg.mamba_d_state
+        inner, bc = heads * p, groups * n
+        batch, seq, _ = x.shape
+        z, xbc, dt = jnp.split(
+            nn.Dense(2 * inner + 2 * bc + heads, use_bias=False,
+                     dtype=cfg.dtype, name="in_proj")(x),
+            [inner, 2 * inner + 2 * bc], axis=-1)
+        conv_init = _conv_init(cfg.mamba_d_conv)
+        kernel = self.param("conv_kernel", conv_init,
+                            (cfg.mamba_d_conv, inner + 2 * bc))
+        bias = self.param("conv_bias", conv_init, (inner + 2 * bc,))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (heads,))
+        a_log = self.param("A_log", _a_log_init, (heads,))
+        skip = self.param("D", nn.initializers.ones, (heads,))
+        with jax.named_scope("conv"):
+            xbc = jax.nn.silu(causal_conv(xbc, kernel.astype(cfg.dtype),
+                                          bias.astype(cfg.dtype)))
+        xs, b, c = jnp.split(xbc, [inner, inner + bc], axis=-1)
+        xs = xs.reshape(batch, seq, heads, p)
+        with jax.named_scope("ssd"):
+            # the step sizes and the decay rates: float32 from here on
+            y = ssd_scan(xs, jax.nn.softplus(dt.astype(jnp.float32) + dt_bias),
+                         -jnp.exp(a_log.astype(jnp.float32)),
+                         b.reshape(batch, seq, groups, n),
+                         c.reshape(batch, seq, groups, n),
+                         chunk=cfg.mamba_chunk)
+            y = y + xs * skip.astype(cfg.dtype)[:, None]
+        scale = self.param("norm_scale", nn.initializers.ones, (inner,))
+        with jax.named_scope("gated_norm"):
+            # float32 inside, as nn.RMSNorm; the gate before the norm
+            g = y.reshape(batch, seq, inner).astype(jnp.float32) \
+                * jax.nn.silu(z.astype(jnp.float32))
+            g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                                  + cfg.rms_eps)
+            y = (g * scale).astype(cfg.dtype)
+        return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                        name="out_proj")(y)

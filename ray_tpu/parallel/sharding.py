@@ -80,7 +80,14 @@ def llama_partition_rules() -> PartitionRules:
         (r"moe/router/kernel", _spec()),
         (r"moe/(gate_proj|up_proj)", _spec("ep", "fsdp", "tp")),
         (r"moe/down_proj", _spec("ep", "tp", "fsdp")),
-        # attn_norm, mlp_norm, norm_f and the q_norm / k_norm scales
+        # mamba layers (models/mamba.py::Mamba2Mixer as h_<n>/mamba): the two
+        # projections as the attention's; the convolution's kernel and bias
+        # and the per-head dt_bias, A_log and D are small and replicated
+        (r"mamba/in_proj/kernel", _spec("fsdp", "tp")),
+        (r"mamba/out_proj/kernel", _spec("tp", "fsdp")),
+        (r"mamba/(conv_kernel|conv_bias|dt_bias|A_log|D)$", _spec()),
+        # attn_norm, mlp_norm, norm_f, the q_norm / k_norm scales and the
+        # mixer's norm_scale
         (r"norm|scale", _spec()),
         (r"lm_head/kernel", _spec("fsdp", "tp")),
     ])

@@ -11,7 +11,10 @@ OLMoE-1B-7B at published widths (2048 wide, 64 experts of 1024, top-8, vocab
 50304, seq 4096, remat; depth cut to the benchmark's one layer): the grouped
 matmul's Mosaic kernels meet the compiler here, and the full compile's memory
 analysis for 1, 2 and 4 rows a step is where ``olmoe-s4k-1chip``'s
-``rows_per_step`` was decided.
+``rows_per_step`` was decided.  And the one-chip step of Granite-4.0-H-Micro
+(2048 wide, five Mamba-2 layers of 64 heads x 64 with a state of 128 and one
+grouped-query attention layer, vocab 100,352 tied, seq 8192, remat): the
+plain-XLA scan's chunk tensors have to fit beside 7.8 GB of state.
 
 Every case runs in a subprocess (this file, as a script): the libtpu client
 must never meet the forced-CPU test process, and the child must NOT inherit
@@ -42,12 +45,13 @@ CASES = {
 }
 
 
-def _olmoe_cell():
-    """(model configuration, seq) of the benchmark's ``olmoe-s4k-1chip``:
-    OLMoE-1B-7B-0125 at published widths, one layer."""
+def _cell(name):
+    """(model configuration, seq) of a one-chip cell of the benchmark:
+    ``olmoe-s4k-1chip`` is OLMoE-1B-7B-0125 at published widths, one layer;
+    ``granite-h-s8k-1chip`` Granite-4.0-H-Micro's first six layers."""
     from perfbench.harness import families, manifest
 
-    cell = manifest.cell("olmoe-s4k-1chip")
+    cell = manifest.cell(name)
     return (families.of(cell.config).model_config(cell.config, 1),
             cell.traffic["seq"])
 
@@ -69,8 +73,14 @@ def _build(case: str, compile_: bool) -> dict:
         chips_per_host_bounds=[2, 2, 1], num_slices=1)
     if case.startswith("olmoe_b"):
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
-        (config, seq), rows = _olmoe_cell(), int(case[len("olmoe_b"):])
+        (config, seq), rows = _cell("olmoe-s4k-1chip"), \
+            int(case[len("olmoe_b"):])
         assert config.n_experts == 64 and config.d_model == 2048
+    elif case == "granite":
+        mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+        (config, seq), rows = _cell("granite-h-s8k-1chip"), 1
+        assert config.layer_types == ("mamba",) * 5 + ("attention",)
+        assert config.mamba_n_heads == 64 and config.mamba_chunk == 256
     else:
         mesh_kwargs, n_devices = CASES[case]
         mesh = build_mesh(MeshConfig(**mesh_kwargs),
@@ -164,6 +174,27 @@ def test_olmoe_step_compiles_and_says_how_many_rows_fit():
         # backward calls each
         assert row["tpu_custom_calls"] == 4 + 12, row
         assert row["argument_bytes"] + row["temp_bytes"] < 14e9, row
+
+
+def test_granite_step_lowers_for_one_v5e_chip():
+    """Tier-1: the one-chip Granite-4.0-H-Micro step at published widths
+    (five Mamba-2 layers and one attention layer, seq 8192 x 1 row) lowers
+    for the TPU with the flash kernel's Mosaic calls in it."""
+    row = _child(["granite"], compile_=False)["granite"]
+    assert row["lowered_has_mosaic"]
+
+
+@pytest.mark.slow
+def test_granite_step_compiles_and_fits_the_chip():
+    """The TPU compiler takes the plain-XLA scan at 64 heads x 32 chunks of
+    256, and its memory analysis says the step fits (PR 29: 7.77 GB of
+    arguments + 3.98 GB of temporaries)."""
+    row = _child(["granite"], compile_=True)["granite"]
+    assert "refused" not in row, row
+    # the one attention layer: flash forward, its recomputation, and the
+    # backward's two kernels
+    assert row["tpu_custom_calls"] == 4, row
+    assert row["argument_bytes"] + row["temp_bytes"] < 14e9, row
 
 
 if __name__ == "__main__":
