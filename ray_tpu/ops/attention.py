@@ -3,16 +3,17 @@
 Greenfield relative to the reference — it has no sequence parallelism anywhere
 (SURVEY §5.7; no ring/blockwise attention hits in the reference tree).  Design:
 
-- ``flash_attention``: online-softmax blockwise attention as Pallas (Mosaic)
-  kernels, forward and backward: grids over (batch*heads, q blocks, k blocks)
-  with the k (or q) axis the reduction, K / V streamed from HBM tile by tile
-  through their BlockSpecs, the running (m, l, acc) of the flash recurrence in
-  VMEM scratch.  Operands go to the MXU in the inputs' dtype, accumulation is
-  float32, no tile above the causal diagonal is fetched or computed.  The
-  backward (dK/dV, then dQ) recomputes P tile by tile from the saved
-  logsumexp, so memory stays O(S·d) rather than O(S²), nothing in VMEM scales
-  with the sequence, and nothing but the output, one float32 per query and
-  the gradients is written to HBM.
+- ``flash_attention``: online-softmax blockwise attention as two Pallas
+  (Mosaic) kernels, one forward and one backward: grids over (batch*heads,
+  q blocks, k blocks) — the backward's with the q axis innermost — K / V (or
+  Q / dO) streamed from HBM tile by tile through their BlockSpecs, the
+  running (m, l, acc) of the flash recurrence in VMEM scratch.  Operands go
+  to the MXU in the inputs' dtype, accumulation is float32, no tile above the
+  causal diagonal is fetched or computed.  The backward recomputes P and dS
+  once a tile from the saved logsumexp and takes dV, dK and dQ from them, so
+  memory stays O(S·d) rather than O(S²); the one thing in VMEM that scales
+  with the sequence is dQ's float32 accumulator (S·d), and nothing but the
+  output, one float32 per query and the gradients is written to HBM.
 - ``ring_attention``: shard_map over the ``sp`` mesh axis; each step computes
   blockwise attention of the local Q shard against the resident KV shard, then
   rotates KV around the ring with ``jax.lax.ppermute`` (ICI neighbor traffic),
@@ -78,8 +79,8 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = N
 
 
 # ========================================================== pallas kernels
-# Flash attention as three Mosaic kernels over (block_q, block_k) tiles of the
-# score square: the forward, and the backward as dK/dV and dQ.  MXU operands
+# Flash attention as two Mosaic kernels over (block_q, block_k) tiles of the
+# score square: the forward, and one backward for dK, dV and dQ.  MXU operands
 # stay in the inputs' dtype, every dot accumulates in float32, and sm_scale
 # meets S after its dot (and dQ / dK once, as the accumulator is written out).
 #
@@ -88,17 +89,18 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = N
 #   accumulator of the online softmax live in VMEM scratch, the statistics as
 #   lane-replicated columns; on the last k block the output is normalised and
 #   the logsumexp written as a (1, block_q) row, the layout the backward reads.
-# - ``flash_bwd_dkv``: grid (b*h, k blocks, q blocks).  Recomputes
+# - ``flash_bwd``: grid (b*h, k blocks, q blocks).  Recomputes
 #   P = exp(S - lse) on the transposed tile S^T (block_k, block_q), so
 #   dV += P^T dO and dK += dS^T Q (dS = P * (dP - delta)) are plain matmuls and
-#   lse / delta are (1, block_q) rows that broadcast down sublanes.
-# - ``flash_bwd_dq``: grid (b*h, q blocks, k blocks).  Works on S, dQ += dS K;
-#   lse / delta arrive as the same rows and are turned into lane-replicated
-#   columns once per q block.
+#   lse / delta are (1, block_q) rows that broadcast down sublanes; the same
+#   dS^T, contracted over its keys, gives dQ[q tile] += dS K: five matmuls and
+#   one mask / exp / dS pass a tile.  dK / dV reduce over the inner axis; dQ
+#   reduces over the outer one, into a float32 accumulator that holds one
+#   b*h's whole sequence and is written out once, on that b*h's last step.
 #
-# The last grid axis is the reduction ("arbitrary"): the accumulators are reset
-# on its first step and written out on its last, and K / V (or Q / dO) arrive
-# tile by tile through their BlockSpecs, so nothing in VMEM scales with the
+# The forward's last grid axis is its reduction ("arbitrary"): the accumulators
+# are reset on its first step and written out on its last, and K / V arrive
+# tile by tile through their BlockSpecs, so nothing there scales with the
 # sequence.  Under a causal mask a tile wholly above the diagonal does no work,
 # and the index maps clamp its block index to the nearest live tile, so nothing
 # is fetched for it either (``_Tiles.tile_of``).  Tiles that the diagonal or
@@ -107,6 +109,7 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = N
 # a function of the tile's indices alone.
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
+_TN = (((0,), (0,)), ((), ()))  # a.T @ b
 
 
 def _dot(a, b, dims):
@@ -119,8 +122,8 @@ def _round_up(x: int, m: int) -> int:
 
 # Queries of a diagonal tile are taken this many at a time, each chunk against
 # only the keys up to its last query: at 128, 10/16 of a 512 tile's square and
-# 36/64 of a 1024 one.  By the chip's clock (PERF.md, PR 24 and PR 26): 128 for
-# the backward, 256 for the forward, where a chunk's fixed cost weighs more
+# 36/64 of a 1024 one.  By the chip's clock (PERF.md, PRs 24, 26 and 30): 128
+# for the backward, 256 for the forward, where a chunk's fixed cost weighs more
 # than the keys it spares.
 _FWD_DIAG_CHUNK = 2 * LANES
 _BWD_DIAG_CHUNK = LANES
@@ -144,7 +147,7 @@ def _block(s: int, d: int, dtype, block: Optional[int] = None) -> int:
 
 
 class _Tiles(NamedTuple):
-    """How one call's score square is cut: shared by the three kernels."""
+    """How one call's score square is cut: shared by both kernels."""
     causal: bool
     offset: int      # q_offset - k_offset: query r sees key c iff r + offset >= c
     block_q: int
@@ -363,10 +366,31 @@ def _bwd_p(s, lse, q_dim: int, thresh, k_limit):
     return lax.exp(lax.sub(_masked(s, q_dim, thresh, k_limit), lse))
 
 
-def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                          dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale: float,
-                          t: _Tiles):
+# What Mosaic gives a kernel's blocks, scratch and temporaries unless told
+# otherwise; a tile's share of the backward fits in it at every tile edge
+# ``_block`` picks, as it did before dQ had to span the sequence.
+_MOSAIC_SCOPE_BYTES = 16 << 20
+
+
+def _bwd_vmem_bytes(s_q_pad: int, d: int, dtype) -> int:
+    """The backward kernel's VMEM limit: the default scope for what belongs to
+    a tile, and beside it what spans the sequence — dQ's float32 accumulator
+    and its output block, which the pipeline holds twice.  (A block's last dim
+    fills whole lanes.)  Past the chip's VMEM — 128 MiB on the v5e, about
+    118,000 queries at d <= 128 in bf16 — the compiler refuses the call."""
+    return _MOSAIC_SCOPE_BYTES + s_q_pad * _round_up(d, LANES) * (
+        4 + 2 * jnp.dtype(dtype).itemsize)
+
+
+def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
+                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
+                      *, sm_scale: float, t: _Tiles):
     ik, iq = pl.program_id(1), pl.program_id(2)
+    last_k, last_q = pl.num_programs(1) - 1, pl.num_programs(2) - 1
+
+    @pl.when(jnp.logical_and(ik == 0, iq == 0))
+    def _():
+        dq_acc[...] = jnp.zeros_like(dq_acc)
 
     @pl.when(iq == 0)
     def _():
@@ -374,47 +398,26 @@ def _flash_bwd_dkv_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
     def part(qs, ks, thresh, k_limit):
-        q, do = q_ref[qs, :], do_ref[qs, :]
-        st = lax.mul(_dot(k_ref[ks, :], q, _NT), sm_scale)
+        q, do, k = q_ref[qs, :], do_ref[qs, :], k_ref[ks, :]
+        st = lax.mul(_dot(k, q, _NT), sm_scale)
         pt = _bwd_p(st, lse_ref[:, qs], 1, thresh, k_limit)
         dv_acc[ks, :] += _dot(pt.astype(do.dtype), do, _NN)
         dst = lax.mul(pt, lax.sub(_dot(v_ref[ks, :], do, _NT),
-                                  delta_ref[:, qs]))
-        dk_acc[ks, :] += _dot(dst.astype(q.dtype), q, _NN)
+                                  delta_ref[:, qs])).astype(q.dtype)
+        dk_acc[ks, :] += _dot(dst, q, _NN)
+        # these queries' rows of the whole-sequence accumulator
+        start, stop, _ = qs.indices(t.block_q)
+        rows = pl.ds(pl.multiple_of(iq * t.block_q + start, LANES), stop - start)
+        dq_acc[rows, :] += _dot(dst, k, _TN)
 
     _on_tiles(t, iq, ik, part)
 
-    @pl.when(iq == pl.num_programs(2) - 1)
+    @pl.when(iq == last_q)
     def _():
         dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
-
-def _flash_bwd_dq_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                         dq_ref, dq_acc, lse_col, delta_col, *, sm_scale: float,
-                         t: _Tiles):
-    block_q = q_ref.shape[0]
-    iq, ik = pl.program_id(1), pl.program_id(2)
-
-    @pl.when(ik == 0)
-    def _():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
-        # (1, block_q) rows -> (block_q, LANES) columns, every lane the same
-        lse_col[...] = jnp.broadcast_to(lse_ref[...], (LANES, block_q)).T
-        delta_col[...] = jnp.broadcast_to(delta_ref[...], (LANES, block_q)).T
-
-    def part(qs, ks, thresh, k_limit):
-        k = k_ref[ks, :]
-        s = lax.mul(_dot(q_ref[qs, :], k, _NT), sm_scale)
-        reps = (1, k.shape[0] // LANES)
-        p = _bwd_p(s, jnp.tile(lse_col[qs, :], reps), 0, thresh, k_limit)
-        ds = lax.mul(p, lax.sub(_dot(do_ref[qs, :], v_ref[ks, :], _NT),
-                                jnp.tile(delta_col[qs, :], reps)))
-        dq_acc[qs, :] += _dot(ds.astype(k.dtype), k, _NN)
-
-    _on_tiles(t, iq, ik, part)
-
-    @pl.when(ik == pl.num_programs(2) - 1)
+    @pl.when(jnp.logical_and(ik == last_k, iq == last_q))
     def _():
         dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
@@ -443,35 +446,31 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
     # exp(s - lse) would blow up: such rows, like the padding rows, get an lse
     # under which every p is zero.
     lse = jnp.where(lse > NEG_INF / 2, lse, -NEG_INF)
-    operands = (_rows(q, s_q_pad), _rows(g, s_q_pad), row(lse, -NEG_INF),
-                row(delta.reshape(b * h, 1, s_q), 0.0),
-                _rows(k, s_k_pad), _rows(v, s_k_pad))
-
-    def call(kernel, name, q_is_inner, scratch):
-        n_out = 2 if q_is_inner else 1  # dK and dV, or dQ
-        q_spec, row_spec, k_spec = t.specs(d, q_is_inner)
-        out_block, out_pad, out_len = ((t.block_k, s_k_pad, s_k) if q_is_inner
-                                       else (t.block_q, s_q_pad, s_q))
-        outs = pl.pallas_call(
-            functools.partial(kernel, sm_scale=sm_scale, t=t),
-            grid=(b * h, t.nk, t.nq) if q_is_inner else (b * h, t.nq, t.nk),
-            in_specs=[q_spec, q_spec, row_spec, row_spec, k_spec, k_spec],
-            out_specs=[pl.BlockSpec((None, out_block, d),
-                                    lambda bh, i, j: (bh, i, 0))] * n_out,
-            out_shape=[jax.ShapeDtypeStruct((b * h, out_pad, d), q.dtype)] * n_out,
-            scratch_shapes=[pltpu.VMEM((out_block, d), jnp.float32)] * n_out
-            + scratch,
-            compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
-            interpret=interpret,
-            name=name,
-        )(*operands)
-        return [o[:, :out_len].reshape(b, h, out_len, d) for o in outs]
-
-    dk, dv = call(_flash_bwd_dkv_kernel, "flash_bwd_dkv", True, [])
-    dq, = call(_flash_bwd_dq_kernel, "flash_bwd_dq", False,
-               [pltpu.VMEM((t.block_q, LANES), jnp.float32)] * 2)
-    return dq, dk.astype(k.dtype), dv.astype(v.dtype)
+    q_spec, row_spec, k_spec = t.specs(d, q_is_inner=True)
+    dkv_spec = pl.BlockSpec((None, t.block_k, d), lambda bh, i, j: (bh, i, 0))
+    # dQ sums over the k blocks, the outer axis: its block is the whole
+    # sequence of one b*h, written back once when the b*h is done.
+    dq_spec = pl.BlockSpec((None, s_q_pad, d), lambda bh, i, j: (bh, 0, 0))
+    dq, dk, dv = pl.pallas_call(
+        functools.partial(_flash_bwd_kernel, sm_scale=sm_scale, t=t),
+        grid=(b * h, t.nk, t.nq),
+        in_specs=[q_spec, q_spec, row_spec, row_spec, k_spec, k_spec],
+        out_specs=[dq_spec, dkv_spec, dkv_spec],
+        out_shape=[jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype)]
+        + [jax.ShapeDtypeStruct((b * h, s_k_pad, d), q.dtype)] * 2,
+        scratch_shapes=[pltpu.VMEM((s_q_pad, d), jnp.float32)]
+        + [pltpu.VMEM((t.block_k, d), jnp.float32)] * 2,
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=_bwd_vmem_bytes(s_q_pad, d, q.dtype)),
+        interpret=interpret,
+        name="flash_bwd",
+    )(_rows(q, s_q_pad), _rows(g, s_q_pad), row(lse, -NEG_INF),
+      row(delta.reshape(b * h, 1, s_q), 0.0),
+      _rows(k, s_k_pad), _rows(v, s_k_pad))
+    return (dq[:, :s_q].reshape(b, h, s_q, d),
+            dk[:, :s_k].reshape(b, h, s_k, d).astype(k.dtype),
+            dv[:, :s_k].reshape(b, h, s_k, d).astype(v.dtype))
 
 
 # ============================================================= public op

@@ -27,6 +27,18 @@ def _qkv(b=2, h=2, s=256, d=32, seed=0, dtype=jnp.float32):
     return q, k, v
 
 
+def _eqns(jaxpr, kernel=None):
+    """Every equation under ``jaxpr``, each with the ``pallas_call`` equation
+    whose kernel it lies in (None outside any)."""
+    for eqn in jaxpr.eqns:
+        yield eqn, kernel
+        if eqn.primitive.name == "pallas_call":
+            yield from _eqns(eqn.params["jaxpr"], eqn)
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from _eqns(sub, kernel)
+
+
 class TestFlashAttention:
     def test_matches_reference_causal(self):
         q, k, v = _qkv()
@@ -117,6 +129,13 @@ class TestFlashAttention:
         "causal-sq-ne-sk-q-offset": (True, 64, jnp.float32, 256, 768, 512, 0),
         "causal-sq-ne-sk-both-offsets": (True, 128, jnp.float32, 640, 1100, 560, 100),
         "causal-empty-softmax-rows": (True, 64, jnp.float32, 256, 768, 100, 300),
+        # dQ's whole-sequence accumulator summed over several k blocks: four
+        # tiles of 1024 at width 128; 5 x 5 unequal tiles (256 x 512), both
+        # lengths padded, under an offset; 3 x 5 tiles of 256 with the
+        # diagonal in chunks, the padded last k block past every query
+        "causal-d128-bf16-four-tiles": (True, 128, jnp.bfloat16, 2048, 2048, 0, 0),
+        "causal-sq-ne-sk-many-k-blocks-padded": (True, 64, jnp.bfloat16, 1100, 2200, 1100, 0),
+        "causal-sq-ne-sk-offset-diagonal-chunks": (True, 64, jnp.float32, 768, 1100, 256, 0),
     }
 
     @pytest.mark.parametrize("case", list(BWD_CASES))
@@ -210,20 +229,10 @@ class TestFlashAttention:
         accumulates in float32."""
         q, k, v = _qkv(b=1, h=2, s=2048, d=128, dtype=jnp.bfloat16)
         jaxpr = jax.make_jaxpr(lambda q, k, v: flash_attention(q, k, v))(q, k, v)
-        calls, dots = [], []
-
-        def walk(j, inside):
-            for eqn in j.eqns:
-                if eqn.primitive.name == "pallas_call":
-                    calls.append(eqn)
-                    walk(eqn.params["jaxpr"], True)
-                    continue
-                if inside and eqn.primitive.name == "dot_general":
-                    dots.append(eqn)
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    walk(sub, inside)
-
-        walk(jaxpr.jaxpr, False)
+        eqns = list(_eqns(jaxpr.jaxpr))
+        calls = [e for e, _ in eqns if e.primitive.name == "pallas_call"]
+        dots = [e for e, kernel in eqns
+                if kernel is not None and e.primitive.name == "dot_general"]
         assert [c.params["name"] for c in calls] == ["flash_fwd"]
         assert tuple(calls[0].params["grid_mapping"].grid) == (2, 2, 2)
         assert len(dots) >= 2
@@ -242,20 +251,43 @@ class TestFlashAttention:
         jaxpr = jax.make_jaxpr(jax.grad(
             lambda q, k, v: jnp.sum(flash_attention(q, k, v)),
             argnums=(0, 1, 2)))(q, k, v)
-        kernels, others = [], []
-
-        def walk(j):
-            for eqn in j.eqns:
-                if eqn.primitive.name == "pallas_call":
-                    kernels.append(eqn.params["name"])  # the kernel's own loops stay inside
-                    continue
-                others.append(eqn.primitive.name)
-                for sub in jax.core.jaxprs_in_params(eqn.params):
-                    walk(sub)
-
-        walk(jaxpr.jaxpr)
-        assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+        # (the kernels' own loops stay inside them)
+        outside = [e for e, kernel in _eqns(jaxpr.jaxpr) if kernel is None]
+        kernels = [e.params["name"] for e in outside
+                   if e.primitive.name == "pallas_call"]
+        others = [e.primitive.name for e in outside]
+        assert sorted(kernels) == ["flash_bwd", "flash_fwd"]
         assert not {"scan", "while"} & set(others), others
+
+    def test_backward_is_one_kernel_of_five_dots_and_one_exp_a_tile(self):
+        """The mechanism engages: the backward of the op is one Pallas call
+        whose bare tile body recomputes P once (one ``exp``) and takes dV, dK
+        and dQ from it in five matmuls — bf16 operands, float32 accumulation,
+        dQ's as the contraction over dim 0 of dS^T and K — and whose dQ
+        accumulator and output block span the sequence."""
+        q, k, v = _qkv(b=1, h=2, s=2048, d=128, dtype=jnp.bfloat16)
+        jaxpr = jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(flash_attention(q, k, v, causal=False)
+                                    .astype(jnp.float32)),
+            argnums=(0, 1, 2)))(q, k, v)
+        eqns = list(_eqns(jaxpr.jaxpr))
+        calls = [e for e, _ in eqns if e.primitive.name == "pallas_call"
+                 and e.params["name"].startswith("flash_bwd")]
+        assert [c.params["name"] for c in calls] == ["flash_bwd"]
+        assert tuple(calls[0].params["grid_mapping"].grid) == (2, 2, 2)
+        # no mask, no padding: every tile takes the one bare body
+        body = [e for e, kernel in eqns if kernel is calls[0]]
+        dots = [e for e in body if e.primitive.name == "dot_general"]
+        assert len(dots) == 5
+        assert sum(e.primitive.name == "exp" for e in body) == 1
+        for eqn in dots:
+            assert [x.aval.dtype for x in eqn.invars] == [jnp.bfloat16] * 2
+            assert eqn.outvars[0].aval.dtype == jnp.float32
+        contracting = [e.params["dimension_numbers"][0] for e in dots]
+        assert contracting.count(((0,), (0,))) == 1  # dQ += (dS^T)^T K
+        kernel_avals = [x.aval for x in calls[0].params["jaxpr"].invars]
+        assert sorted(a.shape for a in kernel_avals
+                      if max(a.shape) == 2048) == [(2048, 128)] * 2
 
     def test_offsets_shift_mask(self):
         # with q_offset = S_k, every key is visible (no masking)

@@ -250,9 +250,12 @@ def test_the_step_carries_its_scopes_in_op_name(toy_step):
     if family == "llama":
         assert any("/h_0/attn/kv_repeat/" in n for n in op_names)
     bare_names = set(re.findall(r'op_name="([^"]+)"', bare))
-    # (the backward's kernels keep their own names, flash_bwd_dkv / _dq: it
-    # is the scope, a path component of its own, that must be gone)
-    assert not any("optimizer" in n or "/flash_bwd/" in n for n in bare_names)
+    # (the backward's kernel keeps its own name, which is the scope's: it is
+    # the scope, a path component of its own above the kernel's, that must be
+    # gone)
+    assert any("/flash_bwd/flash_bwd/" in n for n in op_names)
+    assert not any("optimizer" in n or "flash_bwd/flash_bwd" in n
+                   for n in bare_names)
 
 
 def test_the_loss_rules_carry_lm_loss_forward_and_backward(toy_step):
