@@ -237,12 +237,16 @@ def _token_nll_bwd(res, g):
 _token_nll.defvjp(_token_nll_fwd, _token_nll_bwd)
 
 
-def lm_loss(logits, targets, mask=None):
+def lm_loss(logits, targets, mask=None, total=None):
     """Mean next-token cross entropy in f32 (max, sum, log and mean), over
-    the rows ``mask`` keeps; 0 where it keeps none."""
+    the rows ``mask`` keeps; 0 where it keeps none.  ``mask`` may be any
+    per-row weights; ``total``, where given, is what their weighted sum is
+    divided by in place of their own sum."""
     nll = _token_nll(logits, targets)
     with jax.named_scope("lm_loss"):
         if mask is None:
             return jnp.mean(nll)
         mask = mask.astype(jnp.float32)
+        if total is not None:
+            return jnp.sum(nll * mask) / total
         return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)

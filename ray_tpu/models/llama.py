@@ -8,9 +8,12 @@ hybrid models: a layer's feed-forward is the dense ``SwiGLU`` (``mlp``) or,
 in every ``moe_every``-th layer, the dropless routed experts of
 ``models/moe.py``
 (``moe``: ``n_experts`` SwiGLU experts of width ``d_expert``,
-``moe_top_k`` per token), and ``qk_norm`` puts an RMSNorm over the whole
+``moe_top_k`` per token, of which the layer may hold a part:
+``experts_held``), and ``qk_norm`` puts an RMSNorm over the whole
 query and key projections before the heads are split and rotated
-(OLMoE-1B-7B is this block with both).  A layer's token mixer is a kind
+(OLMoE-1B-7B is this block with both), or, as ``"head"``, over each head's
+``head_dim`` after the split, one scale shared by the heads; ``head_dim`` is
+a size of its own where it is not ``d_model / n_head``.  A layer's token mixer is a kind
 too: ``layer_types`` names each layer ``"attention"`` (``attn``) or
 ``"mamba"`` (``mamba``: the Mamba-2 mixer of ``models/mamba.py`` over the
 chunked scan of ``ops/ssd.py``); attention may go without RoPE (``rope``)
@@ -18,8 +21,13 @@ and take a score scale of its own (``attn_scale``); the embedding, each
 branch before its residual add and the logits take constant multipliers;
 and ``tie_embeddings`` makes the head the embedding table itself, its matmul
 still under the name path ``lm_head`` (Granite 4.0-H is this block with all
-of these).  Every such field at its default leaves the program the dense
-Llama it was.  Same TPU discipline as the GPT stack —
+of these).  ``objective="block_diffusion"`` makes the model the denoiser of
+block-diffusion training: it takes a noised and a clean copy of each row side
+by side, ``[x_t ; x]``, runs both through every layer under
+``ops.attention.block_diffusion_mask`` with the positions ``0..L-1`` twice,
+and gives logits for the noised copy alone (SDAR is the block with this, the
+per-head norm, its own ``head_dim`` and a part of each layer's experts).
+Every such field at its default leaves the program the dense Llama it was.  Same TPU discipline as the GPT stack —
 bfloat16 activations, fused QKV-free layout matched to
 ``llama_partition_rules`` so tp/fsdp shardings apply by regex, attention
 via the Pallas flash kernel (``ray_tpu.ops.flash_attention``) or ring
@@ -40,8 +48,8 @@ import jax.numpy as jnp
 from ray_tpu.models.gpt2 import mask_vocab_padding, padded_vocab
 from ray_tpu.models.mamba import Mamba2Mixer
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
-from ray_tpu.ops.attention import (flash_attention, mha_reference,
-                                   ring_attention_sharded)
+from ray_tpu.ops.attention import (block_diffusion_mask, flash_attention,
+                                   mha_reference, ring_attention_sharded)
 from ray_tpu.parallel.sharding import constrain_residual
 
 
@@ -61,7 +69,11 @@ class LlamaConfig:
     ring_axis: str = "sp"
     remat: bool = True
     remat_policy: str = "full"
-    qk_norm: bool = False            # RMSNorm over the q and k projections
+    # RMSNorm over the q and k projections: True, over the whole projection
+    # before the split into heads; "head", over each head's head_dim after it
+    # (one scale of that width for all heads); both before RoPE
+    qk_norm: Any = False
+    head_dim: Optional[int] = None   # a head's width; None: d_model / n_head
     # every moe_every-th layer (the last of each period) routes its tokens
     # through n_experts SwiGLU experts of width d_expert; 0: all dense
     moe_every: int = 0
@@ -69,6 +81,9 @@ class LlamaConfig:
     moe_top_k: int = 0
     d_expert: int = 0
     norm_topk_prob: bool = False     # renormalise the chosen probabilities
+    # (first index, count) of each routed layer's experts that are held here,
+    # as one chip of an expert-parallel group holds them; None: all
+    experts_held: Optional[Tuple[int, int]] = None
     router_aux_weight: float = 0.01  # x load-balancing loss, in the objective
     router_z_weight: float = 1e-3    # x router z-loss
     # each layer's token mixer, "attention" or "mamba" (models/mamba.py), one
@@ -86,6 +101,14 @@ class LlamaConfig:
     residual_multiplier: float = 1.0     # x each branch, before its residual add
     logits_scaling: float = 1.0          # the logits are divided by it
     tie_embeddings: bool = False         # the head is the embedding table
+    # what the trainer minimises (models/pretrain.py): "next_token", or
+    # "block_diffusion" — the masked-token loss of a noised copy of each row,
+    # in blocks of diffusion_block, each at its own noise level in
+    # (diffusion_t_min, 1], masked positions holding mask_token_id
+    objective: str = "next_token"
+    diffusion_block: int = 0
+    diffusion_t_min: float = 1e-3
+    mask_token_id: int = 0
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -121,19 +144,28 @@ class LlamaAttention(nn.Module):
         cfg = self.config
         B, S, E = x.shape
         H, KV = cfg.n_head, cfg.n_kv_head
-        D = E // H
+        D = cfg.head_dim or E // H
         assert H % KV == 0, "n_head must be a multiple of n_kv_head"
         q = nn.Dense(H * D, use_bias=False, dtype=cfg.dtype, name="wq")(x)
         k = nn.Dense(KV * D, use_bias=False, dtype=cfg.dtype, name="wk")(x)
         v = nn.Dense(KV * D, use_bias=False, dtype=cfg.dtype, name="wv")(x)
-        if cfg.qk_norm:
-            q = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
-                           name="q_norm")(q)
-            k = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
-                           name="k_norm")(k)
-        q = q.reshape(B, S, H, D).transpose(0, 2, 1, 3)
-        k = k.reshape(B, S, KV, D).transpose(0, 2, 1, 3)
-        v = v.reshape(B, S, KV, D).transpose(0, 2, 1, 3)
+        if cfg.qk_norm not in (False, True, "head"):
+            raise ValueError(f"unknown qk_norm {cfg.qk_norm!r} (expected "
+                             "False, True or 'head')")
+
+        def norm(name, a):
+            return nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
+                              name=name)(a)
+
+        def heads(a, name=None):
+            a = a.reshape(B, S, -1, D)
+            if name and cfg.qk_norm == "head":
+                a = norm(name, a)
+            return a.transpose(0, 2, 1, 3)
+
+        if cfg.qk_norm is True:
+            q, k = norm("q_norm", q), norm("k_norm", k)
+        q, k, v = heads(q, "q_norm"), heads(k, "k_norm"), heads(v)
         if cfg.rope:
             with jax.named_scope("rope"):
                 cos, sin = rope_frequencies(D, positions, cfg.rope_theta)
@@ -144,7 +176,21 @@ class LlamaAttention(nn.Module):
             with jax.named_scope("kv_repeat"):
                 k = jnp.repeat(k, rep, axis=1)
                 v = jnp.repeat(v, rep, axis=1)
-        if cfg.attention_impl == "ring":
+        if cfg.objective == "block_diffusion":
+            # x is [noised ; clean]: the block mask in place of the causal one
+            if cfg.attention_impl == "reference":
+                out = mha_reference(
+                    q, k, v, sm_scale=cfg.attn_scale,
+                    mask=block_diffusion_mask(S // 2, cfg.diffusion_block))
+            elif cfg.attention_impl == "flash":
+                out = flash_attention(q, k, v, causal=False,
+                                      sm_scale=cfg.attn_scale,
+                                      diffusion_block=cfg.diffusion_block)
+            else:
+                raise NotImplementedError(
+                    "block-diffusion attention over a sharded sequence: "
+                    f"attention_impl={cfg.attention_impl!r} has no block mask")
+        elif cfg.attention_impl == "ring":
             out = ring_attention_sharded(q, k, v, causal=True,
                                          sm_scale=cfg.attn_scale,
                                          seq_axis=cfg.ring_axis)
@@ -200,8 +246,8 @@ class LlamaBlock(nn.Module):
             return add(x, RoutedSwiGLU(RoutedConfig(
                 n_experts=cfg.n_experts, top_k=cfg.moe_top_k,
                 d_model=cfg.d_model, d_ff=cfg.d_expert,
-                norm_topk_prob=cfg.norm_topk_prob, dtype=cfg.dtype),
-                name="moe")(y))
+                norm_topk_prob=cfg.norm_topk_prob, dtype=cfg.dtype,
+                experts_held=cfg.experts_held), name="moe")(y))
         return add(x, SwiGLU(cfg, name="mlp")(y))
 
 
@@ -210,8 +256,18 @@ class LlamaLMModel(nn.Module):
 
     @nn.compact
     def __call__(self, input_ids, *, deterministic: bool = True):
+        """(B, S) token ids -> (B, S, padded vocabulary) logits; under the
+        block-diffusion objective ``input_ids`` is ``[x_t ; x]``, (B, 2 L),
+        and the logits are the noised copy's, (B, L, .)."""
         cfg = self.config
         B, S = input_ids.shape
+        if cfg.objective not in ("next_token", "block_diffusion"):
+            raise ValueError(f"unknown objective {cfg.objective!r} (expected "
+                             "'next_token' or 'block_diffusion')")
+        two_copies = cfg.objective == "block_diffusion"
+        if two_copies and (S // 2) % cfg.diffusion_block:
+            raise ValueError(f"a copy of {S // 2} positions is not whole "
+                             f"blocks of {cfg.diffusion_block}")
         if cfg.layer_types and len(cfg.layer_types) != cfg.n_layer:
             raise ValueError(f"layer_types names {len(cfg.layer_types)} "
                              f"layers, n_layer is {cfg.n_layer}")
@@ -221,7 +277,9 @@ class LlamaLMModel(nn.Module):
         if cfg.embedding_multiplier != 1.0:
             x = x * cfg.embedding_multiplier
         x = constrain_residual(x)
-        positions = jnp.arange(S)
+        # both copies of a row count their positions from 0
+        positions = jnp.tile(jnp.arange(S // 2), 2) if two_copies \
+            else jnp.arange(S)
         if cfg.remat:
             policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                       if cfg.remat_policy == "dots" else None)
@@ -233,6 +291,8 @@ class LlamaLMModel(nn.Module):
             mixer = cfg.layer_types[i] if cfg.layer_types else "attention"
             x = constrain_residual(
                 block_cls(cfg, routed, mixer, name=f"h_{i}")(x, positions))
+        if two_copies:
+            x = x[:, :S // 2]       # the head sees the noised copy alone
         x = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype, name="norm_f")(x)
         if cfg.logits_scaling != 1.0:
             # on the narrow side of the head's matmul: the logits stay bf16

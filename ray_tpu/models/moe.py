@@ -1,8 +1,8 @@
 """Mixture-of-Experts feed-forward layers: two forms, one module each.
 
-``RoutedSwiGLU`` — the dropless layer of today's open sparse models (OLMoE,
-and every row of ROADMAP's Reach queue): the router's softmax over all experts
-in float32, ``top_k`` experts per token, the ``T * k`` (token, expert) rows
+``RoutedSwiGLU`` — the dropless layer of today's open sparse models: the
+router's softmax over all experts in float32, ``top_k`` experts per token,
+the ``T * k`` (token, expert) rows
 sorted by expert, three grouped matmuls over the contiguous groups of uneven
 size (SwiGLU experts: gate, up, down), the gate weights applied on the way
 back, rows returned to token order and summed over their ``k`` slots.  No
@@ -18,14 +18,27 @@ another's rows) through all the experts, whose weights are stored sharded
 (``fsdp`` / ``tp``) and gathered whole, in bf16, by every routed layer's
 forward, recomputation and backward: 0.8 GB a gather at OLMoE's widths,
 which is the price of this form and has not been measured on a multi-chip
-mesh.  Experts sharded over ``ep`` with an all-to-all are the follow-up that
-removes it, and raise ``NotImplementedError`` here.
+mesh.
+
+A layer may be told which experts it holds (``RoutedConfig.experts_held``:
+first index, count), as one chip of an expert-parallel group is: it has
+those experts' matrices and no others.  The router, its softmax, the top-k
+and the renormalisation stay over all ``n_experts``; the rows bound for
+absent experts sort behind every held group, as one last group the grouped
+matmuls have no matrix for and never visit, and come back as zeros: the layer
+returns its own experts' part of the result.  The row buffer stays ``T * k``
+(every assignment may fall to a held expert), the kernels' work follows the
+rows that came; nothing is dropped and nothing stands in for the absent chips
+or for their exchange.  What ``ep > 1`` on a mesh still lacks is that
+exchange — the all-to-all that sends each token's rows to the device holding
+its expert and brings the results back — and it raises
+``NotImplementedError``.
 
 ``MoEMlpBlock`` — the older GShard / Switch form, wired into GPT-2 only
 (``GPT2Config.moe_every``): top-k routing as DENSE dispatch / combine einsums
 against one-hot capacity tensors, GELU experts, tokens over capacity dropped;
 with the expert dimension sharded on ``ep`` GSPMD lowers the einsums into
-all-to-alls.  ROADMAP R1 retires it once the dropless layer serves GPT-2 too.
+all-to-alls.
 
 Both sow their auxiliary terms into the ``intermediates`` collection;
 ``collect_aux`` turns them into the objective's extra term and a step's
@@ -36,7 +49,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Any, Tuple
+from typing import Any, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -165,6 +178,8 @@ class RoutedConfig:
     d_ff: int                       # one expert's SwiGLU hidden width
     norm_topk_prob: bool = False    # renormalise the chosen k probabilities
     dtype: Any = jnp.bfloat16
+    # (first index, count) of the experts this layer holds; None: all of them
+    experts_held: Optional[Tuple[int, int]] = None
 
 
 def _gmm_tiling(m: int, k: int, n: int):
@@ -189,7 +204,9 @@ def _tgmm_tiling(m: int, k: int, n: int):
 def _gmm(lhs, rhs, sizes, *, transpose_rhs=False):
     """``lhs[rows of group e] @ rhs[e]`` (``rhs[e].T`` if ``transpose_rhs``)
     by the megablox kernel.  It wants the row count a multiple of the row
-    tile: rows are padded behind the last group and cut off again."""
+    tile: rows are padded behind the last group and cut off again.  Where
+    ``sizes`` counts more groups than ``rhs`` has matrices, the rows of the
+    groups past the last matrix are not visited and come back zero."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     m, k = lhs.shape
@@ -201,9 +218,9 @@ def _gmm(lhs, rhs, sizes, *, transpose_rhs=False):
     return out[:m]
 
 
-def _tgmm(lhs, grad, sizes, dtype):
+def _tgmm(lhs, grad, sizes, dtype, n_groups):
     """Per group ``lhs[rows].T @ grad[rows]``: the weights' gradient,
-    ``(E, k, n)``."""
+    ``(n_groups, k, n)``, for the first ``n_groups`` of ``sizes``."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
 
     m, k = lhs.shape
@@ -212,13 +229,15 @@ def _tgmm(lhs, grad, sizes, dtype):
         lhs, grad = (jnp.pad(a, ((0, -m % tiling[0]), (0, 0)))
                      for a in (lhs, grad))
     return tgmm(lhs.T, grad, sizes, preferred_element_type=dtype,
-                tiling=tiling, interpret=_interpret())
+                tiling=tiling, num_actual_groups=n_groups,
+                interpret=_interpret())
 
 
 @jax.custom_vjp
 def grouped_matmul(lhs, rhs, sizes):
     """``lhs`` (N, k) in contiguous groups of ``sizes`` (E,) rows, each group
-    times its own ``rhs[e]`` (E, k, n) -> (N, n)."""
+    times its own ``rhs[e]`` (E, k, n) -> (N, n).  ``sizes`` may count one
+    group more than ``rhs`` holds: see ``_gmm``."""
     return _gmm(lhs, rhs, sizes)
 
 
@@ -229,7 +248,7 @@ def _grouped_matmul_fwd(lhs, rhs, sizes):
 def _grouped_matmul_bwd(res, grad):
     lhs, rhs, sizes = res
     return (_gmm(grad, rhs, sizes, transpose_rhs=True),
-            _tgmm(lhs, grad, sizes, rhs.dtype), None)
+            _tgmm(lhs, grad, sizes, rhs.dtype, rhs.shape[0]), None)
 
 
 grouped_matmul.defvjp(_grouped_matmul_fwd, _grouped_matmul_bwd)
@@ -268,18 +287,31 @@ _permute_rows.defvjp(lambda rows, perm, inverse: (rows[perm], inverse),
 def routed_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig):
     """One device's tokens through their experts.  x (..., D); weights, idx
     (..., k): each token's gate weights and chosen experts; gate, up
-    (E, D, F) and down (E, F, D) in the compute dtype."""
+    (E, D, F) and down (E, F, D) in the compute dtype — E the experts held
+    (``cfg.experts_held``), whose part of the result this is."""
     lead, d = x.shape[:-1], x.shape[-1]
-    k, n_experts = cfg.top_k, cfg.n_experts
+    k, n_groups = cfg.top_k, cfg.n_experts
     x = x.reshape(-1, d)
     with jax.named_scope("dispatch"):
         flat = idx.reshape(-1)
+        if cfg.experts_held is not None:
+            # held experts by their local index; every absent expert's rows
+            # in one last group, behind them, that has no matrix
+            first, n_held = cfg.experts_held
+            flat = jnp.where((flat >= first) & (flat < first + n_held),
+                             flat - first, n_held)
+            n_groups = n_held + 1
         order = jnp.argsort(flat, stable=True)
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=order.dtype))
-        sizes = jnp.sum(flat[:, None] == jnp.arange(n_experts), axis=0,
+        sizes = jnp.sum(flat[:, None] == jnp.arange(n_groups), axis=0,
                         dtype=jnp.int32)
         rows = _rows_to_expert_order(x, order, inverse, k)
+        if cfg.experts_held is not None:
+            # the buffer holds T * k rows, the worst case; past the held
+            # experts' rows it is empty, not other chips' tokens
+            rows = jnp.where((jnp.arange(rows.shape[0]) < jnp.sum(
+                sizes[:-1]))[:, None], rows, jnp.zeros_like(rows))
     with jax.named_scope("experts"):
         h = jax.nn.silu(grouped_matmul(rows, gate, sizes)) \
             * grouped_matmul(rows, up, sizes)
@@ -310,7 +342,9 @@ class RoutedSwiGLU(nn.Module):
     (``E * sum_e f_e P_e``: ``f_e`` the assignments expert ``e`` received per
     token — its share of the (token, slot) assignments times ``top_k`` —
     ``P_e`` its mean router probability; ``top_k`` at balance), ``moe_z`` (mean of ``logsumexp(router logits) ** 2``) and
-    ``moe_max_load`` (the busiest expert's assignments over the mean)."""
+    ``moe_max_load`` (the busiest expert's assignments over the mean), each
+    over all ``n_experts``; and, where the layer holds a part of them,
+    ``moe_rows_held``: the assignments its own experts received."""
 
     config: RoutedConfig
 
@@ -324,8 +358,10 @@ class RoutedSwiGLU(nn.Module):
         if mesh is not None and mesh.shape.get("ep", 1) > 1:
             raise NotImplementedError(
                 "RoutedSwiGLU on a mesh with ep > 1: experts sharded over "
-                "'ep' need the all-to-all dispatch (ROADMAP R1, cell "
-                "olmoe-ep4-s4k); run it with ep=1 (dp / fsdp / tp)")
+                "'ep' need the all-to-all that sends each token's rows to "
+                "the device holding its expert and brings the results back, "
+                "which this layer does not have; run it with ep=1 (dp / fsdp "
+                "/ tp), where every device holds the layer's experts_held")
         with jax.named_scope("router"):
             # float32 at full precision: the router's rounding decides which
             # experts a token gets
@@ -346,15 +382,20 @@ class RoutedSwiGLU(nn.Module):
                 jax.nn.logsumexp(logits, axis=-1) ** 2))
             self.sow("intermediates", "moe_max_load",
                      jnp.max(share) * n_experts)
+            if cfg.experts_held is not None:
+                first, n_held = cfg.experts_held
+                self.sow("intermediates", "moe_rows_held",
+                         jnp.sum(counts[first:first + n_held]))
 
         # each expert's own matrix as nn.Dense would initialise it
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                             batch_axis=(0,))
+        n_held = cfg.experts_held[1] if cfg.experts_held else n_experts
         gate, up, down = (
             self.param(name, init, shape, jnp.float32)
-            for name, shape in (("gate_proj", (n_experts, d, f)),
-                                ("up_proj", (n_experts, d, f)),
-                                ("down_proj", (n_experts, f, d))))
+            for name, shape in (("gate_proj", (n_held, d, f)),
+                                ("up_proj", (n_held, d, f)),
+                                ("down_proj", (n_held, f, d))))
         with jax.named_scope("experts"):    # the casts are the experts' cost
             gate, up, down = (w.astype(cfg.dtype) for w in (gate, up, down))
 
@@ -378,7 +419,9 @@ def collect_aux(intermediates, aux_weight: float = 0.0, z_weight: float = 0.0):
     """What the MoE layers sowed in one forward pass -> (the objective's
     extra term, the step's statistics).  ``MoEMlpBlock`` sows its term
     already weighted; ``RoutedSwiGLU``'s two losses are averaged over the
-    routed layers and weighted here; ``max_load`` is the worst layer's."""
+    routed layers and weighted here; ``max_load`` is the worst layer's and
+    ``moe_rows_held``, where the layers hold a part of their experts, a
+    layer's, averaged over them."""
     by_name: dict = {}
     # sow keeps a tuple under each name: (..., "h_3", "moe", "moe_z", 0)
     for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
@@ -390,6 +433,8 @@ def collect_aux(intermediates, aux_weight: float = 0.0, z_weight: float = 0.0):
         stats = {"load_balance": sum(by_name["moe_load_balance"]) / n,
                  "z": sum(by_name["moe_z"]) / n,
                  "max_load": jnp.max(jnp.stack(by_name["moe_max_load"]))}
+        if "moe_rows_held" in by_name:    # a layer's, averaged over them
+            stats["moe_rows_held"] = sum(by_name["moe_rows_held"]) / n
         total = total + aux_weight * stats["load_balance"] \
             + z_weight * stats["z"]
     return total, stats

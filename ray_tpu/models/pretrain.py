@@ -5,6 +5,14 @@ run a fused forward+backward+optimizer step under one jit.  XLA/GSPMD inserts
 the ICI collectives (grad reduce over dp/fsdp, weight all-gathers for
 tp/fsdp, ring ppermute for sp attention).  ``ShardedPretrainer`` is what a
 train worker holds.
+
+The model's configuration chooses the objective.  ``"next_token"`` (every
+model but one): the cross entropy of ``targets`` under a causal model.
+``"block_diffusion"`` (``LlamaConfig.objective``): the step noises the
+batch's ``input_ids`` itself, on the device, from a key it folds its own step
+count into (``noise_blocks``), runs the noised and the clean copy through the
+model side by side, and takes the cross entropy of the tokens that were masked,
+each weighted by one over its block's noise level; ``targets`` is not read.
 """
 
 from __future__ import annotations
@@ -58,26 +66,78 @@ def init_params(config, rng=None):
     return model, init_model.init(rng, dummy)["params"]
 
 
+def noise_blocks(key, ids, block: int, mask_id: int, t_min: float):
+    """Block diffusion's forward process on a batch of rows (rows, L): each
+    block of ``block`` positions draws its own noise level ``t`` uniformly
+    from ``(t_min, 1]`` (the linear schedule: a token is masked with
+    probability ``t``), and each of its tokens is replaced by ``mask_id``
+    independently with that probability.  -> (``x_t``, which positions were
+    masked, the loss weights: ``1 / t`` on the masked positions, 0 elsewhere).
+    The same key gives the same noise."""
+    with jax.named_scope("noise"):
+        rows, length = ids.shape
+        level_key, mask_key = jax.random.split(key)
+        # uniform() is in [0, 1): 1 - u is in (0, 1]
+        t = t_min + (1.0 - t_min) * (1.0 - jax.random.uniform(
+            level_key, (rows, length // block), jnp.float32))
+        t = jnp.repeat(t, block, axis=1)
+        masked = jax.random.uniform(mask_key, ids.shape, jnp.float32) < t
+        return (jnp.where(masked, jnp.asarray(mask_id, ids.dtype), ids),
+                masked, jnp.where(masked, 1.0 / t, 0.0))
+
+
+def _is_block_diffusion(config) -> bool:
+    return getattr(config, "objective", "next_token") == "block_diffusion"
+
+
+def _noised(config, batch, key):
+    """``batch`` with ``x_t`` and ``weights`` drawn from ``key``."""
+    x_t, _, weights = noise_blocks(
+        key, batch["input_ids"], config.diffusion_block, config.mask_token_id,
+        config.diffusion_t_min)
+    return dict(batch, x_t=x_t, weights=weights)
+
+
+def _model_inputs(config, batch):
+    """(what the model is applied to, the targets, their weights, what the
+    weighted sum is divided by: None for the weights' own sum)."""
+    if _is_block_diffusion(config):
+        # [x_t ; x] -> logits at x_t's positions, each predicting the token
+        # that was there (no shift); the mean is over every data token
+        ids = batch["input_ids"]
+        return (jnp.concatenate([batch["x_t"], ids], axis=1), ids,
+                batch["weights"], ids.size)
+    return batch["input_ids"], batch["targets"], batch.get("mask"), None
+
+
 def loss_fn(model: GPT2LMModel, params, batch):
-    """The language-model cross entropy, for every model."""
-    logits = model.apply({"params": params}, batch["input_ids"])
-    return lm_loss(logits, batch["targets"], batch.get("mask"))
+    """The language-model cross entropy, for every model.  Under the
+    block-diffusion objective ``batch`` holds ``x_t`` and ``weights`` as
+    ``noise_blocks`` gives them, and the loss is
+    ``sum(weights * nll) / input_ids.size``."""
+    inputs, targets, weights, total = _model_inputs(model.config, batch)
+    logits = model.apply({"params": params}, inputs)
+    return lm_loss(logits, targets, weights, total)
 
 
-def objective_fn(model, params, batch):
+def objective_fn(model, params, batch, key=None):
     """What ``train_step`` differentiates, and what it reports beside it:
     (cross entropy + the MoE layers' auxiliary terms, (cross entropy, the
     step's MoE statistics)).  A dense model has no such terms and no
-    statistics, and its objective is ``loss_fn``."""
-    if model.config.moe_every <= 0:
+    statistics, and its objective is ``loss_fn``.  Under the block-diffusion
+    objective a batch that brings no ``x_t`` is noised here, from ``key``."""
+    cfg = model.config
+    if _is_block_diffusion(cfg) and "x_t" not in batch:
+        batch = _noised(cfg, batch, key)
+    if cfg.moe_every <= 0:
         loss = loss_fn(model, params, batch)
         return loss, (loss, {})
     from ray_tpu.models.moe import collect_aux
 
-    cfg = model.config
-    logits, sown = model.apply({"params": params}, batch["input_ids"],
+    inputs, targets, weights, total = _model_inputs(cfg, batch)
+    logits, sown = model.apply({"params": params}, inputs,
                                mutable=["intermediates"])
-    loss = lm_loss(logits, batch["targets"], batch.get("mask"))
+    loss = lm_loss(logits, targets, weights, total)
     aux, stats = collect_aux(sown["intermediates"],
                              getattr(cfg, "router_aux_weight", 0.0),
                              getattr(cfg, "router_z_weight", 0.0))
@@ -87,8 +147,15 @@ def objective_fn(model, params, batch):
 def train_step(model, tx, state, batch):
     """state = (params, opt_state). One fused fwd+bwd+update ->
     (state, the cross entropy, the step's MoE statistics: ``{}`` for a dense
-    model, else ``load_balance``, ``z``, ``max_load`` as device scalars)."""
+    model, else ``load_balance``, ``z``, ``max_load`` and, where the layers
+    hold a part of their experts, ``moe_rows_held``, as device scalars)."""
     params, opt_state = state
+    if _is_block_diffusion(model.config):
+        # the step's own noise, drawn before (and outside) the differentiated
+        # forward: the optimizer's count of steps, folded into the key
+        count = optax.tree_utils.tree_get_all_with_path(opt_state, "count")
+        batch = _noised(model.config, batch, jax.random.fold_in(
+            jax.random.PRNGKey(0), count[0][1]))
     (_, (loss, stats)), grads = jax.value_and_grad(
         lambda p: objective_fn(model, p, batch), has_aux=True)(params)
     with jax.named_scope("optimizer"):
@@ -165,7 +232,8 @@ class ShardedPretrainer:
         self.state = jax.jit(init_state, out_shardings=jax.tree_util.tree_map(
             lambda a: a.sharding, layout))()
         self._steps = 0     # calls of step(): the profiler's step number
-        # the last step's MoE statistics (load_balance, z, max_load), device
+        # the last step's MoE statistics (load_balance, z, max_load, and
+        # moe_rows_held where the layers hold a part of their experts), device
         # scalars that nothing has synchronised on; {} for a dense model
         self.moe_stats: Dict[str, Any] = {}
 

@@ -14,7 +14,12 @@ analysis for 1, 2 and 4 rows a step is where ``olmoe-s4k-1chip``'s
 ``rows_per_step`` was decided.  And the one-chip step of Granite-4.0-H-Micro
 (2048 wide, five Mamba-2 layers of 64 heads x 64 with a state of 128 and one
 grouped-query attention layer, vocab 100,352 tied, seq 8192, remat): the
-plain-XLA scan's chunk tensors have to fit beside 7.8 GB of state.
+plain-XLA scan's chunk tensors have to fit beside 7.8 GB of state.  And the
+one-chip block-diffusion step of SDAR-30B-A3B-Chat as one chip of eight holds
+it (2048 wide, 32 / 4 heads of 128, 16 of 128 experts of 768 held, 18,992
+vocabulary rows, six layers, two rows of 4096 tokens as 8192 positions,
+remat): the flash kernels under the block mask and the grouped matmuls over a
+worst-case row buffer meet the compiler, beside 10.3 GB of state.
 
 Every case runs in a subprocess (this file, as a script): the libtpu client
 must never meet the forced-CPU test process, and the child must NOT inherit
@@ -79,6 +84,11 @@ def _build(case: str, compile_: bool) -> dict:
         (config, seq), rows = _cell("olmoe-s4k-1chip"), \
             int(case[len("olmoe_b"):])
         assert config.n_experts == 64 and config.d_model == 2048
+    elif case == "sdar":
+        mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+        (config, seq), rows = _cell("sdar-bd-s4k-1chip"), 2
+        assert config.experts_held == (0, 16) and config.n_experts == 128
+        assert config.objective == "block_diffusion" and config.n_layer == 6
     elif case == "granite":
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
         (config, seq), rows = _cell("granite-h-s8k-1chip"), 1
@@ -243,6 +253,34 @@ def test_granite_step_compiles_and_fits_the_chip():
     # backward's one kernel
     assert row["tpu_custom_calls"] == 3, row
     assert row["argument_bytes"] + row["temp_bytes"] < 14e9, row
+
+
+def test_sdar_step_lowers_for_one_v5e_chip():
+    """Tier-1: the one-chip block-diffusion step of SDAR-30B-A3B-Chat at
+    published widths (six layers, 16 of 128 experts held, two rows of 4096
+    tokens as a noised and a clean copy) lowers for the TPU with its Mosaic
+    kernels in it: the flash kernels under the block mask, the grouped
+    matmuls of the held experts."""
+    kernels = _child(["sdar"], compile_=False)["sdar"]["lowered_kernels"]
+    assert kernels.pop("kernel") > 0
+    # six layers under remat (a kernel called by every layer through one
+    # function is printed once per trace: forward, recomputation, backward)
+    assert set(kernels) == {"flash_fwd", "flash_bwd"}, kernels
+
+
+@pytest.mark.slow
+def test_sdar_step_compiles_and_fits_the_chip():
+    """The TPU compiler takes the block-mask kernels at 2 x 4096 queries over
+    4096 keys and the grouped matmuls over 131,072 buffer rows, and its
+    memory analysis says six layers fit one chip (PR 31: see PERF.md)."""
+    row = _child(["sdar"], compile_=True)["sdar"]
+    print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
+    assert "refused" not in row, row
+    # a layer: flash forward, its recomputation, the backward's one kernel;
+    # the grouped matmul: gate, up, down forward, recomputed, and two
+    # backward calls each
+    assert row["tpu_custom_calls"] == 6 * (3 + 12), row
+    assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
 if __name__ == "__main__":

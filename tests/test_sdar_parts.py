@@ -1,0 +1,256 @@
+"""The parts SDAR's block-diffusion training brought to the program, each
+against something plain, at toy sizes on the CPU: the flash kernels' block
+mask against the written-out boolean mask; a routed layer that holds a part of
+its experts against ``perfbench/harness/families/sdar_moe.py::routed_part``
+(the shares add up to the whole layer; nothing is dropped at either extreme
+of the routing); the noising's statistics; ``head_dim`` and the per-head q/k
+norm; and every model the benchmark had before, unchanged.  The whole model
+against the whole reference is ``tests/test_sdar.py``."""
+
+import dataclasses
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from perfbench.harness import families, reference
+from perfbench.harness.families import sdar_moe
+from perfbench.harness.tokens import ZipfStream
+from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
+from ray_tpu.models.pretrain import (init_params, loss_fn, make_optimizer,
+                                     noise_blocks, train_step)
+from ray_tpu.ops.attention import (block_diffusion_mask, flash_attention,
+                                   mha_reference)
+
+_TOYS = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "perfbench", "tests", "toy")
+with open(os.path.join(_TOYS, "toy-sdar.json")) as f:
+    # 64 wide, 4 / 2 heads of 32 (not 64 / 4), 8 experts of 32 of which 4 are
+    # held, top-2 renormalised, 512 of 2048 vocabulary rows, blocks of 4
+    TOY = json.load(f)
+
+
+@pytest.mark.parametrize("length,block", [(160, 4), (160, 32), (1152, 32),
+                                          (384, 4)])
+def test_c_the_kernels_block_mask_is_the_written_out_mask(length, block):
+    """The interpreted flash kernels under ``diffusion_block`` against plain
+    attention under the boolean ``block_diffusion_mask``, forward and
+    backward: at 160 a copy is one 128-tile and a padded one, at 1152 four
+    256-tiles and a padded fifth with the diagonal taken chunk by chunk, at
+    384 three whole 128-tiles; the noised blocks' own squares are the plain
+    term merged by logsumexp."""
+    mask = block_diffusion_mask(length, block)
+    assert int(mask.sum()) == length * length + length * block
+    assert bool(jnp.all(mask == sdar_moe.block_mask(length, block)))
+    q, k, v = (jax.random.normal(key, (1, 2, 2 * length, 32), jnp.float32)
+               for key in jax.random.split(jax.random.PRNGKey(length), 3))
+
+    def kernel(q, k, v):
+        return flash_attention(q, k, v, causal=False, diffusion_block=block)
+
+    def plain(q, k, v):
+        return mha_reference(q, k, v, mask=mask)
+
+    np.testing.assert_allclose(kernel(q, k, v), plain(q, k, v), atol=5e-6)
+    g = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
+    for got, want in zip(jax.vjp(kernel, q, k, v)[1](g),
+                         jax.vjp(plain, q, k, v)[1](g)):
+        np.testing.assert_allclose(got, want, atol=3e-5)
+
+
+def _layer(held, n_experts=8, k=2):
+    return RoutedSwiGLU(RoutedConfig(
+        n_experts=n_experts, top_k=k, d_model=64, d_ff=32,
+        norm_topk_prob=True, dtype=jnp.float32, experts_held=held))
+
+
+def _whole_layer_params():
+    layer = _layer(None)
+    x = jax.random.normal(jax.random.PRNGKey(2), (2, 44, 64), jnp.float32)
+    params = layer.init(jax.random.PRNGKey(0), x)["params"]
+    params["router"]["kernel"] = 2.0 * jax.random.normal(
+        jax.random.PRNGKey(3), (64, 8))
+    return x, params
+
+
+def _share(params, first, count):
+    return dict(params, **{name: params[name][first:first + count]
+                           for name in ("gate_proj", "up_proj", "down_proj")})
+
+
+def test_d_the_shares_of_a_routed_layer_add_up_to_the_whole_layer():
+    """The tie the model-configs guide asks for: four chips that hold two of
+    the eight experts each, every one routing over all eight and
+    renormalising over its tokens' whole top-2, give parts that sum to what
+    the uncut reference (and the uncut program) gives for the whole layer;
+    and their held rows sum to every assignment."""
+    x, params = _whole_layer_params()
+    config = dict(TOY, num_experts=8)
+    with jax.default_matmul_precision("highest"):
+        whole = sdar_moe.routed_part(x, params, config, 0)[0]
+        np.testing.assert_allclose(
+            _layer(None).apply({"params": params}, x), whole, atol=2e-5)
+        parts, rows = [], 0.0
+        for first in range(0, 8, 2):
+            share = _share(params, first, 2)
+            part, sown = _layer((first, 2)).apply(
+                {"params": share}, x, mutable=["intermediates"])
+            np.testing.assert_allclose(
+                part, sdar_moe.routed_part(x, share, config, first)[0],
+                atol=2e-5)
+            parts.append(part)
+            rows += float(sown["intermediates"]["moe_rows_held"][0])
+    np.testing.assert_allclose(sum(parts), whole, atol=5e-5)
+    assert rows == 2 * 44 * 2
+    assert float(jnp.max(jnp.abs(parts[0]))) > 1e-3     # a part is not nothing
+
+
+@pytest.mark.parametrize("favoured,rows_held", [(0, 2 * 44 * 2), (4, 0)])
+def test_e_no_token_is_dropped_at_either_extreme(favoured, rows_held):
+    """A router that sends every token to experts ``favoured`` and
+    ``favoured + 1``: with experts 0-3 held that is every assignment (the
+    buffer's worst case, full) or none of them (empty; the layer's part is
+    exactly zero).  Both equal the reference, and both are one program:
+    the jaxpr does not depend on the routing."""
+    x, params = _whole_layer_params()
+    router = jnp.zeros((64, 8)).at[:, favoured:favoured + 2].set(1.0)
+    x = jnp.abs(x)      # so that the favoured logits are the largest
+    share = _share(dict(params, router={"kernel": router}), 0, 4)
+    layer, config = _layer((0, 4)), dict(TOY, num_experts=8)
+
+    def part(p, x):
+        return layer.apply({"params": p}, x, mutable=["intermediates"])
+
+    with jax.default_matmul_precision("highest"):
+        got, sown = part(share, x)
+        want = sdar_moe.routed_part(x, share, config, 0)[0]
+        grads = jax.grad(lambda p: jnp.sum(jnp.sin(part(p, x)[0])))(share)
+        want_grads = jax.grad(lambda p: jnp.sum(jnp.sin(
+            sdar_moe.routed_part(x, p, config, 0)[0])))(share)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    assert float(sown["intermediates"]["moe_rows_held"][0]) == rows_held
+    if not rows_held:
+        assert float(jnp.max(jnp.abs(got))) == 0.0
+    for name in ("gate_proj", "up_proj", "down_proj"):
+        np.testing.assert_allclose(grads[name], want_grads[name], atol=1e-4)
+        assert bool(jnp.all(jnp.isfinite(grads[name])))
+    other = _share(params, 0, 4)
+    assert str(jax.make_jaxpr(part)(share, x)) == \
+        str(jax.make_jaxpr(part)(other, x))
+
+
+def test_f_the_noising_masks_a_share_t_of_each_block_and_weighs_by_1_over_t():
+    """Blocks of 32 over 64 rows of 4096: every block's weights are 0 or one
+    value 1/t with t in (t_min, 1]; over the blocks the masked share follows
+    t (a block of 32 at level t masks 32 t on average); masked positions hold
+    the mask id and the others their token; the same key gives the same
+    noise, another key another."""
+    ids = jax.random.randint(jax.random.PRNGKey(0), (64, 4096), 0, 500)
+    key = jax.random.PRNGKey(11)
+    x_t, masked, weights = noise_blocks(key, ids, 32, 511, 0.001)
+    assert bool(jnp.all(jnp.where(masked, x_t == 511, x_t == ids)))
+    w = np.asarray(weights).reshape(64, 128, 32)
+    m = np.asarray(masked).reshape(64, 128, 32)
+    level = 1.0 / w.max(axis=-1, where=m, initial=1e-9)    # a block's t
+    some = m.any(axis=-1)
+    assert np.all(w[m] >= 1.0) and np.all(w[~m] == 0.0)
+    assert np.all(np.where(m, w, w.max(-1, keepdims=True))
+                  == w.max(-1, keepdims=True))      # one weight a block
+    t = level[some]
+    assert 0.001 < t.min() and t.max() <= 1.0
+    assert abs(t.mean() - 0.5) < 0.02               # uniform over (t_min, 1]
+    share = m.mean(axis=-1)[some]
+    assert abs(np.mean(share - t)) < 0.005          # masked share = t
+    assert np.corrcoef(share, t)[0, 1] > 0.95
+    # E[sum of a block's weights] is its length: the loss is a mean nll
+    assert abs(w.sum(-1).mean() / 32 - 1.0) < 0.05
+    again = noise_blocks(key, ids, 32, 511, 0.001)
+    assert all(bool(jnp.all(a == b)) for a, b in zip(again,
+                                                     (x_t, masked, weights)))
+    other = noise_blocks(jax.random.fold_in(key, 1), ids, 32, 511, 0.001)
+    assert float(jnp.mean(other[1] != masked)) > 0.2
+
+
+def test_h_head_dim_and_the_per_head_norm_against_a_plain_attention():
+    """A causal model whose ``head_dim`` is not ``d_model / n_head`` (32 at
+    64 / 4) with the per-head q/k norm: the projections are ``n_head *
+    head_dim`` wide, the norms' scales ``head_dim`` wide, and the layer equals
+    plain attention with the norm applied per head after the split; the
+    whole-projection norm (``qk_norm=True``) keeps its projection-wide
+    scale."""
+    from ray_tpu.models.llama import LlamaAttention
+
+    cfg = dataclasses.replace(
+        LlamaConfig.tiny(), head_dim=32, qk_norm="head", dtype=jnp.float32,
+        attention_impl="reference")
+    layer = LlamaAttention(cfg)
+    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 64), jnp.float32)
+    p = layer.init(jax.random.PRNGKey(1), x, jnp.arange(24))["params"]
+    assert p["wq"]["kernel"].shape == (64, 4 * 32)
+    assert p["wk"]["kernel"].shape == (64, 2 * 32)
+    assert p["wo"]["kernel"].shape == (4 * 32, 64)
+    assert p["q_norm"]["scale"].shape == p["k_norm"]["scale"].shape == (32,)
+    for name in ("q_norm", "k_norm"):
+        p[name]["scale"] = 1.0 + 0.3 * jax.random.normal(
+            jax.random.PRNGKey(5), (32,))
+    with jax.default_matmul_precision("highest"):
+        got = layer.apply({"params": p}, x, jnp.arange(24))
+        q = reference.rope(reference.rms_norm(reference.heads(
+            x @ p["wq"]["kernel"], 4), p["q_norm"], cfg.rms_eps),
+            cfg.rope_theta)
+        k = reference.rope(reference.rms_norm(reference.heads(
+            x @ p["wk"]["kernel"], 2), p["k_norm"], cfg.rms_eps),
+            cfg.rope_theta)
+        v = reference.heads(x @ p["wv"]["kernel"], 2)
+        want = reference.merge(reference.causal_attention(
+            q.reshape(2, 2, 2, 24, 32), k, v)) @ p["wo"]["kernel"]
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    whole = LlamaAttention(dataclasses.replace(cfg, qk_norm=True)).init(
+        jax.random.PRNGKey(1), x, jnp.arange(24))["params"]
+    assert whole["q_norm"]["scale"].shape == (128,)
+    assert whole["k_norm"]["scale"].shape == (64,)
+
+
+# loss of loss_fn on ZipfStream(vocab, seed=5).rows(2, 48) at PRNGKey(0)
+# weights, as float.hex(), and the parameter count, at the parent commit
+# (223ded3): (XLA attention, interpreted flash kernels)
+_AS_IT_WAS = {
+    "toy-gpt2": (173824, "0x1.a497d00000000p+2", "0x1.a49a560000000p+2"),
+    "toy-llama": (108736, "0x1.b2d00c0000000p+2", "0x1.b2c9600000000p+2"),
+    "toy-olmoe": (198208, "0x1.a3552e0000000p+2", "0x1.a35efe0000000p+2"),
+    "toy-granite": (175408, "0x1.8dc7500000000p+2", "0x1.8dc6cc0000000p+2"),
+}
+
+
+@pytest.mark.parametrize("name", sorted(_AS_IT_WAS))
+def test_i_every_model_the_benchmark_has_is_the_program_it_was(name):
+    """``head_dim``, the per-head norm, ``experts_held``, the objective and
+    the block mask come from the configuration: the toy of every family the
+    benchmark had before has the parameters it had and, bit for bit, the loss
+    it had at the parent commit, under XLA attention and under the
+    (interpreted) flash kernels; nothing of the new objective is in its step.
+    (The jaxpr text of all eight train steps equals the parent's character
+    for character: checked by hand in PR 31.)"""
+    with open(os.path.join(_TOYS, name + ".json")) as f:
+        config = json.load(f)
+    chips = 1 if "1" in config.get("cut_by_chips", {"1": 0}) else 4
+    n_params, *losses = _AS_IT_WAS[name]
+    batch = {k: jnp.asarray(v) for k, v in ZipfStream(
+        config["vocab_size"], seed=5).rows(2, 48).items()}
+    for impl, want in zip(("reference", "flash"), losses):
+        cfg = dataclasses.replace(
+            families.of(config).model_config(config, chips),
+            attention_impl=impl)
+        model, params = init_params(cfg)
+        assert sum(a.size for a in jax.tree_util.tree_leaves(params)) \
+            == n_params
+        assert float(loss_fn(model, params, batch)).hex() == want, impl
+    tx = make_optimizer()
+    text = str(jax.make_jaxpr(lambda s, b: train_step(model, tx, s, b))(
+        (params, tx.init(params)), batch))
+    for absent in ("noise", "random_bits", "threefry", "bd_diagonal"):
+        assert absent not in text, absent
