@@ -23,16 +23,27 @@ mesh.
 A layer may be told which experts it holds (``RoutedConfig.experts_held``:
 first index, count), as one chip of an expert-parallel group is: it has
 those experts' matrices and no others.  The router, its softmax, the top-k
-and the renormalisation stay over all ``n_experts``; the rows bound for
-absent experts sort behind every held group, as one last group the grouped
-matmuls have no matrix for and never visit, and come back as zeros: the layer
-returns its own experts' part of the result.  The row buffer stays ``T * k``
-(every assignment may fall to a held expert), the kernels' work follows the
-rows that came; nothing is dropped and nothing stands in for the absent chips
-or for their exchange.  What ``ep > 1`` on a mesh still lacks is that
-exchange — the all-to-all that sends each token's rows to the device holding
-its expert and brings the results back — and it raises
-``NotImplementedError``.
+and the renormalisation stay over all ``n_experts``; the assignments bound
+for absent experts get no row, and the layer returns its own experts' part of
+the result (``held_experts``).  The assignments are counted and given their
+buffer rows at ``T * k``, as indices (a counting sort over the held experts).
+Everything as wide as the model or an expert — the gather of token rows, the
+three grouped matmuls, ``silu * up``, each result row times its gate weight
+added in float32 into its token — runs on pieces of the balance share ``T * k
+* held / n_experts`` rows, in a loop on the device that makes as many trips
+as the rows that came need: the layer runs at the smallest capacity of a
+ladder the configuration gives (``capacity_ladder``: the share and its
+multiples up to ``T * k``) that holds them, each device of a mesh for its own
+rows.  The last rung is the whole buffer, so nothing is dropped, nothing is
+approximated and nothing stands in for the absent chips or for their
+exchange.  The loop has a differentiation rule of its own (``_through_held``):
+reverse mode cannot pass through a trip count found on the device, and the
+rule's backward is the same loop with a piece's forward recomputed and
+transposed.  It is one body whatever the rows: a ``lax.switch`` over a body a
+capacity made the cell's programs outgrow the compile cache (``PERF.md``,
+PR 32).  What ``ep > 1`` on a mesh still lacks is that exchange — the
+all-to-all that sends each token's rows to the device holding its expert and
+brings the results back — and it raises ``NotImplementedError``.
 
 ``MoEMlpBlock`` — the older GShard / Switch form, wired into GPT-2 only
 (``GPT2Config.moe_every``): top-k routing as DENSE dispatch / combine einsums
@@ -49,7 +60,7 @@ from __future__ import annotations
 
 import functools
 from dataclasses import dataclass
-from typing import Any, Optional, Tuple
+from typing import Any, NamedTuple, Optional, Tuple
 
 import flax.linen as nn
 import jax
@@ -204,9 +215,9 @@ def _tgmm_tiling(m: int, k: int, n: int):
 def _gmm(lhs, rhs, sizes, *, transpose_rhs=False):
     """``lhs[rows of group e] @ rhs[e]`` (``rhs[e].T`` if ``transpose_rhs``)
     by the megablox kernel.  It wants the row count a multiple of the row
-    tile: rows are padded behind the last group and cut off again.  Where
-    ``sizes`` counts more groups than ``rhs`` has matrices, the rows of the
-    groups past the last matrix are not visited and come back zero."""
+    tile: rows are padded behind the last group and cut off again.  Rows
+    past the last group are not visited: what comes back there is not
+    written."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
 
     m, k = lhs.shape
@@ -218,9 +229,10 @@ def _gmm(lhs, rhs, sizes, *, transpose_rhs=False):
     return out[:m]
 
 
-def _tgmm(lhs, grad, sizes, dtype, n_groups):
+def _tgmm(lhs, grad, sizes, dtype, n_groups, onto=None):
     """Per group ``lhs[rows].T @ grad[rows]``: the weights' gradient,
-    ``(n_groups, k, n)``, for the first ``n_groups`` of ``sizes``."""
+    ``(n_groups, k, n)``, for the first ``n_groups`` of ``sizes``; added to
+    ``onto`` where that is given."""
     from jax.experimental.pallas.ops.tpu.megablox.gmm import tgmm
 
     m, k = lhs.shape
@@ -229,15 +241,14 @@ def _tgmm(lhs, grad, sizes, dtype, n_groups):
         lhs, grad = (jnp.pad(a, ((0, -m % tiling[0]), (0, 0)))
                      for a in (lhs, grad))
     return tgmm(lhs.T, grad, sizes, preferred_element_type=dtype,
-                tiling=tiling, num_actual_groups=n_groups,
+                tiling=tiling, num_actual_groups=n_groups, existing_out=onto,
                 interpret=_interpret())
 
 
 @jax.custom_vjp
 def grouped_matmul(lhs, rhs, sizes):
     """``lhs`` (N, k) in contiguous groups of ``sizes`` (E,) rows, each group
-    times its own ``rhs[e]`` (E, k, n) -> (N, n).  ``sizes`` may count one
-    group more than ``rhs`` holds: see ``_gmm``."""
+    times its own ``rhs[e]`` (E, k, n) -> (N, n)."""
     return _gmm(lhs, rhs, sizes)
 
 
@@ -285,33 +296,21 @@ _permute_rows.defvjp(lambda rows, perm, inverse: (rows[perm], inverse),
 
 
 def routed_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig):
-    """One device's tokens through their experts.  x (..., D); weights, idx
+    """One device's tokens through all the experts.  x (..., D); weights, idx
     (..., k): each token's gate weights and chosen experts; gate, up
-    (E, D, F) and down (E, F, D) in the compute dtype — E the experts held
-    (``cfg.experts_held``), whose part of the result this is."""
+    (E, D, F) and down (E, F, D) in the compute dtype.  Every one of the
+    ``T * k`` buffer rows holds a token: one pass over the whole of it."""
     lead, d = x.shape[:-1], x.shape[-1]
-    k, n_groups = cfg.top_k, cfg.n_experts
+    k = cfg.top_k
     x = x.reshape(-1, d)
     with jax.named_scope("dispatch"):
         flat = idx.reshape(-1)
-        if cfg.experts_held is not None:
-            # held experts by their local index; every absent expert's rows
-            # in one last group, behind them, that has no matrix
-            first, n_held = cfg.experts_held
-            flat = jnp.where((flat >= first) & (flat < first + n_held),
-                             flat - first, n_held)
-            n_groups = n_held + 1
         order = jnp.argsort(flat, stable=True)
         inverse = jnp.zeros_like(order).at[order].set(
             jnp.arange(order.shape[0], dtype=order.dtype))
-        sizes = jnp.sum(flat[:, None] == jnp.arange(n_groups), axis=0,
+        sizes = jnp.sum(flat[:, None] == jnp.arange(cfg.n_experts), axis=0,
                         dtype=jnp.int32)
         rows = _rows_to_expert_order(x, order, inverse, k)
-        if cfg.experts_held is not None:
-            # the buffer holds T * k rows, the worst case; past the held
-            # experts' rows it is empty, not other chips' tokens
-            rows = jnp.where((jnp.arange(rows.shape[0]) < jnp.sum(
-                sizes[:-1]))[:, None], rows, jnp.zeros_like(rows))
     with jax.named_scope("experts"):
         h = jax.nn.silu(grouped_matmul(rows, gate, sizes)) \
             * grouped_matmul(rows, up, sizes)
@@ -321,6 +320,179 @@ def routed_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig):
         out = jnp.sum(rows.astype(jnp.float32)
                       * weights.reshape(-1, k, 1), axis=1)
     return out.astype(cfg.dtype).reshape(*lead, d)
+
+
+# ------------------------------------- a layer that holds a part of its experts
+def capacity_ladder(n_rows: int, n_held: int, n_experts: int):
+    """The row capacities a layer holding ``n_held`` of ``n_experts`` may run
+    at, ascending, from the configuration alone: the balance share of the
+    ``n_rows`` = ``T * k`` assignments (whole row tiles of the grouped
+    matmul) and its multiples up to the first that holds ``n_rows`` — every
+    assignment may fall to a held expert, so no routing is ever refused."""
+    share = max(n_rows * n_held // n_experts, 1)
+    share = _round_up(share, _gmm_tiling(share, 1, 1)[0])
+    return tuple(share * (i + 1) for i in range(-(-n_rows // share)))
+
+
+class _Route(NamedTuple):
+    """Where the held experts' assignments stand: indices, at ``T * k``."""
+    order: Any      # the assignment (token * k + slot) in buffer row j: the
+                    # held experts' alone, by expert, a group in token order
+    sizes: Any      # (n_held,): the assignments each held expert received
+
+
+class _Piece(NamedTuple):
+    """One share-sized piece of the buffer: its ``c`` rows."""
+    slots: Any      # (c,) the assignments of the piece's rows
+    live: Any       # (c,) the rows an assignment stands in: the first ones
+    sizes: Any      # (n_held,) each held expert's rows inside the piece
+
+
+def _route(flat, n_held: int, n_rows: int) -> _Route:
+    """``flat`` (T * k,): each assignment's held expert by its local index,
+    ``n_held`` for an absent one -> the held assignments in expert order, in
+    a buffer of ``n_rows`` rows.  A counting sort, a key a held expert: an
+    assignment's row is its expert's first row plus the assignments to that
+    expert before it."""
+    hot = flat[:, None] == jnp.arange(n_held)
+    sizes = jnp.sum(hot, axis=0, dtype=jnp.int32)
+    row = jnp.sum(jnp.where(hot, jnp.cumsum(sizes) - sizes + jnp.cumsum(
+        hot, axis=0, dtype=jnp.int32) - 1, 0), axis=1)
+    # (an absent expert's assignment has no row: past the end, dropped)
+    order = jnp.zeros((n_rows,), jnp.int32).at[
+        jnp.where(flat < n_held, row, n_rows)].set(
+            jnp.arange(flat.shape[0], dtype=jnp.int32), mode="drop")
+    return _Route(order, sizes)
+
+
+def _piece(route: _Route, i, c: int) -> _Piece:
+    """Piece ``i`` of the buffer, rows ``i * c`` to ``i * c + c``."""
+    lo, ends = i * c, jnp.cumsum(route.sizes)
+    return _Piece(
+        jax.lax.dynamic_slice(route.order, (lo,), (c,)),
+        lo + jnp.arange(c) < ends[-1],
+        jnp.clip(ends, lo, lo + c) - jnp.clip(ends - route.sizes, lo, lo + c))
+
+
+def _piece_forward(x, weights, route: _Route, i, c: int, k: int):
+    """The piece's rows through their experts: the token rows gathered, the
+    three grouped matmuls over ``c`` rows, ``silu * up``.  Rows past the live
+    ones belong to no group: the kernels leave them unwritten."""
+    gate, up, down = weights
+    piece = _piece(route, i, c)
+    with jax.named_scope("dispatch"):
+        rows = x[piece.slots // k]
+    a, b = _gmm(rows, gate, piece.sizes), _gmm(rows, up, piece.sizes)
+    h = jax.nn.silu(a) * b
+    return piece, rows, (a, b), h, _gmm(h, down, piece.sizes)
+
+
+def _onto_tokens(acc, rows, piece: _Piece, k: int):
+    """``acc`` (T, D) float32 plus each token's live rows of the piece
+    (``rows`` (c, D) float32, in the piece's order)."""
+    return acc.at[piece.slots // k].add(
+        jnp.where(piece.live[:, None], rows, 0))
+
+
+def _n_pieces(route: _Route, c: int):
+    return (jnp.sum(route.sizes) + c - 1) // c
+
+
+# The pieces are a loop on the device whose trip count follows the rows that
+# came, which reverse mode cannot pass through; and its residuals would be
+# every piece's.  So the held experts' part has a rule of its own, which
+# saves what it was given and runs the loop again in its backward: a piece's
+# forward recomputed, then transposed.
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
+def _through_held(x, gates, weights, route: _Route, c: int, k: int):
+    """(T, D) tokens -> (T, D): each token's rows through the held experts,
+    times their gate weights (``gates`` (T * k,), by assignment), added in
+    float32.  ``c`` rows at a time, as many times as the rows that came
+    need."""
+    def body(i, acc):
+        piece, _, _, _, out = _piece_forward(x, weights, route, i, c, k)
+        with jax.named_scope("combine"):
+            return _onto_tokens(acc, out.astype(jnp.float32)
+                                * gates[piece.slots][:, None], piece, k)
+
+    return jax.lax.fori_loop(
+        0, _n_pieces(route, c), body,
+        jnp.zeros(x.shape, jnp.float32)).astype(x.dtype)
+
+
+def _through_held_fwd(x, gates, weights, route, c, k):
+    return (_through_held(x, gates, weights, route, c, k),
+            (x, gates, weights, route))
+
+
+def _through_held_bwd(c, k, res, g):
+    x, gates, weights, route = res
+    gate, up, down = weights
+    n_held = gate.shape[0]
+
+    def body(i, carry):
+        dx, dgates, (dgate, dup, ddown) = carry
+        piece, rows, (a, b), h, out = _piece_forward(x, weights, route, i, c,
+                                                     k)
+        with jax.named_scope("combine"):
+            # the transpose of the weighted sum: a live row's gradient is its
+            # gate times its token's ``g``, a gate's its row times ``g``
+            g_rows = g[piece.slots // k].astype(jnp.float32)
+            dgates = dgates.at[piece.slots].add(jnp.where(
+                piece.live, jnp.sum(out.astype(jnp.float32) * g_rows, axis=1),
+                0))
+            d_out = (g_rows * jnp.where(piece.live, gates[piece.slots], 0)[
+                :, None]).astype(out.dtype)
+        # (an expert's gradient is one kernel's float32 sum, rounded once,
+        # unless its rows straddle two pieces: then once more in between)
+        d_h = _gmm(d_out, down, piece.sizes, transpose_rhs=True)
+        ddown = _tgmm(h, d_out, piece.sizes, down.dtype, n_held, onto=ddown)
+        d_a, d_b = jax.vjp(lambda a, b: jax.nn.silu(a) * b, a, b)[1](d_h)
+        d_rows = _gmm(d_a, gate, piece.sizes, transpose_rhs=True) \
+            + _gmm(d_b, up, piece.sizes, transpose_rhs=True)
+        dgate = _tgmm(rows, d_a, piece.sizes, gate.dtype, n_held, onto=dgate)
+        dup = _tgmm(rows, d_b, piece.sizes, up.dtype, n_held, onto=dup)
+        with jax.named_scope("dispatch"):
+            # a token's gradient: the float32 sum of its live rows'
+            dx = _onto_tokens(dx, d_rows.astype(jnp.float32), piece, k)
+        return dx, dgates, (dgate, dup, ddown)
+
+    dx, dgates, dweights = jax.lax.fori_loop(
+        0, _n_pieces(route, c), body,
+        (jnp.zeros(x.shape, jnp.float32), jnp.zeros_like(gates),
+         tuple(jnp.zeros_like(w) for w in weights)))
+    return dx.astype(x.dtype), dgates, dweights, None
+
+
+_through_held.defvjp(_through_held_fwd, _through_held_bwd)
+
+
+def held_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig):
+    """One device's tokens through the experts the layer holds
+    (``cfg.experts_held``) -> (their part of the result, the rows the layer
+    ran at).  The assignments are counted and placed at ``T * k``, as
+    indices; everything as wide as the model or an expert — the gather of
+    token rows, the grouped matmuls, ``silu * up``, the weighted sum into the
+    tokens — runs on pieces of ``capacity_ladder``'s first rung, the balance
+    share, in a loop on the device that makes as many trips as the rows that
+    came need: the layer runs at the smallest rung that holds them."""
+    lead, d = x.shape[:-1], x.shape[-1]
+    k, (first, n_held) = cfg.top_k, cfg.experts_held
+    x = x.reshape(-1, d)
+    with jax.named_scope("dispatch"):
+        flat = idx.reshape(-1)
+        # held experts by their local index; every absent expert's
+        # assignments are no row of the buffer
+        flat = jnp.where((flat >= first) & (flat < first + n_held),
+                         flat - first, n_held)
+        ladder = capacity_ladder(flat.shape[0], n_held, cfg.n_experts)
+        route = _route(flat, n_held, ladder[-1])
+    with jax.named_scope("experts"):
+        out = _through_held(x, weights.reshape(-1), (gate, up, down), route,
+                            ladder[0], k)
+    return (out.reshape(*lead, d),
+            (_n_pieces(route, ladder[0]) * ladder[0]).astype(
+                jnp.float32).reshape((1,) * len(lead) + (1,)))
 
 
 def token_spec(mesh):
@@ -344,7 +516,9 @@ class RoutedSwiGLU(nn.Module):
     ``P_e`` its mean router probability; ``top_k`` at balance), ``moe_z`` (mean of ``logsumexp(router logits) ** 2``) and
     ``moe_max_load`` (the busiest expert's assignments over the mean), each
     over all ``n_experts``; and, where the layer holds a part of them,
-    ``moe_rows_held``: the assignments its own experts received."""
+    ``moe_rows_held``: the assignments its own experts received, and
+    ``moe_buffer_rows``: the capacity the layer ran at for them (over a mesh,
+    the devices' capacities together)."""
 
     config: RoutedConfig
 
@@ -399,20 +573,29 @@ class RoutedSwiGLU(nn.Module):
         with jax.named_scope("experts"):    # the casts are the experts' cost
             gate, up, down = (w.astype(cfg.dtype) for w in (gate, up, down))
 
+        # a layer that holds all its experts fills its buffer by construction
+        held = n_held < n_experts
+
         def experts(x, weights, idx, gate, up, down):
-            return routed_experts(x, weights, idx, gate, up, down, cfg)
+            return (held_experts if held else routed_experts)(
+                x, weights, idx, gate, up, down, cfg)
 
         if mesh is None or mesh.size == 1:
-            return experts(x, weights, idx, gate, up, down)
-        # each device routes its own tokens through all the experts: the
-        # weights come in whole (GSPMD gathers their fsdp / tp shards)
-        from jax.sharding import PartitionSpec as P
+            out = experts(x, weights, idx, gate, up, down)
+        else:
+            # each device routes its own tokens through all the experts: the
+            # weights come in whole (GSPMD gathers their fsdp / tp shards)
+            from jax.sharding import PartitionSpec as P
 
-        tokens = token_spec(mesh)
-        return jax.shard_map(
-            experts, mesh=mesh, in_specs=(tokens,) * 3 + (P(),) * 3,
-            out_specs=tokens, check_vma=False)(
-                x, weights, idx, gate, up, down)
+            tokens = token_spec(mesh)
+            out = jax.shard_map(
+                experts, mesh=mesh, in_specs=(tokens,) * 3 + (P(),) * 3,
+                out_specs=(tokens, tokens) if held else tokens,
+                check_vma=False)(x, weights, idx, gate, up, down)
+        if held:    # every device's own capacity, from its own rows
+            out, buffer_rows = out
+            self.sow("intermediates", "moe_buffer_rows", jnp.sum(buffer_rows))
+        return out
 
 
 def collect_aux(intermediates, aux_weight: float = 0.0, z_weight: float = 0.0):
@@ -420,8 +603,8 @@ def collect_aux(intermediates, aux_weight: float = 0.0, z_weight: float = 0.0):
     extra term, the step's statistics).  ``MoEMlpBlock`` sows its term
     already weighted; ``RoutedSwiGLU``'s two losses are averaged over the
     routed layers and weighted here; ``max_load`` is the worst layer's and
-    ``moe_rows_held``, where the layers hold a part of their experts, a
-    layer's, averaged over them."""
+    ``moe_rows_held`` and ``moe_buffer_rows``, where the layers hold a part
+    of their experts, a layer's, averaged over them."""
     by_name: dict = {}
     # sow keeps a tuple under each name: (..., "h_3", "moe", "moe_z", 0)
     for path, leaf in jax.tree_util.tree_flatten_with_path(intermediates)[0]:
@@ -433,8 +616,9 @@ def collect_aux(intermediates, aux_weight: float = 0.0, z_weight: float = 0.0):
         stats = {"load_balance": sum(by_name["moe_load_balance"]) / n,
                  "z": sum(by_name["moe_z"]) / n,
                  "max_load": jnp.max(jnp.stack(by_name["moe_max_load"]))}
-        if "moe_rows_held" in by_name:    # a layer's, averaged over them
-            stats["moe_rows_held"] = sum(by_name["moe_rows_held"]) / n
+        for name in ("moe_rows_held", "moe_buffer_rows"):
+            if name in by_name:    # a layer's, averaged over them
+                stats[name] = sum(by_name[name]) / n
         total = total + aux_weight * stats["load_balance"] \
             + z_weight * stats["z"]
     return total, stats

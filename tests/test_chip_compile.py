@@ -271,14 +271,17 @@ def test_sdar_step_lowers_for_one_v5e_chip():
 @pytest.mark.slow
 def test_sdar_step_compiles_and_fits_the_chip():
     """The TPU compiler takes the block-mask kernels at 2 x 4096 queries over
-    4096 keys and the grouped matmuls over 131,072 buffer rows, and its
-    memory analysis says six layers fit one chip (PR 31: see PERF.md)."""
+    4096 keys and the grouped matmuls over the 16,384 rows of a piece of the
+    buffer, inside the loops whose trips the device counts, and its memory
+    analysis says six layers fit one chip (PR 31, PR 32: see PERF.md)."""
     row = _child(["sdar"], compile_=True)["sdar"]
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row
     # a layer: flash forward, its recomputation, the backward's one kernel;
-    # the grouped matmul: gate, up, down forward, recomputed, and two
-    # backward calls each
+    # the grouped matmul: gate, up, down in the forward's loop, and in the
+    # backward's the three recomputed and two transposes each — the
+    # checkpoint's recomputation of the forward's loop is dropped, nothing
+    # reads it (PR 32)
     assert row["tpu_custom_calls"] == 6 * (3 + 12), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 

@@ -115,7 +115,8 @@ def test_g_train_step_draws_another_noise_every_step_and_learns():
         state, loss, stats = step(state, batch)
         assert float(loss) == pytest.approx(float(want), rel=1e-5)
         assert set(stats) == {"load_balance", "z", "max_load",
-                              "moe_rows_held"}
+                              "moe_rows_held", "moe_buffer_rows"}
+        assert stats["moe_rows_held"] <= stats["moe_buffer_rows"] <= 2 * 96 * 2
         seen.append(float(loss))
     assert len(set(seen)) == 3
     fixed = _program()[2]
@@ -131,7 +132,8 @@ def test_g_train_step_draws_another_noise_every_step_and_learns():
 def test_h_a_sharded_mesh_gives_the_single_device_loss(mesh):
     """On a virtual CPU mesh the step — the noise drawn under the layout, the
     block mask inside ``shard_map``, each device routing its own rows through
-    the held experts — gives the losses and the statistics of one device."""
+    the held experts in a buffer of its own capacity — gives the losses and
+    the statistics of one device."""
     from ray_tpu.models.pretrain import ShardedPretrainer
     from ray_tpu.parallel.mesh import MeshConfig
 
@@ -145,5 +147,17 @@ def test_h_a_sharded_mesh_gives_the_single_device_loss(mesh):
         assert float(many.step(rows)) == pytest.approx(float(one.step(rows)),
                                                        rel=1e-5)
     for name, value in one.moe_stats.items():
+        if name == "moe_buffer_rows":   # each device's own rung, together
+            continue
         assert float(many.moe_stats[name]) == pytest.approx(float(value),
                                                             rel=1e-4), name
+    # every device picks a capacity for its own rows, with no collective:
+    # the four buffers together hold the rows that came and are four rungs
+    # of the quarter-size ladder, not the one device's rung
+    from ray_tpu.models.moe import capacity_ladder
+
+    rows, buffers = (float(many.moe_stats[name]) for name in (
+        "moe_rows_held", "moe_buffer_rows"))
+    ladder = capacity_ladder(2 * 64 * 2, 4, 8)      # a device: one row
+    assert rows <= buffers <= 4 * ladder[-1]
+    assert 4 * ladder[0] <= buffers

@@ -20,9 +20,10 @@ from perfbench.harness import families, reference
 from perfbench.harness.families import sdar_moe
 from perfbench.harness.tokens import ZipfStream
 from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
+from ray_tpu.models.moe import (RoutedConfig, RoutedSwiGLU,
+                                capacity_ladder)
 from ray_tpu.models.pretrain import (init_params, loss_fn, make_optimizer,
-                                     noise_blocks, train_step)
+                                     noise_blocks, objective_fn, train_step)
 from ray_tpu.ops.attention import (block_diffusion_mask, flash_attention,
                                    mha_reference)
 
@@ -143,6 +144,141 @@ def test_e_no_token_is_dropped_at_either_extreme(favoured, rows_held):
         str(jax.make_jaxpr(part)(other, x))
 
 
+def _routed_to_held(n):
+    """Inputs and a share of the toy layer (experts 0-1 of 8 held, top-2 over
+    2 x 44 tokens: 176 assignments) whose router sends exactly ``n``
+    assignments to held experts: channel 2 flags the ``n // 2`` tokens that
+    go to experts 0 and 1, channel 1 the ``n % 2`` that go to 0 and 4,
+    channel 0 the rest, which go to 4 and 5."""
+    x, params = _whole_layer_params()
+    both, one = n // 2, n % 2
+    kind = jnp.where(jnp.arange(88) < both, 2,
+                     jnp.where(jnp.arange(88) < both + one, 1, 0))
+    x = jnp.abs(x).at[..., :3].set(
+        8.0 * jax.nn.one_hot(kind, 3).reshape(2, 44, 3))
+    router = jnp.zeros((64, 8)).at[0, 4:6].set(1.0).at[1, (0, 4)].set(
+        1.0).at[2, 0:2].set(1.0)
+    return x, _share(dict(params, router={"kernel": router}), 0, 2)
+
+
+# a share of 44 where the toy's balance is 88: four rungs, so that the
+# loop's second and later trips are walked too
+_LADDER = capacity_ladder(176, 2, 8)
+
+
+def test_j_the_ladder_is_the_configurations_own():
+    """The balance share and its multiples up to the whole buffer: 16,384 to
+    131,072 in eight rungs at the cell's shapes; small shares are rounded up
+    to whole row tiles of the grouped matmul, and the last rung holds every
+    assignment whatever the rounding."""
+    assert capacity_ladder(16384 * 8, 16, 128) == tuple(
+        16384 * i for i in range(1, 9))
+    assert _LADDER == (48, 96, 144, 192)
+    assert capacity_ladder(8 * 8, 16, 128) == (8,) * 0 + tuple(
+        range(8, 65, 8))
+    assert capacity_ladder(4, 1, 128) == (8,)
+    for rows, held, experts in ((176, 4, 8), (100, 3, 7), (4096, 16, 128)):
+        ladder = capacity_ladder(rows, held, experts)
+        assert ladder[-1] >= rows > ladder[-1] - ladder[0]
+
+
+@pytest.mark.parametrize("n", [0, 1, _LADDER[0] - 1, _LADDER[0],
+                               _LADDER[0] + 1, _LADDER[1], 176])
+def test_k_every_edge_of_the_ladder_is_the_reference(n):
+    """Exactly ``n`` assignments to the held experts, around every capacity:
+    the part and the gradients of ``x`` and of the three matrices equal the
+    reference's, the counter is ``n``, the layer runs at the smallest rung
+    that holds ``n`` (no piece at all for no row) — and it is one program for
+    every ``n``."""
+    x, share = _routed_to_held(n)
+    layer, config = _layer((0, 2)), dict(TOY, num_experts=8)
+
+    def part(p, x):
+        return layer.apply({"params": p}, x, mutable=["intermediates"])
+
+    def loss(of):
+        return lambda p, x: jnp.sum(jnp.sin(of(p, x)))
+
+    with jax.default_matmul_precision("highest"):
+        got, sown = part(share, x)
+        want = sdar_moe.routed_part(x, share, config, 0)[0]
+        grads = jax.grad(loss(lambda p, x: part(p, x)[0]), (0, 1))(share, x)
+        want_grads = jax.grad(loss(lambda p, x: sdar_moe.routed_part(
+            x, p, config, 0)[0]), (0, 1))(share, x)
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    sown = sown["intermediates"]
+    assert float(sown["moe_rows_held"][0]) == n
+    assert float(sown["moe_buffer_rows"][0]) == min(
+        c for c in (0,) + _LADDER if c >= n)
+    for name in ("gate_proj", "up_proj", "down_proj", "router"):
+        # (the flagged channels make some sums hundreds large: rtol)
+        np.testing.assert_allclose(
+            *(jax.tree.leaves(g[0][name]) for g in (grads, want_grads)),
+            atol=1e-4, rtol=1e-5)
+    np.testing.assert_allclose(grads[1], want_grads[1], atol=1e-4, rtol=1e-5)
+    assert str(jax.make_jaxpr(part)(share, x)) == \
+        str(jax.make_jaxpr(part)(*_routed_to_held(7)[::-1]))
+
+
+def _walk(jaxpr, found, in_loop=False):
+    """Every equation of ``jaxpr`` and of the jaxprs inside it (not inside a
+    kernel): ``found`` gets, for each ``while`` with a trip count found on
+    the device, the Mosaic calls of its body (``loops``), the ``cond``s
+    (``switches``), and the primitives that give what ``_buffer_wide`` names
+    (``wide``), wherever they stand."""
+    for eqn in jaxpr.eqns:
+        name = eqn.primitive.name
+        if any(_buffer_wide(v.aval) for v in eqn.outvars):
+            found["wide"].append(name)
+        if name == "pallas_call":
+            if in_loop:
+                found["loops"][-1] += 1
+            continue
+        if name == "cond":
+            found["switches"].append(len(eqn.params["branches"]))
+        if name == "while":
+            found["loops"].append(0)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            _walk(getattr(sub, "jaxpr", sub), found, in_loop or name == "while")
+
+
+def _buffer_wide(aval, rows=176, k=2, widths=(64, 32)):
+    """A float array with a row for every one of the ``T * k`` assignments,
+    as wide as the model or as an expert."""
+    shape = getattr(aval, "shape", ())
+    return (shape[:1] == (rows,) or shape[:2] == (rows // k, k)) \
+        and shape[-1] in widths and len(shape) > 1 \
+        and jnp.issubdtype(aval.dtype, jnp.floating)
+
+
+def test_l_no_row_is_moved_at_the_worst_cases_size():
+    """The mechanism, not its speed: in the held layer's ``value_and_grad``
+    no array as wide as the model or an expert has a row for every
+    assignment, anywhere; whatever is that wide is inside a loop whose trip
+    count the device finds, at one piece's rows: the forward's loop with the
+    three grouped matmuls, and the backward's with the three recomputed and
+    their six transposes — and no third: where the checkpoint recomputes the
+    forward nothing reads the rule's result, and its loop is not there.  A layer that holds every expert has no loop and does pass
+    over all ``T * k`` rows."""
+    x, share = _routed_to_held(7)
+    layer = _layer((0, 2))
+
+    def loss(p, x):
+        return jnp.sum(jnp.sin(jax.checkpoint(
+            lambda p, x: layer.apply({"params": p}, x))(p, x)))
+
+    found = {"wide": [], "switches": [], "loops": []}
+    _walk(jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(share, x).jaxpr,
+          found)
+    assert found["wide"] == [] and found["switches"] == [], found
+    assert found["loops"] == [3, 9], found
+    whole = {"wide": [], "switches": [], "loops": []}
+    _walk(jax.make_jaxpr(jax.value_and_grad(lambda p, x: jnp.sum(
+        _layer(None).apply({"params": p}, x))))(
+            _whole_layer_params()[1], x).jaxpr, whole)
+    assert whole["loops"] == [] and whole["wide"]    # the walk does see
+
+
 def test_f_the_noising_masks_a_share_t_of_each_block_and_weighs_by_1_over_t():
     """Blocks of 32 over 64 rows of 4096: every block's weights are 0 or one
     value 1/t with t in (t_min, 1]; over the blocks the masked share follows
@@ -250,7 +386,32 @@ def test_i_every_model_the_benchmark_has_is_the_program_it_was(name):
             == n_params
         assert float(loss_fn(model, params, batch)).hex() == want, impl
     tx = make_optimizer()
-    text = str(jax.make_jaxpr(lambda s, b: train_step(model, tx, s, b))(
-        (params, tx.init(params)), batch))
+    step = jax.make_jaxpr(lambda s, b: train_step(model, tx, s, b))(
+        (params, tx.init(params)), batch)
+    text = str(step)
     for absent in ("noise", "random_bits", "threefry", "bd_diagonal"):
         assert absent not in text, absent
+    # a layer that holds all its experts has no capacity to choose: no
+    # branch outside the kernels (the toy OLMoE step's jaxpr equals the
+    # parent's character for character: checked by hand in PR 32)
+    found = {"wide": [], "switches": [], "loops": []}
+    _walk(step.jaxpr, found)
+    assert found["switches"] == [] and found["loops"] == [], name
+
+
+@pytest.mark.parametrize("impl,want", [("reference", "0x1.5bfd300000000p+2"),
+                                       ("flash", "0x1.5c2ed60000000p+2")])
+def test_m_the_sdar_toy_has_the_loss_it_had_with_the_whole_buffer(impl, want):
+    """The toy's objective at ``PRNGKey(0)`` weights under the noise of
+    ``PRNGKey(0)``, on ``ZipfStream(held vocabulary, seed=5).rows(2, 48)``,
+    against the parent commit's (6530a06: every layer passing over all
+    ``T * k`` rows): the order of a token's sum may change, the number may
+    not."""
+    cfg = dataclasses.replace(sdar_moe.model_config(TOY, 1),
+                              attention_impl=impl)
+    model, params = init_params(cfg)
+    batch = {k: jnp.asarray(v) for k, v in ZipfStream(
+        cfg.vocab_size, seed=5).rows(2, 48).items()}
+    loss, stats = objective_fn(model, params, batch, jax.random.PRNGKey(0))[1]
+    assert float(loss) == pytest.approx(float.fromhex(want), rel=1e-6)
+    assert stats["moe_rows_held"] <= stats["moe_buffer_rows"] <= 2 * 96 * 2
