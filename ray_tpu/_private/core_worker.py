@@ -580,6 +580,9 @@ class CoreWorker:
             self.executor_pool.shutdown(wait=False)
         self._get_pool.shutdown(wait=False)
         self.io.stop()
+        # the ring stays on disk with its session; a later init() in this
+        # process opens its own, under the new session's directory
+        flight_recorder.shutdown()
 
     # ============================================================== pub/sub
     async def _on_publish(self, conn, msg):
@@ -2280,6 +2283,7 @@ class CoreWorker:
             self._cancelled_exec.discard(tkey)
 
     def _create_actor_sync(self, spec: TaskSpec) -> dict:
+        t_create = time.perf_counter()
         try:
             from ray_tpu import runtime_env as renv
 
@@ -2303,6 +2307,11 @@ class CoreWorker:
             # always restore: a failed constructor must not leave the
             # creation span as this executor thread's ambient context
             _trace_ctx.reset(trace_token)
+        if flight_recorder.RECORDING:
+            # the class unpickled — its module's imports, which for a train
+            # worker are the train package's, jax among them — and built
+            flight_recorder.mark("bringup.worker.actor",
+                                 time.perf_counter() - t_create, spec.name)
         self.actor_id = spec.actor_creation_id
         self.job_id = spec.job_id
         if spec.max_concurrency > 1:

@@ -26,17 +26,31 @@ Enabled per-process by :func:`init_process` (core workers and nodelets call
 it at startup); sized by the ``flight_recorder_bytes`` flag (0 disables).
 Call sites guard with ``if flight_recorder.RECORDING:`` so a disabled
 recorder costs one module-attribute check.
+
+Timed marks.  A record whose detail starts with a duration —
+``<seconds>|<rest>`` — is an interval that *ends* at the record's stamp
+(:func:`mark`, and :func:`timed` around a block).  Every stamp is
+``time.time()``, the one clock all processes of a host share, so the marks
+of a driver, a nodelet and a worker nest by time though no span crosses a
+process.  A job's start is written this way (kinds ``bringup.*``: the table
+is in docs/ARCHITECTURE.md 5e) and so are the train worker's compile events
+(kind ``compile``); :func:`bringup_timeline` reads them back from every ring
+of a session.  Marks made before a process has its ring (a driver before
+its core worker, a worker before its own) wait in a short list and are
+written, with their own stamps, by :func:`init_process`.
 """
 
 from __future__ import annotations
 
+import contextlib
+import glob
 import math
 import mmap
 import os
 import struct
 import threading
 import time
-from typing import Dict, List, Optional
+from typing import Dict, Iterator, List, Optional, Tuple
 
 FILE_MAGIC = b"RTFR"
 VERSION = 1
@@ -54,6 +68,15 @@ _cursor = 0  # offset into the data region (after the header)
 _seq = 0
 _path: Optional[str] = None
 _m_records = None
+# marks made before this process has a ring: (kind, detail, ts), oldest
+# first, written by init_process; bounded, so a process that never opens a
+# ring (recorder off, a plain script) keeps a few hundred bytes
+_pending: List[Tuple[str, str, float]] = []
+_PENDING_MAX = 64
+
+# A mark: (process name, kind, start, end, detail), times on time.time()
+Mark = Tuple[str, str, float, float, str]
+ENTERED = "bringup.worker.train_fn_enter"
 
 
 def ring_path(session_dir: str, name: str) -> str:
@@ -73,6 +96,8 @@ def init_process(session_dir: str, name: str) -> bool:
 
     size = int(RayConfig.flight_recorder_bytes)
     if size <= 0 or not session_dir:
+        if _mm is None:
+            _pending.clear()    # off: what waited for a ring is dropped
         return RECORDING
     with _lock:
         if _mm is not None:
@@ -102,21 +127,25 @@ def init_process(session_dir: str, name: str) -> bool:
                 "flight-recorder records appended to this process's "
                 "crash-surviving ring file, by record kind")
         RECORDING = True
+        waited, _pending[:] = list(_pending), []
+    for kind, detail, ts in waited:
+        record(kind, detail, ts)
     record("recorder.init", name)
     return True
 
 
-def record(kind: str, detail: str = "") -> None:
-    """Append one record.  Pure memory writes into the mmap — the kernel
-    flushes the dirty page on its own schedule (and at process death), so
-    the hot path never issues a syscall."""
+def record(kind: str, detail: str = "", ts: Optional[float] = None) -> None:
+    """Append one record, stamped ``ts`` (default: now).  Pure memory writes
+    into the mmap — the kernel flushes the dirty page on its own schedule
+    (and at process death), so the hot path never issues a syscall."""
     global _cursor, _seq
     mm = _mm
     if mm is None:
         return
     payload = f"{kind}|{detail}".encode("utf-8", "replace")[:MAX_PAYLOAD]
     need = REC_HEAD.size + len(payload)
-    ts = time.time()
+    if ts is None:
+        ts = time.time()
     with _lock:
         if _mm is None:  # closed between the guard and the lock
             return
@@ -137,12 +166,38 @@ def record(kind: str, detail: str = "") -> None:
         _m_records.inc(1, {"kind": kind})
 
 
+def mark(kind: str, seconds: float, detail: str = "") -> None:
+    """One timed record: an interval of ``seconds`` that ends now, the
+    duration first in the detail.  Before this process has a ring the mark
+    waits (see the module docstring)."""
+    ts = time.time()
+    detail = f"{seconds:.6f}|{detail}" if detail else f"{seconds:.6f}"
+    if _mm is None:
+        if len(_pending) < _PENDING_MAX:
+            _pending.append((kind, detail, ts))
+        return
+    record(kind, detail, ts)
+
+
+@contextlib.contextmanager
+def timed(kind: str, detail: str = "") -> Iterator[None]:
+    """Time the block (``perf_counter``) and write one :func:`mark` at its
+    exit — also where the block raises: a phase that failed is the one an
+    operator looks for."""
+    t0 = time.perf_counter()
+    try:
+        yield
+    finally:
+        mark(kind, time.perf_counter() - t0, detail)
+
+
 def shutdown() -> None:
-    """Close the ring (tests; a real crash is the point of not needing
-    this).  The file stays on disk for harvest."""
+    """Close the ring (a driver's ``shutdown()``, tests; a real crash is the
+    point of not needing this).  The file stays on disk for harvest."""
     global RECORDING, _mm, _path
     with _lock:
         RECORDING = False
+        _pending.clear()
         if _mm is not None:
             try:
                 _mm.close()
@@ -200,3 +255,46 @@ def harvest_for(session_dir: str, name: str,
                 limit: Optional[int] = None) -> List[Dict]:
     """Harvest by (session_dir, process name); [] when no ring exists."""
     return harvest(ring_path(session_dir, name), limit)
+
+
+def bringup_gap(marks: List[Mark]) -> Optional[float]:
+    """Seconds between the start of the first ``bringup.*`` mark and the last
+    ``train_fn_enter`` that no ``bringup.*`` mark of any process covers;
+    ``None`` where no train function was entered."""
+    spans = sorted((m[2], m[3]) for m in marks if m[1].startswith("bringup."))
+    entered = [m[3] for m in marks if m[1] == ENTERED]
+    if not entered:
+        return None
+    end = max(entered)
+    gap, reached = 0.0, spans[0][0]
+    for a, b in spans:
+        if a >= end:
+            break
+        if a > reached:
+            gap += a - reached
+        reached = max(reached, min(b, end))
+    return gap + max(0.0, end - reached)
+
+
+def bringup_timeline(session_dir: str
+                     ) -> Tuple[List[Mark], Optional[float]]:
+    """A job's start as the processes of ``session_dir`` wrote it: every
+    ``bringup.*`` mark and ``compile`` / ``compile.cache`` record of every
+    ring, ordered by start on the one clock, and :func:`bringup_gap` of
+    them.  A record without a leading duration (``compile.cache|hit``) is a
+    point."""
+    marks: List[Mark] = []
+    for path in glob.glob(ring_path(session_dir, "*")):
+        name = os.path.basename(path)[:-len(".ring")]
+        for r in harvest(path):
+            kind = r["kind"]
+            if not kind.startswith(("bringup.", "compile")):
+                continue
+            head, _, rest = r["detail"].partition("|")
+            try:
+                secs, detail = float(head), rest
+            except ValueError:
+                secs, detail = 0.0, r["detail"]
+            marks.append((name, kind, r["ts"] - secs, r["ts"], detail))
+    marks.sort(key=lambda m: (m[2], m[3]))
+    return marks, bringup_gap(marks)

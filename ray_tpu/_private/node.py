@@ -17,6 +17,7 @@ import threading
 import time
 from typing import Dict, Optional, Tuple
 
+from ray_tpu._private import flight_recorder
 from ray_tpu._private.config import RayConfig
 
 
@@ -121,11 +122,15 @@ class Node:
         logs = os.path.join(self.session_dir, "logs")
         os.makedirs(logs, exist_ok=True)
         if self.head:
-            self.gcs_proc, found = _spawn_and_scrape(
-                [sys.executable, "-u", "-m", "ray_tpu._private.gcs.server",
-                 "--port", "0", "--session-dir", self.session_dir],
-                {"GCS_PORT"}, os.path.join(logs, "gcs.log"), env=self._env(),
-            )
+            # a child's interpreter, imports and bind, seen from the process
+            # that waits for it (the GCS keeps no ring of its own)
+            with flight_recorder.timed("bringup.init.gcs_spawn"):
+                self.gcs_proc, found = _spawn_and_scrape(
+                    [sys.executable, "-u", "-m", "ray_tpu._private.gcs.server",
+                     "--port", "0", "--session-dir", self.session_dir],
+                    {"GCS_PORT"}, os.path.join(logs, "gcs.log"),
+                    env=self._env(),
+                )
             self.gcs_addr = ("127.0.0.1", int(found["GCS_PORT"]))
         assert self.gcs_addr is not None, "non-head Node requires gcs_addr"
         cmd = [
@@ -138,11 +143,12 @@ class Node:
         ]
         if self.object_store_memory:
             cmd += ["--object-store-memory", str(self.object_store_memory)]
-        self.nodelet_proc, found = _spawn_and_scrape(
-            cmd, {"NODELET_PORT", "NODELET_ID"},
-            os.path.join(logs, f"nodelet-{self.node_name or 'head'}.log"),
-            env=self._env(),
-        )
+        with flight_recorder.timed("bringup.init.nodelet_spawn"):
+            self.nodelet_proc, found = _spawn_and_scrape(
+                cmd, {"NODELET_PORT", "NODELET_ID"},
+                os.path.join(logs, f"nodelet-{self.node_name or 'head'}.log"),
+                env=self._env(),
+            )
         self.nodelet_addr = ("127.0.0.1", int(found["NODELET_PORT"]))
         self.node_id_hex = found["NODELET_ID"]
         return self

@@ -52,6 +52,10 @@ def _reap(procs, timeout_s: float) -> None:
             pass
 
 
+# dead workers' rings left in the session's blackbox/ (at most 256 KB each)
+_DEAD_RINGS_KEPT = 64
+
+
 class _LeaseCancelled(Exception):
     """A queued lease request was cancelled by its client."""
 
@@ -175,6 +179,9 @@ class Nodelet:
         # hang watchdog: (task_id hex, attempt) -> flag record of tasks
         # currently running past their threshold on this node
         self._suspected_hung: Dict[Tuple[str, int], dict] = {}
+        # harvested rings of dead workers, oldest first: the newest stay on
+        # disk for a reader that comes when the run is over
+        self._dead_rings: deque = deque()
 
     # ------------------------------------------------------------------ boot
     async def start(self, host: str = "127.0.0.1", port: int = 0) -> Tuple[str, int]:
@@ -954,6 +961,10 @@ class Nodelet:
         h.pid = msg.get("pid", h.pid)
         h.state = "idle"
         h.idle_since = time.monotonic()
+        if flight_recorder.RECORDING:
+            # Popen to here: the worker's interpreter, imports and connect
+            flight_recorder.mark("bringup.worker_spawn",
+                                 h.idle_since - h.started_at, wid.hex())
         self._starting_count = max(0, self._starting_count - 1)
         if h.env_key:
             self._starting_by_key[h.env_key] = max(
@@ -1183,13 +1194,18 @@ class Nodelet:
     def _harvest_blackbox(self, worker_id: bytes, reason: str):
         """Read the dead worker's crash-surviving flight-recorder ring out
         of the session dir (the kernel kept the mmap'd pages; SIGKILL could
-        not take them), then unlink it — one harvest per death."""
+        not take them).  The file stays — a job's start is read from its
+        workers' rings after they are gone
+        (``flight_recorder.bringup_timeline``) — until ``_DEAD_RINGS_KEPT``
+        later deaths have pushed it out."""
         path = flight_recorder.ring_path(self.session_dir, worker_id.hex())
         records = flight_recorder.harvest(path, limit=200)
-        try:
-            os.unlink(path)
-        except OSError:
-            pass
+        self._dead_rings.append(path)
+        while len(self._dead_rings) > _DEAD_RINGS_KEPT:
+            try:
+                os.unlink(self._dead_rings.popleft())
+            except OSError:
+                pass
         if not records:
             return None
         if flight_recorder.RECORDING:

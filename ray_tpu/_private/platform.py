@@ -13,7 +13,14 @@ Single authoritative implementation — do not copy this dance elsewhere.
 
 from __future__ import annotations
 
+import logging
 import os
+import sys
+import threading
+
+from ray_tpu._private import flight_recorder
+
+logger = logging.getLogger(__name__)
 
 # <checkout>/.jax_cache: derived from this file's own location, so every
 # process of every run in one checkout agrees on it (the path is part of what
@@ -74,3 +81,73 @@ def enable_compile_cache() -> str:
         return from_env
     jax.config.update("jax_compilation_cache_dir", _DEFAULT_COMPILE_CACHE)
     return _DEFAULT_COMPILE_CACHE
+
+
+# --- the program's one listener of JAX's compile events --------------------
+_COMPILE_EVENTS = ("/jax/core/compile/", "/jax/compilation_cache/")
+_TIME_SAVED = "compile_time_saved_sec"  # an estimate, not time that passed
+_BUILT = "backend_compile_duration"     # one executable compiled or loaded
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+# an unrolled twelve-layer trace fires hundreds of sub-millisecond events;
+# what is shorter than this is neither recorded nor counted
+_COMPILE_MIN_S = 0.05
+_RECOMPILE_WARN_S = 1.0
+_watching = False
+# JAX reports a hit or a miss (a miss only where it writes the entry) before
+# the build's own duration event, on the compiling thread
+_cache = threading.local()
+
+
+def watch_compiles() -> None:
+    """Register, once a process, the listener that turns ``jax.monitoring``'s
+    compile events into flight-recorder records (``compile``,
+    ``compile.cache``), the ``train_compiles_total`` /
+    ``train_compile_seconds`` instruments, and a warning where a function is
+    built after the session's first ``train.report``."""
+    global _watching
+    if _watching:
+        return
+    _watching = True
+    import jax
+
+    jax.monitoring.register_event_duration_secs_listener(_on_compile_seconds)
+    jax.monitoring.register_event_listener(_on_cache_event)
+
+
+def _on_cache_event(event: str, **kw) -> None:
+    state = _CACHE_EVENTS.get(event)
+    if state is None:
+        return
+    _cache.state = state
+    if flight_recorder.RECORDING:
+        flight_recorder.record("compile.cache", state)
+
+
+def _on_compile_seconds(event: str, seconds: float, **kw) -> None:
+    if not event.startswith(_COMPILE_EVENTS):
+        return
+    stage = event.rsplit("/", 1)[-1]
+    cache = None
+    if stage == _BUILT:     # taken whatever the build's length: it is this one's
+        cache, _cache.state = getattr(_cache, "state", "uncached"), "uncached"
+    if seconds < _COMPILE_MIN_S or stage == _TIME_SAVED:
+        return
+    fun_name = str(kw.get("fun_name") or "")
+    if flight_recorder.RECORDING:
+        flight_recorder.mark("compile", seconds, f"{stage}|{fun_name}")
+    from ray_tpu.train._metrics import train_metrics
+
+    metrics = train_metrics()
+    metrics["compile_seconds"].observe(seconds, {"stage": stage})
+    if stage != _BUILT:
+        return
+    metrics["compiles"].inc(1, {"fun_name": fun_name, "cache": cache})
+    session = sys.modules.get("ray_tpu.train._session")
+    session = session and session.get_session()
+    if seconds >= _RECOMPILE_WARN_S and session and session.report_step:
+        logger.warning(
+            "%s was built inside the loop (%.1f s, compile cache: %s), after "
+            "train.report step %d: a jitted function ran for the first time "
+            "or met a new shape, dtype or static argument",
+            fun_name, seconds, cache, session.report_step)

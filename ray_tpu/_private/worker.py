@@ -80,6 +80,10 @@ def init(
                 return _global_worker
             raise RuntimeError("ray_tpu.init() called twice; use ignore_reinit_error=True")
 
+        # the runtime's start on the flight recorder's clock; written into
+        # the driver's ring, which its core worker opens below
+        t_init = time.perf_counter()
+        from ray_tpu._private import flight_recorder
         from ray_tpu._private.core_worker import CoreWorker
         from ray_tpu._private.node import Node
 
@@ -115,18 +119,25 @@ def init(
             gcs_addr = (host, int(port))
             nodelet_addr = _find_nodelet(gcs_addr)
 
-        core = CoreWorker(
-            mode="driver",
-            gcs_addr=gcs_addr,
-            nodelet_addr=nodelet_addr,
-            remote_plasma=client_mode,
-            namespace=namespace,
-        )
-        core.register_with_nodelet()
-        core.register_driver(entrypoint=os.environ.get("_", ""))
+        with flight_recorder.timed("bringup.init.driver_connect"):
+            # a node this call started keeps the driver's ring with its
+            # session's; a cluster found by address says on no reply where
+            # its session is, and the ring stays at the constructor's default
+            where = {"session_dir": node.session_dir} if node else {}
+            core = CoreWorker(
+                mode="driver",
+                gcs_addr=gcs_addr,
+                nodelet_addr=nodelet_addr,
+                remote_plasma=client_mode,
+                namespace=namespace,
+                **where,
+            )
+            core.register_with_nodelet()
+            core.register_driver(entrypoint=os.environ.get("_", ""))
         _global_worker = Worker(core, node=node, namespace=namespace)
         set_global_core(core)
         atexit.register(_atexit_shutdown)
+        flight_recorder.mark("bringup.init", time.perf_counter() - t_init)
         return _global_worker
 
 

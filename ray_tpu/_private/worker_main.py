@@ -10,9 +10,11 @@ from __future__ import annotations
 import argparse
 import logging
 import sys
+import time
 
 
 def main(argv=None):
+    t_main = time.perf_counter()
     parser = argparse.ArgumentParser()
     parser.add_argument("--nodelet-host", required=True)
     parser.add_argument("--nodelet-port", type=int, required=True)
@@ -39,20 +41,25 @@ def main(argv=None):
     # rather than a bare MainThread parked on the shutdown event
     threading.current_thread().name = "worker-main-wait"
 
+    from ray_tpu._private import flight_recorder
     from ray_tpu._private import worker as worker_mod
     from ray_tpu._private.core_worker import CoreWorker
     from ray_tpu._private.ids import NodeID, WorkerID
 
-    core = CoreWorker(
-        mode="worker",
-        gcs_addr=(args.gcs_host, args.gcs_port),
-        nodelet_addr=(args.nodelet_host, args.nodelet_port),
-        worker_id=WorkerID.from_hex(args.worker_id),
-        node_id=NodeID.from_hex(args.node_id),
-        session_dir=args.session_dir,
-    )
-    worker_mod.set_global_core(core)
-    core.register_with_nodelet()
+    # both wait for the ring the core worker opens, and keep their stamps
+    flight_recorder.mark("bringup.worker.imports",
+                         time.perf_counter() - t_main)
+    with flight_recorder.timed("bringup.worker.connect"):
+        core = CoreWorker(
+            mode="worker",
+            gcs_addr=(args.gcs_host, args.gcs_port),
+            nodelet_addr=(args.nodelet_host, args.nodelet_port),
+            worker_id=WorkerID.from_hex(args.worker_id),
+            node_id=NodeID.from_hex(args.node_id),
+            session_dir=args.session_dir,
+        )
+        worker_mod.set_global_core(core)
+        core.register_with_nodelet()
     # Block forever; the nodelet owns this process's lifetime.
     core.shutdown_event.wait()
 

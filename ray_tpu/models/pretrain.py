@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu._private import flight_recorder
 from ray_tpu.models.gpt2 import GPT2Config, GPT2LMModel, lm_loss
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.parallel.sharding import (
@@ -229,8 +230,10 @@ class ShardedPretrainer:
         # holds the whole state, and every process of a multi-host mesh
         # builds only what it addresses (same seed, same values: the RNG
         # does not depend on the layout).
-        self.state = jax.jit(init_state, out_shardings=jax.tree_util.tree_map(
-            lambda a: a.sharding, layout))()
+        with flight_recorder.timed("bringup.state_init"):
+            self.state = jax.block_until_ready(jax.jit(
+                init_state, out_shardings=jax.tree_util.tree_map(
+                    lambda a: a.sharding, layout))())
         self._steps = 0     # calls of step(): the profiler's step number
         # the last step's MoE statistics (load_balance, z, max_load, and
         # moe_rows_held where the layers hold a part of their experts), device

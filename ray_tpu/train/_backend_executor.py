@@ -15,6 +15,7 @@ import collections
 from typing import Any, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu._private import flight_recorder
 from ray_tpu.air.config import ScalingConfig
 from ray_tpu.exceptions import RayError
 from ray_tpu.train._session import TrainContext, _TrainingResult
@@ -43,18 +44,20 @@ class BackendExecutor:
 
     # ---------------------------------------------------------- lifecycle
     def start(self) -> None:
-        sc = self._scaling_config
-        self.worker_group = WorkerGroup(
-            num_workers=sc.num_workers,
-            resources_per_worker=sc._worker_resources,
-            placement_strategy=sc.placement_strategy,
-        )
-        try:
-            self._backend.on_start(self.worker_group, self._backend_config)
-        except Exception:
-            self.worker_group.shutdown()
-            self.worker_group = None
-            raise
+        with flight_recorder.timed("bringup.gang"):
+            sc = self._scaling_config
+            self.worker_group = WorkerGroup(
+                num_workers=sc.num_workers,
+                resources_per_worker=sc._worker_resources,
+                placement_strategy=sc.placement_strategy,
+            )
+            try:
+                self._backend.on_start(self.worker_group,
+                                       self._backend_config)
+            except Exception:
+                self.worker_group.shutdown()
+                self.worker_group = None
+                raise
 
     def start_training(self, train_fn, train_loop_config: Dict[str, Any],
                        experiment_name: str, trial_name: str, trial_dir: str,

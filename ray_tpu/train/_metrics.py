@@ -66,6 +66,19 @@ def train_metrics() -> Dict[str, M.Metric]:
                         "hand-off (result queued until the actor thread "
                         "took it), per experiment",
                         boundaries=M.PHASE_SECONDS_BOUNDARIES),
+                    "compiles": M.Counter(
+                        "train_compiles_total",
+                        "executables JAX built in a train worker that took "
+                        "at least 50 ms, per jitted function and compile-"
+                        "cache outcome (hit: loaded; miss: compiled and "
+                        "written; uncached: compiled, no entry)"),
+                    "compile_seconds": M.Histogram(
+                        "train_compile_seconds",
+                        "seconds of one stage of building a jitted function "
+                        "in a train worker (trace, lowering, backend "
+                        "compile-or-load, cache retrieval), per stage; "
+                        "events under 50 ms are left out",
+                        boundaries=CHECKPOINT_SECONDS_BOUNDARIES),
                     "ckpt_restore": M.Histogram(
                         "train_checkpoint_restore_seconds",
                         "checkpoint download/materialize duration",

@@ -21,7 +21,7 @@ import threading
 from dataclasses import dataclass, field
 from typing import Any, Dict, Optional
 
-from ray_tpu._private import fault_injection
+from ray_tpu._private import fault_injection, flight_recorder
 from ray_tpu.train._checkpoint import Checkpoint
 
 _session_lock = threading.Lock()
@@ -92,15 +92,28 @@ class _TrainSession:
         # each report START, so one slow rank shows as step skew while its
         # peers sit blocked in the lockstep queue.
         self._step = 0
+        self._entered = threading.Event()   # _run reached the train function
         self._thread = threading.Thread(
             target=self._run, args=(train_fn, config), daemon=True,
             name="train-loop")
 
     def start(self) -> None:
+        """Returns once the loop's thread stands before the user's first
+        line: RUNNING, as the driver reads it, means the loops run."""
         self._thread.start()
+        self._entered.wait(timeout=10.0)
+
+    @property
+    def report_step(self) -> int:
+        """``train.report`` rounds this session has begun."""
+        return self._step
 
     # ------------------------------------------------- train-loop side
     def _run(self, train_fn, config) -> None:
+        # the end of everything the program does before the user's code
+        if flight_recorder.RECORDING:
+            flight_recorder.mark("bringup.worker.train_fn_enter", 0.0)
+        self._entered.set()
         try:
             import inspect
 

@@ -14,6 +14,7 @@ from dataclasses import dataclass
 from typing import Any, Callable, Dict, List, Optional
 
 import ray_tpu
+from ray_tpu._private import flight_recorder
 from ray_tpu.util.placement_group import (
     PlacementGroup,
     placement_group,
@@ -117,9 +118,12 @@ class WorkerGroup:
         self.num_workers = num_workers
         self.resources_per_worker = dict(resources_per_worker)
         bundles = [dict(resources_per_worker) for _ in range(num_workers)]
-        self._pg: Optional[PlacementGroup] = placement_group(
-            bundles, strategy=placement_strategy, name="train-worker-group")
-        if not self._pg.ready(timeout=ready_timeout_s):
+        with flight_recorder.timed("bringup.gang.placement_group"):
+            self._pg: Optional[PlacementGroup] = placement_group(
+                bundles, strategy=placement_strategy,
+                name="train-worker-group")
+            ready = self._pg.ready(timeout=ready_timeout_s)
+        if not ready:
             pg, self._pg = self._pg, None
             remove_placement_group(pg)
             raise TimeoutError(
@@ -134,19 +138,21 @@ class WorkerGroup:
                  if k not in ("CPU", "TPU")}
         self.workers: List = []
         try:
-            self.workers = [
-                worker_cls.options(
-                    num_cpus=num_cpus,
-                    num_tpus=num_tpus,
-                    resources=extra or None,
-                    scheduling_strategy=PlacementGroupSchedulingStrategy(
-                        placement_group=self._pg,
-                        placement_group_bundle_index=i),
-                ).remote()
-                for i in range(num_workers)
-            ]
-            self.metadata: List[WorkerMetadata] = ray_tpu.get(
-                [w.get_metadata.remote() for w in self.workers])
+            # actor creation to the first answers: holds each worker's spawn
+            with flight_recorder.timed("bringup.gang.actors"):
+                self.workers = [
+                    worker_cls.options(
+                        num_cpus=num_cpus,
+                        num_tpus=num_tpus,
+                        resources=extra or None,
+                        scheduling_strategy=PlacementGroupSchedulingStrategy(
+                            placement_group=self._pg,
+                            placement_group_bundle_index=i),
+                    ).remote()
+                    for i in range(num_workers)
+                ]
+                self.metadata: List[WorkerMetadata] = ray_tpu.get(
+                    [w.get_metadata.remote() for w in self.workers])
         except Exception:
             # never leak reserved bundles/actors out of a failed bring-up:
             # a leaked PG would starve every retry's scheduling forever

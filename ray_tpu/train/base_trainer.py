@@ -13,13 +13,15 @@ code path whether the trainer is used standalone or under a Tuner.
 from __future__ import annotations
 
 import json
+import logging
 import os
 import time
 import uuid
-from typing import Any, Callable, Dict, Optional
+from typing import Any, Callable, Dict, List, Optional
 
 import cloudpickle
 
+from ray_tpu._private import flight_recorder
 from ray_tpu.air.config import RunConfig, ScalingConfig
 from ray_tpu.air.result import Result
 from ray_tpu.train._backend_executor import BackendExecutor, TrainingFailedError
@@ -29,6 +31,8 @@ from ray_tpu.train.jax_config import BackendConfig
 
 _TRAINER_PKL = "trainer.pkl"
 _PROGRESS_JSON = "progress.json"
+
+logger = logging.getLogger(__name__)
 
 
 class BaseTrainer:
@@ -135,6 +139,55 @@ def latest_checkpoint(trial_dir: str) -> Optional[str]:
     return path if path and storage.exists(path) else None
 
 
+# the gang's start, phase by phase, as the one line an operator reads:
+# (label, mark, [(label, mark) shown in brackets after it])
+_GANG_UP_PHASES = (
+    ("init", "bringup.init", (("gcs", "bringup.init.gcs_spawn"),
+                              ("nodelet", "bringup.init.nodelet_spawn"),
+                              ("connect", "bringup.init.driver_connect"))),
+    ("gang", "bringup.gang", (("placement", "bringup.gang.placement_group"),
+                              ("actors", "bringup.gang.actors"),
+                              ("backend", "bringup.gang.backend"))),
+    ("worker spawn", "bringup.worker_spawn",
+     (("imports", "bringup.worker.imports"),
+      ("connect", "bringup.worker.connect"))),
+    ("actor", "bringup.worker.actor", ()),
+    ("jax import", "bringup.worker.jax_import", ()),
+    ("compile cache", "bringup.worker.compile_cache", ()),
+    ("distributed init", "bringup.worker.distributed_init", ()),
+    ("tpu client", "bringup.worker.tpu_client", ()),
+    ("session", "bringup.session", ()),
+)
+
+
+def gang_up_line(marks: List[flight_recorder.Mark]) -> Optional[str]:
+    """``flight_recorder.bringup_timeline``'s marks as one line: every phase
+    that has a mark (the slowest where a gang's workers each wrote one) and
+    the seconds no mark covers.  ``None`` until a train function was
+    entered."""
+    gap = flight_recorder.bringup_gap(marks)
+    if gap is None:
+        return None
+    trained = {m[0] for m in marks if m[1] == flight_recorder.ENTERED}
+    seconds: Dict[str, float] = {}
+    for name, kind, start, end, detail in marks:
+        if kind == "bringup.worker_spawn" and detail not in trained:
+            continue    # a pooled worker that is not of this gang
+        seconds[kind] = max(seconds.get(kind, 0.0), end - start)
+
+    def shown(label, kind, children=()):
+        if kind not in seconds:
+            return None
+        inner = ", ".join(filter(None, (shown(*c) for c in children)))
+        return f"{label} {seconds[kind]:.1f}" + (f" ({inner})" if inner else "")
+
+    total = max(m[3] for m in marks if m[1] == flight_recorder.ENTERED) \
+        - min(m[2] for m in marks if m[1].startswith("bringup."))
+    phases = filter(None, (shown(*p) for p in _GANG_UP_PHASES))
+    return (f"train gang up in {total:.1f} s: " + " | ".join(phases)
+            + f" | uncovered {gap:.1f}")
+
+
 class DataParallelTrainer(BaseTrainer):
     """SPMD function-trainer: same ``train_loop_per_worker`` on every worker
     of the gang (reference: train/data_parallel_trainer.py:25)."""
@@ -167,6 +220,7 @@ class DataParallelTrainer(BaseTrainer):
         executor lives on the driver side of the trial."""
         from ray_tpu.train._metrics import GANG_STATES, train_metrics
 
+        t_loop = time.time()
         trial_dir = self.trial_dir
         storage.makedirs(trial_dir)
         self._save_trainer_state()
@@ -176,6 +230,7 @@ class DataParallelTrainer(BaseTrainer):
         metrics["gang_state"].set(GANG_STATES["STARTING"], mlabels)
         executor = BackendExecutor(self.backend_config, self.scaling_config)
         executor.start()
+        t_session = time.perf_counter()
         metrics_history = []
         latest_ckpt: Optional[str] = (
             self.resume_from_checkpoint.path
@@ -202,7 +257,10 @@ class DataParallelTrainer(BaseTrainer):
                 checkpoint_seq_start=_next_checkpoint_seq(trial_dir),
                 dataset_shards=dataset_shards,
             )
+            flight_recorder.mark("bringup.session",
+                                 time.perf_counter() - t_session)
             metrics["gang_state"].set(GANG_STATES["RUNNING"], mlabels)
+            self._log_gang_up(t_loop)
             metrics["gang_workers"].set(n_workers, mlabels)
             while True:
                 results = executor.get_next_results(
@@ -236,6 +294,24 @@ class DataParallelTrainer(BaseTrainer):
             path=trial_dir,
             metrics_history=metrics_history,
         )
+
+    @staticmethod
+    def _log_gang_up(t_loop: float) -> None:
+        """One INFO line from the session's rings, which outlives their
+        wrap in the driver's log; nothing where the recorder is off."""
+        from ray_tpu._private.worker import global_worker_core
+
+        core = global_worker_core()
+        if core is None or not flight_recorder.RECORDING:
+            return
+        marks, _ = flight_recorder.bringup_timeline(core.session_dir)
+        if any(m[1] == "bringup.gang" and m[3] < t_loop for m in marks):
+            # not the session's first gang: the runtime's start and the
+            # earlier gangs' marks are not part of this one's
+            marks = [m for m in marks if m[3] >= t_loop]
+        line = gang_up_line(marks)
+        if line:
+            logger.info(line)
 
     def _write_progress(self, trial_dir: str, ckpt: str, metrics) -> None:
         storage.write_bytes(

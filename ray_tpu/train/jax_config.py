@@ -28,6 +28,7 @@ import re
 from dataclasses import dataclass
 from typing import Optional
 
+from ray_tpu._private import flight_recorder
 from ray_tpu.train._worker_group import WorkerGroup
 
 
@@ -106,6 +107,8 @@ def _setup_jax_distributed(coordinator: Optional[str], num_processes: int,
     if platform not in ("cpu", "tpu"):
         raise ValueError(f"platform must be 'cpu' or 'tpu', got {platform!r}")
 
+    from ray_tpu._private.platform import enable_compile_cache, watch_compiles
+
     if platform == "cpu":
         # Replace (not append) any inherited device-count flag: workers
         # inherit the driver/test env where it is pinned to 8.
@@ -117,40 +120,43 @@ def _setup_jax_distributed(coordinator: Optional[str], num_processes: int,
         os.environ["JAX_PLATFORMS"] = "cpu"
         # the test substrate: Pallas kernels interpreted, by request
         os.environ["RAY_TPU_PALLAS_INTERPRET"] = "1"
-        import jax
-
-        # jax may already be imported in this worker process, where the
-        # env var alone would come too late
-        jax.config.update("jax_platforms", "cpu")
-        if coordinator is not None:
-            # gloo needs the jax.distributed client; a one-process gang has
-            # none (local XLA collectives only)
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
     else:
         os.environ["JAX_PLATFORMS"] = "tpu"
         os.environ.pop("RAY_TPU_PALLAS_INTERPRET", None)
+    with flight_recorder.timed("bringup.worker.jax_import"):
         import jax
 
-        from ray_tpu._private.platform import enable_compile_cache
-
-        jax.config.update("jax_platforms", "tpu")
-        enable_compile_cache()
+    # jax may already be imported in this worker process, where the env var
+    # alone would come too late
+    jax.config.update("jax_platforms", platform)
+    if platform == "tpu":
+        with flight_recorder.timed("bringup.worker.compile_cache"):
+            enable_compile_cache()
+    elif coordinator is not None:
+        # gloo needs the jax.distributed client; a one-process gang has
+        # none (local XLA collectives only)
+        jax.config.update("jax_cpu_collectives_implementation", "gloo")
+    watch_compiles()
 
     if coordinator is not None:
-        jax.distributed.initialize(coordinator, num_processes=num_processes,
-                                   process_id=process_id)
-    backend = jax.default_backend()  # raises if `platform` cannot initialize
-    if backend != platform:
-        raise RuntimeError(
-            f"train worker asked for platform {platform!r} but jax came up "
-            f"on {backend!r}")
-    return {
-        "process_id": jax.process_index(),
-        "process_count": jax.process_count(),
-        "local_device_count": jax.local_device_count(),
-        "global_device_count": jax.device_count(),
-        "platform": backend,
-    }
+        with flight_recorder.timed("bringup.worker.distributed_init"):
+            jax.distributed.initialize(coordinator,
+                                       num_processes=num_processes,
+                                       process_id=process_id)
+    with flight_recorder.timed("bringup.worker.tpu_client"):
+        # raises if `platform` cannot initialize
+        backend = jax.default_backend()
+        if backend != platform:
+            raise RuntimeError(
+                f"train worker asked for platform {platform!r} but jax came "
+                f"up on {backend!r}")
+        return {
+            "process_id": jax.process_index(),
+            "process_count": jax.process_count(),
+            "local_device_count": jax.local_device_count(),
+            "global_device_count": jax.device_count(),
+            "platform": backend,
+        }
 
 
 def _teardown_jax_distributed() -> None:
@@ -180,20 +186,23 @@ class _JaxBackend(Backend):
         platform = backend_config.platform or (
             "tpu" if worker_group.resources_per_worker.get("TPU") else "cpu")
         refs = []
-        for s in range(worlds):
-            lo = s * gang
-            if gang == 1:
-                coordinator = None  # one-process gang: no jax.distributed
-            else:
-                port = backend_config.coordinator_port or \
-                    worker_group.execute_single(lo, _free_port)
-                coordinator = f"{worker_group.metadata[lo].node_ip}:{port}"
-            for gr in range(gang):
-                w = worker_group.workers[lo + gr]
-                refs.append(w.execute.remote(
-                    _setup_jax_distributed, coordinator, gang, gr,
-                    platform, backend_config.cpu_devices_per_worker))
-        infos = ray_tpu.get(refs, timeout=120.0)
+        # submission to the answers: holds each worker's `import jax` and
+        # its backend client
+        with flight_recorder.timed("bringup.gang.backend"):
+            for s in range(worlds):
+                lo = s * gang
+                if gang == 1:
+                    coordinator = None  # one-process gang: no jax.distributed
+                else:
+                    port = backend_config.coordinator_port or \
+                        worker_group.execute_single(lo, _free_port)
+                    coordinator = f"{worker_group.metadata[lo].node_ip}:{port}"
+                for gr in range(gang):
+                    w = worker_group.workers[lo + gr]
+                    refs.append(w.execute.remote(
+                        _setup_jax_distributed, coordinator, gang, gr,
+                        platform, backend_config.cpu_devices_per_worker))
+            infos = ray_tpu.get(refs, timeout=120.0)
         # device counts must agree WITHIN each gang (gangs are independent
         # jax worlds and may differ across stages/replicas)
         for s in range(worlds):

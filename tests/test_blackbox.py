@@ -78,6 +78,37 @@ def test_ring_roundtrip_wrap_and_limit(own_ring):
     assert [r["seq"] for r in last3] == seqs[-3:]
 
 
+def test_record_takes_a_stamp(own_ring):
+    fr, sdir = own_ring
+    fr.init_process(sdir, "stamped")
+    fr.record("past", "x", ts=123.5)
+    fr.record("now")
+    past, now = fr.harvest_for(sdir, "stamped")[-2:]
+    assert (past["kind"], past["detail"], past["ts"]) == ("past", "x", 123.5)
+    assert now["seq"] == past["seq"] + 1 and now["ts"] > 1e9
+
+
+def test_timed_writes_one_mark_at_its_exit(own_ring):
+    fr, sdir = own_ring
+    fr.init_process(sdir, "timed")
+    with fr.timed("bringup.unit", "why"):
+        inside = [r["kind"] for r in fr.harvest_for(sdir, "timed")]
+    assert "bringup.unit" not in inside      # one record, at the exit
+    with pytest.raises(KeyError):
+        with fr.timed("bringup.failed"):     # a phase that raised is marked
+            raise KeyError("x")
+    fr.mark("bringup.known", 2.5)
+    unit, failed, known = fr.harvest_for(sdir, "timed")[-3:]
+    seconds, _, why = unit["detail"].partition("|")
+    assert unit["kind"] == "bringup.unit" and why == "why"
+    assert 0.0 <= float(seconds) < 60.0
+    assert failed["kind"] == "bringup.failed" and float(failed["detail"]) >= 0
+    assert (known["kind"], known["detail"]) == ("bringup.known", "2.500000")
+    (_, _, start, end, _), = [m for m in fr.bringup_timeline(sdir)[0]
+                              if m[1] == "bringup.known"]
+    assert end == known["ts"] and end - start == pytest.approx(2.5)
+
+
 def test_ring_harvest_survives_torn_bytes(own_ring):
     fr, sdir = own_ring
     fr.init_process(sdir, "torn")
