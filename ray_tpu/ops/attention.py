@@ -176,14 +176,19 @@ class _Tiles(NamedTuple):
     nk: int
     k_pad_from: Optional[int]   # first padding key, None if there is none
     tri: int         # chunk of a diagonal tile's queries; 0: whole-tile mask
-    # Block diffusion (``_flash_bd``): the queries are two halves of ``nq / 2``
-    # tiles each, the noised copy and then the clean copy of the ``s_k``
-    # positions the keys (the clean copy) have, in blocks of ``bd``.  Query r
-    # of half h (0 noised, 1 clean) sees key c iff c < (r // bd + h) * bd: the
-    # clean blocks before its own, and for a clean query its own too.  It is
-    # the causal mask with the query moved to the last position of its block
-    # (clean) or of the block before (noised), so tiles are skipped, clamped
-    # and chunked as under the causal diagonal.  0: no such mask.
+    # Block diffusion (``_flash_bd``): queries and keys are two halves of
+    # ``nq / 2 == nk`` tiles each, the noised copy and then the clean copy of
+    # the same ``s_k`` positions, in blocks of ``bd``; ``nk``, ``block_k`` and
+    # the walk are over the clean keys.  Query r of half h (0 noised, 1 clean)
+    # sees clean key c iff c < (r // bd + h) * bd: the clean blocks before its
+    # own, and for a clean query its own too.  It is the causal mask with the
+    # query moved to the last position of its block (clean) or of the block
+    # before (noised), so tiles are skipped, clamped and chunked as under the
+    # causal diagonal.  A noised query also sees the noised keys of its own
+    # block: those squares lie on the diagonal of the noised tile at the query
+    # tile's own index, and each kernel takes them in one more step there, a
+    # lane-wide chunk of positions against itself (``_same_block``).  0: no
+    # such mask.
     bd: int = 0
 
     @classmethod
@@ -196,16 +201,14 @@ class _Tiles(NamedTuple):
         s_k_pad = _round_up(s_k, block_k)
         if bd:
             # a block never straddles a tile, a chunk or the keys' end, so no
-            # real query sees a padding key
+            # real query sees a padding key; a query tile's own positions are
+            # one key tile
             assert not causal and s_q == s_k and LANES % bd == 0 \
-                and s_k % bd == 0, (causal, s_q, s_k, bd)
-            tri = 0
-            if block_q == block_k:
-                tri = diag_chunk if block_q % diag_chunk == 0 else LANES
-            return cls(False, 0, block_q, block_k,
-                       2 * (_round_up(s_q, block_q) // block_q),
-                       s_k_pad // block_k,
-                       None if s_k_pad == s_k else s_k, tri, bd)
+                and s_k % bd == 0 and block_q == block_k, \
+                (causal, s_q, s_k, bd, block_q, block_k)
+            return cls(False, 0, block_q, block_k, 2 * (s_k_pad // block_k),
+                       s_k_pad // block_k, None if s_k_pad == s_k else s_k,
+                       diag_chunk if block_q % diag_chunk == 0 else LANES, bd)
         # Square tiles that the diagonal meets corner to corner, and no real
         # query that sees a padding key: a tile on the diagonal can go chunk
         # by chunk.
@@ -223,15 +226,14 @@ class _Tiles(NamedTuple):
         iq, ik = (j, i) if q_is_inner else (i, j)
         if self.bd:
             half, iq_l = self.half_of(iq)
-            shift = half * self.bd
             if q_is_inner:
-                first = lax.div(ik * self.block_k + self.bd - shift,
-                                jnp.int32(self.block_q))
-                n = self.nq // 2
-                return half * n + jnp.clip(iq_l, first, n - 1), ik
+                # key tile ik's first live query tile is the one at its own
+                # index, in both copies: the clean copy's diagonal, the
+                # noised copy's own squares
+                return half * self.nk + jnp.maximum(iq_l, ik), ik
             last = lax.div(jnp.maximum(
-                iq_l * self.block_q + self.block_q - self.bd - 1 + shift, 0),
-                jnp.int32(self.block_k))
+                iq_l * self.block_q + self.block_q - 1 + (half - 1) * self.bd,
+                0), jnp.int32(self.block_k))
             return iq, jnp.minimum(ik, jnp.minimum(last, self.nk - 1))
         if not self.causal:
             return iq, ik
@@ -253,15 +255,32 @@ class _Tiles(NamedTuple):
     def specs(self, d: int, q_is_inner: bool):
         """BlockSpecs of a (b*h, s_q, d) operand, a (b*h, 1, s_q) row of
         per-query statistics and a (b*h, s_k, d) operand, following
-        ``tile_of``."""
+        ``tile_of``.  Under ``bd`` the last is (b*h, 2, s_k, d), the two
+        copies: with the queries inner, both copies' tile ik as one block;
+        with the keys inner, the clean tile of the walk, and a fourth spec,
+        the noised tile at the query tile's own index, which stays where it
+        is while the clean copy's queries run and so is fetched once a noised
+        query tile."""
         def at(i, j):
             return self.tile_of(i, j, q_is_inner)
-        return (pl.BlockSpec((None, self.block_q, d),
-                             lambda bh, i, j: (bh, at(i, j)[0], 0)),
-                pl.BlockSpec((None, 1, self.block_q),
-                             lambda bh, i, j: (bh, 0, at(i, j)[0])),
-                pl.BlockSpec((None, self.block_k, d),
-                             lambda bh, i, j: (bh, at(i, j)[1], 0)))
+        q_spec = pl.BlockSpec((None, self.block_q, d),
+                              lambda bh, i, j: (bh, at(i, j)[0], 0))
+        row_spec = pl.BlockSpec((None, 1, self.block_q),
+                                lambda bh, i, j: (bh, 0, at(i, j)[0]))
+        if not self.bd:
+            return (q_spec, row_spec,
+                    pl.BlockSpec((None, self.block_k, d),
+                                 lambda bh, i, j: (bh, at(i, j)[1], 0)))
+        if q_is_inner:
+            return (q_spec, row_spec,
+                    pl.BlockSpec((None, 2, self.block_k, d),
+                                 lambda bh, i, j: (bh, 0, i, 0)))
+        return (q_spec, row_spec,
+                pl.BlockSpec((None, None, self.block_k, d),
+                             lambda bh, i, j: (bh, 1, at(i, j)[1], 0)),
+                pl.BlockSpec((None, None, self.block_k, d),
+                             lambda bh, i, j: (
+                                 bh, 0, jnp.minimum(i, self.nk - 1), 0)))
 
 
 def _rows(x, s_pad: int):
@@ -272,15 +291,20 @@ def _rows(x, s_pad: int):
     return jnp.pad(x.reshape(b * h, s, d), ((0, 0), (0, s_pad - s), (0, 0)))
 
 
-def _halves(x, l_pad: int, fill=0.0):
-    """(b, h, 2 l, d), two copies of ``l`` positions -> (b*h, 2 l_pad, d),
-    each copy padded with ``fill`` up to whole blocks, so that a tile belongs
-    to one copy."""
+def _halves(x, s_pad: int, fill=0.0):
+    """(b, h, 2 l, d), two copies of ``l`` positions -> (b*h, s_pad, d),
+    each copy padded with ``fill`` up to ``s_pad / 2``, whole blocks, so that
+    a tile belongs to one copy."""
     b, h, s, d = x.shape
     x = jnp.pad(x.reshape(b * h, 2, s // 2, d),
-                ((0, 0), (0, 0), (0, l_pad - s // 2), (0, 0)),
+                ((0, 0), (0, 0), (0, (s_pad - s) // 2), (0, 0)),
                 constant_values=fill)
-    return x.reshape(b * h, 2 * l_pad, d)
+    return x.reshape(b * h, s_pad, d)
+
+
+def _copies(x, l_pad: int):
+    """``_halves`` with the copies apart: (b*h, 2, l_pad, d)."""
+    return _halves(x, 2 * l_pad).reshape(-1, 2, l_pad, x.shape[-1])
 
 
 def _unhalved(x, l: int, axis: int):
@@ -313,6 +337,26 @@ def _masked(s, q_dim: int, thresh, k_limit, bd: int = 0):
         in_k = lax.lt(c, k_limit)
         valid = in_k if valid is None else lax.bitwise_and(valid, in_k)
     return lax.select(valid, s, lax.full_like(s, NEG_INF))
+
+
+def _same_block(s, bd: int):
+    """A square chunk of scaled scores, positions against themselves, with
+    NEG_INF outside the ``bd`` x ``bd`` squares on its diagonal (``bd`` a
+    power of two: r and c are in one block iff r ^ c < bd)."""
+    if bd >= s.shape[0]:
+        return s
+    r = lax.broadcasted_iota(jnp.int32, s.shape, 0)
+    c = lax.broadcasted_iota(jnp.int32, s.shape, 1)
+    return lax.select(lax.lt(lax.bitwise_xor(r, c), jnp.int32(bd)), s,
+                      lax.full_like(s, NEG_INF))
+
+
+def _lane_chunks(block: int):
+    """A tile's positions a lane-wide chunk at a time, as slices: how a
+    noised tile's own squares are taken, 128 / ``bd`` of them a chunk (3% of
+    it at ``bd`` 4: what that wastes on the MXU is nothing beside a pass over
+    the output in HBM)."""
+    return [slice(j, j + LANES) for j in range(0, block, LANES)]
 
 
 def _on_tiles(t: _Tiles, iq, ik, part):
@@ -369,12 +413,37 @@ def _across(col, n: int):
 
 
 # -------------------------------------------------------------- forward
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, m_col, l_col, acc,
-                      *, sm_scale: float, t: _Tiles):
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles):
+    # under ``t.bd`` two more inputs: the noised K and V tile of the query
+    # tile's own positions
+    *own_refs, o_ref, lse_ref, m_col, l_col, acc = rest
     iq, ik = pl.program_id(1), pl.program_id(2)
     d = q_ref.shape[1]
 
-    @pl.when(ik == 0)
+    from_nothing = ik == 0
+    if t.bd:
+        # a noised query tile starts from its own squares, where another
+        # starts from nothing: every query sees itself, so its running max
+        # is finite from there on
+        kn_ref, vn_ref = own_refs
+        from_own = jnp.logical_and(from_nothing, iq < t.nk)
+        from_nothing = jnp.logical_and(from_nothing, iq >= t.nk)
+
+        @pl.when(from_own)
+        def _():
+            for rows in _lane_chunks(t.block_q):
+                s = _same_block(lax.mul(_dot(
+                    q_ref[rows, :], kn_ref[rows, :], _NT), sm_scale), t.bd)
+                m = jnp.broadcast_to(jnp.max(s, axis=1, keepdims=True),
+                                     s.shape)
+                m_col[rows, :] = m
+                p = lax.exp(lax.sub(s, m))
+                l_col[rows, :] = jnp.broadcast_to(
+                    jnp.sum(p, axis=1, keepdims=True), s.shape)
+                acc[rows, :] = _dot(p.astype(vn_ref.dtype), vn_ref[rows, :],
+                                    _NN)
+
+    @pl.when(from_nothing)
     def _():
         m_col[...] = jnp.full_like(m_col, NEG_INF)
         l_col[...] = jnp.zeros_like(l_col)
@@ -422,7 +491,8 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
                    block_k: Optional[int], interpret: bool, bd: int = 0):
     """``out`` (b, h, s_q, d) and the logsumexp of every query's scaled
     scores as (b*h, 1, s_q) rows, NEG_INF where a query sees no key.  Under
-    ``bd`` (see ``_Tiles``) the queries are the two copies, ``s_q == 2 s_k``.
+    ``bd`` (see ``_Tiles``) q, k and v are the two copies of ``s_q / 2``
+    positions, and every query sees a key.
 
     Jitted and inlined so that a model's layers, which call it with the same
     shapes, share one trace of the kernel: the equations land in the caller's
@@ -430,16 +500,18 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    s_k = k.shape[2] // 2 if bd else k.shape[2]     # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
                   q_offset - k_offset, _FWD_DIAG_CHUNK, block_q, block_k, bd)
-    s_q_pad = t.nq * t.block_q
-    q_spec, row_spec, k_spec = t.specs(d, q_is_inner=False)
+    s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
+    q_spec, row_spec, k_spec, *own_spec = t.specs(d, q_is_inner=False)
+    q_rows, k_rows = (_halves, _copies) if bd else (_rows, _rows)
     with jax.named_scope("flash_fwd"):
+        q, k, v = q_rows(q, s_q_pad), k_rows(k, s_k_pad), k_rows(v, s_k_pad)
         out, lse = pl.pallas_call(
             functools.partial(_flash_fwd_kernel, sm_scale=sm_scale, t=t),
             grid=(b * h, t.nq, t.nk),
-            in_specs=[q_spec, k_spec, k_spec],
+            in_specs=[q_spec, k_spec, k_spec] + own_spec * 2,
             out_specs=[q_spec, row_spec],
             out_shape=[jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype),
                        jax.ShapeDtypeStruct((b * h, 1, s_q_pad), jnp.float32)],
@@ -449,8 +521,7 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
             name="flash_fwd",
-        )(_halves(q, s_q_pad // 2) if bd else _rows(q, s_q_pad),
-          _rows(k, t.nk * t.block_k), _rows(v, t.nk * t.block_k))
+        )(q, k, v, *(k, v) * len(own_spec))
     if bd:
         return (_unhalved(out, s_k, 1).reshape(b, h, s_q, d),
                 _unhalved(lse, s_k, 2))
@@ -458,12 +529,6 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
 
 
 # ------------------------------------------------------------- backward
-def _bwd_p(s, lse, q_dim: int, thresh, k_limit, bd: int = 0):
-    """P = exp(s - lse) of a tile of scaled scores, zero where ``_masked``
-    masks."""
-    return lax.exp(lax.sub(_masked(s, q_dim, thresh, k_limit, bd), lse))
-
-
 # What Mosaic gives a kernel's blocks, scratch and temporaries unless told
 # otherwise; a tile's share of the backward fits in it at every tile edge
 # ``_block`` picks, as it did before dQ had to span the sequence.
@@ -485,6 +550,12 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                       *, sm_scale: float, t: _Tiles):
     ik, iq = pl.program_id(1), pl.program_id(2)
     last_k, last_q = pl.num_programs(1) - 1, pl.num_programs(2) - 1
+    if t.bd:
+        # both copies' tile ik: the clean one takes the walk and the
+        # accumulators, the noised one the own squares of query tile ik
+        (kn_ref, k_ref), (vn_ref, v_ref), (dkn_ref, dk_ref), \
+            (dvn_ref, dv_ref) = ((r.at[0], r.at[1])
+                                 for r in (k_ref, v_ref, dk_ref, dv_ref))
 
     @pl.when(jnp.logical_and(ik == 0, iq == 0))
     def _():
@@ -495,19 +566,35 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def part(qs, ks, thresh, k_limit):
-        q, do, k = q_ref[qs, :], do_ref[qs, :], k_ref[ks, :]
-        st = lax.mul(_dot(k, q, _NT), sm_scale)
-        pt = _bwd_p(st, lse_ref[:, qs], 1, thresh, k_limit, t.bd)
-        dv_acc[ks, :] += _dot(pt.astype(do.dtype), do, _NN)
-        dst = lax.mul(pt, lax.sub(_dot(v_ref[ks, :], do, _NT),
+    def part(qs, ks, thresh, k_limit, own=False):
+        kr, vr = (kn_ref, vn_ref) if own else (k_ref, v_ref)
+        q, do, k = q_ref[qs, :], do_ref[qs, :], kr[ks, :]
+        st, lse = lax.mul(_dot(k, q, _NT), sm_scale), lse_ref[:, qs]
+        st = _same_block(st, t.bd) if own \
+            else _masked(st, 1, thresh, k_limit, t.bd)
+        pt = lax.exp(lax.sub(st, lse))      # P^T, zero where masked
+        if own:     # nothing else reaches these keys: no sum over steps
+            dvn_ref[ks, :] = _dot(pt.astype(do.dtype), do, _NN
+                                  ).astype(dvn_ref.dtype)
+        else:
+            dv_acc[ks, :] += _dot(pt.astype(do.dtype), do, _NN)
+        dst = lax.mul(pt, lax.sub(_dot(vr[ks, :], do, _NT),
                                   delta_ref[:, qs])).astype(q.dtype)
-        dk_acc[ks, :] += _dot(dst, q, _NN)
+        if own:
+            dkn_ref[ks, :] = (_dot(dst, q, _NN) * sm_scale
+                              ).astype(dkn_ref.dtype)
+        else:
+            dk_acc[ks, :] += _dot(dst, q, _NN)
         # these queries' rows of the whole-sequence accumulator
         start, stop, _ = qs.indices(t.block_q)
         rows = pl.ds(pl.multiple_of(iq * t.block_q + start, LANES), stop - start)
         dq_acc[rows, :] += _dot(dst, k, _TN)
 
+    if t.bd:
+        @pl.when(iq == ik)
+        def _():
+            for rows in _lane_chunks(t.block_q):
+                part(rows, rows, None, None, own=True)
     _on_tiles(t, iq, ik, part)
 
     @pl.when(iq == last_q)
@@ -520,43 +607,39 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 12), inline=True)
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11), inline=True)
 def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
                     q_offset: int, k_offset: int, interpret: bool,
-                    g_lse=None, bd: int = 0):
+                    bd: int = 0):
     """dq, dk, dv of ``_flash_attention`` from its residuals (``lse`` as the
-    forward leaves it: (b*h, 1, s_q) rows) and ``g``.  ``g_lse``, where the
-    caller used the logsumexp too, is its cotangent: d lse / d S is P, so it
-    enters dS = P * (dP - delta) as ``delta - g_lse``.
+    forward leaves it: (b*h, 1, s_q) rows) and ``g``.
 
     Jitted and inlined for the reason ``_flash_forward`` is."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s_q, d = q.shape
-    s_k = k.shape[2]
+    s_k = k.shape[2] // 2 if bd else k.shape[2]     # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
                   q_offset - k_offset, _BWD_DIAG_CHUNK, bd=bd)
     s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
 
     def row(x, fill):
         if bd:      # a statistic a query, laid out as the queries are
-            return _halves(x.reshape(b, h, s_q, 1), s_q_pad // 2,
+            return _halves(x.reshape(b, h, s_q, 1), s_q_pad,
                            fill).reshape(b * h, 1, s_q_pad)
         return jnp.pad(x, ((0, 0), (0, 0), (0, s_q_pad - s_q)),
                        constant_values=fill)
 
-    def q_rows(x):
-        return _halves(x, s_q_pad // 2) if bd else _rows(x, s_q_pad)
-
+    q_rows, k_rows = (_halves, _copies) if bd else (_rows, _rows)
     delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
-    if g_lse is not None:
-        delta = delta - g_lse.reshape(delta.shape)
     # A row with an empty (fully masked) softmax has lse == NEG_INF, and
     # exp(s - lse) would blow up: such rows, like the padding rows, get an lse
     # under which every p is zero.
     lse = jnp.where(lse > NEG_INF / 2, lse, -NEG_INF)
     q_spec, row_spec, k_spec = t.specs(d, q_is_inner=True)
-    dkv_spec = pl.BlockSpec((None, t.block_k, d), lambda bh, i, j: (bh, i, 0))
+    # under ``bd`` both copies' tile, as the keys come: ``k_spec`` ignores j
+    dkv_spec = k_spec if bd else pl.BlockSpec(
+        (None, t.block_k, d), lambda bh, i, j: (bh, i, 0))
     # dQ sums over the k blocks, the outer axis: its block is the whole
     # sequence of one b*h, written back once when the b*h is done.
     dq_spec = pl.BlockSpec((None, s_q_pad, d), lambda bh, i, j: (bh, 0, 0))
@@ -566,7 +649,8 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
         in_specs=[q_spec, q_spec, row_spec, row_spec, k_spec, k_spec],
         out_specs=[dq_spec, dkv_spec, dkv_spec],
         out_shape=[jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype)]
-        + [jax.ShapeDtypeStruct((b * h, s_k_pad, d), q.dtype)] * 2,
+        + [jax.ShapeDtypeStruct((b * h, 2, s_k_pad, d) if bd
+                                else (b * h, s_k_pad, d), q.dtype)] * 2,
         scratch_shapes=[pltpu.VMEM((s_q_pad, d), jnp.float32)]
         + [pltpu.VMEM((t.block_k, d), jnp.float32)] * 2,
         compiler_params=pltpu.CompilerParams(
@@ -574,11 +658,14 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
             vmem_limit_bytes=_bwd_vmem_bytes(s_q_pad, d, q.dtype)),
         interpret=interpret,
         name="flash_bwd",
-    )(q_rows(q), q_rows(g), row(lse, -NEG_INF),
+    )(q_rows(q, s_q_pad), q_rows(g, s_q_pad), row(lse, -NEG_INF),
       row(delta.reshape(b * h, 1, s_q), 0.0),
-      _rows(k, s_k_pad), _rows(v, s_k_pad))
+      k_rows(k, s_k_pad), k_rows(v, s_k_pad))
     if bd:
-        dq = _unhalved(dq, s_k, 1)
+        return tuple(
+            _unhalved(dx.reshape(b * h, s_q_pad, d), s_k, 1
+                      ).reshape(b, h, s_q, d).astype(x.dtype)
+            for dx, x in ((dq, q), (dk, k), (dv, v)))
     return (dq[:, :s_q].reshape(b, h, s_q, d),
             dk[:, :s_k].reshape(b, h, s_k, d).astype(k.dtype),
             dv[:, :s_k].reshape(b, h, s_k, d).astype(v.dtype))
@@ -613,61 +700,30 @@ _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 # ------------------------------------------------------ block diffusion
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
 def _flash_bd(q, k, v, sm_scale, bd):
-    """Both copies' queries (b, h, 2 l, d) against the clean copy's keys and
-    values (b, h, l, d) under ``_Tiles``' block mask -> (out, the logsumexp of
-    every query's live scores as (b, h, 2 l), NEG_INF where it sees none)."""
+    """Attention under ``block_diffusion_mask``: q, k, v (b, h, 2 l, d), the
+    noised copy of ``l`` positions and then the clean one, in blocks of
+    ``bd``.  One kernel call, one online softmax over every live pair: both
+    copies' queries over the clean keys, tiles skipped and masked as under a
+    causal diagonal, and a noised block against itself (``l / bd`` squares of
+    ``bd`` x ``bd`` scores: 0.1% of the pairs at l 4096 and bd 4) as one more
+    step of a noised tile (``_Tiles``)."""
     return _flash_bd_fwd(q, k, v, sm_scale, bd)[0]
 
 
 def _flash_bd_fwd(q, k, v, sm_scale, bd):
     out, lse = _flash_forward(q, k, v, False, sm_scale, 0, 0, None, None,
                               _interpret(), bd)
-    return (out, lse.reshape(q.shape[:3])), (q, k, v, out, lse)
+    return out, (q, k, v, out, lse)
 
 
 def _flash_bd_bwd(sm_scale, bd, residuals, g):
     q, k, v, out, lse = residuals
     with jax.named_scope("flash_bwd"):
-        return _flash_backward(q, k, v, out, lse, g[0], False, sm_scale, 0, 0,
-                               _interpret(), g[1], bd)
+        return _flash_backward(q, k, v, out, lse, g, False, sm_scale, 0, 0,
+                               _interpret(), bd)
 
 
 _flash_bd.defvjp(_flash_bd_fwd, _flash_bd_bwd)
-
-
-def _block_diffusion_attention(q, k, v, sm_scale: float, bd: int):
-    """Attention under ``block_diffusion_mask``: q, k, v (b, h, 2 l, d), the
-    noised copy of ``l`` positions and then the clean one.
-
-    Every live pair but the noised blocks' own squares has a clean key, so one
-    flash call takes both copies' queries over the clean keys, its tiles
-    skipped and masked as under a causal diagonal.  What is left, a noised
-    block against itself, is ``l / bd`` squares of ``bd`` x ``bd`` scores:
-    0.1% of the pairs at l 4096 and bd 4, of which a 128-wide MXU tile would
-    be 3% full.  They are a plain batched term here, merged with the kernel's
-    result by the two logsumexps, which is exact."""
-    b, h, s, d = q.shape
-    l = s // 2
-    out, lse = _flash_bd(q, k[:, :, l:], v[:, :, l:], sm_scale, bd)
-    with jax.named_scope("bd_diagonal"):
-        blocks = (b, h, l // bd, bd, d)
-        qn, kn, vn = (x[:, :, :l].reshape(blocks) for x in (q, k, v))
-        scores = jnp.einsum("bhnqd,bhnkd->bhnqk", qn, kn,
-                            preferred_element_type=jnp.float32) * sm_scale
-        top = jnp.max(scores, axis=-1, keepdims=True)
-        p = jnp.exp(scores - top)
-        own = jnp.einsum("bhnqk,bhnkd->bhnqd", p.astype(v.dtype), vn,
-                         preferred_element_type=jnp.float32)
-        own_lse = (top + jnp.log(jnp.sum(p, axis=-1, keepdims=True))
-                   ).reshape(b, h, l, 1)
-        # the own block always holds the query itself: own_lse is finite
-        own = own.reshape(b, h, l, d) * jnp.exp(top.reshape(b, h, l, 1)
-                                                - own_lse)
-        before_lse = lse[:, :, :l, None]
-        both = jnp.logaddexp(before_lse, own_lse)
-        noised = out[:, :, :l].astype(jnp.float32) \
-            * jnp.exp(before_lse - both) + own * jnp.exp(own_lse - both)
-    return jnp.concatenate([noised.astype(out.dtype), out[:, :, l:]], axis=2)
 
 
 def _bhsd_spec(mesh, batch_axes, head_axis, seq_axis=None):
@@ -696,8 +752,7 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     if diffusion_block:
-        f = functools.partial(_block_diffusion_attention,
-                              sm_scale=float(sm_scale),
+        f = functools.partial(_flash_bd, sm_scale=float(sm_scale),
                               bd=int(diffusion_block))
     else:
         f = functools.partial(

@@ -8,6 +8,7 @@ norm; and every model the benchmark had before, unchanged.  The whole model
 against the whole reference is ``tests/test_sdar.py``."""
 
 import dataclasses
+import hashlib
 import json
 import os
 
@@ -24,6 +25,7 @@ from ray_tpu.models.moe import (RoutedConfig, RoutedSwiGLU,
                                 capacity_ladder)
 from ray_tpu.models.pretrain import (init_params, loss_fn, make_optimizer,
                                      noise_blocks, objective_fn, train_step)
+from ray_tpu.ops import attention
 from ray_tpu.ops.attention import (block_diffusion_mask, flash_attention,
                                    mha_reference)
 
@@ -36,14 +38,20 @@ with open(os.path.join(_TOYS, "toy-sdar.json")) as f:
 
 
 @pytest.mark.parametrize("length,block", [(160, 4), (160, 32), (1152, 32),
-                                          (384, 4)])
+                                          (384, 4), (384, 128), (1152, 128),
+                                          (160, 1), (1152, 1)])
 def test_c_the_kernels_block_mask_is_the_written_out_mask(length, block):
     """The interpreted flash kernels under ``diffusion_block`` against plain
     attention under the boolean ``block_diffusion_mask``, forward and
-    backward: at 160 a copy is one 128-tile and a padded one, at 1152 four
-    256-tiles and a padded fifth with the diagonal taken chunk by chunk, at
-    384 three whole 128-tiles; the noised blocks' own squares are the plain
-    term merged by logsumexp."""
+    backward: at 160 a copy is one padded 256-tile, at 1152 four 256-tiles
+    and a padded fifth with the diagonal taken chunk by chunk, at 384 three
+    whole 128-tiles.  The noised blocks' own squares are one more step of
+    the kernels' own softmax, a 128-chunk of positions against itself: a
+    block of 128 fills a whole chunk (and at 384 a whole tile, whose clean
+    diagonal tile is then dead), a block of 1 is a noised token that sees
+    itself alone of its copy.  The first noised block sees no clean key at
+    all: its rows are plain attention inside the block, and their logsumexp
+    is finite."""
     mask = block_diffusion_mask(length, block)
     assert int(mask.sum()) == length * length + length * block
     assert bool(jnp.all(mask == sdar_moe.block_mask(length, block)))
@@ -56,11 +64,88 @@ def test_c_the_kernels_block_mask_is_the_written_out_mask(length, block):
     def plain(q, k, v):
         return mha_reference(q, k, v, mask=mask)
 
-    np.testing.assert_allclose(kernel(q, k, v), plain(q, k, v), atol=5e-6)
+    out = kernel(q, k, v)
+    np.testing.assert_allclose(out, plain(q, k, v), atol=5e-6)
+    first = slice(0, block)
+    np.testing.assert_allclose(
+        out[:, :, first], mha_reference(
+            q[:, :, first], k[:, :, first], v[:, :, first], causal=False),
+        atol=5e-6)
+    _, lse = attention._flash_forward(q, k, v, False, 32 ** -0.5, 0, 0, None,
+                                      None, True, block)
+    assert lse.shape == (2, 1, 2 * length)
+    scores = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                        precision="highest") * 32 ** -0.5
+    np.testing.assert_allclose(
+        lse[:, 0], jax.nn.logsumexp(jnp.where(mask, scores, -jnp.inf),
+                                    axis=-1)[0], atol=2e-5)
     g = jax.random.normal(jax.random.PRNGKey(7), q.shape, jnp.float32)
     for got, want in zip(jax.vjp(kernel, q, k, v)[1](g),
                          jax.vjp(plain, q, k, v)[1](g)):
         np.testing.assert_allclose(got, want, atol=3e-5)
+
+
+# sha256 of str(jax.make_jaxpr(...)) of the causal call and of its vjp at
+# (1, 2, 1152, 64) bf16 (256-tiles, the last one padded, the diagonal chunk
+# by chunk), and of the unmasked call at (1, 2, 200, 64) float32 (padding
+# keys), at the parent commit (7ea4048), kernel bodies included (under a JAX
+# that prints a jaxpr otherwise, take them again there)
+_CAUSAL_AS_IT_WAS = {
+    ("causal", "fwd"):
+        "8f8a7618d0a5448c90482230bae4a51691715a7ebad9340cffac823f4ec2fbb1",
+    ("causal", "vjp"):
+        "75113af9f5f4c4fc122ca69a61c2a578185b611b864b4cb2d2720b4abd4f699a",
+    ("full", "fwd"):
+        "329822db3c2b5c40a921bb7a32492b033cd414c6f8a46114852be8296fb55f7d",
+    ("full", "vjp"):
+        "6dd08463bb813cbf6939539aafbbd233d905fa7bcbe98a41cb649a523abeb021",
+}
+
+
+def _eqns(jaxpr, inside_kernel=False):
+    """(primitive name, whether inside a pallas_call) of every equation,
+    sub-jaxprs included."""
+    for eqn in jaxpr.eqns:
+        yield eqn.primitive.name, inside_kernel
+        inner = inside_kernel or eqn.primitive.name == "pallas_call"
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _eqns(sub, inner)
+
+
+@pytest.mark.parametrize("which", ["fwd", "vjp"])
+def test_n_the_block_mask_attention_is_the_kernel_alone(which):
+    """``flash_attention(..., diffusion_block=4)`` is one ``pallas_call`` and
+    its vjp (the forward's rule and the backward's) two, and beside them only
+    what lays the operands out and, in the backward, ``delta`` and the guard
+    of the logsumexp: no softmax arithmetic, no matmul and no copy of the
+    output (the noised blocks' own squares were a plain term merged by
+    logsumexp until PR 34).  And the calls without the block mask trace to
+    the parent's jaxpr, character for character."""
+    def traced(f, x):
+        if which == "fwd":
+            return jax.make_jaxpr(f)(x, x, x)
+        return jax.make_jaxpr(
+            lambda q, k, v, g: jax.vjp(f, q, k, v)[1](g))(x, x, x, x)
+
+    x = jax.ShapeDtypeStruct((2, 4, 2 * 384, 32), jnp.bfloat16)
+    names = list(_eqns(traced(lambda q, k, v: flash_attention(
+        q, k, v, causal=False, diffusion_block=4), x).jaxpr))
+    assert [n for n, _ in names].count("pallas_call") \
+        == {"fwd": 1, "vjp": 2}[which]
+    layout = {"custom_vjp_call", "jit", "pallas_call", "reshape", "pad",
+              "slice", "convert_element_type"}
+    delta_and_guard = {"mul", "reduce_sum", "gt", "select_n",
+                       "broadcast_in_dim"}
+    assert {n for n, inside in names if not inside} \
+        <= layout | (delta_and_guard if which == "vjp" else set())
+    for kind, x in (("causal", jax.ShapeDtypeStruct((1, 2, 1152, 64),
+                                                    jnp.bfloat16)),
+                    ("full", jax.ShapeDtypeStruct((1, 2, 200, 64),
+                                                  jnp.float32))):
+        text = str(traced(lambda q, k, v: flash_attention(
+            q, k, v, causal=kind == "causal"), x))
+        assert hashlib.sha256(text.encode()).hexdigest() \
+            == _CAUSAL_AS_IT_WAS[kind, which], (kind, which)
 
 
 def _layer(held, n_experts=8, k=2):
@@ -399,16 +484,28 @@ def test_i_every_model_the_benchmark_has_is_the_program_it_was(name):
     assert found["switches"] == [] and found["loops"] == [], name
 
 
-@pytest.mark.parametrize("impl,want", [("reference", "0x1.5bfd300000000p+2"),
-                                       ("flash", "0x1.5c2ed60000000p+2")])
-def test_m_the_sdar_toy_has_the_loss_it_had_with_the_whole_buffer(impl, want):
+@pytest.mark.parametrize("impl,dtype,want", [
+    ("reference", None, "0x1.5bfd300000000p+2"),
+    ("flash", None, "0x1.5c0c4e0000000p+2"),
+    ("reference", jnp.float32, "0x1.5c68860000000p+2"),
+    ("flash", jnp.float32, "0x1.5c68860000000p+2")])
+def test_m_the_sdar_toy_has_the_loss_it_had_with_the_whole_buffer(
+        impl, dtype, want):
     """The toy's objective at ``PRNGKey(0)`` weights under the noise of
     ``PRNGKey(0)``, on ``ZipfStream(held vocabulary, seed=5).rows(2, 48)``,
-    against the parent commit's (6530a06: every layer passing over all
+    against an earlier commit's (6530a06: every layer passing over all
     ``T * k`` rows): the order of a token's sum may change, the number may
-    not."""
+    not.  The flash row in the toy's own bf16 is PR 34's: a noised row's
+    output is rounded to bf16 once, from one softmax over all its keys, where
+    it was the kernel's bf16 result merged with the own squares' term in
+    float32 and rounded again (0x1.5c2ed6p+2 then, further from the XLA
+    row).  In float32 nothing rounds: the flash kernels give the XLA row's
+    number, as they did at the parent (0x1.5c6888p+2) to the last bit but
+    one."""
     cfg = dataclasses.replace(sdar_moe.model_config(TOY, 1),
                               attention_impl=impl)
+    if dtype is not None:
+        cfg = dataclasses.replace(cfg, dtype=dtype)
     model, params = init_params(cfg)
     batch = {k: jnp.asarray(v) for k, v in ZipfStream(
         cfg.vocab_size, seed=5).rows(2, 48).items()}
