@@ -27,6 +27,20 @@ by side, ``[x_t ; x]``, runs both through every layer under
 ``ops.attention.block_diffusion_mask`` with the positions ``0..L-1`` twice,
 and gives logits for the noised copy alone (SDAR is the block with this, the
 per-head norm, its own ``head_dim`` and a part of each layer's experts).
+Attention layers come in two more kinds, ``"full_attention"`` and
+``"sliding_attention"`` — the second under a window of ``sliding_window``
+positions (``ops.attention``'s band: a query sees itself and the
+``sliding_window - 1`` before it), its kernel calls under the scope
+``window`` — and a kind may have a rotary table of its own (``rope_tables``:
+``RopeTable``, YaRN-scaled frequencies, a part of each head rotated) and a
+layer a head count of its own (``n_head_per_layer``); ``attn_gate`` gives
+each head's output a sigmoid gate computed from the layer's normed input
+(``attn/wg``, applied under the scope ``gate``); ``mlp_types`` names each
+layer's feed-forward ``"dense"`` or ``"sparse"`` where ``moe_every``'s fixed
+period cannot; and the routed layer may score its experts by sigmoids, scale
+the chosen weights and add a shared expert every token passes
+(``router_scoring``, ``routed_scale``, ``d_shared_expert``: ``models/moe.py``)
+(Laguna-XS.2 is the block with all of these).
 Every such field at its default leaves the program the dense Llama it was.  Same TPU discipline as the GPT stack —
 bfloat16 activations, fused QKV-free layout matched to
 ``llama_partition_rules`` so tp/fsdp shardings apply by regex, attention
@@ -51,6 +65,25 @@ from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
 from ray_tpu.ops.attention import (block_diffusion_mask, flash_attention,
                                    mha_reference, ring_attention_sharded)
 from ray_tpu.parallel.sharding import constrain_residual
+
+
+@dataclass(frozen=True)
+class RopeTable:
+    """One rotary table: which part of a head turns, and how fast.  The
+    first ``rotary_fraction`` of each head's ``head_dim`` is rotated
+    (rotate-half inside that part), the rest passed through.  ``factor`` > 1:
+    YaRN (Peng et al. 2023) over the rotated part's frequencies — those that
+    turn more than ``beta_fast`` times in ``original_positions`` are kept,
+    those that turn fewer than ``beta_slow`` times are divided by ``factor``,
+    a linear ramp over the dimensions between — and cos and sin are
+    multiplied by ``attention_factor``."""
+    theta: float = 10000.0
+    rotary_fraction: float = 1.0
+    factor: float = 1.0
+    original_positions: int = 0
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    attention_factor: float = 1.0
 
 
 @dataclass(frozen=True)
@@ -109,6 +142,21 @@ class LlamaConfig:
     diffusion_block: int = 0
     diffusion_t_min: float = 1e-3
     mask_token_id: int = 0
+    # "sliding_attention" layers of layer_types see this many positions, the
+    # query's own among them; "full_attention" ones all before them
+    sliding_window: int = 0
+    # a layer's query heads, one entry a layer; empty: n_head in every layer
+    n_head_per_layer: Tuple[int, ...] = ()
+    # (layer kind, RopeTable) pairs: the rotary table of the attention layers
+    # of that kind; a kind without one rotates the whole head by rope_theta
+    rope_tables: Tuple[Tuple[str, RopeTable], ...] = ()
+    attn_gate: bool = False          # a sigmoid gate a head on the attention's output
+    # each layer's feed-forward, "dense" or "sparse" (the routed experts), one
+    # entry a layer; empty: by moe_every
+    mlp_types: Tuple[str, ...] = ()
+    router_scoring: str = "softmax"  # or "sigmoid": the experts' scores
+    routed_scale: float = 1.0        # x the chosen experts' weights
+    d_shared_expert: int = 0         # a SwiGLU every token passes, beside the routed
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -118,17 +166,44 @@ class LlamaConfig:
 
 def rope_frequencies(head_dim: int, positions, theta: float):
     """(S, head_dim/2) cos/sin tables for the given absolute positions."""
-    inv = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
-                                      dtype=jnp.float32) / head_dim))
+    return rope_table(head_dim, positions, RopeTable(theta=theta))
+
+
+def rope_table(head_dim: int, positions, table: RopeTable):
+    """(S, rot/2) cos/sin of ``table`` for the given positions, ``rot`` =
+    ``head_dim * table.rotary_fraction`` the rotated part of a head."""
+    import math
+
+    rot = int(head_dim * table.rotary_fraction)
+    inv = 1.0 / (table.theta ** (jnp.arange(0, rot, 2,
+                                            dtype=jnp.float32) / rot))
+    if table.factor > 1.0:
+        def turns_at(turns):    # the dimension that turns so often in the
+            return rot * math.log(      # original context
+                table.original_positions / (turns * 2 * math.pi)) \
+                / (2 * math.log(table.theta))
+        low = max(math.floor(turns_at(table.beta_fast)), 0)
+        high = min(math.ceil(turns_at(table.beta_slow)), rot - 1)
+        ramp = jnp.clip((jnp.arange(rot // 2, dtype=jnp.float32) - low)
+                        / max(high - low, 1e-3), 0.0, 1.0)
+        inv = inv / table.factor * ramp + inv * (1.0 - ramp)
     ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
-    return jnp.cos(ang), jnp.sin(ang)
+    cos, sin = jnp.cos(ang), jnp.sin(ang)
+    if table.attention_factor != 1.0:
+        cos, sin = cos * table.attention_factor, sin * table.attention_factor
+    return cos, sin
 
 
 def apply_rope(x, cos, sin):
     """x: (B, H, S, D); rotate-half (GPT-NeoX) convention — pairs
     (x_i, x_{i+D/2}) rotate by the position angle.  NOT the interleaved
     Meta-original layout: checkpoints using that convention need their
-    wq/wk columns permuted before loading."""
+    wq/wk columns permuted before loading.  Tables narrower than D / 2 turn
+    the first ``2 * cos.shape[-1]`` dimensions and pass the rest through."""
+    rot = 2 * cos.shape[-1]
+    if rot < x.shape[-1]:
+        return jnp.concatenate(
+            [apply_rope(x[..., :rot], cos, sin), x[..., rot:]], axis=-1)
     x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     # cos/sin: (S, D/2) -> broadcast over (B, H)
     rot1 = x1 * cos - x2 * sin
@@ -138,12 +213,18 @@ def apply_rope(x, cos, sin):
 
 class LlamaAttention(nn.Module):
     config: LlamaConfig
+    kind: str = "attention"     # or "full_attention", "sliding_attention"
+    n_head: int = 0             # this layer's query heads; 0: config.n_head
 
     @nn.compact
     def __call__(self, x, positions):
         cfg = self.config
         B, S, E = x.shape
-        H, KV = cfg.n_head, cfg.n_kv_head
+        H, KV = self.n_head or cfg.n_head, cfg.n_kv_head
+        window = cfg.sliding_window if self.kind == "sliding_attention" else 0
+        # the kind's own rotary table, or the whole head turned by rope_theta
+        table = dict(cfg.rope_tables).get(self.kind) or (
+            RopeTable(theta=cfg.rope_theta) if cfg.rope else None)
         D = cfg.head_dim or E // H
         assert H % KV == 0, "n_head must be a multiple of n_kv_head"
         q = nn.Dense(H * D, use_bias=False, dtype=cfg.dtype, name="wq")(x)
@@ -166,9 +247,9 @@ class LlamaAttention(nn.Module):
         if cfg.qk_norm is True:
             q, k = norm("q_norm", q), norm("k_norm", k)
         q, k, v = heads(q, "q_norm"), heads(k, "k_norm"), heads(v)
-        if cfg.rope:
+        if table is not None:
             with jax.named_scope("rope"):
-                cos, sin = rope_frequencies(D, positions, cfg.rope_theta)
+                cos, sin = rope_table(D, positions, table)
                 q = apply_rope(q, cos, sin)
                 k = apply_rope(k, cos, sin)
         if KV != H:  # GQA: each kv head serves H/KV query heads
@@ -190,6 +271,19 @@ class LlamaAttention(nn.Module):
                 raise NotImplementedError(
                     "block-diffusion attention over a sharded sequence: "
                     f"attention_impl={cfg.attention_impl!r} has no block mask")
+        elif window:
+            if cfg.attention_impl == "reference":
+                out = mha_reference(q, k, v, causal=True, window=window,
+                                    sm_scale=cfg.attn_scale)
+            elif cfg.attention_impl == "flash":
+                # the scope tells these calls from the full layers' in a trace
+                with jax.named_scope("window"):
+                    out = flash_attention(q, k, v, causal=True, window=window,
+                                          sm_scale=cfg.attn_scale)
+            else:
+                raise NotImplementedError(
+                    "window attention over a sharded sequence: "
+                    f"attention_impl={cfg.attention_impl!r} has no window")
         elif cfg.attention_impl == "ring":
             out = ring_attention_sharded(q, k, v, causal=True,
                                          sm_scale=cfg.attn_scale,
@@ -199,7 +293,14 @@ class LlamaAttention(nn.Module):
         else:
             out = flash_attention(q, k, v, causal=True,
                                   sm_scale=cfg.attn_scale)
-        out = out.transpose(0, 2, 1, 3).reshape(B, S, H * D)
+        out = out.transpose(0, 2, 1, 3)
+        if cfg.attn_gate:
+            # one scalar a head a token, from the layer's normed input
+            gate = nn.Dense(H, use_bias=False, dtype=cfg.dtype, name="wg")(x)
+            with jax.named_scope("gate"):
+                out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+                    out.dtype)[..., None]
+        out = out.reshape(B, S, H * D)
         return nn.Dense(E, use_bias=False, dtype=cfg.dtype, name="wo")(out)
 
 
@@ -217,10 +318,15 @@ class SwiGLU(nn.Module):
                         name="down_proj")(jax.nn.silu(gate) * up)
 
 
+ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention")
+
+
 class LlamaBlock(nn.Module):
     config: LlamaConfig
     routed: bool = False    # this layer's feed-forward: routed experts
-    mixer: str = "attention"    # this layer's token mixer, or "mamba"
+    # this layer's token mixer: "mamba", or attention of a kind
+    mixer: str = "attention"
+    n_head: int = 0         # this layer's query heads; 0: config.n_head
 
     @nn.compact
     def __call__(self, x, positions):
@@ -235,11 +341,12 @@ class LlamaBlock(nn.Module):
                        name="attn_norm")(x)
         if self.mixer == "mamba":
             x = add(x, Mamba2Mixer(cfg, name="mamba")(y))
-        elif self.mixer == "attention":
-            x = add(x, LlamaAttention(cfg, name="attn")(y, positions))
+        elif self.mixer in ATTENTION_KINDS:
+            x = add(x, LlamaAttention(cfg, self.mixer, self.n_head,
+                                      name="attn")(y, positions))
         else:
             raise ValueError(f"unknown layer type {self.mixer!r} (expected "
-                             "'attention' or 'mamba')")
+                             f"'mamba' or one of {ATTENTION_KINDS})")
         y = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
                        name="mlp_norm")(x)
         if self.routed:
@@ -247,7 +354,9 @@ class LlamaBlock(nn.Module):
                 n_experts=cfg.n_experts, top_k=cfg.moe_top_k,
                 d_model=cfg.d_model, d_ff=cfg.d_expert,
                 norm_topk_prob=cfg.norm_topk_prob, dtype=cfg.dtype,
-                experts_held=cfg.experts_held), name="moe")(y))
+                experts_held=cfg.experts_held, scoring=cfg.router_scoring,
+                routed_scale=cfg.routed_scale,
+                d_shared=cfg.d_shared_expert), name="moe")(y))
         return add(x, SwiGLU(cfg, name="mlp")(y))
 
 
@@ -268,9 +377,10 @@ class LlamaLMModel(nn.Module):
         if two_copies and (S // 2) % cfg.diffusion_block:
             raise ValueError(f"a copy of {S // 2} positions is not whole "
                              f"blocks of {cfg.diffusion_block}")
-        if cfg.layer_types and len(cfg.layer_types) != cfg.n_layer:
-            raise ValueError(f"layer_types names {len(cfg.layer_types)} "
-                             f"layers, n_layer is {cfg.n_layer}")
+        for name in ("layer_types", "mlp_types", "n_head_per_layer"):
+            if getattr(cfg, name) and len(getattr(cfg, name)) != cfg.n_layer:
+                raise ValueError(f"{name} names {len(getattr(cfg, name))} "
+                                 f"layers, n_layer is {cfg.n_layer}")
         wte = nn.Embed(padded_vocab(cfg.vocab_size), cfg.d_model,
                        dtype=cfg.dtype, name="wte")
         x = wte(input_ids)
@@ -287,10 +397,15 @@ class LlamaLMModel(nn.Module):
         else:
             block_cls = LlamaBlock
         for i in range(cfg.n_layer):
-            routed = cfg.moe_every > 0 and i % cfg.moe_every == cfg.moe_every - 1
+            if cfg.mlp_types:
+                routed = cfg.mlp_types[i] == "sparse"
+            else:
+                routed = cfg.moe_every > 0 \
+                    and i % cfg.moe_every == cfg.moe_every - 1
             mixer = cfg.layer_types[i] if cfg.layer_types else "attention"
-            x = constrain_residual(
-                block_cls(cfg, routed, mixer, name=f"h_{i}")(x, positions))
+            n_head = cfg.n_head_per_layer[i] if cfg.n_head_per_layer else 0
+            x = constrain_residual(block_cls(
+                cfg, routed, mixer, n_head, name=f"h_{i}")(x, positions))
         if two_copies:
             x = x[:, :S // 2]       # the head sees the noised copy alone
         x = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype, name="norm_f")(x)

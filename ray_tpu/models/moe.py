@@ -45,6 +45,12 @@ PR 32).  What ``ep > 1`` on a mesh still lacks is that exchange — the
 all-to-all that sends each token's rows to the device holding its expert and
 brings the results back — and it raises ``NotImplementedError``.
 
+The scores may be sigmoids in place of the softmax (``scoring``), the chosen
+weights scaled (``routed_scale``, after the renormalisation), and a shared
+expert added that every token passes: a SwiGLU of width ``d_shared``, the
+child ``shared`` under its own scope, whole on every chip whatever part of
+the routed experts the layer holds.
+
 ``MoEMlpBlock`` — the older GShard / Switch form, wired into GPT-2 only
 (``GPT2Config.moe_every``): top-k routing as DENSE dispatch / combine einsums
 against one-hot capacity tensors, GELU experts, tokens over capacity dropped;
@@ -191,6 +197,11 @@ class RoutedConfig:
     dtype: Any = jnp.bfloat16
     # (first index, count) of the experts this layer holds; None: all of them
     experts_held: Optional[Tuple[int, int]] = None
+    # an expert's score: its "softmax" probability over all the experts, or
+    # the "sigmoid" of its own logit
+    scoring: str = "softmax"
+    routed_scale: float = 1.0       # x the chosen weights, once normalised
+    d_shared: int = 0               # the shared expert's width; 0: none
 
 
 def _gmm_tiling(m: int, k: int, n: int):
@@ -507,13 +518,31 @@ def token_spec(mesh):
              tuple(a for a in ("sp", "tp") if a in mesh.shape) or None, None)
 
 
+class SharedSwiGLU(nn.Module):
+    """The expert every token passes: a dense SwiGLU."""
+
+    d_model: int
+    d_ff: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        def dense(n, name):
+            return nn.Dense(n, use_bias=False, dtype=self.dtype, name=name)
+        return dense(self.d_model, "down_proj")(
+            jax.nn.silu(dense(self.d_ff, "gate_proj")(x))
+            * dense(self.d_ff, "up_proj")(x))
+
+
 class RoutedSwiGLU(nn.Module):
     """Dropless top-k routed SwiGLU experts.  Call with x of shape (B, S, D).
 
     Sows, per call, into ``intermediates``: ``moe_load_balance``
     (``E * sum_e f_e P_e``: ``f_e`` the assignments expert ``e`` received per
     token — its share of the (token, slot) assignments times ``top_k`` —
-    ``P_e`` its mean router probability; ``top_k`` at balance), ``moe_z`` (mean of ``logsumexp(router logits) ** 2``) and
+    ``P_e`` its mean router probability, or under sigmoid scores, which do not
+sum to one over the experts, its mean share of a token's scores; ``top_k`` at
+balance), ``moe_z`` (mean of ``logsumexp(router logits) ** 2``) and
     ``moe_max_load`` (the busiest expert's assignments over the mean), each
     over all ``n_experts``; and, where the layer holds a part of them,
     ``moe_rows_held``: the assignments its own experts received, and
@@ -542,16 +571,25 @@ class RoutedSwiGLU(nn.Module):
             logits = nn.Dense(n_experts, use_bias=False, dtype=jnp.float32,
                               precision=jax.lax.Precision.HIGHEST,
                               name="router")(x.astype(jnp.float32))
-            probs = jax.nn.softmax(logits, axis=-1)
+            if cfg.scoring == "softmax":
+                probs = shares = jax.nn.softmax(logits, axis=-1)
+            elif cfg.scoring == "sigmoid":
+                probs = jax.nn.sigmoid(logits)
+                shares = probs / jnp.sum(probs, axis=-1, keepdims=True)
+            else:
+                raise ValueError(f"unknown scoring {cfg.scoring!r} (expected "
+                                 "'softmax' or 'sigmoid')")
             weights, idx = jax.lax.top_k(probs, k)
             if cfg.norm_topk_prob:
                 weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+            if cfg.routed_scale != 1.0:
+                weights = weights * cfg.routed_scale
             counts = jnp.sum(idx[..., None] == jnp.arange(n_experts),
                              axis=tuple(range(idx.ndim)), dtype=jnp.float32)
             share = counts / idx.size
             self.sow("intermediates", "moe_load_balance",
                      n_experts * k * jnp.sum(share * jnp.mean(
-                         probs.reshape(-1, n_experts), axis=0)))
+                         shares.reshape(-1, n_experts), axis=0)))
             self.sow("intermediates", "moe_z", jnp.mean(
                 jax.nn.logsumexp(logits, axis=-1) ** 2))
             self.sow("intermediates", "moe_max_load",
@@ -595,6 +633,9 @@ class RoutedSwiGLU(nn.Module):
         if held:    # every device's own capacity, from its own rows
             out, buffer_rows = out
             self.sow("intermediates", "moe_buffer_rows", jnp.sum(buffer_rows))
+        if cfg.d_shared:
+            out = out + SharedSwiGLU(d, cfg.d_shared, cfg.dtype,
+                                     name="shared")(x)
         return out
 
 

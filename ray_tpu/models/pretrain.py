@@ -130,7 +130,7 @@ def objective_fn(model, params, batch, key=None):
     cfg = model.config
     if _is_block_diffusion(cfg) and "x_t" not in batch:
         batch = _noised(cfg, batch, key)
-    if cfg.moe_every <= 0:
+    if cfg.moe_every <= 0 and "sparse" not in getattr(cfg, "mlp_types", ()):
         loss = loss_fn(model, params, batch)
         return loss, (loss, {})
     from ray_tpu.models.moe import collect_aux

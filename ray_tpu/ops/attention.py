@@ -9,7 +9,8 @@ Greenfield relative to the reference — it has no sequence parallelism anywhere
   Q / dO) streamed from HBM tile by tile through their BlockSpecs, the
   running (m, l, acc) of the flash recurrence in VMEM scratch.  Operands go
   to the MXU in the inputs' dtype, accumulation is float32, no tile above the
-  causal diagonal is fetched or computed.  The backward recomputes P and dS
+  causal diagonal — or, under ``window``, below the band — is fetched or
+  computed.  The backward recomputes P and dS
   once a tile from the saved logsumexp and takes dV, dK and dQ from them, so
   memory stays O(S·d) rather than O(S²); the one thing in VMEM that scales
   with the sequence is dQ's float32 accumulator (S·d), and nothing but the
@@ -42,6 +43,7 @@ from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
@@ -81,10 +83,12 @@ def block_diffusion_mask(length: int, block: int):
 
 
 def mha_reference(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = None,
-                  q_offset: int = 0, k_offset: int = 0, mask=None):
+                  q_offset: int = 0, k_offset: int = 0, mask=None,
+                  window: int = 0):
     """Naive attention; ground truth for kernels. q,k,v: (B, H, S, D).
     ``mask``: a boolean (S_q, S_k) array of the pairs that are seen, in place
-    of the causal one."""
+    of the causal one.  ``window`` > 0: under the causal mask a query sees
+    itself and the ``window - 1`` positions before it."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
@@ -93,7 +97,10 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = N
     elif causal:
         qi = jnp.arange(q.shape[2])[:, None] + q_offset
         ki = jnp.arange(k.shape[2])[None, :] + k_offset
-        logits = jnp.where(qi >= ki, logits, NEG_INF)
+        seen = qi >= ki
+        if window:
+            seen &= qi - ki < window
+        logits = jnp.where(seen, logits, NEG_INF)
     w = jax.nn.softmax(logits, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", w.astype(v.dtype), v)
 
@@ -126,7 +133,10 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = N
 # is fetched for it either (``_Tiles.tile_of``).  Tiles that the diagonal or
 # the key padding crosses take a masked body (on the diagonal, where the tiling
 # allows, chunk by chunk: ``_on_tiles``), all others the bare one.  The mask is
-# a function of the tile's indices alone.
+# a function of the tile's indices alone.  Under a window the tiles below the
+# band are skipped as those above the diagonal are, and more: the reduction
+# axis of either grid is only as long as the band is wide in tiles, and starts
+# at the band's first tile (``_Tiles.walk``).
 _NT = (((1,), (1,)), ((), ()))  # a @ b.T
 _NN = (((1,), (0,)), ((), ()))  # a @ b
 _TN = (((0,), (0,)), ((), ()))  # a.T @ b
@@ -190,12 +200,31 @@ class _Tiles(NamedTuple):
     # lane-wide chunk of positions against itself (``_same_block``).  0: no
     # such mask.
     bd: int = 0
+    # A window under the causal mask: query r sees key c iff
+    # 0 <= r + offset - c < window, a band ``window`` wide under the diagonal.
+    # Tiles below the band do no more work than tiles above the diagonal, and
+    # neither kind is a step of the grid: its inner axis has only the
+    # ``steps`` tiles a band can meet in one row (column) of tiles, counted
+    # from the band's first (``walk``).  The tile edge is no more than the
+    # window in whole lanes, so that a 512-wide band is not cut from
+    # 1024-wide tiles.  The tile the diagonal crosses and the tiles the band's
+    # lower edge crosses are masked (chunk by chunk under ``tri``, each chunk
+    # against only the keys its queries can see), everything between is bare.
+    # 0: no window.
+    window: int = 0
+    steps_k: int = 0    # the inner axis with the keys inner (window only)
+    steps_q: int = 0    # ... and with the queries inner
 
     @classmethod
     def of(cls, s_q, s_k, d, dtype, causal, offset, diag_chunk,
-           block_q=None, block_k=None, bd=0):
+           block_q=None, block_k=None, bd=0, window=0):
         """``s_q``: the queries' length; under ``bd`` one half's, which is
         ``s_k``."""
+        if window:
+            assert causal and not bd and window > 0, (causal, bd, window)
+            edge = _round_up(window, LANES)
+            block_q = block_q or min(_block(s_q, d, dtype), edge)
+            block_k = block_k or min(_block(s_k, d, dtype), edge)
         block_q = _block(s_q, d, dtype, block_q)
         block_k = _block(s_k, d, dtype, block_k)
         s_k_pad = _round_up(s_k, block_k)
@@ -216,14 +245,67 @@ class _Tiles(NamedTuple):
         if (causal and block_q == block_k and offset % block_q == 0
                 and (s_k_pad == s_k or s_q + offset <= s_k)):
             tri = diag_chunk if block_q % diag_chunk == 0 else LANES
-        return cls(causal, offset, block_q, block_k,
-                   _round_up(s_q, block_q) // block_q, s_k_pad // block_k,
-                   None if s_k_pad == s_k else s_k, tri)
+        t = cls(causal, offset, block_q, block_k,
+                _round_up(s_q, block_q) // block_q, s_k_pad // block_k,
+                None if s_k_pad == s_k else s_k, tri)
+        if not window:
+            return t
+        t = t._replace(window=window)
+        # the most tiles the band meets in a row (a column) of tiles
+        iq, ik = np.arange(t.nq), np.arange(t.nk)
+        return t._replace(
+            steps_k=int(np.max(t._last_k(iq, np)
+                               - np.minimum(t._first_k(iq, np), t.nk - 1))
+                        ) + 1,
+            steps_q=int(np.max(t._last_q(ik, np)
+                               - np.minimum(t._first_q(ik, np), t.nq - 1))
+                        ) + 1)
+
+    # The band's first and last tile along the inner axis, for traced grid
+    # indices (``jnp``) and, to count the steps, for all of them (``np``);
+    # the last ones clamped into the grid.
+    def _first_k(self, iq, xp=jnp):
+        return xp.maximum(iq * self.block_q + self.offset - self.window + 1,
+                          0) // self.block_k
+
+    def _last_k(self, iq, xp=jnp):
+        return xp.minimum(xp.maximum(
+            iq * self.block_q + self.block_q - 1 + self.offset, 0)
+            // self.block_k, self.nk - 1)
+
+    def _first_q(self, ik, xp=jnp):
+        return xp.maximum(ik * self.block_k - self.offset, 0) // self.block_q
+
+    def _last_q(self, ik, xp=jnp):
+        return xp.minimum(xp.maximum(
+            ik * self.block_k + self.block_k + self.window - 2 - self.offset,
+            0) // self.block_q, self.nq - 1)
+
+    def steps(self, q_is_inner: bool) -> int:
+        """The length of the grid's inner axis."""
+        if self.window:
+            return self.steps_q if q_is_inner else self.steps_k
+        return self.nq if q_is_inner else self.nk
+
+    def walk(self, i, j, q_is_inner: bool):
+        """Grid position -> the tile (iq, ik) the step stands for, not
+        clamped: under a window the inner axis counts from the band's first
+        tile, so a step past the band's last names a tile that does no work
+        (``_on_tiles``) or none at all."""
+        if not self.window:
+            return (j, i) if q_is_inner else (i, j)
+        if q_is_inner:
+            return self._first_q(i) + j, i
+        return i, self._first_k(i) + j
 
     def tile_of(self, i, j, q_is_inner: bool):
         """Grid position -> (iq, ik), the inner one clamped to the nearest
         tile that does work."""
-        iq, ik = (j, i) if q_is_inner else (i, j)
+        iq, ik = self.walk(i, j, q_is_inner)
+        if self.window:
+            if q_is_inner:
+                return jnp.minimum(iq, self._last_q(ik)), ik
+            return iq, jnp.minimum(ik, self._last_k(iq))
         if self.bd:
             half, iq_l = self.half_of(iq)
             if q_is_inner:
@@ -316,23 +398,29 @@ def _unhalved(x, l: int, axis: int):
     return x.reshape(*shape[:axis], 2 * l, *shape[axis + 1:])
 
 
-def _masked(s, q_dim: int, thresh, k_limit, bd: int = 0):
+def _masked(s, q_dim: int, thresh, k_limit, bd: int = 0, below=None):
     """A tile of scaled scores whose dim ``q_dim`` runs over queries r and
     whose other dim runs over keys c, with NEG_INF where r - c < thresh (the
-    key is past the query) or c >= k_limit (the key is padding); None: no such
+    key is past the query), r - c >= below (the key is under the window) or
+    c >= k_limit (the key is padding); None: no such
     mask.  Under ``bd`` r counts in whole blocks: r - r % bd.  (``jax.lax``
     throughout the tile bodies: they are traced once per chunk of every
     diagonal tile, and ``jnp``'s wrappers cost several times the primitive to
     trace.)"""
-    if thresh is None and k_limit is None:
+    if thresh is None and k_limit is None and below is None:
         return s
     c = lax.broadcasted_iota(jnp.int32, s.shape, 1 - q_dim)
     valid = None
-    if thresh is not None:
+    if thresh is not None or below is not None:
         r = lax.broadcasted_iota(jnp.int32, s.shape, q_dim)
         if bd > 1:      # a power of two: it divides LANES
             r = lax.bitwise_and(r, jnp.int32(-bd))
-        valid = lax.ge(lax.sub(r, c), thresh)
+        ahead = lax.sub(r, c)
+        if thresh is not None:
+            valid = lax.ge(ahead, thresh)
+        if below is not None:
+            in_w = lax.lt(ahead, below)
+            valid = in_w if valid is None else lax.bitwise_and(valid, in_w)
     if k_limit is not None:
         in_k = lax.lt(c, k_limit)
         valid = in_k if valid is None else lax.bitwise_and(valid, in_k)
@@ -359,13 +447,35 @@ def _lane_chunks(block: int):
     return [slice(j, j + LANES) for j in range(0, block, LANES)]
 
 
+def _band_chunks(t: _Tiles, m: int):
+    """Under a window and ``t.tri``: the chunks of the tile ``m`` tiles under
+    the diagonal (thresh == -m * block), as ``part``'s arguments: ``t.tri``
+    queries at a time against only the lanes of keys that one of them sees,
+    with the diagonal's mask on the diagonal tile and the lower edge's where
+    it passes through the chunk."""
+    b, c = t.block_q, t.tri
+    for r0 in range(0, b, c):
+        # a query r of the tile sees its keys r + m b - window < c <= r + m b
+        lo = max(0, (r0 + m * b - t.window + 1) // LANES * LANES)
+        hi = min(b, r0 + c + m * b)
+        if lo >= hi:
+            continue
+        thresh = lo - r0 - m * b        # slice-local: r - c >= thresh
+        below = thresh + t.window       # ... and r - c < below
+        yield (slice(r0, r0 + c), slice(lo, hi),
+               thresh if thresh > lo - hi + 1 else None, None,
+               below if below <= c - 1 else None)
+
+
 def _on_tiles(t: _Tiles, iq, ik, part):
     """Run ``part(q_slice, k_slice, thresh, k_limit)`` (see ``_masked``) over
     tile (iq, ik) as its kind needs: not at all above the diagonal, bare below
     it, masked where the diagonal or the key padding passes through.  With
     ``t.tri``, a tile on the diagonal is square and aligned to it, and is done
     in chunks of ``t.tri`` queries against only the keys up to each chunk's
-    last query."""
+    last query.  Under ``t.window`` a tile below the band does nothing either,
+    and a tile the band's lower edge crosses is masked as well, with ``part``'s
+    fifth argument ``below``."""
     whole = slice(None)
 
     def bare():
@@ -391,6 +501,30 @@ def _on_tiles(t: _Tiles, iq, ik, part):
         thresh = ik * t.block_k - iq * t.block_q - t.offset
         live = thresh <= t.block_q - 1
     crossing = thresh > 1 - t.block_k
+    if t.window:
+        below = thresh + t.window
+        # (a step past the band's last tile may name a tile outside the grid)
+        live = functools.reduce(jnp.logical_and, (
+            live, below > 1 - t.block_k, iq < t.nq, ik < t.nk))
+        is_masked = jnp.logical_or(crossing, below <= t.block_q - 1)
+        if t.tri:
+            # thresh is a multiple of the tile edge: the diagonal tile and
+            # the one or two tiles the lower edge crosses, each its own body
+            b = t.block_q
+            # (the tiles m below the diagonal with 1 - b < window - m b < b)
+            edge = range(max(0, -(-(t.window - b + 1) // b)),
+                         (t.window + b - 2) // b + 1)
+            for m in sorted({0, *edge}):
+                def chunked(m=m):
+                    for args in _band_chunks(t, m):
+                        part(*args)
+                pl.when(jnp.logical_and(live, thresh == -m * b))(chunked)
+        else:
+            is_masked = jnp.logical_or(is_masked, padded)
+            pl.when(jnp.logical_and(live, is_masked))(
+                lambda: part(whole, whole, thresh, k_limit, below))
+        pl.when(jnp.logical_and(live, jnp.logical_not(is_masked)))(bare)
+        return
     if t.tri:
         def masked():
             for j in range(t.block_q // t.tri):
@@ -417,10 +551,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles):
     # under ``t.bd`` two more inputs: the noised K and V tile of the query
     # tile's own positions
     *own_refs, o_ref, lse_ref, m_col, l_col, acc = rest
-    iq, ik = pl.program_id(1), pl.program_id(2)
+    row, step = pl.program_id(1), pl.program_id(2)
+    iq, ik = t.walk(row, step, False)
     d = q_ref.shape[1]
 
-    from_nothing = ik == 0
+    from_nothing = step == 0
     if t.bd:
         # a noised query tile starts from its own squares, where another
         # starts from nothing: every query sees itself, so its running max
@@ -449,17 +584,17 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles):
         l_col[...] = jnp.zeros_like(l_col)
         acc[...] = jnp.zeros_like(acc)
 
-    def part(qs, ks, thresh, k_limit):
+    def part(qs, ks, thresh, k_limit, below=None):
         v = v_ref[ks, :]
         s = _masked(lax.mul(_dot(q_ref[qs, :], k_ref[ks, :], _NT), sm_scale),
-                    0, thresh, k_limit, t.bd)
+                    0, thresh, k_limit, t.bd, below)
         lanes = (s.shape[0], LANES)
         m_old = m_col[qs, :]
         m_new = lax.max(m_old, jnp.broadcast_to(
             jnp.max(s, axis=1, keepdims=True), lanes))
         m_col[qs, :] = m_new
         alpha = lax.exp(lax.sub(m_old, m_new))
-        if thresh is not None or k_limit is not None:
+        if not (thresh is None and k_limit is None and below is None):
             # A row with every key masked so far has m == NEG_INF, and
             # exp(s - m) would be 1 per column: take its exp against 0.
             m_new = lax.select(lax.gt(m_new, NEG_INF / 2), m_new,
@@ -472,7 +607,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles):
 
     _on_tiles(t, iq, ik, part)
 
-    @pl.when(ik == t.nk - 1)
+    @pl.when(step == t.steps(False) - 1)
     def _():
         l = l_col[...]
         empty = lax.eq(l, 0.0)  # no key seen: output 0, lse NEG_INF
@@ -484,11 +619,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles):
         lse_ref[...] = lse.T[:1, :]
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10),
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11),
                    inline=True)
 def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
                    k_offset: int, block_q: Optional[int],
-                   block_k: Optional[int], interpret: bool, bd: int = 0):
+                   block_k: Optional[int], interpret: bool, bd: int = 0,
+                   window: int = 0):
     """``out`` (b, h, s_q, d) and the logsumexp of every query's scaled
     scores as (b*h, 1, s_q) rows, NEG_INF where a query sees no key.  Under
     ``bd`` (see ``_Tiles``) q, k and v are the two copies of ``s_q / 2``
@@ -502,7 +638,8 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
     b, h, s_q, d = q.shape
     s_k = k.shape[2] // 2 if bd else k.shape[2]     # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
-                  q_offset - k_offset, _FWD_DIAG_CHUNK, block_q, block_k, bd)
+                  q_offset - k_offset, _FWD_DIAG_CHUNK, block_q, block_k, bd,
+                  window)
     s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
     q_spec, row_spec, k_spec, *own_spec = t.specs(d, q_is_inner=False)
     q_rows, k_rows = (_halves, _copies) if bd else (_rows, _rows)
@@ -510,7 +647,7 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
         q, k, v = q_rows(q, s_q_pad), k_rows(k, s_k_pad), k_rows(v, s_k_pad)
         out, lse = pl.pallas_call(
             functools.partial(_flash_fwd_kernel, sm_scale=sm_scale, t=t),
-            grid=(b * h, t.nq, t.nk),
+            grid=(b * h, t.nq, t.steps(False)),
             in_specs=[q_spec, k_spec, k_spec] + own_spec * 2,
             out_specs=[q_spec, row_spec],
             out_shape=[jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype),
@@ -548,8 +685,9 @@ def _bwd_vmem_bytes(s_q_pad: int, d: int, dtype) -> int:
 def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                       dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
                       *, sm_scale: float, t: _Tiles):
-    ik, iq = pl.program_id(1), pl.program_id(2)
-    last_k, last_q = pl.num_programs(1) - 1, pl.num_programs(2) - 1
+    ik, step = pl.program_id(1), pl.program_id(2)
+    iq = t.walk(ik, step, True)[0]
+    last_k, last_step = pl.num_programs(1) - 1, pl.num_programs(2) - 1
     if t.bd:
         # both copies' tile ik: the clean one takes the walk and the
         # accumulators, the noised one the own squares of query tile ik
@@ -557,21 +695,21 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
             (dvn_ref, dv_ref) = ((r.at[0], r.at[1])
                                  for r in (k_ref, v_ref, dk_ref, dv_ref))
 
-    @pl.when(jnp.logical_and(ik == 0, iq == 0))
+    @pl.when(jnp.logical_and(ik == 0, step == 0))
     def _():
         dq_acc[...] = jnp.zeros_like(dq_acc)
 
-    @pl.when(iq == 0)
+    @pl.when(step == 0)
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
 
-    def part(qs, ks, thresh, k_limit, own=False):
+    def part(qs, ks, thresh, k_limit, below=None, own=False):
         kr, vr = (kn_ref, vn_ref) if own else (k_ref, v_ref)
         q, do, k = q_ref[qs, :], do_ref[qs, :], kr[ks, :]
         st, lse = lax.mul(_dot(k, q, _NT), sm_scale), lse_ref[:, qs]
         st = _same_block(st, t.bd) if own \
-            else _masked(st, 1, thresh, k_limit, t.bd)
+            else _masked(st, 1, thresh, k_limit, t.bd, below)
         pt = lax.exp(lax.sub(st, lse))      # P^T, zero where masked
         if own:     # nothing else reaches these keys: no sum over steps
             dvn_ref[ks, :] = _dot(pt.astype(do.dtype), do, _NN
@@ -597,20 +735,21 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                 part(rows, rows, None, None, own=True)
     _on_tiles(t, iq, ik, part)
 
-    @pl.when(iq == last_q)
+    @pl.when(step == last_step)
     def _():
         dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
 
-    @pl.when(jnp.logical_and(ik == last_k, iq == last_q))
+    @pl.when(jnp.logical_and(ik == last_k, step == last_step))
     def _():
         dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11), inline=True)
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12),
+                   inline=True)
 def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
                     q_offset: int, k_offset: int, interpret: bool,
-                    bd: int = 0):
+                    bd: int = 0, window: int = 0):
     """dq, dk, dv of ``_flash_attention`` from its residuals (``lse`` as the
     forward leaves it: (b*h, 1, s_q) rows) and ``g``.
 
@@ -620,7 +759,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
     b, h, s_q, d = q.shape
     s_k = k.shape[2] // 2 if bd else k.shape[2]     # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
-                  q_offset - k_offset, _BWD_DIAG_CHUNK, bd=bd)
+                  q_offset - k_offset, _BWD_DIAG_CHUNK, bd=bd, window=window)
     s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
 
     def row(x, fill):
@@ -645,7 +784,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
     dq_spec = pl.BlockSpec((None, s_q_pad, d), lambda bh, i, j: (bh, 0, 0))
     dq, dk, dv = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, sm_scale=sm_scale, t=t),
-        grid=(b * h, t.nk, t.nq),
+        grid=(b * h, t.nk, t.steps(True)),
         in_specs=[q_spec, q_spec, row_spec, row_spec, k_spec, k_spec],
         out_specs=[dq_spec, dkv_spec, dkv_spec],
         out_shape=[jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype)]
@@ -672,26 +811,27 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
 
 
 # ============================================================= public op
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_attention(q, k, v, causal, sm_scale, q_offset, k_offset,
-                     block_q, block_k):
+                     block_q, block_k, window=0):
     out, _ = _flash_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
-                            block_q, block_k, _interpret())
+                            block_q, block_k, _interpret(), 0, window)
     return out
 
 
-def _flash_fwd_rule(q, k, v, causal, sm_scale, q_offset, k_offset, block_q, block_k):
+def _flash_fwd_rule(q, k, v, causal, sm_scale, q_offset, k_offset, block_q,
+                    block_k, window):
     out, lse = _flash_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
-                              block_q, block_k, _interpret())
+                              block_q, block_k, _interpret(), 0, window)
     return out, (q, k, v, out, lse)
 
 
 def _flash_bwd_rule(causal, sm_scale, q_offset, k_offset, block_q, block_k,
-                    residuals, g):
+                    window, residuals, g):
     q, k, v, out, lse = residuals
     with jax.named_scope("flash_bwd"):
         return _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                               q_offset, k_offset, _interpret())
+                               q_offset, k_offset, _interpret(), 0, window)
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -737,8 +877,12 @@ def _bhsd_spec(mesh, batch_axes, head_axis, seq_axis=None):
 def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = None,
                     q_offset: int = 0, k_offset: int = 0,
                     block_q: Optional[int] = None, block_k: Optional[int] = None,
-                    diffusion_block: int = 0):
+                    diffusion_block: int = 0, window: int = 0):
     """Blockwise (flash) attention. q,k,v: (B, H, S, D) -> (B, H, S, D).
+
+    ``window`` > 0, under the causal mask: a query sees itself and the
+    ``window - 1`` positions before it, and no tile outside that band is
+    fetched or visited (``_Tiles``).
 
     ``diffusion_block`` > 0: the mask of block-diffusion training in place of
     the causal one (``block_diffusion_mask``; S is a noised and a clean copy
@@ -751,6 +895,8 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    if window and (diffusion_block or not causal):
+        raise ValueError("a window belongs to the causal mask")
     if diffusion_block:
         f = functools.partial(_flash_bd, sm_scale=float(sm_scale),
                               bd=int(diffusion_block))
@@ -758,7 +904,7 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
         f = functools.partial(
             _flash_attention, causal=causal, sm_scale=float(sm_scale),
             q_offset=int(q_offset), k_offset=int(k_offset),
-            block_q=block_q, block_k=block_k)
+            block_q=block_q, block_k=block_k, window=int(window))
     mesh = ambient_mesh()
     if mesh is None or mesh.size == 1:
         return f(q, k, v)

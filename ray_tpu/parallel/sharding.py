@@ -71,6 +71,8 @@ def llama_partition_rules() -> PartitionRules:
     return PartitionRules([
         (r"wte/embedding", _spec("tp", "fsdp")),
         (r"attn/(wq|wk|wv)/kernel", _spec("fsdp", "tp")),
+        # the gate on the attention's output: a column a query head
+        (r"attn/wg/kernel", _spec("fsdp", "tp")),
         (r"attn/wo/kernel", _spec("tp", "fsdp")),
         (r"mlp/(gate_proj|up_proj)/kernel", _spec("fsdp", "tp")),
         (r"mlp/down_proj/kernel", _spec("tp", "fsdp")),
@@ -78,6 +80,9 @@ def llama_partition_rules() -> PartitionRules:
         # router replicated; expert arrays (E, ., .) over ep, then as the
         # dense MLP's
         (r"moe/router/kernel", _spec()),
+        # the expert every token passes, as the dense MLP
+        (r"moe/shared/(gate_proj|up_proj)/kernel", _spec("fsdp", "tp")),
+        (r"moe/shared/down_proj/kernel", _spec("tp", "fsdp")),
         (r"moe/(gate_proj|up_proj)", _spec("ep", "fsdp", "tp")),
         (r"moe/down_proj", _spec("ep", "tp", "fsdp")),
         # mamba layers (models/mamba.py::Mamba2Mixer as h_<n>/mamba): the two

@@ -19,7 +19,13 @@ one-chip block-diffusion step of SDAR-30B-A3B-Chat as one chip of eight holds
 it (2048 wide, 32 / 4 heads of 128, 16 of 128 experts of 768 held, 18,992
 vocabulary rows, six layers, two rows of 4096 tokens as 8192 positions,
 remat): the flash kernels under the block mask and the grouped matmuls over a
-worst-case row buffer meet the compiler, beside 10.3 GB of state.
+worst-case row buffer meet the compiler, beside 10.3 GB of state.  And the
+one-chip step of Laguna-XS.2 as one chip of eight holds it (2048 wide, a full
++ dense layer, three sliding + sparse layers and a full + sparse one, 48 / 64
+query heads over 8 key/value heads of 128, a window of 512, 32 of 256 experts
+of 512 held beside a shared one, 12,544 vocabulary rows, two rows of 8192,
+remat): the flash kernels' window mode at 512-wide tiles beside the causal
+ones, and the grouped matmuls at the 512-wide shape, beside 11.1 GB of state.
 
 Every case runs in a subprocess (this file, as a script): the libtpu client
 must never meet the forced-CPU test process, and the child must NOT inherit
@@ -89,6 +95,12 @@ def _build(case: str, compile_: bool) -> dict:
         (config, seq), rows = _cell("sdar-bd-s4k-1chip"), 2
         assert config.experts_held == (0, 16) and config.n_experts == 128
         assert config.objective == "block_diffusion" and config.n_layer == 6
+    elif case == "laguna":
+        mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+        (config, seq), rows = _cell("laguna-s8k-1chip"), 2
+        assert config.experts_held == (0, 32) and config.n_experts == 256
+        assert config.n_head_per_layer == (48, 64, 64, 64, 48)
+        assert config.sliding_window == 512 and seq == 8192
     elif case == "granite":
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
         (config, seq), rows = _cell("granite-h-s8k-1chip"), 1
@@ -283,6 +295,32 @@ def test_sdar_step_compiles_and_fits_the_chip():
     # checkpoint's recomputation of the forward's loop is dropped, nothing
     # reads it (PR 32)
     assert row["tpu_custom_calls"] == 6 * (3 + 12), row
+    assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
+
+
+def test_laguna_step_lowers_for_one_v5e_chip():
+    """Tier-1: the one-chip step of Laguna-XS.2 at published widths (five
+    layers of three kinds, 32 of 256 experts held, two rows of 8192) lowers
+    for the TPU with its Mosaic kernels in it: the flash kernels, causal and
+    under the window, and the grouped matmuls of the held experts."""
+    kernels = _child(["laguna"], compile_=False)["laguna"]["lowered_kernels"]
+    assert kernels.pop("kernel") > 0
+    assert set(kernels) == {"flash_fwd", "flash_bwd"}, kernels
+
+
+@pytest.mark.slow
+def test_laguna_step_compiles_and_fits_the_chip():
+    """The TPU compiler takes the window kernels (tiles of 512, two steps of
+    the reduction axis) beside the causal ones and the grouped matmuls over
+    512-wide experts, and its memory analysis says the five layers fit one
+    chip at two rows of 8192 (PR 35: 8.30 GB of arguments + 4.54 GB of
+    temporaries)."""
+    row = _child(["laguna"], compile_=True)["laguna"]
+    print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
+    assert "refused" not in row, row
+    # a layer: flash forward, its recomputation, the backward's one kernel;
+    # a sparse layer's held experts: twelve grouped-matmul calls, as SDAR's
+    assert row["tpu_custom_calls"] == 5 * 3 + 4 * 12, row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
