@@ -36,7 +36,16 @@ ladder the configuration gives (``capacity_ladder``: the share and its
 multiples up to ``T * k``) that holds them, each device of a mesh for its own
 rows.  The last rung is the whole buffer, so nothing is dropped, nothing is
 approximated and nothing stands in for the absent chips or for their
-exchange.  The loop has a differentiation rule of its own (``_through_held``):
+exchange.  A piece's rows reach their tokens by one Pallas pass
+(``_onto_tokens``): an assignment is ``token * k + slot``, so the rows by
+assignment are in token order and a row's place there is a running count, not
+a sort; a tile of tokens then owns one run of rows, which the kernel reads as
+the grouped matmul wrote them and adds into the float32 sum in place, a
+(tokens x rows) selection matrix on the MXU, the forward's gate weights as
+three bf16 parts — an XLA scatter-add of the same rows ran at a twentieth of
+the memory's speed (``PERF.md``, PR 36).  The backward's sum of the rows'
+gradients into their tokens is the same pass without gates.
+The loop has a differentiation rule of its own (``_through_held``):
 reverse mode cannot pass through a trip count found on the device, and the
 rule's backward is the same loop with a piece's forward recomputed and
 transposed.  It is one body whatever the rows: a ``lax.switch`` over a body a
@@ -350,6 +359,8 @@ class _Route(NamedTuple):
     order: Any      # the assignment (token * k + slot) in buffer row j: the
                     # held experts' alone, by expert, a group in token order
     sizes: Any      # (n_held,): the assignments each held expert received
+    row: Any        # (T * k,) each assignment's buffer row; past the buffer's
+                    # end for an absent expert's
 
 
 class _Piece(NamedTuple):
@@ -357,6 +368,7 @@ class _Piece(NamedTuple):
     slots: Any      # (c,) the assignments of the piece's rows
     live: Any       # (c,) the rows an assignment stands in: the first ones
     sizes: Any      # (n_held,) each held expert's rows inside the piece
+    mine: Any       # (T * k,) the assignments that stand in the piece
 
 
 def _route(flat, n_held: int, n_rows: int) -> _Route:
@@ -370,10 +382,10 @@ def _route(flat, n_held: int, n_rows: int) -> _Route:
     row = jnp.sum(jnp.where(hot, jnp.cumsum(sizes) - sizes + jnp.cumsum(
         hot, axis=0, dtype=jnp.int32) - 1, 0), axis=1)
     # (an absent expert's assignment has no row: past the end, dropped)
-    order = jnp.zeros((n_rows,), jnp.int32).at[
-        jnp.where(flat < n_held, row, n_rows)].set(
-            jnp.arange(flat.shape[0], dtype=jnp.int32), mode="drop")
-    return _Route(order, sizes)
+    row = jnp.where(flat < n_held, row, n_rows)
+    order = jnp.zeros((n_rows,), jnp.int32).at[row].set(
+        jnp.arange(flat.shape[0], dtype=jnp.int32), mode="drop")
+    return _Route(order, sizes, row)
 
 
 def _piece(route: _Route, i, c: int) -> _Piece:
@@ -382,27 +394,173 @@ def _piece(route: _Route, i, c: int) -> _Piece:
     return _Piece(
         jax.lax.dynamic_slice(route.order, (lo,), (c,)),
         lo + jnp.arange(c) < ends[-1],
-        jnp.clip(ends, lo, lo + c) - jnp.clip(ends - route.sizes, lo, lo + c))
+        jnp.clip(ends, lo, lo + c) - jnp.clip(ends - route.sizes, lo, lo + c),
+        (route.row >= lo) & (route.row < lo + c))
 
 
-def _piece_forward(x, weights, route: _Route, i, c: int, k: int):
+def _beside(scope: str, name: str):
+    """The scope ``name`` of a piece's work, spelled with the module's whole
+    path (``scope``: ``h_<n>/moe``) where the layer gave it.  JAX prints a
+    loop body's operations under ``<where the loop stands>/while/body/``, so
+    a plain ``experts`` inside the loop would read ``moe/while/body/experts``;
+    with the path spelled out a profile shows ``h_<n>/moe/dispatch``,
+    ``h_<n>/moe/experts`` and ``h_<n>/moe/combine`` behind the loop's prefix
+    as a layer without a loop has them, and whatever selects the grouped
+    matmuls by ``h_<n>/moe/experts/`` finds them and nothing else."""
+    return jax.named_scope(f"{scope}/{name}" if scope else name)
+
+
+def _piece_forward(x, weights, route: _Route, i, c: int, k: int, scope: str):
     """The piece's rows through their experts: the token rows gathered, the
     three grouped matmuls over ``c`` rows, ``silu * up``.  Rows past the live
     ones belong to no group: the kernels leave them unwritten."""
     gate, up, down = weights
     piece = _piece(route, i, c)
-    with jax.named_scope("dispatch"):
+    with _beside(scope, "dispatch"):
         rows = x[piece.slots // k]
-    a, b = _gmm(rows, gate, piece.sizes), _gmm(rows, up, piece.sizes)
-    h = jax.nn.silu(a) * b
-    return piece, rows, (a, b), h, _gmm(h, down, piece.sizes)
+    with _beside(scope, "experts"):
+        a, b = _gmm(rows, gate, piece.sizes), _gmm(rows, up, piece.sizes)
+        h = jax.nn.silu(a) * b
+        return piece, rows, (a, b), h, _gmm(h, down, piece.sizes)
 
 
-def _onto_tokens(acc, rows, piece: _Piece, k: int):
+def _tile(n: int, most: int) -> int:
+    """The largest tile of at most ``most`` (halved down to 8) that divides
+    ``n``; ``n`` itself where none does."""
+    while most >= 8:
+        if n % most == 0:
+            return most
+        most //= 2
+    return n
+
+
+# The sum of a piece's rows into their tokens goes tile of tokens by tile of
+# tokens: 128 tokens against blocks of 128 rows, by the chip's clock at the
+# cells' shapes (PERF.md, PR 36).
+_TOKEN_TILE, _ROW_BLOCK = 128, 128
+
+
+class _ByToken(NamedTuple):
+    """A piece's live rows in token order: a token's rows stand together, a
+    tile of tokens owns one run of places."""
+    perm: Any       # (c,) the piece's row that stands in each place
+    slots: Any      # (c,) and its assignment
+    tokens: Any     # (1, c) the token of each place; past the live ones, none
+    tiles: Any      # (steps,) the tile of tokens a step of the sum adds into
+    blocks: Any     # (steps,) the block of places it reads
+    n_steps: Any    # (1,) the steps that add anything: the others are idle
+
+
+def _by_token(piece: _Piece, k: int, n_tokens: int, tile: int,
+              block: int) -> _ByToken:
+    """An assignment is ``token * k + slot``: the piece's live rows by
+    assignment are in token order, and a row's place is a running count of
+    the piece's assignments over ``T * k`` — no sort.  The steps of the sum
+    pair each tile of ``tile`` tokens with the blocks of ``block`` places its
+    run touches, tiles ascending; a tile with no row has no step."""
+    c = piece.slots.shape[0]
+    n_tiles, n_blocks = n_tokens // tile, c // block
+    upto = jnp.cumsum(piece.mine, dtype=jnp.int32)
+    rows = jnp.arange(c, dtype=jnp.int32)
+    # (a row no assignment stands in keeps its place, past the live ones; the
+    # place reads row 0 then, which is written whenever any row is)
+    perm = jnp.zeros((c,), jnp.int32).at[
+        jnp.where(piece.live, upto[piece.slots] - 1, rows)].set(
+            rows, unique_indices=True)
+    perm = jnp.where(piece.live, perm, 0)
+    slots = piece.slots[perm]
+    tokens = jnp.where(piece.live, slots // k, n_tokens)
+    ends = upto[tile * k - 1::tile * k]            # where each tile's run ends
+    starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+    first = starts // block
+    n_of = jnp.where(ends > starts, (ends - 1) // block - first + 1, 0)
+    done = jnp.cumsum(n_of)                        # steps up to and with a tile
+    # (past the last step that adds anything the grid stays where it is)
+    step = jnp.minimum(jnp.arange(n_tiles + n_blocks - 1),
+                       jnp.maximum(done[-1] - 1, 0))
+    tiles = jnp.minimum(jnp.sum(done[None, :] <= step[:, None], axis=1),
+                        n_tiles - 1).astype(jnp.int32)
+    blocks = (first - (done - n_of))[tiles] + step
+    return _ByToken(perm, slots, tokens[None, :], tiles, blocks, done[-1:])
+
+
+def _onto_tokens_kernel(tiles, blocks, n_steps, tokens, *refs, tile: int,
+                        gated: bool):
+    """One step: ``out`` (a tile of tokens, float32) plus the rows of one
+    block of places that belong to the tile's tokens, as a (tokens x places)
+    selection matrix times the block's rows on the MXU.  A float32 gate
+    enters as three bf16 parts, whose products with bf16 rows are exact."""
+    from jax.experimental import pallas as pl
+
+    gates, rows, acc, out = refs if gated else (None,) + refs
+    s = pl.program_id(1)
+
+    @pl.when((s == 0) | (tiles[s] != tiles[jnp.maximum(s - 1, 0)]))
+    def _():
+        out[...] = acc[...]
+
+    @pl.when(s < n_steps[0])
+    def _():
+        block = rows.shape[0]
+        own = tokens[...] - tiles[s] * tile == jax.lax.broadcasted_iota(
+            jnp.int32, (tile, block), 0)
+
+        def add(pick):      # (a mask is laid out as the int32 it came from)
+            out[...] += jnp.dot(
+                jnp.where(own, pick, 0.0).astype(rows.dtype), rows[...],
+                precision=None if rows.dtype == jnp.bfloat16
+                else jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32)
+
+        if not gated:
+            add(1.0)
+        elif rows.dtype != jnp.bfloat16:    # float32 rows: a float32 matmul
+            add(gates[...])
+        else:
+            left = gates[...]
+            for _ in range(3):
+                part = left.astype(jnp.bfloat16).astype(jnp.float32)
+                add(part)
+                left = left - part
+
+
+@functools.partial(jax.jit, static_argnames=("k",))
+def _onto_tokens(acc, rows, gates, piece: _Piece, k: int):
     """``acc`` (T, D) float32 plus each token's live rows of the piece
-    (``rows`` (c, D) float32, in the piece's order)."""
-    return acc.at[piece.slots // k].add(
-        jnp.where(piece.live[:, None], rows, 0))
+    (``rows`` (c, D), in the piece's order and the grouped matmul's dtype),
+    each times its assignment's gate weight (``gates`` (T * k,) float32)
+    where there are gates: the rows brought into token order, then one
+    Pallas pass over them, tile of tokens by tile of tokens, ``acc`` updated
+    in place.  Every layer calls it at the same shapes: jitted, it is traced
+    and lowered once for them all (a trace a call cost the cell 7 s of
+    set-up: ``PERF.md``, PR 36)."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    n_tokens, d = acc.shape
+    tile, block = _tile(n_tokens, _TOKEN_TILE), _tile(rows.shape[0],
+                                                      _ROW_BLOCK)
+    wide, gated = _tile(d, 2048), gates is not None
+    by = _by_token(piece, k, n_tokens, tile, block)
+    by_block = pl.BlockSpec((1, block), lambda j, s, tiles, blocks, n:
+                            (0, blocks[s]))
+    by_tile = pl.BlockSpec((tile, wide), lambda j, s, tiles, blocks, n:
+                           (tiles[s], j))
+    return pl.pallas_call(
+        functools.partial(_onto_tokens_kernel, tile=tile, gated=gated),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3, grid=(d // wide, by.tiles.shape[0]),
+            in_specs=[by_block] * (1 + gated) + [
+                pl.BlockSpec((block, wide), lambda j, s, tiles, blocks, n:
+                             (blocks[s], j)), by_tile],
+            out_specs=by_tile),
+        out_shape=jax.ShapeDtypeStruct(acc.shape, acc.dtype),
+        input_output_aliases={5 + gated: 0},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=_interpret(), name="onto_tokens",
+    )(by.tiles, by.blocks, by.n_steps, by.tokens,
+      *((gates[by.slots][None, :],) if gated else ()), rows[by.perm], acc)
 
 
 def _n_pieces(route: _Route, c: int):
@@ -414,29 +572,30 @@ def _n_pieces(route: _Route, c: int):
 # every piece's.  So the held experts' part has a rule of its own, which
 # saves what it was given and runs the loop again in its backward: a piece's
 # forward recomputed, then transposed.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def _through_held(x, gates, weights, route: _Route, c: int, k: int):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+def _through_held(x, gates, weights, route: _Route, c: int, k: int,
+                  scope: str):
     """(T, D) tokens -> (T, D): each token's rows through the held experts,
     times their gate weights (``gates`` (T * k,), by assignment), added in
     float32.  ``c`` rows at a time, as many times as the rows that came
     need."""
     def body(i, acc):
-        piece, _, _, _, out = _piece_forward(x, weights, route, i, c, k)
-        with jax.named_scope("combine"):
-            return _onto_tokens(acc, out.astype(jnp.float32)
-                                * gates[piece.slots][:, None], piece, k)
+        piece, _, _, _, out = _piece_forward(x, weights, route, i, c, k,
+                                             scope)
+        with _beside(scope, "combine"):
+            return _onto_tokens(acc, out, gates, piece, k)
 
     return jax.lax.fori_loop(
         0, _n_pieces(route, c), body,
         jnp.zeros(x.shape, jnp.float32)).astype(x.dtype)
 
 
-def _through_held_fwd(x, gates, weights, route, c, k):
-    return (_through_held(x, gates, weights, route, c, k),
+def _through_held_fwd(x, gates, weights, route, c, k, scope):
+    return (_through_held(x, gates, weights, route, c, k, scope),
             (x, gates, weights, route))
 
 
-def _through_held_bwd(c, k, res, g):
+def _through_held_bwd(c, k, scope, res, g):
     x, gates, weights, route = res
     gate, up, down = weights
     n_held = gate.shape[0]
@@ -444,8 +603,8 @@ def _through_held_bwd(c, k, res, g):
     def body(i, carry):
         dx, dgates, (dgate, dup, ddown) = carry
         piece, rows, (a, b), h, out = _piece_forward(x, weights, route, i, c,
-                                                     k)
-        with jax.named_scope("combine"):
+                                                     k, scope)
+        with _beside(scope, "combine"):
             # the transpose of the weighted sum: a live row's gradient is its
             # gate times its token's ``g``, a gate's its row times ``g``
             g_rows = g[piece.slots // k].astype(jnp.float32)
@@ -454,18 +613,22 @@ def _through_held_bwd(c, k, res, g):
                 0))
             d_out = (g_rows * jnp.where(piece.live, gates[piece.slots], 0)[
                 :, None]).astype(out.dtype)
-        # (an expert's gradient is one kernel's float32 sum, rounded once,
-        # unless its rows straddle two pieces: then once more in between)
-        d_h = _gmm(d_out, down, piece.sizes, transpose_rhs=True)
-        ddown = _tgmm(h, d_out, piece.sizes, down.dtype, n_held, onto=ddown)
-        d_a, d_b = jax.vjp(lambda a, b: jax.nn.silu(a) * b, a, b)[1](d_h)
-        d_rows = _gmm(d_a, gate, piece.sizes, transpose_rhs=True) \
-            + _gmm(d_b, up, piece.sizes, transpose_rhs=True)
-        dgate = _tgmm(rows, d_a, piece.sizes, gate.dtype, n_held, onto=dgate)
-        dup = _tgmm(rows, d_b, piece.sizes, up.dtype, n_held, onto=dup)
-        with jax.named_scope("dispatch"):
+        with _beside(scope, "experts"):
+            # (an expert's gradient is one kernel's float32 sum, rounded
+            # once, unless its rows straddle two pieces: then once more in
+            # between)
+            d_h = _gmm(d_out, down, piece.sizes, transpose_rhs=True)
+            ddown = _tgmm(h, d_out, piece.sizes, down.dtype, n_held,
+                          onto=ddown)
+            d_a, d_b = jax.vjp(lambda a, b: jax.nn.silu(a) * b, a, b)[1](d_h)
+            d_rows = _gmm(d_a, gate, piece.sizes, transpose_rhs=True) \
+                + _gmm(d_b, up, piece.sizes, transpose_rhs=True)
+            dgate = _tgmm(rows, d_a, piece.sizes, gate.dtype, n_held,
+                          onto=dgate)
+            dup = _tgmm(rows, d_b, piece.sizes, up.dtype, n_held, onto=dup)
+        with _beside(scope, "dispatch"):
             # a token's gradient: the float32 sum of its live rows'
-            dx = _onto_tokens(dx, d_rows.astype(jnp.float32), piece, k)
+            dx = _onto_tokens(dx, d_rows, None, piece, k)
         return dx, dgates, (dgate, dup, ddown)
 
     dx, dgates, dweights = jax.lax.fori_loop(
@@ -478,7 +641,8 @@ def _through_held_bwd(c, k, res, g):
 _through_held.defvjp(_through_held_fwd, _through_held_bwd)
 
 
-def held_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig):
+def held_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig,
+                 scope: str = ""):
     """One device's tokens through the experts the layer holds
     (``cfg.experts_held``) -> (their part of the result, the rows the layer
     ran at).  The assignments are counted and placed at ``T * k``, as
@@ -498,9 +662,8 @@ def held_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig):
                          flat - first, n_held)
         ladder = capacity_ladder(flat.shape[0], n_held, cfg.n_experts)
         route = _route(flat, n_held, ladder[-1])
-    with jax.named_scope("experts"):
-        out = _through_held(x, weights.reshape(-1), (gate, up, down), route,
-                            ladder[0], k)
+    out = _through_held(x, weights.reshape(-1), (gate, up, down), route,
+                        ladder[0], k, scope)
     return (out.reshape(*lead, d),
             (_n_pieces(route, ladder[0]) * ladder[0]).astype(
                 jnp.float32).reshape((1,) * len(lead) + (1,)))
@@ -615,8 +778,10 @@ balance), ``moe_z`` (mean of ``logsumexp(router logits) ** 2``) and
         held = n_held < n_experts
 
         def experts(x, weights, idx, gate, up, down):
-            return (held_experts if held else routed_experts)(
-                x, weights, idx, gate, up, down, cfg)
+            if held:    # (its loop's scopes carry the module's path)
+                return held_experts(x, weights, idx, gate, up, down, cfg,
+                                    "/".join(self.path))
+            return routed_experts(x, weights, idx, gate, up, down, cfg)
 
         if mesh is None or mesh.size == 1:
             out = experts(x, weights, idx, gate, up, down)

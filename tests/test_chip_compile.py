@@ -276,8 +276,9 @@ def test_sdar_step_lowers_for_one_v5e_chip():
     kernels = _child(["sdar"], compile_=False)["sdar"]["lowered_kernels"]
     assert kernels.pop("kernel") > 0
     # six layers under remat (a kernel called by every layer through one
-    # function is printed once per trace: forward, recomputation, backward)
-    assert set(kernels) == {"flash_fwd", "flash_bwd"}, kernels
+    # function is printed once per trace: forward, recomputation, backward);
+    # ``onto_tokens`` adds a piece's rows into their tokens (PR 36)
+    assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens"}, kernels
 
 
 @pytest.mark.slow
@@ -293,8 +294,9 @@ def test_sdar_step_compiles_and_fits_the_chip():
     # the grouped matmul: gate, up, down in the forward's loop, and in the
     # backward's the three recomputed and two transposes each — the
     # checkpoint's recomputation of the forward's loop is dropped, nothing
-    # reads it (PR 32)
-    assert row["tpu_custom_calls"] == 6 * (3 + 12), row
+    # reads it (PR 32); and in either loop the one call that adds the
+    # piece's rows into their tokens (PR 36): 3 + 12 + 2 a layer
+    assert row["tpu_custom_calls"] == 6 * (3 + 12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
@@ -305,7 +307,7 @@ def test_laguna_step_lowers_for_one_v5e_chip():
     under the window, and the grouped matmuls of the held experts."""
     kernels = _child(["laguna"], compile_=False)["laguna"]["lowered_kernels"]
     assert kernels.pop("kernel") > 0
-    assert set(kernels) == {"flash_fwd", "flash_bwd"}, kernels
+    assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens"}, kernels
 
 
 @pytest.mark.slow
@@ -319,8 +321,9 @@ def test_laguna_step_compiles_and_fits_the_chip():
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row
     # a layer: flash forward, its recomputation, the backward's one kernel;
-    # a sparse layer's held experts: twelve grouped-matmul calls, as SDAR's
-    assert row["tpu_custom_calls"] == 5 * 3 + 4 * 12, row
+    # a sparse layer's held experts: twelve grouped-matmul calls and the two
+    # that add rows into tokens, as SDAR's: 5 x 3 + 4 x (12 + 2)
+    assert row["tpu_custom_calls"] == 5 * 3 + 4 * (12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 

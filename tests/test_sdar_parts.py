@@ -21,6 +21,7 @@ from perfbench.harness import families, reference
 from perfbench.harness.families import sdar_moe
 from perfbench.harness.tokens import ZipfStream
 from ray_tpu.models.llama import LlamaConfig
+from ray_tpu.models import moe
 from ray_tpu.models.moe import (RoutedConfig, RoutedSwiGLU,
                                 capacity_ladder)
 from ray_tpu.models.pretrain import (init_params, loss_fn, make_optimizer,
@@ -341,10 +342,12 @@ def test_l_no_row_is_moved_at_the_worst_cases_size():
     no array as wide as the model or an expert has a row for every
     assignment, anywhere; whatever is that wide is inside a loop whose trip
     count the device finds, at one piece's rows: the forward's loop with the
-    three grouped matmuls, and the backward's with the three recomputed and
-    their six transposes — and no third: where the checkpoint recomputes the
-    forward nothing reads the rule's result, and its loop is not there.  A layer that holds every expert has no loop and does pass
-    over all ``T * k`` rows."""
+    three grouped matmuls and the kernel that adds their rows into the
+    tokens, and the backward's with the three recomputed, their six
+    transposes and the same kernel for the tokens' gradients — and no third:
+    where the checkpoint recomputes the forward nothing reads the rule's
+    result, and its loop is not there.  A layer that holds every expert has
+    no loop and does pass over all ``T * k`` rows."""
     x, share = _routed_to_held(7)
     layer = _layer((0, 2))
 
@@ -356,12 +359,125 @@ def test_l_no_row_is_moved_at_the_worst_cases_size():
     _walk(jax.make_jaxpr(jax.value_and_grad(loss, (0, 1)))(share, x).jaxpr,
           found)
     assert found["wide"] == [] and found["switches"] == [], found
-    assert found["loops"] == [3, 9], found
+    assert found["loops"] == [3 + 1, 9 + 1], found
     whole = {"wide": [], "switches": [], "loops": []}
     _walk(jax.make_jaxpr(jax.value_and_grad(lambda p, x: jnp.sum(
         _layer(None).apply({"params": p}, x))))(
             _whole_layer_params()[1], x).jaxpr, whole)
     assert whole["loops"] == [] and whole["wide"]    # the walk does see
+
+
+# ---------------------- (o) a piece's rows added into their tokens (PR 36)
+# 512 tokens x 4 slots over 3 held experts, pieces of 384 rows: four tiles of
+# 128 tokens and three blocks of 128 rows, so that a run of rows crosses
+# both.  A case is the (token, slot) assignments that go to a held expert.
+_ONTO = {
+    "a_no_live_row": [],
+    "b_one_row": [(301, 2)],
+    "c_every_slot_of_a_token": [(200, s) for s in range(4)] + [(3, 1),
+                                                               (450, 0)],
+    "d_a_tokens_rows_in_two_pieces": [(t, s) for t in range(128)
+                                      for s in range(4)],
+    "e_rows_end_inside_a_tile": [(37 * i % 512, i % 4) for i in range(150)],
+    "e_a_piece_exactly_full": [(5 * t, s) for t in range(96)
+                               for s in range(4)],
+}
+
+
+@pytest.mark.parametrize("gated", [True, False], ids=["gates", "plain"])
+@pytest.mark.parametrize("case", sorted(_ONTO))
+def test_o_a_pieces_rows_added_into_their_tokens_are_the_scatter_add(
+        case, gated):
+    """``_onto_tokens`` — the rows brought into token order, then the
+    (interpreted) kernel over tiles of tokens, ``acc`` updated in place —
+    against ``acc.at[tokens].add(rows * gates)``, piece after piece as the
+    loop runs them: bf16 rows as the grouped matmul writes them, what lies
+    past a piece's live rows never written (NaN here), the forward's gates
+    and the backward's plain sum."""
+    n_tokens, k, n_held, c, d = 512, 4, 3, 384, 128
+    flat = np.full((n_tokens * k,), n_held, np.int32)
+    for token, slot in _ONTO[case]:
+        flat[token * k + slot] = (token + slot) % n_held
+    route = moe._route(jnp.asarray(flat), n_held, n_tokens * k)
+    n_pieces = int(moe._n_pieces(route, c))
+    assert n_pieces == -(-len(_ONTO[case]) // c)
+    keys = jax.random.split(jax.random.PRNGKey(len(case)), 3)
+    got = want = jax.random.normal(keys[0], (n_tokens, d), jnp.float32)
+    gates = jax.random.uniform(keys[1], (n_tokens * k,), jnp.float32)
+    seen = 0
+    for i in range(max(n_pieces, 1)):   # (no row: the one, empty piece)
+        piece = moe._piece(route, i, c)
+        live = np.asarray(piece.live)
+        rows = jnp.where(live[:, None], jax.random.normal(
+            jax.random.fold_in(keys[2], i), (c, d), jnp.float32),
+            jnp.nan).astype(jnp.bfloat16)
+        got = moe._onto_tokens(got, rows, gates if gated else None, piece, k)
+        want = want.at[piece.slots // k].add(jnp.where(
+            live[:, None], rows.astype(jnp.float32)
+            * (gates[piece.slots][:, None] if gated else 1.0), 0))
+        seen += int(live.sum())
+    assert seen == len(_ONTO[case])
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=2e-6)
+    if not _ONTO[case]:
+        assert bool(jnp.all(got == want))    # nothing added: not a bit moved
+    if case.startswith("d"):    # the last token's rows: experts 1, 2, 0, 1
+        assert len({int(r) // c
+                    for r in np.asarray(route.row[127 * k:128 * k])}) > 1
+
+
+def test_p_a_held_layers_gradients_are_the_whole_layers_with_absent_gates_zero():
+    """``jax.grad`` of ``held_experts`` (the loop over pieces, the kernel
+    that adds rows into tokens in both directions) against ``routed_experts``
+    over all the experts with the absent ones' gate weights set to zero: the
+    tokens', the gate weights' and the held matrices' gradients; and in the
+    held layer's forward and backward no scatter-add of rows as wide as the
+    model is left, inside the loops or outside them."""
+    n_experts, n_held, k, d, f = 8, 3, 2, 64, 32
+    keys = jax.random.split(jax.random.PRNGKey(36), 6)
+    x = jax.random.normal(keys[0], (2, 44, d), jnp.float32)
+    idx = jax.lax.top_k(jax.random.normal(keys[1], (2, 44, n_experts)), k)[1]
+    weights = jax.random.uniform(keys[2], (2, 44, k), jnp.float32)
+    mats = tuple(0.2 * jax.random.normal(key, (n_experts,) + shape)
+                 for key, shape in zip(keys[3:], ((d, f), (d, f), (f, d))))
+    cfg = RoutedConfig(n_experts=n_experts, top_k=k, d_model=d, d_ff=f,
+                       dtype=jnp.float32)
+    held_cfg = dataclasses.replace(cfg, experts_held=(0, n_held))
+
+    def held(x, weights, mine):
+        return jnp.sum(jnp.sin(moe.held_experts(
+            x, weights, idx, *mine, held_cfg)[0]))
+
+    def whole(x, weights, mine):
+        return jnp.sum(jnp.sin(moe.routed_experts(
+            x, jnp.where(idx < n_held, weights, 0.0), idx,
+            *(jnp.concatenate([m, rest[n_held:]]) for m, rest
+              in zip(mine, mats)), cfg)))
+
+    mine = tuple(m[:n_held] for m in mats)
+    with jax.default_matmul_precision("highest"):
+        got = jax.grad(held, (0, 1, 2))(x, weights, mine)
+        want = jax.grad(whole, (0, 1, 2))(x, weights, mine)
+    for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(g, w, atol=2e-5, rtol=1e-5)
+    assert float(jnp.max(jnp.abs(got[0]))) > 1e-3
+
+    def scatter_adds(jaxpr, out):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                continue
+            if eqn.primitive.name in ("scatter-add", "scatter_add"):
+                out.append(eqn.outvars[0].aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                scatter_adds(getattr(sub, "jaxpr", sub), out)
+        return out
+
+    shapes = scatter_adds(jax.make_jaxpr(jax.value_and_grad(
+        held, (0, 1, 2)))(x, weights, mine).jaxpr, [])
+    # (the gates' gradient is one, over scalars; the grouped matmuls' own
+    # bookkeeping has more)
+    assert (2 * 44 * k,) in shapes and all(len(s) == 1 for s in shapes), shapes
+    assert scatter_adds(jax.make_jaxpr(lambda acc, rows, at: acc.at[at].add(
+        rows))(x[0], x[0], jnp.arange(44)).jaxpr, []) == [(44, d)]
 
 
 def test_f_the_noising_masks_a_share_t_of_each_block_and_weighs_by_1_over_t():
