@@ -40,7 +40,14 @@ layer's feed-forward ``"dense"`` or ``"sparse"`` where ``moe_every``'s fixed
 period cannot; and the routed layer may score its experts by sigmoids, scale
 the chosen weights and add a shared expert every token passes
 (``router_scoring``, ``routed_scale``, ``d_shared_expert``: ``models/moe.py``)
-(Laguna-XS.2 is the block with all of these).
+(Laguna-XS.2 is the block with all of these).  ``kv_lora_rank`` > 0 makes
+every attention layer latent (``LatentAttention``, DeepSeek-V2's MLA): keys and
+values come from one ``kv_lora_rank``-wide latent a position, normed, the
+rotary part of the key (``qk_rope_head_dim``) is one vector a position that
+all heads share, and a head's scores are ``qk_nope_head_dim +
+qk_rope_head_dim`` wide over values ``v_head_dim`` wide
+(``ops.attention``'s ``k_shared``; Kimi-VL-A3B's decoder is the block with
+this, the leading dense layer and the sigmoid-routed experts).
 Every such field at its default leaves the program the dense Llama it was.  Same TPU discipline as the GPT stack —
 bfloat16 activations, fused QKV-free layout matched to
 ``llama_partition_rules`` so tp/fsdp shardings apply by regex, attention
@@ -157,6 +164,12 @@ class LlamaConfig:
     router_scoring: str = "softmax"  # or "sigmoid": the experts' scores
     routed_scale: float = 1.0        # x the chosen experts' weights
     d_shared_expert: int = 0         # a SwiGLU every token passes, beside the routed
+    # latent attention (``LatentAttention``) in every attention layer: the
+    # width of the latent keys and values are made from; 0: none
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0        # a head's key part made from the latent
+    qk_rope_head_dim: int = 0        # the rotary key part all heads share
+    v_head_dim: int = 0              # a head's values
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -304,6 +317,64 @@ class LlamaAttention(nn.Module):
         return nn.Dense(E, use_bias=False, dtype=cfg.dtype, name="wo")(out)
 
 
+class LatentAttention(nn.Module):
+    """Multi-head latent attention (DeepSeek-V2, section 2.1) with the query
+    projected whole (no query latent): ``wdkv`` takes the layer's input down
+    to a latent ``c`` of ``kv_lora_rank`` and one rotary key ``kr`` of
+    ``qk_rope_head_dim`` a position; ``kv_norm`` norms ``c``; ``wukv`` makes
+    each head's key part ``kn`` (``qk_nope_head_dim``) and values
+    (``v_head_dim``) from it.  A head's query is ``[qn ; R(qr)]``, its key
+    ``[kn ; R(kr)]`` with the one ``kr`` for all heads, the scores are scaled
+    by the inverse root of their whole width, and ``wo`` takes the heads'
+    values back to the model's width.  The kernels take the key's parts as
+    they are (``flash_attention``'s ``k_shared``): nothing is broadcast to
+    the heads or joined in HBM, and the scope ``assemble`` that would hold it
+    stays empty."""
+    config: LlamaConfig
+    kind: str = "attention"     # or "full_attention"
+
+    @nn.compact
+    def __call__(self, x, positions):
+        cfg = self.config
+        B, S, E = x.shape
+        H, rank = cfg.n_head, cfg.kv_lora_rank
+        dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=False, dtype=cfg.dtype, name=name)
+
+        def heads(a):
+            return a.reshape(B, S, H, -1).transpose(0, 2, 1, 3)
+
+        q = heads(dense(H * (dn + dr), "wq")(x))
+        down = dense(rank + dr, "wdkv")(x)
+        latent = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
+                            name="kv_norm")(down[..., :rank])
+        kv = heads(dense(H * (dn + dv), "wukv")(latent))
+        k, v, kr = kv[..., :dn], kv[..., dn:], down[:, None, :, rank:]
+        with jax.named_scope("rope"):
+            cos, sin = rope_table(dr, positions, RopeTable(theta=cfg.rope_theta))
+            q = jnp.concatenate(
+                [q[..., :dn], apply_rope(q[..., dn:], cos, sin)], axis=-1)
+            kr = apply_rope(kr, cos, sin)
+        if (cfg.objective == "block_diffusion" or cfg.attention_impl == "ring"
+                or self.kind == "sliding_attention"):
+            raise NotImplementedError(
+                "latent attention under the block mask, over a sharded "
+                "sequence (those kernels take one width for scores and "
+                "values) or under a window")
+        if cfg.attention_impl == "reference":
+            out = mha_reference(q, k, v, causal=True, sm_scale=cfg.attn_scale,
+                                k_shared=kr)
+        else:
+            # the scope tells these calls from another kind's in a trace
+            with jax.named_scope("mla"):
+                out = flash_attention(q, k, v, causal=True,
+                                      sm_scale=cfg.attn_scale, k_shared=kr)
+        out = out.transpose(0, 2, 1, 3).reshape(B, S, H * dv)
+        return dense(E, "wo")(out)
+
+
 class SwiGLU(nn.Module):
     config: LlamaConfig
 
@@ -342,8 +413,10 @@ class LlamaBlock(nn.Module):
         if self.mixer == "mamba":
             x = add(x, Mamba2Mixer(cfg, name="mamba")(y))
         elif self.mixer in ATTENTION_KINDS:
-            x = add(x, LlamaAttention(cfg, self.mixer, self.n_head,
-                                      name="attn")(y, positions))
+            attn = LatentAttention(cfg, self.mixer, name="attn") \
+                if cfg.kv_lora_rank else LlamaAttention(
+                    cfg, self.mixer, self.n_head, name="attn")
+            x = add(x, attn(y, positions))
         else:
             raise ValueError(f"unknown layer type {self.mixer!r} (expected "
                              f"'mamba' or one of {ATTENTION_KINDS})")

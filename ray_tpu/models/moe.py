@@ -220,9 +220,16 @@ def _gmm_tiling(m: int, k: int, n: int):
     weight tile at 2M elements (4 MB in bf16, double-buffered, beside the
     float32 accumulator in 16 MB of scoped VMEM).  Rows of a group that do
     not fill a row tile cost a whole one, and each row tile reads its weight
-    tile again, which is what the 256 trades off."""
+    tile again, which is what the 256 trades off.  A width that does not fit
+    is cut into whole lanes, as evenly as they allow (an expert 1,408 wide:
+    2,048 columns as 2 x 1,024 under a contraction of 1,408, its own 1,408 as
+    768 + 640 under one of 2,048)."""
     tk = min(k, 2048)
-    return min(256, _round_up(m, 8)), tk, min(n, (2 << 20) // tk)
+    most = (2 << 20) // tk
+    if n > most:
+        tiles = -(-n // (most // 128 * 128))
+        n = _round_up(-(-n // tiles), 128)
+    return min(256, _round_up(m, 8)), tk, n
 
 
 def _tgmm_tiling(m: int, k: int, n: int):

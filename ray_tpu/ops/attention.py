@@ -20,6 +20,14 @@ Greenfield relative to the reference — it has no sequence parallelism anywhere
   rotates KV around the ring with ``jax.lax.ppermute`` (ICI neighbor traffic),
   merging partial results with the online-softmax combine.  Causal masking uses
   global offsets so the math matches unsharded attention exactly.
+- The values may be another width than the scores, and the last dimensions
+  of every head's key may be one vector a position that all heads share
+  (``flash_attention``'s ``k_shared``: latent attention's rotary key part):
+  q, dQ, K and dK are as wide as the scores, the forward's accumulator and
+  output and the backward's dV as wide as the values, the shared part is read
+  through a BlockSpec that drops the head, and nothing is padded, broadcast
+  or joined in HBM.  With one width and no shared part the traced calls are
+  what they were.
 - Under an ambient mesh (``jax.set_mesh``) ``flash_attention`` runs the kernel
   inside a ``shard_map`` (batch over dp/fsdp, heads over tp): Mosaic kernels
   cannot be partitioned by GSPMD, so each device must see a whole local call.
@@ -84,13 +92,19 @@ def block_diffusion_mask(length: int, block: int):
 
 def mha_reference(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = None,
                   q_offset: int = 0, k_offset: int = 0, mask=None,
-                  window: int = 0):
-    """Naive attention; ground truth for kernels. q,k,v: (B, H, S, D).
+                  window: int = 0, k_shared=None):
+    """Naive attention; ground truth for kernels. q,k,v: (B, H, S, D); v may
+    be another width than q and k, and is the output's.
     ``mask``: a boolean (S_q, S_k) array of the pairs that are seen, in place
     of the causal one.  ``window`` > 0: under the causal mask a query sees
-    itself and the ``window - 1`` positions before it."""
+    itself and the ``window - 1`` positions before it.  ``k_shared`` (B, 1,
+    S, D_s): the last D_s dimensions of every head's key, held once a
+    position; k is then D - D_s wide."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    if k_shared is not None:
+        k = jnp.concatenate([k, jnp.broadcast_to(
+            k_shared, (*k.shape[:-1], k_shared.shape[-1]))], axis=-1)
     logits = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32) * sm_scale
     if mask is not None:
         logits = jnp.where(mask, logits, NEG_INF)
@@ -364,6 +378,14 @@ class _Tiles(NamedTuple):
                              lambda bh, i, j: (
                                  bh, 0, jnp.minimum(i, self.nk - 1), 0)))
 
+    def shared_spec(self, d: int, heads: int, q_is_inner: bool):
+        """BlockSpec of a (b, s_k, d) operand that the ``heads`` heads of a
+        batch row share, following ``tile_of`` as a (b*h, s_k, d) one does."""
+        return pl.BlockSpec(
+            (None, self.block_k, d), lambda bh, i, j: (
+                lax.div(bh, jnp.int32(heads)),
+                self.tile_of(i, j, q_is_inner)[1], 0))
+
 
 def _rows(x, s_pad: int):
     """(b, h, s, d) -> (b*h, s_pad, d), zero padded up to whole blocks: a
@@ -547,13 +569,25 @@ def _across(col, n: int):
 
 
 # -------------------------------------------------------------- forward
+def _scores(q_ref, qs, k_ref, ks, ks_ref):
+    """Unscaled scores (queries, keys) of queries ``qs`` against keys ``ks``;
+    ``ks_ref``: the keys' shared part (``flash_attention``'s ``k_shared``),
+    which the queries' last dimensions meet in a contraction of their own."""
+    if ks_ref is None:
+        return _dot(q_ref[qs, :], k_ref[ks, :], _NT)
+    d = k_ref.shape[1]
+    return lax.add(_dot(q_ref[qs, :d], k_ref[ks, :], _NT),
+                   _dot(q_ref[qs, d:], ks_ref[ks, :], _NT))
+
+
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles):
     # under ``t.bd`` two more inputs: the noised K and V tile of the query
-    # tile's own positions
+    # tile's own positions; without it, at most one: the keys' shared part
     *own_refs, o_ref, lse_ref, m_col, l_col, acc = rest
     row, step = pl.program_id(1), pl.program_id(2)
     iq, ik = t.walk(row, step, False)
-    d = q_ref.shape[1]
+    d = v_ref.shape[1]
+    ks_ref = own_refs[0] if own_refs and not t.bd else None
 
     from_nothing = step == 0
     if t.bd:
@@ -586,7 +620,7 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles):
 
     def part(qs, ks, thresh, k_limit, below=None):
         v = v_ref[ks, :]
-        s = _masked(lax.mul(_dot(q_ref[qs, :], k_ref[ks, :], _NT), sm_scale),
+        s = _masked(lax.mul(_scores(q_ref, qs, k_ref, ks, ks_ref), sm_scale),
                     0, thresh, k_limit, t.bd, below)
         lanes = (s.shape[0], LANES)
         m_old = m_col[qs, :]
@@ -624,11 +658,12 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles):
 def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
                    k_offset: int, block_q: Optional[int],
                    block_k: Optional[int], interpret: bool, bd: int = 0,
-                   window: int = 0):
-    """``out`` (b, h, s_q, d) and the logsumexp of every query's scaled
-    scores as (b*h, 1, s_q) rows, NEG_INF where a query sees no key.  Under
-    ``bd`` (see ``_Tiles``) q, k and v are the two copies of ``s_q / 2``
-    positions, and every query sees a key.
+                   window: int = 0, k_shared=None):
+    """``out`` (b, h, s_q, v's width) and the logsumexp of every query's
+    scaled scores as (b*h, 1, s_q) rows, NEG_INF where a query sees no key.
+    Under ``bd`` (see ``_Tiles``) q, k and v are the two copies of ``s_q / 2``
+    positions, and every query sees a key.  ``k_shared`` (b, 1, s_k, .): see
+    ``flash_attention``.
 
     Jitted and inlined so that a model's layers, which call it with the same
     shapes, share one trace of the kernel: the equations land in the caller's
@@ -636,33 +671,41 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s_q, d = q.shape
+    d_v = v.shape[-1]
     s_k = k.shape[2] // 2 if bd else k.shape[2]     # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
                   q_offset - k_offset, _FWD_DIAG_CHUNK, block_q, block_k, bd,
                   window)
     s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
-    q_spec, row_spec, k_spec, *own_spec = t.specs(d, q_is_inner=False)
+    q_spec, row_spec, _ = t.specs(d, q_is_inner=False)[:3]
+    k_spec = t.specs(k.shape[-1], q_is_inner=False)[2]
+    o_spec, _, v_spec, *own_spec = t.specs(d_v, q_is_inner=False)
     q_rows, k_rows = (_halves, _copies) if bd else (_rows, _rows)
     with jax.named_scope("flash_fwd"):
         q, k, v = q_rows(q, s_q_pad), k_rows(k, s_k_pad), k_rows(v, s_k_pad)
+        own = (k, v) * len(own_spec)
+        own_spec = own_spec * 2
+        if k_shared is not None:
+            own = (_rows(k_shared, s_k_pad),)
+            own_spec = [t.shared_spec(k_shared.shape[-1], h, False)]
         out, lse = pl.pallas_call(
             functools.partial(_flash_fwd_kernel, sm_scale=sm_scale, t=t),
             grid=(b * h, t.nq, t.steps(False)),
-            in_specs=[q_spec, k_spec, k_spec] + own_spec * 2,
-            out_specs=[q_spec, row_spec],
-            out_shape=[jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype),
+            in_specs=[q_spec, k_spec, v_spec] + own_spec,
+            out_specs=[o_spec, row_spec],
+            out_shape=[jax.ShapeDtypeStruct((b * h, s_q_pad, d_v), q.dtype),
                        jax.ShapeDtypeStruct((b * h, 1, s_q_pad), jnp.float32)],
             scratch_shapes=[pltpu.VMEM((t.block_q, LANES), jnp.float32)] * 2
-            + [pltpu.VMEM((t.block_q, d), jnp.float32)],
+            + [pltpu.VMEM((t.block_q, d_v), jnp.float32)],
             compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel", "arbitrary")),
             interpret=interpret,
             name="flash_fwd",
-        )(q, k, v, *(k, v) * len(own_spec))
+        )(q, k, v, *own)
     if bd:
         return (_unhalved(out, s_k, 1).reshape(b, h, s_q, d),
                 _unhalved(lse, s_k, 2))
-    return out[:, :s_q].reshape(b, h, s_q, d), lse[:, :, :s_q]
+    return out[:, :s_q].reshape(b, h, s_q, d_v), lse[:, :, :s_q]
 
 
 # ------------------------------------------------------------- backward
@@ -682,9 +725,17 @@ def _bwd_vmem_bytes(s_q_pad: int, d: int, dtype) -> int:
         4 + 2 * jnp.dtype(dtype).itemsize)
 
 
-def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
-                      dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc,
-                      *, sm_scale: float, t: _Tiles):
+def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
+                      sm_scale: float, t: _Tiles):
+    # with the keys' shared part (``flash_attention``'s ``k_shared``) one
+    # more input, output and accumulator: that part, and its gradient from
+    # this b*h's queries
+    ks_ref, dks_ref, dks_acc = None, None, None
+    if len(rest) == 9:
+        ks_ref, dq_ref, dk_ref, dv_ref, dks_ref, dq_acc, dk_acc, dv_acc, \
+            dks_acc = rest
+    else:
+        dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
     ik, step = pl.program_id(1), pl.program_id(2)
     iq = t.walk(ik, step, True)[0]
     last_k, last_step = pl.num_programs(1) - 1, pl.num_programs(2) - 1
@@ -703,11 +754,20 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     def _():
         dk_acc[...] = jnp.zeros_like(dk_acc)
         dv_acc[...] = jnp.zeros_like(dv_acc)
+        if dks_acc is not None:
+            dks_acc[...] = jnp.zeros_like(dks_acc)
 
     def part(qs, ks, thresh, k_limit, below=None, own=False):
         kr, vr = (kn_ref, vn_ref) if own else (k_ref, v_ref)
         q, do, k = q_ref[qs, :], do_ref[qs, :], kr[ks, :]
-        st, lse = lax.mul(_dot(k, q, _NT), sm_scale), lse_ref[:, qs]
+        if ks_ref is None:
+            st = _dot(k, q, _NT)
+        else:
+            # the queries' last dimensions against the shared part
+            d = k.shape[1]
+            shared, q, q_shared = ks_ref[ks, :], q[:, :d], q[:, d:]
+            st = lax.add(_dot(k, q, _NT), _dot(shared, q_shared, _NT))
+        st, lse = lax.mul(st, sm_scale), lse_ref[:, qs]
         st = _same_block(st, t.bd) if own \
             else _masked(st, 1, thresh, k_limit, t.bd, below)
         pt = lax.exp(lax.sub(st, lse))      # P^T, zero where masked
@@ -726,7 +786,12 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
         # these queries' rows of the whole-sequence accumulator
         start, stop, _ = qs.indices(t.block_q)
         rows = pl.ds(pl.multiple_of(iq * t.block_q + start, LANES), stop - start)
-        dq_acc[rows, :] += _dot(dst, k, _TN)
+        if ks_ref is None:
+            dq_acc[rows, :] += _dot(dst, k, _TN)
+        else:
+            dks_acc[ks, :] += _dot(dst, q_shared, _NN)
+            dq_acc[rows, :d] += _dot(dst, k, _TN)
+            dq_acc[rows, d:] += _dot(dst, shared, _TN)
 
     if t.bd:
         @pl.when(iq == ik)
@@ -739,6 +804,8 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
     def _():
         dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
         dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+        if dks_ref is not None:
+            dks_ref[...] = (dks_acc[...] * sm_scale).astype(dks_ref.dtype)
 
     @pl.when(jnp.logical_and(ik == last_k, step == last_step))
     def _():
@@ -749,14 +816,16 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref,
                    inline=True)
 def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
                     q_offset: int, k_offset: int, interpret: bool,
-                    bd: int = 0, window: int = 0):
+                    bd: int = 0, window: int = 0, k_shared=None):
     """dq, dk, dv of ``_flash_attention`` from its residuals (``lse`` as the
-    forward leaves it: (b*h, 1, s_q) rows) and ``g``.
+    forward leaves it: (b*h, 1, s_q) rows) and ``g``; with ``k_shared``, its
+    gradient too, summed over the heads.
 
     Jitted and inlined for the reason ``_flash_forward`` is."""
     from jax.experimental.pallas import tpu as pltpu
 
     b, h, s_q, d = q.shape
+    d_k, d_v = k.shape[-1], v.shape[-1]
     s_k = k.shape[2] // 2 if bd else k.shape[2]     # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
                   q_offset - k_offset, _BWD_DIAG_CHUNK, bd=bd, window=window)
@@ -775,39 +844,65 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
     # exp(s - lse) would blow up: such rows, like the padding rows, get an lse
     # under which every p is zero.
     lse = jnp.where(lse > NEG_INF / 2, lse, -NEG_INF)
-    q_spec, row_spec, k_spec = t.specs(d, q_is_inner=True)
-    # under ``bd`` both copies' tile, as the keys come: ``k_spec`` ignores j
-    dkv_spec = k_spec if bd else pl.BlockSpec(
-        (None, t.block_k, d), lambda bh, i, j: (bh, i, 0))
+    q_spec, row_spec, _ = t.specs(d, q_is_inner=True)
+    k_spec = t.specs(d_k, q_is_inner=True)[2]
+    do_spec, _, v_spec = t.specs(d_v, q_is_inner=True)
+
+    def dk_spec(spec, d):
+        # under ``bd`` both copies' tile, as the keys come: ``spec`` ignores j
+        return spec if bd else pl.BlockSpec(
+            (None, t.block_k, d), lambda bh, i, j: (bh, i, 0))
+
+    def dk_shape(d):
+        return jax.ShapeDtypeStruct((b * h, 2, s_k_pad, d) if bd
+                                    else (b * h, s_k_pad, d), q.dtype)
+
     # dQ sums over the k blocks, the outer axis: its block is the whole
     # sequence of one b*h, written back once when the b*h is done.
     dq_spec = pl.BlockSpec((None, s_q_pad, d), lambda bh, i, j: (bh, 0, 0))
-    dq, dk, dv = pl.pallas_call(
+    in_specs = [q_spec, do_spec, row_spec, row_spec, k_spec, v_spec]
+    out_specs = [dq_spec, dk_spec(k_spec, d_k), dk_spec(v_spec, d_v)]
+    out_shape = [jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype),
+                 dk_shape(d_k), dk_shape(d_v)]
+    scratch = [pltpu.VMEM((s_q_pad, d), jnp.float32),
+               pltpu.VMEM((t.block_k, d_k), jnp.float32),
+               pltpu.VMEM((t.block_k, d_v), jnp.float32)]
+    operands = [q_rows(q, s_q_pad), q_rows(g, s_q_pad), row(lse, -NEG_INF),
+                row(delta.reshape(b * h, 1, s_q), 0.0),
+                k_rows(k, s_k_pad), k_rows(v, s_k_pad)]
+    if k_shared is not None:
+        # each b*h's own gradient of the shared part, summed over the heads
+        # beside the kernel
+        d_s = k_shared.shape[-1]
+        in_specs.append(t.shared_spec(d_s, h, True))
+        out_specs.append(dk_spec(None, d_s))
+        out_shape.append(dk_shape(d_s))
+        scratch.append(pltpu.VMEM((t.block_k, d_s), jnp.float32))
+        operands.append(_rows(k_shared, s_k_pad))
+    dq, dk, dv, *dks = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, sm_scale=sm_scale, t=t),
         grid=(b * h, t.nk, t.steps(True)),
-        in_specs=[q_spec, q_spec, row_spec, row_spec, k_spec, k_spec],
-        out_specs=[dq_spec, dkv_spec, dkv_spec],
-        out_shape=[jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype)]
-        + [jax.ShapeDtypeStruct((b * h, 2, s_k_pad, d) if bd
-                                else (b * h, s_k_pad, d), q.dtype)] * 2,
-        scratch_shapes=[pltpu.VMEM((s_q_pad, d), jnp.float32)]
-        + [pltpu.VMEM((t.block_k, d), jnp.float32)] * 2,
+        in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
+        scratch_shapes=scratch,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary", "arbitrary"),
             vmem_limit_bytes=_bwd_vmem_bytes(s_q_pad, d, q.dtype)),
         interpret=interpret,
         name="flash_bwd",
-    )(q_rows(q, s_q_pad), q_rows(g, s_q_pad), row(lse, -NEG_INF),
-      row(delta.reshape(b * h, 1, s_q), 0.0),
-      k_rows(k, s_k_pad), k_rows(v, s_k_pad))
+    )(*operands)
     if bd:
         return tuple(
             _unhalved(dx.reshape(b * h, s_q_pad, d), s_k, 1
                       ).reshape(b, h, s_q, d).astype(x.dtype)
             for dx, x in ((dq, q), (dk, k), (dv, v)))
-    return (dq[:, :s_q].reshape(b, h, s_q, d),
-            dk[:, :s_k].reshape(b, h, s_k, d).astype(k.dtype),
-            dv[:, :s_k].reshape(b, h, s_k, d).astype(v.dtype))
+    grads = (dq[:, :s_q].reshape(b, h, s_q, d),
+             dk[:, :s_k].reshape(b, h, s_k, d_k).astype(k.dtype),
+             dv[:, :s_k].reshape(b, h, s_k, d_v).astype(v.dtype))
+    if k_shared is None:
+        return grads
+    return grads + (jnp.sum(
+        dks[0][:, :s_k].reshape(b, h, s_k, -1), axis=1, keepdims=True,
+        dtype=jnp.float32).astype(k_shared.dtype),)
 
 
 # ============================================================= public op
@@ -866,6 +961,36 @@ def _flash_bd_bwd(sm_scale, bd, residuals, g):
 _flash_bd.defvjp(_flash_bd_fwd, _flash_bd_bwd)
 
 
+# ------------------------------------------------- a shared part of the keys
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
+def _flash_shared(q, k, v, k_shared, causal, sm_scale, q_offset, k_offset,
+                  block_q, block_k, window=0):
+    """``_flash_attention`` with the last dimensions of every head's key held
+    once a position (``flash_attention``'s ``k_shared``)."""
+    return _flash_shared_fwd(q, k, v, k_shared, causal, sm_scale, q_offset,
+                             k_offset, block_q, block_k, window)[0]
+
+
+def _flash_shared_fwd(q, k, v, k_shared, causal, sm_scale, q_offset, k_offset,
+                      block_q, block_k, window):
+    out, lse = _flash_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
+                              block_q, block_k, _interpret(), 0, window,
+                              k_shared)
+    return out, (q, k, v, k_shared, out, lse)
+
+
+def _flash_shared_bwd(causal, sm_scale, q_offset, k_offset, block_q, block_k,
+                      window, residuals, g):
+    q, k, v, k_shared, out, lse = residuals
+    with jax.named_scope("flash_bwd"):
+        return _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
+                               q_offset, k_offset, _interpret(), 0, window,
+                               k_shared)
+
+
+_flash_shared.defvjp(_flash_shared_fwd, _flash_shared_bwd)
+
+
 def _bhsd_spec(mesh, batch_axes, head_axis, seq_axis=None):
     """PartitionSpec of a (B, H, S, D) tensor over whichever of the named
     axes ``mesh`` has."""
@@ -877,8 +1002,18 @@ def _bhsd_spec(mesh, batch_axes, head_axis, seq_axis=None):
 def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = None,
                     q_offset: int = 0, k_offset: int = 0,
                     block_q: Optional[int] = None, block_k: Optional[int] = None,
-                    diffusion_block: int = 0, window: int = 0):
+                    diffusion_block: int = 0, window: int = 0, k_shared=None):
     """Blockwise (flash) attention. q,k,v: (B, H, S, D) -> (B, H, S, D).
+
+    v may be another width than q and k (latent attention's 128 under scores
+    192 wide): the output is v's, nothing is padded, and the accumulators
+    that belong to the values (the forward's output, the backward's dV) are
+    as wide as they.  ``k_shared`` (B, 1, S, D_s): the last D_s dimensions of
+    every head's key, held once a position — a rotary part that all heads
+    share; k is then D - D_s wide, the kernels take the two parts as they
+    are (a query's first dimensions against k, its last against the shared
+    part, one sum of scores), and the shared part's gradient is summed over
+    the heads.
 
     ``window`` > 0, under the causal mask: a query sees itself and the
     ``window - 1`` positions before it, and no tile outside that band is
@@ -897,20 +1032,31 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
         sm_scale = q.shape[-1] ** -0.5
     if window and (diffusion_block or not causal):
         raise ValueError("a window belongs to the causal mask")
+    shared = 0 if k_shared is None else k_shared.shape[-1]
+    if q.shape[-1] != k.shape[-1] + shared:
+        raise ValueError(f"queries {q.shape[-1]} wide against keys "
+                         f"{k.shape[-1]} + {shared}")
+    if diffusion_block and (shared or q.shape[-1] != v.shape[-1]):
+        raise NotImplementedError(
+            "the block mask's kernels take one width for scores and values")
+    operands = (q, k, v) if k_shared is None else (q, k, v, k_shared)
     if diffusion_block:
         f = functools.partial(_flash_bd, sm_scale=float(sm_scale),
                               bd=int(diffusion_block))
     else:
         f = functools.partial(
-            _flash_attention, causal=causal, sm_scale=float(sm_scale),
+            _flash_attention if k_shared is None else _flash_shared,
+            causal=causal, sm_scale=float(sm_scale),
             q_offset=int(q_offset), k_offset=int(k_offset),
             block_q=block_q, block_k=block_k, window=int(window))
     mesh = ambient_mesh()
     if mesh is None or mesh.size == 1:
-        return f(q, k, v)
+        return f(*operands)
     spec = _bhsd_spec(mesh, ("dp", "fsdp"), "tp")
-    return jax.shard_map(f, mesh=mesh, in_specs=(spec, spec, spec),
-                         out_specs=spec, check_vma=False)(q, k, v)
+    # (the shared part's one head: whole on every device of a tp group)
+    in_specs = (spec, spec, spec, _bhsd_spec(mesh, ("dp", "fsdp"), None))
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs[:len(operands)],
+                         out_specs=spec, check_vma=False)(*operands)
 
 
 # ======================================================== ring attention
@@ -953,6 +1099,9 @@ def ring_attention(q, k, v, *, axis_name: str = "sp", causal: bool = True,
     merge with the online-softmax combine.  Matches unsharded causal attention
     exactly (global positions reconstructed from the axis index).
     """
+    if q.shape[-1] != v.shape[-1]:
+        raise NotImplementedError(
+            "ring attention takes one width for scores and values")
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     ring = jax.lax.axis_size(axis_name)

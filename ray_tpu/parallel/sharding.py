@@ -74,6 +74,11 @@ def llama_partition_rules() -> PartitionRules:
         # the gate on the attention's output: a column a query head
         (r"attn/wg/kernel", _spec("fsdp", "tp")),
         (r"attn/wo/kernel", _spec("tp", "fsdp")),
+        # latent attention: the down-projection to the latent and the shared
+        # rotary key belongs to no head; the up-projection's columns are the
+        # heads' (kv_norm's scale: replicated, below)
+        (r"attn/wdkv/kernel", _spec("fsdp", None)),
+        (r"attn/wukv/kernel", _spec("fsdp", "tp")),
         (r"mlp/(gate_proj|up_proj)/kernel", _spec("fsdp", "tp")),
         (r"mlp/down_proj/kernel", _spec("tp", "fsdp")),
         # routed layers (models/moe.py::RoutedSwiGLU as h_<n>/moe): the

@@ -26,6 +26,14 @@ query heads over 8 key/value heads of 128, a window of 512, 32 of 256 experts
 of 512 held beside a shared one, 12,544 vocabulary rows, two rows of 8192,
 remat): the flash kernels' window mode at 512-wide tiles beside the causal
 ones, and the grouped matmuls at the 512-wide shape, beside 11.1 GB of state.
+And the one-chip step of Kimi-VL-A3B-Instruct's decoder as one chip of eight
+holds it (2048 wide, a dense layer and five sparse ones, latent attention in
+each: 16 heads whose scores are 192 wide over values 128 wide with the rotary
+64 of the key held once a position, 8 of 64 experts of 1408 held beside a
+shared one of 2816, 20,480 vocabulary rows, one row of 16,384, remat): the
+flash kernels with their two widths and the shared key part at 1024-wide
+tiles, the backward's float32 dQ of 16,384 x 192 in VMEM, beside 10.7 GB of
+state.
 
 Every case runs in a subprocess (this file, as a script): the libtpu client
 must never meet the forced-CPU test process, and the child must NOT inherit
@@ -101,6 +109,14 @@ def _build(case: str, compile_: bool) -> dict:
         assert config.experts_held == (0, 32) and config.n_experts == 256
         assert config.n_head_per_layer == (48, 64, 64, 64, 48)
         assert config.sliding_window == 512 and seq == 8192
+    elif case == "kimi_vl":
+        mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+        (config, seq), rows = _cell("kimi-vl-s16k-1chip"), 1
+        assert config.experts_held == (0, 8) and config.n_experts == 64
+        assert (config.kv_lora_rank, config.qk_nope_head_dim,
+                config.qk_rope_head_dim, config.v_head_dim) == (
+                    512, 128, 64, 128)
+        assert config.n_layer == 6 and seq == 16384
     elif case == "granite":
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
         (config, seq), rows = _cell("granite-h-s8k-1chip"), 1
@@ -324,6 +340,34 @@ def test_laguna_step_compiles_and_fits_the_chip():
     # a sparse layer's held experts: twelve grouped-matmul calls and the two
     # that add rows into tokens, as SDAR's: 5 x 3 + 4 x (12 + 2)
     assert row["tpu_custom_calls"] == 5 * 3 + 4 * (12 + 2), row
+    assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
+
+
+def test_kimi_vl_step_lowers_for_one_v5e_chip():
+    """Tier-1: the one-chip step of Kimi-VL-A3B-Instruct's decoder at
+    published widths (a dense layer and five sparse ones, 8 of 64 experts
+    held, one row of 16,384) lowers for the TPU with its Mosaic kernels in
+    it: the flash kernels with scores 192 wide over values 128 wide and the
+    key's shared rotary part, the grouped matmuls of the held experts and the
+    sum of their rows into the tokens, and no other."""
+    kernels = _child(["kimi_vl"], compile_=False)["kimi_vl"]["lowered_kernels"]
+    assert kernels.pop("kernel") > 0
+    assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens"}, kernels
+
+
+@pytest.mark.slow
+def test_kimi_vl_step_compiles_and_fits_the_chip():
+    """The TPU compiler takes the two-width kernels at tiles of 1024 (the
+    backward with a float32 dQ of 16,384 x 192 in VMEM) and the grouped
+    matmuls over 1408-wide experts, and its memory analysis says the six
+    layers fit one chip at one row of 16,384 (PR 37: see PERF.md)."""
+    row = _child(["kimi_vl"], compile_=True)["kimi_vl"]
+    print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
+    assert "refused" not in row, row
+    # a layer: flash forward, its recomputation, the backward's one kernel;
+    # a sparse layer's held experts: twelve grouped-matmul calls and the two
+    # that add rows into tokens, as Laguna's: 6 x 3 + 5 x (12 + 2)
+    assert row["tpu_custom_calls"] == 6 * 3 + 5 * (12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
