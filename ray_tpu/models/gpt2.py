@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import (
+    FLASH_RESIDUALS,
     NEG_INF,
     flash_attention,
     mha_reference,
@@ -71,9 +72,11 @@ class GPT2Config:
     # at ~+1 forward pass of FLOPs; worth it for long-seq / large models, pure
     # overhead for small models that fit comfortably.
     remat: bool = True
-    # "full" recomputes everything; "dots" saves matmul outputs and recomputes
-    # only cheap elementwise ops (gelu/layernorm/softmax) — near-zero extra
-    # MXU FLOPs but longer live ranges (slower compile, more HBM).
+    # "full" recomputes a block from its input, except a flash kernel's own
+    # output and logsumexp, which are kept (``remat_block``); "dots" saves
+    # matmul outputs and recomputes only cheap elementwise ops
+    # (gelu/layernorm/softmax) — near-zero extra MXU FLOPs but longer live
+    # ranges (slower compile, more HBM).
     remat_policy: str = "full"  # "full" | "dots"
     # MoE: every `moe_every`-th block swaps its dense MLP for an expert-
     # parallel MoE FFN (0 = dense everywhere).  Experts shard over the `ep`
@@ -157,6 +160,19 @@ class Block(nn.Module):
         return x
 
 
+def remat_block(block_cls, remat_policy: str):
+    """``block_cls`` recomputed in the backward from its input.  Under
+    ``"full"`` everything in the block is, except that a flash kernel's
+    output and logsumexp are kept (``FLASH_RESIDUALS``): they are the one
+    thing in a block whose recomputation costs a whole kernel for one
+    activation-sized array.  q, k and v are recomputed like the rest."""
+    policies = jax.checkpoint_policies
+    policy = (policies.dots_with_no_batch_dims_saveable
+              if remat_policy == "dots"
+              else policies.save_only_these_names(*FLASH_RESIDUALS))
+    return nn.remat(block_cls, policy=policy)
+
+
 class GPT2LMModel(nn.Module):
     config: GPT2Config
 
@@ -174,14 +190,8 @@ class GPT2LMModel(nn.Module):
         if cfg.remat_policy not in ("full", "dots"):
             raise ValueError(f"unknown remat_policy: {cfg.remat_policy!r} "
                              "(expected 'full' or 'dots')")
-        if cfg.remat and cfg.remat_policy == "dots":
-            block_cls = nn.remat(
-                Block,
-                policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
-        elif cfg.remat:
-            block_cls = nn.remat(Block)
-        else:
-            block_cls = Block
+        block_cls = remat_block(Block, cfg.remat_policy) if cfg.remat \
+            else Block
         for i in range(cfg.n_layer):
             # remat each block: trade FLOPs for HBM (activations recomputed in
             # backward) — the standard TPU memory/bandwidth trade.

@@ -48,7 +48,12 @@ all heads share, and a head's scores are ``qk_nope_head_dim +
 qk_rope_head_dim`` wide over values ``v_head_dim`` wide
 (``ops.attention``'s ``k_shared``; Kimi-VL-A3B's decoder is the block with
 this, the leading dense layer and the sigmoid-routed experts).
-Every such field at its default leaves the program the dense Llama it was.  Same TPU discipline as the GPT stack —
+Every such field at its default leaves the program the dense Llama it was.
+``remat`` recomputes each block from its input in the backward; what
+``remat_policy="full"`` keeps beside that input is each attention layer's
+flash kernel output and logsumexp, so that no kernel's forward runs twice
+(``models/gpt2.py::remat_block``; a Mamba layer, a routed layer's Mosaic
+calls and reference attention keep nothing).  Same TPU discipline as the GPT stack —
 bfloat16 activations, fused QKV-free layout matched to
 ``llama_partition_rules`` so tp/fsdp shardings apply by regex, attention
 via the Pallas flash kernel (``ray_tpu.ops.flash_attention``) or ring
@@ -66,7 +71,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.gpt2 import mask_vocab_padding, padded_vocab
+from ray_tpu.models.gpt2 import (mask_vocab_padding, padded_vocab,
+                                 remat_block)
 from ray_tpu.models.mamba import Mamba2Mixer
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
 from ray_tpu.ops.attention import (block_diffusion_mask, flash_attention,
@@ -108,6 +114,8 @@ class LlamaConfig:
     attention_impl: str = "flash"    # "flash" | "ring" | "reference"
     ring_axis: str = "sp"
     remat: bool = True
+    # "full": a block is recomputed from its input, its flash kernels' output
+    # and logsumexp kept; "dots": matmul outputs kept (gpt2.remat_block)
     remat_policy: str = "full"
     # RMSNorm over the q and k projections: True, over the whole projection
     # before the split into heads; "head", over each head's head_dim after it
@@ -463,12 +471,8 @@ class LlamaLMModel(nn.Module):
         # both copies of a row count their positions from 0
         positions = jnp.tile(jnp.arange(S // 2), 2) if two_copies \
             else jnp.arange(S)
-        if cfg.remat:
-            policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
-                      if cfg.remat_policy == "dots" else None)
-            block_cls = nn.remat(LlamaBlock, policy=policy)
-        else:
-            block_cls = LlamaBlock
+        block_cls = remat_block(LlamaBlock, cfg.remat_policy) if cfg.remat \
+            else LlamaBlock
         for i in range(cfg.n_layer):
             if cfg.mlp_types:
                 routed = cfg.mlp_types[i] == "sparse"

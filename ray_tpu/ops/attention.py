@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
@@ -63,6 +64,12 @@ NEG_INF = -1e30
 # along a sequence is a multiple of LANES, and a per-query statistic lives in
 # VMEM as a (rows, LANES) column with every lane the same.
 LANES = 128
+# The names the forward rules give their output and logsumexp: the two
+# residuals that only the forward kernel can make again.  A block built under
+# ``jax.checkpoint(policy=save_only_these_names(*FLASH_RESIDUALS))`` keeps
+# them, and its recomputation for the backward runs no forward kernel;
+# outside a checkpoint a name lowers to nothing.
+FLASH_RESIDUALS = ("flash_out", "flash_lse")
 
 
 def _interpret() -> bool:
@@ -906,6 +913,12 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
 
 
 # ============================================================= public op
+def _named_forward(*args, **kwargs):
+    """``_flash_forward``'s ``out`` and ``lse`` under ``FLASH_RESIDUALS``."""
+    return tuple(map(checkpoint_name, _flash_forward(*args, **kwargs),
+                     FLASH_RESIDUALS))
+
+
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
 def _flash_attention(q, k, v, causal, sm_scale, q_offset, k_offset,
                      block_q, block_k, window=0):
@@ -916,7 +929,7 @@ def _flash_attention(q, k, v, causal, sm_scale, q_offset, k_offset,
 
 def _flash_fwd_rule(q, k, v, causal, sm_scale, q_offset, k_offset, block_q,
                     block_k, window):
-    out, lse = _flash_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
+    out, lse = _named_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
                               block_q, block_k, _interpret(), 0, window)
     return out, (q, k, v, out, lse)
 
@@ -946,7 +959,7 @@ def _flash_bd(q, k, v, sm_scale, bd):
 
 
 def _flash_bd_fwd(q, k, v, sm_scale, bd):
-    out, lse = _flash_forward(q, k, v, False, sm_scale, 0, 0, None, None,
+    out, lse = _named_forward(q, k, v, False, sm_scale, 0, 0, None, None,
                               _interpret(), bd)
     return out, (q, k, v, out, lse)
 
@@ -973,7 +986,7 @@ def _flash_shared(q, k, v, k_shared, causal, sm_scale, q_offset, k_offset,
 
 def _flash_shared_fwd(q, k, v, k_shared, causal, sm_scale, q_offset, k_offset,
                       block_q, block_k, window):
-    out, lse = _flash_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
+    out, lse = _named_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
                               block_q, block_k, _interpret(), 0, window,
                               k_shared)
     return out, (q, k, v, k_shared, out, lse)

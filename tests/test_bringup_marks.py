@@ -95,6 +95,11 @@ def _fit(tmp, name):
     logger.addHandler(keep)
     logger.setLevel(logging.INFO)
     ray_tpu.shutdown()
+    # marks that an earlier test file of this process left waiting for a
+    # ring (a ``ShardedPretrainer`` built outside any runtime) are not this
+    # session's: without this the verdict hangs on which files xdist gave
+    # this worker before
+    fr.shutdown()
     ray_tpu.init(num_cpus=4, object_store_memory=128 * 1024**2)
     try:
         core = global_worker_core()

@@ -134,12 +134,14 @@ def _build(case: str, compile_: bool) -> dict:
     batch = {k: jax.ShapeDtypeStruct((rows, seq), jnp.int32, sharding=sh)
              for k, sh in s.batch_sharding.items()}
     with jax.set_mesh(mesh):
-        lowered = s.step.trace(s.state, batch).lower(
-            lowering_platforms=("tpu",))
+        traced = s.step.trace(s.state, batch)
+        lowered = traced.lower(lowering_platforms=("tpu",))
     # the Mosaic calls of the lowered module by their kernels' names (a call
-    # the module makes twice through one function is printed once)
+    # the module makes twice through one function is printed once), and the
+    # flash forward's calls as the step makes them: those of its jaxpr
     out = {"case": case, "lowered_kernels": dict(collections.Counter(
-        re.findall(r'kernel_name = "(\w+)"', lowered.as_text())))}
+        re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))),
+        "flash_fwd_calls": _flash_fwd_calls(traced.jaxpr.jaxpr)}
     if compile_:
         try:
             compiled = lowered.compile()
@@ -152,6 +154,22 @@ def _build(case: str, compile_: bool) -> dict:
         out["temp_bytes"] = int(mem.temp_size_in_bytes)
         out["argument_bytes"] = int(mem.argument_size_in_bytes)
     return out
+
+
+def _flash_fwd_calls(jaxpr) -> int:
+    """The ``flash_fwd`` kernel's calls under ``jaxpr``: one an attention
+    layer a step, remat or not (the blocks' checkpoint keeps the kernel's
+    output and logsumexp: ``models/gpt2.py::remat_block``)."""
+    import jax
+
+    n = 0
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n += eqn.params["name"] == "flash_fwd"
+        else:
+            n += sum(_flash_fwd_calls(sub)
+                     for sub in jax.core.jaxprs_in_params(eqn.params))
+    return n
 
 
 def _build_flash(case: str, device) -> dict:
@@ -202,6 +220,7 @@ def test_flash_step_lowers_for_a_sharded_v5e_mesh():
     # a layer: one flash forward and the one kernel of its backward
     assert row["lowered_kernels"] == {"flash_fwd": N_LAYER,
                                       "flash_bwd": N_LAYER}, row
+    assert row["flash_fwd_calls"] == N_LAYER, row
 
 
 def test_flash_backward_compiles_with_its_whole_sequence_dq_in_vmem():
@@ -235,18 +254,23 @@ def test_olmoe_step_lowers_for_one_v5e_chip():
     """Tier-1: the one-chip OLMoE step at published widths (one layer, seq
     4096 x 2 rows) lowers for the TPU with its Mosaic kernels in it: the flash
     attention's and the routed experts' grouped matmul."""
-    kernels = _child(["olmoe_b2"], compile_=False)["olmoe_b2"]["lowered_kernels"]
-    # the one layer under remat: the flash forward twice and its backward's
-    # one kernel, beside the grouped matmul's (megablox names them "kernel")
+    row = _child(["olmoe_b2"], compile_=False)["olmoe_b2"]
+    kernels = row["lowered_kernels"]
+    # the one layer under remat: the flash forward once (its output and
+    # logsumexp are kept) and its backward's one kernel, beside the grouped
+    # matmul's (megablox names them "kernel")
     assert kernels.pop("kernel") > 0
-    assert kernels == {"flash_fwd": 2, "flash_bwd": 1}, kernels
+    assert kernels == {"flash_fwd": 1, "flash_bwd": 1}, kernels
+    assert row["flash_fwd_calls"] == 1, row
 
 
 @pytest.mark.slow
 def test_olmoe_step_compiles_and_says_how_many_rows_fit():
     """The TPU compiler takes the grouped matmul's shapes, and its memory
     analysis says what a step holds at 1, 2 and 4 rows of 4096 (PR 25:
-    7.51 GB of arguments + 2.43 / 3.40 / 5.72 GB of temporaries)."""
+    7.51 GB of arguments + 2.43 / 3.40 / 5.72 GB of temporaries; PR 38, the
+    block keeping its flash kernel's output and logsumexp: 7.51 + 1.87 /
+    2.68 / 4.07)."""
     rows = _child(["olmoe_b1", "olmoe_b2", "olmoe_b4"], compile_=True)
     for case, row in rows.items():
         print(case, {k: row.get(k) for k in
@@ -254,10 +278,11 @@ def test_olmoe_step_compiles_and_says_how_many_rows_fit():
     for case in ("olmoe_b1", "olmoe_b2"):
         row = rows[case]
         assert "refused" not in row, row
-        # flash forward, its recomputation, the backward's one kernel; the
-        # grouped matmul: gate, up, down forward, recomputed, and two
-        # backward calls each
-        assert row["tpu_custom_calls"] == 3 + 12, row
+        # flash forward and the backward's one kernel (no recomputation:
+        # the block keeps the forward's output and logsumexp); the grouped
+        # matmul: gate, up, down forward, recomputed, and two backward calls
+        # each
+        assert row["tpu_custom_calls"] == 2 + 12, row
         assert row["argument_bytes"] + row["temp_bytes"] < 14e9, row
 
 
@@ -266,20 +291,21 @@ def test_granite_step_lowers_for_one_v5e_chip():
     (five Mamba-2 layers and one attention layer, seq 8192 x 1 row) lowers
     for the TPU with the flash kernel's Mosaic calls in it."""
     row = _child(["granite"], compile_=False)["granite"]
-    # the one attention layer under remat: forward twice, one backward kernel
-    assert row["lowered_kernels"] == {"flash_fwd": 2, "flash_bwd": 1}, row
+    # the one attention layer under remat: one forward, one backward kernel
+    assert row["lowered_kernels"] == {"flash_fwd": 1, "flash_bwd": 1}, row
+    assert row["flash_fwd_calls"] == 1, row
 
 
 @pytest.mark.slow
 def test_granite_step_compiles_and_fits_the_chip():
     """The TPU compiler takes the plain-XLA scan at 64 heads x 32 chunks of
     256, and its memory analysis says the step fits (PR 29: 7.77 GB of
-    arguments + 3.98 GB of temporaries)."""
+    arguments + 3.98 GB of temporaries; PR 38: 7.77 + 3.97)."""
     row = _child(["granite"], compile_=True)["granite"]
+    print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row
-    # the one attention layer: flash forward, its recomputation, and the
-    # backward's one kernel
-    assert row["tpu_custom_calls"] == 3, row
+    # the one attention layer: flash forward and the backward's one kernel
+    assert row["tpu_custom_calls"] == 2, row
     assert row["argument_bytes"] + row["temp_bytes"] < 14e9, row
 
 
@@ -289,12 +315,14 @@ def test_sdar_step_lowers_for_one_v5e_chip():
     tokens as a noised and a clean copy) lowers for the TPU with its Mosaic
     kernels in it: the flash kernels under the block mask, the grouped
     matmuls of the held experts."""
-    kernels = _child(["sdar"], compile_=False)["sdar"]["lowered_kernels"]
+    row = _child(["sdar"], compile_=False)["sdar"]
+    kernels = row["lowered_kernels"]
     assert kernels.pop("kernel") > 0
     # six layers under remat (a kernel called by every layer through one
     # function is printed once per trace: forward, recomputation, backward);
     # ``onto_tokens`` adds a piece's rows into their tokens (PR 36)
     assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens"}, kernels
+    assert row["flash_fwd_calls"] == 6, row
 
 
 @pytest.mark.slow
@@ -302,17 +330,20 @@ def test_sdar_step_compiles_and_fits_the_chip():
     """The TPU compiler takes the block-mask kernels at 2 x 4096 queries over
     4096 keys and the grouped matmuls over the 16,384 rows of a piece of the
     buffer, inside the loops whose trips the device counts, and its memory
-    analysis says six layers fit one chip (PR 31, PR 32: see PERF.md)."""
+    analysis says six layers fit one chip (PR 31, PR 32: see PERF.md; PR 38,
+    six layers' outputs and logsumexps kept: 7.75 GB of arguments + 3.39 GB
+    of temporaries, 4.00 at PR 36)."""
     row = _child(["sdar"], compile_=True)["sdar"]
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row
-    # a layer: flash forward, its recomputation, the backward's one kernel;
+    # a layer: flash forward and the backward's one kernel (the forward's
+    # output and logsumexp are kept: no recomputation since PR 38);
     # the grouped matmul: gate, up, down in the forward's loop, and in the
     # backward's the three recomputed and two transposes each — the
     # checkpoint's recomputation of the forward's loop is dropped, nothing
     # reads it (PR 32); and in either loop the one call that adds the
-    # piece's rows into their tokens (PR 36): 3 + 12 + 2 a layer
-    assert row["tpu_custom_calls"] == 6 * (3 + 12 + 2), row
+    # piece's rows into their tokens (PR 36): 2 + 12 + 2 a layer
+    assert row["tpu_custom_calls"] == 6 * (2 + 12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
@@ -321,9 +352,11 @@ def test_laguna_step_lowers_for_one_v5e_chip():
     layers of three kinds, 32 of 256 experts held, two rows of 8192) lowers
     for the TPU with its Mosaic kernels in it: the flash kernels, causal and
     under the window, and the grouped matmuls of the held experts."""
-    kernels = _child(["laguna"], compile_=False)["laguna"]["lowered_kernels"]
+    row = _child(["laguna"], compile_=False)["laguna"]
+    kernels = row["lowered_kernels"]
     assert kernels.pop("kernel") > 0
     assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens"}, kernels
+    assert row["flash_fwd_calls"] == 5, row
 
 
 @pytest.mark.slow
@@ -332,14 +365,15 @@ def test_laguna_step_compiles_and_fits_the_chip():
     the reduction axis) beside the causal ones and the grouped matmuls over
     512-wide experts, and its memory analysis says the five layers fit one
     chip at two rows of 8192 (PR 35: 8.30 GB of arguments + 4.54 GB of
-    temporaries)."""
+    temporaries; PR 38, five layers' outputs and logsumexps kept: 8.30 +
+    4.56)."""
     row = _child(["laguna"], compile_=True)["laguna"]
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row
-    # a layer: flash forward, its recomputation, the backward's one kernel;
-    # a sparse layer's held experts: twelve grouped-matmul calls and the two
-    # that add rows into tokens, as SDAR's: 5 x 3 + 4 x (12 + 2)
-    assert row["tpu_custom_calls"] == 5 * 3 + 4 * (12 + 2), row
+    # a layer: flash forward and the backward's one kernel; a sparse layer's
+    # held experts: twelve grouped-matmul calls and the two that add rows
+    # into tokens, as SDAR's: 5 x 2 + 4 x (12 + 2)
+    assert row["tpu_custom_calls"] == 5 * 2 + 4 * (12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
@@ -350,9 +384,11 @@ def test_kimi_vl_step_lowers_for_one_v5e_chip():
     it: the flash kernels with scores 192 wide over values 128 wide and the
     key's shared rotary part, the grouped matmuls of the held experts and the
     sum of their rows into the tokens, and no other."""
-    kernels = _child(["kimi_vl"], compile_=False)["kimi_vl"]["lowered_kernels"]
+    row = _child(["kimi_vl"], compile_=False)["kimi_vl"]
+    kernels = row["lowered_kernels"]
     assert kernels.pop("kernel") > 0
     assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens"}, kernels
+    assert row["flash_fwd_calls"] == 6, row
 
 
 @pytest.mark.slow
@@ -360,14 +396,16 @@ def test_kimi_vl_step_compiles_and_fits_the_chip():
     """The TPU compiler takes the two-width kernels at tiles of 1024 (the
     backward with a float32 dQ of 16,384 x 192 in VMEM) and the grouped
     matmuls over 1408-wide experts, and its memory analysis says the six
-    layers fit one chip at one row of 16,384 (PR 37: see PERF.md)."""
+    layers fit one chip at one row of 16,384 (PR 37: see PERF.md; PR 38, six
+    layers' outputs and logsumexps kept: 8.03 GB of arguments + 3.82 GB of
+    temporaries, as at PR 37)."""
     row = _child(["kimi_vl"], compile_=True)["kimi_vl"]
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row
-    # a layer: flash forward, its recomputation, the backward's one kernel;
-    # a sparse layer's held experts: twelve grouped-matmul calls and the two
-    # that add rows into tokens, as Laguna's: 6 x 3 + 5 x (12 + 2)
-    assert row["tpu_custom_calls"] == 6 * 3 + 5 * (12 + 2), row
+    # a layer: flash forward and the backward's one kernel; a sparse layer's
+    # held experts: twelve grouped-matmul calls and the two that add rows
+    # into tokens, as Laguna's: 6 x 2 + 5 x (12 + 2)
+    assert row["tpu_custom_calls"] == 6 * 2 + 5 * (12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 

@@ -279,12 +279,13 @@ def test_c_the_eight_shares_add_up_to_the_uncut_layer():
 
 
 # ------------------------------------- (d) the other models' steps, untouched
-def test_d_lagunas_step_is_the_parents():
+def test_d_lagunas_step_is_the_parents(flash_names_off):
     """With one width and no shared key part the traced calls are the
     parent's: ``tests/test_laguna_parts.py`` (e) and ``tests/test_sdar_parts.py``
     (n) pin the dense, routed, hybrid and block-diffusion toys, unedited; this
     is the window kernels' toy, which neither pins: sha256 of its lowered
-    train step on the parent commit (PR 36), kernel bodies included."""
+    train step on the parent commit (PR 36), kernel bodies included (and,
+    since PR 38, ``flash_names_off``)."""
     from ray_tpu.models.pretrain import make_optimizer, sharded_train_step
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 

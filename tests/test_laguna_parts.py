@@ -170,7 +170,7 @@ _PARENT_STEPS = {
 
 
 @pytest.mark.parametrize("toy", sorted(_PARENT_STEPS))
-def test_e_defaults_leave_the_other_steps_as_they_were(toy):
+def test_e_defaults_leave_the_other_steps_as_they_were(toy, flash_names_off):
     from ray_tpu.models.pretrain import make_optimizer, sharded_train_step
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
