@@ -33,7 +33,10 @@ each: 16 heads whose scores are 192 wide over values 128 wide with the rotary
 shared one of 2816, 20,480 vocabulary rows, one row of 16,384, remat): the
 flash kernels with their two widths and the shared key part at 1024-wide
 tiles, the backward's float32 dQ of 16,384 x 192 in VMEM, beside 10.7 GB of
-state.
+state.  And the attention prelude alone (projection, heads, the per-head norm
+where there is one, the rotation, and their backward) at SDAR's and at
+Laguna's full layers' shapes: the bytes the compiled program moves over the
+projection and the transpose are where float32 copies of q would show.
 
 Every case runs in a subprocess (this file, as a script): the libtpu client
 must never meet the forced-CPU test process, and the child must NOT inherit
@@ -61,6 +64,13 @@ CASES = {
     "dp4": ({"dp": 4}, 4),
     "fsdp4": ({"dp": 1, "fsdp": 4}, 4),
     "dp2_tp2": ({"dp": 2, "tp": 2}, 4),
+}
+
+# name -> (query heads, the part of each head the tables turn, per-head norm)
+# of an attention prelude at 2 rows x 8192 positions, heads of 128, 2048 wide
+PRELUDES = {
+    "prelude_sdar": (32, 1.0, True),
+    "prelude_laguna_full": (48, 0.5, False),
 }
 
 
@@ -93,6 +103,8 @@ def _build(case: str, compile_: bool) -> dict:
         chips_per_host_bounds=[2, 2, 1], num_slices=1)
     if case.startswith("flash_s"):
         return _build_flash(case, topo.devices[0])
+    if case in PRELUDES:
+        return _build_prelude(case, topo.devices[0])
     if case.startswith("olmoe_b"):
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
         (config, seq), rows = _cell("olmoe-s4k-1chip"), \
@@ -196,6 +208,48 @@ def _build_flash(case: str, device) -> dict:
     return {"case": case}
 
 
+def _build_prelude(case: str, device) -> dict:
+    """In the child: what lies between a layer's input and its flash kernel
+    for the queries — ``x @ wq``, the split into heads, the transpose to
+    ``(B, H, S, D)`` and ``apply_rope`` (with the per-head norm's scale where
+    the shape has one) — and its backward, compiled for one chip; and the
+    same without ``apply_rope``, which a kernel in that layout costs anyway.
+    The compiled programs' ``bytes accessed``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models.llama import RopeTable, apply_rope, rope_table
+
+    heads, fraction, normed = PRELUDES[case]
+    B, S, D, E = 2, 8192, 128, 2048
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(device))
+
+    def gigabytes(turned: bool) -> float:
+        def prelude(x, wq, scale):
+            q = (x @ wq).reshape(B, S, heads, D).transpose(0, 2, 1, 3)
+            if not turned:
+                return q
+            cos, sin = rope_table(D, jnp.arange(S), RopeTable(
+                theta=1e6, rotary_fraction=fraction))
+            return apply_rope(q, cos, sin, scale if normed else None)
+
+        def both_ways(x, wq, scale, g):
+            out, vjp = jax.vjp(prelude, x, wq, scale)
+            return out, vjp(g)
+
+        compiled = jax.jit(both_ways).lower(
+            shape((B, S, E)), shape((E, heads * D)),
+            shape((D,), jnp.float32), shape((B, heads, S, D))).compile()
+        return compiled.cost_analysis()["bytes accessed"] / 1e9
+
+    return {"case": case, "layout_only_gb": gigabytes(False),
+            "prelude_gb": gigabytes(True)}
+
+
 def _child(cases, compile_: bool) -> dict:
     env = {k: v for k, v in os.environ.items()
            if k not in ("RAY_TPU_PALLAS_INTERPRET", "XLA_FLAGS")}
@@ -234,6 +288,22 @@ def test_flash_backward_compiles_with_its_whole_sequence_dq_in_vmem():
     assert "refused" not in rows["flash_s8192_d128"], rows
     assert "refused" not in rows["flash_s32768_d128"], rows
     assert "vmem" in rows["flash_s131072_d128"]["refused"], rows
+
+
+def test_attention_prelude_moves_no_float32_copy_of_the_queries():
+    """Tier-1, a few seconds a shape: over the projection and the transpose
+    to ``(B, H, S, D)``, norm and rotation (``models/llama.py::apply_rope``)
+    and their backward add the passes an elementwise region needs and little
+    more — 1.86 GB at SDAR's shape (32 heads, per-head norm) and 1.33 GB at
+    Laguna's full layers' (48 heads, half of each turned), PR 39.  The split
+    / concatenate form they replaced added 3.41 and 3.70: XLA wrote q in
+    float32, each 64-lane half in a 128-lane tiling, and copies between."""
+    rows = _child(list(PRELUDES), compile_=True)
+    over = {case: row["prelude_gb"] - row["layout_only_gb"]
+            for case, row in rows.items()}
+    print(rows)
+    assert 0.6 < over["prelude_sdar"] < 2.0, rows
+    assert 0.6 < over["prelude_laguna_full"] < 1.7, rows
 
 
 @pytest.mark.slow
@@ -332,7 +402,7 @@ def test_sdar_step_compiles_and_fits_the_chip():
     buffer, inside the loops whose trips the device counts, and its memory
     analysis says six layers fit one chip (PR 31, PR 32: see PERF.md; PR 38,
     six layers' outputs and logsumexps kept: 7.75 GB of arguments + 3.39 GB
-    of temporaries, 4.00 at PR 36)."""
+    of temporaries, 4.00 at PR 36; 3.33 with PR 39's rotation)."""
     row = _child(["sdar"], compile_=True)["sdar"]
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row
@@ -366,7 +436,7 @@ def test_laguna_step_compiles_and_fits_the_chip():
     512-wide experts, and its memory analysis says the five layers fit one
     chip at two rows of 8192 (PR 35: 8.30 GB of arguments + 4.54 GB of
     temporaries; PR 38, five layers' outputs and logsumexps kept: 8.30 +
-    4.56)."""
+    4.56; 4.38 with PR 39's rotation)."""
     row = _child(["laguna"], compile_=True)["laguna"]
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row

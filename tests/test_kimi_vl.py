@@ -285,7 +285,9 @@ def test_d_lagunas_step_is_the_parents(flash_names_off):
     (n) pin the dense, routed, hybrid and block-diffusion toys, unedited; this
     is the window kernels' toy, which neither pins: sha256 of its lowered
     train step on the parent commit (PR 36), kernel bodies included (and,
-    since PR 38, ``flash_names_off``)."""
+    since PR 38, ``flash_names_off``; since PR 39 the hash is that PR's: the
+    rotation of q and k is ``apply_rope``'s one pass, the kernels' calls as
+    they were)."""
     from ray_tpu.models.pretrain import make_optimizer, sharded_train_step
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
@@ -298,7 +300,7 @@ def test_d_lagunas_step_is_the_parents(flash_names_off):
     with jax.set_mesh(mesh):
         text = s.step.trace(s.state, batch).lower().as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        "bf5533bb617cb61c84c60efa1f7bce5582b1cd9701d8fbb4406b68e10d37863a"
+        "d568d1dee700081fc74714c35bff30d4540609325925531fa23ec0a46dbf3189"
 
 
 # ------------------------------------------------- (e) on a virtual mesh

@@ -158,14 +158,15 @@ def test_c_the_shares_add_up_to_the_uncut_layer():
 # defaults the fields change nothing of the dense, the routed, the hybrid or
 # the block-diffusion program, the interpreted kernels' bodies included.  A
 # later PR that changes one of these programs on purpose prints the new text's
-# hash from the assertion below and pins that.  (``toy-sdar`` is PR 36's: the
-# held layer adds a piece's rows into their tokens by a kernel, not by a
-# scatter-add; the three without a held layer are PR 34's still.)
+# hash from the assertion below and pins that.  (``toy-llama``, ``toy-olmoe``
+# and ``toy-sdar`` are PR 39's: the rotation of q and k is one pass with a
+# matmul by a signed permutation in it, SDAR's per-head norm inside it;
+# ``toy-granite``, which rotates nothing, is PR 34's still.)
 _PARENT_STEPS = {
-    "toy-llama": "b956332e82341f0899a054e790e6f325cb091c48e1a72dfd2694629f1c029a61",
-    "toy-olmoe": "bcea5e9348e7b1f1ef253aeeabbae40090afa6bee0ad5bee9f686fc285b5d8a3",
+    "toy-llama": "b8dbc8021fbd7af294719bf12a00ca4fe10923109267e12b7a8884fbb98d1eca",
+    "toy-olmoe": "9ee0789b91e05c95f4e8c555538c548c4b5b72309abaa69c20a7f686e80fc6d3",
     "toy-granite": "019b08487290338c25579cc673790d46ed096067b1707c60d926f24ad284739b",
-    "toy-sdar": "966bba13b723cb80d3c24019aa0b874b774360f079c598f71c4b0742d57931df",
+    "toy-sdar": "3aedbb0be6aee9cc1357da348c77f5dce55c5b9f477820bdc7a9b2fb82d0add3",
 }
 
 

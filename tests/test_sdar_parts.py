@@ -606,8 +606,8 @@ def test_i_every_model_the_benchmark_has_is_the_program_it_was(name):
 
 
 @pytest.mark.parametrize("impl,dtype,want", [
-    ("reference", None, "0x1.5bfd300000000p+2"),
-    ("flash", None, "0x1.5c0c4e0000000p+2"),
+    ("reference", None, "0x1.5c19680000000p+2"),
+    ("flash", None, "0x1.5c18e20000000p+2"),
     ("reference", jnp.float32, "0x1.5c68860000000p+2"),
     ("flash", jnp.float32, "0x1.5c68860000000p+2")])
 def test_m_the_sdar_toy_has_the_loss_it_had_with_the_whole_buffer(
@@ -620,9 +620,12 @@ def test_m_the_sdar_toy_has_the_loss_it_had_with_the_whole_buffer(
     output is rounded to bf16 once, from one softmax over all its keys, where
     it was the kernel's bf16 result merged with the own squares' term in
     float32 and rounded again (0x1.5c2ed6p+2 then, further from the XLA
-    row).  In float32 nothing rounds: the flash kernels give the XLA row's
-    number, as they did at the parent (0x1.5c6888p+2) to the last bit but
-    one."""
+    row).  Both bf16 rows are PR 39's: the per-head norm of q and k is
+    applied in the rotation's float32 pass and no longer rounded to bf16
+    between the two (0x1.5bfd30p+2 and 0x1.5c0c4ep+2 before: each moved
+    towards the float32 rows).  In float32 nothing rounds: the flash kernels
+    give the XLA row's number, as they did at the parent (0x1.5c6888p+2) to
+    the last bit but one."""
     cfg = dataclasses.replace(sdar_moe.model_config(TOY, 1),
                               attention_impl=impl)
     if dtype is not None:
