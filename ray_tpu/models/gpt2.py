@@ -5,8 +5,8 @@ allreduce).  Design choices for the MXU/HBM:
 
 - bfloat16 activations, float32 params + optimizer state (cast at use);
 - fused QKV projection (one big matmul instead of three);
-- attention via ``ray_tpu.ops.flash_attention`` (Pallas blockwise kernel) or
-  ``ring_attention`` when the batch is sequence-sharded over an ``sp`` axis;
+- attention through ``ops.attention.attention``: the Pallas blockwise flash
+  kernel, or the ring when the batch is sequence-sharded over an ``sp`` axis;
 - parameter names line up with ``parallel.sharding.gpt_partition_rules`` so
   dp/fsdp/tp shardings apply by regex;
 - the two vocab-sized tables (``wte``, ``lm_head``) are stored padded to a
@@ -28,14 +28,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import (
-    FLASH_RESIDUALS,
-    NEG_INF,
-    flash_attention,
-    mha_reference,
-    ring_attention,
-    ring_attention_sharded,
-)
+from ray_tpu.ops.attention import FLASH_RESIDUALS, NEG_INF, attention
 from ray_tpu.parallel.sharding import constrain_residual
 
 VOCAB_ALIGN = 128  # one lane tile; also divisible by every tp size in use
@@ -110,16 +103,8 @@ class Attention(nn.Module):
         q = q.reshape(B, S, H, D).transpose(0, 2, 1, 3)
         k = k.reshape(B, S, H, D).transpose(0, 2, 1, 3)
         v = v.reshape(B, S, H, D).transpose(0, 2, 1, 3)
-        if cfg.attention_impl == "ring":
-            # Under jit/GSPMD the sp axis is made manual via shard_map; inside
-            # an explicit shard_map (axis already bound) call ring_attention
-            # directly instead.
-            out = ring_attention_sharded(q, k, v, causal=True,
-                                         seq_axis=cfg.ring_axis)
-        elif cfg.attention_impl == "reference":
-            out = mha_reference(q, k, v, causal=True)
-        else:
-            out = flash_attention(q, k, v, causal=True)
+        out = attention(q, k, v, impl=cfg.attention_impl,
+                        ring_axis=cfg.ring_axis)
         out = out.transpose(0, 2, 1, 3).reshape(B, S, E)
         return nn.Dense(E, dtype=cfg.dtype, name="out_proj")(out)
 

@@ -57,8 +57,8 @@ flash kernel output and logsumexp, so that no kernel's forward runs twice
 calls and reference attention keep nothing).  Same TPU discipline as the GPT stack —
 bfloat16 activations, fused QKV-free layout matched to
 ``llama_partition_rules`` so tp/fsdp shardings apply by regex, attention
-via the Pallas flash kernel (``ray_tpu.ops.flash_attention``) or ring
-attention under an ``sp`` axis — and the same ``ShardedPretrainer`` drives
+through ``ops.attention.attention``, which picks the Pallas flash kernel or,
+under an ``sp`` axis, ring attention — and the same ``ShardedPretrainer`` drives
 it (reference analogue: the reference trains models through external
 libs; the in-repo flagship models are this framework's own).
 """
@@ -77,8 +77,7 @@ from ray_tpu.models.gpt2 import (mask_vocab_padding, padded_vocab,
                                  remat_block)
 from ray_tpu.models.mamba import Mamba2Mixer
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
-from ray_tpu.ops.attention import (block_diffusion_mask, flash_attention,
-                                   mha_reference, ring_attention_sharded)
+from ray_tpu.ops.attention import attention
 from ray_tpu.parallel.sharding import constrain_residual
 
 
@@ -338,6 +337,20 @@ class HeadNormScale(nn.Module):
         return self.param("scale", nn.initializers.ones, (width,), jnp.float32)
 
 
+def _attend(cfg: LlamaConfig, kind: str, q, k, v, k_shared=None):
+    """A layer's one call into ``ops.attention``: what the configuration and
+    the layer's kind say of the mask — a window for a sliding layer, under
+    block diffusion (x is [noised ; clean]) the block mask in place of the
+    causal one.  Which implementation takes it, and whether one does, is
+    ``attention``'s to say."""
+    return attention(
+        q, k, v, impl=cfg.attention_impl, k_shared=k_shared,
+        window=cfg.sliding_window if kind == "sliding_attention" else 0,
+        diffusion_block=cfg.diffusion_block
+        if cfg.objective == "block_diffusion" else 0,
+        sm_scale=cfg.attn_scale, ring_axis=cfg.ring_axis)
+
+
 class LlamaAttention(nn.Module):
     config: LlamaConfig
     kind: str = "attention"     # or "full_attention", "sliding_attention"
@@ -348,7 +361,6 @@ class LlamaAttention(nn.Module):
         cfg = self.config
         B, S, E = x.shape
         H, KV = self.n_head or cfg.n_head, cfg.n_kv_head
-        window = cfg.sliding_window if self.kind == "sliding_attention" else 0
         # the kind's own rotary table, or the whole head turned by rope_theta
         table = dict(cfg.rope_tables).get(self.kind) or (
             RopeTable(theta=cfg.rope_theta) if cfg.rope else None)
@@ -388,42 +400,7 @@ class LlamaAttention(nn.Module):
             with jax.named_scope("kv_repeat"):
                 k = jnp.repeat(k, rep, axis=1)
                 v = jnp.repeat(v, rep, axis=1)
-        if cfg.objective == "block_diffusion":
-            # x is [noised ; clean]: the block mask in place of the causal one
-            if cfg.attention_impl == "reference":
-                out = mha_reference(
-                    q, k, v, sm_scale=cfg.attn_scale,
-                    mask=block_diffusion_mask(S // 2, cfg.diffusion_block))
-            elif cfg.attention_impl == "flash":
-                out = flash_attention(q, k, v, causal=False,
-                                      sm_scale=cfg.attn_scale,
-                                      diffusion_block=cfg.diffusion_block)
-            else:
-                raise NotImplementedError(
-                    "block-diffusion attention over a sharded sequence: "
-                    f"attention_impl={cfg.attention_impl!r} has no block mask")
-        elif window:
-            if cfg.attention_impl == "reference":
-                out = mha_reference(q, k, v, causal=True, window=window,
-                                    sm_scale=cfg.attn_scale)
-            elif cfg.attention_impl == "flash":
-                # the scope tells these calls from the full layers' in a trace
-                with jax.named_scope("window"):
-                    out = flash_attention(q, k, v, causal=True, window=window,
-                                          sm_scale=cfg.attn_scale)
-            else:
-                raise NotImplementedError(
-                    "window attention over a sharded sequence: "
-                    f"attention_impl={cfg.attention_impl!r} has no window")
-        elif cfg.attention_impl == "ring":
-            out = ring_attention_sharded(q, k, v, causal=True,
-                                         sm_scale=cfg.attn_scale,
-                                         seq_axis=cfg.ring_axis)
-        elif cfg.attention_impl == "reference":
-            out = mha_reference(q, k, v, causal=True, sm_scale=cfg.attn_scale)
-        else:
-            out = flash_attention(q, k, v, causal=True,
-                                  sm_scale=cfg.attn_scale)
+        out = _attend(cfg, self.kind, q, k, v)
         out = out.transpose(0, 2, 1, 3)
         if cfg.attn_gate:
             # one scalar a head a token, from the layer's normed input
@@ -478,20 +455,9 @@ class LatentAttention(nn.Module):
             q = jnp.concatenate(
                 [q[..., :dn], apply_rope(q[..., dn:], cos, sin)], axis=-1)
             kr = apply_rope(kr, cos, sin)
-        if (cfg.objective == "block_diffusion" or cfg.attention_impl == "ring"
-                or self.kind == "sliding_attention"):
-            raise NotImplementedError(
-                "latent attention under the block mask, over a sharded "
-                "sequence (those kernels take one width for scores and "
-                "values) or under a window")
-        if cfg.attention_impl == "reference":
-            out = mha_reference(q, k, v, causal=True, sm_scale=cfg.attn_scale,
-                                k_shared=kr)
-        else:
-            # the scope tells these calls from another kind's in a trace
-            with jax.named_scope("mla"):
-                out = flash_attention(q, k, v, causal=True,
-                                      sm_scale=cfg.attn_scale, k_shared=kr)
+        # the scope tells these calls from another kind's in a trace
+        with jax.named_scope("mla"):
+            out = _attend(cfg, self.kind, q, k, v, kr)
         out = out.transpose(0, 2, 1, 3).reshape(B, S, H * dv)
         return dense(E, "wo")(out)
 

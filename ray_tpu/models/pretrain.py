@@ -167,7 +167,7 @@ def train_step(model, tx, state, batch):
 
 class ShardedStep(NamedTuple):
     step: Any         # jitted (state, batch) -> (state, loss, MoE statistics)
-    model: Any        # the module the step applies (attention impl resolved)
+    model: Any        # the module the step applies
     init_state: Any   # () -> (params, opt_state), seeded; jit it into `state`'s shardings
     state: Any        # (params, opt_state) as ShapeDtypeStructs with shardings
     batch_sharding: Dict[str, NamedSharding]
@@ -183,9 +183,6 @@ def sharded_train_step(config, mesh, tx) -> ShardedStep:
     ``jax.set_mesh(mesh)``: the attention kernels and the residual-stream
     constraint read the ambient mesh.
     """
-    if mesh.shape.get("sp", 1) > 1 and config.attention_impl == "flash":
-        # sequence sharding needs the ring kernel
-        config = dataclasses.replace(config, attention_impl="ring")
     model_cls, rules_fn = _model_family(config)
     model = model_cls(config)
 

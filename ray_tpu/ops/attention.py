@@ -45,6 +45,7 @@ multiple of 128 for peak MXU utilization but any size compiles.
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import os
 from typing import NamedTuple, Optional
@@ -109,6 +110,8 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = N
     position; k is then D - D_s wide."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    if window and (mask is not None or not causal):
+        raise ValueError("a window belongs to the causal mask")
     if k_shared is not None:
         k = jnp.concatenate([k, jnp.broadcast_to(
             k_shared, (*k.shape[:-1], k_shared.shape[-1]))], axis=-1)
@@ -207,7 +210,7 @@ class _Tiles(NamedTuple):
     nk: int
     k_pad_from: Optional[int]   # first padding key, None if there is none
     tri: int         # chunk of a diagonal tile's queries; 0: whole-tile mask
-    # Block diffusion (``_flash_bd``): queries and keys are two halves of
+    # Block diffusion (``bd`` > 0): queries and keys are two halves of
     # ``nq / 2 == nk`` tiles each, the noised copy and then the clean copy of
     # the same ``s_k`` positions, in blocks of ``bd``; ``nk``, ``block_k`` and
     # the walk are over the clean keys.  Query r of half h (0 noised, 1 clean)
@@ -919,89 +922,44 @@ def _named_forward(*args, **kwargs):
                      FLASH_RESIDUALS))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8, 9))
-def _flash_attention(q, k, v, causal, sm_scale, q_offset, k_offset,
-                     block_q, block_k, window=0):
-    out, _ = _flash_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
-                            block_q, block_k, _interpret(), 0, window)
-    return out
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(4, 12)))
+def _flash_attention(q, k, v, k_shared=None, causal=True, sm_scale=1.0,
+                     q_offset=0, k_offset=0, block_q=None, block_k=None,
+                     window=0, bd=0):
+    """The flash pair under its one differentiation rule, for every mask and
+    layout the kernels take.  ``k_shared``: the last dimensions of every
+    head's key held once a position (``flash_attention``), or None — an empty
+    pytree, whose cotangent is None.  ``bd`` > 0, attention under
+    ``block_diffusion_mask``: q, k, v (b, h, 2 l, d), the noised copy of ``l``
+    positions and then the clean one, in blocks of ``bd``.  One kernel call,
+    one online softmax over every live pair: both copies' queries over the
+    clean keys, tiles skipped and masked as under a causal diagonal, and a
+    noised block against itself (``l / bd`` squares of ``bd`` x ``bd``
+    scores: 0.1% of the pairs at l 4096 and bd 4) as one more step of a
+    noised tile (``_Tiles``)."""
+    return _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, q_offset,
+                           k_offset, block_q, block_k, window, bd)[0]
 
 
-def _flash_fwd_rule(q, k, v, causal, sm_scale, q_offset, k_offset, block_q,
-                    block_k, window):
+def _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, q_offset, k_offset,
+                    block_q, block_k, window, bd):
     out, lse = _named_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
-                              block_q, block_k, _interpret(), 0, window)
-    return out, (q, k, v, out, lse)
-
-
-def _flash_bwd_rule(causal, sm_scale, q_offset, k_offset, block_q, block_k,
-                    window, residuals, g):
-    q, k, v, out, lse = residuals
-    with jax.named_scope("flash_bwd"):
-        return _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                               q_offset, k_offset, _interpret(), 0, window)
-
-
-_flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
-
-
-# ------------------------------------------------------ block diffusion
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _flash_bd(q, k, v, sm_scale, bd):
-    """Attention under ``block_diffusion_mask``: q, k, v (b, h, 2 l, d), the
-    noised copy of ``l`` positions and then the clean one, in blocks of
-    ``bd``.  One kernel call, one online softmax over every live pair: both
-    copies' queries over the clean keys, tiles skipped and masked as under a
-    causal diagonal, and a noised block against itself (``l / bd`` squares of
-    ``bd`` x ``bd`` scores: 0.1% of the pairs at l 4096 and bd 4) as one more
-    step of a noised tile (``_Tiles``)."""
-    return _flash_bd_fwd(q, k, v, sm_scale, bd)[0]
-
-
-def _flash_bd_fwd(q, k, v, sm_scale, bd):
-    out, lse = _named_forward(q, k, v, False, sm_scale, 0, 0, None, None,
-                              _interpret(), bd)
-    return out, (q, k, v, out, lse)
-
-
-def _flash_bd_bwd(sm_scale, bd, residuals, g):
-    q, k, v, out, lse = residuals
-    with jax.named_scope("flash_bwd"):
-        return _flash_backward(q, k, v, out, lse, g, False, sm_scale, 0, 0,
-                               _interpret(), bd)
-
-
-_flash_bd.defvjp(_flash_bd_fwd, _flash_bd_bwd)
-
-
-# ------------------------------------------------- a shared part of the keys
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7, 8, 9, 10))
-def _flash_shared(q, k, v, k_shared, causal, sm_scale, q_offset, k_offset,
-                  block_q, block_k, window=0):
-    """``_flash_attention`` with the last dimensions of every head's key held
-    once a position (``flash_attention``'s ``k_shared``)."""
-    return _flash_shared_fwd(q, k, v, k_shared, causal, sm_scale, q_offset,
-                             k_offset, block_q, block_k, window)[0]
-
-
-def _flash_shared_fwd(q, k, v, k_shared, causal, sm_scale, q_offset, k_offset,
-                      block_q, block_k, window):
-    out, lse = _named_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
-                              block_q, block_k, _interpret(), 0, window,
+                              block_q, block_k, _interpret(), bd, window,
                               k_shared)
     return out, (q, k, v, k_shared, out, lse)
 
 
-def _flash_shared_bwd(causal, sm_scale, q_offset, k_offset, block_q, block_k,
-                      window, residuals, g):
+def _flash_bwd_rule(causal, sm_scale, q_offset, k_offset, block_q, block_k,
+                    window, bd, residuals, g):
     q, k, v, k_shared, out, lse = residuals
     with jax.named_scope("flash_bwd"):
-        return _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                               q_offset, k_offset, _interpret(), 0, window,
-                               k_shared)
+        grads = _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
+                                q_offset, k_offset, _interpret(), bd, window,
+                                k_shared)
+    return grads if k_shared is not None else (*grads, None)
 
 
-_flash_shared.defvjp(_flash_shared_fwd, _flash_shared_bwd)
+_flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
 def _bhsd_spec(mesh, batch_axes, head_axis, seq_axis=None):
@@ -1043,6 +1001,8 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    # These three guard the kernels against a direct caller (the tests are
+    # one); ``attention`` calls through and lets them speak.
     if window and (diffusion_block or not causal):
         raise ValueError("a window belongs to the causal mask")
     shared = 0 if k_shared is None else k_shared.shape[-1]
@@ -1053,15 +1013,12 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
         raise NotImplementedError(
             "the block mask's kernels take one width for scores and values")
     operands = (q, k, v) if k_shared is None else (q, k, v, k_shared)
-    if diffusion_block:
-        f = functools.partial(_flash_bd, sm_scale=float(sm_scale),
-                              bd=int(diffusion_block))
-    else:
-        f = functools.partial(
-            _flash_attention if k_shared is None else _flash_shared,
-            causal=causal, sm_scale=float(sm_scale),
-            q_offset=int(q_offset), k_offset=int(k_offset),
-            block_q=block_q, block_k=block_k, window=int(window))
+    # (the block mask is its own: causal is not asked, the offsets not read)
+    f = functools.partial(
+        _flash_attention, causal=causal and not diffusion_block,
+        sm_scale=float(sm_scale), q_offset=int(q_offset),
+        k_offset=int(k_offset), block_q=block_q, block_k=block_k,
+        window=int(window), bd=int(diffusion_block))
     mesh = ambient_mesh()
     if mesh is None or mesh.size == 1:
         return f(*operands)
@@ -1112,6 +1069,7 @@ def ring_attention(q, k, v, *, axis_name: str = "sp", causal: bool = True,
     merge with the online-softmax combine.  Matches unsharded causal attention
     exactly (global positions reconstructed from the axis index).
     """
+    # (guards a direct caller and ``attention`` alike, which calls through)
     if q.shape[-1] != v.shape[-1]:
         raise NotImplementedError(
             "ring attention takes one width for scores and values")
@@ -1168,3 +1126,61 @@ def ring_attention_sharded(q, k, v, *, mesh=None, causal: bool = True,
                           sm_scale=sm_scale),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
     return f(q, k, v)
+
+
+# ================================================================ the entry
+# What was asked (a keyword of ``attention``) -> the implementations that have
+# nothing for it, and the words for it: the one table of what is refused.  A
+# new mask or layout is a row here and a field of ``_Tiles``.  What an
+# implementation's own guard covers is not said again: ``attention`` calls
+# through and lets it speak (a window without the causal mask, the block mask
+# over two widths, two widths around the ring).
+_REFUSED = {
+    "window": (("ring",), "window"),
+    "diffusion_block": (("ring",), "block mask"),
+    "k_shared": (("ring",), "key part that the heads share (latent "
+                 "attention): it takes one width for scores and values"),
+}
+
+
+def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
+              diffusion_block: int = 0, sm_scale: Optional[float] = None,
+              k_shared=None, ring_axis: str = "sp"):
+    """What a model's attention layer calls: q, k, v (B, H, S, D) and the
+    mask's parameters as ``flash_attention`` and ``mha_reference`` take them
+    (``diffusion_block`` > 0: ``block_diffusion_mask`` in place of the causal
+    mask).  Which implementation runs is decided here and nowhere else:
+    ``impl`` is a config's ``attention_impl`` — "reference", "ring" or "flash",
+    and "flash" under an ambient mesh that shards the sequence
+    (``ring_axis`` > 1) is the ring, since the kernels want the sequence whole.
+    What the implementation has nothing for is refused, here (``_REFUSED``)
+    or by its own guard."""
+    mesh = ambient_mesh()
+    sharded = impl == "flash" and mesh is not None \
+        and mesh.shape.get(ring_axis, 1) > 1
+    if sharded:
+        impl = "ring"
+    asked = {"window": bool(window), "diffusion_block": bool(diffusion_block),
+             "k_shared": k_shared is not None}
+    for name, (impls, words) in _REFUSED.items():
+        if asked[name] and impl in impls:
+            raise NotImplementedError(
+                f"attention_impl={impl!r}" + (
+                    f" (\"flash\" over a sequence sharded on {ring_axis!r})"
+                    if sharded else "") + f" has no {words}")
+    if impl == "reference":
+        mask = block_diffusion_mask(q.shape[2] // 2, diffusion_block) \
+            if diffusion_block else None
+        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
+                             mask=mask, window=window, k_shared=k_shared)
+    if impl == "ring":
+        return ring_attention_sharded(q, k, v, causal=causal,
+                                      sm_scale=sm_scale, seq_axis=ring_axis)
+    if impl != "flash":
+        raise ValueError(f"unknown attention_impl {impl!r} (expected "
+                         "'flash', 'ring' or 'reference')")
+    # the scope tells a window's calls from the full layers' in a trace
+    with jax.named_scope("window") if window else contextlib.nullcontext():
+        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
+                               diffusion_block=diffusion_block, window=window,
+                               k_shared=k_shared)
