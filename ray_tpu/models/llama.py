@@ -48,7 +48,15 @@ rotary part of the key (``qk_rope_head_dim``) is one vector a position that
 all heads share, and a head's scores are ``qk_nope_head_dim +
 qk_rope_head_dim`` wide over values ``v_head_dim`` wide
 (``ops.attention``'s ``k_shared``; Kimi-VL-A3B's decoder is the block with
-this, the leading dense layer and the sigmoid-routed experts).
+this, the leading dense layer and the sigmoid-routed experts).  A layer of
+``layer_types`` may be ``"conv"`` (``conv``: ``ShortConvMixer``, LFM2's gated
+short convolution — one projection to three parts ``B``, ``C``, ``u``, a
+causal depthwise convolution ``conv_width`` wide over ``B * u``, the gate
+``C`` on its result, one projection back), and the routed layer may choose its
+experts through a per-expert bias that is state and not a weight
+(``router_selection_bias``, ``norm_topk_eps``: ``models/moe.py``)
+(LFM2-24B-A2B is the block with these, the per-head norm and GQA at heads 64
+wide).
 Every such field at its default leaves the program the dense Llama it was.
 ``remat`` recomputes each block from its input in the backward; what
 ``remat_policy="full"`` keeps beside that input is each attention layer's
@@ -75,7 +83,8 @@ import numpy as np
 
 from ray_tpu.models.gpt2 import (mask_vocab_padding, padded_vocab,
                                  remat_block)
-from ray_tpu.models.mamba import Mamba2Mixer
+from ray_tpu.models.mamba import (Mamba2Mixer, _conv_init,
+                                  gated_short_conv)
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
 from ray_tpu.ops.attention import attention
 from ray_tpu.parallel.sharding import constrain_residual
@@ -135,8 +144,9 @@ class LlamaConfig:
     experts_held: Optional[Tuple[int, int]] = None
     router_aux_weight: float = 0.01  # x load-balancing loss, in the objective
     router_z_weight: float = 1e-3    # x router z-loss
-    # each layer's token mixer, "attention" or "mamba" (models/mamba.py), one
-    # entry a layer; empty: attention in every layer
+    # each layer's token mixer, "attention" (or a kind of it), "mamba"
+    # (models/mamba.py) or "conv" (ShortConvMixer), one entry a layer; empty:
+    # attention in every layer
     layer_types: Tuple[str, ...] = ()
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
@@ -179,6 +189,11 @@ class LlamaConfig:
     qk_nope_head_dim: int = 0        # a head's key part made from the latent
     qk_rope_head_dim: int = 0        # the rotary key part all heads share
     v_head_dim: int = 0              # a head's values
+    conv_width: int = 3              # positions a "conv" layer's kernel spans
+    # the routed layers choose their experts by score + a bias an expert that
+    # no gradient and no optimizer update reaches (models/moe.py)
+    router_selection_bias: bool = False
+    norm_topk_eps: float = 0.0       # + the sum norm_topk_prob divides by
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -462,6 +477,51 @@ class LatentAttention(nn.Module):
         return dense(E, "wo")(out)
 
 
+class ThreeWayDense(nn.Module):
+    """``x -> (x W_0, x W_1, x W_2)``, the kernel (in, 3, out) holding the
+    three matrices side by side: one ``Dense`` to ``3 * out`` whose parts a
+    ``tp`` axis cuts each by its own columns (``P("fsdp", None, "tp")``), and
+    whose three results are arrays of their own, never joined or split in
+    HBM, forward or backward."""
+    features: int
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2)),
+            (x.shape[-1], 3, self.features), jnp.float32).astype(self.dtype)
+        x = x.astype(self.dtype)
+        return tuple(jnp.einsum("...e,ed->...d", x, kernel[:, i])
+                     for i in range(3))
+
+
+class ShortConvMixer(nn.Module):
+    """LFM2's gated short convolution, a layer's token mixer in place of
+    attention: ``[B ; C ; u] = W_in n``, ``m = causal depthwise conv(B * u)``
+    over ``conv_width`` positions with no bias and no activation, ``out =
+    W_out (C * m)``.  The pass between the projections is the scope ``mix``,
+    which needs no collective under ``tp``: ``in_proj`` cuts ``B``, ``C`` and
+    ``u`` each by channel (``ThreeWayDense``) and so is the depthwise kernel
+    cut."""
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        E = x.shape[-1]
+        # (by channel, whatever the layers around cut: a routed layer's
+        # tokens are cut along the sequence over tp)
+        b, c, u = (constrain_residual(part, channels="tp") for part in
+                   ThreeWayDense(E, cfg.dtype, name="in_proj")(x))
+        kernel = self.param("conv_kernel", _conv_init(cfg.conv_width),
+                            (cfg.conv_width, E))
+        with jax.named_scope("mix"):
+            y = gated_short_conv(b, c, u, kernel.astype(cfg.dtype))
+        return nn.Dense(E, use_bias=False, dtype=cfg.dtype,
+                        name="out_proj")(y)
+
+
 class SwiGLU(nn.Module):
     config: LlamaConfig
 
@@ -482,7 +542,7 @@ ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention")
 class LlamaBlock(nn.Module):
     config: LlamaConfig
     routed: bool = False    # this layer's feed-forward: routed experts
-    # this layer's token mixer: "mamba", or attention of a kind
+    # this layer's token mixer: "mamba", "conv", or attention of a kind
     mixer: str = "attention"
     n_head: int = 0         # this layer's query heads; 0: config.n_head
 
@@ -499,6 +559,8 @@ class LlamaBlock(nn.Module):
                        name="attn_norm")(x)
         if self.mixer == "mamba":
             x = add(x, Mamba2Mixer(cfg, name="mamba")(y))
+        elif self.mixer == "conv":
+            x = add(x, ShortConvMixer(cfg, name="conv")(y))
         elif self.mixer in ATTENTION_KINDS:
             attn = LatentAttention(cfg, self.mixer, name="attn") \
                 if cfg.kv_lora_rank else LlamaAttention(
@@ -506,7 +568,7 @@ class LlamaBlock(nn.Module):
             x = add(x, attn(y, positions))
         else:
             raise ValueError(f"unknown layer type {self.mixer!r} (expected "
-                             f"'mamba' or one of {ATTENTION_KINDS})")
+                             f"'mamba', 'conv' or one of {ATTENTION_KINDS})")
         y = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
                        name="mlp_norm")(x)
         if self.routed:
@@ -516,7 +578,9 @@ class LlamaBlock(nn.Module):
                 norm_topk_prob=cfg.norm_topk_prob, dtype=cfg.dtype,
                 experts_held=cfg.experts_held, scoring=cfg.router_scoring,
                 routed_scale=cfg.routed_scale,
-                d_shared=cfg.d_shared_expert), name="moe")(y))
+                d_shared=cfg.d_shared_expert,
+                selection_bias=cfg.router_selection_bias,
+                norm_topk_eps=cfg.norm_topk_eps), name="moe")(y))
         return add(x, SwiGLU(cfg, name="mlp")(y))
 
 

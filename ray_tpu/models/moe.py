@@ -58,7 +58,14 @@ The scores may be sigmoids in place of the softmax (``scoring``), the chosen
 weights scaled (``routed_scale``, after the renormalisation), and a shared
 expert added that every token passes: a SwiGLU of width ``d_shared``, the
 child ``shared`` under its own scope, whole on every chip whatever part of
-the routed experts the layer holds.
+the routed experts the layer holds.  ``selection_bias`` gives each expert a
+float32 bias that is added to its score where the ``top_k`` are chosen and
+nowhere else: the weights come from the scores without it (DeepSeek-V3's
+auxiliary-loss-free selection; LFM2's ``use_expert_bias``).  It is state, not
+a weight: no gradient reaches it, the train step hands it on as it came
+(``models/pretrain.py``), and what would move it by the experts' load is a
+job's rule that this layer does not have.  ``norm_topk_eps`` is added to the
+sum the chosen scores are divided by.
 
 ``MoEMlpBlock`` — the older GShard / Switch form, wired into GPT-2 only
 (``GPT2Config.moe_every``): top-k routing as DENSE dispatch / combine einsums
@@ -211,6 +218,10 @@ class RoutedConfig:
     scoring: str = "softmax"
     routed_scale: float = 1.0       # x the chosen weights, once normalised
     d_shared: int = 0               # the shared expert's width; 0: none
+    # a bias an expert (``selection_bias``, float32, zeros) added to the
+    # scores for the choice of the ``top_k`` alone: state, not a weight
+    selection_bias: bool = False
+    norm_topk_eps: float = 0.0      # + the chosen scores' sum it divides by
 
 
 def _gmm_tiling(m: int, k: int, n: int):
@@ -749,9 +760,20 @@ balance), ``moe_z`` (mean of ``logsumexp(router logits) ** 2``) and
             else:
                 raise ValueError(f"unknown scoring {cfg.scoring!r} (expected "
                                  "'softmax' or 'sigmoid')")
-            weights, idx = jax.lax.top_k(probs, k)
+            if cfg.selection_bias:
+                # chosen by score + bias, weighted by the score itself
+                bias = self.param("selection_bias", nn.initializers.zeros,
+                                  (n_experts,), jnp.float32)
+                _, idx = jax.lax.top_k(
+                    probs + jax.lax.stop_gradient(bias), k)
+                weights = jnp.take_along_axis(probs, idx, axis=-1)
+            else:
+                weights, idx = jax.lax.top_k(probs, k)
             if cfg.norm_topk_prob:
-                weights = weights / jnp.sum(weights, axis=-1, keepdims=True)
+                total = jnp.sum(weights, axis=-1, keepdims=True)
+                if cfg.norm_topk_eps:
+                    total = total + cfg.norm_topk_eps
+                weights = weights / total
             if cfg.routed_scale != 1.0:
                 weights = weights * cfg.routed_scale
             counts = jnp.sum(idx[..., None] == jnp.arange(n_experts),

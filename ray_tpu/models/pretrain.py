@@ -145,6 +145,16 @@ def objective_fn(model, params, batch, key=None):
     return loss + aux, (loss, stats)
 
 
+def _hold_state(new, old):
+    """``new`` with every leaf that is state and not a weight as ``old`` has
+    it: a routed layer's ``selection_bias`` (models/moe.py), which no gradient
+    reaches and which weight decay must not shrink either.  A tree without
+    one comes back as it is."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, n, o: o if getattr(path[-1], "key", None)
+        == "selection_bias" else n, new, old)
+
+
 def train_step(model, tx, state, batch):
     """state = (params, opt_state). One fused fwd+bwd+update ->
     (state, the cross entropy, the step's MoE statistics: ``{}`` for a dense
@@ -161,7 +171,7 @@ def train_step(model, tx, state, batch):
         lambda p: objective_fn(model, p, batch), has_aux=True)(params)
     with jax.named_scope("optimizer"):
         updates, opt_state = tx.update(grads, opt_state, params)
-        params = optax.apply_updates(params, updates)
+        params = _hold_state(optax.apply_updates(params, updates), params)
     return (params, opt_state), loss, stats
 
 

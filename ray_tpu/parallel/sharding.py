@@ -85,6 +85,8 @@ def llama_partition_rules() -> PartitionRules:
         # router replicated; expert arrays (E, ., .) over ep, then as the
         # dense MLP's
         (r"moe/router/kernel", _spec()),
+        # the bias an expert of the selection: state, 'n_experts' wide
+        (r"moe/selection_bias", _spec()),
         # the expert every token passes, as the dense MLP
         (r"moe/shared/(gate_proj|up_proj)/kernel", _spec("fsdp", "tp")),
         (r"moe/shared/down_proj/kernel", _spec("tp", "fsdp")),
@@ -96,6 +98,13 @@ def llama_partition_rules() -> PartitionRules:
         (r"mamba/in_proj/kernel", _spec("fsdp", "tp")),
         (r"mamba/out_proj/kernel", _spec("tp", "fsdp")),
         (r"mamba/(conv_kernel|conv_bias|dt_bias|A_log|D)$", _spec()),
+        # short-convolution layers (models/llama.py::ShortConvMixer as
+        # h_<n>/conv): in_proj's kernel is (embed, 3, embed), its three parts
+        # B, C and u each cut by channel, and so is the depthwise kernel: the
+        # pass between the projections needs no collective under tp
+        (r"conv/in_proj/kernel", _spec("fsdp", None, "tp")),
+        (r"conv/out_proj/kernel", _spec("tp", "fsdp")),
+        (r"conv/conv_kernel", _spec(None, "tp")),
         # attn_norm, mlp_norm, norm_f, the q_norm / k_norm scales and the
         # mixer's norm_scale
         (r"norm|scale", _spec()),
@@ -160,10 +169,12 @@ def shard_pytree(params, specs, mesh):
         lambda x, s: host_to_global(x, NamedSharding(mesh, s)), params, specs)
 
 
-def constrain_residual(x):
+def constrain_residual(x, channels=None):
     """Pin a (batch, seq, embed) activation to the batch's own layout,
     ``P(("dp", "fsdp"), "sp", None)``, under the ambient mesh
-    (``jax.set_mesh``); identity without one.
+    (``jax.set_mesh``); identity without one.  ``channels``: the mesh axis
+    the last dimension is cut over, where the mesh has it (``"tp"`` for what
+    lies between a column- and a row-parallel projection).
 
     The model stacks call this on the residual stream.  Without it GSPMD
     follows the fsdp-sharded *weights* and replicates the batch's compute
@@ -180,4 +191,6 @@ def constrain_residual(x):
         return x
     batch = tuple(a for a in ("dp", "fsdp") if a in mesh.shape) or None
     seq = "sp" if "sp" in mesh.shape else None
-    return jax.lax.with_sharding_constraint(x, _spec(batch, seq, None))
+    if channels not in mesh.shape:
+        channels = None
+    return jax.lax.with_sharding_constraint(x, _spec(batch, seq, channels))

@@ -33,7 +33,14 @@ each: 16 heads whose scores are 192 wide over values 128 wide with the rotary
 shared one of 2816, 20,480 vocabulary rows, one row of 16,384, remat): the
 flash kernels with their two widths and the shared key part at 1024-wide
 tiles, the backward's float32 dQ of 16,384 x 192 in VMEM, beside 10.7 GB of
-state.  And the attention prelude alone (projection, heads, the per-head norm
+state.  And the one-chip step of LFM2-24B-A2B as one chip of eight holds it
+(2048 wide, a conv + dense layer, an attention + sparse layer and three conv +
+sparse ones: the gated short convolution 3 wide over 2 x 16,384 x 2,048
+channels in plain XLA, 32 / 8 heads 64 wide with the per-head norm and RoPE, 8
+of 64 experts of 1536 held, chosen through a selection bias, 8,192 vocabulary
+rows tied, two rows of 16,384, remat): the flash kernels at 64 lanes under
+RoPE and the grouped matmuls at the 1536-wide shape, beside 7.5 GB of state.
+And the attention prelude alone (projection, heads, the per-head norm
 where there is one, the rotation, and their backward) at SDAR's and at
 Laguna's full layers' shapes: the bytes the compiled program moves over the
 projection and the transpose are where float32 copies of q would show.
@@ -129,6 +136,15 @@ def _build(case: str, compile_: bool) -> dict:
                 config.qk_rope_head_dim, config.v_head_dim) == (
                     512, 128, 64, 128)
         assert config.n_layer == 6 and seq == 16384
+    elif case == "lfm2":
+        mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+        (config, seq), rows = _cell("lfm2-s16k-1chip"), 2
+        assert config.experts_held == (0, 8) and config.n_experts == 64
+        assert config.layer_types == ("conv", "full_attention") \
+            + ("conv",) * 3
+        assert (config.head_dim, config.qk_norm, config.conv_width) == (
+            64, "head", 3)
+        assert config.router_selection_bias and seq == 16384
     elif case == "granite":
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
         (config, seq), rows = _cell("granite-h-s8k-1chip"), 1
@@ -476,6 +492,36 @@ def test_kimi_vl_step_compiles_and_fits_the_chip():
     # held experts: twelve grouped-matmul calls and the two that add rows
     # into tokens, as Laguna's: 6 x 2 + 5 x (12 + 2)
     assert row["tpu_custom_calls"] == 6 * 2 + 5 * (12 + 2), row
+    assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
+
+
+def test_lfm2_step_lowers_for_one_v5e_chip():
+    """Tier-1: the one-chip step of LFM2-24B-A2B at published widths (a conv
+    + dense layer, an attention + sparse layer and three conv + sparse ones,
+    8 of 64 experts held, two rows of 16,384) lowers for the TPU with its
+    Mosaic kernels in it: the flash kernels of the one attention layer, the
+    grouped matmuls of the held experts and the sum of their rows into the
+    tokens, and no other — the short convolution is plain XLA."""
+    row = _child(["lfm2"], compile_=False)["lfm2"]
+    kernels = row["lowered_kernels"]
+    assert kernels.pop("kernel") > 0
+    assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens"}, kernels
+    assert row["flash_fwd_calls"] == 1, row
+
+
+@pytest.mark.slow
+def test_lfm2_step_compiles_and_fits_the_chip():
+    """The TPU compiler takes the step — the flash kernels 64 lanes wide
+    under the norm-and-rotate pass, the grouped matmuls over 1536-wide
+    experts under a contraction of 2048 — and its memory analysis says the
+    five layers fit one chip at two rows of 16,384 (PR 41: see PERF.md)."""
+    row = _child(["lfm2"], compile_=True)["lfm2"]
+    print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
+    assert "refused" not in row, row
+    # the attention layer: flash forward and the backward's one kernel; a
+    # sparse layer's held experts: twelve grouped-matmul calls and the two
+    # that add rows into tokens, as Kimi's: 2 + 4 x (12 + 2)
+    assert row["tpu_custom_calls"] == 2 + 4 * (12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
