@@ -28,7 +28,8 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.ops.attention import FLASH_RESIDUALS, NEG_INF, attention
+from ray_tpu.ops.attention import (FLASH_RESIDUALS, NEG_INF, HeadColumns,
+                                   attention)
 from ray_tpu.parallel.sharding import constrain_residual
 
 VOCAB_ALIGN = 128  # one lane tile; also divisible by every tp size in use
@@ -95,17 +96,15 @@ class Attention(nn.Module):
     @nn.compact
     def __call__(self, x, *, deterministic: bool = True):
         cfg = self.config
-        B, S, E = x.shape
+        E = x.shape[-1]
         H = cfg.n_head
         D = E // H
         qkv = nn.Dense(3 * E, dtype=cfg.dtype, name="qkv_proj")(x)
-        q, k, v = jnp.split(qkv, 3, axis=-1)
-        q = q.reshape(B, S, H, D).transpose(0, 2, 1, 3)
-        k = k.reshape(B, S, H, D).transpose(0, 2, 1, 3)
-        v = v.reshape(B, S, H, D).transpose(0, 2, 1, 3)
+        # the kernels read q, k and v where the projection wrote them, a
+        # third of its columns each, and write what out_proj takes
+        q, k, v = (HeadColumns(qkv, H, D, first=i * E) for i in range(3))
         out = attention(q, k, v, impl=cfg.attention_impl,
                         ring_axis=cfg.ring_axis)
-        out = out.transpose(0, 2, 1, 3).reshape(B, S, E)
         return nn.Dense(E, dtype=cfg.dtype, name="out_proj")(out)
 
 

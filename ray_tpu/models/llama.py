@@ -86,7 +86,7 @@ from ray_tpu.models.gpt2 import (mask_vocab_padding, padded_vocab,
 from ray_tpu.models.mamba import (Mamba2Mixer, _conv_init,
                                   gated_short_conv)
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
-from ray_tpu.ops.attention import attention
+from ray_tpu.ops.attention import HeadColumns, attention
 from ray_tpu.parallel.sharding import constrain_residual
 
 
@@ -352,14 +352,30 @@ class HeadNormScale(nn.Module):
         return self.param("scale", nn.initializers.ones, (width,), jnp.float32)
 
 
-def _attend(cfg: LlamaConfig, kind: str, q, k, v, k_shared=None):
+@jax.checkpoint
+def _gated(out, gate):
+    """(B, S, H * D) x (B, S, H): each head's columns by its scalar.  The
+    gate is widened along the lanes by a matmul with a 0 / 1 matrix (exact:
+    one term a column) whose epilogue takes the product, and made again for
+    the backward rather than kept: as a ``repeat`` XLA wrote the wide gate
+    out and read it back, both ways."""
+    h, c = gate.shape[-1], out.shape[-1]
+    of_head = jnp.arange(c)[None, :] // (c // h) == jnp.arange(h)[:, None]
+    return out * (gate @ of_head.astype(out.dtype))
+
+
+def _attend(cfg: LlamaConfig, kind: str, q, k, v, k_shared=None,
+            head_dim=None):
     """A layer's one call into ``ops.attention``: what the configuration and
     the layer's kind say of the mask — a window for a sliding layer, under
     block diffusion (x is [noised ; clean]) the block mask in place of the
     causal one.  Which implementation takes it, and whether one does, is
-    ``attention``'s to say."""
+    ``attention``'s to say.  Operands of rank 4 are (B, H, S, D), of rank 3
+    (B, S, H * D) as their projection wrote them; the result is
+    (B, S, H * Dv), as the output projection takes it."""
     return attention(
         q, k, v, impl=cfg.attention_impl, k_shared=k_shared,
+        head_dim=head_dim,
         window=cfg.sliding_window if kind == "sliding_attention" else 0,
         diffusion_block=cfg.diffusion_block
         if cfg.objective == "block_diffusion" else 0,
@@ -402,8 +418,8 @@ class LlamaAttention(nn.Module):
             # applied to each head in the rotation's own pass, below
             q_scale = HeadNormScale(name="q_norm")(D)
             k_scale = HeadNormScale(name="k_norm")(D)
-        q, k, v = heads(q), heads(k), heads(v)
         if table is not None or cfg.qk_norm == "head":
+            q, k = heads(q), heads(k)   # the pass below is a head's
             with jax.named_scope("rope"):
                 # NoPE: tables of no width turn nothing
                 cos, sin = rope_table(D, positions, table) if table \
@@ -412,18 +428,19 @@ class LlamaAttention(nn.Module):
                 k = apply_rope(k, cos, sin, k_scale, cfg.rms_eps)
         if KV != H:  # GQA: each kv head serves H/KV query heads
             rep = H // KV
+            k, v = (a if a.ndim == 4 else heads(a) for a in (k, v))
             with jax.named_scope("kv_repeat"):
                 k = jnp.repeat(k, rep, axis=1)
                 v = jnp.repeat(v, rep, axis=1)
-        out = _attend(cfg, self.kind, q, k, v)
-        out = out.transpose(0, 2, 1, 3)
+        # what came straight from its projection goes to the kernels as it
+        # lies, (B, S, H * D), and so does the result to ``wo``
+        out = _attend(cfg, self.kind, q, k, v, head_dim=D)
         if cfg.attn_gate:
             # one scalar a head a token, from the layer's normed input
             gate = nn.Dense(H, use_bias=False, dtype=cfg.dtype, name="wg")(x)
             with jax.named_scope("gate"):
-                out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
-                    out.dtype)[..., None]
-        out = out.reshape(B, S, H * D)
+                out = _gated(out, jax.nn.sigmoid(
+                    gate.astype(jnp.float32)).astype(out.dtype))
         return nn.Dense(E, use_bias=False, dtype=cfg.dtype, name="wo")(out)
 
 
@@ -460,8 +477,12 @@ class LatentAttention(nn.Module):
         down = dense(rank + dr, "wdkv")(x)
         latent = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
                             name="kv_norm")(down[..., :rank])
-        kv = heads(dense(H * (dn + dv), "wukv")(latent))
-        k, v, kr = kv[..., :dn], kv[..., dn:], down[:, None, :, rank:]
+        # each head's key part and values, read by the kernels where wukv
+        # wrote them: [kn ; v] a head, side by side
+        kv = dense(H * (dn + dv), "wukv")(latent)
+        k = HeadColumns(kv, H, dn, first=0, stride=dn + dv)
+        v = HeadColumns(kv, H, dv, first=dn, stride=dn + dv)
+        kr = down[:, None, :, rank:]
         with jax.named_scope("rope"):
             cos, sin = rope_table(dr, positions, RopeTable(theta=cfg.rope_theta))
             # the 64 rotary lanes turned as a head of their own and joined to
@@ -473,7 +494,6 @@ class LatentAttention(nn.Module):
         # the scope tells these calls from another kind's in a trace
         with jax.named_scope("mla"):
             out = _attend(cfg, self.kind, q, k, v, kr)
-        out = out.transpose(0, 2, 1, 3).reshape(B, S, H * dv)
         return dense(E, "wo")(out)
 
 

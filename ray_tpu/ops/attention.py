@@ -4,7 +4,7 @@ Greenfield relative to the reference — it has no sequence parallelism anywhere
 (SURVEY §5.7; no ring/blockwise attention hits in the reference tree).  Design:
 
 - ``flash_attention``: online-softmax blockwise attention as two Pallas
-  (Mosaic) kernels, one forward and one backward: grids over (batch*heads,
+  (Mosaic) kernels, one forward and one backward: grids over (batch, heads,
   q blocks, k blocks) — the backward's with the q axis innermost — K / V (or
   Q / dO) streamed from HBM tile by tile through their BlockSpecs, the
   running (m, l, acc) of the flash recurrence in VMEM scratch.  Operands go
@@ -20,6 +20,17 @@ Greenfield relative to the reference — it has no sequence parallelism anywhere
   rotates KV around the ring with ``jax.lax.ppermute`` (ICI neighbor traffic),
   merging partial results with the online-softmax combine.  Causal masking uses
   global offsets so the math matches unsharded attention exactly.
+- An operand has one of two ranks, and the rank says where its heads are.
+  Rank 4 is ``(B, H, S, D)``.  Rank 3 is ``(B, S, H * D)``, the heads side by
+  side in the columns as the projection that made it wrote them (or, a
+  ``HeadColumns``, some columns of a wider array: GPT-2's q, k, v in the one
+  output of ``qkv_proj``): the kernels' BlockSpecs address a head as a
+  128-lane column block of it — two heads to a block at width 64, the pair
+  run in one grid step — and write the output, and the backward the
+  gradients, the same way, so nothing is transposed or copied between a
+  matmul and a kernel (``_Tiles``).  The logsumexp and ``delta`` stay a row
+  a head.  A rank-3 operand whose head width is no lane tile or half of one
+  is turned head-major at the entry, as the models used to do for all.
 - The values may be another width than the scores, and the last dimensions
   of every head's key may be one vector a position that all heads share
   (``flash_attention``'s ``k_shared``: latent attention's rotary key part):
@@ -29,8 +40,9 @@ Greenfield relative to the reference — it has no sequence parallelism anywhere
   or joined in HBM.  With one width and no shared part the traced calls are
   what they were.
 - Under an ambient mesh (``jax.set_mesh``) ``flash_attention`` runs the kernel
-  inside a ``shard_map`` (batch over dp/fsdp, heads over tp): Mosaic kernels
-  cannot be partitioned by GSPMD, so each device must see a whole local call.
+  inside a ``shard_map`` (batch over dp/fsdp, heads over tp — a rank-3
+  operand's columns by whole heads): Mosaic kernels cannot be partitioned by
+  GSPMD, so each device must see a whole local call.
 - The kernel compiles for the TPU unless the process ASKED for the Pallas
   interpreter (``RAY_TPU_PALLAS_INTERPRET=1``, set by the CPU test substrate in
   ``_private/platform.py``); it never falls into interpret mode on its own.
@@ -135,19 +147,20 @@ def mha_reference(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = N
 # stay in the inputs' dtype, every dot accumulates in float32, and sm_scale
 # meets S after its dot (and dQ / dK once, as the accumulator is written out).
 #
-# - ``flash_fwd``: grid (b*h, q blocks, k blocks).  Works on S
+# - ``flash_fwd``: grid (b, h, q blocks, k blocks).  Works on S
 #   (block_q, block_k); the running max, the running sum and the output
 #   accumulator of the online softmax live in VMEM scratch, the statistics as
 #   lane-replicated columns; on the last k block the output is normalised and
 #   the logsumexp written as a (1, block_q) row, the layout the backward reads.
-# - ``flash_bwd``: grid (b*h, k blocks, q blocks).  Recomputes
+# - ``flash_bwd``: grid (b, h, k blocks, q blocks).  Recomputes
 #   P = exp(S - lse) on the transposed tile S^T (block_k, block_q), so
 #   dV += P^T dO and dK += dS^T Q (dS = P * (dP - delta)) are plain matmuls and
 #   lse / delta are (1, block_q) rows that broadcast down sublanes; the same
 #   dS^T, contracted over its keys, gives dQ[q tile] += dS K: five matmuls and
 #   one mask / exp / dS pass a tile.  dK / dV reduce over the inner axis; dQ
 #   reduces over the outer one, into a float32 accumulator that holds one
-#   b*h's whole sequence and is written out once, on that b*h's last step.
+#   head's whole sequence and is written out once, on that head's last step.
+# (``h`` counts grid steps: a head, or a pair of 64-wide heads: ``_Tiles``.)
 #
 # The forward's last grid axis is its reduction ("arbitrary"): the accumulators
 # are reset on its first step and written out on its last, and K / V arrive
@@ -200,8 +213,43 @@ def _block(s: int, d: int, dtype, block: Optional[int] = None) -> int:
     return LANES
 
 
+class _Cols(NamedTuple):
+    """Where a token-major operand ``(B, S, C)`` keeps its heads, as the
+    ``Dense`` that wrote it left them: head ``h`` is the ``width`` columns
+    from ``first + h * stride``."""
+    width: int
+    first: int
+    stride: int
+
+    def fits(self, group: int) -> bool:
+        """Whether a block of ``group`` heads is whole lane tiles that an
+        index map can name: Mosaic wants a block's last dimension in 128s (or
+        the array's own), and a block index counts in whole blocks."""
+        lanes = group * self.width
+        return lanes % LANES == 0 and self.first % lanes == 0 \
+            and (group * self.stride) % lanes == 0 \
+            and (group == 1 or self.stride == self.width)
+
+
 class _Tiles(NamedTuple):
-    """How one call's score square is cut: shared by both kernels."""
+    """How one call's score square is cut, and how its operands are addressed:
+    shared by both kernels.
+
+    The grid is (batch, head group, tiles, tiles), and an operand is one of
+    two things, told by its rank (``_Layout``).  Rank 4, ``(B, H, S, D)``: a
+    block is a group's heads, each its own ``(tile, D)``.  Rank 3,
+    ``(B, S, C)`` with the heads side by side in the columns as a projection
+    writes them (``_Cols``): a block is ``(tile, lanes)``, the group's
+    columns, and nothing is transposed between the matmul and the kernel.
+    That takes whole 128-lane tiles: a head width in 128s is a block a head
+    (``group`` 1: the bodies see what a rank-4 operand gives them), and
+    heads 64 wide go two to a block (``group`` 2: the bodies run the pair in
+    one grid step, each head on its own 64 lanes of the block, and every
+    rank-4 operand of the call hands over two heads a step as well).  An odd
+    number of heads at width 64, any other width (latent attention's 192-wide
+    queries, the tests' 16 and 32) and a 64-wide head under the block mask
+    keep the rank-4 path: ``flash_attention`` turns such an operand
+    head-major first, as the models used to."""
     causal: bool
     offset: int      # q_offset - k_offset: query r sees key c iff r + offset >= c
     block_q: int
@@ -238,12 +286,14 @@ class _Tiles(NamedTuple):
     window: int = 0
     steps_k: int = 0    # the inner axis with the keys inner (window only)
     steps_q: int = 0    # ... and with the queries inner
+    group: int = 1      # heads a grid step
 
     @classmethod
     def of(cls, s_q, s_k, d, dtype, causal, offset, diag_chunk,
            block_q=None, block_k=None, bd=0, window=0):
         """``s_q``: the queries' length; under ``bd`` one half's, which is
-        ``s_k``."""
+        ``s_k``.  (One head a grid step: a caller with pairs replaces
+        ``group``.)"""
         if window:
             assert causal and not bd and window > 0, (causal, bd, window)
             edge = _round_up(window, LANES)
@@ -358,67 +408,177 @@ class _Tiles(NamedTuple):
         half = lax.div(iq, jnp.int32(self.nq // 2))
         return half, iq - half * (self.nq // 2)
 
-    def specs(self, d: int, q_is_inner: bool):
-        """BlockSpecs of a (b*h, s_q, d) operand, a (b*h, 1, s_q) row of
-        per-query statistics and a (b*h, s_k, d) operand, following
-        ``tile_of``.  Under ``bd`` the last is (b*h, 2, s_k, d), the two
-        copies: with the queries inner, both copies' tile ik as one block;
-        with the keys inner, the clean tile of the walk, and a fourth spec,
-        the noised tile at the query tile's own index, which stays where it
-        is while the clean copy's queries run and so is fetched once a noised
+    def blocks(self, dims, width: int, cols: Optional[_Cols], index):
+        """BlockSpec of the grid step's heads' block of an operand ``width``
+        lanes a head: ``dims`` the block between the heads and the lanes (a
+        tile of the sequence, behind the copies' axis where there is one)
+        and ``index(i, j)`` where it is.  ``cols`` None: of a
+        ``(b, h / group, [group,] ..., width)`` array, rank 4 as the caller
+        sees it; else of a ``(b, ..., C)`` one."""
+        g = self.group
+        if cols is None:
+            lead, zero = ((None, None, g), (0,)) if g > 1 \
+                else ((None, None), ())
+            return pl.BlockSpec(
+                lead + dims + (width,),
+                lambda b, h, i, j: (b, h) + zero + index(i, j) + (0,))
+        lanes = g * width
+        first, step = cols.first // lanes, g * cols.stride // lanes
+        return pl.BlockSpec(
+            (None,) + dims + (lanes,),
+            lambda b, h, i, j: (b,) + index(i, j) + (first + h * step,))
+
+    def q_spec(self, width: int, cols, q_is_inner: bool):
+        """Of a ``(.., s_q, .)`` operand, following ``tile_of``."""
+        return self.blocks(
+            (self.block_q,), width, cols,
+            lambda i, j: (self.tile_of(i, j, q_is_inner)[0],))
+
+    def row_spec(self, q_is_inner: bool):
+        """Of the ``(b, h / group, group, s_q)`` rows of a per-query
+        statistic: a row a head, whatever the operands' ranks."""
+        return pl.BlockSpec(
+            (None, None, self.group, self.block_q),
+            lambda b, h, i, j: (b, h, 0, self.tile_of(i, j, q_is_inner)[0]))
+
+    def k_specs(self, width: int, cols, q_is_inner: bool):
+        """Of a ``(.., s_k, .)`` operand, following ``tile_of``, as a list.
+        Under ``bd`` the operand is ``(.., 2, s_k, .)``, the two copies:
+        with the queries inner, both copies' tile ik as one block; with the
+        keys inner, the clean tile of the walk, and a second spec, the
+        noised tile at the query tile's own index, which stays where it is
+        while the clean copy's queries run and so is fetched once a noised
         query tile."""
         def at(i, j):
-            return self.tile_of(i, j, q_is_inner)
-        q_spec = pl.BlockSpec((None, self.block_q, d),
-                              lambda bh, i, j: (bh, at(i, j)[0], 0))
-        row_spec = pl.BlockSpec((None, 1, self.block_q),
-                                lambda bh, i, j: (bh, 0, at(i, j)[0]))
+            return self.tile_of(i, j, q_is_inner)[1]
         if not self.bd:
-            return (q_spec, row_spec,
-                    pl.BlockSpec((None, self.block_k, d),
-                                 lambda bh, i, j: (bh, at(i, j)[1], 0)))
+            return [self.blocks((self.block_k,), width, cols,
+                                 lambda i, j: (at(i, j),))]
         if q_is_inner:
-            return (q_spec, row_spec,
-                    pl.BlockSpec((None, 2, self.block_k, d),
-                                 lambda bh, i, j: (bh, 0, i, 0)))
-        return (q_spec, row_spec,
-                pl.BlockSpec((None, None, self.block_k, d),
-                             lambda bh, i, j: (bh, 1, at(i, j)[1], 0)),
-                pl.BlockSpec((None, None, self.block_k, d),
-                             lambda bh, i, j: (
-                                 bh, 0, jnp.minimum(i, self.nk - 1), 0)))
+            return [self.blocks((2, self.block_k), width, cols,
+                                 lambda i, j: (0, i))]
+        return [self.blocks((None, self.block_k), width, cols,
+                             lambda i, j: (1, at(i, j))),
+                self.blocks((None, self.block_k), width, cols,
+                             lambda i, j: (0, jnp.minimum(i, self.nk - 1)))]
 
-    def shared_spec(self, d: int, heads: int, q_is_inner: bool):
-        """BlockSpec of a (b, s_k, d) operand that the ``heads`` heads of a
-        batch row share, following ``tile_of`` as a (b*h, s_k, d) one does."""
+    def shared_spec(self, width: int, q_is_inner: bool):
+        """Of a (b, s_k, width) operand that the heads of a batch row share,
+        following ``tile_of``."""
         return pl.BlockSpec(
-            (None, self.block_k, d), lambda bh, i, j: (
-                lax.div(bh, jnp.int32(heads)),
-                self.tile_of(i, j, q_is_inner)[1], 0))
+            (None, self.block_k, width), lambda b, h, i, j: (
+                b, self.tile_of(i, j, q_is_inner)[1], 0))
+
+    def array(self, b: int, h: int, mid, width: int, tokens: bool):
+        """The shape of an operand that ``blocks`` addresses, ``mid`` its
+        dimensions between the heads and the lanes."""
+        if tokens:
+            return (b, *mid, h * width)
+        return (b, h // self.group, *self.per_head(*mid, width))
+
+    def per_head(self, *dims):
+        """``dims`` for each of a grid step's heads: the shape of a rank-4
+        operand's block, and of an accumulator in VMEM."""
+        return ((self.group,) if self.group > 1 else ()) + dims
 
 
-def _rows(x, s_pad: int):
-    """(b, h, s, d) -> (b*h, s_pad, d), zero padded up to whole blocks: a
-    padded key is masked by the real length, a padded query is dropped (the
-    forward) or has p == 0 through its lse (the backward)."""
+class _Layout(NamedTuple):
+    """A call's operands, statically: its heads, and where a rank-3 operand
+    keeps them (``_Cols``; None: the operand is rank 4, ``(B, H, S, D)``).
+    ``out`` stands for dO as well, and q, k, v for their gradients, which
+    the backward writes with the heads side by side from column 0."""
+    heads: int
+    q: Optional[_Cols] = None
+    k: Optional[_Cols] = None
+    v: Optional[_Cols] = None
+    out: Optional[_Cols] = None
+
+    @property
+    def group(self) -> int:
+        """Heads a grid step: two where a rank-3 operand's heads are half a
+        lane tile (``_Tiles``)."""
+        return 2 if any(c is not None and c.width % LANES
+                        for c in self[1:]) else 1
+
+
+def _seen(x, cols: Optional[_Cols]):
+    """(length, head width) of an operand of either rank."""
+    return (x.shape[2], x.shape[3]) if cols is None \
+        else (x.shape[1], cols.width)
+
+
+def _dense(width: int) -> _Cols:
+    """The heads side by side from column 0."""
+    return _Cols(width, 0, width)
+
+
+def _take(x, cols: _Cols, heads: int):
+    """(B, S, C) -> (B, S, heads * width): the columns ``cols`` names, as an
+    array of their own."""
+    if cols == _dense(cols.width) and x.shape[-1] == heads * cols.width:
+        return x
+    within = cols.first % cols.stride
+    x = lax.slice_in_dim(x, cols.first - within,
+                         cols.first - within + heads * cols.stride, axis=2)
+    x = x.reshape(*x.shape[:2], heads, cols.stride)
+    return lax.slice_in_dim(x, within, within + cols.width, axis=3).reshape(
+        *x.shape[:2], heads * cols.width)
+
+
+def _to_heads(x, cols: Optional[_Cols], heads: int):
+    """An operand of either rank as (B, H, S, D)."""
+    if cols is None:
+        return x
+    x = _take(x, cols, heads)
+    return x.reshape(*x.shape[:2], heads, cols.width).transpose(0, 2, 1, 3)
+
+
+def _to_tokens(x):
+    """(B, H, S, D) -> (B, S, H * D)."""
     b, h, s, d = x.shape
-    return jnp.pad(x.reshape(b * h, s, d), ((0, 0), (0, s_pad - s), (0, 0)))
+    return x.transpose(0, 2, 1, 3).reshape(b, s, h * d)
 
 
-def _halves(x, s_pad: int, fill=0.0):
-    """(b, h, 2 l, d), two copies of ``l`` positions -> (b*h, s_pad, d),
-    each copy padded with ``fill`` up to ``s_pad / 2``, whole blocks, so that
-    a tile belongs to one copy."""
-    b, h, s, d = x.shape
-    x = jnp.pad(x.reshape(b * h, 2, s // 2, d),
-                ((0, 0), (0, 0), (0, (s_pad - s) // 2), (0, 0)),
-                constant_values=fill)
-    return x.reshape(b * h, s_pad, d)
+def _pad(x, axis: int, to: int, fill=0.0):
+    widths = [(0, 0)] * x.ndim
+    widths[axis] = (0, to - x.shape[axis])
+    return jnp.pad(x, widths, constant_values=fill)
 
 
-def _copies(x, l_pad: int):
-    """``_halves`` with the copies apart: (b*h, 2, l_pad, d)."""
-    return _halves(x, 2 * l_pad).reshape(-1, 2, l_pad, x.shape[-1])
+def _rows(x, s_pad: int, tokens: bool = False, group: int = 1):
+    """(b, h, s, d) -> (b, h / group, [group,] s_pad, d), or a token-major
+    (b, s, c) -> (b, s_pad, c), zero padded up to whole blocks: a padded key
+    is masked by the real length, a padded query is dropped (the forward) or
+    has p == 0 through its lse (the backward)."""
+    if tokens:
+        return _pad(x, 1, s_pad)
+    x = _pad(x, 2, s_pad)
+    return x if group == 1 else x.reshape(
+        x.shape[0], x.shape[1] // group, group, *x.shape[2:])
+
+
+def _halves(x, s_pad: int, tokens: bool = False, fill=0.0):
+    """(b, h, 2 l, d) or (b, 2 l, c), two copies of ``l`` positions ->
+    the same with ``s_pad`` positions, each copy padded with ``fill`` up to
+    ``s_pad / 2``, whole blocks, so that a tile belongs to one copy."""
+    return _merged(_copies(x, s_pad // 2, tokens, fill), tokens)
+
+
+def _copies(x, l_pad: int, tokens: bool = False, fill=0.0):
+    """``_halves`` with the copies apart: (b, h, 2, l_pad, d) or
+    (b, 2, l_pad, c)."""
+    axis = 1 if tokens else 2
+    shape = x.shape
+    x = x.reshape(*shape[:axis], 2, shape[axis] // 2, *shape[axis + 1:])
+    return _pad(x, axis + 1, l_pad, fill)
+
+
+def _merged(x, tokens: bool, heads: int = 0):
+    """A block-addressed array as (b, s, c), or as (b, heads, s, d): the
+    group's axis back into the heads, the copies' into the sequence."""
+    if tokens:
+        return x.reshape(x.shape[0], -1, x.shape[-1])
+    return x.reshape(x.shape[0], heads or x.shape[1], -1, x.shape[-1])
 
 
 def _unhalved(x, l: int, axis: int):
@@ -579,25 +739,66 @@ def _across(col, n: int):
 
 
 # -------------------------------------------------------------- forward
+class _Head:
+    """One head's part of a pair's block, indexed as a (rows, lanes) ref of
+    its own by ``[rows, lanes]`` (slices) or ``[...]``: block ``lead`` of a
+    (2, rows, width) block, or the ``width`` lanes from lane ``first`` of a
+    (rows, 2 * width) one.  Every access is one index into the block itself:
+    Mosaic loads and stores at a lane offset, but cuts no ref inside a lane
+    tile."""
+
+    def __init__(self, ref, lead: tuple, first: int, width: int):
+        self.ref, self.lead, self.first = ref, lead, first
+        self.shape, self.dtype = (ref.shape[-2], width), ref.dtype
+
+    def _at(self, index):
+        rows, lanes = (slice(None),) * 2 if index is Ellipsis else index
+        start, stop, _ = lanes.indices(self.shape[-1])
+        return (*self.lead, rows, slice(self.first + start, self.first + stop))
+
+    def __getitem__(self, index):
+        return self.ref[self._at(index)]
+
+    def __setitem__(self, index, value):
+        self.ref[self._at(index)] = value
+
+
+def _per_head(ref, group: int, tokens: bool):
+    """A grid step's block as one ref a head: the block itself at one head a
+    step, else the pair's two, a rank-4 operand's by its leading axis and a
+    rank-3 operand's by their 64 lanes of the block."""
+    if group == 1:
+        return [ref]
+    width = ref.shape[-1] // group if tokens else ref.shape[-1]
+    return [_Head(ref, (), p * width, width) if tokens
+            else _Head(ref, (p,), 0, width) for p in range(group)]
+
+
 def _scores(q_ref, qs, k_ref, ks, ks_ref):
     """Unscaled scores (queries, keys) of queries ``qs`` against keys ``ks``;
     ``ks_ref``: the keys' shared part (``flash_attention``'s ``k_shared``),
     which the queries' last dimensions meet in a contraction of their own."""
     if ks_ref is None:
         return _dot(q_ref[qs, :], k_ref[ks, :], _NT)
-    d = k_ref.shape[1]
+    d = k_ref.shape[-1]
     return lax.add(_dot(q_ref[qs, :d], k_ref[ks, :], _NT),
                    _dot(q_ref[qs, d:], ks_ref[ks, :], _NT))
 
 
-def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles):
+def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles,
+                      tokens):
     # under ``t.bd`` two more inputs: the noised K and V tile of the query
-    # tile's own positions; without it, at most one: the keys' shared part
+    # tile's own positions; without it, at most one: the keys' shared part.
+    # ``tokens``: which of q, k, v and the output are rank 3 (``_per_head``)
     *own_refs, o_ref, lse_ref, m_col, l_col, acc = rest
-    row, step = pl.program_id(1), pl.program_id(2)
+    row, step = pl.program_id(2), pl.program_id(3)
     iq, ik = t.walk(row, step, False)
-    d = v_ref.shape[1]
     ks_ref = own_refs[0] if own_refs and not t.bd else None
+    heads = list(zip(*(
+        _per_head(ref, t.group, rank3) for ref, rank3 in zip(
+            (q_ref, k_ref, v_ref, o_ref, m_col, l_col, acc),
+            (*tokens, False, False, False)))))
+    d = heads[0][2].shape[-1]
 
     from_nothing = step == 0
     if t.bd:
@@ -629,93 +830,109 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles):
         acc[...] = jnp.zeros_like(acc)
 
     def part(qs, ks, thresh, k_limit, below=None):
-        v = v_ref[ks, :]
-        s = _masked(lax.mul(_scores(q_ref, qs, k_ref, ks, ks_ref), sm_scale),
-                    0, thresh, k_limit, t.bd, below)
-        lanes = (s.shape[0], LANES)
-        m_old = m_col[qs, :]
-        m_new = lax.max(m_old, jnp.broadcast_to(
-            jnp.max(s, axis=1, keepdims=True), lanes))
-        m_col[qs, :] = m_new
-        alpha = lax.exp(lax.sub(m_old, m_new))
-        if not (thresh is None and k_limit is None and below is None):
-            # A row with every key masked so far has m == NEG_INF, and
-            # exp(s - m) would be 1 per column: take its exp against 0.
-            m_new = lax.select(lax.gt(m_new, NEG_INF / 2), m_new,
-                               lax.full_like(m_new, 0.0))
-        p = lax.exp(lax.sub(s, _across(m_new, s.shape[1])))
-        l_col[qs, :] = lax.add(lax.mul(alpha, l_col[qs, :]), jnp.broadcast_to(
-            jnp.sum(p, axis=1, keepdims=True), lanes))
-        acc[qs, :] = lax.add(lax.mul(_across(alpha, d), acc[qs, :]),
-                             _dot(p.astype(v.dtype), v, _NN))
+        for q_ref, k_ref, v_ref, _, m_col, l_col, acc in heads:
+            v = v_ref[ks, :]
+            s = _masked(lax.mul(_scores(q_ref, qs, k_ref, ks, ks_ref),
+                                sm_scale), 0, thresh, k_limit, t.bd, below)
+            lanes = (s.shape[0], LANES)
+            m_old = m_col[qs, :]
+            m_new = lax.max(m_old, jnp.broadcast_to(
+                jnp.max(s, axis=1, keepdims=True), lanes))
+            m_col[qs, :] = m_new
+            alpha = lax.exp(lax.sub(m_old, m_new))
+            if not (thresh is None and k_limit is None and below is None):
+                # A row with every key masked so far has m == NEG_INF, and
+                # exp(s - m) would be 1 per column: take its exp against 0.
+                m_new = lax.select(lax.gt(m_new, NEG_INF / 2), m_new,
+                                   lax.full_like(m_new, 0.0))
+            p = lax.exp(lax.sub(s, _across(m_new, s.shape[1])))
+            l_col[qs, :] = lax.add(
+                lax.mul(alpha, l_col[qs, :]),
+                jnp.broadcast_to(jnp.sum(p, axis=1, keepdims=True), lanes))
+            acc[qs, :] = lax.add(lax.mul(_across(alpha, d), acc[qs, :]),
+                                 _dot(p.astype(v.dtype), v, _NN))
 
     _on_tiles(t, iq, ik, part)
 
     @pl.when(step == t.steps(False) - 1)
     def _():
-        l = l_col[...]
-        empty = lax.eq(l, 0.0)  # no key seen: output 0, lse NEG_INF
-        l = lax.select(empty, lax.full_like(l, 1.0), l)
-        o_ref[...] = lax.div(acc[...], _across(l, d)).astype(o_ref.dtype)
-        lse = lax.select(empty, lax.full_like(l, NEG_INF),
-                         lax.add(m_col[...], lax.log(l)))
-        # (block_q, LANES) column, every lane the same -> (1, block_q) row
-        lse_ref[...] = lse.T[:1, :]
+        for p, (_, _, _, o_ref, m_col, l_col, acc) in enumerate(heads):
+            l = l_col[...]
+            empty = lax.eq(l, 0.0)  # no key seen: output 0, lse NEG_INF
+            l = lax.select(empty, lax.full_like(l, 1.0), l)
+            o_ref[...] = lax.div(acc[...], _across(l, d)).astype(o_ref.dtype)
+            lse = lax.select(empty, lax.full_like(l, NEG_INF),
+                             lax.add(m_col[...], lax.log(l)))
+            # (block_q, LANES) column, every lane the same -> the head's
+            # (1, block_q) row
+            lse_ref[p:p + 1, :] = lse.T[:1, :]
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11),
+@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 13),
                    inline=True)
 def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
                    k_offset: int, block_q: Optional[int],
                    block_k: Optional[int], interpret: bool, bd: int = 0,
-                   window: int = 0, k_shared=None):
-    """``out`` (b, h, s_q, v's width) and the logsumexp of every query's
-    scaled scores as (b*h, 1, s_q) rows, NEG_INF where a query sees no key.
-    Under ``bd`` (see ``_Tiles``) q, k and v are the two copies of ``s_q / 2``
-    positions, and every query sees a key.  ``k_shared`` (b, 1, s_k, .): see
-    ``flash_attention``.
+                   window: int = 0, k_shared=None,
+                   lay: Optional[_Layout] = None):
+    """``out`` — (b, h, s_q, v's width), or (b, s_q, h * v's width) under
+    ``lay.out`` — and the logsumexp of every query's scaled scores as
+    (b*h, 1, s_q) rows, NEG_INF where a query sees no key.  ``lay``: the
+    operands' ranks (None: all of rank 4).  Under ``bd`` (see ``_Tiles``) q,
+    k and v are the two copies of ``s_q / 2`` positions, and every query sees
+    a key.  ``k_shared`` (b, 1, s_k, .): see ``flash_attention``.
 
     Jitted and inlined so that a model's layers, which call it with the same
     shapes, share one trace of the kernel: the equations land in the caller's
     jaxpr under the caller's scopes, as if written there."""
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, s_q, d = q.shape
-    d_v = v.shape[-1]
-    s_k = k.shape[2] // 2 if bd else k.shape[2]     # one copy's
+    lay = lay or _Layout(q.shape[1])
+    b, h, g = q.shape[0], lay.heads, lay.group
+    (s_q, d), (s_k, d_k), (_, d_v) = map(_seen, (q, k, v), lay[1:4])
+    s_k = s_k // 2 if bd else s_k       # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
                   q_offset - k_offset, _FWD_DIAG_CHUNK, block_q, block_k, bd,
-                  window)
+                  window)._replace(group=g)
     s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
-    q_spec, row_spec, _ = t.specs(d, q_is_inner=False)[:3]
-    k_spec = t.specs(k.shape[-1], q_is_inner=False)[2]
-    o_spec, _, v_spec, *own_spec = t.specs(d_v, q_is_inner=False)
-    q_rows, k_rows = (_halves, _copies) if bd else (_rows, _rows)
+    tokens = tuple(c is not None for c in lay[1:])
+    k_spec, *kn_spec = t.k_specs(d_k, lay.k, False)
+    v_spec, *vn_spec = t.k_specs(d_v, lay.v, False)
+    rows = functools.partial(_rows, group=g)
+    q_rows, k_rows = (_halves, _copies) if bd else (rows, rows)
     with jax.named_scope("flash_fwd"):
-        q, k, v = q_rows(q, s_q_pad), k_rows(k, s_k_pad), k_rows(v, s_k_pad)
-        own = (k, v) * len(own_spec)
-        own_spec = own_spec * 2
+        q = q_rows(q, s_q_pad, tokens[0])
+        k = k_rows(k, s_k_pad, tokens[1])
+        v = k_rows(v, s_k_pad, tokens[2])
+        own, own_spec = (k, v) * len(kn_spec), kn_spec + vn_spec
         if k_shared is not None:
-            own = (_rows(k_shared, s_k_pad),)
-            own_spec = [t.shared_spec(k_shared.shape[-1], h, False)]
+            own = (_pad(k_shared[:, 0], 1, s_k_pad),)
+            own_spec = [t.shared_spec(k_shared.shape[-1], False)]
+        def scratch(width):
+            return pltpu.VMEM(t.per_head(t.block_q, width), jnp.float32)
         out, lse = pl.pallas_call(
-            functools.partial(_flash_fwd_kernel, sm_scale=sm_scale, t=t),
-            grid=(b * h, t.nq, t.steps(False)),
-            in_specs=[q_spec, k_spec, v_spec] + own_spec,
-            out_specs=[o_spec, row_spec],
-            out_shape=[jax.ShapeDtypeStruct((b * h, s_q_pad, d_v), q.dtype),
-                       jax.ShapeDtypeStruct((b * h, 1, s_q_pad), jnp.float32)],
-            scratch_shapes=[pltpu.VMEM((t.block_q, LANES), jnp.float32)] * 2
-            + [pltpu.VMEM((t.block_q, d_v), jnp.float32)],
+            functools.partial(_flash_fwd_kernel, sm_scale=sm_scale, t=t,
+                              tokens=tokens),
+            grid=(b, h // g, t.nq, t.steps(False)),
+            in_specs=[t.q_spec(d, lay.q, False), k_spec, v_spec] + own_spec,
+            out_specs=[t.q_spec(d_v, lay.out, False), t.row_spec(False)],
+            out_shape=[
+                jax.ShapeDtypeStruct(
+                    t.array(b, h, (s_q_pad,), d_v, tokens[3]), q.dtype),
+                jax.ShapeDtypeStruct((b, h // g, g, s_q_pad), jnp.float32)],
+            scratch_shapes=[scratch(LANES), scratch(LANES), scratch(d_v)],
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary")),
+                dimension_semantics=("parallel", "parallel", "parallel",
+                                     "arbitrary")),
             interpret=interpret,
             name="flash_fwd",
         )(q, k, v, *own)
+    out, lse = _merged(out, tokens[3], h), lse.reshape(b * h, 1, s_q_pad)
     if bd:
-        return (_unhalved(out, s_k, 1).reshape(b, h, s_q, d),
+        return (_unhalved(out, s_k, 1 if tokens[3] else 2),
                 _unhalved(lse, s_k, 2))
-    return out[:, :s_q].reshape(b, h, s_q, d_v), lse[:, :, :s_q]
+    return (lax.slice_in_dim(out, 0, s_q, axis=1 if tokens[3] else 2),
+            lse[:, :, :s_q])
 
 
 # ------------------------------------------------------------- backward
@@ -736,25 +953,34 @@ def _bwd_vmem_bytes(s_q_pad: int, d: int, dtype) -> int:
 
 
 def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
-                      sm_scale: float, t: _Tiles):
+                      sm_scale: float, t: _Tiles, tokens):
     # with the keys' shared part (``flash_attention``'s ``k_shared``) one
     # more input, output and accumulator: that part, and its gradient from
-    # this b*h's queries
+    # this head's queries.  ``tokens``: which of q, dO, k and v are rank 3
+    # (``_per_head``); a gradient is laid out as what it is the gradient of
     ks_ref, dks_ref, dks_acc = None, None, None
     if len(rest) == 9:
         ks_ref, dq_ref, dk_ref, dv_ref, dks_ref, dq_acc, dk_acc, dv_acc, \
             dks_acc = rest
     else:
         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
-    ik, step = pl.program_id(1), pl.program_id(2)
+    ik, step = pl.program_id(2), pl.program_id(3)
     iq = t.walk(ik, step, True)[0]
-    last_k, last_step = pl.num_programs(1) - 1, pl.num_programs(2) - 1
+    last_k, last_step = pl.num_programs(2) - 1, pl.num_programs(3) - 1
     if t.bd:
         # both copies' tile ik: the clean one takes the walk and the
         # accumulators, the noised one the own squares of query tile ik
         (kn_ref, k_ref), (vn_ref, v_ref), (dkn_ref, dk_ref), \
             (dvn_ref, dv_ref) = ((r.at[0], r.at[1])
                                  for r in (k_ref, v_ref, dk_ref, dv_ref))
+    q_is, do_is, k_is, v_is = tokens
+    heads = list(zip(*(
+        _per_head(ref, t.group, rank3) for ref, rank3 in (
+            (q_ref, q_is), (do_ref, do_is), (k_ref, k_is), (v_ref, v_is),
+            (dq_ref, q_is), (dk_ref, k_is), (dv_ref, v_is),
+            (dq_acc, False), (dk_acc, False), (dv_acc, False))
+        + (((dks_ref, False), (dks_acc, False)) if ks_ref is not None
+           else ()))))
 
     @pl.when(jnp.logical_and(ik == 0, step == 0))
     def _():
@@ -768,40 +994,43 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
             dks_acc[...] = jnp.zeros_like(dks_acc)
 
     def part(qs, ks, thresh, k_limit, below=None, own=False):
-        kr, vr = (kn_ref, vn_ref) if own else (k_ref, v_ref)
-        q, do, k = q_ref[qs, :], do_ref[qs, :], kr[ks, :]
-        if ks_ref is None:
-            st = _dot(k, q, _NT)
-        else:
-            # the queries' last dimensions against the shared part
-            d = k.shape[1]
-            shared, q, q_shared = ks_ref[ks, :], q[:, :d], q[:, d:]
-            st = lax.add(_dot(k, q, _NT), _dot(shared, q_shared, _NT))
-        st, lse = lax.mul(st, sm_scale), lse_ref[:, qs]
-        st = _same_block(st, t.bd) if own \
-            else _masked(st, 1, thresh, k_limit, t.bd, below)
-        pt = lax.exp(lax.sub(st, lse))      # P^T, zero where masked
-        if own:     # nothing else reaches these keys: no sum over steps
-            dvn_ref[ks, :] = _dot(pt.astype(do.dtype), do, _NN
-                                  ).astype(dvn_ref.dtype)
-        else:
-            dv_acc[ks, :] += _dot(pt.astype(do.dtype), do, _NN)
-        dst = lax.mul(pt, lax.sub(_dot(vr[ks, :], do, _NT),
-                                  delta_ref[:, qs])).astype(q.dtype)
-        if own:
-            dkn_ref[ks, :] = (_dot(dst, q, _NN) * sm_scale
-                              ).astype(dkn_ref.dtype)
-        else:
-            dk_acc[ks, :] += _dot(dst, q, _NN)
-        # these queries' rows of the whole-sequence accumulator
-        start, stop, _ = qs.indices(t.block_q)
-        rows = pl.ds(pl.multiple_of(iq * t.block_q + start, LANES), stop - start)
-        if ks_ref is None:
-            dq_acc[rows, :] += _dot(dst, k, _TN)
-        else:
-            dks_acc[ks, :] += _dot(dst, q_shared, _NN)
-            dq_acc[rows, :d] += _dot(dst, k, _TN)
-            dq_acc[rows, d:] += _dot(dst, shared, _TN)
+        for p, (q_ref, do_ref, k_ref, v_ref, _, _, _, dq_acc, dk_acc, dv_acc,
+                *shared) in enumerate(heads):
+            kr, vr = (kn_ref, vn_ref) if own else (k_ref, v_ref)
+            q, do, k = q_ref[qs, :], do_ref[qs, :], kr[ks, :]
+            if ks_ref is None:
+                st = _dot(k, q, _NT)
+            else:
+                # the queries' last dimensions against the shared part
+                d = k.shape[1]
+                ks_part, q, q_shared = ks_ref[ks, :], q[:, :d], q[:, d:]
+                st = lax.add(_dot(k, q, _NT), _dot(ks_part, q_shared, _NT))
+            st, lse = lax.mul(st, sm_scale), lse_ref[p:p + 1, qs]
+            st = _same_block(st, t.bd) if own \
+                else _masked(st, 1, thresh, k_limit, t.bd, below)
+            pt = lax.exp(lax.sub(st, lse))      # P^T, zero where masked
+            if own:     # nothing else reaches these keys: no sum over steps
+                dvn_ref[ks, :] = _dot(pt.astype(do.dtype), do, _NN
+                                      ).astype(dvn_ref.dtype)
+            else:
+                dv_acc[ks, :] += _dot(pt.astype(do.dtype), do, _NN)
+            dst = lax.mul(pt, lax.sub(_dot(vr[ks, :], do, _NT),
+                                      delta_ref[p:p + 1, qs])).astype(q.dtype)
+            if own:
+                dkn_ref[ks, :] = (_dot(dst, q, _NN) * sm_scale
+                                  ).astype(dkn_ref.dtype)
+            else:
+                dk_acc[ks, :] += _dot(dst, q, _NN)
+            # these queries' rows of the whole-sequence accumulator
+            start, stop, _ = qs.indices(t.block_q)
+            rows = pl.ds(pl.multiple_of(iq * t.block_q + start, LANES),
+                         stop - start)
+            if ks_ref is None:
+                dq_acc[rows, :] += _dot(dst, k, _TN)
+            else:
+                shared[1][ks, :] += _dot(dst, q_shared, _NN)
+                dq_acc[rows, :d] += _dot(dst, k, _TN)
+                dq_acc[rows, d:] += _dot(dst, ks_part, _TN)
 
     if t.bd:
         @pl.when(iq == ik)
@@ -812,124 +1041,160 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
 
     @pl.when(step == last_step)
     def _():
-        dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
-        dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
-        if dks_ref is not None:
-            dks_ref[...] = (dks_acc[...] * sm_scale).astype(dks_ref.dtype)
+        for _, _, _, _, _, dk_ref, dv_ref, _, dk_acc, dv_acc, *shared in heads:
+            dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
+            dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
+            if shared:
+                shared[0][...] = (shared[1][...] * sm_scale
+                                  ).astype(shared[0].dtype)
 
     @pl.when(jnp.logical_and(ik == last_k, step == last_step))
     def _():
-        dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
+        for _, _, _, _, dq_ref, _, _, dq_acc, *_ in heads:
+            dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12),
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12, 14),
                    inline=True)
 def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
                     q_offset: int, k_offset: int, interpret: bool,
-                    bd: int = 0, window: int = 0, k_shared=None):
+                    bd: int = 0, window: int = 0, k_shared=None,
+                    lay: Optional[_Layout] = None):
     """dq, dk, dv of ``_flash_attention`` from its residuals (``lse`` as the
     forward leaves it: (b*h, 1, s_q) rows) and ``g``; with ``k_shared``, its
-    gradient too, summed over the heads.
+    gradient too, summed over the heads.  Each gradient has the rank of what
+    it is the gradient of; one of rank 3 is (b, s, h * width), the heads side
+    by side from column 0, whatever columns the operand itself was read at.
 
     Jitted and inlined for the reason ``_flash_forward`` is."""
     from jax.experimental.pallas import tpu as pltpu
 
-    b, h, s_q, d = q.shape
-    d_k, d_v = k.shape[-1], v.shape[-1]
-    s_k = k.shape[2] // 2 if bd else k.shape[2]     # one copy's
+    lay = lay or _Layout(q.shape[1])
+    b, h, n = q.shape[0], lay.heads, lay.group
+    (s_q, d), (s_k, d_k), (_, d_v) = map(_seen, (q, k, v), lay[1:4])
+    s_k = s_k // 2 if bd else s_k       # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
-                  q_offset - k_offset, _BWD_DIAG_CHUNK, bd=bd, window=window)
+                  q_offset - k_offset, _BWD_DIAG_CHUNK, bd=bd, window=window
+                  )._replace(group=n)
     s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
+    q_is, k_is, v_is, o_is = (c is not None for c in lay[1:])
 
     def row(x, fill):
+        """(b*h, 1, s_q) -> the kernel's (b, h / n, n, s_q_pad) rows."""
         if bd:      # a statistic a query, laid out as the queries are
-            return _halves(x.reshape(b, h, s_q, 1), s_q_pad,
-                           fill).reshape(b * h, 1, s_q_pad)
-        return jnp.pad(x, ((0, 0), (0, 0), (0, s_q_pad - s_q)),
-                       constant_values=fill)
+            x = _halves(x.reshape(b, h, s_q, 1), s_q_pad, fill=fill)
+        else:
+            x = _pad(x, 2, s_q_pad, fill)
+        return x.reshape(b, h // n, n, s_q_pad)
 
-    q_rows, k_rows = (_halves, _copies) if bd else (_rows, _rows)
-    delta = jnp.sum(out.astype(jnp.float32) * g.astype(jnp.float32), axis=-1)
+    rows = functools.partial(_rows, group=n)
+    q_rows, k_rows = (_halves, _copies) if bd else (rows, rows)
+    delta = out.astype(jnp.float32) * g.astype(jnp.float32)
+    if o_is:
+        # each head's columns summed on the MXU, by a matrix of ones where a
+        # column is the head's, at float32's precision: the product goes into
+        # the contraction as it is made, and the sums come out a row a head.
+        # (As a reshape to (b, s, h, d) and a sum XLA first writes the
+        # float32 product out in another tiling: 0.53 GB a layer for 0.08 at
+        # GPT-2's shape, 1.36 for 0.27 at 2 x 8192 x 32 x 128, compiled.)
+        of_head = (lax.broadcasted_iota(jnp.int32, (h * d_v, h), 0) // d_v
+                   == lax.broadcasted_iota(jnp.int32, (h * d_v, h), 1))
+        delta = jnp.einsum("bsc,ch->bhs", delta, of_head.astype(jnp.float32),
+                           precision=lax.Precision.HIGHEST)
+    else:
+        delta = jnp.sum(delta, axis=-1)
     # A row with an empty (fully masked) softmax has lse == NEG_INF, and
     # exp(s - lse) would blow up: such rows, like the padding rows, get an lse
     # under which every p is zero.
     lse = jnp.where(lse > NEG_INF / 2, lse, -NEG_INF)
-    q_spec, row_spec, _ = t.specs(d, q_is_inner=True)
-    k_spec = t.specs(d_k, q_is_inner=True)[2]
-    do_spec, _, v_spec = t.specs(d_v, q_is_inner=True)
+    (k_spec,), (v_spec,) = (t.k_specs(d_k, lay.k, True),
+                            t.k_specs(d_v, lay.v, True))
+    row_spec = t.row_spec(True)
 
-    def dk_spec(spec, d):
-        # under ``bd`` both copies' tile, as the keys come: ``spec`` ignores j
-        return spec if bd else pl.BlockSpec(
-            (None, t.block_k, d), lambda bh, i, j: (bh, i, 0))
+    def dk_of(width, cols):
+        """(spec, shape) of a key-side gradient: its tile, written once the
+        inner axis is through; under ``bd`` both copies' tile, as the keys
+        come."""
+        cols = cols and _dense(width)
+        mid = (2, s_k_pad) if bd else (s_k_pad,)
+        return (t.blocks((2, t.block_k) if bd else (t.block_k,), width, cols,
+                          lambda i, j: (0, i) if bd else (i,)),
+                jax.ShapeDtypeStruct(
+                    t.array(b, h, mid, width, cols is not None), q.dtype))
 
-    def dk_shape(d):
-        return jax.ShapeDtypeStruct((b * h, 2, s_k_pad, d) if bd
-                                    else (b * h, s_k_pad, d), q.dtype)
+    def scratch(rows, width):
+        return pltpu.VMEM(t.per_head(rows, width), jnp.float32)
 
     # dQ sums over the k blocks, the outer axis: its block is the whole
-    # sequence of one b*h, written back once when the b*h is done.
-    dq_spec = pl.BlockSpec((None, s_q_pad, d), lambda bh, i, j: (bh, 0, 0))
-    in_specs = [q_spec, do_spec, row_spec, row_spec, k_spec, v_spec]
-    out_specs = [dq_spec, dk_spec(k_spec, d_k), dk_spec(v_spec, d_v)]
-    out_shape = [jax.ShapeDtypeStruct((b * h, s_q_pad, d), q.dtype),
-                 dk_shape(d_k), dk_shape(d_v)]
-    scratch = [pltpu.VMEM((s_q_pad, d), jnp.float32),
-               pltpu.VMEM((t.block_k, d_k), jnp.float32),
-               pltpu.VMEM((t.block_k, d_v), jnp.float32)]
-    operands = [q_rows(q, s_q_pad), q_rows(g, s_q_pad), row(lse, -NEG_INF),
-                row(delta.reshape(b * h, 1, s_q), 0.0),
-                k_rows(k, s_k_pad), k_rows(v, s_k_pad)]
+    # sequence of the step's heads, written back once when they are done.
+    dq_spec = t.blocks((s_q_pad,), d, lay.q and _dense(d), lambda i, j: (0,))
+    in_specs = [t.q_spec(d, lay.q, True), t.q_spec(d_v, lay.out, True),
+                row_spec, row_spec, k_spec, v_spec]
+    out_specs, out_shape = map(list, zip(
+        (dq_spec, jax.ShapeDtypeStruct(
+            t.array(b, h, (s_q_pad,), d, q_is), q.dtype)),
+        dk_of(d_k, lay.k), dk_of(d_v, lay.v)))
+    scratches = [scratch(s_q_pad, d), scratch(t.block_k, d_k),
+                 scratch(t.block_k, d_v)]
+    operands = [q_rows(q, s_q_pad, q_is), q_rows(g, s_q_pad, o_is),
+                row(lse, -NEG_INF), row(delta.reshape(b * h, 1, s_q), 0.0),
+                k_rows(k, s_k_pad, k_is), k_rows(v, s_k_pad, v_is)]
     if k_shared is not None:
-        # each b*h's own gradient of the shared part, summed over the heads
+        # each head's own gradient of the shared part, summed over the heads
         # beside the kernel
         d_s = k_shared.shape[-1]
-        in_specs.append(t.shared_spec(d_s, h, True))
-        out_specs.append(dk_spec(None, d_s))
-        out_shape.append(dk_shape(d_s))
-        scratch.append(pltpu.VMEM((t.block_k, d_s), jnp.float32))
-        operands.append(_rows(k_shared, s_k_pad))
+        in_specs.append(t.shared_spec(d_s, True))
+        spec, shape = dk_of(d_s, None)
+        out_specs.append(spec)
+        out_shape.append(shape)
+        scratches.append(scratch(t.block_k, d_s))
+        operands.append(_pad(k_shared[:, 0], 1, s_k_pad))
     dq, dk, dv, *dks = pl.pallas_call(
-        functools.partial(_flash_bwd_kernel, sm_scale=sm_scale, t=t),
-        grid=(b * h, t.nk, t.steps(True)),
+        functools.partial(_flash_bwd_kernel, sm_scale=sm_scale, t=t,
+                          tokens=(q_is, o_is, k_is, v_is)),
+        grid=(b, h // n, t.nk, t.steps(True)),
         in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
-        scratch_shapes=scratch,
+        scratch_shapes=scratches,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
-            vmem_limit_bytes=_bwd_vmem_bytes(s_q_pad, d, q.dtype)),
+            dimension_semantics=("parallel", "parallel", "arbitrary",
+                                 "arbitrary"),
+            vmem_limit_bytes=_bwd_vmem_bytes(n * s_q_pad, d, q.dtype)),
         interpret=interpret,
         name="flash_bwd",
     )(*operands)
-    if bd:
-        return tuple(
-            _unhalved(dx.reshape(b * h, s_q_pad, d), s_k, 1
-                      ).reshape(b, h, s_q, d).astype(x.dtype)
-            for dx, x in ((dq, q), (dk, k), (dv, v)))
-    grads = (dq[:, :s_q].reshape(b, h, s_q, d),
-             dk[:, :s_k].reshape(b, h, s_k, d_k).astype(k.dtype),
-             dv[:, :s_k].reshape(b, h, s_k, d_v).astype(v.dtype))
+
+    def back(dx, x, s, tokens):
+        dx = _merged(dx, tokens, h)
+        axis = 1 if tokens else 2
+        return (_unhalved(dx, s_k, axis) if bd
+                else lax.slice_in_dim(dx, 0, s, axis=axis)).astype(x.dtype)
+
+    grads = (back(dq, q, s_q, q_is), back(dk, k, s_k, k_is),
+             back(dv, v, s_k, v_is))
     if k_shared is None:
         return grads
     return grads + (jnp.sum(
-        dks[0][:, :s_k].reshape(b, h, s_k, -1), axis=1, keepdims=True,
+        _merged(dks[0], False, h)[:, :, :s_k], axis=1, keepdims=True,
         dtype=jnp.float32).astype(k_shared.dtype),)
 
 
 # ============================================================= public op
-def _named_forward(*args, **kwargs):
+def _named_forward(*args):
     """``_flash_forward``'s ``out`` and ``lse`` under ``FLASH_RESIDUALS``."""
-    return tuple(map(checkpoint_name, _flash_forward(*args, **kwargs),
+    return tuple(map(checkpoint_name, _flash_forward(*args),
                      FLASH_RESIDUALS))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(4, 12)))
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(4, 13)))
 def _flash_attention(q, k, v, k_shared=None, causal=True, sm_scale=1.0,
                      q_offset=0, k_offset=0, block_q=None, block_k=None,
-                     window=0, bd=0):
+                     window=0, bd=0, lay=None):
     """The flash pair under its one differentiation rule, for every mask and
-    layout the kernels take.  ``k_shared``: the last dimensions of every
-    head's key held once a position (``flash_attention``), or None — an empty
-    pytree, whose cotangent is None.  ``bd`` > 0, attention under
+    layout the kernels take.  ``lay``: the operands' ranks and the output's
+    (``_Layout``, from ``flash_attention``).  ``k_shared``: the last
+    dimensions of every head's key held once a position
+    (``flash_attention``), or None — an empty pytree, whose cotangent is
+    None.  ``bd`` > 0, attention under
     ``block_diffusion_mask``: q, k, v (b, h, 2 l, d), the noised copy of ``l``
     positions and then the clean one, in blocks of ``bd``.  One kernel call,
     one online softmax over every live pair: both copies' queries over the
@@ -938,43 +1203,115 @@ def _flash_attention(q, k, v, k_shared=None, causal=True, sm_scale=1.0,
     scores: 0.1% of the pairs at l 4096 and bd 4) as one more step of a
     noised tile (``_Tiles``)."""
     return _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, q_offset,
-                           k_offset, block_q, block_k, window, bd)[0]
+                           k_offset, block_q, block_k, window, bd, lay)[0]
 
 
 def _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, q_offset, k_offset,
-                    block_q, block_k, window, bd):
+                    block_q, block_k, window, bd, lay):
     out, lse = _named_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
                               block_q, block_k, _interpret(), bd, window,
-                              k_shared)
+                              k_shared, lay)
     return out, (q, k, v, k_shared, out, lse)
 
 
 def _flash_bwd_rule(causal, sm_scale, q_offset, k_offset, block_q, block_k,
-                    window, bd, residuals, g):
+                    window, bd, lay, residuals, g):
     q, k, v, k_shared, out, lse = residuals
     with jax.named_scope("flash_bwd"):
-        grads = _flash_backward(q, k, v, out, lse, g, causal, sm_scale,
-                                q_offset, k_offset, _interpret(), bd, window,
-                                k_shared)
-    return grads if k_shared is not None else (*grads, None)
+        dq, dk, dv, *dks = _flash_backward(
+            q, k, v, out, lse, g, causal, sm_scale, q_offset, k_offset,
+            _interpret(), bd, window, k_shared, lay)
+        # a rank-3 operand read at some columns of its array: the gradient
+        # is that array's, zero elsewhere (three operands of one array add up
+        # to its whole gradient in one pass)
+        dq, dk, dv = (
+            dx if cols is None else jax.linear_transpose(
+                functools.partial(_take, cols=cols, heads=lay.heads), x)(dx)[0]
+            for dx, x, cols in zip((dq, dk, dv), (q, k, v), lay[1:4]))
+    return dq, dk, dv, (dks[0] if dks else None)
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
 
 
-def _bhsd_spec(mesh, batch_axes, head_axis, seq_axis=None):
-    """PartitionSpec of a (B, H, S, D) tensor over whichever of the named
+class HeadColumns(NamedTuple):
+    """A rank-3 operand of ``attention`` whose heads are some columns of a
+    wider array, read where they lie: ``heads`` heads ``width`` wide, head
+    ``h`` the columns of ``x`` (B, S, C) from ``first + h * stride``
+    (``stride`` 0: ``width``, the heads side by side).  GPT-2's q, k and v
+    are the three thirds of ``qkv_proj``'s output, latent attention's keys
+    and values the two halves of each head's columns of ``wukv``'s."""
+    x: jax.Array
+    heads: int
+    width: int
+    first: int = 0
+    stride: int = 0
+
+
+def _operand(x, heads: int):
+    """An operand as ``attention`` takes it -> (array, its ``_Cols`` or
+    None)."""
+    if isinstance(x, HeadColumns):
+        cols = _Cols(x.width, x.first, x.stride or x.width)
+        within = cols.first % cols.stride
+        if x.heads != heads or within + cols.width > cols.stride or \
+                cols.first - within + heads * cols.stride > x.x.shape[-1]:
+            raise ValueError(f"{heads} heads against {x._replace(x=None)} "
+                             f"of {x.x.shape}")
+        return x.x, cols
+    if x.ndim == 4:
+        return x, None
+    if x.shape[-1] % heads:
+        raise ValueError(f"{heads} heads in {x.shape[-1]} columns")
+    return x, _dense(x.shape[-1] // heads)
+
+
+def _heads_in(q, head_dim: Optional[int]) -> int:
+    if isinstance(q, HeadColumns):
+        return q.heads
+    if q.ndim == 4:
+        return q.shape[1]
+    if not head_dim:
+        raise ValueError("a rank-3 (B, S, H * D) query comes with head_dim")
+    return q.shape[-1] // head_dim
+
+
+def _settled(lay: _Layout, bd: int) -> _Layout:
+    """``lay`` with the rank-3 operands left that the kernels can address as
+    they lie (``_Tiles``), the others None: those go head-major."""
+    def kept(group):
+        return lay._replace(**{
+            name: cols if cols is not None and cols.fits(group) else None
+            for name, cols in zip(lay._fields[1:], lay[1:])})
+    if not (bd or lay.heads % 2) and kept(2).group == 2:
+        return kept(2)
+    return kept(1)
+
+
+def _bhsd_spec(mesh, batch_axes, head_axis, seq_axis=None, tokens=False):
+    """PartitionSpec of a (B, H, S, D) tensor — or, ``tokens``, a (B, S,
+    H * D) one, its columns cut by whole heads — over whichever of the named
     axes ``mesh`` has."""
-    return P(tuple(a for a in batch_axes if a in mesh.shape) or None,
-             head_axis if head_axis in mesh.shape else None,
-             seq_axis, None)
+    batch = tuple(a for a in batch_axes if a in mesh.shape) or None
+    head = head_axis if head_axis in mesh.shape else None
+    return P(batch, seq_axis, head) if tokens \
+        else P(batch, head, seq_axis, None)
 
 
 def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = None,
                     q_offset: int = 0, k_offset: int = 0,
                     block_q: Optional[int] = None, block_k: Optional[int] = None,
-                    diffusion_block: int = 0, window: int = 0, k_shared=None):
-    """Blockwise (flash) attention. q,k,v: (B, H, S, D) -> (B, H, S, D).
+                    diffusion_block: int = 0, window: int = 0, k_shared=None,
+                    head_dim: Optional[int] = None,
+                    tokens_out: Optional[bool] = None):
+    """Blockwise (flash) attention.  Each of q, k, v is rank 4, (B, H, S, D),
+    or rank 3, (B, S, H * D) as a projection wrote it (or a ``HeadColumns``
+    of such an array); a rank-3 q comes with ``head_dim``.  The output has
+    q's rank, or ``tokens_out`` says: (B, S, H * Dv) if true, (B, H, S, Dv)
+    if not.  The kernels read a rank-3 operand and write a rank-3 output
+    where they lie, no transpose between, wherever its head width is whole
+    lane tiles or half of one (``_Tiles``); elsewhere the operand is turned
+    head-major here, and the output back.
 
     v may be another width than q and k (latent attention's 128 under scores
     192 wide): the output is v's, nothing is padded, and the accumulators
@@ -999,34 +1336,61 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     every device (sequence sharding is ring attention's job).  Without one,
     or on a one-device mesh, it is the plain call.
     """
+    heads = _heads_in(q, head_dim)
+    (q, k, v), cols = zip(*(_operand(x, heads) for x in (q, k, v)))
+    lay = _Layout(heads, *cols)
+    (_, d), (_, d_k), (_, d_v) = map(_seen, (q, k, v), cols)
+    if tokens_out is None:
+        tokens_out = lay.q is not None
     if sm_scale is None:
-        sm_scale = q.shape[-1] ** -0.5
+        sm_scale = d ** -0.5
     # These three guard the kernels against a direct caller (the tests are
     # one); ``attention`` calls through and lets them speak.
     if window and (diffusion_block or not causal):
         raise ValueError("a window belongs to the causal mask")
     shared = 0 if k_shared is None else k_shared.shape[-1]
-    if q.shape[-1] != k.shape[-1] + shared:
-        raise ValueError(f"queries {q.shape[-1]} wide against keys "
-                         f"{k.shape[-1]} + {shared}")
-    if diffusion_block and (shared or q.shape[-1] != v.shape[-1]):
+    if d != d_k + shared:
+        raise ValueError(f"queries {d} wide against keys {d_k} + {shared}")
+    if diffusion_block and (shared or d != d_v):
         raise NotImplementedError(
             "the block mask's kernels take one width for scores and values")
+    mesh = ambient_mesh()
+    if mesh is not None and mesh.size == 1:
+        mesh = None
+    tp = mesh.shape.get("tp", 1) if mesh is not None else 1
+    if tp > 1:
+        # a device's share of the columns is whole heads only where they lie
+        # side by side and alone: such an operand as an array of its own
+        q, k, v = (x if c is None else _take(x, c, heads)
+                   for x, c in zip((q, k, v), cols))
+        cols = tuple(c and _dense(c.width) for c in cols)
+        lay = _Layout(heads, *cols)
+    # what one device's call can address as it lies; the rest head-major
+    lay = _settled(lay._replace(heads=heads // tp,
+                                out=_dense(d_v) if tokens_out else None),
+                   int(diffusion_block))
+    q, k, v = (x if kept is not None else _to_heads(x, c, heads)
+               for x, c, kept in zip((q, k, v), cols, lay[1:4]))
     operands = (q, k, v) if k_shared is None else (q, k, v, k_shared)
     # (the block mask is its own: causal is not asked, the offsets not read)
     f = functools.partial(
         _flash_attention, causal=causal and not diffusion_block,
         sm_scale=float(sm_scale), q_offset=int(q_offset),
         k_offset=int(k_offset), block_q=block_q, block_k=block_k,
-        window=int(window), bd=int(diffusion_block))
-    mesh = ambient_mesh()
-    if mesh is None or mesh.size == 1:
-        return f(*operands)
-    spec = _bhsd_spec(mesh, ("dp", "fsdp"), "tp")
-    # (the shared part's one head: whole on every device of a tp group)
-    in_specs = (spec, spec, spec, _bhsd_spec(mesh, ("dp", "fsdp"), None))
-    return jax.shard_map(f, mesh=mesh, in_specs=in_specs[:len(operands)],
-                         out_specs=spec, check_vma=False)(*operands)
+        window=int(window), bd=int(diffusion_block), lay=lay)
+    if mesh is None:
+        out = f(*operands)
+    else:
+        # (the shared part's one head: whole on every device of a tp group)
+        in_specs = [_bhsd_spec(mesh, ("dp", "fsdp"), "tp",
+                               tokens=c is not None) for c in lay[1:4]] \
+            + [_bhsd_spec(mesh, ("dp", "fsdp"), None)]
+        out = jax.shard_map(
+            f, mesh=mesh, in_specs=tuple(in_specs[:len(operands)]),
+            out_specs=_bhsd_spec(mesh, ("dp", "fsdp"), "tp",
+                                 tokens=lay.out is not None),
+            check_vma=False)(*operands)
+    return _to_tokens(out) if tokens_out and lay.out is None else out
 
 
 # ======================================================== ring attention
@@ -1145,14 +1509,22 @@ _REFUSED = {
 
 def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
               diffusion_block: int = 0, sm_scale: Optional[float] = None,
-              k_shared=None, ring_axis: str = "sp"):
-    """What a model's attention layer calls: q, k, v (B, H, S, D) and the
-    mask's parameters as ``flash_attention`` and ``mha_reference`` take them
+              k_shared=None, head_dim: Optional[int] = None,
+              ring_axis: str = "sp"):
+    """What a model's attention layer calls.  Each of q, k, v is rank 4,
+    (B, H, S, D), or rank 3, (B, S, H * D) as its projection wrote it (or a
+    ``HeadColumns`` of a wider array); a rank-3 q comes with ``head_dim``.
+    The result is (B, S, H * Dv), what the output projection takes, whatever
+    the operands' ranks.  The mask's parameters are as ``flash_attention`` and
+    ``mha_reference`` take them
     (``diffusion_block`` > 0: ``block_diffusion_mask`` in place of the causal
     mask).  Which implementation runs is decided here and nowhere else:
     ``impl`` is a config's ``attention_impl`` — "reference", "ring" or "flash",
     and "flash" under an ambient mesh that shards the sequence
     (``ring_axis`` > 1) is the ring, since the kernels want the sequence whole.
+    The kernels read rank-3 operands and write the result where they lie
+    (``flash_attention``); "reference" and "ring" want (B, H, S, D) and get
+    it by a transpose here.
     What the implementation has nothing for is refused, here (``_REFUSED``)
     or by its own guard."""
     mesh = ambient_mesh()
@@ -1168,19 +1540,23 @@ def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
                 f"attention_impl={impl!r}" + (
                     f" (\"flash\" over a sequence sharded on {ring_axis!r})"
                     if sharded else "") + f" has no {words}")
+    if impl == "flash":
+        # the scope tells a window's calls from the full layers' in a trace
+        with jax.named_scope("window") if window else contextlib.nullcontext():
+            return flash_attention(
+                q, k, v, causal=causal, sm_scale=sm_scale,
+                diffusion_block=diffusion_block, window=window,
+                k_shared=k_shared, head_dim=head_dim, tokens_out=True)
+    heads = _heads_in(q, head_dim)
+    q, k, v = (_to_heads(*_operand(x, heads), heads) for x in (q, k, v))
     if impl == "reference":
         mask = block_diffusion_mask(q.shape[2] // 2, diffusion_block) \
             if diffusion_block else None
-        return mha_reference(q, k, v, causal=causal, sm_scale=sm_scale,
-                             mask=mask, window=window, k_shared=k_shared)
+        return _to_tokens(mha_reference(
+            q, k, v, causal=causal, sm_scale=sm_scale, mask=mask,
+            window=window, k_shared=k_shared))
     if impl == "ring":
-        return ring_attention_sharded(q, k, v, causal=causal,
-                                      sm_scale=sm_scale, seq_axis=ring_axis)
-    if impl != "flash":
-        raise ValueError(f"unknown attention_impl {impl!r} (expected "
-                         "'flash', 'ring' or 'reference')")
-    # the scope tells a window's calls from the full layers' in a trace
-    with jax.named_scope("window") if window else contextlib.nullcontext():
-        return flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                               diffusion_block=diffusion_block, window=window,
-                               k_shared=k_shared)
+        return _to_tokens(ring_attention_sharded(
+            q, k, v, causal=causal, sm_scale=sm_scale, seq_axis=ring_axis))
+    raise ValueError(f"unknown attention_impl {impl!r} (expected "
+                     "'flash', 'ring' or 'reference')")

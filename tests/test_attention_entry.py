@@ -96,8 +96,11 @@ def test_every_combination_is_taken_or_refused(impl, mask, shared, narrow):
     operands, g, plain = _case(mask, shared, narrow)
 
     def entry(q, k, v, k_shared=None):
-        return attention(q, k, v, k_shared=k_shared, **_asked(mask),
-                         impl="flash" if impl == "flash over sp" else impl)
+        # (B, S, H * Dv), whatever the operands' ranks: here as the heads
+        out = attention(q, k, v, k_shared=k_shared, **_asked(mask),
+                        impl="flash" if impl == "flash over sp" else impl)
+        assert out.shape == (1, q.shape[2], 2 * v.shape[-1])
+        return out.reshape(1, q.shape[2], 2, -1).transpose(0, 2, 1, 3)
 
     # "flash" where the ambient mesh shards the sequence is the ring
     mesh = Mesh(np.asarray(jax.devices()[:2 if ring else 1]), ("sp",))
@@ -112,8 +115,12 @@ def test_every_combination_is_taken_or_refused(impl, mask, shared, narrow):
             assert "ppermute" in traced and "pallas_call" not in traced
             return
         if impl == "reference":     # the very program, so its gradient too
+            def there_and_back(*operands):
+                b, h, s, d = (out := plain(*operands)).shape
+                return out.transpose(0, 2, 1, 3).reshape(b, s, h * d).reshape(
+                    b, s, h, d).transpose(0, 2, 1, 3)
             assert str(jax.make_jaxpr(entry)(*operands)) \
-                == str(jax.make_jaxpr(plain)(*operands))
+                == str(jax.make_jaxpr(there_and_back)(*operands))
             return
         out, grads = _both(entry, operands, g)
     want, want_grads = _want(mask, shared, narrow)

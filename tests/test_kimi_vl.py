@@ -287,7 +287,9 @@ def test_d_lagunas_step_is_the_parents(flash_names_off):
     train step on the parent commit (PR 36), kernel bodies included (and,
     since PR 38, ``flash_names_off``; since PR 39 the hash is that PR's: the
     rotation of q and k is ``apply_rope``'s one pass, the kernels' calls as
-    they were)."""
+    they were; since PR 42 that PR's: the kernels' grid is (batch, heads,
+    tiles, tiles) and their output (B, S, H * D), the gate widened along
+    the lanes)."""
     from ray_tpu.models.pretrain import make_optimizer, sharded_train_step
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
@@ -300,7 +302,7 @@ def test_d_lagunas_step_is_the_parents(flash_names_off):
     with jax.set_mesh(mesh):
         text = s.step.trace(s.state, batch).lower().as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        "d568d1dee700081fc74714c35bff30d4540609325925531fa23ec0a46dbf3189"
+        "7678e709a2b001223ac057c92958b0fe2c106ba3690ad555174f4e909d9db439"
 
 
 # ------------------------------------------------- (e) on a virtual mesh

@@ -161,12 +161,14 @@ def test_c_the_shares_add_up_to_the_uncut_layer():
 # hash from the assertion below and pins that.  (``toy-llama``, ``toy-olmoe``
 # and ``toy-sdar`` are PR 39's: the rotation of q and k is one pass with a
 # matmul by a signed permutation in it, SDAR's per-head norm inside it;
-# ``toy-granite``, which rotates nothing, is PR 34's still.)
+# ``toy-granite``, which rotates nothing, was PR 34's until PR 42.  All four
+# are PR 42's: the flash kernels' grid is (batch, heads, tiles, tiles), the
+# output leaves them as (B, S, H * D) and the models no longer transpose it.)
 _PARENT_STEPS = {
-    "toy-llama": "b8dbc8021fbd7af294719bf12a00ca4fe10923109267e12b7a8884fbb98d1eca",
-    "toy-olmoe": "9ee0789b91e05c95f4e8c555538c548c4b5b72309abaa69c20a7f686e80fc6d3",
-    "toy-granite": "019b08487290338c25579cc673790d46ed096067b1707c60d926f24ad284739b",
-    "toy-sdar": "3aedbb0be6aee9cc1357da348c77f5dce55c5b9f477820bdc7a9b2fb82d0add3",
+    "toy-llama": "114e35a37f363ab0f8c29d7948dfae1ee6cc766feb0bba3985842656d0b70ce6",
+    "toy-olmoe": "41054e8f510af2a7d7be4f320326eb4237e4c1efc006d16bd81fbd3d9bea858f",
+    "toy-granite": "19944bd72f16b5fc7376d8c3862d78ce6964804d07bc2db5d9e8406bcad24ac0",
+    "toy-sdar": "222b0bf1f1a77b9f7b59a06a8e8731dd8c05cc2521c078cde46cd291fa36d872",
 }
 
 

@@ -431,7 +431,8 @@ def test_f_kimi_vls_step_is_the_parents(flash_names_off):
     parent commit (PR 40), kernel bodies included.  Its routed layers run
     ``RoutedSwiGLU`` with sigmoid scores and ``norm_topk_prob``, so the
     selection's and the renormalisation's new branches are what it holds
-    still."""
+    still.  (Since PR 42 the hash is that PR's: the kernels read each head's
+    key part and values where ``wukv`` wrote them and write (B, S, H * Dv).)"""
     from ray_tpu.models.pretrain import make_optimizer, sharded_train_step
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
@@ -444,4 +445,4 @@ def test_f_kimi_vls_step_is_the_parents(flash_names_off):
     with jax.set_mesh(mesh):
         text = s.step.trace(s.state, batch).lower().as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        "f8f2c81c096005591bcc3cfb336f82cfde7d48b765a88b60ba340b3fcb099a37"
+        "c815cb0c9ef10968bb14cc90d0ea62fb2394397fdfca6f6d91348983453b2e60"

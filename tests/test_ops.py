@@ -234,7 +234,8 @@ class TestFlashAttention:
         dots = [e for e, kernel in eqns
                 if kernel is not None and e.primitive.name == "dot_general"]
         assert [c.params["name"] for c in calls] == ["flash_fwd"]
-        assert tuple(calls[0].params["grid_mapping"].grid) == (2, 2, 2)
+        # (batch, heads, q blocks, k blocks)
+        assert tuple(calls[0].params["grid_mapping"].grid) == (1, 2, 2, 2)
         assert len(dots) >= 2
         for eqn in dots:
             assert [x.aval.dtype for x in eqn.invars] == [jnp.bfloat16] * 2
@@ -274,7 +275,7 @@ class TestFlashAttention:
         calls = [e for e, _ in eqns if e.primitive.name == "pallas_call"
                  and e.params["name"].startswith("flash_bwd")]
         assert [c.params["name"] for c in calls] == ["flash_bwd"]
-        assert tuple(calls[0].params["grid_mapping"].grid) == (2, 2, 2)
+        assert tuple(calls[0].params["grid_mapping"].grid) == (1, 2, 2, 2)
         # no mask, no padding: every tile takes the one bare body
         body = [e for e, kernel in eqns if kernel is calls[0]]
         dots = [e for e in body if e.primitive.name == "dot_general"]

@@ -89,17 +89,20 @@ def test_c_the_kernels_block_mask_is_the_written_out_mask(length, block):
 # sha256 of str(jax.make_jaxpr(...)) of the causal call and of its vjp at
 # (1, 2, 1152, 64) bf16 (256-tiles, the last one padded, the diagonal chunk
 # by chunk), and of the unmasked call at (1, 2, 200, 64) float32 (padding
-# keys), at the parent commit (7ea4048), kernel bodies included (under a JAX
-# that prints a jaxpr otherwise, take them again there)
+# keys), kernel bodies included (under a JAX that prints a jaxpr otherwise,
+# take them again there).  PR 42's: the grid is (batch, heads, tiles, tiles)
+# and the operands are addressed by batch and head, where it was (batch *
+# heads, tiles, tiles) at the commit these stood for before (7ea4048); the
+# bodies of a rank-4 call are what they were
 _CAUSAL_AS_IT_WAS = {
     ("causal", "fwd"):
-        "8f8a7618d0a5448c90482230bae4a51691715a7ebad9340cffac823f4ec2fbb1",
+        "a7f6506ccfe8ddccbeddc13d306ca9587480ed4084edfc045a6ffb1f2a7bca1f",
     ("causal", "vjp"):
-        "75113af9f5f4c4fc122ca69a61c2a578185b611b864b4cb2d2720b4abd4f699a",
+        "522e26a9d8f7d46b498b60780f1592ab0aae5137df802edf0b48f12946bfaabd",
     ("full", "fwd"):
-        "329822db3c2b5c40a921bb7a32492b033cd414c6f8a46114852be8296fb55f7d",
+        "9b67f0097cd93e0a93492621680edc864200dd16549b2577d9fac60c97afc4c6",
     ("full", "vjp"):
-        "6dd08463bb813cbf6939539aafbbd233d905fa7bcbe98a41cb649a523abeb021",
+        "41f21073c429b9bf002ab85ab77d39ac581e6eb79979dc29fcb94f36e752b265",
 }
 
 
@@ -121,7 +124,7 @@ def test_n_the_block_mask_attention_is_the_kernel_alone(which, request):
     of the logsumexp: no softmax arithmetic, no matmul and no copy of the
     output (the noised blocks' own squares were a plain term merged by
     logsumexp until PR 34).  And the calls without the block mask trace to
-    the parent's jaxpr, character for character."""
+    the pinned jaxpr, character for character."""
     def traced(f, x):
         if which == "fwd":
             return jax.make_jaxpr(f)(x, x, x)
