@@ -16,8 +16,8 @@ query and key projections before the heads are split and rotated
 own pass (``apply_rope``); ``head_dim`` is
 a size of its own where it is not ``d_model / n_head``.  A layer's token mixer is a kind
 too: ``layer_types`` names each layer ``"attention"`` (``attn``) or
-``"mamba"`` (``mamba``: the Mamba-2 mixer of ``models/mamba.py`` over the
-chunked scan of ``ops/ssd.py``); attention may go without RoPE (``rope``)
+``"mamba"`` (``mamba``: ``models/mamba.py``'s Mamba-2 mixer over the Pallas
+kernels of ``ops/ssd.py``'s scan); attention may go without RoPE (``rope``)
 and take a score scale of its own (``attn_scale``); the embedding, each
 branch before its residual add and the logits take constant multipliers;
 and ``tie_embeddings`` makes the head the embedding table itself, its matmul

@@ -9,9 +9,9 @@ inside ``models/llama.py``'s block.  With ``n`` the block's normed input:
     h_t = a_t h_{t-1} + dt_t X_t B_t^T;  y_t = h_t C_t + D X_t
     out = W_out RMSNorm_w(y * silu(z))   the norm over all heads, after the gate
 
-The recurrence is ``ops/ssd.py``'s chunked scan.  The ``jax.named_scope``s
-``conv``, ``ssd`` and ``gated_norm`` and the ``Dense`` children ``in_proj``
-and ``out_proj`` are what the benchmark's per-layer metrics read.
+The recurrence is ``ops/ssd.py``'s chunked scan, two Pallas kernels.  The
+scopes ``conv``, ``ssd`` and ``gated_norm`` and the ``Dense`` children
+``in_proj`` and ``out_proj`` are what the benchmark's per-layer metrics read.
 """
 
 from __future__ import annotations

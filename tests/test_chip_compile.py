@@ -14,7 +14,8 @@ analysis for 1, 2 and 4 rows a step is where ``olmoe-s4k-1chip``'s
 ``rows_per_step`` was decided.  And the one-chip step of Granite-4.0-H-Micro
 (2048 wide, five Mamba-2 layers of 64 heads x 64 with a state of 128 and one
 grouped-query attention layer, vocab 100,352 tied, seq 8192, remat): the
-plain-XLA scan's chunk tensors have to fit beside 7.8 GB of state.  And the
+scan's Mosaic kernels meet the compiler, and no chunk-square tensor of the
+scan is left in the XLA program beside 7.8 GB of state.  And the
 one-chip block-diffusion step of SDAR-30B-A3B-Chat as one chip of eight holds
 it (2048 wide, 32 / 4 heads of 128, 16 of 128 experts of 768 held, 18,992
 vocabulary rows, six layers, two rows of 4096 tokens as 8192 positions,
@@ -173,9 +174,16 @@ def _build(case: str, compile_: bool) -> dict:
     # the Mosaic calls of the lowered module by their kernels' names (a call
     # the module makes twice through one function is printed once), and the
     # flash forward's calls as the step makes them: those of its jaxpr
+    text = lowered.as_text()
     out = {"case": case, "lowered_kernels": dict(collections.Counter(
-        re.findall(r'kernel_name = "(\w+)"', lowered.as_text()))),
+        re.findall(r'kernel_name = "(\w+)"', text))),
         "flash_fwd_calls": _flash_fwd_calls(traced.jaxpr.jaxpr)}
+    if "mamba" in getattr(config, "layer_types", ()):
+        # every array of the module a scan's chunk on a side and square: the
+        # decay masks, the scores and their products, where XLA holds them
+        out["chunk_squares"] = sorted(set(re.findall(
+            rf"tensor<(?:\d+x)*{config.mamba_chunk}x{config.mamba_chunk}"
+            r"x\w+>", text)))
     if compile_:
         try:
             compiled = lowered.compile()
@@ -465,23 +473,36 @@ def test_olmoe_step_compiles_and_says_how_many_rows_fit():
 def test_granite_step_lowers_for_one_v5e_chip():
     """Tier-1: the one-chip Granite-4.0-H-Micro step at published widths
     (five Mamba-2 layers and one attention layer, seq 8192 x 1 row) lowers
-    for the TPU with the flash kernel's Mosaic calls in it."""
+    for the TPU with its Mosaic calls in it: the flash kernels and the
+    scan's (``ops/ssd.py``), and nothing of a scan's chunk squares — the
+    decay mask, the scores, their product, the cotangents of each — is an
+    array of the XLA program: they live in the kernels' VMEM."""
     row = _child(["granite"], compile_=False)["granite"]
     # the one attention layer under remat: one forward, one backward kernel
-    assert row["lowered_kernels"] == {"flash_fwd": 1, "flash_bwd": 1}, row
+    # (the block keeps the forward's output and logsumexp); a mamba layer's
+    # scan: forward, the forward again in the block's recomputation (its
+    # output and the states before each chunk are the backward's residuals),
+    # backward
+    assert row["lowered_kernels"] == {"flash_fwd": 1, "flash_bwd": 1,
+                                      "ssd_fwd": 2 * 5, "ssd_bwd": 5}, row
     assert row["flash_fwd_calls"] == 1, row
+    # (the parent's step had eight kinds of them, up to 32 x 64 x 256 x 256)
+    assert row["chunk_squares"] == [], row
 
 
 @pytest.mark.slow
 def test_granite_step_compiles_and_fits_the_chip():
-    """The TPU compiler takes the plain-XLA scan at 64 heads x 32 chunks of
-    256, and its memory analysis says the step fits (PR 29: 7.77 GB of
-    arguments + 3.98 GB of temporaries; PR 38: 7.77 + 3.97)."""
+    """The TPU compiler takes the scan's kernels at 64 heads x 32 chunks of
+    256 (eight heads a grid step, X and y turned in VMEM), and its memory
+    analysis says the step fits (PR 29, the scan in plain XLA: 7.77 GB of
+    arguments + 3.98 GB of temporaries; PR 38: 7.77 + 3.97; PR 43, the
+    kernels: 7.77 + 3.72)."""
     row = _child(["granite"], compile_=True)["granite"]
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row
-    # the one attention layer: flash forward and the backward's one kernel
-    assert row["tpu_custom_calls"] == 2, row
+    # the one attention layer: flash forward and the backward's one kernel;
+    # a mamba layer's scan: forward, the forward recomputed, backward
+    assert row["tpu_custom_calls"] == 2 + 5 * 3, row
     assert row["argument_bytes"] + row["temp_bytes"] < 14e9, row
 
 

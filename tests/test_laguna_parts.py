@@ -163,11 +163,13 @@ def test_c_the_shares_add_up_to_the_uncut_layer():
 # matmul by a signed permutation in it, SDAR's per-head norm inside it;
 # ``toy-granite``, which rotates nothing, was PR 34's until PR 42.  All four
 # are PR 42's: the flash kernels' grid is (batch, heads, tiles, tiles), the
-# output leaves them as (B, S, H * D) and the models no longer transpose it.)
+# output leaves them as (B, S, H * D) and the models no longer transpose it.
+# ``toy-granite`` is PR 43's: its scan is the interpreted body of
+# ``ops/ssd.py``'s two kernels; the three others are PR 42's still.)
 _PARENT_STEPS = {
     "toy-llama": "114e35a37f363ab0f8c29d7948dfae1ee6cc766feb0bba3985842656d0b70ce6",
     "toy-olmoe": "41054e8f510af2a7d7be4f320326eb4237e4c1efc006d16bd81fbd3d9bea858f",
-    "toy-granite": "19944bd72f16b5fc7376d8c3862d78ce6964804d07bc2db5d9e8406bcad24ac0",
+    "toy-granite": "d7e06ee2ed4d884dfe315afc1e0cb2b69061aee5d1cdac08c012a96c046fe00e",
     "toy-sdar": "222b0bf1f1a77b9f7b59a06a8e8731dd8c05cc2521c078cde46cd291fa36d872",
 }
 

@@ -94,6 +94,56 @@ def test_a_chunked_scan_equals_the_recurrence(seq, groups):
                 err_msg=name)
 
 
+@pytest.mark.parametrize("case", ["published_tile", "long_carry"])
+def test_a2_the_kernels_at_the_published_tile_and_over_many_chunks(case):
+    """``published_tile``: the shape the chip runs a grid step at — chunks of
+    256, heads 64 wide two to a lane tile, a state of 128, four heads in one
+    group — in bf16 over two whole chunks and a ragged third, against the
+    float32 recurrence on the same bf16 inputs: values to the rounding of the
+    bf16 matmul operands (2% of the largest, as (b)), the five gradients to
+    3% of theirs.  ``long_carry``: float32, six chunks of 8 and a ragged
+    seventh at decays strong enough (``exp(dt A)`` down to 1e-3 a position)
+    that a state carried wrongly from chunk to chunk, or its cotangent on the
+    way back, cannot hide behind the chunk's own part: (a)'s tolerances."""
+    if case == "published_tile":
+        seq, chunk, dtype, value_tol, grad_tol = 600, 256, jnp.bfloat16, \
+            0.02, 0.03
+        k = jax.random.split(jax.random.PRNGKey(1), 5)
+        args = (jax.random.normal(k[0], (1, seq, 4, 64), dtype),
+                jnp.exp(jax.random.uniform(k[1], (1, seq, 4), jnp.float32,
+                                           np.log(1e-3), np.log(0.1))),
+                -jax.random.uniform(k[2], (4,), jnp.float32, 1.0, 16.0),
+                jax.random.normal(k[3], (1, seq, 1, 128), dtype),
+                jax.random.normal(k[4], (1, seq, 1, 128), dtype))
+    else:
+        seq, chunk, dtype, value_tol, grad_tol = 53, 8, jnp.float32, \
+            1e-4, 1e-3
+        x, dt, rate, b, c = _scan_inputs(seq, 2)
+        args = (x, dt, rate * 8.0, b, c)
+
+    def grads(fn):
+        return jax.grad(
+            lambda *a: jnp.sum(jnp.sin(fn(*a).astype(jnp.float32))),
+            argnums=(0, 1, 2, 3, 4))(*args)
+
+    with jax.default_matmul_precision("highest"):
+        got = ssd.ssd_scan(*args, chunk=chunk)
+        assert got.dtype == dtype and got.shape == args[0].shape
+        exact = lambda x, dt, rate, b, c: _recurrence(     # noqa: E731
+            x.astype(jnp.float32), dt, rate, b.astype(jnp.float32),
+            c.astype(jnp.float32))
+        want = exact(*args)
+        np.testing.assert_allclose(
+            got.astype(jnp.float32), want, rtol=0,
+            atol=value_tol * float(jnp.max(jnp.abs(want))))
+        for name, g, w in zip("x dt A B C".split(),
+                              grads(lambda *a: ssd.ssd_scan(*a, chunk=chunk)),
+                              grads(exact)):
+            np.testing.assert_allclose(
+                g.astype(jnp.float32), w.astype(jnp.float32), rtol=0,
+                atol=grad_tol * float(jnp.max(jnp.abs(w))), err_msg=name)
+
+
 def test_b_the_scan_decays_in_float32_inside_a_bf16_layer(monkeypatch):
     """The guarantee the configuration states: with bf16 activations the
     running sums of ``dt * A`` and the decays are float32, so the scan equals
