@@ -244,3 +244,23 @@ def lm_loss(logits, targets, mask=None, total=None):
         if total is not None:
             return jnp.sum(nll * mask) / total
         return jnp.sum(nll * mask) / jnp.maximum(jnp.sum(mask), 1.0)
+
+
+def shifted_heads_loss(logits, targets, mask, heads: int, vocab: int):
+    """``lm_loss`` of ``heads`` prediction heads, all weighted alike: the
+    logits hold one vocabulary of columns a head side by side, head ``r`` at
+    position ``t`` scores ``targets[t + r]`` — the token ``r + 1`` ahead,
+    ``targets`` being the row rolled left by one — and its term counts where
+    the row has such a position and ``mask[t + r]`` counts it: the mean over
+    every term that counts.  One head is ``lm_loss`` itself."""
+    if heads == 1:
+        return lm_loss(logits, targets, mask)
+    b, s = targets.shape
+    logits = logits[..., :heads * vocab].reshape(b, s, heads, vocab)
+    # position t + r of the row, the last where the row has none: not counted
+    at = jnp.arange(s)[:, None] + jnp.arange(heads)[None, :]
+    ahead, counted = jnp.minimum(at, s - 1), (at < s).astype(jnp.float32)
+    if mask is not None:
+        counted = counted * mask.astype(jnp.float32)[:, ahead]
+    return lm_loss(logits, targets[:, ahead],
+                   jnp.broadcast_to(counted, (b, s, heads)))

@@ -56,7 +56,18 @@ causal depthwise convolution ``conv_width`` wide over ``B * u``, the gate
 experts through a per-expert bias that is state and not a weight
 (``router_selection_bias``, ``norm_topk_eps``: ``models/moe.py``)
 (LFM2-24B-A2B is the block with these, the per-head norm and GQA at heads 64
-wide).
+wide).  ``eva_window`` > 0 makes every attention layer EVA attention: exact
+softmax over the positions of the query's own aligned window of that many,
+and beside them, in the same softmax, one summary key and value for every
+``eva_chunk`` positions of the windows before it, pooled under the scope
+``pool`` by weights that each head learns (``attn/phi``, ``attn/mu``:
+``ops.pooling.pool_chunks``; ``ops.attention``'s ``eva_mask``, its kernel
+calls under the scope ``eva``); ``norm_unit_offset`` makes every RMSNorm ``x / rms(x) *
+(1 + g)``; ``residual_dtype`` holds the residual stream, and with it each
+block's saved input, in another dtype than the activations; ``logits_dtype``
+is the head's output's; and ``n_pred_heads`` > 1 gives the head that many
+times the vocabulary's columns, head ``r`` scoring the token ``r + 1`` ahead
+(``models/gpt2.py::shifted_heads_loss``) (EvaByte is the block with these).
 Every such field at its default leaves the program the dense Llama it was.
 ``remat`` recomputes each block from its input in the backward; what
 ``remat_policy="full"`` keeps beside that input is each attention layer's
@@ -73,6 +84,7 @@ libs; the in-repo flagship models are this framework's own).
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
@@ -86,6 +98,7 @@ from ray_tpu.models.gpt2 import (mask_vocab_padding, padded_vocab,
 from ray_tpu.models.mamba import (Mamba2Mixer, _conv_init,
                                   gated_short_conv)
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
+from ray_tpu.ops import pooling
 from ray_tpu.ops.attention import HeadColumns, attention
 from ray_tpu.parallel.sharding import constrain_residual
 
@@ -194,6 +207,17 @@ class LlamaConfig:
     # no gradient and no optimizer update reaches (models/moe.py)
     router_selection_bias: bool = False
     norm_topk_eps: float = 0.0       # + the sum norm_topk_prob divides by
+    # EVA attention in every attention layer: a query sees the positions of
+    # its own aligned window of eva_window, and one learned-pooled summary a
+    # chunk of eva_chunk positions of the windows before; 0: none
+    eva_window: int = 0
+    eva_chunk: int = 0
+    norm_unit_offset: bool = False   # every RMSNorm's scale is 1 + g, g from 0
+    residual_dtype: Any = None       # the residual stream's; None: dtype
+    logits_dtype: Any = None         # the head's output's; None: dtype
+    # the head scores this many tokens ahead of each position, one vocabulary
+    # of columns each, all in the objective alike
+    n_pred_heads: int = 1
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -342,6 +366,31 @@ def apply_rope(x, cos, sin, norm_scale=None, eps: float = 1e-6):
                         jnp.float32(eps))
 
 
+class UnitOffsetRMSNorm(nn.Module):
+    """``x / rms(x) * (1 + scale)``, ``scale`` from zeros: the statistic and
+    the product in float32, the result in ``dtype``."""
+    epsilon: float = 1e-5
+    dtype: Any = jnp.bfloat16
+
+    @nn.compact
+    def __call__(self, x):
+        scale = self.param("scale", nn.initializers.zeros, (x.shape[-1],),
+                           jnp.float32)
+        x = x.astype(jnp.float32)
+        return (x * _rstd(x, self.epsilon) * (1.0 + scale)).astype(self.dtype)
+
+
+def rms_norm(cfg: "LlamaConfig", name: str):
+    """The configuration's RMSNorm, under ``name``."""
+    cls = UnitOffsetRMSNorm if cfg.norm_unit_offset else nn.RMSNorm
+    return cls(epsilon=cfg.rms_eps, dtype=cfg.dtype, name=name)
+
+
+def _pool_init(key, shape, dtype=jnp.float32):
+    return jnp.clip(jax.random.normal(key, shape, dtype), -1.0, 1.0) \
+        / np.sqrt(shape[-1])
+
+
 class HeadNormScale(nn.Module):
     """The scale of a per-head RMSNorm that ``apply_rope`` applies, under the
     path and with the shape, dtype and start ``nn.RMSNorm`` gives its own
@@ -365,12 +414,13 @@ def _gated(out, gate):
 
 
 def _attend(cfg: LlamaConfig, kind: str, q, k, v, k_shared=None,
-            head_dim=None):
+            head_dim=None, pooled=None):
     """A layer's one call into ``ops.attention``: what the configuration and
     the layer's kind say of the mask — a window for a sliding layer, under
     block diffusion (x is [noised ; clean]) the block mask in place of the
-    causal one.  Which implementation takes it, and whether one does, is
-    ``attention``'s to say.  Operands of rank 4 are (B, H, S, D), of rank 3
+    causal one, under EVA the window's own keys and ``pooled``, the
+    summaries' keys and values.  Which implementation takes it, and whether
+    one does, is ``attention``'s to say.  Operands of rank 4 are (B, H, S, D), of rank 3
     (B, S, H * D) as their projection wrote them; the result is
     (B, S, H * Dv), as the output projection takes it."""
     return attention(
@@ -379,7 +429,9 @@ def _attend(cfg: LlamaConfig, kind: str, q, k, v, k_shared=None,
         window=cfg.sliding_window if kind == "sliding_attention" else 0,
         diffusion_block=cfg.diffusion_block
         if cfg.objective == "block_diffusion" else 0,
-        sm_scale=cfg.attn_scale, ring_axis=cfg.ring_axis)
+        sm_scale=cfg.attn_scale, ring_axis=cfg.ring_axis,
+        **(dict(eva_window=cfg.eva_window, eva_chunk=cfg.eva_chunk,
+                k_pooled=pooled[0], v_pooled=pooled[1]) if pooled else {}))
 
 
 class LlamaAttention(nn.Module):
@@ -405,8 +457,7 @@ class LlamaAttention(nn.Module):
                              "False, True or 'head')")
 
         def norm(name, a):
-            return nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
-                              name=name)(a)
+            return rms_norm(cfg, name)(a)
 
         def heads(a):
             return a.reshape(B, S, -1, D).transpose(0, 2, 1, 3)
@@ -432,9 +483,23 @@ class LlamaAttention(nn.Module):
             with jax.named_scope("kv_repeat"):
                 k = jnp.repeat(k, rep, axis=1)
                 v = jnp.repeat(v, rep, axis=1)
+        pooled = None
+        if cfg.eva_window:
+            phi = self.param("phi", _pool_init, (H, D))
+            mu = self.param("mu", _pool_init, (H, D))
+            # (a row of one window sees no summary, and is causal.  The whole
+            # row is pooled, its last window too, whose summaries nothing
+            # reads: a slice of k and v costs more than an eighth of the
+            # pass)
+            if S > cfg.eva_window:
+                with jax.named_scope("pool"):
+                    pooled = pooling.pool_chunks(
+                        heads(k) if k.ndim == 3 else k, v, phi, mu,
+                        cfg.eva_chunk, cfg.attn_scale or D ** -0.5,
+                        impl=cfg.attention_impl)
         # what came straight from its projection goes to the kernels as it
         # lies, (B, S, H * D), and so does the result to ``wo``
-        out = _attend(cfg, self.kind, q, k, v, head_dim=D)
+        out = _attend(cfg, self.kind, q, k, v, head_dim=D, pooled=pooled)
         if cfg.attn_gate:
             # one scalar a head a token, from the layer's normed input
             gate = nn.Dense(H, use_bias=False, dtype=cfg.dtype, name="wg")(x)
@@ -575,8 +640,7 @@ class LlamaBlock(nn.Module):
                 branch = branch * cfg.residual_multiplier
             return x + branch
 
-        y = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
-                       name="attn_norm")(x)
+        y = rms_norm(cfg, "attn_norm")(x)
         if self.mixer == "mamba":
             x = add(x, Mamba2Mixer(cfg, name="mamba")(y))
         elif self.mixer == "conv":
@@ -589,8 +653,7 @@ class LlamaBlock(nn.Module):
         else:
             raise ValueError(f"unknown layer type {self.mixer!r} (expected "
                              f"'mamba', 'conv' or one of {ATTENTION_KINDS})")
-        y = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
-                       name="mlp_norm")(x)
+        y = rms_norm(cfg, "mlp_norm")(x)
         if self.routed:
             return add(x, RoutedSwiGLU(RoutedConfig(
                 n_experts=cfg.n_experts, top_k=cfg.moe_top_k,
@@ -618,6 +681,10 @@ class LlamaLMModel(nn.Module):
             raise ValueError(f"unknown objective {cfg.objective!r} (expected "
                              "'next_token' or 'block_diffusion')")
         two_copies = cfg.objective == "block_diffusion"
+        if cfg.n_pred_heads > 1 and (two_copies or cfg.tie_embeddings):
+            raise ValueError(
+                f"{cfg.n_pred_heads} prediction heads are an untied head "
+                "under the next-token objective")
         if two_copies and (S // 2) % cfg.diffusion_block:
             raise ValueError(f"a copy of {S // 2} positions is not whole "
                              f"blocks of {cfg.diffusion_block}")
@@ -630,6 +697,10 @@ class LlamaLMModel(nn.Module):
         x = wte(input_ids)
         if cfg.embedding_multiplier != 1.0:
             x = x * cfg.embedding_multiplier
+        if cfg.residual_dtype is not None:
+            # every add onto the stream promotes to it; the norms hand the
+            # branches their input in ``dtype``
+            x = x.astype(cfg.residual_dtype)
         x = constrain_residual(x)
         # both copies of a row count their positions from 0
         positions = jnp.tile(jnp.arange(S // 2), 2) if two_copies \
@@ -648,10 +719,11 @@ class LlamaLMModel(nn.Module):
                 cfg, routed, mixer, n_head, name=f"h_{i}")(x, positions))
         if two_copies:
             x = x[:, :S // 2]       # the head sees the noised copy alone
-        x = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype, name="norm_f")(x)
+        x = rms_norm(cfg, "norm_f")(x)
         if cfg.logits_scaling != 1.0:
             # on the narrow side of the head's matmul: the logits stay bf16
             x = x / cfg.logits_scaling
+        columns = cfg.n_pred_heads * cfg.vocab_size
         if cfg.tie_embeddings:
             # the head's matmul against the embedding table itself, under the
             # name path an untied head has; the table's gradient is the sum
@@ -660,9 +732,15 @@ class LlamaLMModel(nn.Module):
                 logits = jnp.einsum("bsd,vd->bsv", x,
                                     wte.embedding.astype(cfg.dtype))
         else:
-            logits = nn.Dense(padded_vocab(cfg.vocab_size), use_bias=False,
-                              dtype=cfg.dtype, name="lm_head")(x)
-        return mask_vocab_padding(logits, cfg.vocab_size)
+            # (n_pred_heads vocabularies of columns side by side, head r the
+            # columns from r * vocab_size)
+            logits = nn.Dense(
+                padded_vocab(columns), use_bias=False, dtype=cfg.dtype,
+                name="lm_head", **({} if cfg.logits_dtype is None else dict(
+                    dot_general=functools.partial(
+                        jax.lax.dot_general,
+                        preferred_element_type=cfg.logits_dtype))))(x)
+        return mask_vocab_padding(logits, columns)
 
 
 def llama_partition_rules():

@@ -8,6 +8,9 @@ train worker holds.
 
 The model's configuration chooses the objective.  ``"next_token"`` (every
 model but one): the cross entropy of ``targets`` under a causal model.
+With ``LlamaConfig.n_pred_heads`` > 1 it is that of every head, head ``r``
+against the token ``r + 1`` ahead, all weighted alike
+(``gpt2.shifted_heads_loss``).
 ``"block_diffusion"`` (``LlamaConfig.objective``): the step noises the
 batch's ``input_ids`` itself, on the device, from a key it folds its own step
 count into (``noise_blocks``), runs the noised and the clean copy through the
@@ -26,7 +29,8 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu._private import flight_recorder
-from ray_tpu.models.gpt2 import GPT2Config, GPT2LMModel, lm_loss
+from ray_tpu.models.gpt2 import (GPT2Config, GPT2LMModel, lm_loss,
+                                 shifted_heads_loss)
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.parallel.sharding import (
     gpt_partition_rules,
@@ -118,6 +122,16 @@ def loss_fn(model: GPT2LMModel, params, batch):
     ``sum(weights * nll) / input_ids.size``."""
     inputs, targets, weights, total = _model_inputs(model.config, batch)
     logits = model.apply({"params": params}, inputs)
+    return _cross_entropy(model.config, logits, targets, weights, total)
+
+
+def _cross_entropy(config, logits, targets, weights, total):
+    """``lm_loss``, or where the head scores more than one token ahead
+    (``LlamaConfig.n_pred_heads``) the mean over every head's terms."""
+    heads = getattr(config, "n_pred_heads", 1)
+    if heads > 1:
+        return shifted_heads_loss(logits, targets, weights, heads,
+                                  config.vocab_size)
     return lm_loss(logits, targets, weights, total)
 
 
@@ -138,7 +152,7 @@ def objective_fn(model, params, batch, key=None):
     inputs, targets, weights, total = _model_inputs(cfg, batch)
     logits, sown = model.apply({"params": params}, inputs,
                                mutable=["intermediates"])
-    loss = lm_loss(logits, targets, weights, total)
+    loss = _cross_entropy(cfg, logits, targets, weights, total)
     aux, stats = collect_aux(sown["intermediates"],
                              getattr(cfg, "router_aux_weight", 0.0),
                              getattr(cfg, "router_z_weight", 0.0))

@@ -39,6 +39,14 @@ Greenfield relative to the reference — it has no sequence parallelism anywhere
   through a BlockSpec that drops the head, and nothing is padded, broadcast
   or joined in HBM.  With one width and no shared part the traced calls are
   what they were.
+- Under the EVA mask (``flash_attention``'s ``eva_window``; ``eva_mask``) a
+  query sees the keys of its own aligned window up to itself and, of a second
+  key / value operand that holds one summary a chunk of positions, those of
+  the windows before its own.  Both kernels meet the two operands in the same
+  online softmax: a grid's inner axis runs over the window's own causal
+  tiles and then over the tiles of summaries that the queries see, and the
+  backward hands the summaries their gradients as it does dK and dV
+  (``_Tiles.eva``).  A row of at most one window is the causal call.
 - Under an ambient mesh (``jax.set_mesh``) ``flash_attention`` runs the kernel
   inside a ``shard_map`` (batch over dp/fsdp, heads over tp — a rank-3
   operand's columns by whole heads): Mosaic kernels cannot be partitioned by
@@ -110,18 +118,39 @@ def block_diffusion_mask(length: int, block: int):
         ~q_clean & (k_blk == q_blk))
 
 
+def eva_mask(length: int, window: int, chunk: int, n_pooled: int):
+    """The (S, n_pooled + S) boolean mask of EVA attention over
+    ``[summaries ; positions]``, written out: query ``i`` of window
+    ``w = i // window`` sees the positions of its own window up to itself and
+    the summary of every ``chunk`` positions of the windows before,
+    ``j < w * window / chunk``."""
+    i = jnp.arange(length)[:, None]
+    first = i // window * window
+    j, m = jnp.arange(n_pooled)[None, :], jnp.arange(length)[None, :]
+    return jnp.concatenate([j * chunk < first, (m >= first) & (m <= i)],
+                           axis=1)
+
+
 def mha_reference(q, k, v, *, causal: bool = True, sm_scale: Optional[float] = None,
                   q_offset: int = 0, k_offset: int = 0, mask=None,
-                  window: int = 0, k_shared=None):
+                  window: int = 0, k_shared=None, eva=(), pooled=None):
     """Naive attention; ground truth for kernels. q,k,v: (B, H, S, D); v may
     be another width than q and k, and is the output's.
     ``mask``: a boolean (S_q, S_k) array of the pairs that are seen, in place
     of the causal one.  ``window`` > 0: under the causal mask a query sees
     itself and the ``window - 1`` positions before it.  ``k_shared`` (B, 1,
     S, D_s): the last D_s dimensions of every head's key, held once a
-    position; k is then D - D_s wide."""
+    position; k is then D - D_s wide.  ``eva`` (window, chunk) with
+    ``pooled`` (keys, values), each (B, H, n, D), one summary a chunk:
+    ``eva_mask`` over ``[summaries ; positions]``, in one softmax."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
+    if eva:
+        if mask is not None or window or k_shared is not None or not causal:
+            raise ValueError("the EVA mask is its own")
+        mask = eva_mask(q.shape[2], *eva, pooled[0].shape[2])
+        k, v = (jnp.concatenate([p.astype(x.dtype), x], axis=2)
+                for p, x in zip(pooled, (k, v)))
     if window and (mask is not None or not causal):
         raise ValueError("a window belongs to the causal mask")
     if k_shared is not None:
@@ -287,13 +316,42 @@ class _Tiles(NamedTuple):
     steps_k: int = 0    # the inner axis with the keys inner (window only)
     steps_q: int = 0    # ... and with the queries inner
     group: int = 1      # heads a grid step
+    # EVA (``eva`` > 0, the window): under the causal mask a query sees the
+    # keys of its own aligned window of ``eva`` positions alone, and beside
+    # them a second key / value operand, one summary a chunk of positions,
+    # ``per`` a window, of which it sees those of the windows before its own:
+    # summary c iff c < (r // eva) * per.  Tiles are square and lie whole in
+    # a window, so the window's own tiles are the causal ones (the diagonal
+    # tile chunked under ``tri``, the tiles before it in the window bare) and
+    # a tile of ``block_s`` summaries is bare, or cut by the key limit where
+    # a window's summaries end inside it.  With the keys inner a query tile
+    # walks its window's ``eva / block`` tiles and then the ``ns`` tiles of
+    # summaries, of which it stops at its last visible one; with the queries
+    # inner the outer axis is the ``nk`` tiles of positions and then the
+    # ``ns`` of summaries, the inner one a position tile's queries to the end
+    # of its window or a summary tile's from the first window after its
+    # first chunk's.  0: no such mask.
+    eva: int = 0
+    per: int = 0
+    block_s: int = 0
+    ns: int = 0
 
     @classmethod
     def of(cls, s_q, s_k, d, dtype, causal, offset, diag_chunk,
-           block_q=None, block_k=None, bd=0, window=0):
+           block_q=None, block_k=None, bd=0, window=0, eva=()):
         """``s_q``: the queries' length; under ``bd`` one half's, which is
-        ``s_k``.  (One head a grid step: a caller with pairs replaces
-        ``group``.)"""
+        ``s_k``.  ``eva``: (window, chunk), the window in whole lanes.  (One
+        head a grid step: a caller with pairs replaces ``group``.)"""
+        if eva:
+            assert causal and not (bd or window or offset) and s_q == s_k \
+                and eva[0] % LANES == 0 and eva[0] % eva[1] == 0, \
+                (causal, bd, window, offset, s_q, s_k, eva)
+            # the largest tile edge asked for or picked that cuts a window
+            # into whole tiles
+            edge = _block(s_q, d, dtype, block_q or block_k)
+            while eva[0] % edge:
+                edge -= LANES
+            block_q = block_k = edge
         if window:
             assert causal and not bd and window > 0, (causal, bd, window)
             edge = _round_up(window, LANES)
@@ -322,6 +380,12 @@ class _Tiles(NamedTuple):
         t = cls(causal, offset, block_q, block_k,
                 _round_up(s_q, block_q) // block_q, s_k_pad // block_k,
                 None if s_k_pad == s_k else s_k, tri)
+        if eva:
+            per = eva[0] // eva[1]
+            block_s = min(_round_up(per, LANES), t.block_k)
+            return t._replace(
+                eva=eva[0], per=per, block_s=block_s,
+                ns=-(-((s_q - 1) // eva[0] * per) // block_s))
         if not window:
             return t
         t = t._replace(window=window)
@@ -355,8 +419,51 @@ class _Tiles(NamedTuple):
             ik * self.block_k + self.block_k + self.window - 2 - self.offset,
             0) // self.block_q, self.nq - 1)
 
+    @property
+    def tps(self) -> int:
+        """Under ``eva``: tiles a window."""
+        return self.eva // self.block_q
+
+    def eva_walk(self, i, j, q_is_inner: bool):
+        """Under ``eva``: grid position -> (iq, ik, js, whether the step is
+        a tile of positions and within its walk, whether it is a tile of
+        summaries that query tile ``iq`` sees), nothing clamped: the tile of
+        positions ``ik`` or of summaries ``js`` that the step stands for."""
+        tps, bs = self.tps, self.block_s
+        if not q_is_inner:
+            window = i // tps
+            js = j - tps
+            return (i, window * tps + j, js, j < tps, jnp.logical_and(
+                js >= 0, js * bs < window * self.per))
+        is_pos = i < self.nk
+        # a tile of positions: its own query tile and those after it in its
+        # window; a tile of summaries: from the first window after the one
+        # its first chunk lies in
+        last = jnp.minimum((i // tps + 1) * tps, self.nq) - 1
+        js = jnp.maximum(i - self.nk, 0)
+        iq = jnp.where(is_pos, i + j, (js * bs // self.per + 1) * tps + j)
+        return (iq, i, js, jnp.logical_and(is_pos, iq <= last),
+                jnp.logical_and(jnp.logical_not(is_pos), iq < self.nq))
+
+    def pooled_of(self, i, j, q_is_inner: bool):
+        """Under ``eva``: grid position -> the tile of summaries, clamped to
+        one the step's queries see (the first, where they see none)."""
+        if q_is_inner:
+            return jnp.maximum(i - self.nk, 0)
+        seen = -(-(i // self.tps * self.per) // self.block_s)
+        return jnp.clip(j - self.tps, 0, jnp.maximum(seen - 1, 0))
+
+    def pooled_spec(self, width: int, cols, q_is_inner: bool):
+        """Of a ``(.., summaries, .)`` operand, following ``pooled_of``."""
+        return self.blocks(
+            (self.block_s,), width, cols,
+            lambda i, j: (self.pooled_of(i, j, q_is_inner),))
+
     def steps(self, q_is_inner: bool) -> int:
         """The length of the grid's inner axis."""
+        if self.eva:
+            return max(self.tps, self.nq - self.tps) if q_is_inner \
+                else self.tps + self.ns
         if self.window:
             return self.steps_q if q_is_inner else self.steps_k
         return self.nq if q_is_inner else self.nk
@@ -375,6 +482,15 @@ class _Tiles(NamedTuple):
     def tile_of(self, i, j, q_is_inner: bool):
         """Grid position -> (iq, ik), the inner one clamped to the nearest
         tile that does work."""
+        if self.eva:
+            tps = self.tps
+            if not q_is_inner:
+                return i, jnp.minimum(i // tps * tps + jnp.minimum(j, tps - 1),
+                                      i)
+            iq, ik, *_ = self.eva_walk(i, j, True)
+            last = jnp.where(i < self.nk, jnp.minimum(
+                (i // tps + 1) * tps, self.nq) - 1, self.nq - 1)
+            return jnp.minimum(iq, last), jnp.minimum(ik, self.nk - 1)
         iq, ik = self.walk(i, j, q_is_inner)
         if self.window:
             if q_is_inner:
@@ -659,7 +775,7 @@ def _band_chunks(t: _Tiles, m: int):
                below if below <= c - 1 else None)
 
 
-def _on_tiles(t: _Tiles, iq, ik, part):
+def _on_tiles(t: _Tiles, iq, ik, part, also=None):
     """Run ``part(q_slice, k_slice, thresh, k_limit)`` (see ``_masked``) over
     tile (iq, ik) as its kind needs: not at all above the diagonal, bare below
     it, masked where the diagonal or the key padding passes through.  With
@@ -667,7 +783,8 @@ def _on_tiles(t: _Tiles, iq, ik, part):
     in chunks of ``t.tri`` queries against only the keys up to each chunk's
     last query.  Under ``t.window`` a tile below the band does nothing either,
     and a tile the band's lower edge crosses is masked as well, with ``part``'s
-    fifth argument ``below``."""
+    fifth argument ``below``.  ``also``: one more condition of a causal
+    tile's doing anything."""
     whole = slice(None)
 
     def bare():
@@ -692,6 +809,8 @@ def _on_tiles(t: _Tiles, iq, ik, part):
         shift = 0
         thresh = ik * t.block_k - iq * t.block_q - t.offset
         live = thresh <= t.block_q - 1
+        if also is not None:
+            live = jnp.logical_and(live, also)
     crossing = thresh > 1 - t.block_k
     if t.window:
         below = thresh + t.window
@@ -729,6 +848,20 @@ def _on_tiles(t: _Tiles, iq, ik, part):
         is_masked = jnp.logical_or(crossing, padded)
     pl.when(jnp.logical_and(live, is_masked))(masked)
     pl.when(jnp.logical_and(live, jnp.logical_not(is_masked)))(bare)
+
+
+def _on_summaries(t: _Tiles, iq, js, seen, part):
+    """Under ``t.eva``: run ``part`` over the tile ``js`` of summaries for
+    query tile ``iq``, where ``seen``: bare, or under the key limit where
+    the summaries of the windows before the queries' end inside the tile."""
+    whole, ks = slice(None), slice(0, t.block_s)
+    limit = iq // t.tps * t.per - js * t.block_s
+    pl.when(jnp.logical_and(seen, limit >= t.block_s))(
+        lambda: part(whole, ks, None, None, pooled=True))
+    if t.per % t.block_s:
+        pl.when(functools.reduce(jnp.logical_and, (
+            seen, limit > 0, limit < t.block_s)))(
+                lambda: part(whole, ks, None, limit, pooled=True))
 
 
 def _across(col, n: int):
@@ -788,12 +921,16 @@ def _scores(q_ref, qs, k_ref, ks, ks_ref):
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles,
                       tokens):
     # under ``t.bd`` two more inputs: the noised K and V tile of the query
-    # tile's own positions; without it, at most one: the keys' shared part.
+    # tile's own positions; under ``t.eva`` two: the summaries' keys and
+    # values; without either, at most one: the keys' shared part.
     # ``tokens``: which of q, k, v and the output are rank 3 (``_per_head``)
     *own_refs, o_ref, lse_ref, m_col, l_col, acc = rest
     row, step = pl.program_id(2), pl.program_id(3)
-    iq, ik = t.walk(row, step, False)
-    ks_ref = own_refs[0] if own_refs and not t.bd else None
+    if t.eva:
+        iq, ik, js, _, seen = t.eva_walk(row, step, False)
+    else:
+        iq, ik = t.walk(row, step, False)
+    ks_ref = own_refs[0] if own_refs and not (t.bd or t.eva) else None
     heads = list(zip(*(
         _per_head(ref, t.group, rank3) for ref, rank3 in zip(
             (q_ref, k_ref, v_ref, o_ref, m_col, l_col, acc),
@@ -829,8 +966,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles,
         l_col[...] = jnp.zeros_like(l_col)
         acc[...] = jnp.zeros_like(acc)
 
-    def part(qs, ks, thresh, k_limit, below=None):
+    def part(qs, ks, thresh, k_limit, below=None, pooled=False):
         for q_ref, k_ref, v_ref, _, m_col, l_col, acc in heads:
+            if pooled:      # (one head a step under ``t.eva``)
+                k_ref, v_ref = own_refs
             v = v_ref[ks, :]
             s = _masked(lax.mul(_scores(q_ref, qs, k_ref, ks, ks_ref),
                                 sm_scale), 0, thresh, k_limit, t.bd, below)
@@ -853,6 +992,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles,
                                  _dot(p.astype(v.dtype), v, _NN))
 
     _on_tiles(t, iq, ik, part)
+    if t.eva:
+        _on_summaries(t, iq, js, seen, part)
 
     @pl.when(step == t.steps(False) - 1)
     def _():
@@ -868,19 +1009,23 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles,
             lse_ref[p:p + 1, :] = lse.T[:1, :]
 
 
-@functools.partial(jax.jit, static_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 13),
+@functools.partial(jax.jit,
+                   static_argnums=(3, 4, 5, 6, 7, 8, 9, 10, 11, 13, 15),
                    inline=True)
 def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
                    k_offset: int, block_q: Optional[int],
                    block_k: Optional[int], interpret: bool, bd: int = 0,
                    window: int = 0, k_shared=None,
-                   lay: Optional[_Layout] = None):
+                   lay: Optional[_Layout] = None, pooled=None,
+                   eva: tuple = ()):
     """``out`` — (b, h, s_q, v's width), or (b, s_q, h * v's width) under
     ``lay.out`` — and the logsumexp of every query's scaled scores as
     (b*h, 1, s_q) rows, NEG_INF where a query sees no key.  ``lay``: the
     operands' ranks (None: all of rank 4).  Under ``bd`` (see ``_Tiles``) q,
     k and v are the two copies of ``s_q / 2`` positions, and every query sees
-    a key.  ``k_shared`` (b, 1, s_k, .): see ``flash_attention``.
+    a key.  ``k_shared`` (b, 1, s_k, .): see ``flash_attention``.  ``eva``
+    (window, chunk) with ``pooled``, the summaries' keys and values laid out
+    as k and v are: see ``_Tiles``.
 
     Jitted and inlined so that a model's layers, which call it with the same
     shapes, share one trace of the kernel: the equations land in the caller's
@@ -893,7 +1038,7 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
     s_k = s_k // 2 if bd else s_k       # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
                   q_offset - k_offset, _FWD_DIAG_CHUNK, block_q, block_k, bd,
-                  window)._replace(group=g)
+                  window, eva)._replace(group=g)
     s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
     tokens = tuple(c is not None for c in lay[1:])
     k_spec, *kn_spec = t.k_specs(d_k, lay.k, False)
@@ -908,6 +1053,11 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
         if k_shared is not None:
             own = (_pad(k_shared[:, 0], 1, s_k_pad),)
             own_spec = [t.shared_spec(k_shared.shape[-1], False)]
+        if eva:
+            own = tuple(_rows(x, t.ns * t.block_s, rank3)
+                        for x, rank3 in zip(pooled, tokens[1:3]))
+            own_spec = [t.pooled_spec(d_k, lay.k and _dense(d_k), False),
+                        t.pooled_spec(d_v, lay.v and _dense(d_v), False)]
         def scratch(width):
             return pltpu.VMEM(t.per_head(t.block_q, width), jnp.float32)
         out, lse = pl.pallas_call(
@@ -959,13 +1109,20 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
     # this head's queries.  ``tokens``: which of q, dO, k and v are rank 3
     # (``_per_head``); a gradient is laid out as what it is the gradient of
     ks_ref, dks_ref, dks_acc = None, None, None
-    if len(rest) == 9:
+    if t.eva:
+        # the summaries' keys and values, and their gradients
+        kp_ref, vp_ref, dq_ref, dk_ref, dv_ref, dkp_ref, dvp_ref, dq_acc, \
+            dk_acc, dv_acc = rest
+    elif len(rest) == 9:
         ks_ref, dq_ref, dk_ref, dv_ref, dks_ref, dq_acc, dk_acc, dv_acc, \
             dks_acc = rest
     else:
         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
     ik, step = pl.program_id(2), pl.program_id(3)
-    iq = t.walk(ik, step, True)[0]
+    if t.eva:
+        iq, _, js, in_window, seen = t.eva_walk(ik, step, True)
+    else:
+        iq = t.walk(ik, step, True)[0]
     last_k, last_step = pl.num_programs(2) - 1, pl.num_programs(3) - 1
     if t.bd:
         # both copies' tile ik: the clean one takes the walk and the
@@ -993,10 +1150,11 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
         if dks_acc is not None:
             dks_acc[...] = jnp.zeros_like(dks_acc)
 
-    def part(qs, ks, thresh, k_limit, below=None, own=False):
+    def part(qs, ks, thresh, k_limit, below=None, own=False, pooled=False):
         for p, (q_ref, do_ref, k_ref, v_ref, _, _, _, dq_acc, dk_acc, dv_acc,
                 *shared) in enumerate(heads):
-            kr, vr = (kn_ref, vn_ref) if own else (k_ref, v_ref)
+            kr, vr = (kn_ref, vn_ref) if own else (kp_ref, vp_ref) if pooled \
+                else (k_ref, v_ref)
             q, do, k = q_ref[qs, :], do_ref[qs, :], kr[ks, :]
             if ks_ref is None:
                 st = _dot(k, q, _NT)
@@ -1037,9 +1195,22 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
         def _():
             for rows in _lane_chunks(t.block_q):
                 part(rows, rows, None, None, own=True)
-    _on_tiles(t, iq, ik, part)
+    if t.eva:
+        _on_tiles(t, iq, ik, part, in_window)
+        _on_summaries(t, iq, js, seen, part)
 
-    @pl.when(step == last_step)
+        # (one head a step) a tile of summaries is the accumulators' first
+        # ``block_s`` rows
+        @pl.when(jnp.logical_and(step == last_step, ik >= t.nk))
+        def _():
+            rows = slice(0, t.block_s)
+            dkp_ref[...] = (dk_acc[rows, :] * sm_scale).astype(dkp_ref.dtype)
+            dvp_ref[...] = dv_acc[rows, :].astype(dvp_ref.dtype)
+    else:
+        _on_tiles(t, iq, ik, part)
+
+    @pl.when(jnp.logical_and(step == last_step, ik < t.nk) if t.eva
+             else step == last_step)
     def _():
         for _, _, _, _, _, dk_ref, dv_ref, _, dk_acc, dv_acc, *shared in heads:
             dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
@@ -1054,15 +1225,17 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
             dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12, 14),
+@functools.partial(jax.jit, static_argnums=(6, 7, 8, 9, 10, 11, 12, 14, 16),
                    inline=True)
 def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
                     q_offset: int, k_offset: int, interpret: bool,
                     bd: int = 0, window: int = 0, k_shared=None,
-                    lay: Optional[_Layout] = None):
+                    lay: Optional[_Layout] = None, pooled=None,
+                    eva: tuple = ()):
     """dq, dk, dv of ``_flash_attention`` from its residuals (``lse`` as the
     forward leaves it: (b*h, 1, s_q) rows) and ``g``; with ``k_shared``, its
-    gradient too, summed over the heads.  Each gradient has the rank of what
+    gradient too, summed over the heads; with ``pooled``, the summaries'
+    keys' and values' (last).  Each gradient has the rank of what
     it is the gradient of; one of rank 3 is (b, s, h * width), the heads side
     by side from column 0, whatever columns the operand itself was read at.
 
@@ -1074,8 +1247,8 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
     (s_q, d), (s_k, d_k), (_, d_v) = map(_seen, (q, k, v), lay[1:4])
     s_k = s_k // 2 if bd else s_k       # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
-                  q_offset - k_offset, _BWD_DIAG_CHUNK, bd=bd, window=window
-                  )._replace(group=n)
+                  q_offset - k_offset, _BWD_DIAG_CHUNK, bd=bd, window=window,
+                  eva=eva)._replace(group=n)
     s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
     q_is, k_is, v_is, o_is = (c is not None for c in lay[1:])
 
@@ -1118,7 +1291,8 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
         cols = cols and _dense(width)
         mid = (2, s_k_pad) if bd else (s_k_pad,)
         return (t.blocks((2, t.block_k) if bd else (t.block_k,), width, cols,
-                          lambda i, j: (0, i) if bd else (i,)),
+                          lambda i, j: (0, i) if bd
+                          else (jnp.minimum(i, t.nk - 1),) if eva else (i,)),
                 jax.ShapeDtypeStruct(
                     t.array(b, h, mid, width, cols is not None), q.dtype))
 
@@ -1149,10 +1323,22 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
         out_shape.append(shape)
         scratches.append(scratch(t.block_k, d_s))
         operands.append(_pad(k_shared[:, 0], 1, s_k_pad))
+    if eva:
+        # the summaries' tiles follow the positions' on the outer axis and
+        # take their turn in the same two accumulators
+        n_pooled = pooled[0].shape[1 if k_is else 2]
+        for x, width, cols in ((pooled[0], d_k, lay.k), (pooled[1], d_v, lay.v)):
+            cols = cols and _dense(width)
+            spec = t.pooled_spec(width, cols, True)
+            in_specs.append(spec)
+            out_specs.append(spec)
+            out_shape.append(jax.ShapeDtypeStruct(t.array(
+                b, h, (t.ns * t.block_s,), width, cols is not None), q.dtype))
+            operands.append(_rows(x, t.ns * t.block_s, cols is not None))
     dq, dk, dv, *dks = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, sm_scale=sm_scale, t=t,
                           tokens=(q_is, o_is, k_is, v_is)),
-        grid=(b, h // n, t.nk, t.steps(True)),
+        grid=(b, h // n, t.nk + t.ns, t.steps(True)),
         in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=scratches,
         compiler_params=pltpu.CompilerParams(
@@ -1171,6 +1357,9 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
 
     grads = (back(dq, q, s_q, q_is), back(dk, k, s_k, k_is),
              back(dv, v, s_k, v_is))
+    if eva:
+        return grads + tuple(back(dx, x, n_pooled, rank3) for dx, x, rank3
+                             in zip(dks, pooled, (k_is, v_is)))
     if k_shared is None:
         return grads
     return grads + (jnp.sum(
@@ -1185,10 +1374,10 @@ def _named_forward(*args):
                      FLASH_RESIDUALS))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(4, 13)))
-def _flash_attention(q, k, v, k_shared=None, causal=True, sm_scale=1.0,
-                     q_offset=0, k_offset=0, block_q=None, block_k=None,
-                     window=0, bd=0, lay=None):
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(5, 15)))
+def _flash_attention(q, k, v, k_shared=None, pooled=None, causal=True,
+                     sm_scale=1.0, q_offset=0, k_offset=0, block_q=None,
+                     block_k=None, window=0, bd=0, lay=None, eva=()):
     """The flash pair under its one differentiation rule, for every mask and
     layout the kernels take.  ``lay``: the operands' ranks and the output's
     (``_Layout``, from ``flash_attention``).  ``k_shared``: the last
@@ -1201,26 +1390,30 @@ def _flash_attention(q, k, v, k_shared=None, causal=True, sm_scale=1.0,
     clean keys, tiles skipped and masked as under a causal diagonal, and a
     noised block against itself (``l / bd`` squares of ``bd`` x ``bd``
     scores: 0.1% of the pairs at l 4096 and bd 4) as one more step of a
-    noised tile (``_Tiles``)."""
-    return _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, q_offset,
-                           k_offset, block_q, block_k, window, bd, lay)[0]
+    noised tile (``_Tiles``).  ``eva`` (window, chunk) with ``pooled``, the
+    keys and values of one summary a chunk (or None, as ``k_shared``): the
+    window's own causal tiles and the summaries of the windows before, in
+    that one softmax."""
+    return _flash_fwd_rule(q, k, v, k_shared, pooled, causal, sm_scale,
+                           q_offset, k_offset, block_q, block_k, window, bd,
+                           lay, eva)[0]
 
 
-def _flash_fwd_rule(q, k, v, k_shared, causal, sm_scale, q_offset, k_offset,
-                    block_q, block_k, window, bd, lay):
+def _flash_fwd_rule(q, k, v, k_shared, pooled, causal, sm_scale, q_offset,
+                    k_offset, block_q, block_k, window, bd, lay, eva):
     out, lse = _named_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
                               block_q, block_k, _interpret(), bd, window,
-                              k_shared, lay)
-    return out, (q, k, v, k_shared, out, lse)
+                              k_shared, lay, pooled, eva)
+    return out, (q, k, v, k_shared, pooled, out, lse)
 
 
 def _flash_bwd_rule(causal, sm_scale, q_offset, k_offset, block_q, block_k,
-                    window, bd, lay, residuals, g):
-    q, k, v, k_shared, out, lse = residuals
+                    window, bd, lay, eva, residuals, g):
+    q, k, v, k_shared, pooled, out, lse = residuals
     with jax.named_scope("flash_bwd"):
         dq, dk, dv, *dks = _flash_backward(
             q, k, v, out, lse, g, causal, sm_scale, q_offset, k_offset,
-            _interpret(), bd, window, k_shared, lay)
+            _interpret(), bd, window, k_shared, lay, pooled, eva)
         # a rank-3 operand read at some columns of its array: the gradient
         # is that array's, zero elsewhere (three operands of one array add up
         # to its whole gradient in one pass)
@@ -1228,7 +1421,9 @@ def _flash_bwd_rule(causal, sm_scale, q_offset, k_offset, block_q, block_k,
             dx if cols is None else jax.linear_transpose(
                 functools.partial(_take, cols=cols, heads=lay.heads), x)(dx)[0]
             for dx, x, cols in zip((dq, dk, dv), (q, k, v), lay[1:4]))
-    return dq, dk, dv, (dks[0] if dks else None)
+    if eva:
+        return dq, dk, dv, None, tuple(dks)
+    return dq, dk, dv, (dks[0] if dks else None), None
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -1276,16 +1471,33 @@ def _heads_in(q, head_dim: Optional[int]) -> int:
     return q.shape[-1] // head_dim
 
 
-def _settled(lay: _Layout, bd: int) -> _Layout:
+def _settled(lay: _Layout, alone: bool) -> _Layout:
     """``lay`` with the rank-3 operands left that the kernels can address as
-    they lie (``_Tiles``), the others None: those go head-major."""
+    they lie (``_Tiles``), the others None: those go head-major.  ``alone``:
+    the mask's kernels take a head a block, never two."""
     def kept(group):
         return lay._replace(**{
             name: cols if cols is not None and cols.fits(group) else None
             for name, cols in zip(lay._fields[1:], lay[1:])})
-    if not (bd or lay.heads % 2) and kept(2).group == 2:
+    if not (alone or lay.heads % 2) and kept(2).group == 2:
         return kept(2)
     return kept(1)
+
+
+def _eva(window: int, chunk: int, length: int) -> tuple:
+    """(window, chunk) of the EVA mask over a row of ``length`` positions,
+    or (): no such mask, and a row of at most one window, which sees its own
+    keys alone, has none either: it is the causal call."""
+    return (int(window), int(chunk)) if 0 < window < length else ()
+
+
+def _pooled_as(x, heads: int, seen: int, tokens: bool):
+    """A summaries' operand of either rank -> its first ``seen`` summaries,
+    (B, n, H * D) if ``tokens`` else (B, H, n, D)."""
+    if (x.ndim == 3) != tokens:
+        x = _to_tokens(x) if tokens else _to_heads(
+            x, _dense(x.shape[-1] // heads), heads)
+    return lax.slice_in_dim(x, 0, seen, axis=1 if tokens else 2)
 
 
 def _bhsd_spec(mesh, batch_axes, head_axis, seq_axis=None, tokens=False):
@@ -1303,7 +1515,9 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
                     block_q: Optional[int] = None, block_k: Optional[int] = None,
                     diffusion_block: int = 0, window: int = 0, k_shared=None,
                     head_dim: Optional[int] = None,
-                    tokens_out: Optional[bool] = None):
+                    tokens_out: Optional[bool] = None,
+                    eva_window: int = 0, eva_chunk: int = 0,
+                    k_pooled=None, v_pooled=None):
     """Blockwise (flash) attention.  Each of q, k, v is rank 4, (B, H, S, D),
     or rank 3, (B, S, H * D) as a projection wrote it (or a ``HeadColumns``
     of such an array); a rank-3 q comes with ``head_dim``.  The output has
@@ -1331,6 +1545,14 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     the causal one (``block_diffusion_mask``; S is a noised and a clean copy
     of S / 2 positions, in blocks of that many; a power of two up to 128).
 
+    ``eva_window`` > 0, under the causal mask (``eva_mask``): a query sees
+    the positions of its own aligned window of that many up to itself, and of
+    ``k_pooled`` / ``v_pooled`` — one summary key and value for every
+    ``eva_chunk`` positions, rank 4 or rank 3 as k and v — those of the
+    windows before its own, all in one online softmax; no tile outside the
+    window and the visible summaries is visited, and the summaries get their
+    gradients.  The window is whole lanes and whole chunks.
+
     Under an ambient mesh of more than one device the kernel runs inside a
     ``shard_map`` — batch over dp/fsdp, heads over tp, the sequence whole on
     every device (sequence sharding is ring attention's job).  Without one,
@@ -1339,7 +1561,7 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     heads = _heads_in(q, head_dim)
     (q, k, v), cols = zip(*(_operand(x, heads) for x in (q, k, v)))
     lay = _Layout(heads, *cols)
-    (_, d), (_, d_k), (_, d_v) = map(_seen, (q, k, v), cols)
+    (_, d), (s_k, d_k), (_, d_v) = map(_seen, (q, k, v), cols)
     if tokens_out is None:
         tokens_out = lay.q is not None
     if sm_scale is None:
@@ -1349,6 +1571,14 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     if window and (diffusion_block or not causal):
         raise ValueError("a window belongs to the causal mask")
     shared = 0 if k_shared is None else k_shared.shape[-1]
+    eva = _eva(eva_window, eva_chunk, s_k)
+    if eva and (window or diffusion_block or shared or not causal
+                or q_offset or k_offset):
+        raise ValueError("the EVA mask is its own, under the causal one")
+    if eva and (eva[0] % LANES or eva[0] % eva[1]):
+        raise NotImplementedError(
+            f"the kernels take an EVA window in whole lanes ({LANES}) and "
+            f"whole chunks, not {eva}")
     if d != d_k + shared:
         raise ValueError(f"queries {d} wide against keys {d_k} + {shared}")
     if diffusion_block and (shared or d != d_v):
@@ -1368,16 +1598,22 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     # what one device's call can address as it lies; the rest head-major
     lay = _settled(lay._replace(heads=heads // tp,
                                 out=_dense(d_v) if tokens_out else None),
-                   int(diffusion_block))
+                   alone=bool(diffusion_block or eva))
     q, k, v = (x if kept is not None else _to_heads(x, c, heads)
                for x, c, kept in zip((q, k, v), cols, lay[1:4]))
     operands = (q, k, v) if k_shared is None else (q, k, v, k_shared)
+    if eva:
+        # the summaries that some query sees, laid out as k and v are
+        seen = (s_k - 1) // eva[0] * (eva[0] // eva[1])
+        operands = (q, k, v, None, tuple(
+            _pooled_as(x, heads, seen, kept is not None)
+            for x, kept in zip((k_pooled, v_pooled), lay[2:4])))
     # (the block mask is its own: causal is not asked, the offsets not read)
     f = functools.partial(
         _flash_attention, causal=causal and not diffusion_block,
         sm_scale=float(sm_scale), q_offset=int(q_offset),
         k_offset=int(k_offset), block_q=block_q, block_k=block_k,
-        window=int(window), bd=int(diffusion_block), lay=lay)
+        window=int(window), bd=int(diffusion_block), lay=lay, eva=eva)
     if mesh is None:
         out = f(*operands)
     else:
@@ -1385,6 +1621,8 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
         in_specs = [_bhsd_spec(mesh, ("dp", "fsdp"), "tp",
                                tokens=c is not None) for c in lay[1:4]] \
             + [_bhsd_spec(mesh, ("dp", "fsdp"), None)]
+        if eva:     # the summaries as k and v
+            in_specs[3:] = [None, tuple(in_specs[1:3])]
         out = jax.shard_map(
             f, mesh=mesh, in_specs=tuple(in_specs[:len(operands)]),
             out_specs=_bhsd_spec(mesh, ("dp", "fsdp"), "tp",
@@ -1504,13 +1742,16 @@ _REFUSED = {
     "diffusion_block": (("ring",), "block mask"),
     "k_shared": (("ring",), "key part that the heads share (latent "
                  "attention): it takes one width for scores and values"),
+    "eva_window": (("ring",), "EVA mask (a window's own keys and the "
+                   "summaries of the windows before)"),
 }
 
 
 def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
               diffusion_block: int = 0, sm_scale: Optional[float] = None,
               k_shared=None, head_dim: Optional[int] = None,
-              ring_axis: str = "sp"):
+              ring_axis: str = "sp", eva_window: int = 0, eva_chunk: int = 0,
+              k_pooled=None, v_pooled=None):
     """What a model's attention layer calls.  Each of q, k, v is rank 4,
     (B, H, S, D), or rank 3, (B, S, H * D) as its projection wrote it (or a
     ``HeadColumns`` of a wider array); a rank-3 q comes with ``head_dim``.
@@ -1518,8 +1759,10 @@ def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
     the operands' ranks.  The mask's parameters are as ``flash_attention`` and
     ``mha_reference`` take them
     (``diffusion_block`` > 0: ``block_diffusion_mask`` in place of the causal
-    mask).  Which implementation runs is decided here and nowhere else:
-    ``impl`` is a config's ``attention_impl`` — "reference", "ring" or "flash",
+    mask; ``eva_window`` > 0 with ``eva_chunk`` and the summaries ``k_pooled``,
+    ``v_pooled``: ``eva_mask``, and a row of at most one window is the causal
+    call, the summaries unread).  Which implementation runs is decided here
+    and nowhere else: ``impl`` is a config's ``attention_impl`` — "reference", "ring" or "flash",
     and "flash" under an ambient mesh that shards the sequence
     (``ring_axis`` > 1) is the ring, since the kernels want the sequence whole.
     The kernels read rank-3 operands and write the result where they lie
@@ -1532,8 +1775,11 @@ def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
         and mesh.shape.get(ring_axis, 1) > 1
     if sharded:
         impl = "ring"
+    heads = _heads_in(q, head_dim)
+    eva_window, eva_chunk = _eva(
+        eva_window, eva_chunk, _seen(*_operand(k, heads))[0]) or (0, 0)
     asked = {"window": bool(window), "diffusion_block": bool(diffusion_block),
-             "k_shared": k_shared is not None}
+             "k_shared": k_shared is not None, "eva_window": bool(eva_window)}
     for name, (impls, words) in _REFUSED.items():
         if asked[name] and impl in impls:
             raise NotImplementedError(
@@ -1541,20 +1787,27 @@ def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
                     f" (\"flash\" over a sequence sharded on {ring_axis!r})"
                     if sharded else "") + f" has no {words}")
     if impl == "flash":
-        # the scope tells a window's calls from the full layers' in a trace
-        with jax.named_scope("window") if window else contextlib.nullcontext():
+        # the scope tells a window's calls, and an EVA layer's, from the full
+        # layers' in a trace
+        scope = "window" if window else "eva" if eva_window else None
+        with jax.named_scope(scope) if scope else contextlib.nullcontext():
             return flash_attention(
                 q, k, v, causal=causal, sm_scale=sm_scale,
                 diffusion_block=diffusion_block, window=window,
-                k_shared=k_shared, head_dim=head_dim, tokens_out=True)
-    heads = _heads_in(q, head_dim)
+                k_shared=k_shared, head_dim=head_dim, tokens_out=True,
+                **(dict(eva_window=eva_window, eva_chunk=eva_chunk,
+                        k_pooled=k_pooled, v_pooled=v_pooled)
+                   if eva_window else {}))
     q, k, v = (_to_heads(*_operand(x, heads), heads) for x in (q, k, v))
     if impl == "reference":
         mask = block_diffusion_mask(q.shape[2] // 2, diffusion_block) \
             if diffusion_block else None
+        eva = dict(eva=(eva_window, eva_chunk), pooled=tuple(
+            _pooled_as(x, heads, x.shape[1 if x.ndim == 3 else 2], False)
+            for x in (k_pooled, v_pooled))) if eva_window else {}
         return _to_tokens(mha_reference(
             q, k, v, causal=causal, sm_scale=sm_scale, mask=mask,
-            window=window, k_shared=k_shared))
+            window=window, k_shared=k_shared, **eva))
     if impl == "ring":
         return _to_tokens(ring_attention_sharded(
             q, k, v, causal=causal, sm_scale=sm_scale, seq_axis=ring_axis))

@@ -74,6 +74,8 @@ def llama_partition_rules() -> PartitionRules:
         # the gate on the attention's output: a column a query head
         (r"attn/wg/kernel", _spec("fsdp", "tp")),
         (r"attn/wo/kernel", _spec("tp", "fsdp")),
+        # EVA's pooling vectors, (heads, head_dim): a row a head
+        (r"attn/(phi|mu)$", _spec("tp", None)),
         # latent attention: the down-projection to the latent and the shared
         # rotary key belongs to no head; the up-projection's columns are the
         # heads' (kv_norm's scale: replicated, below)

@@ -41,6 +41,11 @@ channels in plain XLA, 32 / 8 heads 64 wide with the per-head norm and RoPE, 8
 of 64 experts of 1536 held, chosen through a selection bias, 8,192 vocabulary
 rows tied, two rows of 16,384, remat): the flash kernels at 64 lanes under
 RoPE and the grouped matmuls at the 1536-wide shape, beside 7.5 GB of state.
+And the one-chip step of EvaByte (4096 wide, four of 32 layers, 32
+heads of 128 under EVA's mask — a window's own causal tiles and the summaries
+of the windows before as a second key / value operand of both flash kernels —
+SwiGLU 11,008 wide, eight heads over 320 ids, a float32 residual stream, one
+row of 16,384, remat), beside 13.15 GB of state.
 And the attention prelude alone (projection, heads, the per-head norm
 where there is one, the rotation, and their backward) at SDAR's and at
 Laguna's full layers' shapes: the bytes the compiled program moves over the
@@ -152,6 +157,14 @@ def _build(case: str, compile_: bool) -> dict:
         assert (config.head_dim, config.qk_norm, config.conv_width) == (
             64, "head", 3)
         assert config.router_selection_bias and seq == 16384
+    elif case == "evabyte":
+        mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+        (config, seq), rows = _cell("evabyte-eva-1chip"), 1
+        assert (config.eva_window, config.eva_chunk) == (2048, 16)
+        assert (config.n_layer, config.n_head, config.n_kv_head) == (4, 32, 32)
+        assert config.n_pred_heads == 8 and config.vocab_size == 320
+        assert config.norm_unit_offset and config.residual_dtype == jnp.float32
+        assert seq == 16384
     elif case == "granite":
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
         (config, seq), rows = _cell("granite-h-s8k-1chip"), 1
@@ -633,6 +646,38 @@ def test_lfm2_step_compiles_and_fits_the_chip():
     # sparse layer's held experts: twelve grouped-matmul calls and the two
     # that add rows into tokens, as Kimi's: 2 + 4 x (12 + 2)
     assert row["tpu_custom_calls"] == 2 + 4 * (12 + 2), row
+    assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
+
+
+def test_evabyte_step_lowers_for_one_v5e_chip():
+    """Tier-1: the one-chip step of EvaByte at published widths (four layers,
+    32 heads of 128 under EVA's mask with windows of 2,048 and chunks of 16,
+    eight heads over 320 ids, one row of 16,384) lowers for the TPU with its
+    Mosaic kernels in it: the flash pair of each layer, the summaries a
+    second key / value operand of both, and the pooling's pair
+    (``ops/pooling.py``), and no other."""
+    row = _child(["evabyte"], compile_=False)["evabyte"]
+    kernels = row["lowered_kernels"]
+    assert set(kernels) == {"flash_fwd", "flash_bwd", "pool_fwd",
+                            "pool_bwd"}, kernels
+    assert kernels["flash_fwd"] == kernels["flash_bwd"] == 4, kernels
+    assert row["flash_fwd_calls"] == 4, row
+
+
+@pytest.mark.slow
+def test_evabyte_step_compiles_and_fits_the_chip():
+    """The TPU compiler takes the step — both kernels with the summaries'
+    tiles on their grids, dQ's accumulator spanning 16,384 queries — and its
+    memory analysis says four layers fit one chip at one row of 16,384 beside
+    13.15 GB of state (PR 47: 9.86 GB of arguments + 4.81 GB of temporaries;
+    see PERF.md)."""
+    row = _child(["evabyte"], compile_=True)["evabyte"]
+    print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
+    assert "refused" not in row, row
+    # a layer: flash forward and the backward's one kernel, no second
+    # forward under remat; the pooling forward, again under remat, and its
+    # backward
+    assert row["tpu_custom_calls"] == 4 * (2 + 3), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
