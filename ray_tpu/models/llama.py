@@ -97,7 +97,7 @@ from ray_tpu.models.gpt2 import (mask_vocab_padding, padded_vocab,
                                  remat_block)
 from ray_tpu.models.mamba import (Mamba2Mixer, _conv_init,
                                   gated_short_conv)
-from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
+from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU, silu_mul
 from ray_tpu.ops import pooling
 from ray_tpu.ops.attention import HeadColumns, attention
 from ray_tpu.parallel.sharding import constrain_residual
@@ -618,7 +618,7 @@ class SwiGLU(nn.Module):
         up = nn.Dense(cfg.d_ff, use_bias=False, dtype=cfg.dtype,
                       name="up_proj")(x)
         return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
-                        name="down_proj")(jax.nn.silu(gate) * up)
+                        name="down_proj")(silu_mul(gate, up))
 
 
 ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention")

@@ -699,6 +699,42 @@ def token_spec(mesh):
              tuple(a for a in ("sp", "tp") if a in mesh.shape) or None, None)
 
 
+@jax.custom_vjp
+def silu_mul(gate, up):
+    """``silu(gate) * up``, the middle of a dense SwiGLU, whose backward
+    makes ``dgate`` and ``dup`` once, as arrays, for the two matmuls each
+    feeds."""
+    return jax.nn.silu(gate) * up
+
+
+def _silu_mul_fwd(gate, up):
+    return silu_mul(gate, up), (gate, up)
+
+
+def _silu_mul_bwd(res, g):
+    # Left to itself XLA makes dgate = g * up * silu'(gate) from the three
+    # (tokens, d_ff) arrays in the operand prologue of both of gate_proj's
+    # backward matmuls, and the input gradient's carries the next norm's
+    # backward in its epilogue besides: on one chip that matmul ran at
+    # 1.4-1.6 times up_proj's (PERF.md section 6, PR 49).  Behind the barrier
+    # both gradients are written once, under remat by the recomputed
+    # gate_proj's epilogue, and the four matmuls read them as they read any
+    # operand.  Both, not dgate alone: where the matmuls are loops over a
+    # sharded weight's parts (fsdp) XLA writes dup anyway, and parted from
+    # dgate's pass that cost the four-chip cell 2% of its step.  float32
+    # inside, rounded as the operands were.
+    gate, up = res
+    g, a, b = (t.astype(jnp.float32) for t in (g, gate, up))
+    sig = jax.nn.sigmoid(a)
+    d_gate = g * b * (sig * (1.0 + a * (1.0 - sig)))
+    d_up = g * (a * sig)
+    return jax.lax.optimization_barrier(
+        (d_gate.astype(gate.dtype), d_up.astype(up.dtype)))
+
+
+silu_mul.defvjp(_silu_mul_fwd, _silu_mul_bwd)
+
+
 class SharedSwiGLU(nn.Module):
     """The expert every token passes: a dense SwiGLU."""
 
@@ -711,8 +747,8 @@ class SharedSwiGLU(nn.Module):
         def dense(n, name):
             return nn.Dense(n, use_bias=False, dtype=self.dtype, name=name)
         return dense(self.d_model, "down_proj")(
-            jax.nn.silu(dense(self.d_ff, "gate_proj")(x))
-            * dense(self.d_ff, "up_proj")(x))
+            silu_mul(dense(self.d_ff, "gate_proj")(x),
+                     dense(self.d_ff, "up_proj")(x)))
 
 
 class RoutedSwiGLU(nn.Module):

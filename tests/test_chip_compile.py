@@ -55,6 +55,11 @@ And an attention layer's layout alone: what stands between the projections
 and the flash kernels at GPT-2's shape and on a Llama layer's ``v`` / ``out``
 path, forward and backward, where a copy of an operand would show.
 
+And two of a cell's blocks under remat at Mistral's and at EvaByte's width and
+tokens a step: how many (tokens, d_ff) operands each backward matmul of
+``gate_proj`` and ``up_proj`` reads, where ``dgate`` made twice in a prologue
+would show.
+
 Every case runs in a subprocess (this file, as a script): the libtpu client
 must never meet the forced-CPU test process, and the child must NOT inherit
 the request for the Pallas interpreter.
@@ -124,6 +129,8 @@ def _build(case: str, compile_: bool) -> dict:
         return _build_prelude(case, topo.devices[0])
     if case in LAYOUTS:
         return _build_layout(case, topo.devices[0])
+    if case in MLP_BLOCKS:
+        return _build_mlp_block(case, topo.devices[0])
     if case.startswith("olmoe_b"):
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
         (config, seq), rows = _cell("olmoe-s4k-1chip"), \
@@ -357,6 +364,77 @@ def _build_layout(case: str, device) -> dict:
                 if np.prod([int(n) for n in dims.split(",")]) >= B * S * E]}
 
 
+# name -> the cell whose layers the case compiles two of
+MLP_BLOCKS = {
+    "mlp_block_mistral": "mistral-s8k-1chip",
+    "mlp_block_evabyte": "evabyte-eva-1chip",
+}
+
+
+def _build_mlp_block(case: str, device) -> dict:
+    """In the child: two of the cell's ``LlamaBlock`` under remat as the
+    model stacks them (norm, projections, flash attention, residual, norm,
+    SwiGLU, residual) at the cell's width and tokens a step — Mistral's
+    8192 x 4096 x 14336 in a bf16 stream, EvaByte's 16384 x 4096 x 11008 in a
+    float32 one — forward and backward, compiled for one chip.  Of the
+    optimized HLO's backward fusions (the recomputation aside) under
+    ``mlp/gate_proj`` and ``mlp/up_proj``: what each writes, how many of its
+    operands are (tokens, d_ff) arrays, and the compiler's estimate of its
+    cycles."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.models.gpt2 import remat_block
+    from ray_tpu.models.llama import LlamaBlock
+
+    cfg, seq = _cell(MLP_BLOCKS[case])
+    stream = cfg.residual_dtype or cfg.dtype
+    block = remat_block(LlamaBlock, cfg.remat_policy)(cfg, name="h_0")
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(device))
+
+    x, positions = shape((1, seq, cfg.d_model), stream), jnp.arange(seq)
+    params = jax.tree.map(
+        lambda a: shape(a.shape, a.dtype),
+        jax.eval_shape(block.init, jax.random.PRNGKey(0), x, positions))
+
+    def both_ways(first, second, x, g):
+        out, vjp = jax.vjp(lambda first, second, x: block.apply(
+            second, block.apply(first, x, positions), positions),
+            first, second, x)
+        return out, vjp(g)
+
+    text = jax.jit(both_ways).lower(params, params, x, x).compile().as_text()
+
+    def elements(shapes: str):
+        return [int(np.prod([int(n) for n in dims.split(",") if n] or [1]))
+                for dims in re.findall(r"\w+\[([\d,]*)\]", shapes)]
+
+    written = {m.group(1): elements(m.group(2)) for m in re.finditer(
+        r"^\s*(?:ROOT )?(%[\w.\-]+) = (.*?) [\w\-]+\(", text, re.M)}
+    fusions = []
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?%[\w.\-]+ = (.*?) fusion\((.*?)\), kind=.*"
+            r"op_name=\"[^\"]*transpose\(jvp([^\"]*)/mlp/(gate_proj|up_proj)/"
+            r".*\"estimated_cycles\":\"(\d+)\"", text, re.M):
+        if "rematted_computation" in m.group(3):
+            continue
+        fusions.append({
+            "under": m.group(4), "writes": elements(m.group(1)),
+            "wide_operands": sum(
+                n == seq * cfg.d_ff for name in re.findall(
+                    r"%[\w.\-]+", m.group(2)) for n in written.get(name, [])),
+            "cycles": int(m.group(5))})
+    return {"case": case, "dx_elements": seq * cfg.d_model,
+            "fusions": fusions}
+
+
 def _child(cases, compile_: bool) -> dict:
     env = {k: v for k, v in os.environ.items()
            if k not in ("RAY_TPU_PALLAS_INTERPRET", "XLA_FLAGS")}
@@ -431,6 +509,37 @@ def test_attention_layout_moves_no_copy_of_an_operand():
         assert row["operand_sized_copies"] == [], row
     assert rows["layout_gpt2"]["gigabytes"] < 1.55, rows
     assert rows["layout_llama_v_out"]["gigabytes"] < 2.8, rows
+
+
+@pytest.mark.parametrize("case", sorted(MLP_BLOCKS))
+def test_gate_projs_backward_reads_dgate_as_an_array(case):
+    """Tier-1, a quarter of a minute a shape (PR 49): behind
+    ``models/moe.py::silu_mul``'s rule the backward matmuls of ``gate_proj``
+    and ``up_proj`` read ``dgate`` and ``dup`` as one (tokens, d_ff) operand
+    each.  Left to autodiff ``gate_proj``'s two made ``dgate`` from three —
+    the cotangent, ``up`` and ``gate`` — in their operand prologues, the
+    input gradient's under an epilogue that carries the first half of
+    ``mlp_norm``'s backward, and the chip ran the pair at 1.4-1.6 times
+    ``up_proj``'s (PERF.md section 6, PR 49).  The compiler's own estimate
+    for that fusion stood at 1.9 times ``up_proj``'s input gradient at
+    Mistral's shape and 2.1 at EvaByte's; it is under it now (the norm's
+    epilogue rides whichever of the two comes last)."""
+    row = _child([case], compile_=True)[case]
+    print(row)
+    of = lambda under: [f for f in row["fusions"]  # noqa: E731
+                        if f["under"] == under]
+    # a block: the weight's gradient and the input's, for each projection
+    assert len(of("gate_proj")) == len(of("up_proj")) == 4, row
+    for f in row["fusions"]:
+        assert f["wide_operands"] == 1, f
+
+    def dx_cycles(under):
+        found = [f["cycles"] for f in of(under)
+                 if row["dx_elements"] in f["writes"]]
+        assert len(found) == 2, (under, row)
+        return sum(found)
+
+    assert dx_cycles("gate_proj") < 1.15 * dx_cycles("up_proj"), row
 
 
 @pytest.mark.slow

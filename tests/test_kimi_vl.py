@@ -289,7 +289,8 @@ def test_d_lagunas_step_is_the_parents(flash_names_off):
     rotation of q and k is ``apply_rope``'s one pass, the kernels' calls as
     they were; since PR 42 that PR's: the kernels' grid is (batch, heads,
     tiles, tiles) and their output (B, S, H * D), the gate widened along
-    the lanes)."""
+    the lanes; since PR 49 that PR's: the dense layer's and the shared
+    expert's ``silu * up`` go through ``models/moe.py::silu_mul``)."""
     from ray_tpu.models.pretrain import make_optimizer, sharded_train_step
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
@@ -302,7 +303,7 @@ def test_d_lagunas_step_is_the_parents(flash_names_off):
     with jax.set_mesh(mesh):
         text = s.step.trace(s.state, batch).lower().as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        "7678e709a2b001223ac057c92958b0fe2c106ba3690ad555174f4e909d9db439"
+        "120dd8f5badc1fd9945d40fe305fabfab403128cae91bf1986ae51e14e9ad38b"
 
 
 # ------------------------------------------------- (e) on a virtual mesh

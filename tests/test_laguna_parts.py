@@ -165,11 +165,15 @@ def test_c_the_shares_add_up_to_the_uncut_layer():
 # are PR 42's: the flash kernels' grid is (batch, heads, tiles, tiles), the
 # output leaves them as (B, S, H * D) and the models no longer transpose it.
 # ``toy-granite`` is PR 43's: its scan is the interpreted body of
-# ``ops/ssd.py``'s two kernels; the three others are PR 42's still.)
+# ``ops/ssd.py``'s two kernels; the three others are PR 42's still.
+# ``toy-llama`` and ``toy-granite``, the two with a dense SwiGLU, are PR 49's:
+# ``silu(gate) * up`` goes through ``models/moe.py::silu_mul``, whose backward
+# writes ``dgate`` and ``dup`` behind an optimization barrier; ``toy-olmoe`` and
+# ``toy-sdar``, which have none, did not move.)
 _PARENT_STEPS = {
-    "toy-llama": "114e35a37f363ab0f8c29d7948dfae1ee6cc766feb0bba3985842656d0b70ce6",
+    "toy-llama": "060fa7052c1f3df22c11c764760f272fa1218e8d76190f1ab8d7ad5da08f9993",
     "toy-olmoe": "41054e8f510af2a7d7be4f320326eb4237e4c1efc006d16bd81fbd3d9bea858f",
-    "toy-granite": "d7e06ee2ed4d884dfe315afc1e0cb2b69061aee5d1cdac08c012a96c046fe00e",
+    "toy-granite": "eafbb4378fe3e623ff9c1bb4e36163c87bfd94fe07b3c81c3e62c60d6384aba6",
     "toy-sdar": "222b0bf1f1a77b9f7b59a06a8e8731dd8c05cc2521c078cde46cd291fa36d872",
 }
 

@@ -432,7 +432,9 @@ def test_f_kimi_vls_step_is_the_parents(flash_names_off):
     ``RoutedSwiGLU`` with sigmoid scores and ``norm_topk_prob``, so the
     selection's and the renormalisation's new branches are what it holds
     still.  (Since PR 42 the hash is that PR's: the kernels read each head's
-    key part and values where ``wukv`` wrote them and write (B, S, H * Dv).)"""
+    key part and values where ``wukv`` wrote them and write (B, S, H * Dv).
+    Since PR 49 that PR's: the dense layer's and the shared expert's ``silu *
+    up`` go through ``models/moe.py::silu_mul``.)"""
     from ray_tpu.models.pretrain import make_optimizer, sharded_train_step
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
@@ -445,4 +447,4 @@ def test_f_kimi_vls_step_is_the_parents(flash_names_off):
     with jax.set_mesh(mesh):
         text = s.step.trace(s.state, batch).lower().as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        "c815cb0c9ef10968bb14cc90d0ea62fb2394397fdfca6f6d91348983453b2e60"
+        "5a017906206e0d13c1f718b10c38f6a80c6893aafaa08d252665c2a7a745d148"
