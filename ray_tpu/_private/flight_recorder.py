@@ -42,15 +42,17 @@ written, with their own stamps, by :func:`init_process`.
 
 from __future__ import annotations
 
+import collections
 import contextlib
 import glob
 import math
 import mmap
 import os
+import statistics
 import struct
 import threading
 import time
-from typing import Dict, Iterator, List, Optional, Tuple
+from typing import Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 FILE_MAGIC = b"RTFR"
 VERSION = 1
@@ -166,11 +168,13 @@ def record(kind: str, detail: str = "", ts: Optional[float] = None) -> None:
         _m_records.inc(1, {"kind": kind})
 
 
-def mark(kind: str, seconds: float, detail: str = "") -> None:
-    """One timed record: an interval of ``seconds`` that ends now, the
-    duration first in the detail.  Before this process has a ring the mark
-    waits (see the module docstring)."""
-    ts = time.time()
+def mark(kind: str, seconds: float, detail: str = "",
+         ts: Optional[float] = None) -> None:
+    """One timed record: an interval of ``seconds`` that ends at ``ts``
+    (default: now), the duration first in the detail.  Before this process
+    has a ring the mark waits (see the module docstring)."""
+    if ts is None:
+        ts = time.time()
     detail = f"{seconds:.6f}|{detail}" if detail else f"{seconds:.6f}"
     if _mm is None:
         if len(_pending) < _PENDING_MAX:
@@ -257,6 +261,25 @@ def harvest_for(session_dir: str, name: str,
     return harvest(ring_path(session_dir, name), limit)
 
 
+def _timed_records(session_dir: str, kinds: Tuple[str, ...]
+                   ) -> Iterator[Tuple[str, str, float, float, str]]:
+    """Every record of every ring of ``session_dir`` whose kind starts with
+    one of ``kinds``, as ``(process, kind, start, end, rest of the detail)``:
+    a leading duration is taken off the detail and back from the stamp; a
+    record without one is a point."""
+    for path in glob.glob(ring_path(session_dir, "*")):
+        name = os.path.basename(path)[:-len(".ring")]
+        for r in harvest(path):
+            if not r["kind"].startswith(kinds):
+                continue
+            head, _, rest = r["detail"].partition("|")
+            try:
+                secs, detail = float(head), rest
+            except ValueError:
+                secs, detail = 0.0, r["detail"]
+            yield name, r["kind"], r["ts"] - secs, r["ts"], detail
+
+
 def bringup_gap(marks: List[Mark]) -> Optional[float]:
     """Seconds between the start of the first ``bringup.*`` mark and the last
     ``train_fn_enter`` that no ``bringup.*`` mark of any process covers;
@@ -283,18 +306,189 @@ def bringup_timeline(session_dir: str
     ring, ordered by start on the one clock, and :func:`bringup_gap` of
     them.  A record without a leading duration (``compile.cache|hit``) is a
     point."""
-    marks: List[Mark] = []
-    for path in glob.glob(ring_path(session_dir, "*")):
-        name = os.path.basename(path)[:-len(".ring")]
-        for r in harvest(path):
-            kind = r["kind"]
-            if not kind.startswith(("bringup.", "compile")):
-                continue
-            head, _, rest = r["detail"].partition("|")
-            try:
-                secs, detail = float(head), rest
-            except ValueError:
-                secs, detail = 0.0, r["detail"]
-            marks.append((name, kind, r["ts"] - secs, r["ts"], detail))
-    marks.sort(key=lambda m: (m[2], m[3]))
+    marks: List[Mark] = sorted(
+        _timed_records(session_dir, ("bringup.", "compile")),
+        key=lambda m: (m[2], m[3]))
     return marks, bringup_gap(marks)
+
+
+# --- the steady state: spans without a profiler session, rounds ------------
+ROUNDS = "train.rounds"                 # a train worker's report rounds
+STALL = "train.stall"                   # one stalled round of them, alone
+DRIVER_ROUNDS = "train.driver_rounds"   # the trainer's side of the rounds
+_ROUNDS_SUM_S = 0.5     # rounds are summed into one record until this passed
+_STALL_MIN_S = 0.25     # a shorter round is never a stalled one
+_JUDGED_OVER, _JUDGED_AFTER = 64, 8     # rounds the median is of / needs
+
+_spans = threading.local()
+
+
+def add_span(name: str, seconds: float) -> None:
+    """``seconds`` and one count onto ``name`` in this thread's table."""
+    try:
+        table = _spans.table
+    except AttributeError:
+        table = _spans.table = {}
+    try:
+        entry = table[name]
+        entry[0] += seconds
+        entry[1] += 1
+    except KeyError:
+        table[name] = [seconds, 1]
+
+
+def take_spans() -> Dict[str, List[float]]:
+    """This thread's table, ``name -> [seconds, count]`` since the last take,
+    and an empty one in its place."""
+    table, _spans.table = getattr(_spans, "table", {}), {}
+    return table
+
+
+def round_detail(counts: Dict[str, float], seconds: Dict[str, float]) -> str:
+    """``k=v ...;name=seconds ...`` in what ``MAX_PAYLOAD`` leaves after the
+    kind and the mark's duration: the largest seconds first, and what does
+    not fit left out."""
+    tokens = [" ".join(f"{k}={v}" if isinstance(v, int) else f"{k}={v:.6f}"
+                       for k, v in counts.items()) + ";"]
+    room = MAX_PAYLOAD - 48 - len(tokens[0].encode())
+    for name, secs in sorted(seconds.items(), key=lambda kv: -kv[1]):
+        token = f"{name}={secs:.6f}"
+        room -= len(token.encode()) + 1
+        if room < 0:
+            break
+        tokens.append(token)
+    return tokens[0] + " ".join(tokens[1:])
+
+
+def _parse_round(detail: str) -> Tuple[Dict[str, float], Dict[str, float]]:
+    def values(part: str) -> Dict[str, float]:
+        found: Dict[str, float] = {}
+        for token in part.split():
+            name, _, value = token.rpartition("=")
+            try:
+                found[name] = float(value)
+            except ValueError:
+                continue    # a record cut at MAX_PAYLOAD ends in half a token
+        return found
+
+    counts, _, seconds = detail.partition(";")
+    return values(counts), values(seconds)
+
+
+class RoundLog:
+    """The rounds of one loop as timed marks of ``kind``.
+
+    The ring is a budget (a worker's also holds its start, which is read
+    after the run): consecutive rounds are summed into one record until
+    ``_ROUNDS_SUM_S`` have passed, so a loop whose round is 59 ms writes two
+    records a second and not seventeen.  A record's detail is
+    ``rounds=<n> [steps=<n> ...] longest=<s>;<name>=<s> ...``: what it
+    covers, its longest round, and the seconds by where they went.
+
+    Rounds are judged by the step, since a loop may report every step while
+    it warms up and every eighth after: with ``m`` the median of seconds a
+    step over the last 64 rounds (none is judged before 8 are in), a round of
+    ``n`` steps is *stalled* when it ran at least ``_STALL_MIN_S`` and at
+    least half of ``n * m`` over ``n * m``.  A stalled round is written at
+    once, as a record of its own.  So is a round without a step in a loop
+    that makes steps (its last report, an evaluation): there is no ``m`` to
+    hold it to, and it is not judged.  A loop that never counts a step is
+    judged as one step a round.
+    """
+
+    def __init__(self, kind: str):
+        self.kind = kind
+        self._per_step = collections.deque(maxlen=_JUDGED_OVER)
+        self._stepped = False   # a round of this loop has had a step
+        self._counts: Dict[str, float] = {}
+        self._seconds: Dict[str, float] = {}
+        self._summed = 0.0
+        self._ended = 0.0       # time.time() at the last summed round's end
+
+    def close(self, seconds: float, by: Dict[str, float], steps: int = 0,
+              **counts: int) -> Optional[float]:
+        """One round: ``seconds`` long, ``by`` saying where they went,
+        ``steps`` training steps in it and ``counts`` of what happened in
+        it.  Returns ``n * m`` where the round is stalled, else ``None``."""
+        self._stepped = self._stepped or steps > 0
+        judged = steps > 0 or not self._stepped
+        n = max(1, steps)
+        expected = None
+        if judged:
+            if seconds >= _STALL_MIN_S \
+                    and len(self._per_step) >= _JUDGED_AFTER:
+                usual = n * statistics.median(self._per_step)
+                if seconds - usual >= 0.5 * usual:
+                    expected = usual
+            self._per_step.append(seconds / n)
+        alone = expected is not None or not judged
+        if alone:
+            self.flush()
+        mine = self._counts
+        mine["rounds"] = mine.get("rounds", 0) + 1
+        if steps:
+            counts["steps"] = steps
+        for name, value in counts.items():
+            mine[name] = mine.get(name, 0) + value
+        mine["longest"] = max(mine.get("longest", 0.0), float(seconds))
+        for name, value in by.items():
+            self._seconds[name] = self._seconds.get(name, 0.0) + value
+        self._summed += seconds
+        self._ended = time.time()
+        if alone or self._summed >= _ROUNDS_SUM_S:
+            self.flush()
+        return expected
+
+    def flush(self) -> None:
+        """Write what is summed, if anything, as one mark that ends where its
+        last round did."""
+        if not self._counts:
+            return
+        mark(self.kind, self._summed,
+             round_detail(self._counts, self._seconds), self._ended)
+        self._counts, self._seconds, self._summed = {}, {}, 0.0
+
+
+class Round(NamedTuple):
+    """One ``train.rounds`` / ``train.stall`` / ``train.driver_rounds``
+    record read back: ``counts`` is the detail before the ``;``, ``seconds``
+    the part after it; a stall's ``driver`` is the driver's record that
+    overlaps it longest."""
+    process: str
+    kind: str
+    start: float
+    end: float
+    counts: Dict[str, float]
+    seconds: Dict[str, float]
+    driver: Optional["Round"] = None
+
+    def each(self) -> List[float]:
+        """The seconds of each round the record sums, as far as it says: the
+        longest, and the others at their mean."""
+        n, longest = int(self.counts.get("rounds", 1)), self.counts.get(
+            "longest", self.end - self.start)
+        if n <= 1:
+            return [self.end - self.start]
+        return [longest] + [(self.end - self.start - longest) / (n - 1)] * (
+            n - 1)
+
+
+def round_timeline(session_dir: str) -> List[Round]:
+    """A job's steady state as the processes of ``session_dir`` wrote it:
+    the train workers' round records and stalls and the driver's round
+    records, ordered by start on the one clock — :func:`bringup_timeline`'s
+    sibling for what comes after ``train_fn_enter``."""
+    rounds = sorted(
+        (Round(name, kind, start, end, *_parse_round(detail))
+         for name, kind, start, end, detail in _timed_records(
+             session_dir, (ROUNDS, STALL, DRIVER_ROUNDS))),
+        key=lambda r: (r.start, r.end))
+    drivers = [r for r in rounds if r.kind == DRIVER_ROUNDS]
+
+    def beside(stall: Round) -> Optional[Round]:
+        overlap = lambda d: min(stall.end, d.end) - max(stall.start, d.start)  # noqa: E731
+        best = max(drivers, key=overlap, default=None)
+        return best if best is not None and overlap(best) > 0 else None
+
+    return [r._replace(driver=beside(r)) if r.kind == STALL else r
+            for r in rounds]

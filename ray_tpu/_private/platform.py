@@ -128,6 +128,10 @@ def _on_compile_seconds(event: str, seconds: float, **kw) -> None:
     if not event.startswith(_COMPILE_EVENTS):
         return
     stage = event.rsplit("/", 1)[-1]
+    if flight_recorder.RECORDING and event.startswith(_COMPILE_EVENTS[0]):
+        # trace, lowering and build-or-load of the thread that builds, the
+        # short ones too: a round's ``compile`` (_TrainSession.report)
+        flight_recorder.add_span("compile", seconds)
     cache = None
     if stage == _BUILT:     # taken whatever the build's length: it is this one's
         cache, _cache.state = getattr(_cache, "state", "uncached"), "uncached"

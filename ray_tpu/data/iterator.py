@@ -18,6 +18,7 @@ from typing import Any, Dict, Iterator, List, Optional
 import numpy as np
 
 import ray_tpu
+from ray_tpu._private import flight_recorder
 from ray_tpu.data._metrics import data_metrics
 from ray_tpu.data.block import Block, BlockAccessor, format_batch
 from ray_tpu.util.tracing import profiler_span
@@ -27,14 +28,20 @@ def _observes_wait(iter_batches):
     """A batch iterator that also observes, per yielded batch, how long the
     consumer's ``next()`` spent inside it (``data_iter_wait_seconds``): what
     tells an input-bound loop from a report-bound one with no profiler
-    attached."""
+    attached.  The same seconds go under ``data/next`` into the flight
+    recorder's table of the consuming thread — and into no span of the
+    profiler's trace: the ``data/*`` spans inside it already say what the
+    time was."""
 
     @functools.wraps(iter_batches)
     def observed(*args, **kwargs):
         wait = data_metrics()["iter_wait"]
         t0 = time.perf_counter()
         for batch in iter_batches(*args, **kwargs):
-            wait.observe(time.perf_counter() - t0)
+            waited = time.perf_counter() - t0
+            wait.observe(waited)
+            if flight_recorder.RECORDING:
+                flight_recorder.add_span("data/next", waited)
             yield batch
             t0 = time.perf_counter()
 

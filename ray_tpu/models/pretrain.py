@@ -305,8 +305,7 @@ class ShardedPretrainer:
         profiler session each call is one step of the trace's Steps line,
         with the host's two parts of it — laying the batch out, and the call
         that enqueues the compiled program — as spans under it."""
-        with jax.profiler.StepTraceAnnotation("ray_tpu/step",
-                                              step_num=self._steps), \
+        with profiler_span("step", step_num=self._steps), \
                 jax.set_mesh(self.mesh):
             self._steps += 1
             batch = self.shard_batch(batch)

@@ -7,11 +7,21 @@ train/_internal/training_loop_utils).  Owns the WorkerGroup, runs the backend
 hooks (JaxConfig → jax.distributed bring-up), starts the per-worker sessions,
 and gathers one ``report()`` result per worker per round so the driver sees
 the gang advance in lockstep.
+
+The driver's side of a round goes into the driver's flight recorder (kind
+``train.driver_rounds``, docs/ARCHITECTURE.md 5e): a round is the time from
+one entry of ``get_next_results`` to the next, split into ``skew_probe``
+(``_observe_gang_skew``'s GCS round trips), ``poll`` (a ``session_get_next``
+outstanding on every worker still owed) and ``turnaround`` (the results
+with ``training_loop`` until it asks again).  In ``skew_probe`` and
+``turnaround`` no poll is outstanding: a worker that reports then waits for
+nothing but the driver.
 """
 
 from __future__ import annotations
 
 import collections
+import time
 from typing import Any, Dict, List, Optional
 
 import ray_tpu
@@ -41,6 +51,10 @@ class BackendExecutor:
         self.worker_group: Optional[WorkerGroup] = None
         self._experiment = ""  # heartbeat key space, set by start_training
         self._experiment_label = ""
+        # the rounds' record while the recorder is on, and the open round:
+        # when it was entered and when its results were returned
+        self._rounds: Optional[flight_recorder.RoundLog] = None
+        self._round_open: Optional[tuple] = None
 
     # ---------------------------------------------------------- lifecycle
     def start(self) -> None:
@@ -108,14 +122,19 @@ class BackendExecutor:
         Workers must call report() the same number of times (lockstep
         invariant, same as the reference).
         """
-        import time
-
         assert self.worker_group is not None
         wg = self.worker_group
         results: List[Optional[_TrainingResult]] = [None] * len(wg)
+        entered = time.perf_counter()
+        self._close_round(entered)
+        by = {"skew_probe": 0.0, "poll": 0.0}
+        timeouts = 0
         deadline = time.monotonic() + timeout_s
         while any(r is None for r in results):
+            t0 = time.perf_counter()
             self._observe_gang_skew()
+            t1 = time.perf_counter()
+            by["skew_probe"] += t1 - t0
             if time.monotonic() > deadline:
                 raise TrainingFailedError(
                     f"no report() from workers "
@@ -130,6 +149,8 @@ class BackendExecutor:
                     # actor death OR an executor-side raise both kill the run
                     raise TrainingFailedError(
                         f"train worker {i} failed: {e}", worker_rank=i) from e
+            by["poll"] += time.perf_counter() - t1
+            timeouts += any(r is None for r in results)
             # Surface a captured error IMMEDIATELY: peers of a crashed rank
             # may be blocked in a collective and will never report — waiting
             # for them would stall until the timeout and then mask the real
@@ -139,8 +160,11 @@ class BackendExecutor:
                     raise TrainingFailedError(
                         f"train loop failed on worker {i}:\n{r.error}",
                         worker_rank=i)
+        if flight_recorder.RECORDING:
+            self._round_open = (entered, time.perf_counter(), by, timeouts)
         finals = [r.final for r in results]
         if all(finals):
+            self._close_round(time.perf_counter())
             return None
         if any(finals):
             uneven = [i for i, f in enumerate(finals) if f]
@@ -149,6 +173,19 @@ class BackendExecutor:
                 f"report()ing — all workers must report the same number of "
                 f"times")
         return results  # type: ignore[return-value]
+
+    def _close_round(self, now: float) -> None:
+        """The open round ends at ``now`` (``perf_counter``): the next
+        ``get_next_results`` is entered, or the loops have returned."""
+        if self._round_open is None:
+            return
+        entered, returned, by, timeouts = self._round_open
+        self._round_open = None
+        if self._rounds is None:
+            self._rounds = flight_recorder.RoundLog(
+                flight_recorder.DRIVER_ROUNDS)
+        by["turnaround"] = now - returned
+        self._rounds.close(now - entered, by, timeouts=timeouts)
 
     def _observe_gang_skew(self) -> None:
         """Fold the workers' per-rank step heartbeats (stamped into the GCS
@@ -179,6 +216,8 @@ class BackendExecutor:
             {"experiment": self._experiment_label})
 
     def shutdown(self) -> None:
+        if self._rounds is not None:
+            self._rounds.flush()
         if self.worker_group is None:
             return
         try:

@@ -11,18 +11,36 @@ Checkpoint flow on report: the worker uploads the user's checkpoint dir to
 persistent storage *before* the result crosses the wire (reference:
 train/_internal/storage.py persist_current_checkpoint), so the driver only
 ever sees durable checkpoints.
+
+A *round* is the time since the previous ``report`` returned.  While the
+flight recorder is on, each is closed into it (``flight_recorder.RoundLog``,
+kind ``train.rounds``) with where its seconds went by the loop thread's own
+spans; a stalled one also as ``train.stall`` and one WARNING
+(docs/ARCHITECTURE.md 5e).
 """
 
 from __future__ import annotations
 
-import os
+import gc
+import logging
 import queue
 import threading
-from dataclasses import dataclass, field
+import time
+from dataclasses import dataclass
 from typing import Any, Dict, Optional
 
 from ray_tpu._private import fault_injection, flight_recorder
 from ray_tpu.train._checkpoint import Checkpoint
+from ray_tpu.util.tracing import profiler_span
+
+logger = logging.getLogger(__name__)
+
+HANDOFF_WAIT = "train/report/handoff_wait"
+PERSIST = "train/report/persist"
+# what a round's seconds are split into at the top: the rest is the user's
+# loop, ``float(loss)``'s wait for the device included
+TOP_LEVEL = ("step", "train/report", "data/next")
+_STALL_WARN_EVERY_S = 10.0
 
 _session_lock = threading.Lock()
 _session: Optional["_TrainSession"] = None
@@ -64,6 +82,50 @@ class TrainContext:
         return self.experiment_name
 
 
+class _GcClock:
+    """The seconds this process has spent in garbage collection since it was
+    put into ``gc.callbacks``: a collection stops every thread, whichever
+    thread's allocation began it."""
+
+    def __init__(self):
+        self.seconds = 0.0
+        self._t0: Optional[float] = None
+
+    def __call__(self, phase: str, info: Dict[str, int]) -> None:
+        if phase == "start":
+            self._t0 = time.perf_counter()
+        elif self._t0 is not None:
+            self.seconds += time.perf_counter() - self._t0
+            self._t0 = None
+
+
+def user_loop_seconds(seconds: float, by: Dict[str, float]) -> float:
+    """What of a round no top-level entry covers."""
+    return max(0.0, seconds - sum(by.get(name, 0.0) for name in TOP_LEVEL))
+
+
+def where_it_went(seconds: float, by: Dict[str, float]) -> str:
+    """A round's (or a record's) ``seconds`` by where they went, the largest
+    first: ``handoff_wait 3.28, user loop 2.14 (cpu 0.01), step 0.02``."""
+    parts = {"handoff_wait": by.get(HANDOFF_WAIT, 0.0),
+             "persist": by.get(PERSIST, 0.0), "step": by.get("step", 0.0),
+             "data/next": by.get("data/next", 0.0),
+             "user loop": user_loop_seconds(seconds, by)}
+    return ", ".join(
+        f"{name} {secs:.2f}"
+        + (f" (cpu {by.get('cpu', 0.0):.2f})" if name == "user loop" else "")
+        for name, secs in sorted(parts.items(), key=lambda kv: -kv[1])
+        if secs >= 0.005 or name == "user loop")
+
+
+def stall_line(at: int, steps: int, seconds: float, expected: float,
+               by: Dict[str, float]) -> str:
+    """A stalled round as the one line of the worker's log."""
+    return (f"train round {at} ({steps} steps) took {seconds:.2f} s, "
+            f"{expected:.2f} expected: {where_it_went(seconds, by)}, "
+            f"gc {by.get('gc', 0.0):.2f}, compile {by.get('compile', 0.0):.2f}")
+
+
 @dataclass
 class _TrainingResult:
     """One report() payload from one worker."""
@@ -93,6 +155,11 @@ class _TrainSession:
         # peers sit blocked in the lockstep queue.
         self._step = 0
         self._entered = threading.Event()   # _run reached the train function
+        # the rounds' record, on the loop thread while the recorder is on
+        self._rounds: Optional[flight_recorder.RoundLog] = None
+        self._gc = _GcClock()
+        self._round_from = (0.0, 0.0, 0.0)  # perf_counter, thread CPU, gc
+        self._stall_warned = float("-inf")
         self._thread = threading.Thread(
             target=self._run, args=(train_fn, config), daemon=True,
             name="train-loop")
@@ -113,6 +180,10 @@ class _TrainSession:
         # the end of everything the program does before the user's code
         if flight_recorder.RECORDING:
             flight_recorder.mark("bringup.worker.train_fn_enter", 0.0)
+            self._rounds = flight_recorder.RoundLog(flight_recorder.ROUNDS)
+            flight_recorder.take_spans()    # what the thread did before
+            gc.callbacks.append(self._gc)
+            self._round_from = (time.perf_counter(), time.thread_time(), 0.0)
         self._entered.set()
         try:
             import inspect
@@ -122,21 +193,22 @@ class _TrainSession:
                 train_fn(config)
             else:
                 train_fn()
-            self._result_q.put(_TrainingResult(metrics={}, final=True))
+            final = _TrainingResult(metrics={}, final=True)
         except BaseException:
             import traceback
 
-            self._result_q.put(_TrainingResult(
-                metrics={}, final=True, error=traceback.format_exc()))
+            final = _TrainingResult(
+                metrics={}, final=True, error=traceback.format_exc())
+        if self._rounds is not None:
+            gc.callbacks.remove(self._gc)
+            self._rounds.flush()
+        self._result_q.put(final)
 
     def report(self, metrics: Dict[str, Any],
                checkpoint: Optional[Checkpoint] = None) -> None:
         """Called from the user loop.  Persists the checkpoint, enqueues the
         result, and blocks until the actor thread consumed it."""
-        import time as _time
-
         from ray_tpu.train._metrics import train_metrics
-        from ray_tpu.util.tracing import profiler_span
 
         with profiler_span("train/report"):
             m = train_metrics()
@@ -149,10 +221,9 @@ class _TrainSession:
                 self._stamp_heartbeat()
             persisted = None
             if checkpoint is not None:
-                t0 = _time.perf_counter()
-                with profiler_span("train/report/persist"):
+                with profiler_span(PERSIST, observe=lambda secs: m[
+                        "ckpt_persist"].observe(secs, labels)):
                     persisted = self._persist_checkpoint(checkpoint)
-                m["ckpt_persist"].observe(_time.perf_counter() - t0, labels)
             if fault_injection.ENABLED and fault_injection.hit(
                     "train.report",
                     detail=self.context.experiment_name or "") == "kill":
@@ -161,12 +232,38 @@ class _TrainSession:
                 # persisted dir as durable only once every rank's report
                 # round-tripped
                 fault_injection.kill_self()
-            t0 = _time.perf_counter()
-            with profiler_span("train/report/handoff_wait"):
+            with profiler_span(HANDOFF_WAIT, observe=lambda secs: m[
+                    "report_wait"].observe(secs, labels)):
                 self._result_q.put(_TrainingResult(dict(metrics), persisted))
                 # lockstep with the driver (reference :403)
                 self._consumed.acquire()
-            m["report_wait"].observe(_time.perf_counter() - t0, labels)
+        if self._rounds is not None:
+            self._close_round()
+
+    def _close_round(self) -> None:
+        """The round that this ``report`` ends, into the flight recorder: its
+        seconds by the loop thread's spans (``data/next`` and ``compile``
+        among them), the thread's CPU seconds and the process's seconds of
+        garbage collection."""
+        now, cpu, collected = (time.perf_counter(), time.thread_time(),
+                               self._gc.seconds)
+        t0, cpu0, collected0 = self._round_from
+        self._round_from = (now, cpu, collected)
+        spans = flight_recorder.take_spans()
+        by = {name: entry[0] for name, entry in spans.items()}
+        by["cpu"], by["gc"] = cpu - cpu0, collected - collected0
+        steps = spans["step"][1] if "step" in spans else 0
+        seconds = now - t0
+        expected = self._rounds.close(seconds, by, steps=steps)
+        if expected is None:
+            return
+        flight_recorder.mark(flight_recorder.STALL, seconds, flight_recorder.
+                             round_detail({"at": self._step, "steps": steps,
+                                           "expected": expected}, by))
+        if now - self._stall_warned >= _STALL_WARN_EVERY_S:
+            self._stall_warned = now
+            logger.warning(stall_line(self._step, steps, seconds, expected,
+                                      by))
 
     def _stamp_heartbeat(self) -> None:
         """Per-rank step heartbeat into gang state (GCS KV, fire-and-forget):
@@ -174,7 +271,6 @@ class _TrainSession:
         ray_tpu_train_gang_step_skew gauge, so a straggling rank is visible
         WHILE its peers block — lockstep results alone can't show skew."""
         import json
-        import time as _time
 
         from ray_tpu._private import worker as worker_mod
 
@@ -188,7 +284,7 @@ class _TrainSession:
                 "ns": "train",
                 "key": f"train/{exp}/heartbeat/{self.context.world_rank}",
                 "value": json.dumps({"step": self._step,
-                                     "ts": _time.time()}).encode(),
+                                     "ts": time.time()}).encode(),
                 "overwrite": True,
             }))
         except Exception:

@@ -188,6 +188,42 @@ def gang_up_line(marks: List[flight_recorder.Mark]) -> Optional[str]:
             + f" | uncovered {gap:.1f}")
 
 
+def rounds_line(timeline: List[flight_recorder.Round]) -> Optional[str]:
+    """``flight_recorder.round_timeline``'s records as one line: the rounds
+    and steps the workers' loops made, the median round, the record that
+    holds the longest with where its seconds went on the worker's side and
+    in the driver's records beside it, the rounds stalled and the seconds of
+    garbage collection.  ``None`` where no round was recorded."""
+    import statistics
+
+    from ray_tpu.train._session import where_it_went
+
+    records = [r for r in timeline if r.kind == flight_recorder.ROUNDS]
+    if not records:
+        return None
+    total = lambda key: sum(r.counts.get(key, 0) for r in records)  # noqa: E731
+    worst = max(records, key=lambda r: r.counts.get("longest", 0.0))
+    took = worst.end - worst.start
+    beside = dict.fromkeys(("skew_probe", "poll", "turnaround"), 0.0)
+    for d in timeline:
+        shared = min(d.end, worst.end) - max(d.start, worst.start)
+        if d.kind == flight_recorder.DRIVER_ROUNDS and shared > 0:
+            for key in beside:      # its share, by time
+                beside[key] += d.seconds.get(key, 0.0) * shared / (
+                    d.end - d.start)
+    median = statistics.median(s for r in records for s in r.each())
+    return (
+        f"train loop made {total('rounds'):.0f} rounds ({total('steps'):.0f} "
+        f"steps), median {median:.3f} s; longest "
+        f"{worst.counts.get('longest', took):.3f} s, in "
+        f"{worst.counts.get('rounds', 1):.0f} round(s) of {took:.2f} s: "
+        f"{where_it_went(took, worst.seconds)} | driver beside it: "
+        + ", ".join(f"{key} {secs:.2f}" for key, secs in sorted(
+            beside.items(), key=lambda kv: -kv[1]))
+        + f" | stalled {sum(r.kind == flight_recorder.STALL for r in timeline)}"
+        f" | gc {sum(r.seconds.get('gc', 0.0) for r in records):.2f} s")
+
+
 class DataParallelTrainer(BaseTrainer):
     """SPMD function-trainer: same ``train_loop_per_worker`` on every worker
     of the gang (reference: train/data_parallel_trainer.py:25)."""
@@ -287,6 +323,7 @@ class DataParallelTrainer(BaseTrainer):
         finally:
             metrics["gang_workers"].set(0, mlabels)
             executor.shutdown()
+            self._log_rounds(t_loop)
 
         return Result(
             metrics=last_metrics,
@@ -310,6 +347,20 @@ class DataParallelTrainer(BaseTrainer):
             # earlier gangs' marks are not part of this one's
             marks = [m for m in marks if m[3] >= t_loop]
         line = gang_up_line(marks)
+        if line:
+            logger.info(line)
+
+    @staticmethod
+    def _log_rounds(t_loop: float) -> None:
+        """The steady state's one INFO line, ``_log_gang_up``'s sibling for
+        the loop that followed it."""
+        from ray_tpu._private.worker import global_worker_core
+
+        core = global_worker_core()
+        if core is None or not flight_recorder.RECORDING:
+            return
+        line = rounds_line([r for r in flight_recorder.round_timeline(
+            core.session_dir) if r.end >= t_loop])
         if line:
             logger.info(line)
 

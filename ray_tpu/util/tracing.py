@@ -17,9 +17,10 @@ task-event pipeline), so this module adds the two user-visible pieces:
 and the one the runtime's own per-step paths use:
 
 - :func:`profiler_span` — a span in the JAX profiler's trace, on the device
-  trace's clock.  It costs nothing to speak of unless a profiler session is
-  running, so it may sit on a path that runs every step; ``trace_span``
-  (two events through the task-event pipeline and a GCS flush) may not.
+  trace's clock, and its seconds in the flight recorder's table of the
+  thread.  It costs a microsecond or two, so it may sit on a path that runs
+  every step; ``trace_span`` (two events through the task-event pipeline
+  and a GCS flush) may not.
 """
 
 from __future__ import annotations
@@ -29,8 +30,9 @@ import logging
 import sys
 import time
 from contextlib import contextmanager, nullcontext
-from typing import Any, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional
 
+from ray_tpu._private import flight_recorder
 from ray_tpu._private.ids import _fast_unique
 from ray_tpu._private.worker import require_core
 
@@ -95,23 +97,70 @@ def _emit_span_event(core, span: Span, state: str, ts: float,
 _NO_SPAN = nullcontext()    # reusable and reentrant: one for every caller
 
 
-def profiler_span(name: str):
+class _TimedSpan:
+    """A region timed by one ``perf_counter`` pair around the profiler's
+    annotation of it (``None`` where JAX is not loaded): the seconds go into
+    the flight recorder's table of the thread while it records, and to
+    ``observe`` where the caller gave one."""
+
+    __slots__ = ("_name", "_annotation", "_observe", "_t0")
+
+    def __init__(self, name: str, annotation, observe):
+        self._name = name
+        self._annotation = annotation
+        self._observe = observe
+
+    def __enter__(self):
+        if self._annotation is not None:
+            self._annotation.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        seconds = time.perf_counter() - self._t0
+        if flight_recorder.RECORDING:
+            flight_recorder.add_span(self._name, seconds)
+        if self._observe is not None:
+            self._observe(seconds)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        return False
+
+
+def profiler_span(name: str, step_num: Optional[int] = None,
+                  observe: Optional[Callable[[float], None]] = None):
     """``with profiler_span("train/report"):`` — the region as the span
     ``ray_tpu/train/report`` of the JAX profiler's trace, on the thread that
-    runs it and on the clock of the device's operations, nested by ``with``.
+    runs it and on the clock of the device's operations, nested by ``with``;
+    with ``step_num`` it is that step of the trace's Steps line (a
+    ``StepTraceAnnotation``).
 
-    The profiler session is the switch (``jax.profiler.start_trace`` ...
-    ``stop_trace``): without one the annotation is inactive and records
-    nothing.  JAX is never imported from here — a driver or nodelet that has
-    not loaded it gets one shared no-op, and stays without it.  The names are
-    read letter for letter by ``perfbench/harness/readers/program_span.py``.
-    For cluster-level spans that parent tasks and reach the dashboard and
-    OTLP, use :func:`trace_span`; that one is too dear for a per-step path.
+    One site, two sinks.  The profiler session is the first's switch
+    (``jax.profiler.start_trace`` ... ``stop_trace``): without one the
+    annotation is inactive and records nothing.  The flight recorder is the
+    second's (``flight_recorder_bytes``, on by default): while it records,
+    the region's seconds and one count also go under ``name`` into the table
+    of the thread (``flight_recorder.add_span``), from which
+    ``_TrainSession.report`` makes the round records of a run no profiler
+    watches.  ``observe`` is called with the same seconds at the region's
+    end, recorder or not: an always-on histogram's ``observe``.  JAX is
+    never imported from here — a driver or nodelet that has not loaded it
+    gets no annotation, and stays without it.  The names are read letter for
+    letter by ``perfbench/harness/readers/program_span.py``.  For
+    cluster-level spans that parent tasks and reach the dashboard and OTLP,
+    use :func:`trace_span`; that one is too dear for a per-step path.
     """
     profiler = sys.modules.get("jax.profiler")
     if profiler is None:
-        return _NO_SPAN
-    return profiler.TraceAnnotation("ray_tpu/" + name)
+        annotation = None
+    elif step_num is None:
+        annotation = profiler.TraceAnnotation("ray_tpu/" + name)
+    else:
+        annotation = profiler.StepTraceAnnotation("ray_tpu/" + name,
+                                                  step_num=step_num)
+    if flight_recorder.RECORDING or observe is not None:
+        return _TimedSpan(name, annotation, observe)
+    return _NO_SPAN if annotation is None else annotation
 
 
 @contextmanager
