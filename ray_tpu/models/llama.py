@@ -477,14 +477,14 @@ class LlamaAttention(nn.Module):
                     else (jnp.zeros((S, 0), jnp.float32),) * 2
                 q = apply_rope(q, cos, sin, q_scale, cfg.rms_eps)
                 k = apply_rope(k, cos, sin, k_scale, cfg.rms_eps)
-        if KV != H:  # GQA: each kv head serves H/KV query heads
-            rep = H // KV
-            k, v = (a if a.ndim == 4 else heads(a) for a in (k, v))
-            with jax.named_scope("kv_repeat"):
-                k = jnp.repeat(k, rep, axis=1)
-                v = jnp.repeat(v, rep, axis=1)
+        # (GQA: k and v go on with the KV heads their projections gave them;
+        # ``attention`` reads the group from their shapes)
         pooled = None
         if cfg.eva_window:
+            if KV != H:
+                raise NotImplementedError(
+                    "EVA's pooling takes a key/value head a query head "
+                    f"(n_head {H}, n_kv_head {KV})")
             phi = self.param("phi", _pool_init, (H, D))
             mu = self.param("mu", _pool_init, (H, D))
             # (a row of one window sees no summary, and is causal.  The whole

@@ -31,6 +31,20 @@ Greenfield relative to the reference — it has no sequence parallelism anywhere
   matmul and a kernel (``_Tiles``).  The logsumexp and ``delta`` stay a row
   a head.  A rank-3 operand whose head width is no lane tile or half of one
   is turned head-major at the entry, as the models used to do for all.
+- A key-side operand — k, v — keeps the heads its projection gave it: under
+  grouped-query attention ``n_kv`` where q has ``heads``, ``rep = heads /
+  n_kv`` query heads to a key/value head, of either rank, read from the
+  operands' shapes and from nothing else.  The forward's grid walks the
+  query heads and addresses a key-side block at head ``h // rep``; the
+  backward's walks the key/value heads, a tile's dK and dV stay in their
+  float32 accumulators across the whole group and are written once, at
+  ``n_kv`` heads, and dQ's accumulator is the group's (where that does not
+  fit VMEM: a gradient a query head, summed beside the kernel).  No ``(B,
+  heads, S, D)`` copy of K, V, dK or dV exists.  Where the kernels have no
+  grouped form — the pair form of 64-wide heads, ``k_shared``, the EVA mask,
+  a ``tp`` share that is no whole key/value heads — ``flash_attention``
+  copies k and v to the query heads under the scope ``kv_repeat``, as it does
+  for "reference" and "ring", and says so in the log (``_Tiles.rep``).
 - The values may be another width than the scores, and the last dimensions
   of every head's key may be one vector a position that all heads share
   (``flash_attention``'s ``k_shared``: latent attention's rotary key part):
@@ -67,6 +81,7 @@ from __future__ import annotations
 
 import contextlib
 import functools
+import logging
 import os
 from typing import NamedTuple, Optional
 
@@ -79,6 +94,8 @@ from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.parallel.mesh import ambient_mesh
+
+logger = logging.getLogger(__name__)
 
 NEG_INF = -1e30
 # Mosaic tiles the last two dims of every block as (8, 128): every tile edge
@@ -335,6 +352,19 @@ class _Tiles(NamedTuple):
     per: int = 0
     block_s: int = 0
     ns: int = 0
+    # Grouped-query attention (``rep`` > 1): a key/value head serves ``rep``
+    # query heads, and K and V come with the heads their projection gave
+    # them.  The forward's grid walks the query heads and reads a key-side
+    # block at head ``h // rep``.  The backward's walks the key/value heads
+    # (``kv_grid``) and has one more axis, the group's query heads, between
+    # the outer tiles and the walk: dK and dV of a tile stay in their
+    # accumulators across the whole group and are written once, at the
+    # key/value heads, and dQ's accumulator and output block are the
+    # group's.  Where the group's dQ does not fit VMEM
+    # the backward's grid walks the query heads as the forward's does, and
+    # writes a dK and dV a query head (``_flash_backward`` sums them).
+    rep: int = 1
+    kv_grid: bool = False
 
     @classmethod
     def of(cls, s_q, s_k, d, dtype, causal, offset, diag_chunk,
@@ -524,25 +554,45 @@ class _Tiles(NamedTuple):
         half = lax.div(iq, jnp.int32(self.nq // 2))
         return half, iq - half * (self.nq // 2)
 
-    def blocks(self, dims, width: int, cols: Optional[_Cols], index):
+    def head_of(self, h, member, keys: bool):
+        """Grid position -> the head of an operand on the keys' side or on
+        the queries' (``rep``, above; the grid's own where a head has its
+        own key/value head)."""
+        if self.rep == 1:
+            return h
+        if self.kv_grid:
+            return h if keys else h * self.rep + member
+        return lax.div(h, jnp.int32(self.rep)) if keys else h
+
+    def at(self, place):
+        """``place(h, i, j, member)`` as an index map of the grid: (batch,
+        head, outer tile, inner tile), and under ``kv_grid`` (batch,
+        key/value head, outer tile, the group's member, inner tile)."""
+        if self.kv_grid:
+            return lambda b, h, i, member, j: (b, *place(h, i, j, member))
+        return lambda b, h, i, j: (b, *place(h, i, j, None))
+
+    def blocks(self, dims, width: int, cols: Optional[_Cols], index,
+               keys: bool = False):
         """BlockSpec of the grid step's heads' block of an operand ``width``
         lanes a head: ``dims`` the block between the heads and the lanes (a
         tile of the sequence, behind the copies' axis where there is one)
         and ``index(i, j)`` where it is.  ``cols`` None: of a
         ``(b, h / group, [group,] ..., width)`` array, rank 4 as the caller
-        sees it; else of a ``(b, ..., C)`` one."""
+        sees it; else of a ``(b, ..., C)`` one.  ``keys``: the operand's
+        heads are the key/value heads (``head_of``)."""
         g = self.group
         if cols is None:
             lead, zero = ((None, None, g), (0,)) if g > 1 \
                 else ((None, None), ())
-            return pl.BlockSpec(
-                lead + dims + (width,),
-                lambda b, h, i, j: (b, h) + zero + index(i, j) + (0,))
+            return pl.BlockSpec(lead + dims + (width,), self.at(
+                lambda h, i, j, member: (self.head_of(h, member, keys),)
+                + zero + index(i, j) + (0,)))
         lanes = g * width
         first, step = cols.first // lanes, g * cols.stride // lanes
-        return pl.BlockSpec(
-            (None,) + dims + (lanes,),
-            lambda b, h, i, j: (b,) + index(i, j) + (first + h * step,))
+        return pl.BlockSpec((None,) + dims + (lanes,), self.at(
+            lambda h, i, j, member: index(i, j) + (
+                first + self.head_of(h, member, keys) * step,)))
 
     def q_spec(self, width: int, cols, q_is_inner: bool):
         """Of a ``(.., s_q, .)`` operand, following ``tile_of``."""
@@ -554,8 +604,9 @@ class _Tiles(NamedTuple):
         """Of the ``(b, h / group, group, s_q)`` rows of a per-query
         statistic: a row a head, whatever the operands' ranks."""
         return pl.BlockSpec(
-            (None, None, self.group, self.block_q),
-            lambda b, h, i, j: (b, h, 0, self.tile_of(i, j, q_is_inner)[0]))
+            (None, None, self.group, self.block_q), self.at(
+                lambda h, i, j, member: (self.head_of(h, member, False), 0,
+                                         self.tile_of(i, j, q_is_inner)[0])))
 
     def k_specs(self, width: int, cols, q_is_inner: bool):
         """Of a ``(.., s_k, .)`` operand, following ``tile_of``, as a list.
@@ -569,14 +620,15 @@ class _Tiles(NamedTuple):
             return self.tile_of(i, j, q_is_inner)[1]
         if not self.bd:
             return [self.blocks((self.block_k,), width, cols,
-                                 lambda i, j: (at(i, j),))]
+                                 lambda i, j: (at(i, j),), True)]
         if q_is_inner:
             return [self.blocks((2, self.block_k), width, cols,
-                                 lambda i, j: (0, i))]
+                                 lambda i, j: (0, i), True)]
         return [self.blocks((None, self.block_k), width, cols,
-                             lambda i, j: (1, at(i, j))),
+                             lambda i, j: (1, at(i, j)), True),
                 self.blocks((None, self.block_k), width, cols,
-                             lambda i, j: (0, jnp.minimum(i, self.nk - 1)))]
+                             lambda i, j: (0, jnp.minimum(i, self.nk - 1)),
+                             True)]
 
     def shared_spec(self, width: int, q_is_inner: bool):
         """Of a (b, s_k, width) operand that the heads of a batch row share,
@@ -602,19 +654,22 @@ class _Layout(NamedTuple):
     """A call's operands, statically: its heads, and where a rank-3 operand
     keeps them (``_Cols``; None: the operand is rank 4, ``(B, H, S, D)``).
     ``out`` stands for dO as well, and q, k, v for their gradients, which
-    the backward writes with the heads side by side from column 0."""
+    the backward writes with the heads side by side from column 0.  ``heads``
+    are the queries'; k and v have ``heads / rep`` (grouped-query attention:
+    ``_Tiles.rep``), which ``flash_attention`` reads from their shapes."""
     heads: int
     q: Optional[_Cols] = None
     k: Optional[_Cols] = None
     v: Optional[_Cols] = None
     out: Optional[_Cols] = None
+    rep: int = 1
 
     @property
     def group(self) -> int:
         """Heads a grid step: two where a rank-3 operand's heads are half a
         lane tile (``_Tiles``)."""
         return 2 if any(c is not None and c.width % LANES
-                        for c in self[1:]) else 1
+                        for c in self[1:5]) else 1
 
 
 def _seen(x, cols: Optional[_Cols]):
@@ -1038,9 +1093,9 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
     s_k = s_k // 2 if bd else s_k       # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
                   q_offset - k_offset, _FWD_DIAG_CHUNK, block_q, block_k, bd,
-                  window, eva)._replace(group=g)
+                  window, eva)._replace(group=g, rep=lay.rep)
     s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
-    tokens = tuple(c is not None for c in lay[1:])
+    tokens = tuple(c is not None for c in lay[1:5])
     k_spec, *kn_spec = t.k_specs(d_k, lay.k, False)
     v_spec, *vn_spec = t.k_specs(d_v, lay.v, False)
     rows = functools.partial(_rows, group=g)
@@ -1092,12 +1147,18 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
 _MOSAIC_SCOPE_BYTES = 16 << 20
 
 
+# The v5e's VMEM: what a grid step's heads' share of dQ must fit in.
+_VMEM_BYTES = 128 << 20
+
+
 def _bwd_vmem_bytes(s_q_pad: int, d: int, dtype) -> int:
     """The backward kernel's VMEM limit: the default scope for what belongs to
     a tile, and beside it what spans the sequence — dQ's float32 accumulator
     and its output block, which the pipeline holds twice.  (A block's last dim
-    fills whole lanes.)  Past the chip's VMEM — 128 MiB on the v5e, about
-    118,000 queries at d <= 128 in bf16 — the compiler refuses the call."""
+    fills whole lanes.)  ``s_q_pad``: the queries of every head whose dQ a
+    grid step holds — a pair's, a group's (``_Tiles.rep``).  Past the chip's
+    VMEM — 128 MiB on the v5e, about 118,000 queries at d <= 128 in bf16 —
+    the compiler refuses the call."""
     return _MOSAIC_SCOPE_BYTES + s_q_pad * _round_up(d, LANES) * (
         4 + 2 * jnp.dtype(dtype).itemsize)
 
@@ -1119,17 +1180,42 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
     else:
         dq_ref, dk_ref, dv_ref, dq_acc, dk_acc, dv_acc = rest
     ik, step = pl.program_id(2), pl.program_id(3)
+    # what the first and last steps zero and write, whole; below, the names
+    # stand for the part of each that a step's head works on
+    dq_out, dq_all, key_side = dq_ref, dq_acc, (
+        (dk_ref, dk_acc), (dv_ref, dv_acc))
+    if t.kv_grid:
+        # one more axis, the group's query heads: this step's head's rows of
+        # the group's dQ
+        member, step = step, pl.program_id(4)
+        dq_acc = dq_acc.at[member]
     if t.eva:
         iq, _, js, in_window, seen = t.eva_walk(ik, step, True)
     else:
         iq = t.walk(ik, step, True)[0]
-    last_k, last_step = pl.num_programs(2) - 1, pl.num_programs(3) - 1
+    last_k, last_step = pl.num_programs(2) - 1, pl.num_programs(
+        4 if t.kv_grid else 3) - 1
+
+    def first():
+        """A key tile's first step, of its first head."""
+        return jnp.logical_and(member == 0, step == 0) if t.kv_grid \
+            else step == 0
+
+    def last():
+        return jnp.logical_and(member == t.rep - 1, step == last_step) \
+            if t.kv_grid else step == last_step
+
     if t.bd:
         # both copies' tile ik: the clean one takes the walk and the
-        # accumulators, the noised one the own squares of query tile ik
+        # accumulators, the noised one the own squares of query tile ik,
+        # which one head's queries alone reach — or a group's, and then
+        # through accumulators of their own
         (kn_ref, k_ref), (vn_ref, v_ref), (dkn_ref, dk_ref), \
             (dvn_ref, dv_ref) = ((r.at[0], r.at[1])
                                  for r in (k_ref, v_ref, dk_ref, dv_ref))
+        if t.kv_grid:
+            (dkn_acc, dk_acc), (dvn_acc, dv_acc) = (
+                (a.at[0], a.at[1]) for a in (dk_acc, dv_acc))
     q_is, do_is, k_is, v_is = tokens
     heads = list(zip(*(
         _per_head(ref, t.group, rank3) for ref, rank3 in (
@@ -1139,14 +1225,14 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
         + (((dks_ref, False), (dks_acc, False)) if ks_ref is not None
            else ()))))
 
-    @pl.when(jnp.logical_and(ik == 0, step == 0))
+    @pl.when(jnp.logical_and(ik == 0, first()))
     def _():
-        dq_acc[...] = jnp.zeros_like(dq_acc)
+        dq_all[...] = jnp.zeros_like(dq_all)
 
-    @pl.when(step == 0)
+    @pl.when(first())
     def _():
-        dk_acc[...] = jnp.zeros_like(dk_acc)
-        dv_acc[...] = jnp.zeros_like(dv_acc)
+        for _, acc in key_side:
+            acc[...] = jnp.zeros_like(acc)
         if dks_acc is not None:
             dks_acc[...] = jnp.zeros_like(dks_acc)
 
@@ -1167,14 +1253,18 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
             st = _same_block(st, t.bd) if own \
                 else _masked(st, 1, thresh, k_limit, t.bd, below)
             pt = lax.exp(lax.sub(st, lse))      # P^T, zero where masked
-            if own:     # nothing else reaches these keys: no sum over steps
+            if own and t.kv_grid:
+                dvn_acc[ks, :] += _dot(pt.astype(do.dtype), do, _NN)
+            elif own:   # nothing else reaches these keys: no sum over steps
                 dvn_ref[ks, :] = _dot(pt.astype(do.dtype), do, _NN
                                       ).astype(dvn_ref.dtype)
             else:
                 dv_acc[ks, :] += _dot(pt.astype(do.dtype), do, _NN)
             dst = lax.mul(pt, lax.sub(_dot(vr[ks, :], do, _NT),
                                       delta_ref[p:p + 1, qs])).astype(q.dtype)
-            if own:
+            if own and t.kv_grid:
+                dkn_acc[ks, :] += _dot(dst, q, _NN)
+            elif own:
                 dkn_ref[ks, :] = (_dot(dst, q, _NN) * sm_scale
                                   ).astype(dkn_ref.dtype)
             else:
@@ -1210,8 +1300,13 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
         _on_tiles(t, iq, ik, part)
 
     @pl.when(jnp.logical_and(step == last_step, ik < t.nk) if t.eva
-             else step == last_step)
+             else last())
     def _():
+        if t.bd and t.kv_grid:      # both copies' tile, as accumulated
+            (dk_out, dk_all), (dv_out, dv_all) = key_side
+            dk_out[...] = (dk_all[...] * sm_scale).astype(dk_out.dtype)
+            dv_out[...] = dv_all[...].astype(dv_out.dtype)
+            return
         for _, _, _, _, _, dk_ref, dv_ref, _, dk_acc, dv_acc, *shared in heads:
             dk_ref[...] = (dk_acc[...] * sm_scale).astype(dk_ref.dtype)
             dv_ref[...] = dv_acc[...].astype(dv_ref.dtype)
@@ -1219,8 +1314,12 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
                 shared[0][...] = (shared[1][...] * sm_scale
                                   ).astype(shared[0].dtype)
 
-    @pl.when(jnp.logical_and(ik == last_k, step == last_step))
+    @pl.when(jnp.logical_and(ik == last_k, last()))
     def _():
+        if t.kv_grid:
+            for p, dq in enumerate(_per_head(dq_out, t.rep, q_is)):
+                dq[...] = (dq_all[p] * sm_scale).astype(dq_out.dtype)
+            return
         for _, _, _, _, dq_ref, _, _, dq_acc, *_ in heads:
             dq_ref[...] = (dq_acc[...] * sm_scale).astype(dq_ref.dtype)
 
@@ -1243,14 +1342,21 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
     from jax.experimental.pallas import tpu as pltpu
 
     lay = lay or _Layout(q.shape[1])
-    b, h, n = q.shape[0], lay.heads, lay.group
+    b, h, n, rep = q.shape[0], lay.heads, lay.group, lay.rep
     (s_q, d), (s_k, d_k), (_, d_v) = map(_seen, (q, k, v), lay[1:4])
     s_k = s_k // 2 if bd else s_k       # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
                   q_offset - k_offset, _BWD_DIAG_CHUNK, bd=bd, window=window,
-                  eva=eva)._replace(group=n)
+                  eva=eva)._replace(group=n, rep=rep)
     s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
-    q_is, k_is, v_is, o_is = (c is not None for c in lay[1:])
+    # a group's dK and dV are summed in VMEM where the group's dQ fits
+    # there, else beside the kernel (``_Tiles.rep``)
+    t = t._replace(kv_grid=rep > 1 and _bwd_vmem_bytes(
+        rep * s_q_pad, d, q.dtype) <= _VMEM_BYTES)
+    # the heads of a key-side gradient, and of a grid step's dQ
+    h_k, whole = h // rep if t.kv_grid else h, t._replace(
+        group=n * rep if t.kv_grid else n)
+    q_is, k_is, v_is, o_is = (c is not None for c in lay[1:5])
 
     def row(x, fill):
         """(b*h, 1, s_q) -> the kernel's (b, h / n, n, s_q_pad) rows."""
@@ -1290,26 +1396,31 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
         come."""
         cols = cols and _dense(width)
         mid = (2, s_k_pad) if bd else (s_k_pad,)
+        # (its heads are the grid's: the key/value heads under ``kv_grid``)
         return (t.blocks((2, t.block_k) if bd else (t.block_k,), width, cols,
                           lambda i, j: (0, i) if bd
-                          else (jnp.minimum(i, t.nk - 1),) if eva else (i,)),
+                          else (jnp.minimum(i, t.nk - 1),) if eva else (i,),
+                          t.kv_grid),
                 jax.ShapeDtypeStruct(
-                    t.array(b, h, mid, width, cols is not None), q.dtype))
+                    t.array(b, h_k, mid, width, cols is not None), q.dtype))
 
     def scratch(rows, width):
-        return pltpu.VMEM(t.per_head(rows, width), jnp.float32)
+        return pltpu.VMEM(rows + (width,), jnp.float32)
 
     # dQ sums over the k blocks, the outer axis: its block is the whole
     # sequence of the step's heads, written back once when they are done.
-    dq_spec = t.blocks((s_q_pad,), d, lay.q and _dense(d), lambda i, j: (0,))
+    dq_spec = whole.blocks((s_q_pad,), d, lay.q and _dense(d),
+                           lambda i, j: (0,), t.kv_grid)
     in_specs = [t.q_spec(d, lay.q, True), t.q_spec(d_v, lay.out, True),
                 row_spec, row_spec, k_spec, v_spec]
     out_specs, out_shape = map(list, zip(
         (dq_spec, jax.ShapeDtypeStruct(
-            t.array(b, h, (s_q_pad,), d, q_is), q.dtype)),
+            whole.array(b, h, (s_q_pad,), d, q_is), q.dtype)),
         dk_of(d_k, lay.k), dk_of(d_v, lay.v)))
-    scratches = [scratch(s_q_pad, d), scratch(t.block_k, d_k),
-                 scratch(t.block_k, d_v)]
+    # (under ``bd`` a group's noised squares have accumulators of their own)
+    tile = t.per_head(*((2,) if bd and t.kv_grid else ()), t.block_k)
+    scratches = [scratch(whole.per_head(s_q_pad), d), scratch(tile, d_k),
+                 scratch(tile, d_v)]
     operands = [q_rows(q, s_q_pad, q_is), q_rows(g, s_q_pad, o_is),
                 row(lse, -NEG_INF), row(delta.reshape(b * h, 1, s_q), 0.0),
                 k_rows(k, s_k_pad, k_is), k_rows(v, s_k_pad, v_is)]
@@ -1321,7 +1432,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
         spec, shape = dk_of(d_s, None)
         out_specs.append(spec)
         out_shape.append(shape)
-        scratches.append(scratch(t.block_k, d_s))
+        scratches.append(scratch(tile, d_s))
         operands.append(_pad(k_shared[:, 0], 1, s_k_pad))
     if eva:
         # the summaries' tiles follow the positions' on the outer axis and
@@ -1338,25 +1449,39 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
     dq, dk, dv, *dks = pl.pallas_call(
         functools.partial(_flash_bwd_kernel, sm_scale=sm_scale, t=t,
                           tokens=(q_is, o_is, k_is, v_is)),
-        grid=(b, h // n, t.nk + t.ns, t.steps(True)),
+        grid=(b, h_k // n, t.nk + t.ns, *((rep,) if t.kv_grid else ()),
+              t.steps(True)),
         in_specs=in_specs, out_specs=out_specs, out_shape=out_shape,
         scratch_shapes=scratches,
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary"),
-            vmem_limit_bytes=_bwd_vmem_bytes(n * s_q_pad, d, q.dtype)),
+            dimension_semantics=("parallel", "parallel") + ("arbitrary",) * (
+                3 if t.kv_grid else 2),
+            vmem_limit_bytes=_bwd_vmem_bytes(whole.group * s_q_pad, d,
+                                             q.dtype)),
         interpret=interpret,
         name="flash_bwd",
     )(*operands)
 
-    def back(dx, x, s, tokens):
-        dx = _merged(dx, tokens, h)
+    def back(dx, x, s, tokens, heads=h):
+        dx = _merged(dx, tokens, heads)
         axis = 1 if tokens else 2
         return (_unhalved(dx, s_k, axis) if bd
                 else lax.slice_in_dim(dx, 0, s, axis=axis)).astype(x.dtype)
 
-    grads = (back(dq, q, s_q, q_is), back(dk, k, s_k, k_is),
-             back(dv, v, s_k, v_is))
+    def key_grad(dx, x, tokens):
+        dx = back(dx, x, s_k, tokens, h_k)
+        if h_k * rep == h:
+            return dx
+        # a gradient a query head: the group's sum, beside the kernel
+        if tokens:
+            return jnp.sum(
+                dx.reshape(*dx.shape[:2], h // rep, rep, -1), axis=3,
+                dtype=jnp.float32).astype(dx.dtype).reshape(*dx.shape[:2], -1)
+        return jnp.sum(dx.reshape(b, h // rep, rep, *dx.shape[2:]), axis=2,
+                       dtype=jnp.float32).astype(dx.dtype)
+
+    grads = (back(dq, q, s_q, q_is), key_grad(dk, k, k_is),
+             key_grad(dv, v, v_is))
     if eva:
         return grads + tuple(back(dx, x, n_pooled, rank3) for dx, x, rank3
                              in zip(dks, pooled, (k_is, v_is)))
@@ -1419,8 +1544,10 @@ def _flash_bwd_rule(causal, sm_scale, q_offset, k_offset, block_q, block_k,
         # to its whole gradient in one pass)
         dq, dk, dv = (
             dx if cols is None else jax.linear_transpose(
-                functools.partial(_take, cols=cols, heads=lay.heads), x)(dx)[0]
-            for dx, x, cols in zip((dq, dk, dv), (q, k, v), lay[1:4]))
+                functools.partial(_take, cols=cols, heads=heads), x)(dx)[0]
+            for dx, x, cols, heads in zip(
+                (dq, dk, dv), (q, k, v), lay[1:4],
+                (lay.heads,) + (lay.heads // lay.rep,) * 2))
     if eva:
         return dq, dk, dv, None, tuple(dks)
     return dq, dk, dv, (dks[0] if dks else None), None
@@ -1471,6 +1598,34 @@ def _heads_in(q, head_dim: Optional[int]) -> int:
     return q.shape[-1] // head_dim
 
 
+def _operands(q, k, v, head_dim: Optional[int], shared: int = 0):
+    """q, k, v as ``attention`` takes them -> (the query heads, the
+    key/value heads, the three arrays, their ``_Cols``).  The key/value
+    heads are what k's shape says — a rank-3 k is as wide a head as the
+    queries less the ``shared`` part — and v has as many."""
+    heads = _heads_in(q, head_dim)
+    q, q_cols = _operand(q, heads)
+    n_kv = _heads_in(k, _seen(q, q_cols)[1] - shared)
+    if n_kv < 1 or heads % n_kv:
+        raise ValueError(f"{heads} query heads over {n_kv} key/value heads")
+    (k, k_cols), (v, v_cols) = _operand(k, n_kv), _operand(v, n_kv)
+    if v.ndim == 4 and v.shape[1] != n_kv:
+        raise ValueError(f"{n_kv} key heads against {v.shape[1]} of values")
+    return heads, n_kv, (q, k, v), (q_cols, k_cols, v_cols)
+
+
+def _repeated(x, cols: Optional[_Cols], n_kv: int, rep: int):
+    """A key-side operand of either rank as (B, H, S, D), each key/value
+    head copied to its ``rep`` query heads: what an implementation with no
+    grouped form takes.  The scope is the trace's name for these copies, and
+    for the sum over a group that is their transpose."""
+    x = _to_heads(x, cols, n_kv)
+    if rep == 1:
+        return x
+    with jax.named_scope("kv_repeat"):
+        return jnp.repeat(x, rep, axis=1)
+
+
 def _settled(lay: _Layout, alone: bool) -> _Layout:
     """``lay`` with the rank-3 operands left that the kernels can address as
     they lie (``_Tiles``), the others None: those go head-major.  ``alone``:
@@ -1478,7 +1633,7 @@ def _settled(lay: _Layout, alone: bool) -> _Layout:
     def kept(group):
         return lay._replace(**{
             name: cols if cols is not None and cols.fits(group) else None
-            for name, cols in zip(lay._fields[1:], lay[1:])})
+            for name, cols in zip(lay._fields[1:5], lay[1:5])})
     if not (alone or lay.heads % 2) and kept(2).group == 2:
         return kept(2)
     return kept(1)
@@ -1520,7 +1675,11 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
                     k_pooled=None, v_pooled=None):
     """Blockwise (flash) attention.  Each of q, k, v is rank 4, (B, H, S, D),
     or rank 3, (B, S, H * D) as a projection wrote it (or a ``HeadColumns``
-    of such an array); a rank-3 q comes with ``head_dim``.  The output has
+    of such an array); a rank-3 q comes with ``head_dim``.  k and v may have
+    fewer heads than q, a whole number of query heads to each (grouped-query
+    attention: a rank-4 k's second dimension, a rank-3 k's columns over the
+    queries' head width), and are read where they lie, nothing repeated
+    (``_Tiles.rep``; the module's docstring says where not).  The output has
     q's rank, or ``tokens_out`` says: (B, S, H * Dv) if true, (B, H, S, Dv)
     if not.  The kernels read a rank-3 operand and write a rank-3 output
     where they lie, no transpose between, wherever its head width is whole
@@ -1558,19 +1717,17 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     every device (sequence sharding is ring attention's job).  Without one,
     or on a one-device mesh, it is the plain call.
     """
-    heads = _heads_in(q, head_dim)
-    (q, k, v), cols = zip(*(_operand(x, heads) for x in (q, k, v)))
-    lay = _Layout(heads, *cols)
+    shared = 0 if k_shared is None else k_shared.shape[-1]
+    heads, n_kv, (q, k, v), cols = _operands(q, k, v, head_dim, shared)
     (_, d), (s_k, d_k), (_, d_v) = map(_seen, (q, k, v), cols)
     if tokens_out is None:
-        tokens_out = lay.q is not None
+        tokens_out = cols[0] is not None
     if sm_scale is None:
         sm_scale = d ** -0.5
     # These three guard the kernels against a direct caller (the tests are
     # one); ``attention`` calls through and lets them speak.
     if window and (diffusion_block or not causal):
         raise ValueError("a window belongs to the causal mask")
-    shared = 0 if k_shared is None else k_shared.shape[-1]
     eva = _eva(eva_window, eva_chunk, s_k)
     if eva and (window or diffusion_block or shared or not causal
                 or q_offset or k_offset):
@@ -1591,16 +1748,28 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     if tp > 1:
         # a device's share of the columns is whole heads only where they lie
         # side by side and alone: such an operand as an array of its own
-        q, k, v = (x if c is None else _take(x, c, heads)
-                   for x, c in zip((q, k, v), cols))
+        q, k, v = (x if c is None else _take(x, c, n)
+                   for x, c, n in zip((q, k, v), cols, (heads, n_kv, n_kv)))
         cols = tuple(c and _dense(c.width) for c in cols)
-        lay = _Layout(heads, *cols)
+    alone = bool(diffusion_block or eva)
+    lay = _Layout(heads // tp, *cols, _dense(d_v) if tokens_out else None)
+    if n_kv != heads and (shared or eva or n_kv % tp
+                          or _settled(lay, alone).group > 1):
+        # the kernels address a group's key/value head for a head a grid
+        # step, of one kind of key, a device's share whole key/value heads
+        logger.warning(
+            "flash attention: %d query heads over %d key/value heads (k %s, "
+            "v %s, tp %d) are not addressed as they lie; k and v are copied "
+            "to the query heads", heads, n_kv, k.shape, v.shape, tp)
+        k, v = (_repeated(x, c, n_kv, heads // n_kv)
+                for x, c in zip((k, v), cols[1:]))
+        n_kv, cols, lay = heads, (cols[0], None, None), lay._replace(
+            k=None, v=None)
     # what one device's call can address as it lies; the rest head-major
-    lay = _settled(lay._replace(heads=heads // tp,
-                                out=_dense(d_v) if tokens_out else None),
-                   alone=bool(diffusion_block or eva))
-    q, k, v = (x if kept is not None else _to_heads(x, c, heads)
-               for x, c, kept in zip((q, k, v), cols, lay[1:4]))
+    lay = _settled(lay._replace(rep=heads // n_kv), alone)
+    q, k, v = (x if kept is not None else _to_heads(x, c, n)
+               for x, c, kept, n in zip((q, k, v), cols, lay[1:4],
+                                        (heads, n_kv, n_kv)))
     operands = (q, k, v) if k_shared is None else (q, k, v, k_shared)
     if eva:
         # the summaries that some query sees, laid out as k and v are
@@ -1755,6 +1924,7 @@ def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
     """What a model's attention layer calls.  Each of q, k, v is rank 4,
     (B, H, S, D), or rank 3, (B, S, H * D) as its projection wrote it (or a
     ``HeadColumns`` of a wider array); a rank-3 q comes with ``head_dim``.
+    Under grouped-query attention k and v come with their own, fewer heads.
     The result is (B, S, H * Dv), what the output projection takes, whatever
     the operands' ranks.  The mask's parameters are as ``flash_attention`` and
     ``mha_reference`` take them
@@ -1766,8 +1936,9 @@ def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
     and "flash" under an ambient mesh that shards the sequence
     (``ring_axis`` > 1) is the ring, since the kernels want the sequence whole.
     The kernels read rank-3 operands and write the result where they lie
-    (``flash_attention``); "reference" and "ring" want (B, H, S, D) and get
-    it by a transpose here.
+    (``flash_attention``); "reference" and "ring" want (B, H, S, D) with K
+    and V at the query heads and get it by a transpose and, under the scope
+    ``kv_repeat``, a copy here.
     What the implementation has nothing for is refused, here (``_REFUSED``)
     or by its own guard."""
     mesh = ambient_mesh()
@@ -1775,9 +1946,10 @@ def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
         and mesh.shape.get(ring_axis, 1) > 1
     if sharded:
         impl = "ring"
-    heads = _heads_in(q, head_dim)
+    heads, n_kv, operands, cols = _operands(
+        q, k, v, head_dim, 0 if k_shared is None else k_shared.shape[-1])
     eva_window, eva_chunk = _eva(
-        eva_window, eva_chunk, _seen(*_operand(k, heads))[0]) or (0, 0)
+        eva_window, eva_chunk, _seen(operands[1], cols[1])[0]) or (0, 0)
     asked = {"window": bool(window), "diffusion_block": bool(diffusion_block),
              "k_shared": k_shared is not None, "eva_window": bool(eva_window)}
     for name, (impls, words) in _REFUSED.items():
@@ -1798,7 +1970,10 @@ def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
                 **(dict(eva_window=eva_window, eva_chunk=eva_chunk,
                         k_pooled=k_pooled, v_pooled=v_pooled)
                    if eva_window else {}))
-    q, k, v = (_to_heads(*_operand(x, heads), heads) for x in (q, k, v))
+    # (B, H, S, D), K and V copied to the query heads
+    q = _to_heads(operands[0], cols[0], heads)
+    k, v = (_repeated(x, c, n_kv, heads // n_kv)
+            for x, c in zip(operands[1:], cols[1:]))
     if impl == "reference":
         mask = block_diffusion_mask(q.shape[2] // 2, diffusion_block) \
             if diffusion_block else None

@@ -211,8 +211,8 @@ def pool_chunks(k, v, phi, mu, chunk: int, scale: float, *, impl: str):
     decided here and nowhere else, as ``ops.attention.attention`` decides the
     attention's: ``impl`` is a config's ``attention_impl``, and "flash" is
     the kernels wherever they address the operands as they lie (``fits``).
-    Where they do not (heads narrower than a lane tile, v head-major after a
-    ``kv_repeat``) "flash" runs the plain form, which XLA runs at a sixth of
+    Where they do not (heads narrower than a lane tile, v head-major)
+    "flash" runs the plain form, which XLA runs at a sixth of
     the kernels' speed (PERF.md, PR 47), and says so once in the log; no
     configuration of the benchmark lies there."""
     if impl != "flash":

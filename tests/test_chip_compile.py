@@ -129,6 +129,8 @@ def _build(case: str, compile_: bool) -> dict:
         return _build_prelude(case, topo.devices[0])
     if case in LAYOUTS:
         return _build_layout(case, topo.devices[0])
+    if case in MHA_CALLS:
+        return _build_mha_call(case, topo.devices[0])
     if case in MLP_BLOCKS:
         return _build_mlp_block(case, topo.devices[0])
     if case.startswith("olmoe_b"):
@@ -236,26 +238,36 @@ def _flash_fwd_calls(jaxpr) -> int:
 
 def _build_flash(case: str, device) -> dict:
     """In the child: compile the flash kernels alone, forward and backward,
-    for one chip at ``flash_s<seq>_d<head width>`` in bf16 over 32 heads, the
-    long cells' count (the kernel's need grows a little with it)."""
+    for one chip at ``flash_s<seq>_d<head width>[_g<group>]`` in bf16 over 32
+    heads, the long cells' count (the kernel's need grows a little with it);
+    k and v at 32 / ``group`` heads.  ``dk_heads``: the heads of the dK the
+    backward kernel writes."""
+    import re
+
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
     from ray_tpu.ops.attention import flash_attention
 
-    seq, d = (int(part[1:]) for part in case.split("_")[1:])
-    x = jax.ShapeDtypeStruct((1, 32, seq, d), jnp.bfloat16,
-                             sharding=SingleDeviceSharding(device))
+    seq, d, rep = (int(part[1:]) for part in (case + "_g1").split("_")[1:4])
+
+    def shape(heads):
+        return jax.ShapeDtypeStruct((1, heads, seq, d), jnp.bfloat16,
+                                    sharding=SingleDeviceSharding(device))
 
     def grads(q, k, v, g):
         return jax.vjp(flash_attention, q, k, v)[1](g)
 
     try:
-        jax.jit(grads).lower(x, x, x, x).compile()
+        text = jax.jit(grads).lower(shape(32), shape(32 // rep),
+                                    shape(32 // rep), shape(32)
+                                    ).compile().as_text()
     except Exception as e:  # what Mosaic or the TPU compiler refuses
         return {"case": case, "refused": str(e)[:600]}
-    return {"case": case}
+    (results,) = re.findall(r"= \((.*?)\) custom-call\(.*flash_bwd", text)
+    return {"case": case, "dk_heads": int(
+        re.findall(r"\w+\[([\d,]+)\]", results)[1].split(",")[1])}
 
 
 def _build_prelude(case: str, device) -> dict:
@@ -300,12 +312,18 @@ def _build_prelude(case: str, device) -> dict:
             "prelude_gb": gigabytes(True)}
 
 
-# name -> (batch, seq, heads, head width) of an attention layer whose kernels
-# read their operands where the projections wrote them
+# name -> (batch, seq, query heads, head width, key/value heads, the mask) of
+# an attention layer whose kernels read their operands where the projections
+# wrote them
 LAYOUTS = {
-    "layout_gpt2": (24, 1024, 12, 64),
-    "layout_llama_v_out": (2, 8192, 32, 128),
+    "layout_gpt2": (24, 1024, 12, 64, 12, {}),
+    "layout_llama_v_out": (2, 8192, 32, 128, 32, {}),
+    # grouped: a sliding layer of Laguna-XS.2, a layer of SDAR (2 x 4096
+    # tokens as two copies each)
+    "layout_gqa_laguna": (2, 8192, 64, 128, 8, dict(window=512)),
+    "layout_gqa_sdar": (2, 8192, 32, 128, 4, dict(diffusion_block=4)),
 }
+GQA_WIDTH = 2048    # the model's width in both grouped cells
 
 
 def _build_layout(case: str, device) -> dict:
@@ -313,11 +331,12 @@ def _build_layout(case: str, device) -> dict:
     flash kernels and back, forward and backward, compiled for one chip.
     ``layout_gpt2``: ``models/gpt2.py::Attention`` whole at the control
     cell's shape (q, k, v the thirds of ``qkv_proj``'s output, two heads to a
-    column block).  ``layout_llama_v_out``: ``x @ wv`` -> the kernels -> ``@
-    wo`` with q and k given head-major, as a rotated layer hands them over.
-    The compiled program's ``bytes accessed``, and the shape of every
-    ``copy`` / ``transpose`` instruction of the optimized HLO that holds as
-    many elements as an operand."""
+    column block).  The others: ``x @ wv`` -> the kernels -> ``@ wo`` with q
+    and k given head-major, as a rotated layer hands them over, k and v at
+    the key/value heads.  The compiled program's ``bytes accessed``, the
+    shape of every ``copy`` / ``transpose`` instruction of the optimized HLO
+    that holds as many elements as the smallest operand, and of the Mosaic
+    calls' operands and results those as large as q."""
     import re
 
     import jax
@@ -328,8 +347,8 @@ def _build_layout(case: str, device) -> dict:
     from ray_tpu.models.gpt2 import Attention, GPT2Config
     from ray_tpu.ops.attention import attention
 
-    B, S, H, D = LAYOUTS[case]
-    E = H * D
+    B, S, H, D, KV, mask = LAYOUTS[case]
+    E = H * D if KV == H else GQA_WIDTH
 
     def shape(*dims):
         return jax.ShapeDtypeStruct(dims, jnp.bfloat16,
@@ -343,25 +362,126 @@ def _build_layout(case: str, device) -> dict:
         f, operands = layer.apply, (params, shape(B, S, E))
     else:
         def f(q, k, x, wv, wo):
-            return attention(q, k, x @ wv, impl="flash") @ wo
-        operands = (shape(B, H, S, D), shape(B, H, S, D), shape(B, S, E),
-                    shape(E, E), shape(E, E))
+            return attention(q, k, x @ wv, impl="flash", **mask) @ wo
+        operands = (shape(B, H, S, D), shape(B, KV, S, D), shape(B, S, E),
+                    shape(E, KV * D), shape(H * D, E))
 
     def both_ways(g, *operands):
         out, vjp = jax.vjp(f, *operands)
         return out, vjp(g)
 
-    compiled = jax.jit(both_ways).lower(shape(B, S, E), *operands).compile()
+    def elements(dims):
+        return np.prod([int(n) for n in dims.split(",")])
+
+    try:
+        compiled = jax.jit(both_ways).lower(
+            shape(B, S, E), *operands).compile()
+    except Exception as e:  # what Mosaic or the TPU compiler refuses
+        return {"case": case, "refused": str(e)[:600]}
     text = compiled.as_text()
     moved = [m.group(1) for m in re.finditer(
         r"= \w+\[([\d,]+)\]\S* (?:copy|transpose)\(", text)]
+    calls = [line for line in text.splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    # (a call's results stand before ``custom-call(``, its operands' shapes
+    # in ``operand_layout_constraints``)
+    seen = [dims for line in calls for part in (
+        line.split(" custom-call(")[0],
+        line.split("operand_layout_constraints={")[1].split("}}")[0])
+        for dims in re.findall(r"\w+\[([\d,]+)\]", part)]
     return {"case": case,
             "gigabytes": compiled.cost_analysis()["bytes accessed"] / 1e9,
-            "mosaic_calls": len(re.findall(
-                r'custom_call_target="tpu_custom_call"', text)),
+            "mosaic_calls": len(calls),
             "operand_sized_copies": [
                 dims for dims in moved
-                if np.prod([int(n) for n in dims.split(",")]) >= B * S * E]}
+                if elements(dims) >= B * S * min(E, KV * D)],
+            "as_large_as_q": [dims for dims in seen
+                              if elements(dims) >= B * S * H * D]}
+
+
+# name -> the lowered text of an attention call with a key/value head a query
+# head, forward and backward, as the commit before grouped-query attention
+# went into the kernels lowered it (PR 52's parent, ``3e9566a``): sha256 of
+# the StableHLO with the Mosaic kernels' modules in it, their source
+# locations aside.  A PR that changes the kernels on purpose records them
+# anew: ``python tests/test_chip_compile.py lower mha_gpt2 ...``.
+MHA_CALLS = {
+    "mha_gpt2":
+        "7842815cbbf75dcee0e92eaecf76923f9cea956a93401f2016f7f8767fa7b85d",
+    "mha_olmoe":
+        "6c581509c9f17ef168bdf70ab9881c88db410fb0013d72a6acdb3227094b39e9",
+    "mha_kimi_vl":
+        "91e5abe6a955a9d62e953a80d4f7b4177b9d9a7abdbafa778e2d113ea8588ca6",
+    "mha_evabyte":
+        "8d5240e2ab194f23855ddeb967e8f1d5f55b69f4e67f2df8bd07fc981cc3a537",
+}
+
+
+def _build_mha_call(case: str, device) -> dict:
+    """In the child: ``attention`` at GPT-2's shape (24 x 1024, 12 heads of
+    64, the thirds of one array), OLMoE's (1 x 4096, 16 heads of 128, q and
+    k head-major, v as its projection wrote it), Kimi-VL's (1 x 16384, 16
+    heads, the key's rotary part shared) or EvaByte's (1 x 16384, 32 heads
+    under EVA's mask), lowered for one chip, as a digest: what at ``rep`` 1 every index map, grid and scratch shape must
+    leave as it was."""
+    import base64
+    import hashlib
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax._src.interpreters import mlir
+    from jax._src.lib.mlir import ir
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.attention import HeadColumns, attention
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16,
+                                    sharding=SingleDeviceSharding(device))
+
+    if case == "mha_gpt2":
+        def f(qkv):
+            return attention(*(HeadColumns(qkv, 12, 64, first=i * 768)
+                               for i in range(3)), impl="flash")
+        operands = (shape(24, 1024, 768), shape(24, 1024, 2304))
+    elif case == "mha_kimi_vl":
+        def f(q, kv, kr):
+            return attention(
+                q, HeadColumns(kv, 16, 128, first=0, stride=256),
+                HeadColumns(kv, 16, 128, first=128, stride=256),
+                k_shared=kr, impl="flash")
+        operands = (shape(1, 16384, 2048), shape(1, 16, 16384, 192),
+                    shape(1, 16384, 4096), shape(1, 1, 16384, 64))
+    elif case == "mha_evabyte":
+        def f(q, k, v, kp, vp):
+            return attention(q, k, v, impl="flash", eva_window=2048,
+                             eva_chunk=16, k_pooled=kp, v_pooled=vp)
+        operands = (shape(1, 16384, 4096), shape(1, 32, 16384, 128),
+                    shape(1, 32, 16384, 128), shape(1, 16384, 4096),
+                    shape(1, 32, 1024, 128), shape(1, 1024, 4096))
+    else:
+        def f(q, k, v):
+            return attention(q, k, v, impl="flash")
+        operands = (shape(1, 4096, 2048), shape(1, 16, 4096, 128),
+                    shape(1, 16, 4096, 128), shape(1, 4096, 2048))
+
+    def both_ways(g, *operands):
+        out, vjp = jax.vjp(f, *operands)
+        return out, vjp(g)
+
+    def kernel(match):
+        """A Mosaic module, as text without its locations."""
+        context = mlir.make_ir_context()
+        context.allow_unregistered_dialects = True
+        with context:
+            return ir.Module.parse(base64.b64decode(match.group(1))
+                                   ).operation.get_asm(enable_debug_info=False)
+
+    text, kernels = re.subn(r'(?<=body\\22: \\22)([A-Za-z0-9+/=]+)', kernel,
+                            jax.jit(both_ways).lower(*operands).as_text())
+    return {"case": case, "kernels": kernels,
+            "sha256": hashlib.sha256(text.encode()).hexdigest()}
 
 
 # name -> the cell whose layers the case compiles two of
@@ -467,12 +587,21 @@ def test_flash_backward_compiles_with_its_whole_sequence_dq_in_vmem():
     dQ for a head's whole sequence in VMEM, and the limit it asks for
     (``_bwd_vmem_bytes``) is enough at the longest cells' shape (s 8192 x
     d 128) and at s 32768, where Mosaic's default 16 MiB scope is not; past
-    the chip's 128 MiB the compiler refuses the call and says so."""
+    the chip's 128 MiB the compiler refuses the call and says so.  Under
+    grouped-query attention the dQ is the group's: eight heads' at s 8192
+    (Laguna's and SDAR's shape) and at s 14336, the last that
+    ``_bwd_vmem_bytes`` puts inside 128 MiB, with dK written at the four
+    key/value heads; at s 16384 a head's alone, dK a query head, the sum
+    beside the kernel."""
     rows = _child(["flash_s8192_d128", "flash_s32768_d128",
-                   "flash_s131072_d128"], compile_=True)
-    assert "refused" not in rows["flash_s8192_d128"], rows
-    assert "refused" not in rows["flash_s32768_d128"], rows
-    assert "vmem" in rows["flash_s131072_d128"]["refused"], rows
+                   "flash_s131072_d128", "flash_s8192_d128_g8",
+                   "flash_s14336_d128_g8", "flash_s16384_d128_g8"],
+                  compile_=True)
+    assert "vmem" in rows.pop("flash_s131072_d128")["refused"], rows
+    assert {case: row.get("dk_heads") for case, row in rows.items()} == {
+        "flash_s8192_d128": 32, "flash_s32768_d128": 32,
+        "flash_s8192_d128_g8": 4, "flash_s14336_d128_g8": 4,
+        "flash_s16384_d128_g8": 32}, rows
 
 
 def test_attention_prelude_moves_no_float32_copy_of_the_queries():
@@ -501,7 +630,16 @@ def test_attention_layout_moves_no_copy_of_an_operand():
     position).  One GPT-2 attention layer at 24 x 1024 x 768 moves 1.39 GB
     where the head-major form moved 3.39 (fourteen copies of an operand's
     size), a Llama layer's ``v`` / ``out`` path at 2 x 8192 x 32 x 128 2.64
-    for 3.43 (five)."""
+    for 3.43 (five).
+
+    Grouped (PR 52): a sliding layer of Laguna (64 / 8 heads of 128, 2 x
+    8192, window 512) and a layer of SDAR (32 / 4, the block mask) hand the
+    kernels k and v with the heads their projections gave them, and the
+    backward fits VMEM with the group's dQ in it: of all the Mosaic calls'
+    operands and results only q, dO, the output and dQ are as large as q —
+    no K, V, dK or dV of ``heads x D`` columns — and nothing of even a
+    key/value operand's size is copied beside them; 2.66 and 1.67 GB moved,
+    the projections' matmuls in it."""
     rows = _child(list(LAYOUTS), compile_=True)
     print(rows)
     for row in rows.values():
@@ -509,6 +647,24 @@ def test_attention_layout_moves_no_copy_of_an_operand():
         assert row["operand_sized_copies"] == [], row
     assert rows["layout_gpt2"]["gigabytes"] < 1.55, rows
     assert rows["layout_llama_v_out"]["gigabytes"] < 2.8, rows
+    assert rows["layout_gqa_laguna"]["gigabytes"] < 2.8, rows
+    assert rows["layout_gqa_sdar"]["gigabytes"] < 1.75, rows
+    for case in ("layout_gqa_laguna", "layout_gqa_sdar"):
+        # the forward's q and output, the backward's q, dO and dQ
+        assert len(rows[case]["as_large_as_q"]) == 5, rows[case]
+
+
+def test_an_attention_call_without_groups_lowers_to_what_it_was():
+    """Tier-1, a second a shape (PR 52): whether a call is grouped is read
+    from its operands' shapes, and at a key/value head a query head the
+    kernels' index maps, grids and scratch shapes are what they were: the
+    attention calls of the cells that have no group — GPT-2's, OLMoE's,
+    Kimi-VL's, EvaByte's — lower to the text they lowered to before
+    (``MHA_CALLS``), so nothing can move there."""
+    rows = _child(list(MHA_CALLS), compile_=False)
+    for case, row in rows.items():
+        assert row["kernels"] == 2, row
+        assert row["sha256"] == MHA_CALLS[case], row
 
 
 @pytest.mark.parametrize("case", sorted(MLP_BLOCKS))

@@ -7,7 +7,9 @@ against the same call on the same values head-major (Pallas interpreter):
 output and every gradient, and whether a transpose stands between the
 operands and the kernels (where the width allows none should).  Then the
 models: ``GPT2`` and a Llama block against ``attention_impl="reference"``,
-their parameter trees as the parent's."""
+their parameter trees as the parent's.  And grouped-query calls, k and v with
+the heads their projections gave them (``_Tiles.rep``), against
+``mha_reference`` on operands repeated to the query heads."""
 
 import dataclasses
 
@@ -18,7 +20,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 
-from ray_tpu.ops.attention import HeadColumns, flash_attention
+from ray_tpu.ops import attention as attention_ops
+from ray_tpu.ops.attention import (HeadColumns, block_diffusion_mask,
+                                   flash_attention, mha_reference)
 
 SEQ = 256
 
@@ -231,6 +235,125 @@ def test_under_a_tp_mesh_the_columns_are_cut_by_whole_heads(width):
     np.testing.assert_allclose(got_grad, want_grad, rtol=1e-5, atol=2e-5)
 
 
+# ------------------------------------------------- grouped-query attention
+# name -> (query heads, key/value heads, head width, length, the call's
+#          keywords, the reference's, which of q, k, v are rank 3, how the
+#          group's dK and dV are summed: "vmem", "beside" the kernel, or
+#          "repeated" — k and v copied to the query heads first)
+_TILES = dict(block_q=128, block_k=128)
+_BLOCK_MASK = (dict(causal=False, diffusion_block=4),
+               dict(causal=False, mask=block_diffusion_mask(SEQ, 4)))
+GROUPED = {
+    # what a rotated layer sends: q and k as heads, v from its projection
+    "causal-rep4": (4, 1, 128, 384, _TILES, {}, "v", "vmem"),
+    "causal-rep8": (8, 1, 128, 384, _TILES, {}, "v", "vmem"),
+    "causal-rep4-padded": (8, 2, 128, 264, {}, {}, "v", "vmem"),
+    # NoPE: every operand as its projection wrote it
+    "causal-rep4-rank-3": (8, 2, 128, 384, _TILES, {}, "qkv", "vmem"),
+    "causal-rep8-rank-4": (8, 1, 128, SEQ, {}, {}, "", "vmem"),
+    "window-rep4": (8, 2, 128, 384, dict(window=100, **_TILES),
+                    dict(window=100), "v", "vmem"),
+    "window-rep8": (8, 1, 128, 384, dict(window=100, **_TILES),
+                    dict(window=100), "v", "vmem"),
+    "window-rep4-rank-3": (4, 1, 128, 384, dict(window=100),
+                           dict(window=100), "qkv", "vmem"),
+    "block-mask-rep4": (8, 2, 128, 2 * SEQ, *_BLOCK_MASK, "v", "vmem"),
+    "block-mask-rep8": (8, 1, 128, 2 * SEQ, *_BLOCK_MASK, "v", "vmem"),
+    "block-mask-rep8-rank-3": (8, 1, 128, 2 * SEQ, *_BLOCK_MASK, "qkv",
+                               "vmem"),
+    # the group's dQ past VMEM: a gradient a query head, summed outside
+    "causal-rep4-beside": (8, 2, 128, 384, _TILES, {}, "v", "beside"),
+    "block-mask-rep4-beside": (4, 1, 128, 2 * SEQ, *_BLOCK_MASK, "qkv",
+                               "beside"),
+    # any width as (B, H, S, D); the pair form of 64-wide heads has no
+    # grouped form, and the copies of before stand in
+    "causal-rep2-d32": (4, 2, 32, SEQ, {}, {}, "v", "vmem"),
+    "causal-rep2-d64-pair": (4, 2, 64, SEQ, {}, {}, "v", "repeated"),
+}
+
+
+def _kernel_operands(f, *operands):
+    """kernel name -> the shapes of its operands and results, in ``f``'s
+    program."""
+    shapes = {}
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                shapes[eqn.params["name"]] = [
+                    v.aval.shape for v in (*eqn.invars, *eqn.outvars)]
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+    walk(jax.make_jaxpr(f)(*operands).jaxpr)
+    return shapes
+
+
+@pytest.mark.parametrize("case", list(GROUPED))
+def test_grouped_operands_against_the_reference_on_repeated_ones(
+        case, monkeypatch):
+    h, n_kv, d, s, kw, ref_kw, rank3, summed = GROUPED[case]
+    if summed == "beside":
+        monkeypatch.setattr(attention_ops, "_VMEM_BYTES", 1 << 20)
+    b, rep = 2, h // n_kv
+    keys = jax.random.split(jax.random.PRNGKey(11), 4)
+    q, k, v, g = (jax.random.normal(key, (b, heads, s, d))
+                  for key, heads in zip(keys, (h, n_kv, n_kv, h)))
+    g = _tokens(g)
+
+    def grouped(q, k, v):
+        q, k, v = (_tokens(x) if n in rank3 else x
+                   for n, x in zip("qkv", (q, k, v)))
+        return flash_attention(q, k, v, head_dim=d, tokens_out=True, **kw)
+
+    def repeated(q, k, v):
+        k, v = (jnp.repeat(x, rep, axis=1) for x in (k, v))
+        return _tokens(mha_reference(q, k, v, **ref_kw))
+
+    (got, got_grads), (want, want_grads) = (
+        _both(f, (q, k, v), g) for f in (grouped, repeated))
+    _close(got, want, jnp.float32, False, "out")
+    for name, x, y in zip("qkv", got_grads, want_grads):
+        assert x.shape == y.shape
+        _close(x, y, jnp.float32, False, "d" + name)
+
+    # what the kernels are handed and what they write: k, v and their
+    # gradients at the key/value heads, whatever the rank, unless the copies
+    # stand in; a gradient a query head where the sum is beside the kernel
+    kernels = _kernel_operands(lambda *a: _both(grouped, a, g), q, k, v)
+    as_q = np.prod(kernels["flash_fwd"][0])
+    smaller = [as_q // np.prod(shape) for shape in (
+        kernels["flash_fwd"][1:3] + kernels["flash_bwd"][4:6]
+        + kernels["flash_bwd"][-2:])]
+    assert smaller == {
+        "vmem": [rep] * 6, "beside": [rep] * 4 + [1] * 2,
+        "repeated": [1] * 6}[summed], kernels
+
+
+@pytest.mark.parametrize("n_kv", [2, 1])
+def test_under_a_tp_mesh_a_device_takes_whole_key_value_heads(n_kv, caplog):
+    """dp=2 x tp=2 on four CPU devices, four query heads: over two key/value
+    heads each device's call is grouped, two heads over its one; over one,
+    which no two devices can share by whole heads, the copies stand in, and
+    the log says so."""
+    b, h, d = 2, 4, 128
+    keys = jax.random.split(jax.random.PRNGKey(2), 4)
+    q, k, v, g = (jax.random.normal(key, (b, heads, SEQ, d))
+                  for key, heads in zip(keys, (h, n_kv, n_kv, h)))
+    g = _tokens(g)
+
+    def grouped(q, k, v):
+        return flash_attention(q, k, _tokens(v), tokens_out=True)
+
+    want, want_grads = _both(grouped, (q, k, v), g)
+    mesh = Mesh(np.asarray(jax.devices()[:4]).reshape(2, 2), ("dp", "tp"))
+    with jax.set_mesh(mesh), caplog.at_level("WARNING"):
+        got, got_grads = jax.jit(lambda *a: _both(grouped, a, g))(q, k, v)
+    assert ("copied to the query heads" in caplog.text) == (n_kv == 1)
+    np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
+    for x, y in zip(got_grads, want_grads):
+        np.testing.assert_allclose(x, y, rtol=1e-5, atol=2e-5)
+
+
 # ------------------------------------------------------------- the models
 def _tree(params):
     return {"/".join(str(k.key) for k in path): leaf.shape for path, leaf
@@ -291,9 +414,12 @@ def test_gpt2_reads_its_projection_as_it_lies():
 LLAMA_TREES = {
     # every head its own key/value head: v goes as its projection wrote it
     "mha": (dict(n_head=2, n_kv_head=2), 2),
-    # grouped: k and v repeated head-major, the output token-major
+    # grouped: k and v go as they are, one head where q has two
     "gqa": (dict(n_head=2, n_kv_head=1), 1),
-    # 64-wide heads, grouped, under the per-head norm: the pair form
+    "gqa-rep4-nope": (dict(n_head=4, n_kv_head=1, head_dim=128, rope=False),
+                      1),
+    # 64-wide heads, grouped, under the per-head norm: the pair form, over
+    # k and v copied to the query heads (``flash_attention``'s fallback)
     "gqa-d64-head-norm": (dict(n_head=4, n_kv_head=2, head_dim=64,
                                qk_norm="head"), 2),
     # no rotation: q straight from its projection too

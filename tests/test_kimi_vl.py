@@ -290,7 +290,8 @@ def test_d_lagunas_step_is_the_parents(flash_names_off):
     they were; since PR 42 that PR's: the kernels' grid is (batch, heads,
     tiles, tiles) and their output (B, S, H * D), the gate widened along
     the lanes; since PR 49 that PR's: the dense layer's and the shared
-    expert's ``silu * up`` go through ``models/moe.py::silu_mul``)."""
+    expert's ``silu * up`` go through ``models/moe.py::silu_mul``; since
+    PR 52 that PR's: k and v reach the kernels with their own heads)."""
     from ray_tpu.models.pretrain import make_optimizer, sharded_train_step
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
@@ -303,7 +304,7 @@ def test_d_lagunas_step_is_the_parents(flash_names_off):
     with jax.set_mesh(mesh):
         text = s.step.trace(s.state, batch).lower().as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        "120dd8f5badc1fd9945d40fe305fabfab403128cae91bf1986ae51e14e9ad38b"
+        "e6b075776c92df8f5105659bddc4cc43ae1ddb6623aab49da61b356952a34f02"
 
 
 # ------------------------------------------------- (e) on a virtual mesh

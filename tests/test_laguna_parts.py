@@ -169,12 +169,16 @@ def test_c_the_shares_add_up_to_the_uncut_layer():
 # ``toy-llama`` and ``toy-granite``, the two with a dense SwiGLU, are PR 49's:
 # ``silu(gate) * up`` goes through ``models/moe.py::silu_mul``, whose backward
 # writes ``dgate`` and ``dup`` behind an optimization barrier; ``toy-olmoe`` and
-# ``toy-sdar``, which have none, did not move.)
+# ``toy-sdar``, which have none, did not move.  ``toy-llama``, ``toy-granite``
+# and ``toy-sdar``, the three with grouped-query attention, are PR 52's: k and
+# v go to the kernels with their own heads, read through ``h // rep``, and the
+# models repeat nothing; ``toy-olmoe``, a key/value head a query head, did not
+# move.)
 _PARENT_STEPS = {
-    "toy-llama": "060fa7052c1f3df22c11c764760f272fa1218e8d76190f1ab8d7ad5da08f9993",
+    "toy-llama": "05cee1746a8f6209b4e123dcef499fec5aa78464beb6fceb5809a4a38b457868",
     "toy-olmoe": "41054e8f510af2a7d7be4f320326eb4237e4c1efc006d16bd81fbd3d9bea858f",
-    "toy-granite": "eafbb4378fe3e623ff9c1bb4e36163c87bfd94fe07b3c81c3e62c60d6384aba6",
-    "toy-sdar": "222b0bf1f1a77b9f7b59a06a8e8731dd8c05cc2521c078cde46cd291fa36d872",
+    "toy-granite": "1f0266daf56d2ba8071ad74e34924b85515c59f0ccae7e18406b18f8183ba900",
+    "toy-sdar": "e7b9a49026afa34ac996425b5e0c43eb0ccff4c77555b69209104e0d52e52ab0",
 }
 
 

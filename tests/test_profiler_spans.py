@@ -33,7 +33,9 @@ def _toy_config(family):
         return GPT2Config(vocab_size=256, n_positions=64, n_embd=32,
                           n_layer=1, n_head=2, remat=False)
     return LlamaConfig(vocab_size=256, n_positions=64, d_model=32, n_layer=1,
-                       n_head=4, n_kv_head=2, d_ff=64, remat=True)
+                       n_head=4, n_kv_head=2, d_ff=64, remat=True,
+                       attention_impl="reference"
+                       if family == "llama-reference" else "flash")
 
 
 def _toy_trainer(family):
@@ -201,9 +203,11 @@ def test_histograms_move_once_per_report_and_per_batch(toy_loop, histogram):
 
 
 # ------------------------------------------------- inside the jitted step
+# (``kv_repeat`` is K and V copied to the query heads: what "reference" and
+# "ring" take, and what the flash kernels read through ``h // rep`` instead)
 SCOPES = {"gpt2": ("optimizer", "lm_loss", "flash_bwd", "flash_fwd"),
-          "llama": ("optimizer", "lm_loss", "flash_bwd", "flash_fwd",
-                    "kv_repeat", "rope")}
+          "llama": ("optimizer", "lm_loss", "flash_bwd", "flash_fwd", "rope"),
+          "llama-reference": ("optimizer", "lm_loss", "kv_repeat", "rope")}
 
 
 def _instructions(hlo_text):
@@ -215,7 +219,7 @@ def _instructions(hlo_text):
                  "StackFrames"))]
 
 
-@pytest.fixture(scope="module", params=["gpt2", "llama"])
+@pytest.fixture(scope="module", params=list(SCOPES))
 def toy_step(request):
     """(family, the compiled step's text, the same with ``jax.named_scope``
     a null context: a switch of this test's, the program has none)."""
@@ -241,21 +245,24 @@ def test_the_step_carries_its_scopes_in_op_name(toy_step):
     for scope in SCOPES[family]:
         assert any(re.search(rf"[/(]{scope}[/)]", n) for n in op_names), scope
     # where each stands: the optimizer outside the model, the backward rule's
-    # operations under the transpose, the kernel and GQA's copies in attn
+    # operations under the transpose, the kernel in attn — and GQA's copies
+    # there too, under "reference" alone
     assert any(n.startswith("jit(pretrain_step)/optimizer/")
                for n in op_names)
+    flash = "flash_fwd" in SCOPES[family]
     assert any(re.search(r"transpose\(.*/h_0/attn/flash_bwd/", n)
-               for n in op_names)
-    assert any(re.search(r"/h_0/attn/flash_fwd/", n) for n in op_names)
-    if family == "llama":
-        assert any("/h_0/attn/kv_repeat/" in n for n in op_names)
+               for n in op_names) == flash
+    assert any(re.search(r"/h_0/attn/flash_fwd/", n)
+               for n in op_names) == flash
+    assert any("/h_0/attn/kv_repeat/" in n for n in op_names) == (
+        family == "llama-reference")
     bare_names = set(re.findall(r'op_name="([^"]+)"', bare))
     # (the backward's kernel keeps its own name, which is the scope's: it is
     # the scope, a path component of its own above the kernel's, that must be
     # gone)
-    assert any("/flash_bwd/flash_bwd/" in n for n in op_names)
+    assert any("/flash_bwd/flash_bwd/" in n for n in op_names) == flash
     assert not any("optimizer" in n or "flash_bwd/flash_bwd" in n
-                   for n in bare_names)
+                   or "kv_repeat" in n for n in bare_names)
 
 
 def test_the_loss_rules_carry_lm_loss_forward_and_backward(toy_step):
