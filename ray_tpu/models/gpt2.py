@@ -149,7 +149,11 @@ def remat_block(block_cls, remat_policy: str):
     ``"full"`` everything in the block is, except that a flash kernel's
     output and logsumexp are kept (``FLASH_RESIDUALS``): they are the one
     thing in a block whose recomputation costs a whole kernel for one
-    activation-sized array.  q, k and v are recomputed like the rest."""
+    activation-sized array.  q, k and v are recomputed like the rest, and so
+    is a recurrent layer's scan: what would spare a Kimi Delta Attention
+    layer its second forward is its output and the float32 state before each
+    chunk, 0.67 GB a layer at 16,384 positions, and the one cell that has
+    such layers has no room for four of them (``PERF.md``, PR 53)."""
     policies = jax.checkpoint_policies
     policy = (policies.dots_with_no_batch_dims_saveable
               if remat_policy == "dots"

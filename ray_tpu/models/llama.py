@@ -68,12 +68,17 @@ block's saved input, in another dtype than the activations; ``logits_dtype``
 is the head's output's; and ``n_pred_heads`` > 1 gives the head that many
 times the vocabulary's columns, head ``r`` scoring the token ``r + 1`` ahead
 (``models/gpt2.py::shifted_heads_loss``) (EvaByte is the block with these).
+A layer of ``layer_types`` may be ``"kda"`` (``kda``: ``models/kda.py``'s Kimi
+Delta Attention mixer over ``ops/kda.py``'s chunked scan, a gated delta rule
+with a decay a channel), and with ``rope`` off latent attention turns nothing,
+the shared key part un-rotated (Kimi-Linear-48B-A3B is the block with these,
+the leading dense layer and the sigmoid-routed experts).
 Every such field at its default leaves the program the dense Llama it was.
 ``remat`` recomputes each block from its input in the backward; what
 ``remat_policy="full"`` keeps beside that input is each attention layer's
 flash kernel output and logsumexp, so that no kernel's forward runs twice
-(``models/gpt2.py::remat_block``; a Mamba layer, a routed layer's Mosaic
-calls and reference attention keep nothing).  Same TPU discipline as the GPT stack —
+(``models/gpt2.py::remat_block``; a Mamba or KDA layer, a routed layer's
+Mosaic calls and reference attention keep nothing).  Same TPU discipline as the GPT stack —
 bfloat16 activations, fused QKV-free layout matched to
 ``llama_partition_rules`` so tp/fsdp shardings apply by regex, attention
 through ``ops.attention.attention``, which picks the Pallas flash kernel or,
@@ -95,6 +100,7 @@ import numpy as np
 
 from ray_tpu.models.gpt2 import (mask_vocab_padding, padded_vocab,
                                  remat_block)
+from ray_tpu.models.kda import KDAMixer
 from ray_tpu.models.mamba import (Mamba2Mixer, _conv_init,
                                   gated_short_conv)
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU, silu_mul
@@ -158,8 +164,8 @@ class LlamaConfig:
     router_aux_weight: float = 0.01  # x load-balancing loss, in the objective
     router_z_weight: float = 1e-3    # x router z-loss
     # each layer's token mixer, "attention" (or a kind of it), "mamba"
-    # (models/mamba.py) or "conv" (ShortConvMixer), one entry a layer; empty:
-    # attention in every layer
+    # (models/mamba.py), "conv" (ShortConvMixer) or "kda" (models/kda.py),
+    # one entry a layer; empty: attention in every layer
     layer_types: Tuple[str, ...] = ()
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
@@ -218,6 +224,13 @@ class LlamaConfig:
     # the head scores this many tokens ahead of each position, one vocabulary
     # of columns each, all in the objective alike
     n_pred_heads: int = 1
+    # "kda" layers of layer_types (models/kda.py: Kimi Delta Attention):
+    # heads of kda_head_dim for keys and values alike, the width of the three
+    # causal convolutions and the chunk of the scan (ops/kda.py)
+    kda_n_heads: int = 0
+    kda_head_dim: int = 0
+    kda_d_conv: int = 4
+    kda_chunk: int = 64
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -548,14 +561,18 @@ class LatentAttention(nn.Module):
         k = HeadColumns(kv, H, dn, first=0, stride=dn + dv)
         v = HeadColumns(kv, H, dv, first=dn, stride=dn + dv)
         kr = down[:, None, :, rank:]
-        with jax.named_scope("rope"):
-            cos, sin = rope_table(dr, positions, RopeTable(theta=cfg.rope_theta))
-            # the 64 rotary lanes turned as a head of their own and joined to
-            # the rest again: against the whole 192-wide head through the
-            # pass the scope read 7.1 ms a step for 11.7 on the chip (PR 39)
-            q = jnp.concatenate(
-                [q[..., :dn], apply_rope(q[..., dn:], cos, sin)], axis=-1)
-            kr = apply_rope(kr, cos, sin)
+        if cfg.rope:
+            with jax.named_scope("rope"):
+                cos, sin = rope_table(dr, positions,
+                                      RopeTable(theta=cfg.rope_theta))
+                # the 64 rotary lanes turned as a head of their own and
+                # joined to the rest again: against the whole 192-wide head
+                # through the pass the scope read 7.1 ms a step for 11.7 on
+                # the chip (PR 39)
+                q = jnp.concatenate(
+                    [q[..., :dn], apply_rope(q[..., dn:], cos, sin)], axis=-1)
+                kr = apply_rope(kr, cos, sin)
+        # (NoPE: q and the shared key part go to the kernels as projected)
         # the scope tells these calls from another kind's in a trace
         with jax.named_scope("mla"):
             out = _attend(cfg, self.kind, q, k, v, kr)
@@ -645,6 +662,8 @@ class LlamaBlock(nn.Module):
             x = add(x, Mamba2Mixer(cfg, name="mamba")(y))
         elif self.mixer == "conv":
             x = add(x, ShortConvMixer(cfg, name="conv")(y))
+        elif self.mixer == "kda":
+            x = add(x, KDAMixer(cfg, name="kda")(y))
         elif self.mixer in ATTENTION_KINDS:
             attn = LatentAttention(cfg, self.mixer, name="attn") \
                 if cfg.kv_lora_rank else LlamaAttention(
@@ -652,7 +671,8 @@ class LlamaBlock(nn.Module):
             x = add(x, attn(y, positions))
         else:
             raise ValueError(f"unknown layer type {self.mixer!r} (expected "
-                             f"'mamba', 'conv' or one of {ATTENTION_KINDS})")
+                             "'kda', 'mamba', 'conv' or one of "
+                             f"{ATTENTION_KINDS})")
         y = rms_norm(cfg, "mlp_norm")(x)
         if self.routed:
             return add(x, RoutedSwiGLU(RoutedConfig(

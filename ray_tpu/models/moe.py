@@ -224,6 +224,15 @@ class RoutedConfig:
     norm_topk_eps: float = 0.0      # + the chosen scores' sum it divides by
 
 
+def _even_tile(width: int, most: int) -> int:
+    """``min(width, most)``, but for a width past 2,048 that ``most`` does not
+    divide the most whole lanes under ``most`` that do divide it (every width
+    up to 2,048 keeps the tile the chip's clock chose for it)."""
+    if width <= 2048 or width % most == 0:
+        return min(width, most)
+    return max(t for t in range(128, most + 1, 128) if width % t == 0)
+
+
 def _gmm_tiling(m: int, k: int, n: int):
     """(m, k, n) tile of the grouped matmul ``(m, k) x (E, k, n)``, by the
     chip's clock at OLMoE's shapes (PERF.md, PR 25): 256 rows, the whole
@@ -234,8 +243,11 @@ def _gmm_tiling(m: int, k: int, n: int):
     tile again, which is what the 256 trades off.  A width that does not fit
     is cut into whole lanes, as evenly as they allow (an expert 1,408 wide:
     2,048 columns as 2 x 1,024 under a contraction of 1,408, its own 1,408 as
-    768 + 640 under one of 2,048)."""
-    tk = min(k, 2048)
+    768 + 640 under one of 2,048).  A contraction past 2,048 that 2,048 does
+    not divide (2,304: Kimi-Linear's model width) is cut into whole lanes
+    that do, 2 x 1,152, where a tile of 2,048 and a masked one of 256 would
+    stream 4,096."""
+    tk = _even_tile(k, 2048)
     most = (2 << 20) // tk
     if n > most:
         tiles = -(-n // (most // 128 * 128))
@@ -246,8 +258,10 @@ def _gmm_tiling(m: int, k: int, n: int):
 def _tgmm_tiling(m: int, k: int, n: int):
     """Tile of ``(m, k).T x (m, n) -> (E, k, n)``: 256 rows of the reduction,
     a 1024 x 1024 float32 accumulator (two operands' tiles in float32 for
-    the kernel's masks sit beside it)."""
-    return min(256, _round_up(m, 8)), min(k, 1024), min(n, 1024)
+    the kernel's masks sit beside it); a width past 2,048 in whole lanes
+    that divide it (2,304 as 3 x 768), as ``_gmm_tiling`` cuts it."""
+    return (min(256, _round_up(m, 8)), _even_tile(k, 1024),
+            _even_tile(n, 1024))
 
 
 def _gmm(lhs, rhs, sizes, *, transpose_rhs=False):

@@ -1,0 +1,572 @@
+"""Kimi Delta Attention's recurrence (Kimi Linear, arXiv:2510.26692) in
+chunks: a gated delta rule whose decay is a vector a position, one factor a
+channel of the key.  Per head, with ``a_t = exp(g_t)`` in (0, 1]^dk, a scalar
+``b_t`` and a ``dk x dv`` float32 state from zero:
+
+    S_t = (I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T      o_t = S_t^T q_t
+
+The sequence is cut into chunks of ``chunk`` positions.  With ``u_t = b_t (v_t
+- k_t^T Diag(a_t) S_{t-1})`` the update is ``S_t = Diag(a_t) S_{t-1} + k_t
+u_t^T``, so inside a chunk that starts from ``S_0``, with the running
+log-decays ``G_r = sum_{i <= r} g_i``,
+
+    (I + L) U = Diag(b) (V - (K * e^G) S_0)       L_rj = b_r sum_d k_rd k_jd e^(G_rd - G_jd),  j < r
+    O   = (Q * e^G) S_0 + A U                      A_rj = sum_d q_rd k_jd e^(G_rd - G_jd),      j <= r
+    S_C = Diag(e^(G_C)) S_0 + (K * e^(G_C - G))^T U
+
+``I + L`` is unit lower triangular: the solve is a forward substitution.  The
+decay sits inside the contraction over ``dk``, so ``L`` and ``A`` are no
+masked outer products as ``ops/ssd.py``'s are, and ``e^(G_r) * e^(-G_j)``
+overflows within one chunk at a small ``a``.  Every exponent here is a
+difference that is never positive.
+
+``kda_scan`` (what the mixer calls): two Mosaic kernels under one
+``custom_vjp``, the state riding the grid as ``ops/ssd.py``'s does.  A pair
+of positions goes through a position between them, ``(G_r - G_m) + (G_m -
+G_j)`` with both parts <= 0, which leaves a matmul of two scaled operands;
+which ``m`` is the pair's level of the chunk's binary tree (see ``_tree``).
+The running sums and every level's differences come from one exact matmul,
+``(I + L)^-1`` from float32 matmuls (``_unit_lower_inverse``), and the
+backward makes the chunk's forward again from the state before it, which is
+the one residual beside the operands.
+
+``kda_scan_xla``, a yardstick that no model calls (the tests hold it to the
+recurrence, and ``perfbench/tests/kimi_linear_on_chip.py`` times the kernels
+against it): the same chunks as ``jax.numpy`` under a
+``lax.scan``, each chunk's body under ``jax.checkpoint`` so that reverse mode
+keeps the state before a chunk and the operands and nothing else.  A chunk is
+cut into sub-blocks of ``_SUB`` positions; a pair in two different sub-blocks
+goes through the later block's start, a pair inside one takes its own
+difference ``G_r - G_j``, channel by channel, before the ``exp`` and is
+summed over the channels without a matmul; the solve is XLA's triangular
+solve.
+
+Layout: ``q``, ``k``, ``v`` and ``g`` are ``(batch, seq, heads * 128)`` as the
+projections wrote them, a head a block of columns; ``beta`` is ``(batch, seq,
+heads)``.  Matmul operands are in the activations' dtype and accumulate in
+float32; ``g``, the running sums, every ``exp``, the solve and the state are
+float32 whatever the activations are.
+"""
+
+from __future__ import annotations
+
+import functools
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+from jax.experimental import pallas as pl
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.ops.attention import _NN, _NT, _TN, _interpret
+from ray_tpu.parallel.mesh import ambient_mesh
+
+# positions of a sub-block: pairs inside one take their exponent's difference
+# channel by channel; pairs across two go through the MXU
+_SUB = 16
+_MASKED = -1e30
+
+
+def _mm(a, b, spec, dtype):
+    """An einsum of two float32 arrays with its operands in ``dtype`` and a
+    float32 sum, as the MXU takes them."""
+    return jnp.einsum(spec, a.astype(dtype), b.astype(dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _pairs(rows, cols, G, sub: int, dtype):
+    """``sum_d rows_rd cols_jd e^(G_rd - G_jd)`` for ``j <= r`` and zero for
+    ``j > r``: (..., C, C) from (..., C, d) operands, every exponent a
+    difference that is not positive."""
+    *lead, C, d = G.shape
+    n = C // sub
+    blocks = lambda t: t.reshape(*lead, n, sub, d)
+    Gb, rb, cb = blocks(G), blocks(rows), blocks(cols)
+    # a sub-block's running sum before its first position
+    start = jnp.concatenate(
+        [jnp.zeros_like(Gb[..., :1, -1:, :]), Gb[..., :-1, -1:, :]], axis=-3)
+    # across sub-blocks: a row from its block's start, a column up to the
+    # row's block's start (clipped where the column is not before it: masked)
+    to_row = rb * jnp.exp(Gb - start)
+    to_start = cols[..., None, :, :] * jnp.exp(jnp.minimum(
+        start - G[..., None, :, :], 0.0))               # (..., n, C, d)
+    across = _mm(to_row, to_start, "...isd,...ijd->...isj", dtype)
+    before = (jnp.arange(C)[None, None, :] // sub) < jnp.arange(n)[:, None, None]
+    across = jnp.where(before, across, 0.0).reshape(*lead, C, C)
+    # inside a sub-block: the difference itself, masked before the exp
+    seen = jnp.arange(sub)[:, None] >= jnp.arange(sub)[None, :]
+    decay = jnp.exp(jnp.where(
+        seen[:, :, None], Gb[..., :, None, :] - Gb[..., None, :, :], _MASKED))
+    inside = jnp.sum(rb[..., :, None, :] * cb[..., None, :, :] * decay,
+                     axis=-1)                           # (..., n, sub, sub)
+    eye = jnp.eye(n, dtype=inside.dtype)
+    inside = (inside[..., :, :, None, :] * eye[:, None, :, None]
+              ).reshape(*lead, C, C)
+    return across + inside
+
+
+def _chunk(state, q, k, v, g, beta, sub: int, dtype):
+    """One chunk of every head from the state before it: ``state`` (B, H, dk,
+    dv), ``q``, ``k``, ``v``, ``g`` (B, H, C, d) and ``beta`` (B, H, C), all
+    float32 -> (the state after it, the outputs (B, H, C, dv))."""
+    C = q.shape[-2]
+    G = jnp.cumsum(g, axis=-2)
+    end = G[..., -1:, :]
+    strict = jnp.arange(C)[:, None] > jnp.arange(C)[None, :]
+    L = jnp.where(strict, _pairs(k, k, G, sub, dtype), 0.0) \
+        * beta[..., None]
+    A = _pairs(q, k, G, sub, dtype)
+    from_start = jnp.exp(G)
+    rhs = beta[..., None] * (v - _mm(k * from_start, state,
+                                     "...cd,...de->...ce", dtype))
+    U = jax.scipy.linalg.solve_triangular(
+        L + jnp.eye(C, dtype=L.dtype), rhs, lower=True, unit_diagonal=True)
+    out = _mm(q * from_start, state, "...cd,...de->...ce", dtype) \
+        + _mm(A, U, "...cj,...je->...ce", dtype)
+    state = jnp.swapaxes(jnp.exp(end), -1, -2) * state \
+        + _mm(k * jnp.exp(end - G), U, "...cd,...ce->...de", dtype)
+    return state, out
+
+
+def _scan_xla(q, k, v, g, beta, chunk: int):
+    """(B, S, H * d) operands, S whole chunks -> (B, S, H * dv) float32."""
+    batch, seq, heads = beta.shape
+    dtype = q.dtype
+    sub = _SUB if chunk % _SUB == 0 else chunk
+
+    def chunks(t):      # (B, S, H * d) -> (chunks, B, H, C, d)
+        t = t.astype(jnp.float32).reshape(batch, seq // chunk, chunk, heads,
+                                          -1)
+        return t.transpose(1, 0, 3, 2, 4)
+
+    body = jax.checkpoint(functools.partial(_chunk, sub=sub, dtype=dtype))
+
+    def step(state, at):
+        return body(state, *at[:4], at[4][..., 0])
+
+    dk, dv = k.shape[-1] // heads, v.shape[-1] // heads
+    _, out = lax.scan(
+        step, jnp.zeros((batch, heads, dk, dv), jnp.float32),
+        tuple(map(chunks, (q, k, v, g, beta))))
+    return out.transpose(1, 0, 3, 2, 4).reshape(batch, seq, heads * dv)
+
+
+# ------------------------------------------------------------ the kernels
+# A pair of positions r > j of a chunk splits at exactly one level of the
+# chunk's binary tree: the level whose block of 2s positions holds both, r in
+# its second half and j in its first.  Through the first half's last position
+# m the exponent is (G_r - G_m) + (G_m - G_j), both parts <= 0, so a level is
+# one matmul of the operands scaled by E = exp(-|X|), X = N g, with N's rows
+# +1 over (m, r] for a row of a second half and -1 over (r, m] for one of a
+# first half, under the level's mask M.  log2(chunk) levels and the diagonal
+# (exponent 0) are the whole lower triangle, every exponent a difference
+# that is not positive, nothing but whole-tile matmuls and elementwise
+# passes.
+
+
+def _tree(chunk: int):
+    """(N, M, sign): the matrices of the running sum (C, C) and of each
+    level's differences, in {0, 1, -1}, one under the other ((1 + levels) *
+    C, C); per level the pairs' mask M (levels, C, C) and each row's half
+    (levels, C, 1), +1 in a second half and -1 in a first."""
+    import numpy as np
+
+    r, t = np.arange(chunk)[:, None], np.arange(chunk)[None, :]
+    tri = (t <= r).astype(np.float32)
+    ns, ms, signs = [tri], [], []
+    s = chunk // 2
+    while s >= 1:
+        mid = r // (2 * s) * (2 * s) + s - 1
+        ns.append(tri - (t <= mid))
+        ms.append(((r // (2 * s) == t // (2 * s)) & (r % (2 * s) >= s)
+                   & (t % (2 * s) < s)).astype(np.float32))
+        signs.append(np.where(r % (2 * s) >= s, 1.0, -1.0))
+        s //= 2
+    return (jnp.asarray(np.concatenate(ns), jnp.bfloat16), np.stack(ms),
+            np.stack(signs).astype(np.float32))
+
+
+_HIGHEST = lax.Precision.HIGHEST
+
+
+def _dotf(a, b, dims):
+    """A float32 matmul, whole: the running sums, the solve."""
+    return lax.dot_general(a, b, dims, precision=_HIGHEST,
+                           preferred_element_type=jnp.float32)
+
+
+def _pieces(x):
+    """A float32 array as three bfloat16 ones whose sum is it, bit for
+    bit."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    return hi, mid, (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+
+
+def _sums(n, x, dims):
+    """``n`` (entries 0, 1 and -1, bfloat16) against a float32 ``x``, a
+    float32 matmul at a half of its passes: every product is exact and the
+    MXU adds in float32, so only ``x`` is cut into pieces, once for all the
+    levels' matrices."""
+    return sum(lax.dot_general(n, piece, dims,
+                               preferred_element_type=jnp.float32)
+               for piece in _pieces(x))
+
+
+def _dotl(a, b, dims, dtype):
+    """A matmul with its operands in the activations' dtype."""
+    return lax.dot_general(
+        a.astype(dtype), b.astype(dtype), dims,
+        precision=_HIGHEST if dtype == jnp.float32 else None,
+        preferred_element_type=jnp.float32)
+
+
+def _blocks(chunk: int, size: int):
+    """The mask of the diagonal blocks of ``size`` (a power of two)."""
+    shift = size.bit_length() - 1
+    row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    return jnp.right_shift(row, shift) == jnp.right_shift(col, shift)
+
+
+def _unit_lower_inverse(L, chunk: int):
+    """``(I + L)^-1`` for a strictly lower triangular ``L``, float32, or for
+    several of them (C, C) down the diagonal of one matrix (n C, n C): the
+    MXU's pass takes the wider matrix at the price of the narrower.  Inside
+    diagonal blocks of ``_SUB`` the nilpotent product ``(I - L)(I + L^2)(I +
+    L^4) ...``; then blocks are merged two by two up to ``chunk``, ``[[A, 0],
+    [B, D]]^-1 = [[A^-1, 0], [-D^-1 B A^-1, D^-1]]``."""
+    n = L.shape[0]
+    size = min(_SUB, chunk)
+    inside = _blocks(n, size)
+    Ld = jnp.where(inside, L, 0.0)
+    X, power, p = _blocks(n, 1).astype(jnp.float32) - Ld, Ld, 1
+    while 2 * p < size:
+        power = _dotf(power, power, _NN)
+        X = X + _dotf(X, power, _NN)
+        p *= 2
+    while size < chunk:
+        wider = _blocks(n, 2 * size)
+        off = jnp.where(jnp.logical_and(wider, jnp.logical_not(inside)), L,
+                        0.0)
+        X = X - _dotf(X, _dotf(off, X, _NN), _NN)
+        inside, size = wider, 2 * size
+    return X
+
+
+def _inverses(Ls):
+    """``(I + L)^-1`` of each of a head block's ``L`` (C, C): those of two
+    heads side by side in one matrix, twice as wide."""
+    chunk, out = Ls[0].shape[0], []
+    zero = jnp.zeros_like(Ls[0])
+    for a, b in zip(Ls[::2], Ls[1::2]):
+        X = _unit_lower_inverse(jnp.concatenate([
+            jnp.concatenate([a, zero], axis=1),
+            jnp.concatenate([zero, b], axis=1)]), chunk)
+        out += [X[:chunk, :chunk], X[chunk:, chunk:]]
+    if len(Ls) % 2:
+        out.append(_unit_lower_inverse(Ls[-1], chunk))
+    return out
+
+
+class _Inside:
+    """What one head's chunk is made of before the state comes into it, as
+    the forward and the backward both need it: the running sums ``G``, a
+    level's ``E`` and scaled operands, ``A`` and ``L``'s pairs.  ``q``, ``k``,
+    ``g``: (C, d) float32; ``beta``: (C, 1)."""
+
+    def __init__(self, q, k, g, beta, n_ref, m_ref, s_ref, dtype):
+        self.q, self.k, self.beta = q, k, beta
+        chunk = q.shape[0]
+        row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+        col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+        self.eye = (row == col).astype(jnp.float32)
+        self.strict = (row > col).astype(jnp.float32)
+        # the running sums and every level's differences in one matmul
+        sums = _sums(n_ref[...], g, _NN)
+        self.G = sums[:chunk]
+        self.levels = []
+        Akk = jnp.zeros((chunk, chunk), jnp.float32)
+        Aqk = _dotl(q, k, _NT, dtype) * self.eye
+        for i in range(m_ref.shape[0]):
+            sign = s_ref[i]
+            E = jnp.exp(jnp.minimum(
+                sign * sums[(i + 1) * chunk:(i + 2) * chunk], 0.0))
+            both, M = jnp.concatenate([q * E, k * E]), m_ref[i]
+            pairs = _dotl(both, both[chunk:], _NT, dtype)   # q.k over k.k
+            Aqk, Akk = Aqk + M * pairs[:chunk], Akk + M * pairs[chunk:]
+            self.levels.append((sign, E, both, M))
+        self.Akk, self.Aqk = Akk, Aqk
+        self.from_start = jnp.exp(self.G)
+        self.end = self.G[chunk - 1:chunk]                  # (1, d)
+        self.to_end = jnp.exp(self.end - self.G)
+
+
+def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
+                    o_ref, before_ref, state, *, hb: int, d: int):
+    """A chunk of one head block.  ``state``: the block's heads' states,
+    each (dv, dk) — the key's channels along the lanes, where a decay a
+    channel is a row — carried from chunk to chunk."""
+    dtype = q_ref.dtype
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        state[...] = jnp.zeros_like(state)
+
+    before_ref[0, 0] = state[...]
+    heads = []
+    for h in range(hb):
+        at = slice(h * d, (h + 1) * d)
+        q, k, v = (r[0, :, at].astype(jnp.float32)
+                   for r in (q_ref, k_ref, v_ref))
+        heads.append((at, v, _Inside(q, k, g_ref[0, :, at],
+                                     b_ref[0, 0, :, h:h + 1], n_ref, m_ref,
+                                     s_ref, dtype)))
+    inverses = _inverses([c.beta * c.Akk for _, _, c in heads])
+    outs = []
+    for (at, v, c), inverse in zip(heads, inverses):
+        q, k, beta, S = c.q, c.k, c.beta, state[at, :]
+        rhs = beta * (v - _dotl(k * c.from_start, S, _NT, dtype))
+        U = _dotf(inverse, rhs, _NN)
+        outs.append((_dotl(q * c.from_start, S, _NT, dtype)
+                     + _dotl(c.Aqk, U, _NN, dtype)).astype(o_ref.dtype))
+        state[at, :] = jnp.exp(c.end) * S + _dotl(U, k * c.to_end, _TN, dtype)
+    o_ref[0] = outs[0] if hb == 1 else jnp.concatenate(outs, axis=1)
+
+
+def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
+                    before_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref,
+                    db_ref, dstate, *, hb: int, d: int):
+    """A chunk of one head block, the chunks visited last to first (the index
+    maps turn the axis): ``dstate`` holds the cotangent of the states after
+    the chunk.  The chunk's forward is made again from the state before it."""
+    dtype = q_ref.dtype
+    chunk = q_ref.shape[1]
+
+    @pl.when(pl.program_id(2) == 0)
+    def _():
+        dstate[...] = jnp.zeros_like(dstate)
+
+    lane = lax.broadcasted_iota(jnp.int32, (chunk, hb), 1)
+    dqs, dks, dvs, dgs = [], [], [], []
+    dbeta = jnp.zeros((chunk, hb), jnp.float32)
+    heads = []
+    for h in range(hb):
+        at = slice(h * d, (h + 1) * d)
+        q, k, v, do = (r[0, :, at].astype(jnp.float32)
+                       for r in (q_ref, k_ref, v_ref, do_ref))
+        heads.append((h, at, v, do, _Inside(
+            q, k, g_ref[0, :, at], b_ref[0, 0, :, h:h + 1], n_ref, m_ref,
+            s_ref, dtype)))
+    inverses = _inverses([c.beta * c.Akk for *_, c in heads])
+    for (h, at, v, do, c), inverse in zip(heads, inverses):
+        q, k, beta = c.q, c.k, c.beta
+        S, dS = before_ref[0, 0, at, :], dstate[at, :]
+        kg, qg, kend = k * c.from_start, q * c.from_start, k * c.to_end
+        rest = v - _dotl(kg, S, _NT, dtype)
+        U = _dotf(inverse, beta * rest, _NN)
+        # the read-out and the state's update
+        dU = _dotl(c.Aqk, do, _TN, dtype) + _dotl(kend, dS, _NT, dtype)
+        dAqk = (c.strict + c.eye) * _dotl(do, U, _NT, dtype)
+        dqg = _dotl(do, S, _NN, dtype)
+        dkend = _dotl(U, dS, _NN, dtype)
+        e_end = jnp.exp(c.end)
+        dS_before = e_end * dS + _dotl(do, qg, _TN, dtype)
+        d_end = jnp.sum(e_end * S * dS, axis=0, keepdims=True) \
+            + jnp.sum(dkend * kend, axis=0, keepdims=True)
+        # the solve
+        drhs = _dotf(inverse, dU, _TN)
+        dL = -c.strict * _dotf(drhs, U, _NT)
+        db = jnp.sum(drhs * rest, axis=1, keepdims=True) \
+            + jnp.sum(dL * c.Akk, axis=1, keepdims=True)
+        dbeta = jnp.where(lane == h, db, dbeta)
+        drest = beta * drhs
+        dkg = -_dotl(drest, S, _NN, dtype)
+        dS_before = dS_before - _dotl(drest, kg, _TN, dtype)
+        dAkk = beta * dL
+        # the operands through the running sums from the chunk's start and to
+        # its end, and the diagonal
+        on_diagonal = jnp.sum(c.eye * dAqk, axis=1, keepdims=True)
+        dq = dqg * c.from_start + on_diagonal * k
+        dk = dkg * c.from_start + dkend * c.to_end + on_diagonal * q
+        dsums = [dkg * kg + dqg * qg - dkend * kend]
+        for sign, E, both, M in c.levels:
+            # the level's q.k pairs over its k.k pairs, as the forward's
+            dpairs = jnp.concatenate([M * dAqk, M * dAkk])
+            by_rows = _dotl(dpairs, both[chunk:], _NN, dtype)
+            dKe = by_rows[chunk:] + _dotl(dpairs, both, _TN, dtype)
+            dQe = by_rows[:chunk]
+            dq, dk = dq + dQe * E, dk + dKe * E
+            dsums.append(sign * E * (dQe * q + dKe * k))
+        dg = _sums(n_ref[...], jnp.concatenate(dsums), _TN) + d_end
+        dstate[at, :] = dS_before
+        dqs.append(dq.astype(dq_ref.dtype))
+        dks.append(dk.astype(dk_ref.dtype))
+        dvs.append(drest.astype(dv_ref.dtype))
+        dgs.append(dg)
+    for ref, parts in ((dq_ref, dqs), (dk_ref, dks), (dv_ref, dvs),
+                       (dg_ref, dgs)):
+        ref[0] = parts[0] if hb == 1 else jnp.concatenate(parts, axis=1)
+    db_ref[0, 0] = dbeta
+
+
+# the most heads a grid step takes: its share of a step's fixed cost against
+# the size of the unrolled body
+_HEAD_BLOCK = 4
+
+
+class _Shape:
+    """The sizes of one device's call and the blocks of its grid: (batch,
+    head block, chunk), the chunks in sequence."""
+
+    def __init__(self, q, beta, chunk: int):
+        self.batch, self.seq, width = q.shape
+        self.heads = beta.shape[-1]
+        self.d, self.chunk = width // self.heads, chunk
+        self.hb = max(n for n in range(1, _HEAD_BLOCK + 1)
+                      if self.heads % n == 0)
+        self.blocks, self.chunks = self.heads // self.hb, self.seq // chunk
+        self.tree = tuple(map(jnp.asarray, _tree(chunk)))
+
+    def specs(self, turned: bool):
+        last = self.chunks - 1
+
+        def at(ic):
+            return last - ic if turned else ic
+
+        wide = pl.BlockSpec((1, self.chunk, self.hb * self.d),
+                            lambda ib, ih, ic: (ib, at(ic), ih))
+        column = pl.BlockSpec((1, 1, self.chunk, self.hb),
+                              lambda ib, ih, ic: (ib, ih, at(ic), 0))
+        before = pl.BlockSpec((1, 1, self.hb * self.d, self.d),
+                              lambda ib, ih, ic: (ib, at(ic), ih, 0))
+        whole = [pl.BlockSpec(t.shape, lambda ib, ih, ic, n=t.ndim: (0,) * n)
+                 for t in self.tree]
+        return wide, column, before, whole
+
+    def columns(self, t):
+        """(batch, seq, heads) as (batch, blocks, seq, hb)."""
+        return t.reshape(self.batch, self.seq, self.blocks, self.hb
+                         ).transpose(0, 2, 1, 3)
+
+    def rows(self, t):
+        return t.transpose(0, 2, 1, 3).reshape(self.batch, self.seq,
+                                               self.heads)
+
+    def call(self, kernel, name, **kwargs):
+        from jax.experimental.pallas import tpu as pltpu
+
+        return pl.pallas_call(
+            functools.partial(kernel, hb=self.hb, d=self.d),
+            grid=(self.batch, self.blocks, self.chunks),
+            compiler_params=pltpu.CompilerParams(
+                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                vmem_limit_bytes=64 << 20),
+            interpret=_interpret(), name=name, **kwargs)
+
+
+@functools.partial(jax.jit, static_argnums=(5,), inline=True)
+def _forward(q, k, v, g, beta, chunk: int):
+    """``o`` and the float32 states before every chunk, (batch, chunks,
+    heads * dv, dk).  Jitted and inlined as ``ops/ssd.py``'s."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    s = _Shape(q, beta, chunk)
+    wide, column, before, whole = s.specs(False)
+    return s.call(
+        _kda_fwd_kernel, "kda_fwd",
+        in_specs=[wide] * 4 + [column] + whole,
+        out_specs=[wide, before],
+        out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
+                   jax.ShapeDtypeStruct(
+                       (s.batch, s.chunks, s.heads * s.d, s.d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((s.hb * s.d, s.d), jnp.float32)],
+    )(q, k, v, g, s.columns(beta), *s.tree)
+
+
+@functools.partial(jax.jit, static_argnums=(7,), inline=True)
+def _backward(q, k, v, g, beta, before, do, chunk: int):
+    from jax.experimental.pallas import tpu as pltpu
+
+    s = _Shape(q, beta, chunk)
+    wide, column, state, whole = s.specs(True)
+    dq, dk, dv, dg, db = s.call(
+        _kda_bwd_kernel, "kda_bwd",
+        in_specs=[wide] * 4 + [column] + whole + [state, wide],
+        out_specs=[wide] * 4 + [column],
+        out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype)
+                   for t in (q, k, v, g)]
+        + [jax.ShapeDtypeStruct((s.batch, s.blocks, s.seq, s.hb),
+                                jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((s.hb * s.d, s.d), jnp.float32)],
+    )(q, k, v, g, s.columns(beta), *s.tree, before, do)
+    return dq, dk, dv, dg, s.rows(db)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5,))
+def _scan_kernels(q, k, v, g, beta, chunk):
+    """The kernels under their one differentiation rule: (batch, seq, heads *
+    d) operands, seq whole chunks; ``g`` and ``beta`` float32."""
+    return _forward(q, k, v, g, beta, chunk)[0]
+
+
+def _scan_kernels_fwd(q, k, v, g, beta, chunk):
+    o, before = _forward(q, k, v, g, beta, chunk)
+    return o, (q, k, v, g, beta, before)
+
+
+def _scan_kernels_bwd(chunk, residuals, do):
+    return _backward(*residuals, do, chunk)
+
+
+_scan_kernels.defvjp(_scan_kernels_fwd, _scan_kernels_bwd)
+
+
+def _scan_pallas(q, k, v, g, beta, chunk: int):
+    """Under an ambient mesh of more than one device the calls run inside a
+    ``shard_map`` — batch over dp/fsdp, heads over tp — since GSPMD cannot
+    partition a Mosaic call."""
+    if chunk & (chunk - 1):
+        raise ValueError(f"the kernels' chunk is a power of two, not {chunk}")
+    mesh = ambient_mesh()
+    if mesh is None or mesh.size == 1:
+        return _scan_kernels(q, k, v, g, beta, chunk)
+    tp = mesh.shape.get("tp", 1)
+    if beta.shape[-1] % tp:
+        raise ValueError(f"{beta.shape[-1]} heads over tp={tp}")
+    rows = tuple(a for a in ("dp", "fsdp") if a in mesh.shape) or None
+    by_head = P(rows, None, "tp" if tp > 1 else None)
+    return jax.shard_map(
+        functools.partial(_scan_kernels, chunk=chunk), mesh=mesh,
+        in_specs=(by_head,) * 5, out_specs=by_head, check_vma=False,
+    )(q, k, v, g, beta)
+
+
+def _whole_chunks(scan, q, k, v, g, beta, chunk: int):
+    """``scan`` on the operands padded at the sequence's end to whole chunks
+    with ``g = 0`` and ``beta = 0`` — a step that neither decays the state
+    nor writes to it —, the padding's outputs dropped; ``g`` and ``beta``
+    float32, the result in ``v``'s dtype."""
+    seq = q.shape[1]
+    pad = -seq % chunk
+    g, beta = g.astype(jnp.float32), beta.astype(jnp.float32)
+    if pad:
+        q, k, v, g, beta = (jnp.pad(t, [(0, 0), (0, pad), (0, 0)])
+                            for t in (q, k, v, g, beta))
+    return scan(q, k, v, g, beta, chunk)[:, :seq].astype(v.dtype)
+
+
+def kda_scan(q, k, v, g, beta, *, chunk: int = 64):
+    """``q``, ``k``: (batch, seq, heads * dk); ``v``: (batch, seq, heads *
+    dv); ``g``: (batch, seq, heads * dk), the log-decays, never positive;
+    ``beta``: (batch, seq, heads).  Returns ``o`` (batch, seq, heads * dv) in
+    ``v``'s dtype, from a zero state; a sequence may be any length (see
+    ``_whole_chunks``)."""
+    return _whole_chunks(_scan_pallas, q, k, v, g, beta, chunk)
+
+
+def kda_scan_xla(q, k, v, g, beta, *, chunk: int = 64):
+    """``kda_scan`` as ``jax.numpy`` with XLA's own derivative: the yardstick
+    of the tests and of the on-chip timing."""
+    return _whole_chunks(_scan_xla, q, k, v, g, beta, chunk)

@@ -107,6 +107,20 @@ def llama_partition_rules() -> PartitionRules:
         (r"conv/in_proj/kernel", _spec("fsdp", None, "tp")),
         (r"conv/out_proj/kernel", _spec("tp", "fsdp")),
         (r"conv/conv_kernel", _spec(None, "tp")),
+        # Kimi Delta Attention layers (models/kda.py::KDAMixer as h_<n>/kda):
+        # what lies between the projections is a head's own, so the three
+        # projections, the up side of the two low-rank maps (and g_b's bias),
+        # the write strengths, the convolutions' kernels, A_log and dt_bias
+        # are cut by head and the scan needs no collective under tp; the
+        # low-rank maps' down side belongs to no head (o_norm's scale, one for
+        # all heads: replicated, below)
+        (r"kda/(q_proj|k_proj|v_proj|b_proj)/kernel", _spec("fsdp", "tp")),
+        (r"kda/(f_a|g_a)/kernel", _spec("fsdp", None)),
+        (r"kda/(f_b|g_b)/kernel", _spec(None, "tp")),
+        (r"kda/g_b/bias", _spec("tp")),
+        (r"kda/o_proj/kernel", _spec("tp", "fsdp")),
+        (r"kda/(q|k|v)_conv", _spec(None, "tp")),
+        (r"kda/(A_log|dt_bias)$", _spec("tp")),
         # attn_norm, mlp_norm, norm_f, the q_norm / k_norm scales and the
         # mixer's norm_scale
         (r"norm|scale", _spec()),

@@ -70,6 +70,7 @@ pre-flight before spending chip time (``.claude/skills/verify/SKILL.md``).
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import subprocess
@@ -125,6 +126,8 @@ def _build(case: str, compile_: bool) -> dict:
         chips_per_host_bounds=[2, 2, 1], num_slices=1)
     if case.startswith("flash_s"):
         return _build_flash(case, topo.devices[0])
+    if case.startswith("kda_s"):
+        return _build_kda(case, topo.devices[0])
     if case in PRELUDES:
         return _build_prelude(case, topo.devices[0])
     if case in LAYOUTS:
@@ -174,6 +177,15 @@ def _build(case: str, compile_: bool) -> dict:
         assert config.n_pred_heads == 8 and config.vocab_size == 320
         assert config.norm_unit_offset and config.residual_dtype == jnp.float32
         assert seq == 16384
+    elif case == "kimi_linear":
+        mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+        (config, seq), rows = _cell("kimi-linear-s16k-1chip"), 1
+        assert config.experts_held == (0, 8) and config.n_experts == 256
+        assert config.layer_types == ("kda",) * 3 + ("full_attention", "kda")
+        assert (config.kda_n_heads, config.kda_head_dim, config.kda_chunk,
+                config.kda_d_conv) == (32, 128, 64, 4)
+        assert not config.rope and config.kv_lora_rank == 512
+        assert (config.d_model, config.n_layer, seq) == (2304, 5, 16384)
     elif case == "granite":
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
         (config, seq), rows = _cell("granite-h-s8k-1chip"), 1
@@ -268,6 +280,42 @@ def _build_flash(case: str, device) -> dict:
     (results,) = re.findall(r"= \((.*?)\) custom-call\(.*flash_bwd", text)
     return {"case": case, "dk_heads": int(
         re.findall(r"\w+\[([\d,]+)\]", results)[1].split(",")[1])}
+
+
+def _build_kda(case: str, device) -> dict:
+    """In the child: compile ``ops/kda.py``'s kernels alone, forward and
+    backward, for one chip at ``kda_s<seq>`` in bf16 over 32 heads of 128 at
+    chunks of 64: the Mosaic calls of the compiled program."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.kda import kda_scan
+
+    seq, heads, d = int(case.split("_s")[1]), 32, 128
+
+    def shape(width, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((1, seq, width), dtype,
+                                    sharding=SingleDeviceSharding(device))
+
+    def grads(q, k, v, g, beta, do):
+        out, vjp = jax.vjp(functools.partial(kda_scan, chunk=64),
+                           q, k, v, g, beta)
+        return out, vjp(do)
+
+    wide = shape(heads * d)
+    try:
+        compiled = jax.jit(grads).lower(
+            wide, wide, wide, shape(heads * d, jnp.float32),
+            shape(heads, jnp.float32), wide).compile()
+    except Exception as e:  # what Mosaic or the TPU compiler refuses
+        return {"case": case, "refused": str(e)[-1500:]}
+    mem = compiled.memory_analysis()
+    return {"case": case, "tpu_custom_calls": len(re.findall(
+        r'custom_call_target="tpu_custom_call"', compiled.as_text())),
+        "temp_bytes": int(mem.temp_size_in_bytes)}
 
 
 def _build_prelude(case: str, device) -> dict:
@@ -912,6 +960,53 @@ def test_lfm2_step_compiles_and_fits_the_chip():
     # that add rows into tokens, as Kimi's: 2 + 4 x (12 + 2)
     assert row["tpu_custom_calls"] == 2 + 4 * (12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
+
+
+def test_kimi_linear_step_lowers_for_one_v5e_chip():
+    """Tier-1: the one-chip step of Kimi-Linear-48B-A3B at published widths
+    (KDA + dense; KDA, KDA, MLA, KDA sparse; 8 of 256 experts held, one row
+    of 16,384) lowers for the TPU with its Mosaic kernels in it: the scan's
+    pair (``ops/kda.py``) in the four KDA layers, the flash pair of the one
+    latent-attention layer, the grouped matmuls of the held experts under a
+    contraction of 2,304 and the sum of their rows into the tokens, and no
+    other — the convolutions, the gates and the norms are plain XLA."""
+    row = _child(["kimi_linear"], compile_=False)["kimi_linear"]
+    kernels = row["lowered_kernels"]
+    assert kernels.pop("kernel") > 0
+    assert set(kernels) == {"kda_fwd", "kda_bwd", "flash_fwd", "flash_bwd",
+                            "onto_tokens"}, kernels
+    assert kernels["flash_fwd"] == kernels["flash_bwd"] == 1, kernels
+    assert row["flash_fwd_calls"] == 1, row
+
+
+@pytest.mark.slow
+def test_kimi_linear_step_compiles_and_fits_the_chip():
+    """The TPU compiler takes the step — the scan's two kernels with their
+    float32 solve and the state riding the grid, the flash kernels at 32
+    heads with an un-rotated shared key part, the grouped matmuls under a
+    contraction of 2,304 cut in two — and its memory analysis says five
+    layers fit one chip at one row of 16,384 beside 9.64 GB of state (PR 53:
+    7.23 GB of arguments + 6.24 GB of temporaries; see PERF.md)."""
+    row = _child(["kimi_linear"], compile_=True)["kimi_linear"]
+    print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
+    assert "refused" not in row, row
+    # a KDA layer: the scan's forward, again under remat, and its backward;
+    # the MLA layer: flash forward and the backward's one kernel; a sparse
+    # layer's held experts: twelve grouped-matmul calls and the two that add
+    # rows into tokens, as Kimi-VL's: 4 x 3 + 2 + 4 x (12 + 2)
+    assert row["tpu_custom_calls"] == 4 * 3 + 2 + 4 * (12 + 2), row
+    assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
+
+
+def test_kda_kernels_compile_for_one_v5e_chip():
+    """Tier-1, fifteen seconds: Mosaic takes ``ops/kda.py``'s two kernels at
+    the cell's shape (1 x 16,384 x 32 heads x 128, chunks of 64, bf16) — the
+    levels' matmuls, two heads' solves side by side in one 128-wide matrix,
+    the float32 sums as three bfloat16 passes —, which the interpreter on
+    the CPU cannot say."""
+    row = _child(["kda_s16384"], compile_=True)["kda_s16384"]
+    assert "refused" not in row, row
+    assert row["tpu_custom_calls"] == 2, row    # a forward and a backward
 
 
 def test_evabyte_step_lowers_for_one_v5e_chip():

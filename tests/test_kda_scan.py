@@ -1,0 +1,142 @@
+"""``ops/kda.py``'s chunked scan against Kimi Delta Attention's recurrence one
+position at a time, in float32 on the CPU: outputs and the gradient of every
+operand, for the ``jax.numpy`` form and for the Mosaic kernels under the
+Pallas interpreter, at one chunk, several, a partial last chunk and two rows,
+at decays so small that ``e^(G_r) * e^(-G_j)`` would overflow inside a chunk,
+and that no position reads a later one.
+
+Tolerances.  Float32 against float32 at matmul precision 'highest' differ by
+summation order and by the solve's: 1e-4 of the largest value for outputs,
+1e-3 of a gradient's largest value (``tests/test_mamba.py``'s, for the same
+reason).
+"""
+
+import functools
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import kda
+
+# the yardstick as ``jax.numpy`` and the Mosaic kernels the mixer calls
+FORMS = {"xla": kda.kda_scan_xla, "pallas": kda.kda_scan}
+IMPLS = tuple(FORMS)
+
+
+def recurrence(q, k, v, g, beta):
+    """``S_t = (I - b_t k_t k_t^T) Diag(e^(g_t)) S_{t-1} + b_t k_t v_t^T``,
+    ``o_t = S_t^T q_t``, one position at a time from a zero state.  (B, S,
+    H * d) operands as ``kda_scan`` takes them."""
+    batch, seq, heads = beta.shape
+    q, k, v, g = (t.reshape(batch, seq, heads, -1) for t in (q, k, v, g))
+
+    def step(S, at):
+        q_t, k_t, v_t, g_t, b_t = at
+        S = jnp.exp(g_t)[..., None] * S
+        S = S + (b_t[..., None] * k_t)[..., None] * (
+            v_t - jnp.einsum("bhd,bhde->bhe", k_t, S))[..., None, :]
+        return S, jnp.einsum("bhde,bhd->bhe", S, q_t)
+
+    _, o = jax.lax.scan(
+        step, jnp.zeros((batch, heads, k.shape[-1], v.shape[-1])),
+        tuple(jnp.moveaxis(t, 1, 0) for t in (q, k, v, g, beta)))
+    return jnp.moveaxis(o, 0, 1).reshape(batch, seq, -1)
+
+
+def operands(seq, batch=1, heads=2, d=16, decay=1.0, seed=0):
+    """q and k of unit length a head (q scaled as the mixer scales it), as
+    the layer makes them; ``decay`` multiplies the log-decays."""
+    ks = jax.random.split(jax.random.PRNGKey(seed * 1000 + seq), 5)
+
+    def unit(key):
+        t = jax.random.normal(key, (batch, seq, heads, d))
+        return (t / jnp.linalg.norm(t, axis=-1, keepdims=True)).reshape(
+            batch, seq, heads * d)
+
+    g = -decay * jax.nn.softplus(jax.random.normal(ks[3],
+                                                   (batch, seq, heads * d)))
+    return (unit(ks[0]) * d ** -0.5, unit(ks[1]),
+            jax.random.normal(ks[2], (batch, seq, heads * d)), g,
+            jax.nn.sigmoid(jax.random.normal(ks[4], (batch, seq, heads))))
+
+
+def _close(got, want, rel, what):
+    scale = float(jnp.max(jnp.abs(want)))
+    assert np.isfinite(np.asarray(got)).all(), what
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=0,
+                               atol=rel * max(scale, 1e-30), err_msg=what)
+
+
+# one chunk; several; a partial last chunk; shorter than a chunk; two rows
+SHAPES = [(8, 1), (32, 1), (37, 1), (5, 1), (24, 2)]
+
+
+@functools.lru_cache(maxsize=None)
+def _by_the_recurrence(seq, batch=1, decay=1.0, seed=0):
+    """(operands, the weights of the loss, the recurrence's outputs, its
+    gradients), once for both forms."""
+    args = operands(seq, batch, decay=decay, seed=seed)
+    weight = jax.random.normal(jax.random.PRNGKey(7), args[2].shape)
+    with jax.default_matmul_precision("highest"):
+        out, grads = jax.jit(lambda *a: (recurrence(*a), jax.grad(
+            lambda *b: jnp.sum(recurrence(*b) * weight),
+            argnums=range(5))(*a)))(*args)
+    return args, weight, out, grads
+
+
+def _held_to_the_recurrence(impl, chunk, *key):
+    args, weight, out, want = _by_the_recurrence(*key)
+    scan = functools.partial(FORMS[impl], chunk=chunk)
+    with jax.default_matmul_precision("highest"):
+        got_out, got = jax.jit(lambda *a: (scan(*a), jax.grad(
+            lambda *b: jnp.sum(scan(*b) * weight),
+            argnums=range(5))(*a)))(*args)
+    _close(got_out, out, 1e-4, "outputs")
+    for name, a, b in zip("q k v g beta".split(), got, want):
+        _close(a, b, 1e-3, f"d{name}")
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("seq,batch", SHAPES)
+def test_a_chunked_scan_equals_the_recurrence(seq, batch, impl):
+    _held_to_the_recurrence(impl, 8, seq, batch)
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+@pytest.mark.parametrize("chunk,seq", [(8, 24), (32, 64)])
+def test_a_decays_that_a_split_exponent_cannot_hold(chunk, seq, impl):
+    """Log-decays near -30 a step: over a chunk of 8 the running sum passes
+    -200 and ``e^(-G_j)`` is past float32 (``e^88``); over the sixteen
+    positions of a sub-block of a chunk of 32 it passes -400.  Outputs and
+    gradients stay finite and equal the recurrence's."""
+    args = _by_the_recurrence(seq, 1, 40.0, 1)[0]
+    assert float(jnp.min(jnp.cumsum(args[3][:, :8], axis=1))) < -150
+    _held_to_the_recurrence(impl, chunk, seq, 1, 40.0, 1)
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+def test_a_position_reads_no_later_input(impl):
+    """Every operand changed from position 13 on: outputs before it are the
+    same bits."""
+    args = operands(29)
+    other = operands(29, seed=3)
+    mixed = tuple(jnp.concatenate([a[:, :13], b[:, 13:]], axis=1)
+                  for a, b in zip(args, other))
+    scan = functools.partial(FORMS[impl], chunk=8)
+    np.testing.assert_array_equal(np.asarray(scan(*args))[:, :13],
+                                  np.asarray(scan(*mixed))[:, :13])
+
+
+@pytest.mark.parametrize("impl", IMPLS)
+def test_a_low_precision_operands_keep_a_float32_state(impl):
+    """bf16 q, k, v: the result is bf16, within bf16's rounding of the
+    float32 recurrence on the same rounded operands, and the log-decays are
+    never rounded (a bf16 running sum over a chunk is another model)."""
+    q, k, v, g, beta = operands(32)
+    low = tuple(t.astype(jnp.bfloat16) for t in (q, k, v))
+    got = FORMS[impl](*low, g, beta, chunk=8)
+    assert got.dtype == jnp.bfloat16
+    want = recurrence(*(t.astype(jnp.float32) for t in low), g, beta)
+    _close(got.astype(jnp.float32), want, 3e-2, "bf16 outputs")
