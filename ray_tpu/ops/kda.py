@@ -26,9 +26,18 @@ of positions goes through a position between them, ``(G_r - G_m) + (G_m -
 G_j)`` with both parts <= 0, which leaves a matmul of two scaled operands;
 which ``m`` is the pair's level of the chunk's binary tree (see ``_tree``).
 The running sums and every level's differences come from one exact matmul,
-``(I + L)^-1`` from float32 matmuls (``_unit_lower_inverse``), and the
-backward makes the chunk's forward again from the state before it, which is
-the one residual beside the operands.
+``(I + L)^-1`` from float32 matmuls (``_unit_lower_inverse``).  The backward
+makes the chunk's forward again from two float32 residuals beside the
+operands, both written by the forward a chunk at a time: the state before the
+chunk (64 KB a head a chunk at 128 x 128; 0.54 GB a layer at 32 heads x 256
+chunks) and the chunk's ``(I + L)^-1`` (16 KB a head at a chunk of 64, a head
+block's side by side; 0.13 GB a layer).  The inverse because it is the
+dearest thing a byte that the backward would make again: ten dependent
+float32 matmuls, 30 of a head's some 86 MXU passes, from ``k``, ``g`` and
+``beta`` alone; ``U``, ``A`` and ``L``'s pairs together are 56 KB a head a
+chunk for a third of that, and the levels' ``E`` the gradients need anyway.
+Under a rematerialised block both residuals live from the layer's second
+forward to its backward: one layer's at a time.
 
 ``kda_scan_xla``, a yardstick that no model calls (the tests hold it to the
 recurrence, and ``perfbench/tests/kimi_linear_on_chip.py`` times the kernels
@@ -222,6 +231,11 @@ def _dotl(a, b, dims, dtype):
         preferred_element_type=jnp.float32)
 
 
+def _beside(parts):
+    """A head block's arrays, one a head, side by side along the lanes."""
+    return parts[0] if len(parts) == 1 else jnp.concatenate(parts, axis=1)
+
+
 def _blocks(chunk: int, size: int):
     """The mask of the diagonal blocks of ``size`` (a power of two)."""
     shift = size.bit_length() - 1
@@ -304,10 +318,13 @@ class _Inside:
 
 
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
-                    o_ref, before_ref, state, *, hb: int, d: int):
+                    o_ref, before_ref, inverse_ref, state, *, hb: int,
+                    d: int):
     """A chunk of one head block.  ``state``: the block's heads' states,
     each (dv, dk) — the key's channels along the lanes, where a decay a
-    channel is a row — carried from chunk to chunk."""
+    channel is a row — carried from chunk to chunk.  The backward's two
+    residuals: the states before the chunk, and the heads' ``(I + L)^-1``
+    side by side, (C, hb * C)."""
     dtype = q_ref.dtype
 
     @pl.when(pl.program_id(2) == 0)
@@ -324,6 +341,7 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
                                      b_ref[0, 0, :, h:h + 1], n_ref, m_ref,
                                      s_ref, dtype)))
     inverses = _inverses([c.beta * c.Akk for _, _, c in heads])
+    inverse_ref[0, 0] = _beside(inverses)
     outs = []
     for (at, v, c), inverse in zip(heads, inverses):
         q, k, beta, S = c.q, c.k, c.beta, state[at, :]
@@ -332,15 +350,17 @@ def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
         outs.append((_dotl(q * c.from_start, S, _NT, dtype)
                      + _dotl(c.Aqk, U, _NN, dtype)).astype(o_ref.dtype))
         state[at, :] = jnp.exp(c.end) * S + _dotl(U, k * c.to_end, _TN, dtype)
-    o_ref[0] = outs[0] if hb == 1 else jnp.concatenate(outs, axis=1)
+    o_ref[0] = _beside(outs)
 
 
 def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
-                    before_ref, do_ref, dq_ref, dk_ref, dv_ref, dg_ref,
-                    db_ref, dstate, *, hb: int, d: int):
+                    before_ref, inverse_ref, do_ref, dq_ref, dk_ref, dv_ref,
+                    dg_ref, db_ref, dstate, *, hb: int, d: int):
     """A chunk of one head block, the chunks visited last to first (the index
     maps turn the axis): ``dstate`` holds the cotangent of the states after
-    the chunk.  The chunk's forward is made again from the state before it."""
+    the chunk.  The chunk's forward is made again from the state before it
+    and from the forward's ``(I + L)^-1``: the levels, which the gradients
+    need, are made here; the solve's thirty passes a head are not."""
     dtype = q_ref.dtype
     chunk = q_ref.shape[1]
 
@@ -351,17 +371,14 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
     lane = lax.broadcasted_iota(jnp.int32, (chunk, hb), 1)
     dqs, dks, dvs, dgs = [], [], [], []
     dbeta = jnp.zeros((chunk, hb), jnp.float32)
-    heads = []
     for h in range(hb):
         at = slice(h * d, (h + 1) * d)
         q, k, v, do = (r[0, :, at].astype(jnp.float32)
                        for r in (q_ref, k_ref, v_ref, do_ref))
-        heads.append((h, at, v, do, _Inside(
-            q, k, g_ref[0, :, at], b_ref[0, 0, :, h:h + 1], n_ref, m_ref,
-            s_ref, dtype)))
-    inverses = _inverses([c.beta * c.Akk for *_, c in heads])
-    for (h, at, v, do, c), inverse in zip(heads, inverses):
-        q, k, beta = c.q, c.k, c.beta
+        c = _Inside(q, k, g_ref[0, :, at], b_ref[0, 0, :, h:h + 1], n_ref,
+                    m_ref, s_ref, dtype)
+        beta = c.beta
+        inverse = inverse_ref[0, 0, :, h * chunk:(h + 1) * chunk]
         S, dS = before_ref[0, 0, at, :], dstate[at, :]
         kg, qg, kend = k * c.from_start, q * c.from_start, k * c.to_end
         rest = v - _dotl(kg, S, _NT, dtype)
@@ -407,7 +424,7 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
         dgs.append(dg)
     for ref, parts in ((dq_ref, dqs), (dk_ref, dks), (dv_ref, dvs),
                        (dg_ref, dgs)):
-        ref[0] = parts[0] if hb == 1 else jnp.concatenate(parts, axis=1)
+        ref[0] = _beside(parts)
     db_ref[0, 0] = dbeta
 
 
@@ -441,9 +458,11 @@ class _Shape:
                               lambda ib, ih, ic: (ib, ih, at(ic), 0))
         before = pl.BlockSpec((1, 1, self.hb * self.d, self.d),
                               lambda ib, ih, ic: (ib, at(ic), ih, 0))
+        inverse = pl.BlockSpec((1, 1, self.chunk, self.hb * self.chunk),
+                               lambda ib, ih, ic: (ib, ih, at(ic), 0))
         whole = [pl.BlockSpec(t.shape, lambda ib, ih, ic, n=t.ndim: (0,) * n)
                  for t in self.tree]
-        return wide, column, before, whole
+        return wide, column, before, inverse, whole
 
     def columns(self, t):
         """(batch, seq, heads) as (batch, blocks, seq, hb)."""
@@ -468,39 +487,44 @@ class _Shape:
 
 @functools.partial(jax.jit, static_argnums=(5,), inline=True)
 def _forward(q, k, v, g, beta, chunk: int):
-    """``o`` and the float32 states before every chunk, (batch, chunks,
-    heads * dv, dk).  Jitted and inlined as ``ops/ssd.py``'s."""
+    """``o`` and the backward's two float32 residuals: the states before
+    every chunk, (batch, chunks, heads * dv, dk), and every chunk's ``(I +
+    L)^-1``, a head block's side by side, (batch, head blocks, seq, hb *
+    chunk).  Jitted and inlined as ``ops/ssd.py``'s."""
     from jax.experimental.pallas import tpu as pltpu
 
     s = _Shape(q, beta, chunk)
-    wide, column, before, whole = s.specs(False)
+    wide, column, before, inverse, whole = s.specs(False)
     return s.call(
         _kda_fwd_kernel, "kda_fwd",
         in_specs=[wide] * 4 + [column] + whole,
-        out_specs=[wide, before],
+        out_specs=[wide, before, inverse],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(
-                       (s.batch, s.chunks, s.heads * s.d, s.d), jnp.float32)],
+                       (s.batch, s.chunks, s.heads * s.d, s.d), jnp.float32),
+                   jax.ShapeDtypeStruct(
+                       (s.batch, s.blocks, s.seq, s.hb * chunk),
+                       jnp.float32)],
         scratch_shapes=[pltpu.VMEM((s.hb * s.d, s.d), jnp.float32)],
     )(q, k, v, g, s.columns(beta), *s.tree)
 
 
-@functools.partial(jax.jit, static_argnums=(7,), inline=True)
-def _backward(q, k, v, g, beta, before, do, chunk: int):
+@functools.partial(jax.jit, static_argnums=(8,), inline=True)
+def _backward(q, k, v, g, beta, before, inverse, do, chunk: int):
     from jax.experimental.pallas import tpu as pltpu
 
     s = _Shape(q, beta, chunk)
-    wide, column, state, whole = s.specs(True)
+    wide, column, state, solved, whole = s.specs(True)
     dq, dk, dv, dg, db = s.call(
         _kda_bwd_kernel, "kda_bwd",
-        in_specs=[wide] * 4 + [column] + whole + [state, wide],
+        in_specs=[wide] * 4 + [column] + whole + [state, solved, wide],
         out_specs=[wide] * 4 + [column],
         out_shape=[jax.ShapeDtypeStruct(t.shape, t.dtype)
                    for t in (q, k, v, g)]
         + [jax.ShapeDtypeStruct((s.batch, s.blocks, s.seq, s.hb),
                                 jnp.float32)],
         scratch_shapes=[pltpu.VMEM((s.hb * s.d, s.d), jnp.float32)],
-    )(q, k, v, g, s.columns(beta), *s.tree, before, do)
+    )(q, k, v, g, s.columns(beta), *s.tree, before, inverse, do)
     return dq, dk, dv, dg, s.rows(db)
 
 
@@ -512,8 +536,8 @@ def _scan_kernels(q, k, v, g, beta, chunk):
 
 
 def _scan_kernels_fwd(q, k, v, g, beta, chunk):
-    o, before = _forward(q, k, v, g, beta, chunk)
-    return o, (q, k, v, g, beta, before)
+    o, before, inverse = _forward(q, k, v, g, beta, chunk)
+    return o, (q, k, v, g, beta, before, inverse)
 
 
 def _scan_kernels_bwd(chunk, residuals, do):

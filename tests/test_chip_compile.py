@@ -985,8 +985,9 @@ def test_kimi_linear_step_compiles_and_fits_the_chip():
     float32 solve and the state riding the grid, the flash kernels at 32
     heads with an un-rotated shared key part, the grouped matmuls under a
     contraction of 2,304 cut in two — and its memory analysis says five
-    layers fit one chip at one row of 16,384 beside 9.64 GB of state (PR 53:
-    7.23 GB of arguments + 6.24 GB of temporaries; see PERF.md)."""
+    layers fit one chip at one row of 16,384 beside 9.64 GB of state (PR 54:
+    7.23 GB of arguments + 6.51 GB of temporaries, 6.38 at PR 53 before the
+    scan's inverse was a residual, 0.13 GB a layer; see PERF.md)."""
     row = _child(["kimi_linear"], compile_=True)["kimi_linear"]
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row

@@ -3,7 +3,9 @@ position at a time, in float32 on the CPU: outputs and the gradient of every
 operand, for the ``jax.numpy`` form and for the Mosaic kernels under the
 Pallas interpreter, at one chunk, several, a partial last chunk and two rows,
 at decays so small that ``e^(G_r) * e^(-G_j)`` would overflow inside a chunk,
-and that no position reads a later one.
+and that no position reads a later one; at every shape of a head block (one
+head, a pair and an odd head, several blocks); and the backward's second
+residual, ``(I + L)^-1``, by its definition and as the backward reads it.
 
 Tolerances.  Float32 against float32 at matmul precision 'highest' differ by
 summation order and by the solve's: 1e-4 of the largest value for outputs,
@@ -74,10 +76,10 @@ SHAPES = [(8, 1), (32, 1), (37, 1), (5, 1), (24, 2)]
 
 
 @functools.lru_cache(maxsize=None)
-def _by_the_recurrence(seq, batch=1, decay=1.0, seed=0):
+def _by_the_recurrence(seq, batch=1, decay=1.0, seed=0, heads=2):
     """(operands, the weights of the loss, the recurrence's outputs, its
     gradients), once for both forms."""
-    args = operands(seq, batch, decay=decay, seed=seed)
+    args = operands(seq, batch, heads, decay=decay, seed=seed)
     weight = jax.random.normal(jax.random.PRNGKey(7), args[2].shape)
     with jax.default_matmul_precision("highest"):
         out, grads = jax.jit(lambda *a: (recurrence(*a), jax.grad(
@@ -102,6 +104,78 @@ def _held_to_the_recurrence(impl, chunk, *key):
 @pytest.mark.parametrize("seq,batch", SHAPES)
 def test_a_chunked_scan_equals_the_recurrence(seq, batch, impl):
     _held_to_the_recurrence(impl, 8, seq, batch)
+
+
+@pytest.mark.parametrize("heads", [1, 3, 5, 6])
+def test_a_head_block_of_every_shape_equals_the_recurrence(heads):
+    """``_Shape`` takes the most heads up to four that divide the layer's:
+    one head; three (a pair's solve and an odd head's); five blocks of one;
+    two blocks of three.  Two rows, three chunks: the residuals' layout is
+    held wherever a head can lie in it."""
+    _held_to_the_recurrence("pallas", 8, 24, 2, 1.0, 0, heads)
+
+
+def _inverses_by_head(inverse, heads, chunk):
+    """The forward's second residual, (batch, head blocks, seq, hb * chunk),
+    as (batch, chunks, heads, chunk, chunk)."""
+    batch, blocks, seq, width = inverse.shape
+    return inverse.reshape(batch, blocks, seq // chunk, chunk, width // chunk,
+                           chunk).transpose(0, 2, 1, 4, 3, 5).reshape(
+        batch, seq // chunk, heads, chunk, chunk)
+
+
+@pytest.mark.parametrize("heads,chunk,seq", [(2, 8, 24), (3, 8, 24),
+                                             (5, 8, 16), (6, 32, 64)])
+def test_a_residual_is_the_inverse_of_the_chunks_system(heads, chunk, seq):
+    """``(I + L) @ inverse`` is the identity for every head and chunk, with
+    ``L_rj = b_r sum_d k_rd k_jd e^(G_rd - G_jd)``, ``j < r``, made here."""
+    q, k, v, g, beta = operands(seq, 2, heads)
+    with jax.default_matmul_precision("highest"):
+        inverse = kda._forward(q, k, v, g, beta, chunk)[2]
+
+        def chunks(t):      # (B, S, H * d) -> (B, chunks, H, C, d)
+            return t.reshape(2, seq // chunk, chunk, heads, -1).transpose(
+                0, 1, 3, 2, 4)
+
+        K, G = chunks(k), jnp.cumsum(chunks(g), axis=-2)
+        below = jnp.arange(chunk)[:, None] > jnp.arange(chunk)[None, :]
+        decay = jnp.exp(jnp.where(
+            below[:, :, None], G[..., :, None, :] - G[..., None, :, :],
+            -jnp.inf))
+        L = chunks(beta) * jnp.sum(
+            K[..., :, None, :] * K[..., None, :, :] * decay, axis=-1)
+        eye = jnp.eye(chunk)
+        got = (eye + L) @ _inverses_by_head(inverse, heads, chunk)
+    np.testing.assert_allclose(
+        np.asarray(got), np.broadcast_to(eye, got.shape), rtol=0, atol=1e-5)
+
+
+@pytest.mark.parametrize("heads", [2, 3])
+def test_a_backward_reads_the_inverse_it_is_given(heads):
+    """``_backward`` on the forward's residual, and on an inverse made again
+    outside a kernel from the same operands by the kernels' own ``_Inside``
+    and ``_inverses``: the same five gradients, bit for bit."""
+    chunk, (q, k, v, g, beta) = 8, operands(24, 2, heads)
+    d, tree = q.shape[-1] // heads, kda._tree(chunk)
+    o, before, inverse = kda._forward(q, k, v, g, beta, chunk)
+
+    def block(b, at):   # the heads' inverses of one chunk, side by side
+        insides = [kda._Inside(
+            *(t[b, at:at + chunk, h * d:(h + 1) * d] for t in (q, k, g)),
+            beta[b, at:at + chunk, h:h + 1], *tree, q.dtype)
+            for h in range(heads)]
+        return kda._beside(kda._inverses([c.beta * c.Akk for c in insides]))
+
+    again = jnp.stack([
+        jnp.concatenate([block(b, at) for at in range(0, 24, chunk)])
+        for b in range(2)])[:, None]
+    assert again.shape == inverse.shape     # heads <= 4: one head block
+    do = jax.random.normal(jax.random.PRNGKey(7), o.shape)
+    back = functools.partial(kda._backward, q, k, v, g, beta, before)
+    for a, b in zip(back(inverse, do, chunk), back(again, do, chunk)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    # and makes none of its own: dv = b * inverse^T dU
+    assert not np.any(back(jnp.zeros_like(inverse), do, chunk)[2])
 
 
 @pytest.mark.parametrize("impl", IMPLS)
