@@ -153,7 +153,13 @@ def remat_block(block_cls, remat_policy: str):
     is a recurrent layer's scan: what would spare a Kimi Delta Attention
     layer its second forward is its output and the float32 state before each
     chunk, 0.67 GB a layer at 16,384 positions, and the one cell that has
-    such layers has no room for four of them (``PERF.md``, PR 53)."""
+    such layers has no room for four of them (``PERF.md``, PR 53).  A block
+    may take and return more than the stream (``models/llama.py``'s
+    ``carried``: one layer's scan output, or its keys and values, read by
+    later layers): every argument is an input of the recomputation, kept
+    and not made again, and what a block returns beside the stream is kept
+    as its readers' input; reverse mode sums the readers' cotangents into the
+    producer's."""
     policies = jax.checkpoint_policies
     policy = (policies.dots_with_no_batch_dims_saveable
               if remat_policy == "dots"

@@ -73,6 +73,21 @@ Delta Attention mixer over ``ops/kda.py``'s chunked scan, a gated delta rule
 with a decay a channel), and with ``rope`` off latent attention turns nothing,
 the shared key part un-rotated (Kimi-Linear-48B-A3B is the block with these,
 the leading dense layer and the sigmoid-routed experts).
+A layer of ``layer_types`` may be ``"mamba1"`` (``mamba1``:
+``models/mamba.py``'s Mamba-1 mixer over ``ops/selective_scan.py``, a decay a
+channel a state), and **a block may hand a tensor on to later blocks**
+(``producers``): a ``"mamba1"`` layer its scan's output, which the ``"gmu"``
+layers after it read (``gmu``: ``GatedMemoryUnit``), an attention layer its
+keys and values, which the ``"cross_attention"`` layers after it read through
+a query projection of their own.  Such a tensor is an input of every block
+that reads it, so under remat it is kept and not made again, and the
+producer's cotangent is the sum over its readers'.  ``diff_attn`` makes every
+attention layer differential attention (``DifferentialAttention``: two
+softmaxes over a pair of value heads, subtracted), ``norm="layer"`` every norm
+of the stream a LayerNorm, ``attn_bias`` gives the attention's projections
+biases, and ``layer_depths`` names the layers' places in a deeper model they
+are cut from (Phi-4-mini-flash-reasoning, SambaY, is the block with these,
+without rotation and with the head tied).
 Every such field at its default leaves the program the dense Llama it was.
 ``remat`` recomputes each block from its input in the backward; what
 ``remat_policy="full"`` keeps beside that input is each attention layer's
@@ -90,6 +105,7 @@ libs; the in-repo flagship models are this framework's own).
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass
 from typing import Any, Optional, Tuple
 
@@ -100,9 +116,9 @@ import numpy as np
 
 from ray_tpu.models.gpt2 import (mask_vocab_padding, padded_vocab,
                                  remat_block)
-from ray_tpu.models.kda import KDAMixer
-from ray_tpu.models.mamba import (Mamba2Mixer, _conv_init,
-                                  gated_short_conv)
+from ray_tpu.models.kda import HeadNorm, KDAMixer
+from ray_tpu.models.mamba import (GatedMemoryUnit, Mamba1Mixer, Mamba2Mixer,
+                                  SplitDense, _conv_init, gated_short_conv)
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU, silu_mul
 from ray_tpu.ops import pooling
 from ray_tpu.ops.attention import HeadColumns, attention
@@ -165,7 +181,9 @@ class LlamaConfig:
     router_z_weight: float = 1e-3    # x router z-loss
     # each layer's token mixer, "attention" (or a kind of it), "mamba"
     # (models/mamba.py), "conv" (ShortConvMixer) or "kda" (models/kda.py),
-    # one entry a layer; empty: attention in every layer
+    # one entry a layer; empty: attention in every layer.  "mamba1"
+    # (Mamba1Mixer) reads mamba_d_state, mamba_d_conv and, as its scan's block
+    # of positions, mamba_chunk; "gmu" and "cross_attention" read a producer
     layer_types: Tuple[str, ...] = ()
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
@@ -231,6 +249,17 @@ class LlamaConfig:
     kda_head_dim: int = 0
     kda_d_conv: int = 4
     kda_chunk: int = 64
+    norm: str = "rms"                # or "layer": LayerNorm, a scale and a bias
+    attn_bias: bool = False          # biases on the attention's projections
+    # differential attention in every attention layer (``DifferentialAttention``)
+    diff_attn: bool = False
+    # each layer's index in the whole model, where the layers here are a cut
+    # of it (differential attention's lam0 is a function of it); empty: its own
+    layer_depths: Tuple[int, ...] = ()
+    # the layers that hand a tensor on to later layers: a "mamba1" layer its
+    # scan's output, which the "gmu" layers after it read; an attention layer
+    # its keys and values, which the "cross_attention" layers after it read
+    producers: Tuple[int, ...] = ()
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -394,8 +423,14 @@ class UnitOffsetRMSNorm(nn.Module):
 
 
 def rms_norm(cfg: "LlamaConfig", name: str):
-    """The configuration's RMSNorm, under ``name``."""
-    cls = UnitOffsetRMSNorm if cfg.norm_unit_offset else nn.RMSNorm
+    """The configuration's norm of the residual stream, under ``name``: an
+    RMSNorm, or LayerNorm (``norm="layer"``: mean and variance, a scale and a
+    bias)."""
+    if cfg.norm not in ("rms", "layer"):
+        raise ValueError(f"unknown norm {cfg.norm!r} (expected 'rms' or "
+                         "'layer')")
+    cls = nn.LayerNorm if cfg.norm == "layer" else \
+        UnitOffsetRMSNorm if cfg.norm_unit_offset else nn.RMSNorm
     return cls(epsilon=cfg.rms_eps, dtype=cfg.dtype, name=name)
 
 
@@ -522,6 +557,75 @@ class LlamaAttention(nn.Module):
         return nn.Dense(E, use_bias=False, dtype=cfg.dtype, name="wo")(out)
 
 
+def _lambda_init(key, shape, dtype=jnp.float32):
+    return 0.1 * jax.random.normal(key, shape, dtype)
+
+
+class DifferentialAttention(nn.Module):
+    """Differential attention (Ye et al. 2024, arXiv:2410.05258) as SambaY's
+    decoders run it: the query heads come in pairs (the even and the odd),
+    and so do the key heads; each pair's two softmaxes — under the causal mask,
+    or a window's — are taken over the same values, a pair of value heads side
+    by side (twice a head's width), and the second is subtracted ``lam`` times:
+
+        a1 = softmax(q1 k1^T / sqrt(D)) V     a2 = softmax(q2 k2^T / sqrt(D)) V
+        lam = exp(lq1 . lk1) - exp(lq2 . lk2) + lam0,  lam0 = 0.8 - 0.6 exp(-0.3 depth)
+        out = wo ((1 - lam0) RMSNorm_2D(a1 - lam a2))
+
+    with four learned vectors of ``D`` and one norm scale of ``2 D`` a layer.
+    The two softmaxes are two calls into ``ops.attention.attention`` (the
+    scope ``diff``), each reading its heads where the projection wrote them:
+    scores ``D`` wide over values ``2 D`` wide, a key/value head to two query
+    heads.  What follows them is the scope ``combine``.  A layer that hands on
+    (``hands_on``) returns its keys and values beside its output, as one array
+    (B, S, 2 KV D): the keys' columns, then the values'; a
+    ``"cross_attention"`` layer has a query projection alone and takes that
+    array."""
+    config: LlamaConfig
+    kind: str = "full_attention"    # or "sliding_attention", "cross_attention"
+    depth: int = 0
+
+    @nn.compact
+    def __call__(self, x, kv=None):
+        cfg = self.config
+        H, KV, E = cfg.n_head, cfg.n_kv_head, x.shape[-1]
+        D = cfg.head_dim or E // H
+        if H % 2 or KV % 2 or (H // 2) % (KV // 2):
+            raise ValueError(f"differential attention pairs {H} query heads "
+                             f"and {KV} key/value heads")
+
+        def dense(width, name):
+            return nn.Dense(width, use_bias=cfg.attn_bias, dtype=cfg.dtype,
+                            name=name)
+
+        if self.kind == "cross_attention":
+            q = dense(H * D, "wq")(x)
+        else:
+            qkv = dense((H + 2 * KV) * D, "wqkv")(x)
+            q, kv = qkv, qkv[..., H * D:]
+
+        def pair(x, heads, first):
+            return HeadColumns(x, heads // 2, D, first=first, stride=2 * D)
+
+        v = HeadColumns(kv, KV // 2, 2 * D, first=KV * D)
+        mask = "sliding_attention" if self.kind == "sliding_attention" \
+            else "full_attention"
+        with jax.named_scope("diff"):
+            a1, a2 = (_attend(cfg, mask, pair(q, H, first), pair(kv, KV, first),
+                              v) for first in (0, D))
+        lq1, lk1, lq2, lk2 = (self.param(name, _lambda_init, (D,)) for name in
+                              ("lambda_q1", "lambda_k1", "lambda_q2",
+                               "lambda_k2"))
+        lam0 = 0.8 - 0.6 * math.exp(-0.3 * self.depth)
+        sub_norm = HeadNorm(H // 2, cfg.rms_eps, cfg.dtype, name="sub_norm")
+        with jax.named_scope("combine"):
+            lam = jnp.exp(jnp.sum(lq1 * lk1)) - jnp.exp(jnp.sum(lq2 * lk2)) \
+                + lam0
+            out = sub_norm(a1.astype(jnp.float32)
+                           - lam * a2.astype(jnp.float32)) * (1.0 - lam0)
+        return dense(E, "wo")(out.astype(cfg.dtype)), kv
+
+
 class LatentAttention(nn.Module):
     """Multi-head latent attention (DeepSeek-V2, section 2.1) with the query
     projected whole (no query latent): ``wdkv`` takes the layer's input down
@@ -579,23 +683,9 @@ class LatentAttention(nn.Module):
         return dense(E, "wo")(out)
 
 
-class ThreeWayDense(nn.Module):
-    """``x -> (x W_0, x W_1, x W_2)``, the kernel (in, 3, out) holding the
-    three matrices side by side: one ``Dense`` to ``3 * out`` whose parts a
-    ``tp`` axis cuts each by its own columns (``P("fsdp", None, "tp")``), and
-    whose three results are arrays of their own, never joined or split in
-    HBM, forward or backward."""
-    features: int
-    dtype: Any = jnp.bfloat16
-
-    @nn.compact
-    def __call__(self, x):
-        kernel = self.param(
-            "kernel", nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2)),
-            (x.shape[-1], 3, self.features), jnp.float32).astype(self.dtype)
-        x = x.astype(self.dtype)
-        return tuple(jnp.einsum("...e,ed->...d", x, kernel[:, i])
-                     for i in range(3))
+# ``x -> (x W_0, x W_1, x W_2)``, the kernel (in, 3, out): ``SplitDense`` at
+# its three parts
+ThreeWayDense = SplitDense
 
 
 class ShortConvMixer(nn.Module):
@@ -639,18 +729,64 @@ class SwiGLU(nn.Module):
 
 
 ATTENTION_KINDS = ("attention", "full_attention", "sliding_attention")
+# what a layer of a kind hands on to later layers where it is named a
+# producer (``LlamaConfig.producers``; an attention layer under ``diff_attn``
+# alone), and the kind of layer that reads it
+HANDS_ON = {"mamba1": "scan output",
+            **{kind: "keys and values" for kind in ATTENTION_KINDS}}
+READERS = {"gmu": "scan output", "cross_attention": "keys and values"}
+
+
+def carried_plan(cfg: LlamaConfig):
+    """For each layer, the producer it reads (None: it reads none), checked:
+    a reader takes the nearest producer before it that hands on what it
+    reads; a reader with none, and a producer that no later layer reads, are
+    refused by name."""
+    kinds = cfg.layer_types or ("attention",) * cfg.n_layer
+    for i in cfg.producers:
+        if not 0 <= i < cfg.n_layer or kinds[i] not in HANDS_ON or (
+                kinds[i] != "mamba1" and not cfg.diff_attn):
+            raise ValueError(
+                f"layer {i} is named a producer and has nothing to hand on "
+                "(a 'mamba1' layer hands on its scan's output, a "
+                "differential attention layer its keys and values)")
+    reads = []
+    for i, kind in enumerate(kinds):
+        before = [j for j in cfg.producers
+                  if j < i and HANDS_ON[kinds[j]] == READERS.get(kind)]
+        if kind in READERS and not before:
+            raise ValueError(
+                f"layer {i} ({kind!r}) reads the {READERS[kind]} of an "
+                "earlier layer, and no layer before it hands one on "
+                f"(producers: {cfg.producers})")
+        reads.append(max(before, default=None))
+    for i in cfg.producers:
+        if i not in reads:
+            raise ValueError(
+                f"layer {i} ({kinds[i]!r}) hands on its {HANDS_ON[kinds[i]]} "
+                "and no later layer reads it")
+    return tuple(reads)
 
 
 class LlamaBlock(nn.Module):
     config: LlamaConfig
     routed: bool = False    # this layer's feed-forward: routed experts
-    # this layer's token mixer: "mamba", "conv", or attention of a kind
+    # this layer's token mixer: "mamba", "conv", "kda", "mamba1", "gmu",
+    # "cross_attention" or attention of a kind
     mixer: str = "attention"
     n_head: int = 0         # this layer's query heads; 0: config.n_head
+    depth: int = 0          # this layer's index in the whole model
+    # the block returns (x, what its mixer hands on) and not x alone
+    hands_on: bool = False
 
     @nn.compact
-    def __call__(self, x, positions):
+    def __call__(self, x, positions, carried=None):
+        """``carried``: what a "gmu" or "cross_attention" layer reads of an
+        earlier layer, an input of the block like ``x``: under remat it is
+        kept and not made again, and its cotangent is one term of the
+        producer's."""
         cfg = self.config
+        handed = None
 
         def add(x, branch):
             if cfg.residual_multiplier != 1.0:
@@ -664,6 +800,16 @@ class LlamaBlock(nn.Module):
             x = add(x, ShortConvMixer(cfg, name="conv")(y))
         elif self.mixer == "kda":
             x = add(x, KDAMixer(cfg, name="kda")(y))
+        elif self.mixer == "mamba1":
+            out, handed = Mamba1Mixer(cfg, name="mamba1")(y)
+            x = add(x, out)
+        elif self.mixer == "gmu":
+            x = add(x, GatedMemoryUnit(cfg, name="gmu")(y, carried))
+        elif cfg.diff_attn and self.mixer in ATTENTION_KINDS + (
+                "cross_attention",):
+            out, handed = DifferentialAttention(
+                cfg, self.mixer, self.depth, name="attn")(y, carried)
+            x = add(x, out)
         elif self.mixer in ATTENTION_KINDS:
             attn = LatentAttention(cfg, self.mixer, name="attn") \
                 if cfg.kv_lora_rank else LlamaAttention(
@@ -671,11 +817,12 @@ class LlamaBlock(nn.Module):
             x = add(x, attn(y, positions))
         else:
             raise ValueError(f"unknown layer type {self.mixer!r} (expected "
-                             "'kda', 'mamba', 'conv' or one of "
+                             "'mamba1', 'gmu', 'cross_attention' (under "
+                             "diff_attn), 'kda', 'mamba', 'conv' or one of "
                              f"{ATTENTION_KINDS})")
         y = rms_norm(cfg, "mlp_norm")(x)
         if self.routed:
-            return add(x, RoutedSwiGLU(RoutedConfig(
+            x = add(x, RoutedSwiGLU(RoutedConfig(
                 n_experts=cfg.n_experts, top_k=cfg.moe_top_k,
                 d_model=cfg.d_model, d_ff=cfg.d_expert,
                 norm_topk_prob=cfg.norm_topk_prob, dtype=cfg.dtype,
@@ -684,7 +831,9 @@ class LlamaBlock(nn.Module):
                 d_shared=cfg.d_shared_expert,
                 selection_bias=cfg.router_selection_bias,
                 norm_topk_eps=cfg.norm_topk_eps), name="moe")(y))
-        return add(x, SwiGLU(cfg, name="mlp")(y))
+        else:
+            x = add(x, SwiGLU(cfg, name="mlp")(y))
+        return (x, handed) if self.hands_on else x
 
 
 class LlamaLMModel(nn.Module):
@@ -708,7 +857,8 @@ class LlamaLMModel(nn.Module):
         if two_copies and (S // 2) % cfg.diffusion_block:
             raise ValueError(f"a copy of {S // 2} positions is not whole "
                              f"blocks of {cfg.diffusion_block}")
-        for name in ("layer_types", "mlp_types", "n_head_per_layer"):
+        for name in ("layer_types", "mlp_types", "n_head_per_layer",
+                     "layer_depths"):
             if getattr(cfg, name) and len(getattr(cfg, name)) != cfg.n_layer:
                 raise ValueError(f"{name} names {len(getattr(cfg, name))} "
                                  f"layers, n_layer is {cfg.n_layer}")
@@ -727,6 +877,7 @@ class LlamaLMModel(nn.Module):
             else jnp.arange(S)
         block_cls = remat_block(LlamaBlock, cfg.remat_policy) if cfg.remat \
             else LlamaBlock
+        reads, handed = carried_plan(cfg), {}
         for i in range(cfg.n_layer):
             if cfg.mlp_types:
                 routed = cfg.mlp_types[i] == "sparse"
@@ -735,8 +886,18 @@ class LlamaLMModel(nn.Module):
                     and i % cfg.moe_every == cfg.moe_every - 1
             mixer = cfg.layer_types[i] if cfg.layer_types else "attention"
             n_head = cfg.n_head_per_layer[i] if cfg.n_head_per_layer else 0
-            x = constrain_residual(block_cls(
-                cfg, routed, mixer, n_head, name=f"h_{i}")(x, positions))
+            block = block_cls(
+                cfg, routed, mixer, n_head,
+                cfg.layer_depths[i] if cfg.layer_depths else i,
+                i in cfg.producers, name=f"h_{i}")
+            x = block(x, positions) if reads[i] is None \
+                else block(x, positions, handed[reads[i]])
+            if i in cfg.producers:
+                # by channel, or by head, where a tp axis cuts the projection
+                # that wrote it; along the batch as the stream is
+                x, made = x
+                handed[i] = constrain_residual(made, channels="tp")
+            x = constrain_residual(x)
         if two_copies:
             x = x[:, :S // 2]       # the head sees the noised copy alone
         x = rms_norm(cfg, "norm_f")(x)

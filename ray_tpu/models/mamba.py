@@ -12,10 +12,26 @@ inside ``models/llama.py``'s block.  With ``n`` the block's normed input:
 The recurrence is ``ops/ssd.py``'s chunked scan, two Pallas kernels.  The
 scopes ``conv``, ``ssd`` and ``gated_norm`` and the ``Dense`` children
 ``in_proj`` and ``out_proj`` are what the benchmark's per-layer metrics read.
+
+Beside it the Mamba-1 mixer (Gu and Dao 2023) as SambaY's decoder-hybrid-decoder
+runs it (``Mamba1Mixer``; arXiv:2507.06607), ``d = expand * d_model`` channels
+of ``N`` states each, ``R`` the step sizes' rank:
+
+    [u ; z] = W_in n
+    u = silu(causal depthwise conv1d(u, width d_conv) + b)
+    [r ; B ; C] = W_x u                  r: R; B, C: N, shared by all channels
+    delta = softplus(W_dt r + dt_bias)   a step size a CHANNEL, float32
+    h_t = exp(delta_t A) h_{t-1} + delta_t B_t u_t;  y_t = h_t C_t + D u_t
+    out = W_out (y * silu(z))            A = -exp(A_log), a decay a channel a state
+
+over ``ops/selective_scan.py``, and the gated memory unit that reads one such
+layer's ``y`` in later layers (``GatedMemoryUnit``: ``W_out (silu(W_in n) *
+y)``).  Their scopes: ``conv``, ``dt`` (the step sizes), ``scan``, ``gate``.
 """
 
 from __future__ import annotations
 
+import functools
 import math
 from typing import Any
 
@@ -23,7 +39,10 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.selective_scan import selective_scan
 from ray_tpu.ops.ssd import ssd_scan
+from ray_tpu.parallel.mesh import ambient_mesh
+from ray_tpu.parallel.sharding import constrain_residual
 
 
 def _taps(x, width: int, ahead: bool = False):
@@ -150,3 +169,104 @@ class Mamba2Mixer(nn.Module):
             y = (g * scale).astype(cfg.dtype)
         return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                         name="out_proj")(y)
+
+
+def _a_log_states(key, shape, dtype=jnp.float32):
+    """Mamba-1's start: ``-A`` = 1 .. N along a channel's states."""
+    return jnp.broadcast_to(jnp.log(jnp.arange(1, shape[1] + 1, dtype=dtype)),
+                            shape)
+
+
+class SplitDense(nn.Module):
+    """``x -> (x W_0, .., x W_{parts-1})``, the kernel (in, parts, out)
+    holding the matrices side by side: one ``Dense`` to ``parts * out`` whose
+    parts a ``tp`` axis cuts each by its own columns (``P("fsdp", None,
+    "tp")``), and whose results are arrays of their own, never joined or
+    split in HBM, forward or backward."""
+    features: int
+    dtype: Any = jnp.bfloat16
+    parts: int = 3
+
+    @nn.compact
+    def __call__(self, x):
+        kernel = self.param(
+            "kernel", nn.initializers.lecun_normal(in_axis=0, out_axis=(1, 2)),
+            (x.shape[-1], self.parts, self.features),
+            jnp.float32).astype(self.dtype)
+        x = x.astype(self.dtype)
+        return tuple(jnp.einsum("...e,ed->...d", x, kernel[:, i])
+                     for i in range(self.parts))
+
+
+def mamba1_sizes(cfg):
+    """(channels, the step sizes' rank) of a ``mamba1`` layer: two channels a
+    model dimension, the rank a sixteenth of it, rounded up."""
+    return 2 * cfg.d_model, -(-cfg.d_model // 16)
+
+
+class Mamba1Mixer(nn.Module):
+    """-> (the mixer's output, ``y`` before its gate: what a layer that hands
+    its scan on gives the gated memory units)."""
+    config: Any     # LlamaConfig: d_model, dtype, mamba_d_state, mamba_d_conv,
+    #                 mamba_chunk (the scan's block of positions)
+
+    @nn.compact
+    def __call__(self, x):
+        cfg = self.config
+        (d, rank), n = mamba1_sizes(cfg), cfg.mamba_d_state
+        mesh = ambient_mesh()
+        if mesh is not None and mesh.shape.get("sp", 1) > 1:
+            raise NotImplementedError(
+                "a sequence sharded on 'sp' has no 'mamba1' layer (the "
+                "recurrence carries its state across every position)")
+
+        def by_channel(t):  # between a column- and a row-parallel projection
+            return constrain_residual(t, channels="tp")
+
+        u, z = (by_channel(part) for part in
+                SplitDense(d, cfg.dtype, 2, name="in_proj")(x))
+        conv_init = _conv_init(cfg.mamba_d_conv)
+        kernel = self.param("conv_kernel", conv_init, (cfg.mamba_d_conv, d))
+        bias = self.param("conv_bias", conv_init, (d,))
+        with jax.named_scope("conv"):
+            u = jax.nn.silu(causal_conv(u, kernel.astype(cfg.dtype),
+                                        bias.astype(cfg.dtype)))
+        r, b, c = jnp.split(
+            nn.Dense(rank + 2 * n, use_bias=False, dtype=cfg.dtype,
+                     name="x_proj")(u), [rank, rank + n], axis=-1)
+        # the step sizes leave their matmul in float32 and stay so
+        dt = by_channel(nn.Dense(
+            d, use_bias=False, dtype=cfg.dtype, name="dt_proj",
+            dot_general=functools.partial(
+                jax.lax.dot_general,
+                preferred_element_type=jnp.float32))(r))
+        dt_bias = self.param("dt_bias", _dt_bias_init, (d,))
+        a_log = self.param("A_log", _a_log_states, (d, n))
+        skip = self.param("D", nn.initializers.ones, (d,))
+        with jax.named_scope("dt"):
+            delta = jax.nn.softplus(dt + dt_bias)
+        with jax.named_scope("scan"):
+            y = selective_scan(u, delta, -jnp.exp(a_log.astype(jnp.float32)),
+                               b, c, skip, block=cfg.mamba_chunk)
+        with jax.named_scope("gate"):
+            gated = y * jax.nn.silu(z)
+        return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                        name="out_proj")(gated), y
+
+
+class GatedMemoryUnit(nn.Module):
+    """SambaY's gated memory unit: the layer's own input gates, channel by
+    channel, the scan output ``m`` that an earlier ``mamba1`` layer handed on
+    for the same position; no token is mixed here."""
+    config: Any
+
+    @nn.compact
+    def __call__(self, x, m):
+        cfg = self.config
+        gate = constrain_residual(
+            nn.Dense(m.shape[-1], use_bias=False, dtype=cfg.dtype,
+                     name="in_proj")(x), channels="tp")
+        with jax.named_scope("gate"):
+            gated = jax.nn.silu(gate) * m
+        return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                        name="out_proj")(gated)

@@ -1188,7 +1188,11 @@ def _flash_bwd_kernel(q_ref, do_ref, lse_ref, delta_ref, k_ref, v_ref, *rest,
         # one more axis, the group's query heads: this step's head's rows of
         # the group's dQ
         member, step = step, pl.program_id(4)
-        dq_acc = dq_acc.at[member]
+        # (rows narrower than a lane tile — scores 64 wide on heads that lie
+        # 128 apart — are indexed in the accumulator itself: Mosaic cuts no
+        # ref inside a lane tile)
+        dq_acc = _Head(dq_acc, (member,), 0, dq_acc.shape[-1]) \
+            if dq_acc.shape[-1] % LANES else dq_acc.at[member]
     if t.eva:
         iq, _, js, in_window, seen = t.eva_walk(ik, step, True)
     else:

@@ -121,6 +121,28 @@ def llama_partition_rules() -> PartitionRules:
         (r"kda/o_proj/kernel", _spec("tp", "fsdp")),
         (r"kda/(q|k|v)_conv", _spec(None, "tp")),
         (r"kda/(A_log|dt_bias)$", _spec("tp")),
+        # Mamba-1 layers (models/mamba.py::Mamba1Mixer as h_<n>/mamba1): what
+        # lies between in_proj and out_proj is a channel's own — in_proj's
+        # kernel is (embed, 2, channels), u and z each cut by channel, and so
+        # are the convolution, the step sizes' up-projection and its bias, A
+        # (channels, states) and D — but for B and C, which every channel
+        # shares: x_proj is row-parallel and its output whole on every device
+        (r"mamba1/in_proj/kernel", _spec("fsdp", None, "tp")),
+        (r"mamba1/x_proj/kernel", _spec("tp", None)),
+        (r"mamba1/dt_proj/kernel", _spec(None, "tp")),
+        (r"mamba1/out_proj/kernel", _spec("tp", "fsdp")),
+        (r"mamba1/conv_kernel", _spec(None, "tp")),
+        (r"mamba1/(conv_bias|dt_bias|D)$", _spec("tp")),
+        (r"mamba1/A_log", _spec("tp", None)),
+        # gated memory units (GatedMemoryUnit as h_<n>/gmu): the gate by
+        # channel, as the scan output it multiplies is cut
+        (r"gmu/in_proj/kernel", _spec("fsdp", "tp")),
+        (r"gmu/out_proj/kernel", _spec("tp", "fsdp")),
+        # differential attention (models/llama.py::DifferentialAttention):
+        # wq and wo as the attention's above; wqkv's columns are the query
+        # heads, then the key heads, then the values', which no one cut of
+        # the columns keeps apart: over fsdp alone (lambda_*: replicated)
+        (r"attn/wqkv/kernel", _spec("fsdp", None)),
         # attn_norm, mlp_norm, norm_f, the q_norm / k_norm scales and the
         # mixer's norm_scale
         (r"norm|scale", _spec()),

@@ -128,6 +128,8 @@ def _build(case: str, compile_: bool) -> dict:
         return _build_flash(case, topo.devices[0])
     if case.startswith("kda_s"):
         return _build_kda(case, topo.devices[0])
+    if case.startswith("scan_s"):
+        return _build_selective_scan(case, topo.devices[0])
     if case in PRELUDES:
         return _build_prelude(case, topo.devices[0])
     if case in LAYOUTS:
@@ -186,6 +188,18 @@ def _build(case: str, compile_: bool) -> dict:
                 config.kda_d_conv) == (32, 128, 64, 4)
         assert not config.rope and config.kv_lora_rank == 512
         assert (config.d_model, config.n_layer, seq) == (2304, 5, 16384)
+    elif case == "phi4_flash":
+        mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+        (config, seq), rows = _cell("phi4-flash-s16k-1chip"), 1
+        assert config.layer_types == (
+            "mamba1", "sliding_attention", "mamba1", "full_attention", "gmu",
+            "cross_attention")
+        assert config.producers == (2, 3) and config.diff_attn
+        assert config.layer_depths == (14, 15, 16, 17, 18, 19)
+        assert (config.norm, config.rope, config.tie_embeddings) == (
+            "layer", False, True)
+        assert (config.d_model, config.n_head, config.n_kv_head,
+                config.sliding_window, seq) == (2560, 40, 20, 512, 16384)
     elif case == "granite":
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
         (config, seq), rows = _cell("granite-h-s8k-1chip"), 1
@@ -310,6 +324,43 @@ def _build_kda(case: str, device) -> dict:
         compiled = jax.jit(grads).lower(
             wide, wide, wide, shape(heads * d, jnp.float32),
             shape(heads, jnp.float32), wide).compile()
+    except Exception as e:  # what Mosaic or the TPU compiler refuses
+        return {"case": case, "refused": str(e)[-1500:]}
+    mem = compiled.memory_analysis()
+    return {"case": case, "tpu_custom_calls": len(re.findall(
+        r'custom_call_target="tpu_custom_call"', compiled.as_text())),
+        "temp_bytes": int(mem.temp_size_in_bytes)}
+
+
+def _build_selective_scan(case: str, device) -> dict:
+    """In the child: compile ``ops/selective_scan.py``'s kernels alone,
+    forward and backward, for one chip at ``scan_s<seq>``: one row of 5,120
+    channels of 16 states, bf16 ``u``, ``B`` and ``C``, float32 step sizes,
+    blocks of 256 positions."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.selective_scan import selective_scan
+
+    seq, d, n = int(case.split("_s")[1]), 5120, 16
+
+    def shape(dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(device))
+
+    def grads(u, delta, a, b, c, skip, dy):
+        out, vjp = jax.vjp(functools.partial(selective_scan, block=256),
+                           u, delta, a, b, c, skip)
+        return out, vjp(dy)
+
+    wide, narrow = shape((1, seq, d)), shape((1, seq, n))
+    try:
+        compiled = jax.jit(grads).lower(
+            wide, shape((1, seq, d), jnp.float32), shape((d, n), jnp.float32),
+            narrow, narrow, shape((d,), jnp.float32), wide).compile()
     except Exception as e:  # what Mosaic or the TPU compiler refuses
         return {"case": case, "refused": str(e)[-1500:]}
     mem = compiled.memory_analysis()
@@ -640,16 +691,19 @@ def test_flash_backward_compiles_with_its_whole_sequence_dq_in_vmem():
     (Laguna's and SDAR's shape) and at s 14336, the last that
     ``_bwd_vmem_bytes`` puts inside 128 MiB, with dK written at the four
     key/value heads; at s 16384 a head's alone, dK a query head, the sum
-    beside the kernel."""
+    beside the kernel.  At heads 64 wide a group's dQ is rows of half a lane
+    tile, indexed in the accumulator itself (two query heads a key/value head
+    at s 16384: differential attention's calls)."""
     rows = _child(["flash_s8192_d128", "flash_s32768_d128",
                    "flash_s131072_d128", "flash_s8192_d128_g8",
-                   "flash_s14336_d128_g8", "flash_s16384_d128_g8"],
+                   "flash_s14336_d128_g8", "flash_s16384_d128_g8",
+                   "flash_s16384_d64_g2"],
                   compile_=True)
     assert "vmem" in rows.pop("flash_s131072_d128")["refused"], rows
     assert {case: row.get("dk_heads") for case, row in rows.items()} == {
         "flash_s8192_d128": 32, "flash_s32768_d128": 32,
         "flash_s8192_d128_g8": 4, "flash_s14336_d128_g8": 4,
-        "flash_s16384_d128_g8": 32}, rows
+        "flash_s16384_d128_g8": 32, "flash_s16384_d64_g2": 16}, rows
 
 
 def test_attention_prelude_moves_no_float32_copy_of_the_queries():
@@ -1006,6 +1060,54 @@ def test_kda_kernels_compile_for_one_v5e_chip():
     the float32 sums as three bfloat16 passes —, which the interpreter on
     the CPU cannot say."""
     row = _child(["kda_s16384"], compile_=True)["kda_s16384"]
+    assert "refused" not in row, row
+    assert row["tpu_custom_calls"] == 2, row    # a forward and a backward
+
+
+def test_phi4_flash_step_lowers_for_one_v5e_chip():
+    """Tier-1: the one-chip step of Phi-4-mini-flash-reasoning at published
+    widths (published layers 14 to 19: Mamba-1, window attention, Mamba-1
+    handing on its scan, full attention handing on K and V, a gated memory
+    unit, cross-attention; one row of 16,384) lowers for the TPU with its
+    Mosaic kernels in it: the selective scan's pair
+    (``ops/selective_scan.py``) in the two Mamba-1 layers and the flash pair
+    twice a layer in the three differential attention layers, scores 64 wide
+    over values 128 wide, and no other — the convolution, the gates, the
+    gated memory unit, lam and the sub-layer norm are plain XLA."""
+    row = _child(["phi4_flash"], compile_=False)["phi4_flash"]
+    kernels = row["lowered_kernels"]
+    assert set(kernels) == {"selective_scan_fwd", "selective_scan_bwd",
+                            "flash_fwd", "flash_bwd"}, kernels
+    assert kernels["flash_fwd"] == kernels["flash_bwd"] == 6, kernels
+    assert kernels["selective_scan_bwd"] == 2, kernels
+    assert row["flash_fwd_calls"] == 6, row
+
+
+@pytest.mark.slow
+def test_phi4_flash_step_compiles_and_fits_the_chip():
+    """The TPU compiler takes the step — the scan's two kernels with 16
+    states of 1,024 channels in registers and the state riding the grid, the
+    flash kernels with a key/value head to two query heads at scores 64 wide
+    (a group's dQ indexed in its accumulator) — and its memory analysis says
+    six layers fit one chip at one row of 16,384 beside 11.16 GB of state
+    (PR 55: 8.37 GB of arguments + 5.82 GB of temporaries; see PERF.md)."""
+    row = _child(["phi4_flash"], compile_=True)["phi4_flash"]
+    print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
+    assert "refused" not in row, row
+    # a Mamba-1 layer: the scan's forward, again under remat, and its
+    # backward; an attention layer: two flash forwards and two backwards
+    assert row["tpu_custom_calls"] == 2 * 3 + 3 * 4, row
+    assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
+
+
+def test_selective_scan_kernels_compile_for_one_v5e_chip():
+    """Tier-1, fifteen seconds: Mosaic takes ``ops/selective_scan.py``'s two
+    kernels at the cell's shape (1 x 16,384 x 5,120 channels x 16 states,
+    blocks of 256, bf16 u, B and C) — scalars from SMEM against whole
+    registers, the block's states made again in VMEM, the sums over a
+    channel block's sublanes —, which the interpreter on the CPU cannot
+    say."""
+    row = _child(["scan_s16384"], compile_=True)["scan_s16384"]
     assert "refused" not in row, row
     assert row["tpu_custom_calls"] == 2, row    # a forward and a backward
 

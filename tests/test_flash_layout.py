@@ -329,6 +329,44 @@ def test_grouped_operands_against_the_reference_on_repeated_ones(
         "repeated": [1] * 6}[summed], kernels
 
 
+@pytest.mark.parametrize("first", [0, 16])
+@pytest.mark.parametrize("window", [None, 100])
+def test_a_group_of_narrow_scores_over_wider_values(first, window):
+    """Differential attention's calls: of one projection's 8 query and 4
+    key heads 16 wide every second one, from ``first``, scores the values as
+    2 heads of 32, two query heads a key/value head.  The group's dQ has rows
+    narrower than a lane tile, which the backward kernel indexes in its
+    accumulator (``_Head``), interpreted as compiled."""
+    b, h, n_kv, d, s = 2, 8, 4, 16, 384
+    keys = jax.random.split(jax.random.PRNGKey(13), 2)
+    qkv = jax.random.normal(keys[0], (b, s, (h + 2 * n_kv) * d))
+    g = jax.random.normal(keys[1], (b, s, h // 2 * 2 * d))
+    kw = {} if window is None else dict(window=window)
+
+    def as_they_lie(qkv):
+        kv = qkv[..., h * d:]
+        return flash_attention(
+            HeadColumns(qkv, h // 2, d, first=first, stride=2 * d),
+            HeadColumns(kv, n_kv // 2, d, first=first, stride=2 * d),
+            HeadColumns(kv, n_kv // 2, 2 * d, first=n_kv * d),
+            tokens_out=True, block_q=128, block_k=128, **kw)
+
+    def plain(qkv):
+        q, k, v = jnp.split(qkv, [h * d, (h + n_kv) * d], axis=-1)
+        q, k = (_heads(x, n)[:, first // d::2] for x, n in ((q, h), (k, n_kv)))
+        k, v = (jnp.repeat(x, 2, axis=1) for x in (k, _heads(v, n_kv // 2)))
+        return _tokens(mha_reference(q, k, v, **kw))
+
+    (got, (got_grad,)), (want, (want_grad,)) = (
+        _both(f, (qkv,), g) for f in (as_they_lie, plain))
+    _close(got, want, jnp.float32, False, "out")
+    _close(got_grad, want_grad, jnp.float32, False, "dqkv")
+    # the group's dK and dV are summed in the kernel, at the key/value heads
+    kernels = _kernel_operands(lambda x: _both(as_they_lie, (x,), g), qkv)
+    assert kernels["flash_bwd"][-2:] == [(b, n_kv // 2, s, d),
+                                         (b, n_kv // 2, s, 2 * d)], kernels
+
+
 @pytest.mark.parametrize("n_kv", [2, 1])
 def test_under_a_tp_mesh_a_device_takes_whole_key_value_heads(n_kv, caplog):
     """dp=2 x tp=2 on four CPU devices, four query heads: over two key/value

@@ -291,7 +291,10 @@ def test_d_lagunas_step_is_the_parents(flash_names_off):
     tiles, tiles) and their output (B, S, H * D), the gate widened along
     the lanes; since PR 49 that PR's: the dense layer's and the shared
     expert's ``silu * up`` go through ``models/moe.py::silu_mul``; since
-    PR 52 that PR's: k and v reach the kernels with their own heads)."""
+    PR 52 that PR's: k and v reach the kernels with their own heads; since
+    PR 55 that PR's: the toy's grouped heads are narrower than a lane tile,
+    and the backward kernel indexes such a group's dQ in the accumulator
+    itself, interpreted as compiled)."""
     from ray_tpu.models.pretrain import make_optimizer, sharded_train_step
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
@@ -304,7 +307,7 @@ def test_d_lagunas_step_is_the_parents(flash_names_off):
     with jax.set_mesh(mesh):
         text = s.step.trace(s.state, batch).lower().as_text()
     assert hashlib.sha256(text.encode()).hexdigest() == \
-        "e6b075776c92df8f5105659bddc4cc43ae1ddb6623aab49da61b356952a34f02"
+        "4d7f074a49c3ae1f68e4b5e66cd1af93e583972f0ae498461edcd62e2babffc0"
 
 
 # ------------------------------------------------- (e) on a virtual mesh
