@@ -30,6 +30,7 @@ import jax.numpy as jnp
 
 from ray_tpu.ops.attention import (FLASH_RESIDUALS, NEG_INF, HeadColumns,
                                    attention)
+from ray_tpu.ops.kda import KDA_RESIDUALS
 from ray_tpu.parallel.sharding import constrain_residual
 
 VOCAB_ALIGN = 128  # one lane tile; also divisible by every tp size in use
@@ -146,14 +147,18 @@ class Block(nn.Module):
 
 def remat_block(block_cls, remat_policy: str):
     """``block_cls`` recomputed in the backward from its input.  Under
-    ``"full"`` everything in the block is, except that a flash kernel's
-    output and logsumexp are kept (``FLASH_RESIDUALS``): they are the one
-    thing in a block whose recomputation costs a whole kernel for one
-    activation-sized array.  q, k and v are recomputed like the rest, and so
-    is a recurrent layer's scan: what would spare a Kimi Delta Attention
-    layer its second forward is its output and the float32 state before each
-    chunk, 0.67 GB a layer at 16,384 positions, and the one cell that has
-    such layers has no room for four of them (``PERF.md``, PR 53).  A block
+    ``"full"`` everything in the block is, except what the kernels name: a
+    flash kernel's output and logsumexp (``FLASH_RESIDUALS``), the one thing
+    in a block whose recomputation costs a whole kernel for one
+    activation-sized array, and every chunk's ``(I + L)^-1`` of a Kimi Delta
+    Attention layer's scan (``KDA_RESIDUALS``: a Mosaic call of its own,
+    ``ops/kda.py::kda_solve``, 0.13 GB a layer at 16,384 positions for the
+    dearest part of the scan's forward).  q, k and v are recomputed like the
+    rest, and so is what is left of a recurrent layer's scan: what would
+    spare a Kimi Delta Attention layer its second forward whole is its output
+    and the float32 state before each chunk, 0.67 GB a layer more, and the
+    one cell that has such layers has no room for four of them (``PERF.md``,
+    PRs 53 and 56).  A block
     may take and return more than the stream (``models/llama.py``'s
     ``carried``: one layer's scan output, or its keys and values, read by
     later layers): every argument is an input of the recomputation, kept
@@ -163,7 +168,8 @@ def remat_block(block_cls, remat_policy: str):
     policies = jax.checkpoint_policies
     policy = (policies.dots_with_no_batch_dims_saveable
               if remat_policy == "dots"
-              else policies.save_only_these_names(*FLASH_RESIDUALS))
+              else policies.save_only_these_names(*FLASH_RESIDUALS,
+                                                  *KDA_RESIDUALS))
     return nn.remat(block_cls, policy=policy)
 
 

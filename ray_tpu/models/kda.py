@@ -9,7 +9,9 @@ token mixer in place of attention, inside ``models/llama.py``'s block.  With
     S_t = (I - b_t k_t k_t^T) Diag(e^(g_t)) S_{t-1} + b_t k_t v_t^T;   o_t = S_t^T q_t
     out = Wo [RMSNorm_d(o)_h * sigmoid((Wg_b Wg_a n + bias)_h)]
 
-The recurrence is ``ops/kda.py``'s chunked scan (two Mosaic kernels) and
+The recurrence is ``ops/kda.py``'s chunked scan (three Mosaic kernels: the
+chunks' solve, which a rematerialised block keeps by name, the forward and
+the backward) and
 each convolution ``models/mamba.py::causal_conv``'s shifted multiply-adds in
 plain XLA.  The ``Dense`` children
 ``q_proj``, ``k_proj``, ``v_proj``, ``f_a``, ``f_b``, ``g_a``, ``g_b``,

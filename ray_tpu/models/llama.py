@@ -91,9 +91,10 @@ without rotation and with the head tied).
 Every such field at its default leaves the program the dense Llama it was.
 ``remat`` recomputes each block from its input in the backward; what
 ``remat_policy="full"`` keeps beside that input is each attention layer's
-flash kernel output and logsumexp, so that no kernel's forward runs twice
-(``models/gpt2.py::remat_block``; a Mamba or KDA layer, a routed layer's
-Mosaic calls and reference attention keep nothing).  Same TPU discipline as the GPT stack —
+flash kernel output and logsumexp, so that no attention kernel's forward
+runs twice, and each KDA layer's chunk inverses, so that its second forward
+solves nothing (``models/gpt2.py::remat_block``; a Mamba layer, a routed
+layer's Mosaic calls and reference attention keep nothing).  Same TPU discipline as the GPT stack —
 bfloat16 activations, fused QKV-free layout matched to
 ``llama_partition_rules`` so tp/fsdp shardings apply by regex, attention
 through ``ops.attention.attention``, which picks the Pallas flash kernel or,

@@ -20,24 +20,28 @@ masked outer products as ``ops/ssd.py``'s are, and ``e^(G_r) * e^(-G_j)``
 overflows within one chunk at a small ``a``.  Every exponent here is a
 difference that is never positive.
 
-``kda_scan`` (what the mixer calls): two Mosaic kernels under one
-``custom_vjp``, the state riding the grid as ``ops/ssd.py``'s does.  A pair
+``kda_scan`` (what the mixer calls): three Mosaic kernels under one
+``custom_vjp``.  A pair
 of positions goes through a position between them, ``(G_r - G_m) + (G_m -
 G_j)`` with both parts <= 0, which leaves a matmul of two scaled operands;
 which ``m`` is the pair's level of the chunk's binary tree (see ``_tree``).
 The running sums and every level's differences come from one exact matmul,
-``(I + L)^-1`` from float32 matmuls (``_unit_lower_inverse``).  The backward
-makes the chunk's forward again from two float32 residuals beside the
-operands, both written by the forward a chunk at a time: the state before the
-chunk (64 KB a head a chunk at 128 x 128; 0.54 GB a layer at 32 heads x 256
-chunks) and the chunk's ``(I + L)^-1`` (16 KB a head at a chunk of 64, a head
-block's side by side; 0.13 GB a layer).  The inverse because it is the
-dearest thing a byte that the backward would make again: ten dependent
-float32 matmuls, 30 of a head's some 86 MXU passes, from ``k``, ``g`` and
-``beta`` alone; ``U``, ``A`` and ``L``'s pairs together are 56 KB a head a
-chunk for a third of that, and the levels' ``E`` the gradients need anyway.
-Under a rematerialised block both residuals live from the layer's second
-forward to its backward: one layer's at a time.
+``(I + L)^-1`` from float32 matmuls (``_unit_lower_inverses``): ten, each
+waiting for the one before, 30 of a forward head-chunk's some 50 MXU passes
+and of a backward's 86, from ``k``, ``g`` and ``beta`` alone.  So it is made
+once, by ``kda_solve``, whose grid has no chunk waiting for another, and
+read by ``kda_fwd`` and ``kda_bwd``, the state riding their grids as
+``ops/ssd.py``'s does: 16 KB a head at a chunk of 64, a head block's side by
+side; 0.13 GB a layer at 32 heads x 256 chunks.  It carries a name
+(``KDA_RESIDUALS``), by which a rematerialised block keeps it from its
+forward to its backward (``models/gpt2.py::remat_block``): the block's
+second forward is ``kda_fwd`` alone, and a stack's layers' inverses are
+alive together.  The backward makes the chunk's forward again from it and
+from the rule's other float32 residual, which ``kda_fwd`` writes a chunk at
+a time and the recomputation makes again: the state before the chunk (64 KB
+a head a chunk at 128 x 128; 0.54 GB a layer).  ``U``, ``A`` and ``L``'s
+pairs together are 56 KB a head a chunk for a third of the solve's passes,
+and the levels' ``E`` the gradients need anyway.
 
 ``kda_scan_xla``, a yardstick that no model calls (the tests hold it to the
 recurrence, and ``perfbench/tests/kimi_linear_on_chip.py`` times the kernels
@@ -64,11 +68,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops.attention import _NN, _NT, _TN, _interpret
 from ray_tpu.parallel.mesh import ambient_mesh
+
+# what a block's ``jax.checkpoint`` keeps of the scan by name
+# (``models/gpt2.py::remat_block``): every chunk's ``(I + L)^-1``
+KDA_RESIDUALS = ("kda_inverse",)
 
 # positions of a sub-block: pairs inside one take their exponent's difference
 # channel by channel; pairs across two go through the MXU
@@ -244,51 +253,54 @@ def _blocks(chunk: int, size: int):
     return jnp.right_shift(row, shift) == jnp.right_shift(col, shift)
 
 
-def _unit_lower_inverse(L, chunk: int):
-    """``(I + L)^-1`` for a strictly lower triangular ``L``, float32, or for
-    several of them (C, C) down the diagonal of one matrix (n C, n C): the
-    MXU's pass takes the wider matrix at the price of the narrower.  Inside
-    diagonal blocks of ``_SUB`` the nilpotent product ``(I - L)(I + L^2)(I +
-    L^4) ...``; then blocks are merged two by two up to ``chunk``, ``[[A, 0],
-    [B, D]]^-1 = [[A^-1, 0], [-D^-1 B A^-1, D^-1]]``."""
-    n = L.shape[0]
+def _unit_lower_inverses(Ls, chunk: int):
+    """``(I + L)^-1`` for each strictly lower triangular ``L`` of a list,
+    float32; an ``L`` may hold several (C, C) down the diagonal of one matrix
+    (n C, n C): the MXU's pass takes the wider matrix at the price of the
+    narrower.  Inside diagonal blocks of ``_SUB`` the nilpotent product ``(I
+    - L)(I + L^2)(I + L^4) ...``; then blocks are merged two by two up to
+    ``chunk``, ``[[A, 0], [B, D]]^-1 = [[A^-1, 0], [-D^-1 B A^-1, D^-1]]``.
+    A step of every system is written before the next step of any."""
     size = min(_SUB, chunk)
-    inside = _blocks(n, size)
-    Ld = jnp.where(inside, L, 0.0)
-    X, power, p = _blocks(n, 1).astype(jnp.float32) - Ld, Ld, 1
+
+    def blocks(size):
+        return [_blocks(L.shape[0], size) for L in Ls]
+
+    inside = blocks(size)
+    powers = [jnp.where(own, L, 0.0) for own, L in zip(inside, Ls)]
+    Xs = [eye.astype(jnp.float32) - Ld for eye, Ld in zip(blocks(1), powers)]
+    p = 1
     while 2 * p < size:
-        power = _dotf(power, power, _NN)
-        X = X + _dotf(X, power, _NN)
+        powers = [_dotf(power, power, _NN) for power in powers]
+        Xs = [X + _dotf(X, power, _NN) for X, power in zip(Xs, powers)]
         p *= 2
     while size < chunk:
-        wider = _blocks(n, 2 * size)
-        off = jnp.where(jnp.logical_and(wider, jnp.logical_not(inside)), L,
-                        0.0)
-        X = X - _dotf(X, _dotf(off, X, _NN), _NN)
+        wider = blocks(2 * size)
+        off = [jnp.where(jnp.logical_and(new, jnp.logical_not(own)), L, 0.0)
+               for new, own, L in zip(wider, inside, Ls)]
+        below = [_dotf(B, X, _NN) for B, X in zip(off, Xs)]
+        Xs = [X - _dotf(X, B, _NN) for X, B in zip(Xs, below)]
         inside, size = wider, 2 * size
-    return X
+    return Xs
 
 
-def _inverses(Ls):
-    """``(I + L)^-1`` of each of a head block's ``L`` (C, C): those of two
-    heads side by side in one matrix, twice as wide."""
-    chunk, out = Ls[0].shape[0], []
-    zero = jnp.zeros_like(Ls[0])
-    for a, b in zip(Ls[::2], Ls[1::2]):
-        X = _unit_lower_inverse(jnp.concatenate([
-            jnp.concatenate([a, zero], axis=1),
-            jnp.concatenate([zero, b], axis=1)]), chunk)
-        out += [X[:chunk, :chunk], X[chunk:, chunk:]]
-    if len(Ls) % 2:
-        out.append(_unit_lower_inverse(Ls[-1], chunk))
-    return out
+def _decays(gs, n_ref, s_ref):
+    """Heads' chunks of log-decays (C, d) float32 -> (each head's running
+    sums ``G``, each level's ``E`` of each head): a head's sums and all its
+    levels' differences in one matmul."""
+    chunk = n_ref.shape[1]
+    sums = [_sums(n_ref[...], g, _NN) for g in gs]
+    return [s[:chunk] for s in sums], [
+        [jnp.exp(jnp.minimum(
+            s_ref[i] * s[(i + 1) * chunk:(i + 2) * chunk], 0.0))
+         for s in sums] for i in range(s_ref.shape[0])]
 
 
 class _Inside:
     """What one head's chunk is made of before the state comes into it, as
-    the forward and the backward both need it: the running sums ``G``, a
-    level's ``E`` and scaled operands, ``A`` and ``L``'s pairs.  ``q``, ``k``,
-    ``g``: (C, d) float32; ``beta``: (C, 1)."""
+    the backward needs it: the running sums ``G``, a level's ``E`` and scaled
+    operands, ``A`` and ``L``'s pairs.  ``q``, ``k``, ``g``: (C, d) float32;
+    ``beta``: (C, 1)."""
 
     def __init__(self, q, k, g, beta, n_ref, m_ref, s_ref, dtype):
         self.q, self.k, self.beta = q, k, beta
@@ -297,60 +309,108 @@ class _Inside:
         col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
         self.eye = (row == col).astype(jnp.float32)
         self.strict = (row > col).astype(jnp.float32)
-        # the running sums and every level's differences in one matmul
-        sums = _sums(n_ref[...], g, _NN)
-        self.G = sums[:chunk]
+        (self.G,), decays = _decays([g], n_ref, s_ref)
         self.levels = []
         Akk = jnp.zeros((chunk, chunk), jnp.float32)
         Aqk = _dotl(q, k, _NT, dtype) * self.eye
-        for i in range(m_ref.shape[0]):
-            sign = s_ref[i]
-            E = jnp.exp(jnp.minimum(
-                sign * sums[(i + 1) * chunk:(i + 2) * chunk], 0.0))
+        for i, (E,) in enumerate(decays):
             both, M = jnp.concatenate([q * E, k * E]), m_ref[i]
             pairs = _dotl(both, both[chunk:], _NT, dtype)   # q.k over k.k
             Aqk, Akk = Aqk + M * pairs[:chunk], Akk + M * pairs[chunk:]
-            self.levels.append((sign, E, both, M))
+            self.levels.append((s_ref[i], E, both, M))
         self.Akk, self.Aqk = Akk, Aqk
         self.from_start = jnp.exp(self.G)
         self.end = self.G[chunk - 1:chunk]                  # (1, d)
         self.to_end = jnp.exp(self.end - self.G)
 
 
+# Mosaic issues a kernel's matmuls in the order they are written, and most of
+# these wait for the one before (a solve is a chain of ten).  What does not
+# wait for each other — the systems of a solve, the heads of a forward — is
+# therefore written a stage of all before the next stage of any: at the
+# cell's shape two solves one after the other take 13.76 ms a layer and in
+# step 9.91, a forward's four heads 6.36 and 4.30 (``PERF.md``, PR 56).
+
+
+def _kda_solve_kernel(k_ref, g_ref, b_ref, n_ref, m_ref, s_ref, inverse_ref,
+                      *, hb: int, d: int):
+    """Some chunks of one head block: the heads' ``(I + L)^-1``, ``L = beta
+    * Akk``, side by side, (C, hb * C) a chunk.  Two heads go through the MXU
+    as one system: their ``k * E`` one over the other give both heads' pairs
+    on the diagonal blocks of one product, which a level's mask twice down
+    the diagonal (``m_ref``) keeps and nothing else, and that matrix twice as
+    wide is what ``_unit_lower_inverses`` takes at the price of the narrower;
+    an odd last head goes alone."""
+    dtype = k_ref.dtype
+    chunk = n_ref.shape[1]
+    heads = [(slice(top, top + chunk), h)
+             for top in range(0, k_ref.shape[1], chunk) for h in range(hb)]
+    # a system: a chunk's pair of heads, or its odd last head
+    systems = [range(first, min(first + 2, top + hb))
+               for top in range(0, len(heads), hb)
+               for first in range(top, top + hb, 2)]
+    _, decays = _decays([g_ref[0, rows, h * d:(h + 1) * d]
+                         for rows, h in heads], n_ref, s_ref)
+    ks = [k_ref[0, rows, h * d:(h + 1) * d].astype(jnp.float32)
+          for rows, h in heads]
+    Ls = [jnp.zeros((len(system) * chunk,) * 2, jnp.float32)
+          for system in systems]
+    for i, Es in enumerate(decays):
+        scaled = [jnp.concatenate([ks[j] * Es[j] for j in system])
+                  for system in systems]
+        Ls = [L + m_ref[i, :len(L), :len(L)] * _dotl(ke, ke, _NT, dtype)
+              for L, ke in zip(Ls, scaled)]
+    betas = [b_ref[0, 0, rows, h:h + 1] for rows, h in heads]
+    Xs = _unit_lower_inverses(
+        [jnp.concatenate([betas[j] for j in system]) * L
+         for L, system in zip(Ls, systems)], chunk)
+    inverses = [X[at:at + chunk, at:at + chunk]
+                for X in Xs for at in range(0, len(X), chunk)]
+    for top in range(0, len(heads), hb):
+        inverse_ref[0, 0, heads[top][0]] = _beside(inverses[top:top + hb])
+
+
 def _kda_fwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
-                    o_ref, before_ref, inverse_ref, state, *, hb: int,
+                    inverse_ref, o_ref, before_ref, state, *, hb: int,
                     d: int):
     """A chunk of one head block.  ``state``: the block's heads' states,
     each (dv, dk) — the key's channels along the lanes, where a decay a
-    channel is a row — carried from chunk to chunk.  The backward's two
-    residuals: the states before the chunk, and the heads' ``(I + L)^-1``
-    side by side, (C, hb * C)."""
+    channel is a row — carried from chunk to chunk.  The heads' ``(I +
+    L)^-1`` are read (``kda_solve`` made them) and the states before the
+    chunk written, the backward's residual."""
     dtype = q_ref.dtype
+    chunk = q_ref.shape[1]
 
     @pl.when(pl.program_id(2) == 0)
     def _():
         state[...] = jnp.zeros_like(state)
 
     before_ref[0, 0] = state[...]
-    heads = []
-    for h in range(hb):
-        at = slice(h * d, (h + 1) * d)
-        q, k, v = (r[0, :, at].astype(jnp.float32)
-                   for r in (q_ref, k_ref, v_ref))
-        heads.append((at, v, _Inside(q, k, g_ref[0, :, at],
-                                     b_ref[0, 0, :, h:h + 1], n_ref, m_ref,
-                                     s_ref, dtype)))
-    inverses = _inverses([c.beta * c.Akk for _, _, c in heads])
-    inverse_ref[0, 0] = _beside(inverses)
-    outs = []
-    for (at, v, c), inverse in zip(heads, inverses):
-        q, k, beta, S = c.q, c.k, c.beta, state[at, :]
-        rhs = beta * (v - _dotl(k * c.from_start, S, _NT, dtype))
-        U = _dotf(inverse, rhs, _NN)
-        outs.append((_dotl(q * c.from_start, S, _NT, dtype)
-                     + _dotl(c.Aqk, U, _NN, dtype)).astype(o_ref.dtype))
-        state[at, :] = jnp.exp(c.end) * S + _dotl(U, k * c.to_end, _TN, dtype)
-    o_ref[0] = _beside(outs)
+    ats = [slice(h * d, (h + 1) * d) for h in range(hb)]
+    qs, ks, vs = ([r[0, :, at].astype(jnp.float32) for at in ats]
+                  for r in (q_ref, k_ref, v_ref))
+    Gs, decays = _decays([g_ref[0, :, at] for at in ats], n_ref, s_ref)
+    row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
+    col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    eye = (row == col).astype(jnp.float32)
+    Aqks = [_dotl(q, k, _NT, dtype) * eye for q, k in zip(qs, ks)]
+    for i, Es in enumerate(decays):
+        Aqks = [Aqk + m_ref[i] * _dotl(q * E, k * E, _NT, dtype)
+                for Aqk, q, k, E in zip(Aqks, qs, ks, Es)]
+    from_start = [jnp.exp(G) for G in Gs]
+    ends = [G[chunk - 1:chunk] for G in Gs]                 # (1, d)
+    Ss = [state[at, :] for at in ats]
+    rhs = [b_ref[0, 0, :, h:h + 1] * (v - _dotl(k * e, S, _NT, dtype))
+           for h, (v, k, e, S) in enumerate(zip(vs, ks, from_start, Ss))]
+    Us = [_dotf(inverse_ref[0, 0, :, h * chunk:(h + 1) * chunk], r, _NN)
+          for h, r in enumerate(rhs)]
+    o_ref[0] = _beside([
+        (_dotl(q * e, S, _NT, dtype) + _dotl(Aqk, U, _NN, dtype)
+         ).astype(o_ref.dtype)
+        for q, e, S, Aqk, U in zip(qs, from_start, Ss, Aqks, Us)])
+    for at, G, end, S, U, k in zip(ats, Gs, ends, Ss, Us, ks):
+        state[at, :] = jnp.exp(end) * S \
+            + _dotl(U, k * jnp.exp(end - G), _TN, dtype)
 
 
 def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
@@ -428,6 +488,10 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
     db_ref[0, 0] = dbeta
 
 
+# the most chunks a grid step of the solve takes: more systems in step
+# (9.11 ms a layer at one, 8.77 at two, 8.65 at four: ``PERF.md``, PR 56)
+_SOLVE_SPAN = 2
+
 # the most heads a grid step takes: its share of a step's fixed cost against
 # the size of the unrolled body
 _HEAD_BLOCK = 4
@@ -435,7 +499,8 @@ _HEAD_BLOCK = 4
 
 class _Shape:
     """The sizes of one device's call and the blocks of its grid: (batch,
-    head block, chunk), the chunks in sequence."""
+    head block, chunk), the chunks in sequence — or, where no chunk waits for
+    another (``order="parallel"``), ``span`` of them a grid step."""
 
     def __init__(self, q, beta, chunk: int):
         self.batch, self.seq, width = q.shape
@@ -446,20 +511,21 @@ class _Shape:
         self.blocks, self.chunks = self.heads // self.hb, self.seq // chunk
         self.tree = tuple(map(jnp.asarray, _tree(chunk)))
 
-    def specs(self, turned: bool):
+    def specs(self, turned: bool, span: int = 1):
         last = self.chunks - 1
 
         def at(ic):
             return last - ic if turned else ic
 
-        wide = pl.BlockSpec((1, self.chunk, self.hb * self.d),
+        wide = pl.BlockSpec((1, span * self.chunk, self.hb * self.d),
                             lambda ib, ih, ic: (ib, at(ic), ih))
-        column = pl.BlockSpec((1, 1, self.chunk, self.hb),
+        column = pl.BlockSpec((1, 1, span * self.chunk, self.hb),
                               lambda ib, ih, ic: (ib, ih, at(ic), 0))
         before = pl.BlockSpec((1, 1, self.hb * self.d, self.d),
                               lambda ib, ih, ic: (ib, at(ic), ih, 0))
-        inverse = pl.BlockSpec((1, 1, self.chunk, self.hb * self.chunk),
-                               lambda ib, ih, ic: (ib, ih, at(ic), 0))
+        inverse = pl.BlockSpec(
+            (1, 1, span * self.chunk, self.hb * self.chunk),
+            lambda ib, ih, ic: (ib, ih, at(ic), 0))
         whole = [pl.BlockSpec(t.shape, lambda ib, ih, ic, n=t.ndim: (0,) * n)
                  for t in self.tree]
         return wide, column, before, inverse, whole
@@ -473,40 +539,55 @@ class _Shape:
         return t.transpose(0, 2, 1, 3).reshape(self.batch, self.seq,
                                                self.heads)
 
-    def call(self, kernel, name, **kwargs):
+    def call(self, kernel, name, order="arbitrary", span=1, **kwargs):
         from jax.experimental.pallas import tpu as pltpu
 
         return pl.pallas_call(
             functools.partial(kernel, hb=self.hb, d=self.d),
-            grid=(self.batch, self.blocks, self.chunks),
+            grid=(self.batch, self.blocks, self.chunks // span),
             compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "parallel", "arbitrary"),
+                dimension_semantics=("parallel", "parallel", order),
                 vmem_limit_bytes=64 << 20),
             interpret=_interpret(), name=name, **kwargs)
 
 
-@functools.partial(jax.jit, static_argnums=(5,), inline=True)
-def _forward(q, k, v, g, beta, chunk: int):
-    """``o`` and the backward's two float32 residuals: the states before
-    every chunk, (batch, chunks, heads * dv, dk), and every chunk's ``(I +
-    L)^-1``, a head block's side by side, (batch, head blocks, seq, hb *
-    chunk).  Jitted and inlined as ``ops/ssd.py``'s."""
+@functools.partial(jax.jit, static_argnums=(3,), inline=True)
+def _solve(k, g, beta, chunk: int):
+    """Every chunk's ``(I + L)^-1``, a head block's side by side: (batch,
+    head blocks, seq, hb * chunk) float32.  No ``q``, no ``v`` and no state:
+    no chunk waits for another."""
+    s = _Shape(k, beta, chunk)
+    span = max(n for n in range(1, _SOLVE_SPAN + 1) if s.chunks % n == 0)
+    wide, column, _, inverse, whole = s.specs(False, span)
+    n, m, sign = s.tree
+    twice = jnp.kron(jnp.eye(2, dtype=m.dtype), m)  # a pair of heads' masks
+    whole[1] = pl.BlockSpec(twice.shape, lambda ib, ih, ic: (0, 0, 0))
+    return s.call(
+        _kda_solve_kernel, "kda_solve", order="parallel", span=span,
+        in_specs=[wide] * 2 + [column] + whole, out_specs=inverse,
+        out_shape=jax.ShapeDtypeStruct(
+            (s.batch, s.blocks, s.seq, s.hb * chunk), jnp.float32),
+    )(k, g, s.columns(beta), n, twice, sign)
+
+
+@functools.partial(jax.jit, static_argnums=(6,), inline=True)
+def _forward(q, k, v, g, beta, inverse, chunk: int):
+    """``o`` and the states before every chunk, (batch, chunks, heads * dv,
+    dk) float32, from ``_solve``'s ``inverse``.  Jitted and inlined as
+    ``ops/ssd.py``'s."""
     from jax.experimental.pallas import tpu as pltpu
 
     s = _Shape(q, beta, chunk)
-    wide, column, before, inverse, whole = s.specs(False)
+    wide, column, before, solved, whole = s.specs(False)
     return s.call(
         _kda_fwd_kernel, "kda_fwd",
-        in_specs=[wide] * 4 + [column] + whole,
-        out_specs=[wide, before, inverse],
+        in_specs=[wide] * 4 + [column] + whole + [solved],
+        out_specs=[wide, before],
         out_shape=[jax.ShapeDtypeStruct(v.shape, v.dtype),
                    jax.ShapeDtypeStruct(
-                       (s.batch, s.chunks, s.heads * s.d, s.d), jnp.float32),
-                   jax.ShapeDtypeStruct(
-                       (s.batch, s.blocks, s.seq, s.hb * chunk),
-                       jnp.float32)],
+                       (s.batch, s.chunks, s.heads * s.d, s.d), jnp.float32)],
         scratch_shapes=[pltpu.VMEM((s.hb * s.d, s.d), jnp.float32)],
-    )(q, k, v, g, s.columns(beta), *s.tree)
+    )(q, k, v, g, s.columns(beta), *s.tree, inverse)
 
 
 @functools.partial(jax.jit, static_argnums=(8,), inline=True)
@@ -532,11 +613,12 @@ def _backward(q, k, v, g, beta, before, inverse, do, chunk: int):
 def _scan_kernels(q, k, v, g, beta, chunk):
     """The kernels under their one differentiation rule: (batch, seq, heads *
     d) operands, seq whole chunks; ``g`` and ``beta`` float32."""
-    return _forward(q, k, v, g, beta, chunk)[0]
+    return _scan_kernels_fwd(q, k, v, g, beta, chunk)[0]
 
 
 def _scan_kernels_fwd(q, k, v, g, beta, chunk):
-    o, before, inverse = _forward(q, k, v, g, beta, chunk)
+    inverse = checkpoint_name(_solve(k, g, beta, chunk), *KDA_RESIDUALS)
+    o, before = _forward(q, k, v, g, beta, inverse, chunk)
     return o, (q, k, v, g, beta, before, inverse)
 
 

@@ -246,20 +246,27 @@ def _build(case: str, compile_: bool) -> dict:
     return out
 
 
+def _kernel_calls(jaxpr):
+    """A count of the Pallas calls under ``jaxpr`` by kernel name."""
+    import collections
+
+    import jax
+
+    n = collections.Counter()
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            n[eqn.params["name"]] += 1
+        else:
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                n += _kernel_calls(sub)
+    return n
+
+
 def _flash_fwd_calls(jaxpr) -> int:
     """The ``flash_fwd`` kernel's calls under ``jaxpr``: one an attention
     layer a step, remat or not (the blocks' checkpoint keeps the kernel's
     output and logsumexp: ``models/gpt2.py::remat_block``)."""
-    import jax
-
-    n = 0
-    for eqn in jaxpr.eqns:
-        if eqn.primitive.name == "pallas_call":
-            n += eqn.params["name"] == "flash_fwd"
-        else:
-            n += sum(_flash_fwd_calls(sub)
-                     for sub in jax.core.jaxprs_in_params(eqn.params))
-    return n
+    return _kernel_calls(jaxpr)["flash_fwd"]
 
 
 def _build_flash(case: str, device) -> dict:
@@ -1020,48 +1027,54 @@ def test_kimi_linear_step_lowers_for_one_v5e_chip():
     """Tier-1: the one-chip step of Kimi-Linear-48B-A3B at published widths
     (KDA + dense; KDA, KDA, MLA, KDA sparse; 8 of 256 experts held, one row
     of 16,384) lowers for the TPU with its Mosaic kernels in it: the scan's
-    pair (``ops/kda.py``) in the four KDA layers, the flash pair of the one
+    three (``ops/kda.py``) in the four KDA layers, the flash pair of the one
     latent-attention layer, the grouped matmuls of the held experts under a
     contraction of 2,304 and the sum of their rows into the tokens, and no
     other — the convolutions, the gates and the norms are plain XLA."""
     row = _child(["kimi_linear"], compile_=False)["kimi_linear"]
     kernels = row["lowered_kernels"]
     assert kernels.pop("kernel") > 0
-    assert set(kernels) == {"kda_fwd", "kda_bwd", "flash_fwd", "flash_bwd",
-                            "onto_tokens"}, kernels
+    assert set(kernels) == {"kda_solve", "kda_fwd", "kda_bwd", "flash_fwd",
+                            "flash_bwd", "onto_tokens"}, kernels
     assert kernels["flash_fwd"] == kernels["flash_bwd"] == 1, kernels
+    # a KDA layer under remat: one solve, kept by name; the forward twice
+    assert (kernels["kda_solve"], kernels["kda_fwd"], kernels["kda_bwd"]) \
+        == (4, 8, 4), kernels
     assert row["flash_fwd_calls"] == 1, row
 
 
 @pytest.mark.slow
 def test_kimi_linear_step_compiles_and_fits_the_chip():
-    """The TPU compiler takes the step — the scan's two kernels with their
-    float32 solve and the state riding the grid, the flash kernels at 32
-    heads with an un-rotated shared key part, the grouped matmuls under a
+    """The TPU compiler takes the step — the scan's three kernels with
+    their float32 solve and the state riding the grid, the flash kernels at
+    32 heads with an un-rotated shared key part, the grouped matmuls under a
     contraction of 2,304 cut in two — and its memory analysis says five
-    layers fit one chip at one row of 16,384 beside 9.64 GB of state (PR 54:
-    7.23 GB of arguments + 6.51 GB of temporaries, 6.38 at PR 53 before the
-    scan's inverse was a residual, 0.13 GB a layer; see PERF.md)."""
+    layers fit one chip at one row of 16,384 beside 9.64 GB of state (PR 56:
+    7.23 GB of arguments + 6.48 GB of temporaries with the four layers'
+    inverses, 0.13 GB each, kept from the forward to each layer's backward;
+    6.51 at PR 54, where one layer's lived at a time, 6.38 at PR 53 before
+    the inverse was a residual; see PERF.md)."""
     row = _child(["kimi_linear"], compile_=True)["kimi_linear"]
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row
-    # a KDA layer: the scan's forward, again under remat, and its backward;
-    # the MLA layer: flash forward and the backward's one kernel; a sparse
-    # layer's held experts: twelve grouped-matmul calls and the two that add
-    # rows into tokens, as Kimi-VL's: 4 x 3 + 2 + 4 x (12 + 2)
-    assert row["tpu_custom_calls"] == 4 * 3 + 2 + 4 * (12 + 2), row
+    # a KDA layer: the scan's solve, its forward, the forward again under
+    # remat (the solve is kept by name) and its backward; the MLA layer:
+    # flash forward and the backward's one kernel; a sparse layer's held
+    # experts: twelve grouped-matmul calls and the two that add rows into
+    # tokens, as Kimi-VL's: 4 x 4 + 2 + 4 x (12 + 2)
+    assert row["tpu_custom_calls"] == 4 * 4 + 2 + 4 * (12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
 def test_kda_kernels_compile_for_one_v5e_chip():
-    """Tier-1, fifteen seconds: Mosaic takes ``ops/kda.py``'s two kernels at
-    the cell's shape (1 x 16,384 x 32 heads x 128, chunks of 64, bf16) — the
-    levels' matmuls, two heads' solves side by side in one 128-wide matrix,
+    """Tier-1, fifteen seconds: Mosaic takes ``ops/kda.py``'s three kernels
+    at the cell's shape (1 x 16,384 x 32 heads x 128, chunks of 64, bf16) —
+    the levels' matmuls, two heads' pairs and solves in one 128-wide matrix,
     the float32 sums as three bfloat16 passes —, which the interpreter on
     the CPU cannot say."""
     row = _child(["kda_s16384"], compile_=True)["kda_s16384"]
     assert "refused" not in row, row
-    assert row["tpu_custom_calls"] == 2, row    # a forward and a backward
+    assert row["tpu_custom_calls"] == 3, row    # solve, forward, backward
 
 
 def test_phi4_flash_step_lowers_for_one_v5e_chip():

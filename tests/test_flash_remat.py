@@ -3,11 +3,15 @@ named by the forward rules of ``ops/attention.py`` and saved by the blocks'
 ``jax.checkpoint`` policy (``models/gpt2.py::remat_block``), so that a
 rematerialised block does not run its attention forward a second time.
 (a) the op under a checkpoint, the four uses of it; (b) the model stacks:
-which residuals a loss keeps, on one device and under ``fsdp``.  The Pallas
-kernels are interpreted, on the CPU."""
+which residuals a loss keeps, on one device and under ``fsdp``; (c) a Kimi
+Delta Attention layer's chunk inverses, named by ``ops/kda.py``: the solve
+runs once a layer, and the second forward reads what the first one's made.
+The Pallas kernels are interpreted, on the CPU."""
 
 import collections
+import contextlib
 import dataclasses
+import functools
 import re
 
 import numpy as np
@@ -20,10 +24,11 @@ from jax._src.ad_checkpoint import saved_residuals  # jax.ad_checkpoint has
 
 from ray_tpu.models.gpt2 import GPT2Config
 from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.models.pretrain import init_params, loss_fn
+from ray_tpu.models.pretrain import _model_family, init_params, loss_fn
 from ray_tpu.ops.attention import FLASH_RESIDUALS, flash_attention
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
-from test_chip_compile import _flash_fwd_calls  # the kernel's calls in a jaxpr
+from test_chip_compile import (  # the kernels' calls in a jaxpr
+    _flash_fwd_calls, _kernel_calls)
 
 KEEP = jax.checkpoint_policies.save_only_these_names(*FLASH_RESIDUALS)
 
@@ -203,3 +208,77 @@ def test_b_the_gradient_of_a_stack_runs_each_layers_forward_once(stack):
         jaxpr = jax.make_jaxpr(jax.grad(
             lambda p: loss_fn(model, p, batch)))(params)
         assert _flash_fwd_calls(jaxpr.jaxpr) == layers, remat
+
+
+# -------------------------------------------- (c) the scan's chunk inverses
+def _kda(**fields):
+    """Two Kimi Delta Attention layers: four heads of 16, chunks of 32.
+    In float32: in bf16 the CPU's fusions round a recomputed block's
+    convolutions and norms otherwise than the forward's, under any policy."""
+    return _llama(layer_types=("kda", "kda"), kda_n_heads=4, kda_head_dim=16,
+                  kda_chunk=32, dtype=jnp.float32, **fields)
+
+
+@functools.lru_cache(maxsize=None)
+def _kda_params():
+    return init_params(_kda())[1]   # (runs the layers: once for all)
+
+
+def _kda_grad(cfg):
+    """(the loss of two ``kda`` blocks, the parameters)."""
+    model, params = _model_family(cfg)[0](cfg), _kda_params()
+    batch = {k: jnp.asarray(v) for k, v in zip(
+        ("input_ids", "targets"),
+        np.random.default_rng(0).integers(0, 512, (2, _BATCH, _SEQ // 2)))}
+    return (lambda p: loss_fn(model, p, batch)), params
+
+
+def _kda_calls(mesh=None, **fields):
+    """The scan's kernels in the gradient of two layers' loss, by name."""
+    loss, params = _kda_grad(_kda(**fields))
+    with jax.set_mesh(mesh) if mesh else contextlib.nullcontext():
+        calls = _kernel_calls(jax.make_jaxpr(jax.grad(loss))(params).jaxpr)
+    return tuple(calls[k] for k in ("kda_solve", "kda_fwd", "kda_bwd"))
+
+
+@pytest.mark.parametrize("mesh", [None, {"fsdp": 4}])
+def test_c_a_rematerialised_kda_layer_solves_once_and_runs_its_forward_twice(
+        mesh):
+    """The gradient of two ``kda`` blocks holds, a layer: one ``kda_solve``,
+    ``kda_fwd`` and ``kda_bwd`` without remat; one ``kda_solve``, two
+    ``kda_fwd`` and one ``kda_bwd`` under ``remat_block(..., "full")`` — under
+    the name the recomputation's solve is dead code —; two ``kda_solve``
+    under ``"dots"``, whose policy has no names.  The same on four CPU
+    devices, ``fsdp=4``, where the name is inside ``kda_scan``'s
+    ``shard_map`` and the policy finds it there."""
+    if mesh:
+        mesh = build_mesh(MeshConfig(**mesh), devices=jax.devices()[:4])
+    assert _kda_calls(mesh, remat=False) == (2, 2, 2)
+    assert _kda_calls(mesh, remat=True) == (2, 4, 2)
+    assert _kda_calls(mesh, remat=True, remat_policy="dots") == (4, 4, 2)
+
+
+def test_c_the_kept_inverse_gives_the_gradients_of_a_stack_without_remat():
+    """Bit for bit: the second forward and the backward read the matrix the
+    first forward's solve made."""
+    (loss, params), (plain, _) = (_kda_grad(_kda(remat=r))
+                                  for r in (True, False))
+    jax.tree_util.tree_map(np.testing.assert_array_equal,
+                           jax.jit(jax.grad(loss))(params),
+                           jax.jit(jax.grad(plain))(params))
+
+
+def test_c_a_loss_keeps_one_inverse_a_layer_beside_the_blocks_inputs():
+    """What two rematerialised ``kda`` blocks keep that the same blocks under
+    a policy without names do not: each layer's chunk inverses, (batch, head
+    blocks, seq, heads * chunk) float32.  (The inverse is used again inside
+    its block, by ``kda_fwd``, so JAX hands it over through a
+    ``reduce_precision`` and the list reads that and not the name, as for
+    ``flash_out``.)"""
+    def kept(**fields):
+        cfg = _kda(remat=True, **fields)
+        inverse = (_BATCH, 1, _SEQ // 2, cfg.kda_n_heads * cfg.kda_chunk)
+        return sum(aval.shape == inverse and aval.dtype == jnp.float32
+                   for aval, _ in saved_residuals(*_kda_grad(cfg)))
+
+    assert (kept(), kept(remat_policy="dots")) == (2, 0)

@@ -4,8 +4,10 @@ operand, for the ``jax.numpy`` form and for the Mosaic kernels under the
 Pallas interpreter, at one chunk, several, a partial last chunk and two rows,
 at decays so small that ``e^(G_r) * e^(-G_j)`` would overflow inside a chunk,
 and that no position reads a later one; at every shape of a head block (one
-head, a pair and an odd head, several blocks); and the backward's second
-residual, ``(I + L)^-1``, by its definition and as the backward reads it.
+head, a pair and an odd head, several blocks); ``kda_solve``'s ``(I + L)^-1``
+by its definition and as the forward and the backward read it; and outputs
+and gradients bit for bit what they were before the solve was a call of its
+own.
 
 Tolerances.  Float32 against float32 at matmul precision 'highest' differ by
 summation order and by the solve's: 1e-4 of the largest value for outputs,
@@ -14,6 +16,7 @@ reason).
 """
 
 import functools
+import hashlib
 
 import jax
 import jax.numpy as jnp
@@ -116,22 +119,25 @@ def test_a_head_block_of_every_shape_equals_the_recurrence(heads):
 
 
 def _inverses_by_head(inverse, heads, chunk):
-    """The forward's second residual, (batch, head blocks, seq, hb * chunk),
-    as (batch, chunks, heads, chunk, chunk)."""
+    """``kda_solve``'s result, (batch, head blocks, seq, hb * chunk), as
+    (batch, chunks, heads, chunk, chunk)."""
     batch, blocks, seq, width = inverse.shape
     return inverse.reshape(batch, blocks, seq // chunk, chunk, width // chunk,
                            chunk).transpose(0, 2, 1, 4, 3, 5).reshape(
         batch, seq // chunk, heads, chunk, chunk)
 
 
-@pytest.mark.parametrize("heads,chunk,seq", [(2, 8, 24), (3, 8, 24),
-                                             (5, 8, 16), (6, 32, 64)])
+@pytest.mark.parametrize("heads,chunk,seq", [
+    (1, 8, 24), (2, 8, 24), (3, 8, 24), (5, 8, 16), (6, 32, 64)])
 def test_a_residual_is_the_inverse_of_the_chunks_system(heads, chunk, seq):
-    """``(I + L) @ inverse`` is the identity for every head and chunk, with
-    ``L_rj = b_r sum_d k_rd k_jd e^(G_rd - G_jd)``, ``j < r``, made here."""
+    """``kda_solve`` alone, from ``k``, ``g`` and ``beta``: ``(I + L) @
+    inverse`` is the identity for every head and chunk, with ``L_rj = b_r
+    sum_d k_rd k_jd e^(G_rd - G_jd)``, ``j < r``, made here.  One head, a
+    pair as one system, a pair and an odd head, blocks of one; a chunk and
+    two chunks a grid step."""
     q, k, v, g, beta = operands(seq, 2, heads)
     with jax.default_matmul_precision("highest"):
-        inverse = kda._forward(q, k, v, g, beta, chunk)[2]
+        inverse = kda._solve(k, g, beta, chunk)
 
         def chunks(t):      # (B, S, H * d) -> (B, chunks, H, C, d)
             return t.reshape(2, seq // chunk, chunk, heads, -1).transpose(
@@ -152,19 +158,27 @@ def test_a_residual_is_the_inverse_of_the_chunks_system(heads, chunk, seq):
 
 @pytest.mark.parametrize("heads", [2, 3])
 def test_a_backward_reads_the_inverse_it_is_given(heads):
-    """``_backward`` on the forward's residual, and on an inverse made again
-    outside a kernel from the same operands by the kernels' own ``_Inside``
-    and ``_inverses``: the same five gradients, bit for bit."""
+    """``_backward`` on ``kda_solve``'s result, and on an inverse made again
+    outside a kernel from the same operands by the backward's own ``_Inside``
+    (its ``Akk``: the k.k pairs under the q.k pairs, where the solve makes
+    two heads' in one product) and ``_unit_lower_inverses``: the same five
+    gradients, bit for bit."""
     chunk, (q, k, v, g, beta) = 8, operands(24, 2, heads)
     d, tree = q.shape[-1] // heads, kda._tree(chunk)
-    o, before, inverse = kda._forward(q, k, v, g, beta, chunk)
+    inverse = kda._solve(k, g, beta, chunk)
+    o, before = kda._forward(q, k, v, g, beta, inverse, chunk)
 
     def block(b, at):   # the heads' inverses of one chunk, side by side
-        insides = [kda._Inside(
+        Ls = [c.beta * c.Akk for c in (kda._Inside(
             *(t[b, at:at + chunk, h * d:(h + 1) * d] for t in (q, k, g)),
             beta[b, at:at + chunk, h:h + 1], *tree, q.dtype)
-            for h in range(heads)]
-        return kda._beside(kda._inverses([c.beta * c.Akk for c in insides]))
+            for h in range(heads))]
+        # two heads' systems down the diagonal of one matrix
+        Xs = kda._unit_lower_inverses(
+            [jax.scipy.linalg.block_diag(*Ls[i:i + 2])
+             for i in range(0, heads, 2)], chunk)
+        return kda._beside([X[i:i + chunk, i:i + chunk] for X in Xs
+                            for i in range(0, len(X), chunk)])
 
     again = jnp.stack([
         jnp.concatenate([block(b, at) for at in range(0, 24, chunk)])
@@ -176,6 +190,67 @@ def test_a_backward_reads_the_inverse_it_is_given(heads):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
     # and makes none of its own: dv = b * inverse^T dU
     assert not np.any(back(jnp.zeros_like(inverse), do, chunk)[2])
+
+
+@pytest.mark.parametrize("heads", [2, 3])
+def test_a_forward_reads_the_inverse_it_is_given(heads):
+    """``_forward`` makes no solve of its own: ``U = inverse @ rhs``, so a
+    zero inverse writes nothing to the state and reads nothing out of it,
+    and the identity's outputs are not the solve's."""
+    chunk, (q, k, v, g, beta) = 8, operands(24, 2, heads)
+    inverse = kda._solve(k, g, beta, chunk)
+    forward = functools.partial(kda._forward, q, k, v, g, beta)
+    o, before = forward(inverse, chunk)
+    np.testing.assert_array_equal(
+        np.asarray(o), np.asarray(kda.kda_scan(q, k, v, g, beta, chunk=chunk)))
+    for t in forward(jnp.zeros_like(inverse), chunk):
+        assert not np.any(t)
+    eyes = jnp.tile(jnp.eye(chunk), (24 // chunk, heads))
+    wrong, _ = forward(jnp.broadcast_to(eyes, inverse.shape), chunk)
+    np.testing.assert_array_equal(      # a chunk's first position solves
+        np.asarray(wrong[:, 0]), np.asarray(o[:, 0]))   # nothing
+    assert float(jnp.max(jnp.abs(wrong - o))) > 1e-2 * float(
+        jnp.max(jnp.abs(o)))
+
+
+# sha256 of ``kda_scan``'s outputs and five gradients, float32 bytes one
+# after the other, on ``operands(seq, 2, heads)`` at chunks of 8 under the
+# Pallas interpreter, taken from the tree before ``kda_solve`` (commit
+# ee0fc4d, the fused ``kda_fwd`` that solved for itself): (heads, bf16 q, k
+# and v, seq) -> digest.  The same matmuls on the same operands in the same
+# order of sums: the split, the paired k.k product and the order the kernels
+# are written in change no bit.
+_BEFORE_THE_SPLIT = {
+    (1, False, 24):
+        "6989583a265cbed582f064dfabe5f0c01e42e6b3ef8920be492cbf1868e57a7e",
+    (2, False, 32):
+        "23d7745f81d92000dac647bea2652e0f1f3512c52ccf64dc252dcfbde58ec9f3",
+    (3, False, 24):
+        "a249500fa31a86b3bb5ef7aa4a160e56b26fb34f2b21540206bc2fa733981848",
+    (5, False, 32):
+        "8c621f8c19d59f3aa71c6cb84c5b935074dcedeafb794e948e6d4a0b17228887",
+    (4, True, 32):
+        "98fda6d1931ae1dc0b700c9b634a3fc85af24f961547f128b81f98b3db2b338b",
+    (6, True, 24):
+        "8f2d2e2dcb500b1e40bcc0899a5cf8e30165e5e098c0ddd0f8e63d138e632bf3",
+}
+
+
+@pytest.mark.parametrize("heads,low,seq", sorted(_BEFORE_THE_SPLIT))
+def test_a_outputs_and_gradients_are_the_fused_forwards_bit_for_bit(
+        heads, low, seq):
+    q, k, v, g, beta = operands(seq, 2, heads)
+    if low:
+        q, k, v = (t.astype(jnp.bfloat16) for t in (q, k, v))
+    weight = jax.random.normal(jax.random.PRNGKey(7), v.shape)
+    scan = functools.partial(kda.kda_scan, chunk=8)
+    out, grads = jax.jit(lambda *a: (scan(*a), jax.grad(
+        lambda *b: jnp.sum(scan(*b).astype(jnp.float32) * weight),
+        argnums=range(5))(*a)))(q, k, v, g, beta)
+    digest = hashlib.sha256()
+    for t in (out, *grads):
+        digest.update(np.asarray(t).tobytes())
+    assert digest.hexdigest() == _BEFORE_THE_SPLIT[heads, low, seq]
 
 
 @pytest.mark.parametrize("impl", IMPLS)
