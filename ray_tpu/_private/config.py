@@ -239,9 +239,6 @@ _d("collective_liveness_grace_s", float, 2.0,
 _d("collective_liveness_interval_s", float, 2.0,
    "minimum spacing between liveness probes of the same rank while a "
    "recv keeps waiting (probes are sockets + KV reads; don't spam them)")
-_d("collective_pipeline", bool, True,
-   "pipelined ring data path: fire-and-forget chunked sends overlapped "
-   "with recv+reduce; off = the serial blocking-send ring")
 _d("collective_chunk_bytes", int, 2 * 1024 * 1024,
    "wire chunk size for pipelined ring collectives; each ring step's "
    "payload is split into chunks this size so send, recv, and reduce "

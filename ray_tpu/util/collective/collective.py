@@ -13,8 +13,7 @@ Data path (the fast-collectives stack, ROADMAP item 3):
   riding the RPC layer's coalesced batch (`notify_coalesced_threadsafe`), so
   send, recv, and reduce overlap instead of alternating one blocking
   ``call_sync`` per hop.  A slice is forwarded the moment it is reduced —
-  the 2(N-1)-step allreduce streams.  ``collective_pipeline=False`` selects
-  the serial blocking-send ring (``tests/test_collective.py`` runs both).
+  the 2(N-1)-step allreduce streams.
   When sender and receiver share a node, bulk chunks ride a per-group
   shared-memory arena (``shm_channel.py``) and only a tiny descriptor
   crosses the RPC — the receiver reduces straight out of the mapped
@@ -290,13 +289,10 @@ class Group:
             timeout_s = RayConfig.collective_default_timeout_s
         return time.monotonic() + timeout_s
 
-    def _pipelined(self) -> bool:
-        return bool(RayConfig.collective_pipeline)
-
     def _send_to(self, rank: int, data, seq: int, tag: int = 0,
                  deadline: Optional[float] = None):
-        """Legacy blocking send (one round trip per payload): p2p ``send``
-        and the ``collective_pipeline=False`` serial ring use it."""
+        """Blocking send (one round trip per payload): p2p ``send`` uses
+        it."""
         timeout = RayConfig.collective_op_timeout_s if deadline is None \
             else max(deadline - time.monotonic(), 0.001)
         try:
@@ -331,7 +327,6 @@ class Group:
                 group=self.name, op="send", rank=rank) from e
 
     def _send_payload(self, rank: int, payload, seq: int, tag: int,
-                      deadline: Optional[float], pipelined: bool,
                       shm_ok: bool = True):
         if rank in self._dead_ranks:
             # a probe already declared this peer dead: don't queue frames
@@ -348,24 +343,21 @@ class Group:
             payload = self._shm_resolve(payload, copy=True)
             detached = True
         self._op_bytes += _payload_bytes(payload)
-        if pipelined:
-            wire = self._shm_wire(rank, payload, seq, tag, shm_ok)
-            if wire is payload and not detached \
-                    and isinstance(wire, np.ndarray) \
-                    and wire.nbytes >= rpc._OOB_THRESHOLD:
-                # Inline arrays at/above the RPC out-of-band threshold are
-                # held as zero-copy views until the IO loop writes the
-                # frame; the allgather phase overwrites exactly the slices
-                # reduce-scatter sent, and callers may mutate their tensor
-                # the moment the op returns — either corrupts a frame
-                # still queued behind transport backpressure.  Detach a
-                # copy.  (Smaller payloads were fully pickled inband at
-                # post time; quant records and descriptors are already
-                # frame-stable.)
-                wire = np.array(wire)
-            self._post_send(rank, wire, seq, tag)
-        else:
-            self._send_to(rank, payload, seq, tag, deadline=deadline)
+        wire = self._shm_wire(rank, payload, seq, tag, shm_ok)
+        if wire is payload and not detached \
+                and isinstance(wire, np.ndarray) \
+                and wire.nbytes >= rpc._OOB_THRESHOLD:
+            # Inline arrays at/above the RPC out-of-band threshold are
+            # held as zero-copy views until the IO loop writes the
+            # frame; the allgather phase overwrites exactly the slices
+            # reduce-scatter sent, and callers may mutate their tensor
+            # the moment the op returns — either corrupts a frame
+            # still queued behind transport backpressure.  Detach a
+            # copy.  (Smaller payloads were fully pickled inband at
+            # post time; quant records and descriptors are already
+            # frame-stable.)
+            wire = np.array(wire)
+        self._post_send(rank, wire, seq, tag)
 
     def _shm_wire(self, rank: int, payload, seq: int, tag: int,
                   shm_ok: bool):
@@ -740,11 +732,10 @@ class Group:
             return np.dtype(dtype)
         return np.dtype(np.float64)
 
-    def _wire_bounds(self, size: int, itemsize: int,
-                     pipelined: bool) -> List[tuple]:
+    def _wire_bounds(self, size: int, itemsize: int) -> List[tuple]:
         """Split a flat chunk of ``size`` elements into wire slices."""
         chunk_bytes = RayConfig.collective_chunk_bytes
-        if not pipelined or chunk_bytes <= 0 or size == 0:
+        if chunk_bytes <= 0 or size == 0:
             return [(0, size)]
         per = max(chunk_bytes // max(itemsize, 1), 1)
         # tag space holds _TAG_STRIDE chunk indices per step
@@ -753,7 +744,7 @@ class Group:
 
     def _rs_flat(self, flats: List[np.ndarray], op: str, seq: int,
                  ring: List[int], shift: int, deadline: float,
-                 op_name: str, quant: Optional[str], pipelined: bool) -> None:
+                 op_name: str, quant: Optional[str]) -> None:
         """Streaming ring reduce-scatter over position-indexed flat chunks
         (mutated in place).  After N-1 steps, chunk[(pos + 1 + shift) % N]
         holds the full reduction (shift=-1 leaves position p with shard p).
@@ -767,9 +758,9 @@ class Group:
         left = ring[(pos - 1) % n]
         first = flats[(pos + shift) % n]
         for w, (s, e) in enumerate(self._wire_bounds(
-                first.size, first.itemsize, pipelined)):
+                first.size, first.itemsize)):
             self._send_payload(right, self._maybe_quant(first[s:e], quant),
-                               seq, _TAG_RS + w, deadline, pipelined)
+                               seq, _TAG_RS + w)
         if fault_injection.ENABLED and fault_injection.hit(
                 "collective.step", detail=f"rank{self.rank}") == "kill":
             # mid-collective rank death: our first ring step is already on
@@ -779,7 +770,7 @@ class Group:
         for step in range(n - 1):
             fl = flats[(pos - step - 1 + shift) % n]
             for w, (s, e) in enumerate(self._wire_bounds(
-                    fl.size, fl.itemsize, pipelined)):
+                fl.size, fl.itemsize)):
                 incoming = self._maybe_dequant(self._recv_from(
                     left, seq, _TAG_RS + step * _TAG_STRIDE + w,
                     deadline=deadline, op=op_name))
@@ -788,12 +779,11 @@ class Group:
                 if step + 1 < n - 1:
                     self._send_payload(
                         right, self._maybe_quant(seg, quant), seq,
-                        _TAG_RS + (step + 1) * _TAG_STRIDE + w,
-                        deadline, pipelined)
+                        _TAG_RS + (step + 1) * _TAG_STRIDE + w)
 
     def _ag_flat(self, flats: List[np.ndarray], owned_idx: int, seq: int,
                  ring: List[int], deadline: float, op_name: str,
-                 quant: Optional[str], pipelined: bool) -> None:
+                 quant: Optional[str]) -> None:
         """Streaming ring allgather over position-indexed flat chunks: each
         position starts owning chunk[owned_idx]; N-1 rotations fill all.
         Received wire chunks are relayed VERBATIM (quantized payloads are
@@ -806,22 +796,21 @@ class Group:
         left = ring[(pos - 1) % n]
         own = flats[owned_idx]
         for w, (s, e) in enumerate(self._wire_bounds(
-                own.size, own.itemsize, pipelined)):
+                own.size, own.itemsize)):
             self._send_payload(right, self._maybe_quant(own[s:e], quant),
-                               seq, _TAG_AG + w, deadline, pipelined)
+                               seq, _TAG_AG + w)
         for step in range(n - 1):
             recv_i = (owned_idx - step - 1) % n
             fl = flats[recv_i]
             for w, (s, e) in enumerate(self._wire_bounds(
-                    fl.size, fl.itemsize, pipelined)):
+                fl.size, fl.itemsize)):
                 pay = self._recv_from(
                     left, seq, _TAG_AG + step * _TAG_STRIDE + w,
                     deadline=deadline, op=op_name, raw=True)
                 if step + 1 < n - 1:
                     self._send_payload(
                         right, pay, seq,
-                        _TAG_AG + (step + 1) * _TAG_STRIDE + w,
-                        deadline, pipelined)
+                        _TAG_AG + (step + 1) * _TAG_STRIDE + w)
                 fl[s:e] = self._maybe_dequant(
                     self._shm_resolve(pay)).reshape(-1)
 
@@ -839,12 +828,9 @@ class Group:
             return full.reshape(arr.shape)
         pos = ring.index(self.rank)
         flats = np.array_split(full, n)  # views over one owned buffer
-        pipelined = self._pipelined()
-        self._rs_flat(flats, op, seq, ring, 0, deadline, op_name, quant,
-                      pipelined)
+        self._rs_flat(flats, op, seq, ring, 0, deadline, op_name, quant)
         owned = (pos + 1) % n
-        self._ag_flat(flats, owned, seq, ring, deadline, op_name, quant,
-                      pipelined)
+        self._ag_flat(flats, owned, seq, ring, deadline, op_name, quant)
         return full.reshape(arr.shape)
 
     # -------------------------------------------------- hierarchical two-level
@@ -854,13 +840,12 @@ class Group:
         """Intra-node leader reduce -> inter-node ring over leaders ->
         intra-node broadcast.  Cross-node traffic moves once per NODE
         instead of once per rank (The Big Send-off, arXiv:2504.18658)."""
-        pipelined = self._pipelined()
         ring_op = "sum" if op == "mean" else op
         if not plan.is_leader:
             self._send_payload(
                 plan.leader, self._maybe_quant(np.ascontiguousarray(arr),
                                                quant),
-                seq, _TAG_GATHER, deadline, pipelined)
+                seq, _TAG_GATHER)
             res = self._maybe_dequant(self._recv_from(
                 plan.leader, seq, _TAG_BCAST, deadline=deadline, op=op_name))
             return res.reshape(arr.shape)
@@ -876,8 +861,7 @@ class Group:
         if plan.members:
             pay = self._maybe_quant(np.ascontiguousarray(acc), quant)
             for m in plan.members:
-                self._send_payload(m, pay, seq, _TAG_BCAST, deadline,
-                                   pipelined)
+                self._send_payload(m, pay, seq, _TAG_BCAST)
         return acc
 
     # --------------------------------------------------------- quorum reduce
@@ -904,14 +888,13 @@ class Group:
             return (out / n if op == "mean" else out).astype(
                 arr.dtype).reshape(arr.shape)
         root = 0
-        pipelined = self._pipelined()
         if self.rank != root:
             # shm_ok=False: a contribution outside the quorum parks in
             # root's inbox across rounds — far past the arena's two-op
             # reuse window
             self._send_payload(
                 root, self._maybe_quant(np.ascontiguousarray(arr), quant),
-                seq, _TAG_QUORUM, deadline, pipelined, shm_ok=False)
+                seq, _TAG_QUORUM, shm_ok=False)
             res = self._maybe_dequant(self._recv_from(
                 root, seq, _TAG_QRESULT, deadline=deadline,
                 op=op_name)).astype(np.float64)
@@ -957,8 +940,7 @@ class Group:
         for r in others:
             # shm_ok=False: a straggler may consume this result rounds
             # later, after the root's op counter moved on
-            self._send_payload(r, pay, seq, _TAG_QRESULT, deadline,
-                               pipelined, shm_ok=False)
+            self._send_payload(r, pay, seq, _TAG_QRESULT, shm_ok=False)
         if op == "mean":
             result = result / n
         return result.astype(arr.dtype)
@@ -1066,14 +1048,13 @@ class Group:
             # per-rank payloads may differ in shape: rotate whole payloads
             # (quantized once at the owner, relayed verbatim — one quant
             # stage of error total)
-            pipelined = self._pipelined()
             right = (self.rank + 1) % n
             left = (self.rank - 1) % n
             items: List[Any] = [None] * n
             pay = self._maybe_quant(np.ascontiguousarray(arr), quant)
             items[self.rank] = self._dequant_to_input(pay) \
                 if quant is not None else arr
-            self._send_payload(right, pay, seq, _TAG_AG, deadline, pipelined)
+            self._send_payload(right, pay, seq, _TAG_AG)
             for step in range(n - 1):
                 recv_i = (self.rank - step - 1) % n
                 incoming = self._recv_from(
@@ -1082,8 +1063,7 @@ class Group:
                 if step + 1 < n - 1:
                     self._send_payload(
                         right, incoming, seq,
-                        _TAG_AG + (step + 1) * _TAG_STRIDE,
-                        deadline, pipelined)
+                        _TAG_AG + (step + 1) * _TAG_STRIDE)
                 # copy=True: the result leaves the op, so it must not
                 # alias arena memory the sender will reuse
                 data = self._shm_resolve(incoming, copy=True)
@@ -1115,7 +1095,7 @@ class Group:
             flats = [p.reshape(-1) for p in parts]
             self._rs_flat(flats, "sum" if op == "mean" else op, seq,
                           list(range(n)), -1, deadline, "reducescatter",
-                          quant, self._pipelined())
+                          quant)
             mine = parts[self.rank]
             if op == "mean":
                 mine = mine / n
@@ -1137,10 +1117,9 @@ class Group:
             # structure alone (size passed as "large" sentinel)
             plan = topo_mod.plan(self.rank, n, self._member_nodes,
                                  1 << 62, topology)
-            pipelined = self._pipelined()
             if plan.kind == "hier" and n > 1:
                 return self._hier_broadcast(array, root, seq, plan, deadline,
-                                            pipelined, quant)
+                                            quant)
             if self.rank == root:
                 arr = np.asarray(array)
                 pay = self._maybe_quant(np.ascontiguousarray(arr), quant)
@@ -1150,7 +1129,6 @@ class Group:
                         # any receiver participation, so nothing stops it
                         # from reusing arena regions receivers still read
                         self._send_payload(r, pay, seq, _TAG_BCAST,
-                                           deadline, pipelined,
                                            shm_ok=False)
                 return arr
             return self._maybe_dequant(self._recv_from(
@@ -1160,7 +1138,7 @@ class Group:
 
     def _hier_broadcast(self, array, root: int, seq: int,
                         plan: "topo_mod.Plan", deadline: float,
-                        pipelined: bool, quant: Optional[str]):
+                        quant: Optional[str]):
         """Root -> node leaders -> node members; the quantized payload is
         relayed verbatim (one quant stage of error total)."""
         if self.rank == root:
@@ -1171,12 +1149,11 @@ class Group:
             for lead in plan.leaders:
                 if lead != root:
                     self._send_payload(lead, pay, seq, _TAG_BCAST,
-                                       deadline, pipelined, shm_ok=False)
+                                       shm_ok=False)
             if plan.is_leader:
                 for m in plan.members:
                     if m != root:
                         self._send_payload(m, pay, seq, _TAG_BCAST,
-                                           deadline, pipelined,
                                            shm_ok=False)
             return arr
         src = root if plan.is_leader else plan.leader
@@ -1185,8 +1162,7 @@ class Group:
         if plan.is_leader:
             for m in plan.members:
                 if m != root:
-                    self._send_payload(m, pay, seq, _TAG_BCAST, deadline,
-                                       pipelined, shm_ok=False)
+                    self._send_payload(m, pay, seq, _TAG_BCAST, shm_ok=False)
         return self._maybe_dequant(pay)
 
     def barrier(self, timeout_s: Optional[float] = None):

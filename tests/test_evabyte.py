@@ -13,10 +13,9 @@ leaf, one ``ShardedPretrainer`` step, and every wrong model of the on-chip
 controls outside the float32 limits; (d) the objective: head ``r`` against the
 token ``r + 1`` ahead, the mask shifted with the targets, one head
 ``lm_loss`` to the bit; (e) the new parameters' partition rules and one
-device's losses on a virtual mesh.  (f), the older toys' steps as the parent
-lowered them, is the existing hash tests, unedited:
-``tests/test_sdar_parts.py`` (i), (n), ``tests/test_laguna_parts.py`` (e),
-``tests/test_kimi_vl.py`` (d), ``tests/test_lfm2.py`` (f).  The toy
+device's losses on a virtual mesh.  (f), every toy's step as it was lowered,
+is ``tests/test_pinned_steps.py`` and ``tests/test_sdar_programs.py`` (i),
+``tests/test_sdar_parts.py`` (n).  The toy
 (``perfbench/tests/toy/toy-evabyte.json``): 256 wide, two layers, two heads
 of 128, windows of 128 and chunks of 16, four heads over 96 ids.  On the chip
 the same reference runs at published widths against the bf16 program
@@ -25,28 +24,23 @@ the same reference runs at published widths against the bf16 program
 
 import dataclasses
 import functools
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench.harness import reference
+import toys
 from perfbench.harness.families import evabyte
 from perfbench.harness.tokens import ZipfStream
 from ray_tpu.models.gpt2 import lm_loss, shifted_heads_loss
-from ray_tpu.models.llama import LlamaConfig
-from ray_tpu.models.pretrain import init_params, loss_fn
+from ray_tpu.models.llama import LlamaConfig, LlamaLMModel
+from ray_tpu.models.pretrain import init_params
 from ray_tpu.ops.attention import (attention, eva_mask, flash_attention,
                                    mha_reference)
 from ray_tpu.ops.pooling import pool_chunks
 
-_TOYS = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "perfbench", "tests", "toy")
-with open(os.path.join(_TOYS, "toy-evabyte.json")) as f:
-    TOY = json.load(f)
+TOY = toys.toy("toy-evabyte")
 
 
 # ----------------------------------------------------------- (a) the kernels
@@ -224,22 +218,10 @@ def test_b_the_pooling_equals_the_plain_form(v_rank, kernels, s, chunk):
         np.testing.assert_allclose(a, w, atol=5e-5, err_msg="d" + name)
 
 
-def _program(impl="reference", positions=320, **changes):
-    """The program in float32, so that what is left to differ from the
-    reference is the mathematics; ``impl`` "flash" is the Pallas kernels
-    interpreted, with their own backward rule.  Every leaf is moved off its
-    initial value: no norm's ``g`` is zero."""
-    cfg = dataclasses.replace(evabyte.model_config(TOY, 1), dtype=jnp.float32,
-                              attention_impl=impl, **changes)
-    model, params = init_params(cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(1), 1000))
-    params = jax.tree_util.tree_map(
-        lambda a: a + 0.1 * jax.random.normal(next(keys), a.shape, a.dtype),
-        params)
-    rows = ZipfStream(cfg.vocab_size, seed=5).rows(2, positions)
-    return model, params, {k: jnp.asarray(v) for k, v in rows.items()}
-
-
+# The program runs in float32, so that what is left to differ from the
+# reference is the mathematics; ``attention_impl`` "flash" is the Pallas
+# kernels interpreted, with their own backward rule.  Every leaf is moved off
+# its initial value: no norm's ``g`` is zero.
 @pytest.mark.parametrize("impl", ["reference", "flash"])
 def test_b_what_reaches_what(impl):
     """Position ``t`` does not move when a later input does.  A chunk's
@@ -247,8 +229,8 @@ def test_b_what_reaches_what(impl):
     of window 0 moves its own window's later positions through the exact keys
     alone, so dropping the summaries' path (a window as long as the row)
     changes nothing there — and does reach the next window's."""
-    model, params, batch = _program(impl)
-    ids = batch["input_ids"]
+    model, params = toys.weights(TOY, attention_impl=impl)
+    ids = toys.rows(TOY, 2, 320)["input_ids"]
     apply = jax.jit(lambda ids: model.apply({"params": params}, ids))
     out = apply(ids)
     assert out.dtype == jnp.float32 and out.shape == (2, 320, 4 * 96)
@@ -256,7 +238,8 @@ def test_b_what_reaches_what(impl):
     np.testing.assert_array_equal(out[:, :200], later[:, :200])
     assert float(jnp.max(jnp.abs(out - later)[:, 200:])) > 1e-3
     # window 0 of the EVA stack is the causal stack's window 0 ...
-    causal = _program(impl, eva_window=0)[0]
+    causal = LlamaLMModel(toys.config(TOY, attention_impl=impl,
+                                      eva_window=0))
     plain = jax.jit(lambda ids: causal.apply(
         {"params": {k: ({n: w for n, w in v.items() if n != "attn"}
                         | {"attn": {n: w for n, w in v["attn"].items()
@@ -277,23 +260,6 @@ def test_b_what_reaches_what(impl):
 
 
 # ------------------------------------------ (c) the stack and its reference
-def _both(model, params, batch):
-    """(logits, loss, gradient norm) of program and reference."""
-    def program(params, batch):
-        logits = model.apply({"params": params}, batch["input_ids"])
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(model, p, batch))(params)
-        return (logits[..., :evabyte.columns(TOY)], loss,
-                reference.global_norm(grads))
-
-    def plain(params, batch):
-        return evabyte.logits_loss_gradnorm(
-            params, batch["input_ids"], batch["targets"], TOY)
-
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(program)(params, batch), jax.jit(plain)(params, batch)
-
-
 @pytest.mark.parametrize("impl,positions", [
     ("reference", 320), ("flash", 320), ("flash", 300), ("flash", 96)],
     ids=["reference", "flash", "flash-300", "flash-one-window"])
@@ -301,26 +267,22 @@ def test_c_program_equals_the_reference_in_float32(impl, positions):
     """Logits of all four heads, the loss over them and the gradient norm to
     float32 rounding; 300 positions end inside a window and a chunk, 96 are
     less than one window."""
-    got, want = _both(*_program(impl, positions))
-    assert got[0].shape == (2, positions, 4 * 96)
-    np.testing.assert_allclose(got[0], want[0], rtol=2e-4, atol=2e-4)
-    assert float(got[1]) == pytest.approx(float(want[1]), rel=1e-5)
-    assert float(got[2]) == pytest.approx(float(want[2]), rel=1e-4)
+    got = toys.program(TOY, positions, attention_impl=impl)
+    want = toys.reference(TOY, positions, attention_impl=impl)
+    assert got.logits.shape == (2, positions, 4 * 96)
+    np.testing.assert_allclose(got.logits, want.logits, rtol=2e-4, atol=2e-4)
+    assert float(got.loss) == pytest.approx(float(want.loss), rel=1e-5)
+    assert float(got.gradnorm) == pytest.approx(float(want.gradnorm),
+                                                rel=1e-4)
 
 
 def test_c_every_gradient_equals_the_references():
     """Leaf by leaf, not only the norm: attention's four projections with
     ``phi`` and ``mu``, the norms' ``g``, the SwiGLU, the head's 4 x 96
     columns."""
-    model, params, batch = _program("flash")
-
-    def loss(p):
-        return evabyte.heads_loss(
-            evabyte.logits(p, batch["input_ids"], TOY), batch["targets"], TOY)
-
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(lambda p: loss_fn(model, p, batch)))(params)
-        want = jax.jit(jax.grad(loss))(params)
+    got = toys.program(TOY, 320, attention_impl="flash").grads
+    want = toys.reference(TOY, 320, leaves=True,
+                          attention_impl="flash").grads
     assert set(got["h_1"]["attn"]) == {"wq", "wk", "wv", "wo", "phi", "mu"}
     assert got["lm_head"]["kernel"].shape == (256, 4 * 96)
     for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0],
@@ -337,31 +299,11 @@ def test_c_the_trainers_step_takes_the_references_loss_down():
     step's loss is the reference's on the same batch and weights (every
     ``g``, and so every norm's scale, at its start: 1), and the loss
     falls."""
-    from ray_tpu.models.pretrain import ShardedPretrainer
-    from ray_tpu.parallel.mesh import MeshConfig
-
-    cfg = dataclasses.replace(evabyte.model_config(TOY, 1), dtype=jnp.float32)
-    trainer = ShardedPretrainer(cfg, MeshConfig(), devices=jax.devices()[:1],
-                                lr=0.1)
-    assert not np.any(trainer.state[0]["h_0"]["attn_norm"]["scale"])
-    rows = ZipfStream(cfg.vocab_size, seed=5).rows(2, 320)
-    with jax.default_matmul_precision("highest"):
-        want = evabyte.logits_loss_gradnorm(
-            trainer.state[0], jnp.asarray(rows["input_ids"]),
-            jnp.asarray(rows["targets"]), TOY)[1]
-    losses = [float(trainer.step(rows)) for _ in range(10)]
-    assert losses[0] == pytest.approx(float(want), rel=1e-4)
+    assert not np.any(toys.weights(TOY, by=0)[1]["h_0"]["attn_norm"]["scale"])
+    want, losses, stats, *_ = toys.one_device(TOY, 2, 320, 10, lr=0.1)
+    assert losses[0] == pytest.approx(want, rel=1e-4)
     assert losses[-1] < losses[0] - 0.5
-    assert trainer.moe_stats == {}
-
-
-@functools.lru_cache(maxsize=None)
-def _program_results():
-    model, params, batch = _program()
-    with jax.default_matmul_precision("highest"):
-        return (params, batch) + jax.jit(lambda p, b: (
-            model.apply({"params": p}, b["input_ids"]),
-            loss_fn(model, p, b)))(params, batch)
+    assert stats == {}
 
 
 @pytest.mark.parametrize("wrong", evabyte.WRONG + (evabyte.PRECISION_BELOW,))
@@ -373,14 +315,13 @@ def test_c_the_tolerance_sees_each_wrong_model(wrong):
     RoPE left off, the residual rounded to bf16 — or, where only the
     objective is wrong (the later heads scoring the next token), the loss;
     and so does the reference itself with float8 activations."""
-    params, batch, got, got_loss = _program_results()
-    with jax.default_matmul_precision("highest"):
-        want, loss, _ = jax.jit(lambda p, b: evabyte.logits_loss_gradnorm(
-            p, b["input_ids"], b["targets"], TOY, wrong))(params, batch)
+    got = toys.program(TOY, 320, attention_impl="reference")
+    want = toys.reference(TOY, 320, wrong=wrong, attention_impl="reference")
     if wrong == "heads_next_byte":
-        assert abs(float(loss) - float(got_loss)) > 100 * 1e-5 * float(loss)
+        assert abs(float(want.loss) - float(got.loss)) \
+            > 100 * 1e-5 * float(want.loss)
     else:
-        assert float(jnp.max(jnp.abs(got[..., :want.shape[-1]] - want))) \
+        assert float(jnp.max(jnp.abs(got.logits - want.logits))) \
             > 100 * 2e-4
 
 
@@ -436,8 +377,8 @@ def test_d_the_new_fields_default_to_the_program_as_it_was():
                 0, 0, False, None, None, 1)
     with pytest.raises(ValueError, match="4 prediction heads are an untied "
                        "head under the next-token objective"):
-        init_params(dataclasses.replace(LlamaConfig.tiny(), n_pred_heads=4,
-                                        tie_embeddings=True))
+        jax.eval_shape(lambda: init_params(dataclasses.replace(
+            LlamaConfig.tiny(), n_pred_heads=4, tie_embeddings=True))[1])
 
 
 # ------------------------------------------------- (e) on a virtual mesh
@@ -453,19 +394,16 @@ def test_e_a_sharded_mesh_gives_the_single_device_loss(mesh):
     from ray_tpu.parallel.sharding import (llama_partition_rules,
                                            match_partition_rules)
 
-    cfg = dataclasses.replace(evabyte.model_config(TOY, 1), dtype=jnp.float32)
     specs = match_partition_rules(llama_partition_rules(),
-                                  init_params(cfg)[1])
+                                  toys.weights(TOY)[1])
     attn = specs["h_0"]["attn"]
     assert attn["phi"] == attn["mu"] == P("tp", None)
     assert attn["wq"]["kernel"] == P("fsdp", "tp")
     assert specs["h_0"]["attn_norm"]["scale"] == P()
     assert specs["lm_head"]["kernel"] == P("fsdp", "tp")
 
-    rows = ZipfStream(cfg.vocab_size, seed=5).rows(4, 320)
-    one = ShardedPretrainer(cfg, MeshConfig(), devices=jax.devices()[:1])
-    many = ShardedPretrainer(cfg, MeshConfig(**mesh),
+    one = toys.one_device(TOY, 4, 320, 2, want=False)   # for both meshes
+    many = ShardedPretrainer(toys.config(TOY), MeshConfig(**mesh),
                              devices=jax.devices()[:4])
-    for _ in range(2):      # the second step sees the first's gradients
-        assert float(many.step(rows)) == pytest.approx(float(one.step(rows)),
-                                                       rel=1e-5)
+    for want in one.losses:     # the second step sees the first's gradients
+        assert float(many.step(one.rows)) == pytest.approx(want, rel=1e-5)

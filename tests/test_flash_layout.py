@@ -404,7 +404,8 @@ def _loss_and_grads(model, params, ids):
     def loss(params):
         logits = model.apply({"params": params}, ids)
         return lm_loss(logits[:, :-1], ids[:, 1:])
-    return jax.value_and_grad(loss)(params)
+    # (jitted: op by op a model is a few hundred programs)
+    return jax.jit(jax.value_and_grad(loss))(params)
 
 
 def _against_reference(model_of, cfg, ids):
@@ -413,7 +414,7 @@ def _against_reference(model_of, cfg, ids):
     flash, plain = (model_of(dataclasses.replace(
         cfg, attention_impl=impl, dtype=jnp.float32, remat=False))
         for impl in ("flash", "reference"))
-    params = flash.init(jax.random.PRNGKey(0), ids)["params"]
+    params = jax.jit(flash.init)(jax.random.PRNGKey(0), ids)["params"]
     (got, got_grads), (want, want_grads) = (
         _loss_and_grads(m, params, ids) for m in (flash, plain))
     assert float(got) == pytest.approx(float(want), rel=1e-5)

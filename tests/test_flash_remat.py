@@ -126,10 +126,19 @@ _STACKS = {
 _BATCH, _SEQ = 4, 128
 
 
-def _kept(cfg, mesh=None):
+@functools.lru_cache(maxsize=None)
+def _params(stack):
+    """A stack's parameters, which neither remat nor the attention's kind
+    moves: made once, under jit (the forward ``init`` traces is dead code)."""
+    return jax.jit(lambda: init_params(_STACKS[stack][0]())[1])()
+
+
+def _kept(stack, mesh=None, gradients=True, **fields):
     """(loss, gradients, what the loss keeps for its backward: a count of
-    each (shape, dtype), and the names ``saved_residuals`` read)."""
-    model, params = init_params(cfg)
+    each (shape, dtype), and the names ``saved_residuals`` read); without
+    ``gradients`` nothing is run and the first two are None."""
+    cfg = _STACKS[stack][0](**fields)
+    model, params = _model_family(cfg)[0](cfg), _params(stack)
     batch = {k: jnp.asarray(v) for k, v in zip(
         ("input_ids", "targets"),
         np.random.default_rng(0).integers(0, 512, (2, _BATCH, _SEQ)))}
@@ -138,7 +147,10 @@ def _kept(cfg, mesh=None):
         return loss_fn(model, params, batch)
 
     def run():
-        value, grads = jax.value_and_grad(loss)(params)
+        # (op by op: compiled as one program the bf16 roundings of a block
+        # and of its recomputation fall differently, and the bits below)
+        value, grads = jax.value_and_grad(loss)(params) if gradients \
+            else (None, None)
         kept, names = collections.Counter(), collections.Counter()
         for aval, why in saved_residuals(loss, params):
             kept[aval.shape, str(aval.dtype)] += 1
@@ -153,8 +165,8 @@ def _kept(cfg, mesh=None):
 
 def _check(stack, mesh=None):
     build, attention_layers = _STACKS[stack]
-    loss, grads, kept, names = _kept(build(remat=True), mesh)
-    want_loss, want_grads, *_ = _kept(build(remat=False), mesh)
+    loss, grads, kept, names = _kept(stack, mesh, remat=True)
+    want_loss, want_grads, *_ = _kept(stack, mesh, remat=False)
     # (the same arithmetic: the same kernels on the same operands)
     assert float(loss) == float(want_loss)
     jax.tree_util.tree_map(np.testing.assert_array_equal, grads, want_grads)
@@ -162,8 +174,8 @@ def _check(stack, mesh=None):
     # lies outside the blocks; the kernels add their two arrays a layer to
     # that and nothing else.  (Shapes are the whole batch's: under a mesh a
     # residual crosses the ``shard_map`` as the devices' rows together.)
-    *_, plain, no_names = _kept(
-        build(remat=True, attention_impl="reference"), mesh)
+    *_, plain, no_names = _kept(stack, mesh, gradients=False, remat=True,
+                                attention_impl="reference")
     cfg = build()
     d_head = (cfg.n_embd if stack == "gpt2" else cfg.d_model) // cfg.n_head
     out = ((_BATCH, cfg.n_head, _SEQ, d_head), "bfloat16")
@@ -202,7 +214,8 @@ def test_b_the_gradient_of_a_stack_runs_each_layers_forward_once(stack):
     forward kernels in the gradient of the loss, with remat as without."""
     build, layers = _STACKS[stack]
     for remat in (True, False):
-        model, params = init_params(build(remat=remat))
+        cfg = build(remat=remat)
+        model, params = _model_family(cfg)[0](cfg), _params(stack)
         batch = {k: jnp.zeros((2, _SEQ), jnp.int32)
                  for k in ("input_ids", "targets")}
         jaxpr = jax.make_jaxpr(jax.grad(
@@ -221,7 +234,8 @@ def _kda(**fields):
 
 @functools.lru_cache(maxsize=None)
 def _kda_params():
-    return init_params(_kda())[1]   # (runs the layers: once for all)
+    # (once for all, and under jit: the layers ``init`` traces are dead code)
+    return jax.jit(lambda: init_params(_kda())[1])()
 
 
 def _kda_grad(cfg):

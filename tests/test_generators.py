@@ -95,7 +95,7 @@ def test_streaming_generator_task(cluster):
     assert got == [0, 2, 4, 6]
 
 
-def test_streaming_generator_actor_method(cluster):
+def test_streaming_generator_actor_method(cluster, tmp_path):
     """Streaming ACTOR methods: the first item is gettable BEFORE the
     method completes — the property that lets a consumer overlap with a
     long-running producer loop."""
@@ -103,21 +103,28 @@ def test_streaming_generator_actor_method(cluster):
 
     @ray_tpu.remote
     class Gen:
-        def items(self, n):
+        def items(self, n, seen_path):
+            import os
+
             for i in range(n):
                 yield 100 + i
-                time.sleep(0.2)
+                # the method goes on only once the consumer has the first
+                # item: a drain that is no stream never gets there
+                deadline = time.monotonic() + 300
+                while not os.path.exists(seen_path):
+                    assert time.monotonic() < deadline
+                    time.sleep(0.02)
 
         items.__ray_method_options__ = {"num_returns": "streaming"}
 
     g = Gen.remote()
+    seen = tmp_path / "first-item-seen"
     t0 = time.monotonic()
-    out = g.items.remote(5)
-    first = ray_tpu.get(out.item_ref(0), timeout=60)
-    elapsed = time.monotonic() - t0
+    out = g.items.remote(5, str(seen))
+    first = ray_tpu.get(out.item_ref(0), timeout=120)
+    print(f"first item after {time.monotonic() - t0:.2f} s")
     assert first == 100
-    # 5 items x 0.2s sleep-after-yield: a non-streaming drain takes >= 1s
-    assert elapsed < 0.9, f"first item took {elapsed:.2f}s: not streaming"
+    seen.touch()
     assert [ray_tpu.get(r, timeout=60) for r in out.stream(timeout_s=60)] \
         == [100, 101, 102, 103, 104]
 

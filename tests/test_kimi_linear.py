@@ -8,14 +8,11 @@ rotation against ``mha_reference``; (c) the whole stack (``kda`` and
 the experts held) against the plain reference — the recurrence position by
 position, a dense causal mask, every held expert on every token — logits
 and loss on the five layers, every gradient leaf on one layer of each kind,
-a ``ShardedPretrainer`` step, and every wrong model of the on-chip controls
-outside the float32 limits; (d) the chip's
-share of a sparse layer tied to the uncut layer; (e) the new parameters'
-partition rules on a virtual mesh, and a sharded sequence refused; (f) the
-older toys' lowered steps are held by the hash tests of
-``tests/test_sdar_parts.py`` (i), (n), ``tests/test_laguna_parts.py`` (e),
-``tests/test_kimi_vl.py`` (d) and ``tests/test_lfm2.py`` (f), unedited.  The
-scan alone is ``tests/test_kda_scan.py``'s.  The toy
+and every wrong model of the on-chip controls outside the float32 limits; (d)
+the chip's share of a sparse layer tied to the uncut layer.  The
+``ShardedPretrainer`` step and (e), the partition rules on a virtual mesh, are
+``tests/test_kimi_linear_mesh.py``'s; the toys' lowered steps are held by
+``tests/test_pinned_steps.py``.  The scan alone is ``tests/test_kda_scan.py``'s.  The toy
 (``perfbench/tests/toy/toy-kimi-linear.json``): 64 wide, five layers (KDA +
 dense; KDA, KDA, MLA, KDA sparse), 4 KDA heads of 16 at chunks of 8, 4 MLA
 heads whose scores are 16 + 8 wide over values 16 wide, 8 experts of 32 of
@@ -25,28 +22,21 @@ the same reference runs at published widths against the bf16 program
 """
 
 import dataclasses
-import functools
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import toys
 from perfbench.harness import reference
 from perfbench.harness.families import kimi_linear
-from perfbench.harness.tokens import ZipfStream
 from ray_tpu.models.kda import KDAMixer
 from ray_tpu.models.llama import LatentAttention
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
-from ray_tpu.models.pretrain import init_params, loss_fn
 from ray_tpu.ops.attention import mha_reference
 
-with open(os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "perfbench", "tests", "toy",
-        "toy-kimi-linear.json")) as f:
-    TOY = json.load(f)
+TOY = toys.toy("toy-kimi-linear")
 # the same layers on a chip that holds all eight experts
 WHOLE = dict(TOY, num_experts=8,
              deployment={"chips_sharing_a_layer": 1, "this_chip": 0})
@@ -58,18 +48,6 @@ SHORT = dict(TOY, num_hidden_layers=3, linear_attn_config=dict(
     TOY["linear_attn_config"], kda_layers=[1, 2], full_attn_layers=[3]))
 
 
-def _config(config=TOY, **changes):
-    return dataclasses.replace(kimi_linear.model_config(config, 1),
-                               dtype=jnp.float32, **changes)
-
-
-def _moved(params, seed=1):
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 1000))
-    return jax.tree_util.tree_map(
-        lambda a: a + 0.1 * jax.random.normal(next(keys), a.shape, a.dtype),
-        params)
-
-
 # ------------------------------------------------------ (b) the two mixers
 @pytest.mark.parametrize("seq", [24, 21])
 def test_b_the_mixer_equals_the_plain_form(seq):
@@ -78,10 +56,10 @@ def test_b_the_mixer_equals_the_plain_form(seq):
     per-head norm under the low-rank gate — against the reference's layer,
     output and every parameter's gradient; 21 positions are no whole
     chunks."""
-    cfg = _config()
+    cfg = toys.config(TOY)
     mixer = KDAMixer(cfg)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, seq, cfg.d_model))
-    params = _moved(mixer.init(jax.random.PRNGKey(1), x)["params"])
+    params = toys.moved(jax.jit(mixer.init)(jax.random.PRNGKey(1), x)["params"])
     assert set(params) == {
         "q_proj", "k_proj", "v_proj", "f_a", "f_b", "g_a", "g_b", "b_proj",
         "o_proj", "o_norm", "q_conv", "k_conv", "v_conv", "A_log", "dt_bias"}
@@ -113,11 +91,12 @@ def test_b_latent_attention_without_rotation(impl):
     ``mha_reference`` on q as projected and the key ``[kn ; kr]`` with the
     one un-rotated ``kr`` for all heads — and differs from the rotated
     layer's, which with the field unset is the call it was."""
-    cfg = _config(attention_impl=impl)
+    cfg = toys.config(TOY, attention_impl=impl)
     layer = LatentAttention(cfg, "full_attention")
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, cfg.d_model))
     positions = jnp.arange(40)
-    p = _moved(layer.init(jax.random.PRNGKey(1), x, positions)["params"])
+    p = toys.moved(jax.jit(layer.init)(jax.random.PRNGKey(1), x, positions)[
+        "params"])
     h, dn, dv, rank = (cfg.n_head, cfg.qk_nope_head_dim, cfg.v_head_dim,
                        cfg.kv_lora_rank)
     with jax.default_matmul_precision("highest"):
@@ -146,57 +125,10 @@ def test_b_latent_attention_without_rotation(impl):
 STACKS = {"part": TOY, "all": WHOLE, "short": SHORT}
 
 
-@functools.lru_cache(maxsize=None)
-def _weights(stack: str):
-    """(model, weights moved off their start): ``init_params`` runs op by op,
-    ten seconds a stack, so once for the tests that read it."""
-    model, params = init_params(_config(STACKS[stack]))
-    return model, _moved(params)
-
-
-def _program(stack="part", positions=43):
-    """The program in float32 (the scan's kernels interpreted, with their own
-    backward rule), so that what is left to differ from the reference is the
-    mathematics."""
-    model, params = _weights(stack)
-    rows = ZipfStream(model.config.vocab_size, seed=5).rows(2, positions)
-    return model, params, {k: jnp.asarray(v) for k, v in rows.items()}
-
-
-@functools.lru_cache(maxsize=None)
-def _both(stack: str, positions: int, backward: bool = False):
-    """((logits, loss, gradients) of the program, the same of the
-    reference), each compiled once for the tests that read it; without
-    ``backward`` the gradients are left out."""
-    config = STACKS[stack]
-    model, params, batch = _program(stack, positions)
-
-    def program(params, batch):
-        logits = model.apply({"params": params}, batch["input_ids"])
-        logits = logits[..., :model.config.vocab_size]
-        if not backward:
-            return logits, loss_fn(model, params, batch), None
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(model, p, batch))(params)
-        return logits, loss, grads
-
-    def plain(params, batch):
-        def loss_of(p):
-            logits = kimi_linear.logits(p, batch["input_ids"], config)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            return -jnp.take_along_axis(
-                logp, batch["targets"][..., None], axis=-1).mean(), logits
-
-        if not backward:
-            return (*loss_of(params)[::-1], None)
-        (loss, logits), grads = jax.value_and_grad(loss_of, has_aux=True)(
-            params)
-        return logits, loss, grads
-
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(program)(params, batch), jax.jit(plain)(params, batch)
-
-
+# The program runs in float32 (the scan's kernels interpreted, with their own
+# backward rule), so that what is left to differ from the reference is the
+# mathematics; each stack's weights, and each (stack, positions) of program
+# and reference, are made once (``tests/toys.py``).
 @pytest.mark.parametrize("stack,positions,backward", [
     ("part", 43, False), ("all", 48, False), ("short", 48, True)],
     ids=["part-43", "all", "short-backward"])
@@ -206,20 +138,23 @@ def test_c_program_equals_the_reference_in_float32(stack, positions,
     the experts held (43 positions, which are no whole chunks) and with all
     of them; and with the gradient norm, one layer of each kind (KDA + dense,
     KDA + sparse, MLA + sparse)."""
-    got, want = _both(stack, positions, backward)
-    assert got[0].shape == (2, positions, 512)
-    np.testing.assert_allclose(got[0], want[0], rtol=2e-4, atol=2e-4)
-    assert float(got[1]) == pytest.approx(float(want[1]), rel=1e-5)
+    got = toys.program(STACKS[stack], positions, backward=backward)
+    want = toys.reference(STACKS[stack], positions, backward=False,
+                          leaves=backward)
+    assert got.logits.shape == (2, positions, 512)
+    np.testing.assert_allclose(got.logits, want.logits, rtol=2e-4, atol=2e-4)
+    assert float(got.loss) == pytest.approx(float(want.loss), rel=1e-5)
     if backward:
-        assert float(reference.global_norm(got[2])) == pytest.approx(
-            float(reference.global_norm(want[2])), rel=1e-4)
+        assert float(got.gradnorm) == pytest.approx(float(want.gradnorm),
+                                                    rel=1e-4)
 
 
 def test_c_every_gradient_equals_the_references():
     """Leaf by leaf, not only the norm: the mixer's fifteen, latent
     attention's five, the dense feed-forward, the shared expert, the router,
     the held experts, the embedding and the head."""
-    (_, _, got), (_, _, want) = _both("short", 48, True)
+    got = toys.program(SHORT, 48).grads
+    want = toys.reference(SHORT, 48, backward=False, leaves=True).grads
     assert set(got["h_2"]["attn"]) == {"wq", "wdkv", "kv_norm", "wukv", "wo"}
     assert len(got["h_1"]["kda"]) == 15
     assert "kda" in got["h_0"] and "mlp" in got["h_0"] and "moe" in got["h_1"]
@@ -227,47 +162,6 @@ def test_c_every_gradient_equals_the_references():
                             jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-5,
                                    err_msg=jax.tree_util.keystr(path))
-
-
-@functools.lru_cache(maxsize=None)
-def _one_device():
-    """Twelve steps of ``ShardedPretrainer`` on one device, four rows of 64,
-    of the toy's first, second and fourth layer (``SHORT``): (the first
-    step's weights' reference loss, the steps' losses, the last step's
-    routing counters, the rows)."""
-    from ray_tpu.models.pretrain import ShardedPretrainer
-    from ray_tpu.parallel.mesh import MeshConfig
-
-    # (the schedule warms up over 100 steps: 0.1 is 0.011 by the twelfth)
-    trainer = ShardedPretrainer(_config(SHORT), MeshConfig(),
-                                devices=jax.devices()[:1], lr=0.1)
-    rows = ZipfStream(512, seed=5).rows(4, 64)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda p, ids: jnp.mean(-jnp.take_along_axis(
-            jax.nn.log_softmax(kimi_linear.logits(p, ids, SHORT), axis=-1),
-            jnp.asarray(rows["targets"])[..., None], axis=-1)))(
-                trainer.state[0], jnp.asarray(rows["input_ids"]))
-    losses = [float(trainer.step(rows)) for _ in range(12)]
-    return float(want), losses, dict(trainer.moe_stats), rows
-
-
-def test_c_the_trainers_step_takes_the_references_loss_down():
-    """Through ``ShardedPretrainer``, the path the benchmark times: the first
-    step's loss is the reference's on the same batch and weights, the steps
-    report the held experts' counters, and the loss falls."""
-    want, losses, stats, _ = _one_device()
-    assert losses[0] == pytest.approx(want, rel=1e-4)
-    assert losses[-1] < losses[0] - 0.5
-    assert set(stats) == {"load_balance", "z", "max_load", "moe_rows_held",
-                          "moe_buffer_rows"}
-    # four rows of 64 tokens take 3 of 8 experts each, 2 of them held here
-    assert 0 < float(stats["moe_rows_held"]) <= 4 * 64 * 2
-
-
-@functools.lru_cache(maxsize=None)
-def _program_logits():
-    _, params, batch = _program()
-    return params, batch, _both("part", 43)[0][0]
 
 
 @pytest.mark.parametrize("wrong", kimi_linear.WRONG
@@ -280,10 +174,8 @@ def test_c_the_tolerance_sees_each_wrong_model(wrong):
     rotation in MLA, ``kr`` a head's own, softmax scores, top-6 (of the toy's
     8, for its 3), the routed scale 1, no renormalisation — and so does the
     reference itself with float8 activations."""
-    params, batch, got = _program_logits()
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda p, b: kimi_linear._forward(
-            p, b["input_ids"], TOY, wrong)[0][..., :512])(params, batch)
+    got = toys.program(TOY, 43, backward=False).logits
+    want = toys.reference(TOY, 43, backward=False, wrong=wrong).logits
     assert float(jnp.max(jnp.abs(got - want))) > 100 * 2e-4
 
 
@@ -333,52 +225,3 @@ def test_d_the_four_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(total, routed, atol=5e-5)
     np.testing.assert_allclose(residual + shared + total,
                                residual + shared + routed, atol=5e-5)
-
-
-# ------------------------------------------------- (e) on a virtual mesh
-@pytest.mark.parametrize("mesh", [{"dp": 1, "fsdp": 4}, {"dp": 2, "tp": 2}])
-def test_e_a_sharded_mesh_gives_the_single_device_loss(mesh):
-    """The mixer's parameters shard by the Llama rules — the projections,
-    the convolutions, ``A_log`` and ``dt_bias`` by head, the low-rank maps'
-    down side by no head — and the step under them (the scan's kernels inside
-    ``shard_map``, a ``tp`` group's heads each on its own device) gives one
-    device's losses."""
-    from jax.sharding import PartitionSpec as P
-
-    from ray_tpu.models.pretrain import ShardedPretrainer
-    from ray_tpu.parallel.mesh import MeshConfig
-    from ray_tpu.parallel.sharding import (llama_partition_rules,
-                                           match_partition_rules)
-
-    cfg = _config(SHORT)
-    kda = match_partition_rules(llama_partition_rules(), jax.eval_shape(
-        lambda: init_params(cfg)[1]))["h_0"]["kda"]
-    assert kda["q_proj"]["kernel"] == kda["b_proj"]["kernel"] \
-        == P("fsdp", "tp")
-    assert kda["f_a"]["kernel"] == kda["g_a"]["kernel"] == P("fsdp", None)
-    assert kda["f_b"]["kernel"] == kda["g_b"]["kernel"] == P(None, "tp")
-    assert kda["g_b"]["bias"] == kda["A_log"] == kda["dt_bias"] == P("tp")
-    assert kda["q_conv"] == kda["v_conv"] == P(None, "tp")
-    assert kda["o_proj"]["kernel"] == P("tp", "fsdp")
-    assert kda["o_norm"]["scale"] == P()
-
-    _, one, _, rows = _one_device()
-    many = ShardedPretrainer(cfg, MeshConfig(**mesh),
-                             devices=jax.devices()[:4], lr=0.1)
-    for want in one[:2]:    # the second step sees the first's gradients
-        assert float(many.step(rows)) == pytest.approx(want, rel=1e-5)
-
-
-def test_e_a_sharded_sequence_is_refused():
-    """A ``kda`` layer carries its state across every position: under an
-    ``sp`` axis it raises, in the words ``ops.attention`` refuses with."""
-    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
-
-    from ray_tpu.models.llama import LlamaLMModel
-
-    model = LlamaLMModel(_config(SHORT))
-    mesh = build_mesh(MeshConfig(dp=1, sp=2), devices=jax.devices()[:2])
-    with jax.set_mesh(mesh), pytest.raises(
-            NotImplementedError, match="sharded on 'sp' has no 'kda' layer"):
-        jax.eval_shape(model.init, jax.random.PRNGKey(0),
-                       jnp.zeros((1, 16), jnp.int32))

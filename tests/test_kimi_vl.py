@@ -7,9 +7,10 @@ the shared expert and a part of the experts held) against the plain reference
 of ``perfbench/harness/families/kimi_vl.py`` — a dense causal mask, the shared
 rotary key given to the heads by indexing, every held expert on every token —
 and every wrong model of the on-chip controls outside the float32 limits; (c)
-the chip's share of a sparse layer tied to the uncut layer; (d) the step of
-the one toy that PR 35's hashes do not pin, as the parent lowered it; (e) the
-new parameters' partition rules on a virtual mesh.  The toy
+the chip's share of a sparse layer tied to the uncut layer.  The
+``ShardedPretrainer`` step and (e), the partition rules on a virtual mesh, are
+``tests/test_kimi_vl_mesh.py``'s; the toys' lowered steps are held by
+``tests/test_pinned_steps.py``.  The toy
 (``perfbench/tests/toy/toy-kimi-vl.json``): 64 wide, 4 heads whose scores are
 16 + 8 wide over values 16 wide, a latent of 32, a dense layer and two sparse
 ones, 16 experts of 32 of which 2 are held (chip 1 of 8), top-3, a shared
@@ -17,35 +18,18 @@ expert of 64.  On the chip the same reference runs at published widths
 against the bf16 program (``perfbench/harness/agreement.py``).
 """
 
-import dataclasses
-import functools
-import hashlib
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench.harness import families, reference
+import toys
 from perfbench.harness.families import kimi_vl
-from perfbench.harness.tokens import ZipfStream
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
-from ray_tpu.models.pretrain import init_params, loss_fn
 from ray_tpu.ops.attention import (flash_attention, mha_reference,
                                    ring_attention)
 
-_TOYS = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "perfbench", "tests", "toy")
-
-
-def _toy(name="toy-kimi-vl"):
-    with open(os.path.join(_TOYS, name + ".json")) as f:
-        return json.load(f)
-
-
-TOY = _toy()
+TOY = toys.toy("toy-kimi-vl")
 # the same layers on a chip that holds all sixteen experts
 WHOLE = dict(TOY, n_routed_experts=16,
              deployment={"chips_sharing_a_layer": 1, "this_chip": 0})
@@ -104,46 +88,19 @@ def test_a_two_widths_are_refused_where_no_kernel_takes_them():
         ring_attention(q, k, v)
     with pytest.raises(ValueError, match="24 wide against keys 16 \\+ 4"):
         flash_attention(q, k[..., :16], v, k_shared=k[:, :1, :, :4])
-    cfg = dataclasses.replace(kimi_vl.model_config(TOY, 1),
-                              attention_impl="ring")
-    model, params = init_params(cfg)    # (initialised without the ring)
+    # (the weights are the stack's: initialised without the ring)
+    model, params = toys.weights(TOY, attention_impl="ring")
     with pytest.raises(NotImplementedError, match="latent attention"):
-        model.apply({"params": params}, jnp.zeros((1, 16), jnp.int32))
+        # (refused while it is traced: no layer before it runs)
+        jax.eval_shape(lambda: model.apply(
+            {"params": params}, jnp.zeros((1, 16), jnp.int32)))
 
 
 # ------------------------------------------ (b) the stack and its reference
-def _program(config=TOY, impl="reference", positions=64):
-    """The program in float32, so that what is left to differ from the
-    reference is the mathematics; ``impl`` "flash" is the Pallas kernels
-    interpreted, with their own backward rule."""
-    cfg = dataclasses.replace(kimi_vl.model_config(config, 1),
-                              dtype=jnp.float32, attention_impl=impl)
-    model, params = init_params(cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(1), 1000))
-    params = jax.tree_util.tree_map(
-        lambda a: a + 0.1 * jax.random.normal(next(keys), a.shape, a.dtype),
-        params)
-    rows = ZipfStream(cfg.vocab_size, seed=5).rows(2, positions)
-    return model, params, {k: jnp.asarray(v) for k, v in rows.items()}
-
-
-def _both(model, params, batch, config=TOY):
-    """(logits, loss, gradient norm) of program and reference."""
-    def program(params, batch):
-        logits = model.apply({"params": params}, batch["input_ids"])
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(model, p, batch))(params)
-        return (logits[..., :model.config.vocab_size], loss,
-                reference.global_norm(grads))
-
-    def plain(params, batch):
-        return kimi_vl.logits_loss_gradnorm(
-            params, batch["input_ids"], batch["targets"], config)
-
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(program)(params, batch), jax.jit(plain)(params, batch)
-
-
+# The program runs in float32, so that what is left to differ from the
+# reference is the mathematics; ``attention_impl`` "flash" is the Pallas
+# kernels interpreted, with their own backward rule.  Every leaf is moved off
+# its initial value.
 @pytest.mark.parametrize("config,impl,positions", [
     (TOY, "reference", 64), (TOY, "flash", 64), (TOY, "flash", 52),
     (WHOLE, "reference", 64), (WHOLE, "flash", 64)],
@@ -152,67 +109,26 @@ def _both(model, params, batch, config=TOY):
 def test_b_program_equals_the_reference_in_float32(config, impl, positions):
     """Logits, loss and the gradient norm to float32 rounding, a part of the
     experts held and all of them; 52 positions are not whole tiles."""
-    got, want = _both(*_program(config, impl, positions), config=config)
-    assert got[0].shape == (2, positions, 512)
-    np.testing.assert_allclose(got[0], want[0], rtol=2e-4, atol=2e-4)
-    assert float(got[1]) == pytest.approx(float(want[1]), rel=1e-5)
-    assert float(got[2]) == pytest.approx(float(want[2]), rel=1e-4)
-    assert float(want[3]) > 0
+    got = toys.program(config, positions, attention_impl=impl)
+    want = toys.reference(config, positions, attention_impl=impl)
+    assert got.logits.shape == (2, positions, 512)
+    np.testing.assert_allclose(got.logits, want.logits, rtol=2e-4, atol=2e-4)
+    assert float(got.loss) == pytest.approx(float(want.loss), rel=1e-5)
+    assert float(got.gradnorm) == pytest.approx(float(want.gradnorm),
+                                                rel=1e-4)
+    assert float(want.held) > 0
 
 
 def test_b_every_gradient_equals_the_references():
     """Leaf by leaf, not only the norm: the four projections of the latent
     attention, its norm, the shared expert, the router, the held experts."""
-    model, params, batch = _program(impl="flash")
-
-    def loss(p):
-        logp = jax.nn.log_softmax(
-            kimi_vl.logits(p, batch["input_ids"], TOY), axis=-1)
-        return -jnp.take_along_axis(
-            logp, batch["targets"][..., None], axis=-1).mean()
-
-    with jax.default_matmul_precision("highest"):
-        got = jax.jit(jax.grad(lambda p: loss_fn(model, p, batch)))(params)
-        want = jax.jit(jax.grad(loss))(params)
+    got = toys.program(TOY, 64, attention_impl="flash").grads
+    want = toys.reference(TOY, 64, leaves=True, attention_impl="flash").grads
     assert set(got["h_1"]["attn"]) == {"wq", "wdkv", "kv_norm", "wukv", "wo"}
     for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0],
                             jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(g, w, rtol=2e-3, atol=2e-5,
                                    err_msg=jax.tree_util.keystr(path))
-
-
-def test_b_the_trainers_step_takes_the_references_loss_down():
-    """Through ``ShardedPretrainer``, the path the benchmark times: the first
-    step's loss is the reference's on the same batch and weights, the steps
-    report the held experts' counters, and the loss falls."""
-    from ray_tpu.models.pretrain import ShardedPretrainer
-    from ray_tpu.parallel.mesh import MeshConfig
-
-    cfg = dataclasses.replace(kimi_vl.model_config(TOY, 1), dtype=jnp.float32)
-    # (the schedule warms up over 100 steps: 0.1 is 0.011 by the twelfth)
-    trainer = ShardedPretrainer(cfg, MeshConfig(), devices=jax.devices()[:1],
-                                lr=0.1)
-    rows = ZipfStream(cfg.vocab_size, seed=5).rows(2, 64)
-    with jax.default_matmul_precision("highest"):
-        want = kimi_vl.logits_loss_gradnorm(
-            trainer.state[0], jnp.asarray(rows["input_ids"]),
-            jnp.asarray(rows["targets"]), TOY)[1]
-    losses = [float(trainer.step(rows)) for _ in range(12)]
-    assert losses[0] == pytest.approx(float(want), rel=1e-4)
-    assert losses[-1] < losses[0] - 0.5
-    stats = trainer.moe_stats
-    assert set(stats) == {"load_balance", "z", "max_load", "moe_rows_held",
-                          "moe_buffer_rows"}
-    # two rows of 64 tokens take 3 of 16 experts each, 2 of them held here
-    assert 0 < float(stats["moe_rows_held"]) <= 2 * 64 * 2
-
-
-@functools.lru_cache(maxsize=None)
-def _program_logits():
-    model, params, batch = _program()
-    with jax.default_matmul_precision("highest"):
-        return params, batch, jax.jit(lambda p, b: model.apply(
-            {"params": p}, b["input_ids"]))(params, batch)
 
 
 @pytest.mark.parametrize("wrong", kimi_vl.WRONG + (kimi_vl.PRECISION_BELOW,))
@@ -223,10 +139,9 @@ def test_b_the_tolerance_sees_each_wrong_model(wrong):
     taken from the key half of the up-projection, one shared expert for two,
     the routed scale 1, top-(k-1), softmax scores — and so does the reference
     itself with float8 activations."""
-    params, batch, got = _program_logits()
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda p, b: kimi_vl._forward(
-            p, b["input_ids"], TOY, wrong)[0])(params, batch)
+    got = toys.program(TOY, 64, attention_impl="reference").logits
+    want = toys.reference(TOY, 64, backward=False, wrong=wrong,
+                          attention_impl="reference").logits
     assert float(jnp.max(jnp.abs(got - want))) > 100 * 2e-4
 
 
@@ -276,67 +191,3 @@ def test_c_the_eight_shares_add_up_to_the_uncut_layer():
     # ... and counted once: the uncut layer's output
     np.testing.assert_allclose(residual + shared + total,
                                residual + shared + routed, atol=5e-5)
-
-
-# ------------------------------------- (d) the other models' steps, untouched
-def test_d_lagunas_step_is_the_parents(flash_names_off):
-    """With one width and no shared key part the traced calls are the
-    parent's: ``tests/test_laguna_parts.py`` (e) and ``tests/test_sdar_parts.py``
-    (n) pin the dense, routed, hybrid and block-diffusion toys, unedited; this
-    is the window kernels' toy, which neither pins: sha256 of its lowered
-    train step on the parent commit (PR 36), kernel bodies included (and,
-    since PR 38, ``flash_names_off``; since PR 39 the hash is that PR's: the
-    rotation of q and k is ``apply_rope``'s one pass, the kernels' calls as
-    they were; since PR 42 that PR's: the kernels' grid is (batch, heads,
-    tiles, tiles) and their output (B, S, H * D), the gate widened along
-    the lanes; since PR 49 that PR's: the dense layer's and the shared
-    expert's ``silu * up`` go through ``models/moe.py::silu_mul``; since
-    PR 52 that PR's: k and v reach the kernels with their own heads; since
-    PR 55 that PR's: the toy's grouped heads are narrower than a lane tile,
-    and the backward kernel indexes such a group's dQ in the accumulator
-    itself, interpreted as compiled)."""
-    from ray_tpu.models.pretrain import make_optimizer, sharded_train_step
-    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
-
-    config = _toy("toy-laguna")
-    cfg = families.of(config).model_config(config, 1)
-    mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
-    s = sharded_train_step(cfg, mesh, make_optimizer())
-    batch = {k: jax.ShapeDtypeStruct((2, 64), jnp.int32, sharding=sh)
-             for k, sh in s.batch_sharding.items()}
-    with jax.set_mesh(mesh):
-        text = s.step.trace(s.state, batch).lower().as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == \
-        "4d7f074a49c3ae1f68e4b5e66cd1af93e583972f0ae498461edcd62e2babffc0"
-
-
-# ------------------------------------------------- (e) on a virtual mesh
-@pytest.mark.parametrize("mesh", [{"dp": 1, "fsdp": 4}, {"dp": 2, "tp": 2}])
-def test_e_a_sharded_mesh_gives_the_single_device_loss(mesh):
-    """``attn/wdkv`` and ``attn/wukv`` shard by the Llama rules — the latent
-    and the shared rotary key belong to no head, the up-projection's columns
-    to the heads — and the step under them (the kernels inside ``shard_map``,
-    the shared key whole on every device of a ``tp`` group and its gradient
-    summed over the group) gives one device's losses."""
-    from jax.sharding import PartitionSpec as P
-
-    from ray_tpu.models.pretrain import ShardedPretrainer
-    from ray_tpu.parallel.mesh import MeshConfig
-    from ray_tpu.parallel.sharding import (llama_partition_rules,
-                                           match_partition_rules)
-
-    cfg = dataclasses.replace(kimi_vl.model_config(TOY, 1), dtype=jnp.float32)
-    attn = match_partition_rules(llama_partition_rules(),
-                                 init_params(cfg)[1])["h_1"]["attn"]
-    assert attn["wq"]["kernel"] == attn["wukv"]["kernel"] == P("fsdp", "tp")
-    assert attn["wdkv"]["kernel"] == P("fsdp", None)
-    assert attn["wo"]["kernel"] == P("tp", "fsdp")
-    assert attn["kv_norm"]["scale"] == P()
-
-    rows = ZipfStream(cfg.vocab_size, seed=5).rows(4, 64)
-    one = ShardedPretrainer(cfg, MeshConfig(), devices=jax.devices()[:1])
-    many = ShardedPretrainer(cfg, MeshConfig(**mesh),
-                             devices=jax.devices()[:4])
-    for _ in range(2):      # the second step sees the first's gradients
-        assert float(many.step(rows)) == pytest.approx(float(one.step(rows)),
-                                                       rel=1e-5)

@@ -2,37 +2,21 @@
 CPU (the whole model against its reference: ``tests/test_laguna.py``): (b) the
 flash kernels under a window, interpreted, against ``mha_reference``; (c) the
 chip's share of a sparse layer tied to the uncut layer; (e) every new field of
-``LlamaConfig`` at its default leaves the other models' steps as they were;
-(f) the partition rules of the gate and the shared expert on a virtual mesh.
+``LlamaConfig`` at its default leaves the other models' steps as they were:
+``tests/test_pinned_steps.py``; (f) the partition rules of the gate and the shared expert on a virtual mesh.
 """
-
-import dataclasses
-import hashlib
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench.harness import families
+import toys
 from perfbench.harness.families import laguna
-from perfbench.harness.tokens import ZipfStream
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU
-from ray_tpu.models.pretrain import init_params
 from ray_tpu.ops.attention import flash_attention, mha_reference
 
-_TOYS = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "perfbench", "tests", "toy")
-
-
-def _toy(name="toy-laguna"):
-    with open(os.path.join(_TOYS, name + ".json")) as f:
-        return json.load(f)
-
-
-TOY = _toy()
+TOY = toys.toy("toy-laguna")
 # the same layers on a chip that holds all sixteen experts
 WHOLE = dict(TOY, num_experts=16, deployment={"chips_sharing_a_layer": 1,
                                               "this_chip": 0})
@@ -102,12 +86,14 @@ def test_b_the_grid_walks_the_band_alone():
 
 
 def test_b_a_window_under_ring_attention_is_refused():
-    cfg = dataclasses.replace(
-        laguna.model_config(dict(TOY, layer_types=["sliding_attention"] * 4),
-                            1), attention_impl="ring")
-    model, params = init_params(cfg)    # (initialised without the ring)
+    # (the weights are initialised without the ring)
+    model, params = toys.weights(
+        dict(TOY, layer_types=["sliding_attention"] * 4), dtype=None, by=0,
+        attention_impl="ring")
     with pytest.raises(NotImplementedError, match="window"):
-        model.apply({"params": params}, jnp.zeros((1, 16), jnp.int32))
+        # (refused while it is traced: no layer before it runs)
+        jax.eval_shape(lambda: model.apply(
+            {"params": params}, jnp.zeros((1, 16), jnp.int32)))
 
 
 # ------------------------------------------- (c) the share tied to the model
@@ -152,55 +138,6 @@ def test_c_the_shares_add_up_to_the_uncut_layer():
     np.testing.assert_allclose(total, routed, atol=5e-5)
 
 
-# ------------------------------------- (e) the other models' steps, untouched
-# sha256 of the lowered text of each toy's train step on the parent commit
-# (PR 34), where ``LlamaConfig`` had none of this PR's fields: at their
-# defaults the fields change nothing of the dense, the routed, the hybrid or
-# the block-diffusion program, the interpreted kernels' bodies included.  A
-# later PR that changes one of these programs on purpose prints the new text's
-# hash from the assertion below and pins that.  (``toy-llama``, ``toy-olmoe``
-# and ``toy-sdar`` are PR 39's: the rotation of q and k is one pass with a
-# matmul by a signed permutation in it, SDAR's per-head norm inside it;
-# ``toy-granite``, which rotates nothing, was PR 34's until PR 42.  All four
-# are PR 42's: the flash kernels' grid is (batch, heads, tiles, tiles), the
-# output leaves them as (B, S, H * D) and the models no longer transpose it.
-# ``toy-granite`` is PR 43's: its scan is the interpreted body of
-# ``ops/ssd.py``'s two kernels; the three others are PR 42's still.
-# ``toy-llama`` and ``toy-granite``, the two with a dense SwiGLU, are PR 49's:
-# ``silu(gate) * up`` goes through ``models/moe.py::silu_mul``, whose backward
-# writes ``dgate`` and ``dup`` behind an optimization barrier; ``toy-olmoe`` and
-# ``toy-sdar``, which have none, did not move.  ``toy-llama``, ``toy-granite``
-# and ``toy-sdar``, the three with grouped-query attention, are PR 52's: k and
-# v go to the kernels with their own heads, read through ``h // rep``, and the
-# models repeat nothing; ``toy-olmoe``, a key/value head a query head, did not
-# move.  The same three are PR 55's: their grouped heads are narrower than a
-# lane tile, and the interpreted backward kernel indexes such a group's dQ in
-# the accumulator itself (``_Head``), as the compiled one has to; no older
-# cell's compiled step moves, none having grouped heads under 128 wide.)
-_PARENT_STEPS = {
-    "toy-llama": "3f093a50754c69664f39a2c72264ae3a12b3e0d67a722d3f326ae8d1ef0fb00e",
-    "toy-olmoe": "41054e8f510af2a7d7be4f320326eb4237e4c1efc006d16bd81fbd3d9bea858f",
-    "toy-granite": "7a646a83d84178cf45adcbe76b26d3fedef41d6e0ef427a125c34ab1cbddb34d",
-    "toy-sdar": "ff8e09d3e6a65ea9935059aba9f4c46ea2127d572f13434078f2b64b86576cb4",
-}
-
-
-@pytest.mark.parametrize("toy", sorted(_PARENT_STEPS))
-def test_e_defaults_leave_the_other_steps_as_they_were(toy, flash_names_off):
-    from ray_tpu.models.pretrain import make_optimizer, sharded_train_step
-    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
-
-    config = _toy(toy)
-    cfg = families.of(config).model_config(config, 1)
-    mesh = build_mesh(MeshConfig(), devices=jax.devices()[:1])
-    s = sharded_train_step(cfg, mesh, make_optimizer())
-    batch = {k: jax.ShapeDtypeStruct((2, 64), jnp.int32, sharding=sh)
-             for k, sh in s.batch_sharding.items()}
-    with jax.set_mesh(mesh):
-        text = s.step.trace(s.state, batch).lower().as_text()
-    assert hashlib.sha256(text.encode()).hexdigest() == _PARENT_STEPS[toy]
-
-
 # ------------------------------------------------- (f) on a virtual mesh
 @pytest.mark.parametrize("mesh", [{"dp": 1, "fsdp": 4}, {"dp": 2, "tp": 2}])
 def test_f_a_sharded_mesh_gives_the_single_device_loss(mesh):
@@ -215,9 +152,8 @@ def test_f_a_sharded_mesh_gives_the_single_device_loss(mesh):
     from ray_tpu.parallel.sharding import (llama_partition_rules,
                                            match_partition_rules)
 
-    cfg = dataclasses.replace(laguna.model_config(TOY, 1), dtype=jnp.float32)
     specs = match_partition_rules(llama_partition_rules(),
-                                  init_params(cfg)[1])
+                                  toys.weights(TOY)[1])
     assert specs["h_1"]["attn"]["wg"]["kernel"] == P("fsdp", "tp")
     shared = specs["h_1"]["moe"]["shared"]
     assert shared["gate_proj"]["kernel"] == shared["up_proj"]["kernel"] \
@@ -225,10 +161,8 @@ def test_f_a_sharded_mesh_gives_the_single_device_loss(mesh):
     assert shared["down_proj"]["kernel"] == P("tp", "fsdp")
     assert specs["h_1"]["moe"]["gate_proj"] == P("ep", "fsdp", "tp")
 
-    rows = ZipfStream(cfg.vocab_size, seed=5).rows(4, 64)
-    one = ShardedPretrainer(cfg, MeshConfig(), devices=jax.devices()[:1])
-    many = ShardedPretrainer(cfg, MeshConfig(**mesh),
+    one = toys.one_device(TOY, 4, 64, 2, want=False)    # for both meshes
+    many = ShardedPretrainer(toys.config(TOY), MeshConfig(**mesh),
                              devices=jax.devices()[:4])
-    for _ in range(2 if "fsdp" in mesh else 1):
-        assert float(many.step(rows)) == pytest.approx(float(one.step(rows)),
-                                                       rel=1e-5)
+    for want in one.losses[:2 if "fsdp" in mesh else 1]:
+        assert float(many.step(one.rows)) == pytest.approx(want, rel=1e-5)

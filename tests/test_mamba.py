@@ -4,8 +4,9 @@
 hybrid model (``mamba`` and ``attention`` layers mixed, NoPE, the three
 multipliers, the tied head) against the plain reference of
 ``perfbench/harness/families/granite_hybrid.py``, with seeded weights moved
-off their initial values.  On the chip the same reference runs at published
-widths against the bf16 program (``perfbench/harness/agreement.py``).
+off their initial values; the steps under a mesh and the step that learns are
+``tests/test_mamba_mesh.py``'s.  On the chip the same reference runs at
+published widths against the bf16 program (``perfbench/harness/agreement.py``).
 
 Tolerances.  Float32 against float32 at matmul precision 'highest' differ by
 summation order alone: 2e-4 on logits of size 1, 1e-5 on the loss, 1e-4 on
@@ -15,30 +16,23 @@ gradients to 1e-3 of theirs.
 """
 
 import dataclasses
-import json
-import os
+import functools
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench.harness import reference
+import toys
 from perfbench.harness.families import granite_hybrid
-from perfbench.harness.tokens import ZipfStream
 from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models.mamba import causal_conv
-from ray_tpu.models.pretrain import (ShardedPretrainer, init_params, loss_fn,
-                                     make_optimizer, train_step)
+from ray_tpu.models.pretrain import init_params, make_optimizer, train_step
 from ray_tpu.ops import ssd
-from ray_tpu.parallel.mesh import MeshConfig
 
-with open(os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "perfbench", "tests", "toy",
-        "toy-granite.json")) as f:
-    # 64 wide; 8 Mamba heads of 16 with a state of 16, chunks of 16; 4 query
-    # heads over 2 key/value heads; layers mamba, mamba, attention
-    TOY = json.load(f)
+# 64 wide; 8 Mamba heads of 16 with a state of 16, chunks of 16; 4 query
+# heads over 2 key/value heads; layers mamba, mamba, attention
+TOY = toys.toy("toy-granite")
 
 
 def _recurrence(x, dt, rate, b, c):
@@ -76,15 +70,16 @@ def test_a_chunked_scan_equals_the_recurrence(seq, groups):
     padding is inside ``ssd_scan``."""
     args = _scan_inputs(seq, groups)
     with jax.default_matmul_precision("highest"):
-        got = ssd.ssd_scan(*args, chunk=8)
-        want = _recurrence(*args)
+        # (jitted: the interpreter would run the kernels' bodies op by op)
+        got = jax.jit(lambda *a: ssd.ssd_scan(*a, chunk=8))(*args)
+        want = jax.jit(_recurrence)(*args)
         assert got.shape == want.shape == (2, seq, 4, 8)
         np.testing.assert_allclose(got, want, rtol=0,
                                    atol=1e-4 * float(jnp.max(jnp.abs(want))))
 
         def grads(fn):
-            return jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
-                            argnums=(0, 1, 2, 3, 4))(*args)
+            return jax.jit(jax.grad(lambda *a: jnp.sum(jnp.sin(fn(*a))),
+                                    argnums=(0, 1, 2, 3, 4)))(*args)
 
         for name, g, w in zip("x dt A B C".split(),
                               grads(lambda *a: ssd.ssd_scan(*a, chunk=8)),
@@ -122,17 +117,17 @@ def test_a2_the_kernels_at_the_published_tile_and_over_many_chunks(case):
         args = (x, dt, rate * 8.0, b, c)
 
     def grads(fn):
-        return jax.grad(
+        return jax.jit(jax.grad(
             lambda *a: jnp.sum(jnp.sin(fn(*a).astype(jnp.float32))),
-            argnums=(0, 1, 2, 3, 4))(*args)
+            argnums=(0, 1, 2, 3, 4)))(*args)
 
     with jax.default_matmul_precision("highest"):
-        got = ssd.ssd_scan(*args, chunk=chunk)
+        got = jax.jit(lambda *a: ssd.ssd_scan(*a, chunk=chunk))(*args)
         assert got.dtype == dtype and got.shape == args[0].shape
         exact = lambda x, dt, rate, b, c: _recurrence(     # noqa: E731
             x.astype(jnp.float32), dt, rate, b.astype(jnp.float32),
             c.astype(jnp.float32))
-        want = exact(*args)
+        want = jax.jit(exact)(*args)
         np.testing.assert_allclose(
             got.astype(jnp.float32), want, rtol=0,
             atol=value_tol * float(jnp.max(jnp.abs(want))))
@@ -190,37 +185,10 @@ def test_c_the_convolution_equals_a_loop_and_reads_no_later_position():
         np.asarray(causal_conv(later, kernel, bias))[:, :7], got[:, :7])
 
 
-def _program(config=TOY, positions=41, chips=1):
-    """The program in float32 with XLA attention, so that what is left to
-    differ from the reference is the mathematics.  41 positions are two
-    whole chunks of 16 and a padded one."""
-    cfg = dataclasses.replace(
-        granite_hybrid.model_config(config, chips), dtype=jnp.float32,
-        attention_impl="reference")
-    model, params = init_params(cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(1), 1000))
-    params = jax.tree_util.tree_map(
-        lambda a: a + 0.1 * jax.random.normal(next(keys), a.shape, a.dtype),
-        params)
-    rows = ZipfStream(TOY["vocab_size"], seed=5).rows(2, positions)
-    return model, params, {k: jnp.asarray(v) for k, v in rows.items()}
-
-
-def _both(model, params, batch, config=TOY, **wrong):
-    """(logits, loss, gradient norm) of the program and of the reference;
-    ``wrong``: the reference's keywords that make a wrong model of it."""
-    with jax.default_matmul_precision("highest"):
-        logits = model.apply({"params": params}, batch["input_ids"])
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(model, p, batch))(params)
-    got = (logits[..., :TOY["vocab_size"]], loss, reference.global_norm(grads))
-    forward = granite_hybrid.logits
-    granite_hybrid.logits = lambda p, i, c: forward(p, i, c, **wrong)
-    try:
-        return got, reference.logits_loss_gradnorm(
-            params, batch["input_ids"], batch["targets"], config)
-    finally:
-        granite_hybrid.logits = forward
+# The program in float32 with XLA attention, so that what is left to differ
+# from the reference is the mathematics.  41 positions are two whole chunks of
+# 16 and a padded one.
+_F32 = dict(attention_impl="reference")
 
 
 @pytest.mark.parametrize("positions,chips", [(41, 1), (32, 0)])
@@ -228,10 +196,12 @@ def test_d_program_equals_the_reference_in_float32(positions, chips):
     """The three layers of the one-chip cut (mamba, mamba, attention) at a
     length the scan has to pad, and (``chips`` 0: no cut) the whole toy list,
     six layers of both kinds, at two whole chunks."""
-    got, want = _both(*_program(positions=positions, chips=chips))
-    np.testing.assert_allclose(got[0], want[0], rtol=2e-4, atol=2e-4)
-    assert float(got[1]) == pytest.approx(float(want[1]), rel=1e-5)
-    assert float(got[2]) == pytest.approx(float(want[2]), rel=1e-4)
+    got = toys.program("toy-granite", positions, chips=chips, **_F32)
+    want = toys.reference("toy-granite", positions, chips=chips, **_F32)
+    np.testing.assert_allclose(got.logits, want.logits, rtol=2e-4, atol=2e-4)
+    assert float(got.loss) == pytest.approx(float(want.loss), rel=1e-5)
+    assert float(got.gradnorm) == pytest.approx(float(want.gradnorm),
+                                                rel=1e-4)
 
 
 @pytest.mark.parametrize("wrong,margin", [
@@ -249,14 +219,13 @@ def test_e_the_tolerance_sees_a_wrong_model(wrong, margin):
     each at least twenty times the tolerance of (d) away — but for the
     running sums in bf16, which over the toy's chunks of 16 positions lose
     little (five times the tolerance here); what holds them is (b), at the
-    published chunk of 256."""
-    keywords = {k: (jnp.dtype(v) if k == "decay_dtype" else v)
-                for k, v in wrong.items()
-                if k in ("gate_after_norm", "decay_dtype")}
-    config = dict(TOY, **{k: v for k, v in wrong.items()
-                          if k not in keywords})
-    got, want = _both(*_program(), config=config, **keywords)
-    assert float(np.max(np.abs(got[0] - want[0]))) > margin * 2e-4
+    published chunk of 256.  The program's logits are (d)'s, made once."""
+    wrong = {k: (jnp.dtype(v) if k == "decay_dtype" else v)
+             for k, v in wrong.items()}
+    got = toys.program("toy-granite", 41, **_F32)
+    want = toys.reference("toy-granite", 41, backward=False, wrong=wrong,
+                          **_F32)
+    assert float(np.max(np.abs(got.logits - want.logits))) > margin * 2e-4
 
 
 def test_f_the_tied_table_gets_both_gradients():
@@ -266,7 +235,8 @@ def test_f_the_tied_table_gets_both_gradients():
     the two parts, both non-zero, and their sum is the program's gradient."""
     from ray_tpu.models.gpt2 import lm_loss
 
-    model, params, batch = _program()
+    model, params = toys.weights("toy-granite", **_F32)
+    batch = toys.rows("toy-granite", 2, 41)
     assert "lm_head" not in params
     table = params["wte"]["embedding"]
 
@@ -279,10 +249,10 @@ def test_f_the_tied_table_gets_both_gradients():
         return lm_loss(jnp.einsum("bsd,vd->bsv", x / TOY["logits_scaling"],
                                   head_table), batch["targets"])
 
+    whole = toys.program("toy-granite", 41, **_F32).grads["wte"]["embedding"]
     with jax.default_matmul_precision("highest"):
-        whole = jax.grad(lambda p: loss_fn(model, p, batch))(params)[
-            "wte"]["embedding"]
-        by_gather, by_head = jax.grad(loss_of, argnums=(0, 1))(table, table)
+        by_gather, by_head = jax.jit(jax.grad(loss_of, argnums=(0, 1)))(
+            table, table)
     assert float(jnp.max(jnp.abs(by_gather))) > 1e-4
     assert float(jnp.max(jnp.abs(by_head))) > 1e-4
     np.testing.assert_allclose(whole, by_gather + by_head, rtol=1e-4,
@@ -294,7 +264,7 @@ def test_g_layer_types_drive_the_kinds():
     ``attn`` in each block, no ``lm_head`` (the head is the table), and a
     list that does not name every layer, or names an unknown kind, is
     refused."""
-    _, params, _ = _program(chips=0)
+    _, params = toys.weights("toy-granite", 0, **_F32)
     kinds = TOY["layer_types"]
     assert len(kinds) == 6 and set(kinds) == {"mamba", "attention"}
     for i, kind in enumerate(kinds):
@@ -308,16 +278,25 @@ def test_g_layer_types_drive_the_kinds():
     cfg = granite_hybrid.model_config(TOY, 1)
     for wrong in (("mamba",), ("mamba", "attention", "linear")):
         with pytest.raises(ValueError, match="layer"):
-            init_params(dataclasses.replace(cfg, layer_types=wrong))
+            # (refused while it is traced: nothing is initialised)
+            jax.eval_shape(lambda: init_params(dataclasses.replace(
+                cfg, layer_types=wrong))[1])
 
 
-def _step_text(model, params):
-    """The train step lowered, with every operation's name path in it."""
+def _lowered_step(model, params):
+    """The train step lowered, with every operation's name path in it;
+    ``params`` may be shapes alone."""
     tx = make_optimizer()
-    batch = {k: jnp.zeros((2, 16), jnp.int32)
+    batch = {k: jax.ShapeDtypeStruct((2, 16), jnp.int32)
              for k in ("input_ids", "targets")}
     return jax.jit(lambda s, b: train_step(model, tx, s, b)).lower(
-        (params, tx.init(params)), batch).as_text(debug_info=True)
+        (params, jax.eval_shape(tx.init, params)), batch).as_text(
+            debug_info=True)
+
+
+@functools.lru_cache(maxsize=None)
+def _hybrid_text():
+    return _lowered_step(*toys.weights("toy-granite", **_F32))
 
 
 @pytest.mark.parametrize("family", ["llama", "olmoe", "gpt2"])
@@ -327,73 +306,31 @@ def test_h_a_model_without_mamba_is_the_program_it_was(family):
     parameter tree is the one it was.  (The lowered text of these toy steps,
     flash and XLA attention, remat on and off, equals the parent commit's
     byte for byte: checked by hand in PR 29, as PR 25 did.)"""
-    from perfbench.harness.families import olmoe
-    from ray_tpu.models.gpt2 import GPT2Config
+    from ray_tpu.models.gpt2 import GPT2Config, GPT2LMModel
+    from ray_tpu.models.llama import LlamaLMModel
 
     if family == "llama":
         cfg = dataclasses.replace(LlamaConfig.tiny(),
                                   attention_impl="reference")
     elif family == "olmoe":
-        with open(os.path.join(os.path.dirname(os.path.dirname(
-                os.path.abspath(__file__))), "perfbench", "tests", "toy",
-                "toy-olmoe.json")) as f:
-            cfg = dataclasses.replace(olmoe.model_config(json.load(f), 1),
-                                      attention_impl="reference")
+        cfg = toys.config("toy-olmoe", dtype=None, attention_impl="reference")
     else:
         cfg = GPT2Config(vocab_size=512, n_positions=64, n_embd=64, n_layer=2,
                          n_head=4, attention_impl="reference")
-    model, params = init_params(cfg)
+    model = (GPT2LMModel if family == "gpt2" else LlamaLMModel)(cfg)
+    # (the tree's shapes are all the step's text reads of the parameters)
+    params = jax.eval_shape(lambda: init_params(cfg)[1])
     assert "lm_head" in params
     assert all("mamba" not in params[k] for k in params if k.startswith("h_"))
-    text = _step_text(model, params)
+    text = _lowered_step(model, params)
     for absent in ("/mamba/", "/ssd/", "/gated_norm/", "/conv/"):
         assert absent not in text, absent
     for present in ("/lm_head/", "/attn/") + (
             ("/rope/",) if family != "gpt2" else ()):
         assert present in text, present
     # the same text of the hybrid model does name them: the check can see
-    hybrid = _step_text(*_program()[:2])
+    hybrid = _hybrid_text()
     for present in ("/mamba/", "/ssd/", "/gated_norm/", "/conv/", "/lm_head/",
                     "/attn/"):
         assert present in hybrid, present
     assert "/rope/" not in hybrid
-
-
-@pytest.mark.parametrize("mesh", [{"dp": 1, "fsdp": 2}, {"dp": 2, "tp": 2}])
-def test_i_a_sharded_mesh_gives_the_single_device_loss(mesh):
-    """``mamba/*`` under the partition rules (in_proj and out_proj as the
-    attention's projections, the small leaves replicated) on a CPU virtual
-    mesh: two steps equal the single-device steps.  No chip has run this."""
-    P = jax.sharding.PartitionSpec
-    cfg = dataclasses.replace(granite_hybrid.model_config(TOY, 1),
-                              dtype=jnp.float32)
-    n = int(np.prod(list(mesh.values())))
-    batch = next(ZipfStream(TOY["vocab_size"], seed=4).batches(4, 32))
-    one = ShardedPretrainer(cfg, MeshConfig(), devices=jax.devices()[:1])
-    many = ShardedPretrainer(cfg, MeshConfig(**mesh),
-                             devices=jax.devices()[:n])
-    spec = many.param_specs["h_0"]["mamba"]
-    assert spec["in_proj"]["kernel"] == P("fsdp", "tp")
-    assert spec["out_proj"]["kernel"] == P("tp", "fsdp")
-    for leaf in ("conv_kernel", "conv_bias", "dt_bias", "A_log", "D",
-                 "norm_scale"):
-        assert spec[leaf] == P(), leaf
-    assert many.param_specs["wte"]["embedding"] == P("tp", "fsdp")
-    for _ in range(2):      # the second step has been through an update
-        want, got = float(one.step(batch)), float(many.step(batch))
-        assert got == pytest.approx(want, rel=2e-5)
-
-
-def test_j_train_step_lowers_the_loss():
-    """The normal path at toy size, bf16 activations, flash attention
-    interpreted: the hybrid model learns the Zipf stream's unigrams."""
-    trainer = ShardedPretrainer(granite_hybrid.model_config(TOY, 1),
-                                MeshConfig(), lr=3e-2, total_steps=60,
-                                devices=jax.devices()[:1])
-    assert trainer.config.layer_types == ("mamba", "mamba", "attention")
-    batches = ZipfStream(TOY["vocab_size"], seed=3).batches(4, 64)
-    losses = [float(trainer.step(next(batches))) for _ in range(40)]
-    assert all(np.isfinite(losses))
-    # 6.23 to 5.3 when this was written; ln(512) is 6.24
-    assert np.mean(losses[-3:]) < np.mean(losses[:3]) - 0.5
-    assert trainer.moe_stats == {}

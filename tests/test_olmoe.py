@@ -6,14 +6,13 @@ off their initial values.  On the chip the same reference runs at published
 widths against the bf16 program (``perfbench/harness/agreement.py``)."""
 
 import dataclasses
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import toys
 from perfbench.harness import reference
 from perfbench.harness.families import olmoe
 from perfbench.harness.tokens import ZipfStream
@@ -22,45 +21,20 @@ from ray_tpu.models.pretrain import (ShardedPretrainer, init_params, loss_fn,
                                      objective_fn)
 from ray_tpu.parallel.mesh import MeshConfig
 
-with open(os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "perfbench", "tests", "toy",
-        "toy-olmoe.json")) as f:
-    TOY = json.load(f)     # 64 wide, 4 heads, 8 experts of 32, top-2, 2 layers
-
-
-def _program(config=TOY, positions=48):
-    """The program in float32 with XLA attention, so that what is left to
-    differ from the reference is the mathematics (the grouped matmul is the
-    interpreted Pallas one with its own backward rule).  48 positions make
-    2 x 48 x 2 = 192 (token, expert) rows, a whole row tile; 41 make 164,
-    which the grouped matmul has to pad."""
-    cfg = dataclasses.replace(
-        olmoe.model_config(config, 1), dtype=jnp.float32,
-        attention_impl="reference")
-    model, params = init_params(cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(1), 1000))
-    params = jax.tree_util.tree_map(
-        lambda a: a + 0.1 * jax.random.normal(next(keys), a.shape, a.dtype),
-        params)
-    rows = ZipfStream(TOY["vocab_size"], seed=5).rows(2, positions)
-    return model, params, {k: jnp.asarray(v) for k, v in rows.items()}
-
-
-def _both(model, params, batch, config=TOY):
-    """(logits, loss, gradient norm) of the program and of the reference."""
-    with jax.default_matmul_precision("highest"):
-        logits = model.apply({"params": params}, batch["input_ids"])
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(model, p, batch))(params)
-    got = (logits[..., :TOY["vocab_size"]], loss, reference.global_norm(grads))
-    return got, reference.logits_loss_gradnorm(
-        params, batch["input_ids"], batch["targets"], config)
+TOY = toys.toy("toy-olmoe")     # 64 wide, 4 heads, 8 experts of 32, top-2, 2 layers
+# The program in float32 with XLA attention, so that what is left to differ
+# from the reference is the mathematics (the grouped matmul is the interpreted
+# Pallas one with its own backward rule).  48 positions make 2 x 48 x 2 = 192
+# (token, expert) rows, a whole row tile; 41 make 164, which the grouped
+# matmul has to pad.
+_F32 = dict(attention_impl="reference")
 
 
 def _assert_equal(got, want):
-    np.testing.assert_allclose(got[0], want[0], rtol=2e-4, atol=2e-4)
-    assert float(got[1]) == pytest.approx(float(want[1]), rel=1e-5)
-    assert float(got[2]) == pytest.approx(float(want[2]), rel=1e-4)
+    np.testing.assert_allclose(got.logits, want.logits, rtol=2e-4, atol=2e-4)
+    assert float(got.loss) == pytest.approx(float(want.loss), rel=1e-5)
+    assert float(got.gradnorm) == pytest.approx(float(want.gradnorm),
+                                                rel=1e-4)
 
 
 @pytest.mark.parametrize("norm_topk_prob", [False, True])
@@ -69,21 +43,26 @@ def test_a_program_equals_the_reference_in_float32(norm_topk_prob):
     chosen probabilities as the softmax over all experts gave them) and with
     the configuration's other value (renormalised over the chosen)."""
     config = dict(TOY, norm_topk_prob=norm_topk_prob)
-    _assert_equal(*_both(*_program(config), config))
+    _assert_equal(toys.program(config, 48, **_F32),
+                  toys.reference(config, 48, **_F32))
 
 
 @pytest.mark.parametrize("positions", [48, 41])
 def test_b_auxiliary_losses_and_max_load_equal_the_reference(positions):
-    model, params, batch = _program(positions=positions)
+    model, params = toys.weights(TOY, **_F32)
+    batch = toys.rows(TOY, 2, positions)
     with jax.default_matmul_precision("highest"):
-        objective, (loss, stats) = objective_fn(model, params, batch)
-    want = olmoe.aux_losses(params, batch["input_ids"], TOY)
+        objective, (loss, stats) = jax.jit(
+            lambda p, b: objective_fn(model, p, b))(params, batch)
+        want = jax.jit(lambda p, b: olmoe.aux_losses(
+            p, b["input_ids"], TOY))(params, batch)
+        plain_loss = jax.jit(lambda p, b: loss_fn(model, p, b))(params, batch)
     assert set(stats) == set(want) == {"load_balance", "z", "max_load"}
     for name in want:
         assert float(stats[name]) == pytest.approx(float(want[name]),
                                                    rel=1e-5), name
     # what is differentiated is the cross entropy plus the weighted terms
-    assert float(loss) == pytest.approx(float(loss_fn(model, params, batch)))
+    assert float(loss) == pytest.approx(float(plain_loss))
     assert float(objective) == pytest.approx(float(
         loss + 0.01 * want["load_balance"] + 0.001 * want["z"]), rel=1e-6)
     # top-2 of 8: 1.0 is balance, 4.0 every token on the same two experts
@@ -96,11 +75,29 @@ def test_c_no_token_is_dropped_under_the_worst_imbalance(positions):
     breaks the tie by index: every token of every layer takes experts 0 and
     1, whose groups hold all the rows while six groups are empty.  A layer
     with a capacity would drop most tokens; this one equals the reference."""
-    model, params, batch = _program(positions=positions)
-    for name in ("h_0", "h_1"):
-        router = params[name]["moe"]["router"]
-        router["kernel"] = jnp.zeros_like(router["kernel"])
-    _assert_equal(*_both(model, params, batch))
+    model, params = toys.weights(TOY, **_F32)
+    batch = toys.rows(TOY, 2, positions)
+    params = dict(params, **{name: dict(params[name], moe=dict(
+        params[name]["moe"], router={"kernel": jnp.zeros_like(
+            params[name]["moe"]["router"]["kernel"])}))
+        for name in ("h_0", "h_1")})
+
+    def program(params, batch):
+        logits = model.apply({"params": params}, batch["input_ids"])
+        loss, grads = jax.value_and_grad(
+            lambda p: loss_fn(model, p, batch))(params)
+        return toys.Run(logits[..., :TOY["vocab_size"]], loss,
+                        reference.global_norm(grads))
+
+    def plain(params, batch):
+        return toys.Run(*reference.logits_loss_gradnorm(
+            params, batch["input_ids"], batch["targets"], TOY))
+
+    with jax.default_matmul_precision("highest"):
+        _assert_equal(jax.jit(program)(params, batch),
+                      jax.jit(plain)(params, batch))
+    # (op by op: under jit the mean over 164 rows is a product with 1 / 164,
+    # and 3.9999998)
     assert float(objective_fn(model, params, batch)[1][1]["max_load"]) == 4.0
 
 
@@ -109,11 +106,12 @@ def test_c_no_token_is_dropped_under_the_worst_imbalance(positions):
 def test_d_the_tolerance_sees_a_wrong_model(wrong):
     """The reference with renormalised top-k weights, or with top-(k-1),
     lands far outside (a)'s tolerance (2e-4 on the logits, 1e-5 on the
-    loss): a dropped or rescaled term cannot hide in it."""
-    model, params, batch = _program()
-    got, want = _both(model, params, batch, dict(TOY, **wrong))
-    assert float(jnp.max(jnp.abs(got[0] - want[0]))) > 100 * 2e-4
-    assert abs(float(got[1]) / float(want[1]) - 1) > 100 * 1e-5
+    loss): a dropped or rescaled term cannot hide in it.  The program's side
+    is (a)'s published case, made once."""
+    got = toys.program(TOY, 48, **_F32)
+    want = toys.reference(TOY, 48, backward=False, wrong=wrong, **_F32)
+    assert float(jnp.max(jnp.abs(got.logits - want.logits))) > 100 * 2e-4
+    assert abs(float(got.loss) / float(want.loss) - 1) > 100 * 1e-5
 
 
 def test_e_train_step_lowers_the_loss_and_reports_the_cross_entropy():
@@ -124,7 +122,8 @@ def test_e_train_step_lowers_the_loss_and_reports_the_cross_entropy():
     assert trainer.moe_stats == {}
     batches = ZipfStream(TOY["vocab_size"], seed=3).batches(4, 32)
     first = {k: jnp.asarray(v) for k, v in next(batches).items()}
-    want = loss_fn(trainer.model, trainer.state[0], first)
+    want = jax.jit(lambda p: loss_fn(trainer.model, p, first))(
+        trainer.state[0])
     losses = [trainer.step(first)] + [trainer.step(next(batches))
                                       for _ in range(19)]
     # the loss handed back is the cross entropy, not the objective
@@ -147,15 +146,15 @@ def test_f_a_sharded_mesh_gives_the_single_device_loss(mesh):
     no dropless path yet and says so."""
     from ray_tpu.models.moe import token_spec
 
-    cfg = dataclasses.replace(olmoe.model_config(TOY, 1), dtype=jnp.float32)
+    cfg = toys.config(TOY)
     n = int(np.prod(list(mesh.values())))
-    batch = next(ZipfStream(TOY["vocab_size"], seed=4).batches(4, 32))
     if "ep" in mesh:
+        batch = next(ZipfStream(TOY["vocab_size"], seed=4).batches(4, 32))
         with pytest.raises(NotImplementedError, match="ep > 1"):
             ShardedPretrainer(cfg, MeshConfig(**mesh),
                               devices=jax.devices()[:n]).step(batch)
         return
-    one = ShardedPretrainer(cfg, MeshConfig(), devices=jax.devices()[:1])
+    one = toys.one_device(TOY, 4, 32, 2, seed=4, want=False)   # for both
     many = ShardedPretrainer(cfg, MeshConfig(**mesh),
                              devices=jax.devices()[:n])
     assert token_spec(many.mesh) == jax.sharding.PartitionSpec(
@@ -164,10 +163,9 @@ def test_f_a_sharded_mesh_gives_the_single_device_loss(mesh):
     assert spec["gate_proj"] == jax.sharding.PartitionSpec("ep", "fsdp", "tp")
     assert spec["down_proj"] == jax.sharding.PartitionSpec("ep", "tp", "fsdp")
     assert spec["router"]["kernel"] == jax.sharding.PartitionSpec()
-    for _ in range(2):      # the second step has been through an update
-        want, got = float(one.step(batch)), float(many.step(batch))
-        assert got == pytest.approx(want, rel=2e-5)
-    for name, value in one.moe_stats.items():
+    for want in one.losses:     # the second step has been through an update
+        assert float(many.step(one.rows)) == pytest.approx(want, rel=2e-5)
+    for name, value in one.stats.items():
         assert float(many.moe_stats[name]) == pytest.approx(float(value),
                                                             rel=1e-4), name
 
@@ -178,7 +176,8 @@ def test_g_a_dense_model_is_the_program_it_was(family):
     configuration has the parameter tree it had and nothing of either in its
     step (the lowered text of both toy steps equals the parent commit's byte
     for byte: checked by hand in PR 25)."""
-    from ray_tpu.models.gpt2 import GPT2Config
+    from ray_tpu.models.gpt2 import GPT2Config, GPT2LMModel
+    from ray_tpu.models.llama import LlamaLMModel
     from ray_tpu.models.pretrain import make_optimizer, train_step
 
     if family == "llama":
@@ -194,18 +193,20 @@ def test_g_a_dense_model_is_the_program_it_was(family):
         block = {"attn": {"qkv_proj", "out_proj"}, "ln_1": {"scale", "bias"},
                  "mlp": {"fc_in", "fc_out"}, "ln_2": {"scale", "bias"}}
         top = {"wte", "wpe", "h_0", "h_1", "ln_f", "lm_head"}
-    model, params = init_params(cfg)
+    model = (LlamaLMModel if family == "llama" else GPT2LMModel)(cfg)
+    # (the tree's shapes: nothing here reads a parameter's value)
+    params = jax.eval_shape(lambda: init_params(cfg)[1])
     assert set(params) == top
     assert {k: set(v) for k, v in params["h_0"].items()} == block
     tx = make_optimizer()
     batch = {k: jnp.zeros((2, 16), jnp.int32)
              for k in ("input_ids", "targets")}
-    state, loss, stats = jax.eval_shape(
-        lambda s, b: train_step(model, tx, s, b),
-        (params, tx.init(params)), batch)
+    state = (params, jax.eval_shape(tx.init, params))
+    _, loss, stats = jax.eval_shape(
+        lambda s, b: train_step(model, tx, s, b), state, batch)
     assert stats == {} and loss.shape == ()
     text = str(jax.make_jaxpr(lambda s, b: train_step(model, tx, s, b))(
-        (params, tx.init(params)), batch))
+        state, batch))
     for absent in ("moe", "router", "argsort", "top_k",
                    "pallas_call", "q_norm"):
         assert absent not in text, absent

@@ -14,11 +14,9 @@ reference's output of its layer 9 from its input of layer 4 (the published
 order at a third of the depth: 32 toy layers cost tier-1 half a minute), and
 the held rows' logits are the uncut head's; (e) the new parameters' partition rules on a virtual mesh, a
 sharded sequence refused, a reader before its producer and a producer without
-a reader refused; (f) the older toys' lowered steps are held by the hash tests
-of ``tests/test_sdar_parts.py`` (i), (n), ``tests/test_laguna_parts.py`` (e),
-``tests/test_kimi_vl.py`` (d) and ``tests/test_lfm2.py`` (f): unedited, but
-that the three grouped toys' pins and Laguna's are retaken, their interpreted
-backward kernel indexing a narrow group's dQ as the compiled one does.  The
+a reader refused; (f) every toy's lowered step is held by
+``tests/test_pinned_steps.py``, the older programs' losses and kernels by
+``tests/test_sdar_programs.py`` (i) and ``tests/test_sdar_parts.py`` (n).  The
 scan alone is ``tests/test_selective_scan.py``'s.  The toy
 (``perfbench/tests/toy/toy-phi4-flash.json``): 64 wide, the six kinds of layer
 (published 14 to 19 of 32), 4 / 2 heads of 16, 128 channels of 4 states (the
@@ -28,28 +26,22 @@ trace), window 8, ``scan_block`` 8.
 """
 
 import dataclasses
-import functools
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import toys
 from perfbench.harness import reference
 from perfbench.harness.families import phi4_flash
-from perfbench.harness.tokens import ZipfStream
 from ray_tpu.models.llama import (DifferentialAttention, LlamaBlock,
                                   LlamaConfig, LlamaLMModel, carried_plan)
 from ray_tpu.models.mamba import GatedMemoryUnit, Mamba1Mixer
-from ray_tpu.models.pretrain import init_params, loss_fn
+from ray_tpu.models.pretrain import init_params
 from ray_tpu.ops.attention import mha_reference
 
-with open(os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "perfbench", "tests", "toy",
-        "toy-phi4-flash.json")) as f:
-    TOY = json.load(f)
+TOY = toys.toy("toy-phi4-flash")
 # one more pair of the cross-decoder, one layer less of the self-decoder:
 # every kind of layer, and m and the keys and values each with two readers in
 # blocks of their own
@@ -67,18 +59,6 @@ STACKS = {"six": TOY, "seven": SEVEN}
 FOUR = dict(TOY, num_hidden_layers=4, layers_kept=list(range(16, 20)))
 
 
-def _config(config=TOY, **changes):
-    return dataclasses.replace(phi4_flash.model_config(config, 1),
-                               dtype=jnp.float32, **changes)
-
-
-def _moved(params, seed=1, by=0.1):
-    keys = iter(jax.random.split(jax.random.PRNGKey(seed), 1000))
-    return jax.tree_util.tree_map(
-        lambda a: a + by * jax.random.normal(next(keys), a.shape, a.dtype),
-        params)
-
-
 def _same(got, want, rtol=2e-3, atol=2e-5):
     for (path, g), w in zip(jax.tree_util.tree_flatten_with_path(got)[0],
                             jax.tree_util.tree_leaves(want)):
@@ -94,10 +74,10 @@ def test_b_the_mamba1_mixer_equals_the_plain_form(seq):
     scan's kernels, the skip, the gate, ``out_proj`` — against the
     reference's layer: the output, the scan output it hands on, and every
     parameter's gradient; 21 positions are no whole blocks."""
-    cfg = _config()
+    cfg = toys.config(TOY)
     mixer = Mamba1Mixer(cfg)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, seq, cfg.d_model))
-    params = _moved(jax.jit(mixer.init)(jax.random.PRNGKey(1), x)["params"])
+    params = toys.moved(jax.jit(mixer.init)(jax.random.PRNGKey(1), x)["params"])
     assert set(params) == {"in_proj", "x_proj", "dt_proj", "out_proj",
                            "conv_kernel", "conv_bias", "dt_bias", "A_log",
                            "D"}
@@ -126,11 +106,11 @@ def test_b_the_mamba1_mixer_equals_the_plain_form(seq):
 
 
 def test_b_the_gated_memory_unit_equals_the_plain_form():
-    cfg = _config()
+    cfg = toys.config(TOY)
     unit = GatedMemoryUnit(cfg)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, cfg.d_model))
     m = jax.random.normal(jax.random.PRNGKey(1), (2, 24, 128))
-    params = _moved(unit.init(jax.random.PRNGKey(2), x, m)["params"])
+    params = toys.moved(unit.init(jax.random.PRNGKey(2), x, m)["params"])
     assert set(params) == {"in_proj", "out_proj"}
     with jax.default_matmul_precision("highest"):
         np.testing.assert_allclose(unit.apply({"params": params}, x, m),
@@ -147,12 +127,12 @@ def test_b_differential_attention_is_two_softmaxes_subtracted(kind, impl):
     side — under the window of 8 and causal, with keys and values of its own
     and (a cross layer) another layer's; then lam, the subtraction, the
     sub-layer norm over a pair's 32 and ``1 - lam0`` at depth 17."""
-    cfg = _config(attention_impl=impl)
+    cfg = toys.config(TOY, attention_impl=impl)
     layer = DifferentialAttention(cfg, kind, depth=17)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, 40, cfg.d_model))
     given = jax.random.normal(jax.random.PRNGKey(3), (2, 40, 64)) \
         if kind == "cross_attention" else None
-    p = _moved(jax.jit(layer.init)(jax.random.PRNGKey(1), x, given)["params"])
+    p = toys.moved(jax.jit(layer.init)(jax.random.PRNGKey(1), x, given)["params"])
     assert set(p) == {"wq" if given is not None else "wqkv", "wo",
                       "lambda_q1", "lambda_k1", "lambda_q2", "lambda_k2",
                       "sub_norm"}
@@ -203,79 +183,36 @@ def test_b_a_block_with_every_new_field_unset_is_the_block_it_was():
 
 
 # ------------------------------------------ (c) the stack and its reference
-@functools.lru_cache(maxsize=None)
-def _weights(stack: str):
-    """(model, weights moved off their start), once a stack for the tests
-    that read it; under ``jit``, where the forward that ``init`` traces is
-    dead code and not a hundred programs run one by one."""
-    cfg = _config(STACKS[stack])
-    params = jax.jit(lambda: init_params(cfg)[1])()
-    return LlamaLMModel(cfg), _moved(params, by=0.05)
+# weights moved off their start by 0.05 a leaf, each stack's made once
+_BY = dict(by=0.05)
 
 
-def _batch(model, positions=43):
-    rows = ZipfStream(model.config.vocab_size, seed=5).rows(2, positions)
-    return {k: jnp.asarray(v) for k, v in rows.items()}
-
-
-@functools.lru_cache(maxsize=None)
-def _program(stack: str, positions: int, backward: bool = False,
+def _ours(stack: str, positions: int, backward: bool = False,
              remat: bool = True):
     """(logits, loss, gradients) of the program, compiled once for the tests
     that read it.  With the backward the attention is ``mha_reference`` (the
     flash kernels' backward at these widths is the trainer's step's, below,
     and ``tests/test_flash_layout.py``'s): a third of the graph to compile,
     the scan's kernels and the tensors that cross blocks as they are."""
-    model, params = _weights(stack)
-    if backward:
-        model = LlamaLMModel(dataclasses.replace(
-            model.config, remat=remat, attention_impl="reference"))
-    batch = _batch(model, positions)
-
-    def program(params, batch):
-        logits = model.apply({"params": params}, batch["input_ids"])
-        logits = logits[..., :model.config.vocab_size]
-        if not backward:
-            return logits, loss_fn(model, params, batch), None
-        loss, grads = jax.value_and_grad(
-            lambda p: loss_fn(model, p, batch))(params)
-        return logits, loss, grads
-
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(program)(params, batch)
+    if not backward:
+        return toys.program(STACKS[stack], positions, backward=False, **_BY)
+    return toys.program(STACKS[stack], positions, remat=remat,
+                        attention_impl="reference", **_BY)
 
 
-@functools.lru_cache(maxsize=None)
-def _plain(stack: str, positions: int, backward: bool = False):
+def _theirs(stack: str, positions: int, backward: bool = False):
     """The same of the reference."""
-    config = STACKS[stack]
-    model, params = _weights(stack)
-    batch = _batch(model, positions)
-
-    def plain(params, batch):
-        def loss_of(p):
-            logits = phi4_flash.logits(p, batch["input_ids"], config)
-            logp = jax.nn.log_softmax(logits, axis=-1)
-            return -jnp.take_along_axis(
-                logp, batch["targets"][..., None], axis=-1).mean(), logits
-
-        if not backward:
-            return (*loss_of(params)[::-1], None)
-        (loss, logits), grads = jax.value_and_grad(loss_of, has_aux=True)(
-            params)
-        return logits, loss, grads
-
-    with jax.default_matmul_precision("highest"):
-        return jax.jit(plain)(params, batch)
+    return toys.reference(STACKS[stack], positions, backward=False,
+                          leaves=backward, **_BY)
 
 
 def test_c_the_toy_is_the_six_kinds_at_the_seam():
-    cfg = _config()
+    cfg = toys.config(TOY)
     assert cfg.layer_types == ("mamba1", "sliding_attention", "mamba1",
                                "full_attention", "gmu", "cross_attention")
     assert cfg.producers == (2, 3) and carried_plan(cfg) == (
         None, None, None, None, 2, 3)
-    assert carried_plan(_config(SEVEN)) == (None,) * 3 + (1, 2, 1, 2)
+    assert carried_plan(toys.config(SEVEN)) == (None,) * 3 + (1, 2, 1, 2)
     assert cfg.layer_depths == (14, 15, 16, 17, 18, 19)
     assert (cfg.norm, cfg.rope, cfg.tie_embeddings, cfg.diff_attn,
             cfg.attn_bias) == ("layer", False, True, True, True)
@@ -291,10 +228,10 @@ def test_c_the_toy_is_the_six_kinds_at_the_seam():
 def test_c_program_equals_the_reference_in_float32():
     """Logits and loss to float32 rounding on the six layers, at 43 positions
     (no whole blocks of the scan, five windows)."""
-    got, want = _program("six", 43), _plain("six", 43)
-    assert got[0].shape == (2, 43, 512)
-    np.testing.assert_allclose(got[0], want[0], rtol=2e-4, atol=2e-4)
-    assert float(got[1]) == pytest.approx(float(want[1]), rel=1e-5)
+    got, want = _ours("six", 43), _theirs("six", 43)
+    assert got.logits.shape == (2, 43, 512)
+    np.testing.assert_allclose(got.logits, want.logits, rtol=2e-4, atol=2e-4)
+    assert float(got.loss) == pytest.approx(float(want.loss), rel=1e-5)
 
 
 def test_c_every_gradient_equals_the_references():
@@ -304,9 +241,9 @@ def test_c_every_gradient_equals_the_references():
     ``h_2`` itself), each reader in a block of its own under remat, and the
     producers' leaves carry the sum of their cotangents, as the plain
     reference's do by reverse mode alone."""
-    (_, loss, got), (_, plain_loss, want) = (
-        f("seven", 32, True) for f in (_program, _plain))
-    assert float(loss) == pytest.approx(float(plain_loss), rel=1e-5)
+    ours, theirs = (f("seven", 32, True) for f in (_ours, _theirs))
+    got, want = ours.grads, theirs.grads
+    assert float(ours.loss) == pytest.approx(float(theirs.loss), rel=1e-5)
     assert set(got["h_0"]["attn"]) == set(got["h_2"]["attn"]) >= {
         "wqkv", "wo", "sub_norm", "lambda_q1"}
     assert set(got["h_4"]["attn"]) >= {"wq", "wo"} \
@@ -321,35 +258,21 @@ def test_c_remat_on_and_off_give_the_same_gradients():
     """The tensors that cross blocks are inputs of the recomputation: with
     and without it every leaf's gradient is the same, the producers' sums
     over two readers among them."""
-    _, _, on = _program("seven", 32, True)
-    _, _, off = _program("seven", 32, True, remat=False)
+    on = _ours("seven", 32, True).grads
+    off = _ours("seven", 32, True, remat=False).grads
     _same(on, off, rtol=1e-4, atol=1e-6)
 
 
-@functools.lru_cache(maxsize=None)
 def _one_device():
     """Six steps of ``ShardedPretrainer`` on one device, four rows of 32, of
     the seam's four layers (``FOUR``: both producers and both kinds of
-    reader, the flash kernels and the scan's with their backward):
-    (the first step's weights' reference loss, the steps' losses, the
-    rows)."""
-    from ray_tpu.models.pretrain import ShardedPretrainer
-    from ray_tpu.parallel.mesh import MeshConfig
-
-    trainer = ShardedPretrainer(_config(FOUR), MeshConfig(),
-                                devices=jax.devices()[:1], lr=0.1)
-    rows = ZipfStream(512, seed=5).rows(4, 32)
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda p, ids: jnp.mean(-jnp.take_along_axis(
-            jax.nn.log_softmax(phi4_flash.logits(p, ids, FOUR), axis=-1),
-            jnp.asarray(rows["targets"])[..., None], axis=-1)))(
-                trainer.state[0], jnp.asarray(rows["input_ids"]))
-    losses = [float(trainer.step(rows)) for _ in range(6)]
-    return float(want), losses, rows
+    reader, the flash kernels and the scan's with their backward), once for
+    (c) and for both meshes of (e)."""
+    return toys.one_device(FOUR, 4, 32, 6, lr=0.1)
 
 
 def test_c_the_trainers_step_takes_the_references_loss_down():
-    want, losses, _ = _one_device()
+    want, losses, *_ = _one_device()
     assert losses[0] == pytest.approx(want, rel=1e-4)
     assert losses[-1] < losses[0] - 0.2
 
@@ -360,12 +283,8 @@ def test_c_the_tolerance_sees_each_wrong_model(wrong):
     """Each wrong model of the on-chip controls moves the toy's logits by far
     more than (c)'s tolerance, and so does the reference itself with float8
     activations."""
-    _, params = _weights("six")
-    batch = _batch(LlamaLMModel(_config()))
-    got = _program("six", 43)[0]
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(lambda p, b: phi4_flash._forward(
-            p, b["input_ids"], TOY, wrong)[..., :512])(params, batch)
+    got = _ours("six", 43).logits
+    want = toys.reference(TOY, 43, backward=False, wrong=wrong, **_BY).logits
     assert not float(jnp.max(jnp.abs(got - want))) <= 100 * 2e-4
 
 
@@ -380,13 +299,13 @@ def test_d_the_six_layers_are_the_seam_of_the_uncut_model():
     S-1 embed to them and the tied head reads the result out; and with the
     table's held rows unchanged the program's logits are the uncut head's on
     those rows."""
-    _, params = _weights("six")
-    model = LlamaLMModel(_config(SEAM))
+    _, params = toys.weights(TOY, **_BY)
+    model = LlamaLMModel(toys.config(SEAM))
     assert model.config.layer_depths == (4, 5, 6, 7, 8, 9)
     kinds = [phi4_flash.layer_kind(i, 12) for i in range(12)]
     assert tuple(kinds[4:10]) == model.config.layer_types
     kept_of = dict(zip(model.config.layer_types, range(6)))  # a layer a kind
-    uncut = {f"h_{i}": params[f"h_{i - 4}"] if 4 <= i <= 9 else _moved(
+    uncut = {f"h_{i}": params[f"h_{i - 4}"] if 4 <= i <= 9 else toys.moved(
         params[f"h_{kept_of[kind]}"], seed=100 + i, by=0.02)
         for i, kind in enumerate(kinds)}
     seq, eps = 40, TOY["layer_norm_eps"]
@@ -435,7 +354,7 @@ def test_e_a_sharded_mesh_gives_the_single_device_loss(mesh):
     from ray_tpu.parallel.sharding import (llama_partition_rules,
                                            match_partition_rules)
 
-    cfg = _config(FOUR)
+    cfg = toys.config(FOUR)
     specs = match_partition_rules(llama_partition_rules(), jax.eval_shape(
         lambda: init_params(cfg)[1]))
     mamba1, gmu = specs["h_0"]["mamba1"], specs["h_2"]["gmu"]
@@ -452,11 +371,11 @@ def test_e_a_sharded_mesh_gives_the_single_device_loss(mesh):
     assert specs["h_3"]["attn"]["wq"]["kernel"] == P("fsdp", "tp")
     assert specs["h_3"]["attn"]["lambda_q1"] == P()
 
-    _, one, rows = _one_device()
+    one = _one_device()
     many = ShardedPretrainer(cfg, MeshConfig(**mesh),
                              devices=jax.devices()[:4], lr=0.1)
-    for want in one[:2]:    # the second step sees the first's gradients
-        assert float(many.step(rows)) == pytest.approx(want, rel=1e-5)
+    for want in one.losses[:2]:     # the second sees the first's gradients
+        assert float(many.step(one.rows)) == pytest.approx(want, rel=1e-5)
 
 
 def test_e_a_sharded_sequence_is_refused():
@@ -464,7 +383,7 @@ def test_e_a_sharded_sequence_is_refused():
     ``sp`` axis it raises, in the words ``ops.attention`` refuses with."""
     from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 
-    model = LlamaLMModel(_config())
+    model = LlamaLMModel(toys.config(TOY))
     mesh = build_mesh(MeshConfig(dp=1, sp=2), devices=jax.devices()[:2])
     with jax.set_mesh(mesh), pytest.raises(
             NotImplementedError,
@@ -487,7 +406,7 @@ def test_e_a_sharded_sequence_is_refused():
     ids=["reader-first", "no-producer", "no-reader", "no-such-producer"])
 def test_e_a_reader_without_its_producer_is_refused(changes, words):
     """At construction, in words that name the layer."""
-    model = LlamaLMModel(_config(**changes))
+    model = LlamaLMModel(toys.config(TOY, **changes))
     with pytest.raises(ValueError, match=words):
         jax.eval_shape(model.init, jax.random.PRNGKey(0),
                        jnp.zeros((1, 16), jnp.int32))

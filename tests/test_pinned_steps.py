@@ -1,0 +1,44 @@
+"""Every toy's train step, lowered, is the program it was: sha256 of
+``toys.step_text(name)`` — the step as the benchmark builds it (bf16, flash
+attention, remat as the toy says), kernel bodies included, with
+``flash_names_off`` — for every ``perfbench/tests/toy/toy-*.json`` that names
+a model family.  A refactor of ``models/``, ``ops/`` or ``parallel/`` that
+means to move no program is judged here before the chip is asked.  When a
+hash moves on purpose: run the case, take the new hash from the assertion's
+message, pin it, and say in ``CHANGES.md`` which toys moved and why (the
+older hashes are in ``git log -p`` of this file).
+"""
+
+import glob
+import hashlib
+import os
+
+import pytest
+
+import toys
+
+PINNED = {
+    "toy-evabyte": "2d776bb3a348b1a6d357fbc10d09c972ef6cd73a1afd586ac3a1f9ccc3c2de59",
+    "toy-gpt2": "ba11271144fed4ca835af77562ca30fbb9989cac73bf82a195c1a4e4d5921693",
+    "toy-granite": "7a646a83d84178cf45adcbe76b26d3fedef41d6e0ef427a125c34ab1cbddb34d",
+    "toy-kimi-linear": "343430c73c40c47cba973435f1b7e7581c1d40d21743ca9ce69b68246ef78026",
+    "toy-kimi-vl": "5a017906206e0d13c1f718b10c38f6a80c6893aafaa08d252665c2a7a745d148",
+    "toy-laguna": "4d7f074a49c3ae1f68e4b5e66cd1af93e583972f0ae498461edcd62e2babffc0",
+    "toy-lfm2": "ad07a2bf3eae0808f1e15c23cd22a6c700ac2fbf8f402d9cf0bcc4b9ea0af5d6",
+    "toy-llama": "3f093a50754c69664f39a2c72264ae3a12b3e0d67a722d3f326ae8d1ef0fb00e",
+    "toy-olmoe": "41054e8f510af2a7d7be4f320326eb4237e4c1efc006d16bd81fbd3d9bea858f",
+    "toy-phi4-flash": "4a73552ec170305a0509376e0c74ec7c5681a005f8129170a8d1dd88705d4210",
+    "toy-sdar": "ff8e09d3e6a65ea9935059aba9f4c46ea2127d572f13434078f2b64b86576cb4",
+}
+
+
+def test_every_toy_that_names_a_family_is_pinned():
+    names = {os.path.basename(path)[:-len(".json")]
+             for path in glob.glob(os.path.join(toys.TOY_DIR, "toy-*.json"))}
+    assert {n for n in names if "family" in toys.toy(n)} == set(PINNED)
+
+
+@pytest.mark.parametrize("name", sorted(PINNED))
+def test_the_lowered_step_is_the_pinned_one(name, flash_names_off):
+    text = toys.step_text(name)
+    assert hashlib.sha256(text.encode()).hexdigest() == PINNED[name]

@@ -145,7 +145,8 @@ def test_the_head_norms_parameters_keep_their_paths_and_shapes(rope):
         dtype=jnp.float32, attention_impl="reference")
     layer = LlamaAttention(cfg)
     x = jax.random.normal(jax.random.PRNGKey(0), (2, SEQ, 64), jnp.float32)
-    p = layer.init(jax.random.PRNGKey(1), x, jnp.arange(SEQ))["params"]
+    p = jax.jit(layer.init)(jax.random.PRNGKey(1), x, jnp.arange(SEQ))[
+        "params"]
     assert sorted(p) == ["k_norm", "q_norm", "wk", "wo", "wq", "wv"]
     for name in ("q_norm", "k_norm"):
         assert list(p[name]) == ["scale"]
@@ -153,8 +154,8 @@ def test_the_head_norms_parameters_keep_their_paths_and_shapes(rope):
         assert p[name]["scale"].dtype == jnp.float32
         assert bool(jnp.all(p[name]["scale"] == 1.0))
     # the scales are used: the output moves with them, and they get gradients
-    grads = jax.grad(lambda p: jnp.sum(
-        layer.apply({"params": p}, x, jnp.arange(SEQ)) ** 2))(p)
+    grads = jax.jit(jax.grad(lambda p: jnp.sum(
+        layer.apply({"params": p}, x, jnp.arange(SEQ)) ** 2)))(p)
     for name in ("q_norm", "k_norm"):
         assert float(jnp.max(jnp.abs(grads[name]["scale"]))) > 0
 

@@ -8,61 +8,23 @@ a dense (2L, 2L) mask, every held expert on every token, the objective
 written out.  On the chip the same reference runs at published widths against
 the bf16 program (``perfbench/harness/bd_agreement.py``)."""
 
-import dataclasses
-import json
-import os
-
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench.harness import reference
+import toys
 from perfbench.harness.families import sdar_moe
-from perfbench.harness.tokens import ZipfStream
-from ray_tpu.models.pretrain import (init_params, loss_fn, make_optimizer,
-                                     noise_blocks, objective_fn, train_step)
+from ray_tpu.models.pretrain import (loss_fn, make_optimizer, objective_fn,
+                                     train_step)
 
-_TOYS = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "perfbench", "tests", "toy")
-with open(os.path.join(_TOYS, "toy-sdar.json")) as f:
-    # 64 wide, 4 / 2 heads of 32 (not 64 / 4), 8 experts of 32 of which 4 are
-    # held, top-2 renormalised, 512 of 2048 vocabulary rows, blocks of 4
-    TOY = json.load(f)
-
-
-def _program(impl="reference", positions=48, config=TOY):
-    """The program in float32, so that what is left to differ from the
-    reference is the mathematics; ``impl`` "flash" is the Pallas kernels
-    interpreted, with their own backward rule."""
-    cfg = dataclasses.replace(sdar_moe.model_config(config, 1),
-                              dtype=jnp.float32, attention_impl=impl)
-    model, params = init_params(cfg)
-    keys = iter(jax.random.split(jax.random.PRNGKey(1), 1000))
-    params = jax.tree_util.tree_map(
-        lambda a: a + 0.1 * jax.random.normal(next(keys), a.shape, a.dtype),
-        params)
-    ids = jnp.asarray(ZipfStream(cfg.vocab_size, seed=5).rows(
-        2, positions)["input_ids"])
-    x_t, _, weights = noise_blocks(jax.random.PRNGKey(3), ids,
-                                   cfg.diffusion_block, cfg.mask_token_id,
-                                   cfg.diffusion_t_min)
-    return model, params, {"input_ids": ids, "x_t": x_t, "weights": weights}
-
-
-def _both(model, params, batch, wrong=None, config=TOY):
-    """(logits, loss, gradient norm, held rows) of program and reference."""
-    ids = batch["input_ids"]
-    with jax.default_matmul_precision("highest"):
-        logits = model.apply({"params": params}, jnp.concatenate(
-            [batch["x_t"], ids], axis=1))
-        (_, (loss, stats)), grads = jax.value_and_grad(
-            lambda p: objective_fn(model, p, batch), has_aux=True)(params)
-    got = (logits[..., :model.config.vocab_size], loss,
-           reference.global_norm(grads), stats["moe_rows_held"])
-    return got, sdar_moe.logits_loss_gradnorm(
-        params, ids, batch["x_t"], batch["weights"], config, ids.size,
-        wrong=wrong)
+# 64 wide, 4 / 2 heads of 32 (not 64 / 4), 8 experts of 32 of which 4 are
+# held, top-2 renormalised, 512 of 2048 vocabulary rows, blocks of 4.  The
+# program runs in float32, so that what is left to differ from the reference
+# is the mathematics; ``attention_impl`` "flash" is the Pallas kernels
+# interpreted, with their own backward rule.  A batch is ``toys.rows``'s:
+# the rows under the noise of ``PRNGKey(3)``.
+TOY = toys.toy("toy-sdar")
 
 
 @pytest.mark.parametrize("impl,positions", [("reference", 48), ("flash", 48),
@@ -72,12 +34,14 @@ def test_a_program_equals_the_reference_in_float32(impl, positions):
     float32 rounding, and the held experts' assignments exactly; 44
     positions make 2 x 88 x 2 = 352 buffer rows, which the grouped matmul has
     to pad."""
-    got, want = _both(*_program(impl, positions))
-    assert got[0].shape == (2, positions, 512)
-    np.testing.assert_allclose(got[0], want[0], rtol=2e-4, atol=2e-4)
-    assert float(got[1]) == pytest.approx(float(want[1]), rel=1e-5)
-    assert float(got[2]) == pytest.approx(float(want[2]), rel=1e-4)
-    assert float(got[3]) == float(want[3]) > 0
+    got = toys.program("toy-sdar", positions, attention_impl=impl)
+    want = toys.reference("toy-sdar", positions, attention_impl=impl)
+    assert got.logits.shape == (2, positions, 512)
+    np.testing.assert_allclose(got.logits, want.logits, rtol=2e-4, atol=2e-4)
+    assert float(got.loss) == pytest.approx(float(want.loss), rel=1e-5)
+    assert float(got.gradnorm) == pytest.approx(float(want.gradnorm),
+                                                rel=1e-4)
+    assert float(got.held) == float(want.held) > 0
 
 
 @pytest.mark.parametrize("wrong", sdar_moe.WRONG
@@ -89,11 +53,14 @@ def test_b_the_tolerance_sees_each_wrong_model(wrong):
     noised block made causal inside, weights renormalised over the held
     chosen experts only, top-(k-1), and 1/t left out; and so does the
     reference itself with float8 activations, the nearest precision below the
-    program's bf16."""
-    got, want = _both(*_program(), wrong=wrong)
-    assert abs(float(got[1]) / float(want[1]) - 1) > 100 * 1e-5
+    program's bf16.  The program's side is (a)'s first case, made once."""
+    got = toys.program("toy-sdar", 48, attention_impl="reference")
+    want = toys.reference("toy-sdar", 48, backward=False, wrong=wrong,
+                          attention_impl="reference")
+    assert abs(float(got.loss) / float(want.loss) - 1) > 100 * 1e-5
     if wrong != "no_inverse_t":
-        assert float(jnp.max(jnp.abs(got[0] - want[0]))) > 100 * 2e-4
+        assert float(jnp.max(jnp.abs(got.logits - want.logits))) \
+            > 100 * 2e-4
 
 
 def test_g_train_step_draws_another_noise_every_step_and_learns():
@@ -102,16 +69,19 @@ def test_g_train_step_draws_another_noise_every_step_and_learns():
     under the noise of ``fold_in(PRNGKey(0), the optimizer's step count)``,
     so the same batch gives another loss the next step; and at a fixed noise
     the loss falls."""
-    model, params, batch = _program()
-    batch = {"input_ids": batch["input_ids"],
-             "targets": jnp.roll(batch["input_ids"], -1, axis=1)}
+    model, params = toys.weights("toy-sdar", attention_impl="reference")
+    fixed = toys.rows("toy-sdar", 2, 48)
+    batch = {"input_ids": fixed["input_ids"],
+             "targets": jnp.roll(fixed["input_ids"], -1, axis=1)}
     tx = make_optimizer(lr=3e-3, warmup=1)
     step = jax.jit(lambda s, b: train_step(model, tx, s, b))
+    under = jax.jit(lambda p, b, key: objective_fn(model, p, b, key)[1][0])
+    at_fixed = jax.jit(lambda p: loss_fn(model, p, fixed))
     state = (params, tx.init(params))
     seen = []
     for n in range(3):
-        want = objective_fn(model, state[0], batch, jax.random.fold_in(
-            jax.random.PRNGKey(0), n))[1][0]
+        want = under(state[0], batch, jax.random.fold_in(
+            jax.random.PRNGKey(0), n))
         state, loss, stats = step(state, batch)
         assert float(loss) == pytest.approx(float(want), rel=1e-5)
         assert set(stats) == {"load_balance", "z", "max_load",
@@ -119,13 +89,10 @@ def test_g_train_step_draws_another_noise_every_step_and_learns():
         assert stats["moe_rows_held"] <= stats["moe_buffer_rows"] <= 2 * 96 * 2
         seen.append(float(loss))
     assert len(set(seen)) == 3
-    fixed = _program()[2]
-    first = float(loss_fn(model, params, fixed))
+    first = float(at_fixed(params))
     for _ in range(20):
         state, _, _ = step(state, batch)
-    assert float(loss_fn(model, state[0], fixed)) < first - 0.5
-
-
+    assert float(at_fixed(state[0])) < first - 0.5
 
 
 @pytest.mark.parametrize("mesh", [{"dp": 2, "fsdp": 2}, {"dp": 2, "tp": 2}])
@@ -133,20 +100,16 @@ def test_h_a_sharded_mesh_gives_the_single_device_loss(mesh):
     """On a virtual CPU mesh the step — the noise drawn under the layout, the
     block mask inside ``shard_map``, each device routing its own rows through
     the held experts in a buffer of its own capacity — gives the losses and
-    the statistics of one device."""
+    the statistics of one device (run once for both meshes)."""
     from ray_tpu.models.pretrain import ShardedPretrainer
     from ray_tpu.parallel.mesh import MeshConfig
 
-    cfg = dataclasses.replace(sdar_moe.model_config(TOY, 1),
-                              dtype=jnp.float32)
-    rows = ZipfStream(cfg.vocab_size, seed=5).rows(4, 64)
-    one = ShardedPretrainer(cfg, MeshConfig(), devices=jax.devices()[:1])
-    many = ShardedPretrainer(cfg, MeshConfig(**mesh),
+    one = toys.one_device("toy-sdar", 4, 64, 2, want=False)
+    many = ShardedPretrainer(toys.config("toy-sdar"), MeshConfig(**mesh),
                              devices=jax.devices()[:4])
-    for _ in range(2):
-        assert float(many.step(rows)) == pytest.approx(float(one.step(rows)),
-                                                       rel=1e-5)
-    for name, value in one.moe_stats.items():
+    for want in one.losses:
+        assert float(many.step(one.rows)) == pytest.approx(want, rel=1e-5)
+    for name, value in one.stats.items():
         if name == "moe_buffer_rows":   # each device's own rung, together
             continue
         assert float(many.moe_stats[name]) == pytest.approx(float(value),

@@ -3,39 +3,32 @@ against something plain, at toy sizes on the CPU: the flash kernels' block
 mask against the written-out boolean mask; a routed layer that holds a part of
 its experts against ``perfbench/harness/families/sdar_moe.py::routed_part``
 (the shares add up to the whole layer; nothing is dropped at either extreme
-of the routing); the noising's statistics; ``head_dim`` and the per-head q/k
-norm; and every model the benchmark had before, unchanged.  The whole model
-against the whole reference is ``tests/test_sdar.py``."""
+of the routing); the noising's statistics.  ``head_dim`` and the per-head q/k
+norm, and every model the benchmark had before, unchanged, are
+``tests/test_sdar_programs.py``'s.  The whole model against the whole
+reference is ``tests/test_sdar.py``."""
 
 import dataclasses
+import functools
 import hashlib
-import json
-import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from perfbench.harness import families, reference
+import toys
 from perfbench.harness.families import sdar_moe
-from perfbench.harness.tokens import ZipfStream
-from ray_tpu.models.llama import LlamaConfig
 from ray_tpu.models import moe
-from ray_tpu.models.moe import (RoutedConfig, RoutedSwiGLU,
-                                capacity_ladder)
-from ray_tpu.models.pretrain import (init_params, loss_fn, make_optimizer,
-                                     noise_blocks, objective_fn, train_step)
+from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU, capacity_ladder
+from ray_tpu.models.pretrain import noise_blocks
 from ray_tpu.ops import attention
 from ray_tpu.ops.attention import (block_diffusion_mask, flash_attention,
                                    mha_reference)
 
-_TOYS = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.abspath(__file__))), "perfbench", "tests", "toy")
-with open(os.path.join(_TOYS, "toy-sdar.json")) as f:
-    # 64 wide, 4 / 2 heads of 32 (not 64 / 4), 8 experts of 32 of which 4 are
-    # held, top-2 renormalised, 512 of 2048 vocabulary rows, blocks of 4
-    TOY = json.load(f)
+# 64 wide, 4 / 2 heads of 32 (not 64 / 4), 8 experts of 32 of which 4 are
+# held, top-2 renormalised, 512 of 2048 vocabulary rows, blocks of 4
+TOY = toys.toy("toy-sdar")
 
 
 @pytest.mark.parametrize("length,block", [(160, 4), (160, 32), (1152, 32),
@@ -163,13 +156,15 @@ def _layer(held, n_experts=8, k=2):
         norm_topk_prob=True, dtype=jnp.float32, experts_held=held))
 
 
+@functools.lru_cache(maxsize=None)
 def _whole_layer_params():
+    """(inputs, a whole layer's parameters), made once: nobody writes into
+    them (under jit the forward that ``init`` traces is dead code)."""
     layer = _layer(None)
     x = jax.random.normal(jax.random.PRNGKey(2), (2, 44, 64), jnp.float32)
-    params = layer.init(jax.random.PRNGKey(0), x)["params"]
-    params["router"]["kernel"] = 2.0 * jax.random.normal(
-        jax.random.PRNGKey(3), (64, 8))
-    return x, params
+    params = jax.jit(layer.init)(jax.random.PRNGKey(0), x)["params"]
+    return x, dict(params, router={"kernel": 2.0 * jax.random.normal(
+        jax.random.PRNGKey(3), (64, 8))})
 
 
 def _share(params, first, count):
@@ -185,18 +180,21 @@ def test_d_the_shares_of_a_routed_layer_add_up_to_the_whole_layer():
     and their held rows sum to every assignment."""
     x, params = _whole_layer_params()
     config = dict(TOY, num_experts=8)
+    def plain(first):       # (jitted: op by op a layer is a hundred programs)
+        return jax.jit(lambda p, x: sdar_moe.routed_part(
+            x, p, config, first)[0])
+
     with jax.default_matmul_precision("highest"):
-        whole = sdar_moe.routed_part(x, params, config, 0)[0]
-        np.testing.assert_allclose(
-            _layer(None).apply({"params": params}, x), whole, atol=2e-5)
+        whole = plain(0)(params, x)
+        np.testing.assert_allclose(jax.jit(lambda p, x: _layer(None).apply(
+            {"params": p}, x))(params, x), whole, atol=2e-5)
         parts, rows = [], 0.0
         for first in range(0, 8, 2):
             share = _share(params, first, 2)
-            part, sown = _layer((first, 2)).apply(
-                {"params": share}, x, mutable=["intermediates"])
-            np.testing.assert_allclose(
-                part, sdar_moe.routed_part(x, share, config, first)[0],
-                atol=2e-5)
+            part, sown = jax.jit(lambda p, x: _layer((first, 2)).apply(
+                {"params": p}, x, mutable=["intermediates"]))(share, x)
+            np.testing.assert_allclose(part, plain(first)(share, x),
+                                       atol=2e-5)
             parts.append(part)
             rows += float(sown["intermediates"]["moe_rows_held"][0])
     np.testing.assert_allclose(sum(parts), whole, atol=5e-5)
@@ -221,11 +219,13 @@ def test_e_no_token_is_dropped_at_either_extreme(favoured, rows_held):
         return layer.apply({"params": p}, x, mutable=["intermediates"])
 
     with jax.default_matmul_precision("highest"):
-        got, sown = part(share, x)
-        want = sdar_moe.routed_part(x, share, config, 0)[0]
-        grads = jax.grad(lambda p: jnp.sum(jnp.sin(part(p, x)[0])))(share)
-        want_grads = jax.grad(lambda p: jnp.sum(jnp.sin(
-            sdar_moe.routed_part(x, p, config, 0)[0])))(share)
+        got, sown = jax.jit(part)(share, x)
+        want = jax.jit(lambda p: sdar_moe.routed_part(x, p, config, 0)[0])(
+            share)
+        grads = jax.jit(jax.grad(lambda p: jnp.sum(jnp.sin(
+            part(p, x)[0]))))(share)
+        want_grads = jax.jit(jax.grad(lambda p: jnp.sum(jnp.sin(
+            sdar_moe.routed_part(x, p, config, 0)[0]))))(share)
     np.testing.assert_allclose(got, want, atol=2e-5)
     assert float(sown["intermediates"]["moe_rows_held"][0]) == rows_held
     if not rows_held:
@@ -294,11 +294,13 @@ def test_k_every_edge_of_the_ladder_is_the_reference(n):
         return lambda p, x: jnp.sum(jnp.sin(of(p, x)))
 
     with jax.default_matmul_precision("highest"):
-        got, sown = part(share, x)
-        want = sdar_moe.routed_part(x, share, config, 0)[0]
-        grads = jax.grad(loss(lambda p, x: part(p, x)[0]), (0, 1))(share, x)
-        want_grads = jax.grad(loss(lambda p, x: sdar_moe.routed_part(
-            x, p, config, 0)[0]), (0, 1))(share, x)
+        got, sown = jax.jit(part)(share, x)
+        want = jax.jit(lambda p, x: sdar_moe.routed_part(
+            x, p, config, 0)[0])(share, x)
+        grads = jax.jit(jax.grad(loss(lambda p, x: part(p, x)[0]), (0, 1)))(
+            share, x)
+        want_grads = jax.jit(jax.grad(loss(lambda p, x: sdar_moe.routed_part(
+            x, p, config, 0)[0]), (0, 1)))(share, x)
     np.testing.assert_allclose(got, want, atol=2e-5)
     sown = sown["intermediates"]
     assert float(sown["moe_rows_held"][0]) == n
@@ -463,8 +465,8 @@ def test_p_a_held_layers_gradients_are_the_whole_layers_with_absent_gates_zero()
 
     mine = tuple(m[:n_held] for m in mats)
     with jax.default_matmul_precision("highest"):
-        got = jax.grad(held, (0, 1, 2))(x, weights, mine)
-        want = jax.grad(whole, (0, 1, 2))(x, weights, mine)
+        got = jax.jit(jax.grad(held, (0, 1, 2)))(x, weights, mine)
+        want = jax.jit(jax.grad(whole, (0, 1, 2)))(x, weights, mine)
     for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
         np.testing.assert_allclose(g, w, atol=2e-5, rtol=1e-5)
     assert float(jnp.max(jnp.abs(got[0]))) > 1e-3
@@ -518,124 +520,3 @@ def test_f_the_noising_masks_a_share_t_of_each_block_and_weighs_by_1_over_t():
                                                      (x_t, masked, weights)))
     other = noise_blocks(jax.random.fold_in(key, 1), ids, 32, 511, 0.001)
     assert float(jnp.mean(other[1] != masked)) > 0.2
-
-
-def test_h_head_dim_and_the_per_head_norm_against_a_plain_attention():
-    """A causal model whose ``head_dim`` is not ``d_model / n_head`` (32 at
-    64 / 4) with the per-head q/k norm: the projections are ``n_head *
-    head_dim`` wide, the norms' scales ``head_dim`` wide, and the layer equals
-    plain attention with the norm applied per head after the split; the
-    whole-projection norm (``qk_norm=True``) keeps its projection-wide
-    scale."""
-    from ray_tpu.models.llama import LlamaAttention
-
-    cfg = dataclasses.replace(
-        LlamaConfig.tiny(), head_dim=32, qk_norm="head", dtype=jnp.float32,
-        attention_impl="reference")
-    layer = LlamaAttention(cfg)
-    x = jax.random.normal(jax.random.PRNGKey(0), (2, 24, 64), jnp.float32)
-    p = layer.init(jax.random.PRNGKey(1), x, jnp.arange(24))["params"]
-    assert p["wq"]["kernel"].shape == (64, 4 * 32)
-    assert p["wk"]["kernel"].shape == (64, 2 * 32)
-    assert p["wo"]["kernel"].shape == (4 * 32, 64)
-    assert p["q_norm"]["scale"].shape == p["k_norm"]["scale"].shape == (32,)
-    for name in ("q_norm", "k_norm"):
-        p[name]["scale"] = 1.0 + 0.3 * jax.random.normal(
-            jax.random.PRNGKey(5), (32,))
-    with jax.default_matmul_precision("highest"):
-        got = layer.apply({"params": p}, x, jnp.arange(24))
-        q = reference.rope(reference.rms_norm(reference.heads(
-            x @ p["wq"]["kernel"], 4), p["q_norm"], cfg.rms_eps),
-            cfg.rope_theta)
-        k = reference.rope(reference.rms_norm(reference.heads(
-            x @ p["wk"]["kernel"], 2), p["k_norm"], cfg.rms_eps),
-            cfg.rope_theta)
-        v = reference.heads(x @ p["wv"]["kernel"], 2)
-        want = reference.merge(reference.causal_attention(
-            q.reshape(2, 2, 2, 24, 32), k, v)) @ p["wo"]["kernel"]
-    np.testing.assert_allclose(got, want, atol=2e-5)
-    whole = LlamaAttention(dataclasses.replace(cfg, qk_norm=True)).init(
-        jax.random.PRNGKey(1), x, jnp.arange(24))["params"]
-    assert whole["q_norm"]["scale"].shape == (128,)
-    assert whole["k_norm"]["scale"].shape == (64,)
-
-
-# loss of loss_fn on ZipfStream(vocab, seed=5).rows(2, 48) at PRNGKey(0)
-# weights, as float.hex(), and the parameter count, at the parent commit
-# (223ded3): (XLA attention, interpreted flash kernels)
-_AS_IT_WAS = {
-    "toy-gpt2": (173824, "0x1.a497d00000000p+2", "0x1.a49a560000000p+2"),
-    "toy-llama": (108736, "0x1.b2d00c0000000p+2", "0x1.b2c9600000000p+2"),
-    "toy-olmoe": (198208, "0x1.a3552e0000000p+2", "0x1.a35efe0000000p+2"),
-    "toy-granite": (175408, "0x1.8dc7500000000p+2", "0x1.8dc6cc0000000p+2"),
-}
-
-
-@pytest.mark.parametrize("name", sorted(_AS_IT_WAS))
-def test_i_every_model_the_benchmark_has_is_the_program_it_was(name):
-    """``head_dim``, the per-head norm, ``experts_held``, the objective and
-    the block mask come from the configuration: the toy of every family the
-    benchmark had before has the parameters it had and, bit for bit, the loss
-    it had at the parent commit, under XLA attention and under the
-    (interpreted) flash kernels; nothing of the new objective is in its step.
-    (The jaxpr text of all eight train steps equals the parent's character
-    for character: checked by hand in PR 31.)"""
-    with open(os.path.join(_TOYS, name + ".json")) as f:
-        config = json.load(f)
-    chips = 1 if "1" in config.get("cut_by_chips", {"1": 0}) else 4
-    n_params, *losses = _AS_IT_WAS[name]
-    batch = {k: jnp.asarray(v) for k, v in ZipfStream(
-        config["vocab_size"], seed=5).rows(2, 48).items()}
-    for impl, want in zip(("reference", "flash"), losses):
-        cfg = dataclasses.replace(
-            families.of(config).model_config(config, chips),
-            attention_impl=impl)
-        model, params = init_params(cfg)
-        assert sum(a.size for a in jax.tree_util.tree_leaves(params)) \
-            == n_params
-        assert float(loss_fn(model, params, batch)).hex() == want, impl
-    tx = make_optimizer()
-    step = jax.make_jaxpr(lambda s, b: train_step(model, tx, s, b))(
-        (params, tx.init(params)), batch)
-    text = str(step)
-    for absent in ("noise", "random_bits", "threefry", "bd_diagonal"):
-        assert absent not in text, absent
-    # a layer that holds all its experts has no capacity to choose: no
-    # branch outside the kernels (the toy OLMoE step's jaxpr equals the
-    # parent's character for character: checked by hand in PR 32)
-    found = {"wide": [], "switches": [], "loops": []}
-    _walk(step.jaxpr, found)
-    assert found["switches"] == [] and found["loops"] == [], name
-
-
-@pytest.mark.parametrize("impl,dtype,want", [
-    ("reference", None, "0x1.5c19680000000p+2"),
-    ("flash", None, "0x1.5c18e20000000p+2"),
-    ("reference", jnp.float32, "0x1.5c68860000000p+2"),
-    ("flash", jnp.float32, "0x1.5c68860000000p+2")])
-def test_m_the_sdar_toy_has_the_loss_it_had_with_the_whole_buffer(
-        impl, dtype, want):
-    """The toy's objective at ``PRNGKey(0)`` weights under the noise of
-    ``PRNGKey(0)``, on ``ZipfStream(held vocabulary, seed=5).rows(2, 48)``,
-    against an earlier commit's (6530a06: every layer passing over all
-    ``T * k`` rows): the order of a token's sum may change, the number may
-    not.  The flash row in the toy's own bf16 is PR 34's: a noised row's
-    output is rounded to bf16 once, from one softmax over all its keys, where
-    it was the kernel's bf16 result merged with the own squares' term in
-    float32 and rounded again (0x1.5c2ed6p+2 then, further from the XLA
-    row).  Both bf16 rows are PR 39's: the per-head norm of q and k is
-    applied in the rotation's float32 pass and no longer rounded to bf16
-    between the two (0x1.5bfd30p+2 and 0x1.5c0c4ep+2 before: each moved
-    towards the float32 rows).  In float32 nothing rounds: the flash kernels
-    give the XLA row's number, as they did at the parent (0x1.5c6888p+2) to
-    the last bit but one."""
-    cfg = dataclasses.replace(sdar_moe.model_config(TOY, 1),
-                              attention_impl=impl)
-    if dtype is not None:
-        cfg = dataclasses.replace(cfg, dtype=dtype)
-    model, params = init_params(cfg)
-    batch = {k: jnp.asarray(v) for k, v in ZipfStream(
-        cfg.vocab_size, seed=5).rows(2, 48).items()}
-    loss, stats = objective_fn(model, params, batch, jax.random.PRNGKey(0))[1]
-    assert float(loss) == pytest.approx(float.fromhex(want), rel=1e-6)
-    assert stats["moe_rows_held"] <= stats["moe_buffer_rows"] <= 2 * 96 * 2

@@ -30,8 +30,13 @@ def _getpid():
 
 
 @ray_tpu.remote
-def _watchdog_sleep(seconds):
-    time.sleep(seconds)
+def _watchdog_sleep(until_path):
+    """Sleeps until the test has made ``until_path`` (or for two minutes)."""
+    import os
+
+    deadline = time.monotonic() + 120.0
+    while not os.path.exists(until_path) and time.monotonic() < deadline:
+        time.sleep(0.1)
     return True
 
 
@@ -140,7 +145,8 @@ def test_async_actor_stack_lists_owning_task(ray_start_regular):
     ray_tpu.kill(a)
 
 
-def test_watchdog_flags_sleeping_task_then_clears(ray_start_regular):
+def test_watchdog_flags_sleeping_task_then_clears(ray_start_regular,
+                                                  tmp_path):
     """A task sleeping past RAY_TPU_HANG_THRESHOLD_S shows up in
     summarize_hangs with the one-shot stack attached, and drops out once it
     finishes (ISSUE 3 acceptance)."""
@@ -151,13 +157,19 @@ def test_watchdog_flags_sleeping_task_then_clears(ray_start_regular):
     state._nodelet_call(None, "set_env",
                         {"key": "RAY_TPU_HANG_WATCHDOG_INTERVAL_S",
                          "value": "0.5"})
+    # the task sleeps until the watchdog has been seen to flag it: what is
+    # asserted is the order (flagged while it runs, cleared once it is done),
+    # however long the host takes over either
+    done = tmp_path / "seen"
+    started = time.monotonic()
     try:
-        ref = _watchdog_sleep.remote(8.0)
+        ref = _watchdog_sleep.remote(str(done))
         tid = ref.task_id().hex()
         hang = _wait_for(
             lambda: next((h for h in state.summarize_hangs()
                           if h["task_id"] == tid), None),
-            timeout=30.0)
+            timeout=100.0)
+        print(f"flagged after {time.monotonic() - started:.1f} s")
         assert hang is not None, "watchdog never flagged the sleeping task"
         assert hang["name"] == "_watchdog_sleep"
         assert hang["elapsed_s"] > 1.0
@@ -165,13 +177,16 @@ def test_watchdog_flags_sleeping_task_then_clears(ray_start_regular):
         # the gauge rides the node's ordinary scrape
         text = state._nodelet_call(None, "get_metrics_text")
         assert "ray_tpu_suspected_hung_tasks" in text
+        done.touch()
         assert ray_tpu.get(ref) is True
         cleared = _wait_for(
             lambda: (all(h["task_id"] != tid
                          for h in state.summarize_hangs()) or None),
-            timeout=20.0)
+            timeout=60.0)
+        print(f"cleared after {time.monotonic() - started:.1f} s")
         assert cleared, "finished task is still listed as hung"
     finally:
+        done.touch()
         state._nodelet_call(None, "set_env",
                             {"key": "RAY_TPU_HANG_THRESHOLD_S", "value": ""})
         state._nodelet_call(None, "set_env",
