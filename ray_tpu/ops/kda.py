@@ -297,39 +297,46 @@ def _decays(gs, n_ref, s_ref):
 
 
 class _Inside:
-    """What one head's chunk is made of before the state comes into it, as
-    the backward needs it: the running sums ``G``, a level's ``E`` and scaled
-    operands, ``A`` and ``L``'s pairs.  ``q``, ``k``, ``g``: (C, d) float32;
-    ``beta``: (C, 1)."""
+    """What a head block's chunk is made of before the states come into it,
+    as the backward needs it, a list over the heads each: the running sums
+    ``G``, ``A`` and ``L``'s pairs and, a level, its ``E`` and scaled
+    operands; a step of every head before the next step of any.  ``qs``,
+    ``ks``, ``gs``: the heads' (C, d) float32."""
 
-    def __init__(self, q, k, g, beta, n_ref, m_ref, s_ref, dtype):
-        self.q, self.k, self.beta = q, k, beta
-        chunk = q.shape[0]
+    def __init__(self, qs, ks, gs, n_ref, m_ref, s_ref, dtype):
+        chunk = qs[0].shape[0]
         row = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
         col = lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
         self.eye = (row == col).astype(jnp.float32)
         self.strict = (row > col).astype(jnp.float32)
-        (self.G,), decays = _decays([g], n_ref, s_ref)
+        self.Gs, decays = _decays(gs, n_ref, s_ref)
         self.levels = []
-        Akk = jnp.zeros((chunk, chunk), jnp.float32)
-        Aqk = _dotl(q, k, _NT, dtype) * self.eye
-        for i, (E,) in enumerate(decays):
-            both, M = jnp.concatenate([q * E, k * E]), m_ref[i]
-            pairs = _dotl(both, both[chunk:], _NT, dtype)   # q.k over k.k
-            Aqk, Akk = Aqk + M * pairs[:chunk], Akk + M * pairs[chunk:]
-            self.levels.append((s_ref[i], E, both, M))
-        self.Akk, self.Aqk = Akk, Aqk
-        self.from_start = jnp.exp(self.G)
-        self.end = self.G[chunk - 1:chunk]                  # (1, d)
-        self.to_end = jnp.exp(self.end - self.G)
+        Akks = [jnp.zeros((chunk, chunk), jnp.float32) for _ in qs]
+        Aqks = [_dotl(q, k, _NT, dtype) * self.eye for q, k in zip(qs, ks)]
+        for i, Es in enumerate(decays):
+            M = m_ref[i]
+            boths = [jnp.concatenate([q * E, k * E])
+                     for q, k, E in zip(qs, ks, Es)]
+            pairs = [_dotl(both, both[chunk:], _NT, dtype)  # q.k over k.k
+                     for both in boths]
+            Aqks = [Aqk + M * p[:chunk] for Aqk, p in zip(Aqks, pairs)]
+            Akks = [Akk + M * p[chunk:] for Akk, p in zip(Akks, pairs)]
+            self.levels.append((s_ref[i], M, Es, boths))
+        self.Akks, self.Aqks = Akks, Aqks
+        self.from_start = [jnp.exp(G) for G in self.Gs]
+        self.ends = [G[chunk - 1:chunk] for G in self.Gs]   # (1, d)
+        self.to_end = [jnp.exp(end - G)
+                       for end, G in zip(self.ends, self.Gs)]
 
 
 # Mosaic issues a kernel's matmuls in the order they are written, and most of
 # these wait for the one before (a solve is a chain of ten).  What does not
-# wait for each other — the systems of a solve, the heads of a forward — is
-# therefore written a stage of all before the next stage of any: at the
-# cell's shape two solves one after the other take 13.76 ms a layer and in
-# step 9.91, a forward's four heads 6.36 and 4.30 (``PERF.md``, PR 56).
+# wait for each other — the systems of a solve, the heads of a forward or a
+# backward — is therefore written a stage of all before the next stage of
+# any: at the cell's shape two solves one after the other take 13.76 ms a
+# layer and in step 9.91, a forward's four heads 6.36 and 4.30 (``PERF.md``,
+# PR 56), a backward's 14.59 and 10.49 (PR 58).  ``tests/test_kda_scan.py``
+# holds the order by the bodies' jaxprs.
 
 
 def _kda_solve_kernel(k_ref, g_ref, b_ref, n_ref, m_ref, s_ref, inverse_ref,
@@ -420,7 +427,10 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
     maps turn the axis): ``dstate`` holds the cotangent of the states after
     the chunk.  The chunk's forward is made again from the state before it
     and from the forward's ``(I + L)^-1``: the levels, which the gradients
-    need, are made here; the solve's thirty passes a head are not."""
+    need, are made here; the solve's thirty passes a head are not.  A head's
+    chain — the levels, ``U``, ``dU``, ``drhs``, ``dL``, the levels'
+    gradients, ``dg``'s sums — waits for nothing of another head's: every
+    quantity is a list over the block's heads."""
     dtype = q_ref.dtype
     chunk = q_ref.shape[1]
 
@@ -428,63 +438,80 @@ def _kda_bwd_kernel(q_ref, k_ref, v_ref, g_ref, b_ref, n_ref, m_ref, s_ref,
     def _():
         dstate[...] = jnp.zeros_like(dstate)
 
-    lane = lax.broadcasted_iota(jnp.int32, (chunk, hb), 1)
-    dqs, dks, dvs, dgs = [], [], [], []
-    dbeta = jnp.zeros((chunk, hb), jnp.float32)
-    for h in range(hb):
-        at = slice(h * d, (h + 1) * d)
-        q, k, v, do = (r[0, :, at].astype(jnp.float32)
+    ats = [slice(h * d, (h + 1) * d) for h in range(hb)]
+    qs, ks, vs, dos = ([r[0, :, at].astype(jnp.float32) for at in ats]
                        for r in (q_ref, k_ref, v_ref, do_ref))
-        c = _Inside(q, k, g_ref[0, :, at], b_ref[0, 0, :, h:h + 1], n_ref,
-                    m_ref, s_ref, dtype)
-        beta = c.beta
-        inverse = inverse_ref[0, 0, :, h * chunk:(h + 1) * chunk]
-        S, dS = before_ref[0, 0, at, :], dstate[at, :]
-        kg, qg, kend = k * c.from_start, q * c.from_start, k * c.to_end
-        rest = v - _dotl(kg, S, _NT, dtype)
-        U = _dotf(inverse, beta * rest, _NN)
-        # the read-out and the state's update
-        dU = _dotl(c.Aqk, do, _TN, dtype) + _dotl(kend, dS, _NT, dtype)
-        dAqk = (c.strict + c.eye) * _dotl(do, U, _NT, dtype)
-        dqg = _dotl(do, S, _NN, dtype)
-        dkend = _dotl(U, dS, _NN, dtype)
-        e_end = jnp.exp(c.end)
-        dS_before = e_end * dS + _dotl(do, qg, _TN, dtype)
-        d_end = jnp.sum(e_end * S * dS, axis=0, keepdims=True) \
-            + jnp.sum(dkend * kend, axis=0, keepdims=True)
-        # the solve
-        drhs = _dotf(inverse, dU, _TN)
-        dL = -c.strict * _dotf(drhs, U, _NT)
-        db = jnp.sum(drhs * rest, axis=1, keepdims=True) \
-            + jnp.sum(dL * c.Akk, axis=1, keepdims=True)
-        dbeta = jnp.where(lane == h, db, dbeta)
-        drest = beta * drhs
-        dkg = -_dotl(drest, S, _NN, dtype)
-        dS_before = dS_before - _dotl(drest, kg, _TN, dtype)
-        dAkk = beta * dL
-        # the operands through the running sums from the chunk's start and to
-        # its end, and the diagonal
-        on_diagonal = jnp.sum(c.eye * dAqk, axis=1, keepdims=True)
-        dq = dqg * c.from_start + on_diagonal * k
-        dk = dkg * c.from_start + dkend * c.to_end + on_diagonal * q
-        dsums = [dkg * kg + dqg * qg - dkend * kend]
-        for sign, E, both, M in c.levels:
-            # the level's q.k pairs over its k.k pairs, as the forward's
-            dpairs = jnp.concatenate([M * dAqk, M * dAkk])
-            by_rows = _dotl(dpairs, both[chunk:], _NN, dtype)
-            dKe = by_rows[chunk:] + _dotl(dpairs, both, _TN, dtype)
-            dQe = by_rows[:chunk]
-            dq, dk = dq + dQe * E, dk + dKe * E
-            dsums.append(sign * E * (dQe * q + dKe * k))
-        dg = _sums(n_ref[...], jnp.concatenate(dsums), _TN) + d_end
-        dstate[at, :] = dS_before
-        dqs.append(dq.astype(dq_ref.dtype))
-        dks.append(dk.astype(dk_ref.dtype))
-        dvs.append(drest.astype(dv_ref.dtype))
-        dgs.append(dg)
-    for ref, parts in ((dq_ref, dqs), (dk_ref, dks), (dv_ref, dvs),
+    c = _Inside(qs, ks, [g_ref[0, :, at] for at in ats], n_ref, m_ref, s_ref,
+                dtype)
+    betas = [b_ref[0, 0, :, h:h + 1] for h in range(hb)]
+    inverses = [inverse_ref[0, 0, :, h * chunk:(h + 1) * chunk]
+                for h in range(hb)]
+    Ss = [before_ref[0, 0, at, :] for at in ats]
+    dSs = [dstate[at, :] for at in ats]
+    kgs = [k * e for k, e in zip(ks, c.from_start)]
+    qgs = [q * e for q, e in zip(qs, c.from_start)]
+    kends = [k * e for k, e in zip(ks, c.to_end)]
+    rests = [v - _dotl(kg, S, _NT, dtype) for v, kg, S in zip(vs, kgs, Ss)]
+    Us = [_dotf(inverse, beta * rest, _NN)
+          for inverse, beta, rest in zip(inverses, betas, rests)]
+    # the read-out and the state's update
+    dUs = [_dotl(Aqk, do, _TN, dtype) + _dotl(kend, dS, _NT, dtype)
+           for Aqk, do, kend, dS in zip(c.Aqks, dos, kends, dSs)]
+    dAqks = [(c.strict + c.eye) * _dotl(do, U, _NT, dtype)
+             for do, U in zip(dos, Us)]
+    dqgs = [_dotl(do, S, _NN, dtype) for do, S in zip(dos, Ss)]
+    dkends = [_dotl(U, dS, _NN, dtype) for U, dS in zip(Us, dSs)]
+    e_ends = [jnp.exp(end) for end in c.ends]
+    dS_before = [e_end * dS + _dotl(do, qg, _TN, dtype)
+                 for e_end, dS, do, qg in zip(e_ends, dSs, dos, qgs)]
+    d_ends = [jnp.sum(e_end * S * dS, axis=0, keepdims=True)
+              + jnp.sum(dkend * kend, axis=0, keepdims=True)
+              for e_end, S, dS, dkend, kend
+              in zip(e_ends, Ss, dSs, dkends, kends)]
+    # the solve
+    drhs = [_dotf(inverse, dU, _TN) for inverse, dU in zip(inverses, dUs)]
+    dLs = [-c.strict * _dotf(dr, U, _NT) for dr, U in zip(drhs, Us)]
+    dbeta = jnp.zeros((chunk, hb), jnp.float32)
+    lane = lax.broadcasted_iota(jnp.int32, (chunk, hb), 1)
+    for h, (dr, rest, dL, Akk) in enumerate(zip(drhs, rests, dLs, c.Akks)):
+        dbeta = jnp.where(
+            lane == h, jnp.sum(dr * rest, axis=1, keepdims=True)
+            + jnp.sum(dL * Akk, axis=1, keepdims=True), dbeta)
+    drests = [beta * dr for beta, dr in zip(betas, drhs)]
+    dkgs = [-_dotl(drest, S, _NN, dtype) for drest, S in zip(drests, Ss)]
+    dS_before = [dS - _dotl(drest, kg, _TN, dtype)
+                 for dS, drest, kg in zip(dS_before, drests, kgs)]
+    dAkks = [beta * dL for beta, dL in zip(betas, dLs)]
+    # the operands through the running sums from the chunk's start and to its
+    # end, and the diagonal
+    on_diagonal = [jnp.sum(c.eye * dAqk, axis=1, keepdims=True)
+                   for dAqk in dAqks]
+    dqs = [dqg * e + on * k
+           for dqg, e, on, k in zip(dqgs, c.from_start, on_diagonal, ks)]
+    dks = [dkg * e + dkend * to + on * q for dkg, e, dkend, to, on, q
+           in zip(dkgs, c.from_start, dkends, c.to_end, on_diagonal, qs)]
+    dsums = [[dkg * kg + dqg * qg - dkend * kend] for dkg, kg, dqg, qg, dkend,
+             kend in zip(dkgs, kgs, dqgs, qgs, dkends, kends)]
+    for sign, M, Es, boths in c.levels:
+        # the level's q.k pairs over its k.k pairs, as the forward's
+        dpairs = [jnp.concatenate([M * dAqk, M * dAkk])
+                  for dAqk, dAkk in zip(dAqks, dAkks)]
+        by_rows = [_dotl(dp, both[chunk:], _NN, dtype)
+                   for dp, both in zip(dpairs, boths)]
+        dKes = [by[chunk:] + _dotl(dp, both, _TN, dtype)
+                for by, dp, both in zip(by_rows, dpairs, boths)]
+        dQes = [by[:chunk] for by in by_rows]
+        dqs = [dq + dQe * E for dq, dQe, E in zip(dqs, dQes, Es)]
+        dks = [dk + dKe * E for dk, dKe, E in zip(dks, dKes, Es)]
+        for dsum, E, dQe, q, dKe, k in zip(dsums, Es, dQes, qs, dKes, ks):
+            dsum.append(sign * E * (dQe * q + dKe * k))
+    dgs = [_sums(n_ref[...], jnp.concatenate(dsum), _TN) + d_end
+           for dsum, d_end in zip(dsums, d_ends)]
+    for at, dS in zip(ats, dS_before):
+        dstate[at, :] = dS
+    for ref, parts in ((dq_ref, dqs), (dk_ref, dks), (dv_ref, drests),
                        (dg_ref, dgs)):
-        ref[0] = _beside(parts)
+        ref[0] = _beside([part.astype(ref.dtype) for part in parts])
     db_ref[0, 0] = dbeta
 
 

@@ -19,6 +19,7 @@ import functools
 import hashlib
 
 import jax
+import jax.extend
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -169,10 +170,11 @@ def test_a_backward_reads_the_inverse_it_is_given(heads):
     o, before = kda._forward(q, k, v, g, beta, inverse, chunk)
 
     def block(b, at):   # the heads' inverses of one chunk, side by side
-        Ls = [c.beta * c.Akk for c in (kda._Inside(
-            *(t[b, at:at + chunk, h * d:(h + 1) * d] for t in (q, k, g)),
-            beta[b, at:at + chunk, h:h + 1], *tree, q.dtype)
-            for h in range(heads))]
+        inside = kda._Inside(
+            *([t[b, at:at + chunk, h * d:(h + 1) * d] for h in range(heads)]
+              for t in (q, k, g)), *tree, q.dtype)
+        Ls = [beta[b, at:at + chunk, h:h + 1] * Akk
+              for h, Akk in enumerate(inside.Akks)]
         # two heads' systems down the diagonal of one matrix
         Xs = kda._unit_lower_inverses(
             [jax.scipy.linalg.block_diag(*Ls[i:i + 2])
@@ -211,6 +213,51 @@ def test_a_forward_reads_the_inverse_it_is_given(heads):
         np.asarray(wrong[:, 0]), np.asarray(o[:, 0]))   # nothing
     assert float(jnp.max(jnp.abs(wrong - o))) > 1e-2 * float(
         jnp.max(jnp.abs(o)))
+
+
+def _matmuls_between(f, *args):
+    """The body of the one Mosaic call in ``f(*args)``, as its jaxpr: (its
+    ``dot_general``s, for every one that reads another's result — through
+    anything but a third — how many stand between the two)."""
+    def calls(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                yield eqn
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                yield from calls(sub)
+
+    (call,) = calls(jax.make_jaxpr(f)(*args).jaxpr)
+    made_by, count, between = {}, 0, []     # a value -> the matmuls behind it
+    for eqn in call.params["jaxpr"].eqns:
+        read = set().union(*(made_by.get(v, ()) for v in eqn.invars
+                             if not isinstance(v, jax.extend.core.Literal)))
+        if eqn.primitive.name == "dot_general":
+            between += [count - at - 1 for at in read]
+            read, count = {count}, count + 1
+        made_by.update(dict.fromkeys(eqn.outvars, read))
+    return count, between
+
+
+@pytest.mark.parametrize("kernel", ["kda_solve", "kda_fwd", "kda_bwd"])
+def test_a_kernels_matmuls_come_a_stage_of_every_head_together(kernel):
+    """Mosaic issues a body's matmuls in the order of its text, so what the
+    kernels are timed at (``ops/kda.py``, above ``_kda_solve_kernel``) is the
+    order itself: a matmul that waits for another's result has the other
+    heads' matmuls of that stage before it — three of them at a head block of
+    four, as at the solve's two chunks of two pairs of heads.  A body that
+    walks a head at a time reads 0 here."""
+    heads, d, chunk, seq = 4, 16, 32, 64
+    q, k, v, g, beta = operands(seq, 1, heads, d)
+    inverse = jnp.zeros((1, 1, seq, heads * chunk))
+    before = jnp.zeros((1, seq // chunk, heads * d, d))
+    f, args = {
+        "kda_solve": (kda._solve, (k, g, beta)),
+        "kda_fwd": (kda._forward, (q, k, v, g, beta, inverse)),
+        "kda_bwd": (kda._backward, (q, k, v, g, beta, before, inverse, v)),
+    }[kernel]
+    count, between = _matmuls_between(lambda *a: f(*a, chunk), *args)
+    assert count >= 50 and len(between) >= count    # the chains are seen
+    assert min(between) >= heads - 1
 
 
 # sha256 of ``kda_scan``'s outputs and five gradients, float32 bytes one

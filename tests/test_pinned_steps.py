@@ -21,7 +21,7 @@ PINNED = {
     "toy-evabyte": "2d776bb3a348b1a6d357fbc10d09c972ef6cd73a1afd586ac3a1f9ccc3c2de59",
     "toy-gpt2": "ba11271144fed4ca835af77562ca30fbb9989cac73bf82a195c1a4e4d5921693",
     "toy-granite": "7a646a83d84178cf45adcbe76b26d3fedef41d6e0ef427a125c34ab1cbddb34d",
-    "toy-kimi-linear": "343430c73c40c47cba973435f1b7e7581c1d40d21743ca9ce69b68246ef78026",
+    "toy-kimi-linear": "b4a51f9d9b4c98fb00b2eb8c354abd1c4b09b4b16e799c39b638955ed4de2eca",
     "toy-kimi-vl": "5a017906206e0d13c1f718b10c38f6a80c6893aafaa08d252665c2a7a745d148",
     "toy-laguna": "4d7f074a49c3ae1f68e4b5e66cd1af93e583972f0ae498461edcd62e2babffc0",
     "toy-lfm2": "ad07a2bf3eae0808f1e15c23cd22a6c700ac2fbf8f402d9cf0bcc4b9ea0af5d6",
