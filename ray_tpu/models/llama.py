@@ -88,6 +88,16 @@ of the stream a LayerNorm, ``attn_bias`` gives the attention's projections
 biases, and ``layer_depths`` names the layers' places in a deeper model they
 are cut from (Phi-4-mini-flash-reasoning, SambaY, is the block with these,
 without rotation and with the head tied).
+A kind of ``rope_tables`` may name no table (``None``): its layers turn
+nothing while another kind's turn; ``router_before_attention`` hands the
+routed layer the block's first norm's output as what its router reads
+(``models/moe.py``'s ``router_input``), so that each token's experts are a
+function of the attention's own input and known before attention runs, the
+experts still reading the second norm's; and ``expert_activation="relu"``
+gates the routed experts by a ReLU (ReGLU) (SmallThinker-21BA3B is the block
+with these: whole-row layers without rotation beside layers under a window
+of 4,096 with it, 28 query heads over 4 key/value heads, 64 softmax-routed
+experts of which a chip holds a part).
 Every such field at its default leaves the program the dense Llama it was.
 ``remat`` recomputes each block from its input in the backward; what
 ``remat_policy="full"`` keeps beside that input is each attention layer's
@@ -212,8 +222,9 @@ class LlamaConfig:
     # a layer's query heads, one entry a layer; empty: n_head in every layer
     n_head_per_layer: Tuple[int, ...] = ()
     # (layer kind, RopeTable) pairs: the rotary table of the attention layers
-    # of that kind; a kind without one rotates the whole head by rope_theta
-    rope_tables: Tuple[Tuple[str, RopeTable], ...] = ()
+    # of that kind, None for a kind that turns nothing; a kind that is not
+    # named rotates the whole head by rope_theta
+    rope_tables: Tuple[Tuple[str, Optional[RopeTable]], ...] = ()
     attn_gate: bool = False          # a sigmoid gate a head on the attention's output
     # each layer's feed-forward, "dense" or "sparse" (the routed experts), one
     # entry a layer; empty: by moe_every
@@ -261,6 +272,10 @@ class LlamaConfig:
     # scan's output, which the "gmu" layers after it read; an attention layer
     # its keys and values, which the "cross_attention" layers after it read
     producers: Tuple[int, ...] = ()
+    # the routed layers' routers read the block's first norm's output, the
+    # attention's own input, and not the second's (the experts still do)
+    router_before_attention: bool = False
+    expert_activation: str = "silu"  # or "relu": the routed experts' gate
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -493,9 +508,10 @@ class LlamaAttention(nn.Module):
         cfg = self.config
         B, S, E = x.shape
         H, KV = self.n_head or cfg.n_head, cfg.n_kv_head
-        # the kind's own rotary table, or the whole head turned by rope_theta
-        table = dict(cfg.rope_tables).get(self.kind) or (
-            RopeTable(theta=cfg.rope_theta) if cfg.rope else None)
+        # the kind's own rotary table (None: it turns nothing), or the whole
+        # head turned by rope_theta
+        table = dict(cfg.rope_tables).get(
+            self.kind, RopeTable(theta=cfg.rope_theta) if cfg.rope else None)
         D = cfg.head_dim or E // H
         assert H % KV == 0, "n_head must be a multiple of n_kv_head"
         q = nn.Dense(H * D, use_bias=False, dtype=cfg.dtype, name="wq")(x)
@@ -794,7 +810,7 @@ class LlamaBlock(nn.Module):
                 branch = branch * cfg.residual_multiplier
             return x + branch
 
-        y = rms_norm(cfg, "attn_norm")(x)
+        y = attn_in = rms_norm(cfg, "attn_norm")(x)
         if self.mixer == "mamba":
             x = add(x, Mamba2Mixer(cfg, name="mamba")(y))
         elif self.mixer == "conv":
@@ -831,7 +847,9 @@ class LlamaBlock(nn.Module):
                 routed_scale=cfg.routed_scale,
                 d_shared=cfg.d_shared_expert,
                 selection_bias=cfg.router_selection_bias,
-                norm_topk_eps=cfg.norm_topk_eps), name="moe")(y))
+                norm_topk_eps=cfg.norm_topk_eps,
+                activation=cfg.expert_activation), name="moe")(
+                    y, attn_in if cfg.router_before_attention else None))
         else:
             x = add(x, SwiGLU(cfg, name="mlp")(y))
         return (x, handed) if self.hands_on else x

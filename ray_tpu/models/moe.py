@@ -65,7 +65,11 @@ auxiliary-loss-free selection; LFM2's ``use_expert_bias``).  It is state, not
 a weight: no gradient reaches it, the train step hands it on as it came
 (``models/pretrain.py``), and what would move it by the experts' load is a
 job's rule that this layer does not have.  ``norm_topk_eps`` is added to the
-sum the chosen scores are divided by.
+sum the chosen scores are divided by.  ``activation`` names what gates an
+expert's hidden units: ``"silu"``, or ``"relu"`` (ReGLU, ``relu(gate) * up``:
+SmallThinker's experts), whose derivative the held loop's backward writes
+out.  The router may read another tensor than the experts do
+(``RoutedSwiGLU.__call__``'s ``router_input``).
 
 ``MoEMlpBlock`` — the older GShard / Switch form, wired into GPT-2 only
 (``GPT2Config.moe_every``): top-k routing as DENSE dispatch / combine einsums
@@ -222,6 +226,26 @@ class RoutedConfig:
     # scores for the choice of the ``top_k`` alone: state, not a weight
     selection_bias: bool = False
     norm_topk_eps: float = 0.0      # + the chosen scores' sum it divides by
+    # what gates an expert's hidden units: "silu" (SwiGLU) or "relu" (ReGLU:
+    # ``relu(gate) * up``, exact zeros where the gate is not positive)
+    activation: str = "silu"
+
+
+def _gated(a, activation: str):
+    """``act(a)``: what an expert's ``up`` projection is multiplied by."""
+    if activation not in ("silu", "relu"):
+        raise ValueError(f"unknown activation {activation!r} (expected "
+                         "'silu' or 'relu')")
+    return jax.nn.silu(a) if activation == "silu" else jax.nn.relu(a)
+
+
+def _glu_bwd(a, b, d_h, activation: str):
+    """``(d_a, d_b)`` of ``_gated(a) * b`` under the cotangent ``d_h``."""
+    if activation == "relu":
+        # written out: the gate passes where it is positive (0 at 0, as
+        # ``jax.nn.relu``'s own rule has it), ``up`` takes the gated units
+        return jnp.where(a > 0, d_h * b, 0), d_h * jax.nn.relu(a)
+    return jax.vjp(lambda a, b: jax.nn.silu(a) * b, a, b)[1](d_h)
 
 
 def _even_tile(width: int, most: int) -> int:
@@ -364,7 +388,7 @@ def routed_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig):
                         dtype=jnp.int32)
         rows = _rows_to_expert_order(x, order, inverse, k)
     with jax.named_scope("experts"):
-        h = jax.nn.silu(grouped_matmul(rows, gate, sizes)) \
+        h = _gated(grouped_matmul(rows, gate, sizes), cfg.activation) \
             * grouped_matmul(rows, up, sizes)
         rows = grouped_matmul(h, down, sizes)
     with jax.named_scope("combine"):
@@ -442,17 +466,18 @@ def _beside(scope: str, name: str):
     return jax.named_scope(f"{scope}/{name}" if scope else name)
 
 
-def _piece_forward(x, weights, route: _Route, i, c: int, k: int, scope: str):
+def _piece_forward(x, weights, route: _Route, i, c: int, k: int, scope: str,
+                   activation: str):
     """The piece's rows through their experts: the token rows gathered, the
-    three grouped matmuls over ``c`` rows, ``silu * up``.  Rows past the live
-    ones belong to no group: the kernels leave them unwritten."""
+    three grouped matmuls over ``c`` rows, ``act(gate) * up``.  Rows past the
+    live ones belong to no group: the kernels leave them unwritten."""
     gate, up, down = weights
     piece = _piece(route, i, c)
     with _beside(scope, "dispatch"):
         rows = x[piece.slots // k]
     with _beside(scope, "experts"):
         a, b = _gmm(rows, gate, piece.sizes), _gmm(rows, up, piece.sizes)
-        h = jax.nn.silu(a) * b
+        h = _gated(a, activation) * b
         return piece, rows, (a, b), h, _gmm(h, down, piece.sizes)
 
 
@@ -604,16 +629,16 @@ def _n_pieces(route: _Route, c: int):
 # every piece's.  So the held experts' part has a rule of its own, which
 # saves what it was given and runs the loop again in its backward: a piece's
 # forward recomputed, then transposed.
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6))
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5, 6, 7))
 def _through_held(x, gates, weights, route: _Route, c: int, k: int,
-                  scope: str):
+                  scope: str, activation: str = "silu"):
     """(T, D) tokens -> (T, D): each token's rows through the held experts,
     times their gate weights (``gates`` (T * k,), by assignment), added in
     float32.  ``c`` rows at a time, as many times as the rows that came
     need."""
     def body(i, acc):
         piece, _, _, _, out = _piece_forward(x, weights, route, i, c, k,
-                                             scope)
+                                             scope, activation)
         with _beside(scope, "combine"):
             return _onto_tokens(acc, out, gates, piece, k)
 
@@ -622,12 +647,12 @@ def _through_held(x, gates, weights, route: _Route, c: int, k: int,
         jnp.zeros(x.shape, jnp.float32)).astype(x.dtype)
 
 
-def _through_held_fwd(x, gates, weights, route, c, k, scope):
-    return (_through_held(x, gates, weights, route, c, k, scope),
+def _through_held_fwd(x, gates, weights, route, c, k, scope, activation):
+    return (_through_held(x, gates, weights, route, c, k, scope, activation),
             (x, gates, weights, route))
 
 
-def _through_held_bwd(c, k, scope, res, g):
+def _through_held_bwd(c, k, scope, activation, res, g):
     x, gates, weights, route = res
     gate, up, down = weights
     n_held = gate.shape[0]
@@ -635,7 +660,7 @@ def _through_held_bwd(c, k, scope, res, g):
     def body(i, carry):
         dx, dgates, (dgate, dup, ddown) = carry
         piece, rows, (a, b), h, out = _piece_forward(x, weights, route, i, c,
-                                                     k, scope)
+                                                     k, scope, activation)
         with _beside(scope, "combine"):
             # the transpose of the weighted sum: a live row's gradient is its
             # gate times its token's ``g``, a gate's its row times ``g``
@@ -652,7 +677,7 @@ def _through_held_bwd(c, k, scope, res, g):
             d_h = _gmm(d_out, down, piece.sizes, transpose_rhs=True)
             ddown = _tgmm(h, d_out, piece.sizes, down.dtype, n_held,
                           onto=ddown)
-            d_a, d_b = jax.vjp(lambda a, b: jax.nn.silu(a) * b, a, b)[1](d_h)
+            d_a, d_b = _glu_bwd(a, b, d_h, activation)
             d_rows = _gmm(d_a, gate, piece.sizes, transpose_rhs=True) \
                 + _gmm(d_b, up, piece.sizes, transpose_rhs=True)
             dgate = _tgmm(rows, d_a, piece.sizes, gate.dtype, n_held,
@@ -679,8 +704,8 @@ def held_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig,
     (``cfg.experts_held``) -> (their part of the result, the rows the layer
     ran at).  The assignments are counted and placed at ``T * k``, as
     indices; everything as wide as the model or an expert — the gather of
-    token rows, the grouped matmuls, ``silu * up``, the weighted sum into the
-    tokens — runs on pieces of ``capacity_ladder``'s first rung, the balance
+    token rows, the grouped matmuls, ``act(gate) * up``, the weighted sum into
+    the tokens — runs on pieces of ``capacity_ladder``'s first rung, the balance
     share, in a loop on the device that makes as many trips as the rows that
     came need: the layer runs at the smallest rung that holds them."""
     lead, d = x.shape[:-1], x.shape[-1]
@@ -695,7 +720,7 @@ def held_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig,
         ladder = capacity_ladder(flat.shape[0], n_held, cfg.n_experts)
         route = _route(flat, n_held, ladder[-1])
     out = _through_held(x, weights.reshape(-1), (gate, up, down), route,
-                        ladder[0], k, scope)
+                        ladder[0], k, scope, cfg.activation)
     return (out.reshape(*lead, d),
             (_n_pieces(route, ladder[0]) * ladder[0]).astype(
                 jnp.float32).reshape((1,) * len(lead) + (1,)))
@@ -778,12 +803,18 @@ balance), ``moe_z`` (mean of ``logsumexp(router logits) ** 2``) and
     over all ``n_experts``; and, where the layer holds a part of them,
     ``moe_rows_held``: the assignments its own experts received, and
     ``moe_buffer_rows``: the capacity the layer ran at for them (over a mesh,
-    the devices' capacities together)."""
+    the devices' capacities together).
+
+    ``router_input``, where given, is what the router reads in place of
+    ``x`` — the logits, the scores, the choice, the weights and the counters
+    above all come from it — and the experts still take ``x``: a block that
+    routes from its attention's input (``LlamaConfig.router_before_attention``)
+    knows each token's experts before attention has run."""
 
     config: RoutedConfig
 
     @nn.compact
-    def __call__(self, x):
+    def __call__(self, x, router_input=None):
         from ray_tpu.parallel.mesh import ambient_mesh
 
         cfg = self.config
@@ -801,7 +832,9 @@ balance), ``moe_z`` (mean of ``logsumexp(router logits) ** 2``) and
             # experts a token gets
             logits = nn.Dense(n_experts, use_bias=False, dtype=jnp.float32,
                               precision=jax.lax.Precision.HIGHEST,
-                              name="router")(x.astype(jnp.float32))
+                              name="router")(
+                (x if router_input is None else router_input).astype(
+                    jnp.float32))
             if cfg.scoring == "softmax":
                 probs = shares = jax.nn.softmax(logits, axis=-1)
             elif cfg.scoring == "sigmoid":

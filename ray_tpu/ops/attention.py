@@ -1360,6 +1360,13 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
     # the heads of a key-side gradient, and of a grid step's dQ
     h_k, whole = h // rep if t.kv_grid else h, t._replace(
         group=n * rep if t.kv_grid else n)
+    if rep > 1:     # once a traced shape: which grid the group's backward took
+        logger.debug(
+            "flash backward: %d query heads a key/value head, %d queries: %s "
+            "(vmem_limit_bytes %d of %d)", rep, s_q_pad,
+            "the key/value-head grid, the group's dQ in VMEM" if t.kv_grid
+            else "a gradient a query head, summed beside the kernel",
+            _bwd_vmem_bytes(whole.group * s_q_pad, d, q.dtype), _VMEM_BYTES)
     q_is, k_is, v_is, o_is = (c is not None for c in lay[1:5])
 
     def row(x, fill):

@@ -200,6 +200,18 @@ def _build(case: str, compile_: bool) -> dict:
             "layer", False, True)
         assert (config.d_model, config.n_head, config.n_kv_head,
                 config.sliding_window, seq) == (2560, 40, 20, 512, 16384)
+    elif case == "smallthinker":
+        mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+        (config, seq), rows = _cell("smallthinker-s16k-1chip"), 1
+        assert config.experts_held == (0, 16) and config.n_experts == 64
+        assert config.layer_types == ("full_attention",) \
+            + ("sliding_attention",) * 3
+        assert dict(config.rope_tables)["full_attention"] is None
+        assert dict(config.rope_tables)["sliding_attention"].theta == 1.5e6
+        assert config.router_before_attention
+        assert config.expert_activation == "relu"
+        assert (config.d_model, config.n_head, config.n_kv_head,
+                config.sliding_window, seq) == (2560, 28, 4, 4096, 16384)
     elif case == "granite":
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
         (config, seq), rows = _cell("granite-h-s8k-1chip"), 1
@@ -271,10 +283,10 @@ def _flash_fwd_calls(jaxpr) -> int:
 
 def _build_flash(case: str, device) -> dict:
     """In the child: compile the flash kernels alone, forward and backward,
-    for one chip at ``flash_s<seq>_d<head width>[_g<group>]`` in bf16 over 32
-    heads, the long cells' count (the kernel's need grows a little with it);
-    k and v at 32 / ``group`` heads.  ``dk_heads``: the heads of the dK the
-    backward kernel writes."""
+    for one chip at ``flash_s<seq>_d<head width>[_g<group>[_h<heads>[_w<window>]]]``
+    in bf16 over 32 heads, the long cells' count (the kernel's need grows a
+    little with it), or ``heads``; k and v at ``heads / group`` heads.
+    ``dk_heads``: the heads of the dK the backward kernel writes."""
     import re
 
     import jax
@@ -283,18 +295,21 @@ def _build_flash(case: str, device) -> dict:
 
     from ray_tpu.ops.attention import flash_attention
 
-    seq, d, rep = (int(part[1:]) for part in (case + "_g1").split("_")[1:4])
+    given = {part[0]: int(part[1:]) for part in case.split("_")[1:]}
+    seq, d, rep, h, window = (given.get(key, default) for key, default in (
+        ("s", 0), ("d", 0), ("g", 1), ("h", 32), ("w", 0)))
 
     def shape(heads):
         return jax.ShapeDtypeStruct((1, heads, seq, d), jnp.bfloat16,
                                     sharding=SingleDeviceSharding(device))
 
     def grads(q, k, v, g):
-        return jax.vjp(flash_attention, q, k, v)[1](g)
+        return jax.vjp(functools.partial(flash_attention, window=window),
+                       q, k, v)[1](g)
 
     try:
-        text = jax.jit(grads).lower(shape(32), shape(32 // rep),
-                                    shape(32 // rep), shape(32)
+        text = jax.jit(grads).lower(shape(h), shape(h // rep),
+                                    shape(h // rep), shape(h)
                                     ).compile().as_text()
     except Exception as e:  # what Mosaic or the TPU compiler refuses
         return {"case": case, "refused": str(e)[:600]}
@@ -713,6 +728,25 @@ def test_flash_backward_compiles_with_its_whole_sequence_dq_in_vmem():
         "flash_s16384_d128_g8": 32, "flash_s16384_d64_g2": 16}, rows
 
 
+def test_flash_backward_holds_a_group_of_sevens_dq_at_the_whole_vmem():
+    """Tier-1, a few seconds a shape: SmallThinker's 28 query heads over 4
+    key/value heads at 16,384 positions.  A group of seven's float32 dQ and
+    its output block are 7 x 16,384 x 128 x 8 B = 112 MiB, which with the 16
+    MiB scope is the v5e's 128 MiB exactly: ``_bwd_vmem_bytes``' criterion
+    passes with nothing to spare, the call asks Mosaic for the whole VMEM,
+    and Mosaic takes it, causal and under the band of 4,096 — dK is written
+    at the four key/value heads, no gradient a query head is summed beside
+    the kernel."""
+    from ray_tpu.ops.attention import _VMEM_BYTES, _bwd_vmem_bytes
+
+    assert _bwd_vmem_bytes(7 * 16384, 128, "bfloat16") == _VMEM_BYTES
+    rows = _child(["flash_s16384_d128_g7_h28", "flash_s16384_d128_g7_h28_w4096"],
+                  compile_=True)
+    assert {case: row.get("dk_heads") for case, row in rows.items()} == {
+        "flash_s16384_d128_g7_h28": 4,
+        "flash_s16384_d128_g7_h28_w4096": 4}, rows
+
+
 def test_attention_prelude_moves_no_float32_copy_of_the_queries():
     """Tier-1, a few seconds a shape: over the projection and the transpose
     to ``(B, H, S, D)``, norm and rotation (``models/llama.py::apply_rope``)
@@ -1110,6 +1144,39 @@ def test_phi4_flash_step_compiles_and_fits_the_chip():
     # a Mamba-1 layer: the scan's forward, again under remat, and its
     # backward; an attention layer: two flash forwards and two backwards
     assert row["tpu_custom_calls"] == 2 * 3 + 3 * 4, row
+    assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
+
+
+def test_smallthinker_step_lowers_for_one_v5e_chip():
+    """Tier-1: the one-chip step of SmallThinker-21BA3B-Instruct at published
+    widths (published layers 0 to 3: a whole-row layer without rotation and
+    three under a window of 4,096 with RoPE, 28 query heads over 4, the
+    router reading the block's input, 16 of 64 ReLU-gated experts held, one
+    row of 16,384) lowers for the TPU with its Mosaic kernels in it: the flash
+    pair once a layer and the held experts' grouped matmuls and sums into
+    tokens, and no other."""
+    row = _child(["smallthinker"], compile_=False)["smallthinker"]
+    kernels = row["lowered_kernels"]
+    assert kernels.pop("kernel") > 0
+    assert kernels == {"flash_fwd": 4, "flash_bwd": 4,
+                       "onto_tokens": 2}, kernels
+    assert row["flash_fwd_calls"] == 4, row
+
+
+@pytest.mark.slow
+def test_smallthinker_step_compiles_and_fits_the_chip():
+    """The TPU compiler takes the step — the flash backward with a group of
+    seven's dQ at the whole of the VMEM, the band of 4,096 at tiles of 1,024,
+    the grouped matmuls over 768-wide experts — and its memory analysis says
+    four layers fit one chip at one row of 16,384 beside 10.5 GB of state
+    (PR 59: 7.88 GB of arguments + 2.82 GB of temporaries; see PERF.md)."""
+    row = _child(["smallthinker"], compile_=True)["smallthinker"]
+    print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
+    assert "refused" not in row, row
+    # a layer: flash forward and the backward's one kernel; its held
+    # experts: twelve grouped-matmul calls and the two that add rows into
+    # tokens, as SDAR's and Laguna's
+    assert row["tpu_custom_calls"] == 4 * (2 + 12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 

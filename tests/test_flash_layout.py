@@ -265,6 +265,16 @@ GROUPED = {
     "causal-rep4-beside": (8, 2, 128, 384, _TILES, {}, "v", "beside"),
     "block-mask-rep4-beside": (4, 1, 128, 2 * SEQ, *_BLOCK_MASK, "qkv",
                                "beside"),
+    # seven query heads a key/value head (SmallThinker: 28 over 4): the
+    # whole-row layer as projected (NoPE), a window layer's q and k as heads
+    # under a band that spans several tiles, and both where the group's dQ
+    # does not fit VMEM
+    "causal-rep7-rank-3": (7, 1, 128, 384, _TILES, {}, "qkv", "vmem"),
+    "window-rep7": (14, 2, 128, 384, dict(window=200, **_TILES),
+                    dict(window=200), "v", "vmem"),
+    "causal-rep7-beside": (14, 2, 128, 384, _TILES, {}, "qkv", "beside"),
+    "window-rep7-beside": (7, 1, 128, 384, dict(window=200, **_TILES),
+                           dict(window=200), "v", "beside"),
     # any width as (B, H, S, D); the pair form of 64-wide heads has no
     # grouped form, and the copies of before stand in
     "causal-rep2-d32": (4, 2, 32, SEQ, {}, {}, "v", "vmem"),

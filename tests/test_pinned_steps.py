@@ -29,6 +29,7 @@ PINNED = {
     "toy-olmoe": "41054e8f510af2a7d7be4f320326eb4237e4c1efc006d16bd81fbd3d9bea858f",
     "toy-phi4-flash": "4a73552ec170305a0509376e0c74ec7c5681a005f8129170a8d1dd88705d4210",
     "toy-sdar": "ff8e09d3e6a65ea9935059aba9f4c46ea2127d572f13434078f2b64b86576cb4",
+    "toy-smallthinker": "93f22a8b8dd122f0ad365fda5333bd32f284eef760cd37790e1be2d4af71dd0e",
 }
 
 
