@@ -12,8 +12,11 @@ token mixer in place of attention, inside ``models/llama.py``'s block.  With
 The recurrence is ``ops/kda.py``'s chunked scan (three Mosaic kernels: the
 chunks' solve, which a rematerialised block keeps by name, the forward and
 the backward) and
-each convolution ``models/mamba.py::causal_conv``'s shifted multiply-adds in
-plain XLA.  The ``Dense`` children
+each convolution with its silu — for q and k with the head's unit norm and
+q's ``1 / sqrt(d)`` — one call of ``ops/conv.py::conv_silu`` (a forward and a
+backward Mosaic kernel; ``models/mamba.py::causal_conv``'s shifted
+multiply-adds in plain XLA where a head is not 128 columns).  The ``Dense``
+children
 ``q_proj``, ``k_proj``, ``v_proj``, ``f_a``, ``f_b``, ``g_a``, ``g_b``,
 ``b_proj``, ``o_proj``, the norm ``o_norm`` and the scopes ``conv`` (the
 three convolutions, silu and the two unit norms), ``gate`` (softplus and
@@ -31,13 +34,11 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.mamba import (
-    _a_log_init, _conv_init, _dt_bias_init, causal_conv)
+from ray_tpu.models.mamba import _a_log_init, _conv_init, _dt_bias_init
+from ray_tpu.ops.conv import L2_EPS, conv_silu
 from ray_tpu.ops.kda import kda_scan
 from ray_tpu.parallel.mesh import ambient_mesh
 from ray_tpu.parallel.sharding import constrain_residual
-
-L2_EPS = 1e-6
 
 
 def _of_head(width: int, heads: int):
@@ -63,7 +64,8 @@ def _widened(r, width: int):
 
 def _unit(x, heads: int, scale: float = 1.0):
     """Each head's columns of (B, S, H * d) at unit length, times ``scale``:
-    the statistic and the product in float32."""
+    the statistic and the product in float32.  ``ops/conv.py::conv_silu``'s
+    epilogue where its kernels run; its ``jax.numpy`` form calls this."""
     t = x.astype(jnp.float32)
     r = jax.lax.rsqrt(_head_sums(t * t, heads) + L2_EPS) * scale
     return (t * _widened(r, x.shape[-1])).astype(x.dtype)
@@ -114,9 +116,10 @@ class KDAMixer(nn.Module):
         kernels = [self.param(f"{name}_conv", conv_init,
                               (cfg.kda_d_conv, inner)) for name in "qkv"]
         with jax.named_scope("conv"):
-            q, k, v = (jax.nn.silu(causal_conv(t, kernel.astype(cfg.dtype)))
-                       for t, kernel in zip((q, k, v), kernels))
-            q, k = _unit(q, heads, d ** -0.5), _unit(k, heads)
+            q_conv, k_conv, v_conv = (t.astype(cfg.dtype) for t in kernels)
+            q = conv_silu(q, q_conv, unit_heads=heads, scale=d ** -0.5)
+            k = conv_silu(k, k_conv, unit_heads=heads)
+            v = conv_silu(v, v_conv)
         a_log = self.param("A_log", _a_log_init, (heads,))
         dt_bias = self.param("dt_bias", _dt_bias_init, (inner,))
         decay = by_head(dense(inner, "f_b")(dense(rank, "f_a")(x)))

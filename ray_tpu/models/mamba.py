@@ -9,7 +9,11 @@ inside ``models/llama.py``'s block.  With ``n`` the block's normed input:
     h_t = a_t h_{t-1} + dt_t X_t B_t^T;  y_t = h_t C_t + D X_t
     out = W_out RMSNorm_w(y * silu(z))   the norm over all heads, after the gate
 
-The recurrence is ``ops/ssd.py``'s chunked scan, two Pallas kernels.  The
+The recurrence is ``ops/ssd.py``'s chunked scan, two Pallas kernels, and the
+convolution with its bias and silu ``ops/conv.py::conv_silu``'s two (here as
+in ``Mamba1Mixer`` and ``models/kda.py``).  ``causal_conv`` below, the same
+convolution as shifted multiply-adds in plain XLA, is that op's reference
+and its fallback, and what ``gated_short_conv`` is built on.  The
 scopes ``conv``, ``ssd`` and ``gated_norm`` and the ``Dense`` children
 ``in_proj`` and ``out_proj`` are what the benchmark's per-layer metrics read.
 
@@ -39,6 +43,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.conv import conv_silu
 from ray_tpu.ops.selective_scan import selective_scan
 from ray_tpu.ops.ssd import ssd_scan
 from ray_tpu.parallel.mesh import ambient_mesh
@@ -147,8 +152,8 @@ class Mamba2Mixer(nn.Module):
         a_log = self.param("A_log", _a_log_init, (heads,))
         skip = self.param("D", nn.initializers.ones, (heads,))
         with jax.named_scope("conv"):
-            xbc = jax.nn.silu(causal_conv(xbc, kernel.astype(cfg.dtype),
-                                          bias.astype(cfg.dtype)))
+            xbc = conv_silu(xbc, kernel.astype(cfg.dtype),
+                            bias.astype(cfg.dtype))
         xs, b, c = jnp.split(xbc, [inner, inner + bc], axis=-1)
         xs = xs.reshape(batch, seq, heads, p)
         with jax.named_scope("ssd"):
@@ -229,8 +234,7 @@ class Mamba1Mixer(nn.Module):
         kernel = self.param("conv_kernel", conv_init, (cfg.mamba_d_conv, d))
         bias = self.param("conv_bias", conv_init, (d,))
         with jax.named_scope("conv"):
-            u = jax.nn.silu(causal_conv(u, kernel.astype(cfg.dtype),
-                                        bias.astype(cfg.dtype)))
+            u = conv_silu(u, kernel.astype(cfg.dtype), bias.astype(cfg.dtype))
         r, b, c = jnp.split(
             nn.Dense(rank + 2 * n, use_bias=False, dtype=cfg.dtype,
                      name="x_proj")(u), [rank, rank + n], axis=-1)

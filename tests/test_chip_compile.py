@@ -130,6 +130,8 @@ def _build(case: str, compile_: bool) -> dict:
         return _build_kda(case, topo.devices[0])
     if case.startswith("scan_s"):
         return _build_selective_scan(case, topo.devices[0])
+    if case.startswith("conv_s"):
+        return _build_conv(case, topo.devices[0])
     if case in PRELUDES:
         return _build_prelude(case, topo.devices[0])
     if case in LAYOUTS:
@@ -383,6 +385,44 @@ def _build_selective_scan(case: str, device) -> dict:
         compiled = jax.jit(grads).lower(
             wide, shape((1, seq, d), jnp.float32), shape((d, n), jnp.float32),
             narrow, narrow, shape((d,), jnp.float32), wide).compile()
+    except Exception as e:  # what Mosaic or the TPU compiler refuses
+        return {"case": case, "refused": str(e)[-1500:]}
+    mem = compiled.memory_analysis()
+    return {"case": case, "tpu_custom_calls": len(re.findall(
+        r'custom_call_target="tpu_custom_call"', compiled.as_text())),
+        "temp_bytes": int(mem.temp_size_in_bytes)}
+
+
+def _build_conv(case: str, device) -> dict:
+    """In the child: compile ``ops/conv.py``'s kernels alone, forward and
+    backward, for one chip at ``conv_s<seq>_c<channels>[_h<unit heads>]`` in
+    bf16, four taps wide: one row, with a bias where no head is normed."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.conv import conv_silu
+
+    given = {part[0]: int(part[1:]) for part in case.split("_")[1:]}
+    seq, channels, heads = given["s"], given["c"], given.get("h")
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.bfloat16,
+                                    sharding=SingleDeviceSharding(device))
+
+    def grads(dy, x, kernel, *bias):
+        out, vjp = jax.vjp(functools.partial(
+            conv_silu, unit_heads=heads, scale=0.5 if heads else 1.0),
+            x, kernel, *bias)
+        return out, vjp(dy)
+
+    wide = shape(1, seq, channels)
+    try:
+        compiled = jax.jit(grads).lower(
+            wide, wide, shape(4, channels),
+            *([] if heads else [shape(channels)])).compile()
     except Exception as e:  # what Mosaic or the TPU compiler refuses
         return {"case": case, "refused": str(e)[-1500:]}
     mem = compiled.memory_analysis()
@@ -894,18 +934,21 @@ def test_olmoe_step_compiles_and_says_how_many_rows_fit():
 def test_granite_step_lowers_for_one_v5e_chip():
     """Tier-1: the one-chip Granite-4.0-H-Micro step at published widths
     (five Mamba-2 layers and one attention layer, seq 8192 x 1 row) lowers
-    for the TPU with its Mosaic calls in it: the flash kernels and the
-    scan's (``ops/ssd.py``), and nothing of a scan's chunk squares — the
-    decay mask, the scores, their product, the cotangents of each — is an
-    array of the XLA program: they live in the kernels' VMEM."""
+    for the TPU with its Mosaic calls in it: the flash kernels, the scan's
+    (``ops/ssd.py``) and the convolution's (``ops/conv.py``), and nothing of
+    a scan's chunk squares — the decay mask, the scores, their product, the
+    cotangents of each — is an array of the XLA program: they live in the
+    kernels' VMEM."""
     row = _child(["granite"], compile_=False)["granite"]
     # the one attention layer under remat: one forward, one backward kernel
     # (the block keeps the forward's output and logsumexp); a mamba layer's
-    # scan: forward, the forward again in the block's recomputation (its
-    # output and the states before each chunk are the backward's residuals),
-    # backward
-    assert row["lowered_kernels"] == {"flash_fwd": 1, "flash_bwd": 1,
-                                      "ssd_fwd": 2 * 5, "ssd_bwd": 5}, row
+    # scan, and its convolution with the silu: forward, the forward again in
+    # the block's recomputation (the scan's output and the states before
+    # each chunk are the backward's residuals; the convolution's are its
+    # inputs), backward
+    assert row["lowered_kernels"] == {
+        "flash_fwd": 1, "flash_bwd": 1, "ssd_fwd": 2 * 5, "ssd_bwd": 5,
+        "conv_silu_fwd": 2 * 5, "conv_silu_bwd": 5}, row
     assert row["flash_fwd_calls"] == 1, row
     # (the parent's step had eight kinds of them, up to 32 x 64 x 256 x 256)
     assert row["chunk_squares"] == [], row
@@ -922,8 +965,9 @@ def test_granite_step_compiles_and_fits_the_chip():
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row
     # the one attention layer: flash forward and the backward's one kernel;
-    # a mamba layer's scan: forward, the forward recomputed, backward
-    assert row["tpu_custom_calls"] == 2 + 5 * 3, row
+    # a mamba layer's scan and its convolution: forward, the forward
+    # recomputed, backward
+    assert row["tpu_custom_calls"] == 2 + 5 * 6, row
     assert row["argument_bytes"] + row["temp_bytes"] < 14e9, row
 
 
@@ -1063,13 +1107,19 @@ def test_kimi_linear_step_lowers_for_one_v5e_chip():
     of 16,384) lowers for the TPU with its Mosaic kernels in it: the scan's
     three (``ops/kda.py``) in the four KDA layers, the flash pair of the one
     latent-attention layer, the grouped matmuls of the held experts under a
-    contraction of 2,304 and the sum of their rows into the tokens, and no
-    other — the convolutions, the gates and the norms are plain XLA."""
+    contraction of 2,304 and the sum of their rows into the tokens, and the
+    convolutions' pair (``ops/conv.py``: q and k with a head's unit norm
+    inside, v without), and no other — the gates and the output's norm are
+    plain XLA."""
     row = _child(["kimi_linear"], compile_=False)["kimi_linear"]
     kernels = row["lowered_kernels"]
     assert kernels.pop("kernel") > 0
     assert set(kernels) == {"kda_solve", "kda_fwd", "kda_bwd", "flash_fwd",
-                            "flash_bwd", "onto_tokens"}, kernels
+                            "flash_bwd", "onto_tokens", "conv_silu_fwd",
+                            "conv_silu_bwd"}, kernels
+    # q, k and v of a KDA layer: forward, again under remat, backward
+    assert (kernels["conv_silu_fwd"], kernels["conv_silu_bwd"]) \
+        == (2 * 3 * 4, 3 * 4), kernels
     assert kernels["flash_fwd"] == kernels["flash_bwd"] == 1, kernels
     # a KDA layer under remat: one solve, kept by name; the forward twice
     assert (kernels["kda_solve"], kernels["kda_fwd"], kernels["kda_bwd"]) \
@@ -1095,8 +1145,9 @@ def test_kimi_linear_step_compiles_and_fits_the_chip():
     # remat (the solve is kept by name) and its backward; the MLA layer:
     # flash forward and the backward's one kernel; a sparse layer's held
     # experts: twelve grouped-matmul calls and the two that add rows into
-    # tokens, as Kimi-VL's: 4 x 4 + 2 + 4 x (12 + 2)
-    assert row["tpu_custom_calls"] == 4 * 4 + 2 + 4 * (12 + 2), row
+    # tokens, as Kimi-VL's; q, k and v of a KDA layer through the
+    # convolution's pair, the forward twice: 4 x 4 + 2 + 4 x (12 + 2) + 36
+    assert row["tpu_custom_calls"] == 4 * 4 + 2 + 4 * (12 + 2) + 36, row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
@@ -1111,6 +1162,18 @@ def test_kda_kernels_compile_for_one_v5e_chip():
     assert row["tpu_custom_calls"] == 3, row    # solve, forward, backward
 
 
+def test_conv_kernels_compile_for_one_v5e_chip():
+    """Tier-1, ten seconds: Mosaic takes ``ops/conv.py``'s pair at the three
+    cells' shapes — Kimi-Linear's 1 x 16,384 x 4,096 with 32 heads' unit
+    norm, Granite's 1 x 8,192 x 4,352 and Phi-4's 1 x 16,384 x 5,120 with a
+    bias — the strided loads and stores from a dynamic start, the reduction
+    along a head's lanes —, which the interpreter on the CPU cannot say."""
+    cases = ["conv_s16384_c4096_h32", "conv_s8192_c4352", "conv_s16384_c5120"]
+    for case, row in _child(cases, compile_=True).items():
+        assert "refused" not in row, row
+        assert row["tpu_custom_calls"] == 2, row    # a forward and a backward
+
+
 def test_phi4_flash_step_lowers_for_one_v5e_chip():
     """Tier-1: the one-chip step of Phi-4-mini-flash-reasoning at published
     widths (published layers 14 to 19: Mamba-1, window attention, Mamba-1
@@ -1119,12 +1182,16 @@ def test_phi4_flash_step_lowers_for_one_v5e_chip():
     Mosaic kernels in it: the selective scan's pair
     (``ops/selective_scan.py``) in the two Mamba-1 layers and the flash pair
     twice a layer in the three differential attention layers, scores 64 wide
-    over values 128 wide, and no other — the convolution, the gates, the
-    gated memory unit, lam and the sub-layer norm are plain XLA."""
+    over values 128 wide, the convolution's pair (``ops/conv.py``) in the
+    Mamba-1 layers, and no other — the gates, the gated memory unit, lam and
+    the sub-layer norm are plain XLA."""
     row = _child(["phi4_flash"], compile_=False)["phi4_flash"]
     kernels = row["lowered_kernels"]
     assert set(kernels) == {"selective_scan_fwd", "selective_scan_bwd",
-                            "flash_fwd", "flash_bwd"}, kernels
+                            "flash_fwd", "flash_bwd", "conv_silu_fwd",
+                            "conv_silu_bwd"}, kernels
+    assert (kernels["conv_silu_fwd"], kernels["conv_silu_bwd"]) == (4, 2), \
+        kernels
     assert kernels["flash_fwd"] == kernels["flash_bwd"] == 6, kernels
     assert kernels["selective_scan_bwd"] == 2, kernels
     assert row["flash_fwd_calls"] == 6, row
@@ -1142,8 +1209,9 @@ def test_phi4_flash_step_compiles_and_fits_the_chip():
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row
     # a Mamba-1 layer: the scan's forward, again under remat, and its
-    # backward; an attention layer: two flash forwards and two backwards
-    assert row["tpu_custom_calls"] == 2 * 3 + 3 * 4, row
+    # backward, and the convolution's the same; an attention layer: two
+    # flash forwards and two backwards
+    assert row["tpu_custom_calls"] == 2 * 6 + 3 * 4, row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 

@@ -21,13 +21,13 @@ PINNED = {
     "toy-evabyte": "2d776bb3a348b1a6d357fbc10d09c972ef6cd73a1afd586ac3a1f9ccc3c2de59",
     "toy-gpt2": "ba11271144fed4ca835af77562ca30fbb9989cac73bf82a195c1a4e4d5921693",
     "toy-granite": "7a646a83d84178cf45adcbe76b26d3fedef41d6e0ef427a125c34ab1cbddb34d",
-    "toy-kimi-linear": "b4a51f9d9b4c98fb00b2eb8c354abd1c4b09b4b16e799c39b638955ed4de2eca",
+    "toy-kimi-linear": "5c0d97570c411cf0f592f071ab7dd6ff64b04db88fe014a145253f9c80ce579d",
     "toy-kimi-vl": "5a017906206e0d13c1f718b10c38f6a80c6893aafaa08d252665c2a7a745d148",
     "toy-laguna": "4d7f074a49c3ae1f68e4b5e66cd1af93e583972f0ae498461edcd62e2babffc0",
     "toy-lfm2": "ad07a2bf3eae0808f1e15c23cd22a6c700ac2fbf8f402d9cf0bcc4b9ea0af5d6",
     "toy-llama": "3f093a50754c69664f39a2c72264ae3a12b3e0d67a722d3f326ae8d1ef0fb00e",
     "toy-olmoe": "41054e8f510af2a7d7be4f320326eb4237e4c1efc006d16bd81fbd3d9bea858f",
-    "toy-phi4-flash": "4a73552ec170305a0509376e0c74ec7c5681a005f8129170a8d1dd88705d4210",
+    "toy-phi4-flash": "79a8ea30f0ff39e594a039012999dd6e27df988b0ecb88a1d11bf6f31d7440d9",
     "toy-sdar": "ff8e09d3e6a65ea9935059aba9f4c46ea2127d572f13434078f2b64b86576cb4",
     "toy-smallthinker": "93f22a8b8dd122f0ad365fda5333bd32f284eef760cd37790e1be2d4af71dd0e",
 }
