@@ -36,7 +36,10 @@ positions (``ops.attention``'s band: a query sees itself and the
 ``RopeTable``, YaRN-scaled frequencies, a part of each head rotated) and a
 layer a head count of its own (``n_head_per_layer``); ``attn_gate`` gives
 each head's output a sigmoid gate computed from the layer's normed input
-(``attn/wg``, applied under the scope ``gate``); ``mlp_types`` names each
+(``attn/wg``'s logits go to ``ops.attention`` as its ``gate``: the forward
+kernel multiplies in its last step, the backward kernel meets the gates in
+the statistics it is handed, and only the sigmoid of one value a head a token
+is an op of its own, under the scope ``gate``); ``mlp_types`` names each
 layer's feed-forward ``"dense"`` or ``"sparse"`` where ``moe_every``'s fixed
 period cannot; and the routed layer may score its experts by sigmoids, scale
 the chosen weights and add a shared expert every token passes
@@ -465,31 +468,20 @@ class HeadNormScale(nn.Module):
         return self.param("scale", nn.initializers.ones, (width,), jnp.float32)
 
 
-@jax.checkpoint
-def _gated(out, gate):
-    """(B, S, H * D) x (B, S, H): each head's columns by its scalar.  The
-    gate is widened along the lanes by a matmul with a 0 / 1 matrix (exact:
-    one term a column) whose epilogue takes the product, and made again for
-    the backward rather than kept: as a ``repeat`` XLA wrote the wide gate
-    out and read it back, both ways."""
-    h, c = gate.shape[-1], out.shape[-1]
-    of_head = jnp.arange(c)[None, :] // (c // h) == jnp.arange(h)[:, None]
-    return out * (gate @ of_head.astype(out.dtype))
-
-
 def _attend(cfg: LlamaConfig, kind: str, q, k, v, k_shared=None,
-            head_dim=None, pooled=None):
+            head_dim=None, pooled=None, gate=None):
     """A layer's one call into ``ops.attention``: what the configuration and
     the layer's kind say of the mask — a window for a sliding layer, under
     block diffusion (x is [noised ; clean]) the block mask in place of the
     causal one, under EVA the window's own keys and ``pooled``, the
-    summaries' keys and values.  Which implementation takes it, and whether
-    one does, is ``attention``'s to say.  Operands of rank 4 are (B, H, S, D), of rank 3
+    summaries' keys and values; ``gate`` (B, S, H), the logits of a sigmoid
+    gate a head a token on the result.  Which implementation takes it, and
+    whether one does, is ``attention``'s to say.  Operands of rank 4 are (B, H, S, D), of rank 3
     (B, S, H * D) as their projection wrote them; the result is
     (B, S, H * Dv), as the output projection takes it."""
     return attention(
         q, k, v, impl=cfg.attention_impl, k_shared=k_shared,
-        head_dim=head_dim,
+        head_dim=head_dim, gate=gate,
         window=cfg.sliding_window if kind == "sliding_attention" else 0,
         diffusion_block=cfg.diffusion_block
         if cfg.objective == "block_diffusion" else 0,
@@ -562,15 +554,15 @@ class LlamaAttention(nn.Module):
                         heads(k) if k.ndim == 3 else k, v, phi, mu,
                         cfg.eva_chunk, cfg.attn_scale or D ** -0.5,
                         impl=cfg.attention_impl)
+        # a gated layer's one logit a head a token, from the layer's normed
+        # input: the sigmoid and the multiply are ``attention``'s, inside the
+        # kernels' own passes
+        gate = nn.Dense(H, use_bias=False, dtype=cfg.dtype, name="wg")(x) \
+            if cfg.attn_gate else None
         # what came straight from its projection goes to the kernels as it
         # lies, (B, S, H * D), and so does the result to ``wo``
-        out = _attend(cfg, self.kind, q, k, v, head_dim=D, pooled=pooled)
-        if cfg.attn_gate:
-            # one scalar a head a token, from the layer's normed input
-            gate = nn.Dense(H, use_bias=False, dtype=cfg.dtype, name="wg")(x)
-            with jax.named_scope("gate"):
-                out = _gated(out, jax.nn.sigmoid(
-                    gate.astype(jnp.float32)).astype(out.dtype))
+        out = _attend(cfg, self.kind, q, k, v, head_dim=D, pooled=pooled,
+                      gate=gate)
         return nn.Dense(E, use_bias=False, dtype=cfg.dtype, name="wo")(out)
 
 

@@ -61,6 +61,21 @@ Greenfield relative to the reference — it has no sequence parallelism anywhere
   tiles and then over the tiles of summaries that the queries see, and the
   backward hands the summaries their gradients as it does dK and dV
   (``_Tiles.eva``).  A row of at most one window is the causal call.
+- A sigmoid gate a head a query on the output (``attention``'s ``gate``, the
+  logits as (B, S, H): Laguna's) is taken inside the kernels' own passes.
+  The forward kernel gets the gates as one more row a head, laid out as the
+  logsumexp's, and its last step writes ``acc * (g / l)`` where it wrote
+  ``acc / l``, in float32 before the output's one rounding, so the output,
+  and the kept residual, is the gated one.  The backward kernel is the ungated one, handed the gated output's
+  cotangent dY for dO and two rows of statistics that carry the gates: with
+  ``lse - log g`` for the logsumexp it makes ``g P`` where it made P, which
+  is what dV takes (``(g P)^T dY``), and with ``delta / g`` for ``delta`` its
+  ``g P (dY V^T - delta / g)`` is the dS of the ungated output's cotangent
+  ``g dY``; the logits' gradient is ``delta * (1 - g)``, ``delta`` being the
+  row sums of ``dY * out`` the backward makes anyway.  No pass over ``(B, S,
+  H * D)`` is made for the gate in either direction, and no tile of the
+  backward does more work.  Without a gate the kernels are traced as they
+  were (``_Tiles.gated``).
 - Under an ambient mesh (``jax.set_mesh``) ``flash_attention`` runs the kernel
   inside a ``shard_map`` (batch over dp/fsdp, heads over tp — a rank-3
   operand's columns by whole heads): Mosaic kernels cannot be partitioned by
@@ -365,6 +380,13 @@ class _Tiles(NamedTuple):
     # writes a dK and dV a query head (``_flash_backward`` sums them).
     rep: int = 1
     kv_grid: bool = False
+    # A gate a head a query on the output (``flash_attention``'s ``gate``):
+    # one more input of the forward kernel, the (b, h / group, group, s_q)
+    # rows of sigmoid(gate) laid out as the logsumexp's are, and its last
+    # step writes ``acc * (g / l)`` where it wrote ``acc / l``.  (The
+    # backward kernel needs no word of it: ``_flash_backward`` folds the
+    # gates into the two rows of statistics it hands over anyway.)
+    gated: bool = False
 
     @classmethod
     def of(cls, s_q, s_k, d, dtype, causal, offset, diag_chunk,
@@ -728,6 +750,16 @@ def _rows(x, s_pad: int, tokens: bool = False, group: int = 1):
         x.shape[0], x.shape[1] // group, group, *x.shape[2:])
 
 
+def _stat_rows(x, b: int, h: int, n: int, s_q_pad: int, bd: int, fill):
+    """A statistic a query a head, (b*h, 1, s_q), as the kernels' (b, h / n,
+    n, s_q_pad) rows, ``n`` heads a grid step, padded with ``fill``."""
+    if bd:      # laid out as the queries are
+        x = _halves(x.reshape(b, h, x.shape[-1], 1), s_q_pad, fill=fill)
+    else:
+        x = _pad(x, 2, s_q_pad, fill)
+    return x.reshape(b, h // n, n, s_q_pad)
+
+
 def _halves(x, s_pad: int, tokens: bool = False, fill=0.0):
     """(b, h, 2 l, d) or (b, 2 l, c), two copies of ``l`` positions ->
     the same with ``s_pad`` positions, each copy padded with ``fill`` up to
@@ -977,9 +1009,11 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles,
                       tokens):
     # under ``t.bd`` two more inputs: the noised K and V tile of the query
     # tile's own positions; under ``t.eva`` two: the summaries' keys and
-    # values; without either, at most one: the keys' shared part.
+    # values; without either, at most one: the keys' shared part; under
+    # ``t.gated`` one more, last: the heads' rows of gates.
     # ``tokens``: which of q, k, v and the output are rank 3 (``_per_head``)
     *own_refs, o_ref, lse_ref, m_col, l_col, acc = rest
+    gate_ref = own_refs.pop() if t.gated else None
     row, step = pl.program_id(2), pl.program_id(3)
     if t.eva:
         iq, ik, js, _, seen = t.eva_walk(row, step, False)
@@ -1056,7 +1090,17 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, *rest, sm_scale: float, t: _Tiles,
             l = l_col[...]
             empty = lax.eq(l, 0.0)  # no key seen: output 0, lse NEG_INF
             l = lax.select(empty, lax.full_like(l, 1.0), l)
-            o_ref[...] = lax.div(acc[...], _across(l, d)).astype(o_ref.dtype)
+            if gate_ref is None:
+                o_ref[...] = lax.div(acc[...], _across(l, d)
+                                     ).astype(o_ref.dtype)
+            else:
+                # the head's (1, block_q) row of gates as a lane-replicated
+                # column (what ``lse.T[:1, :]`` below undoes); they meet the
+                # output in float32, before its cast
+                g = jnp.broadcast_to(gate_ref[p:p + 1, :],
+                                     (LANES, t.block_q)).T
+                o_ref[...] = lax.mul(acc[...], _across(lax.div(g, l), d)
+                                     ).astype(o_ref.dtype)
             lse = lax.select(empty, lax.full_like(l, NEG_INF),
                              lax.add(m_col[...], lax.log(l)))
             # (block_q, LANES) column, every lane the same -> the head's
@@ -1072,7 +1116,7 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
                    block_k: Optional[int], interpret: bool, bd: int = 0,
                    window: int = 0, k_shared=None,
                    lay: Optional[_Layout] = None, pooled=None,
-                   eva: tuple = ()):
+                   eva: tuple = (), gate=None):
     """``out`` — (b, h, s_q, v's width), or (b, s_q, h * v's width) under
     ``lay.out`` — and the logsumexp of every query's scaled scores as
     (b*h, 1, s_q) rows, NEG_INF where a query sees no key.  ``lay``: the
@@ -1080,7 +1124,9 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
     k and v are the two copies of ``s_q / 2`` positions, and every query sees
     a key.  ``k_shared`` (b, 1, s_k, .): see ``flash_attention``.  ``eva``
     (window, chunk) with ``pooled``, the summaries' keys and values laid out
-    as k and v are: see ``_Tiles``.
+    as k and v are: see ``_Tiles``.  ``gate`` (b, s_q, h), logits: each
+    head's output times the sigmoid of its logit of the query, in the
+    kernel's last step (``_Tiles.gated``); ``out`` is then the gated output.
 
     Jitted and inlined so that a model's layers, which call it with the same
     shapes, share one trace of the kernel: the equations land in the caller's
@@ -1093,13 +1139,26 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
     s_k = s_k // 2 if bd else s_k       # one copy's
     t = _Tiles.of(s_k if bd else s_q, s_k, d, q.dtype, causal,
                   q_offset - k_offset, _FWD_DIAG_CHUNK, block_q, block_k, bd,
-                  window, eva)._replace(group=g, rep=lay.rep)
+                  window, eva)._replace(group=g, rep=lay.rep,
+                                        gated=gate is not None)
     s_q_pad, s_k_pad = t.nq * t.block_q, t.nk * t.block_k
+    if t.gated:     # once a traced shape: the mechanism engages
+        logger.debug(
+            "flash attention: %d heads' outputs gated a query inside the "
+            "kernels (%d queries, tiles of %d)", h, s_q, t.block_q)
     tokens = tuple(c is not None for c in lay[1:5])
     k_spec, *kn_spec = t.k_specs(d_k, lay.k, False)
     v_spec, *vn_spec = t.k_specs(d_v, lay.v, False)
     rows = functools.partial(_rows, group=g)
     q_rows, k_rows = (_halves, _copies) if bd else (rows, rows)
+    if t.gated:
+        # what a gate still is beside the kernels, under the trace's name
+        # for it: the sigmoid of a value a head a query, laid out as a row a
+        # head, zero where padded
+        with jax.named_scope("gate"):
+            gate = _stat_rows(
+                jax.nn.sigmoid(gate.astype(jnp.float32)).transpose(
+                    0, 2, 1).reshape(b * h, 1, s_q), b, h, g, s_q_pad, bd, 0.0)
     with jax.named_scope("flash_fwd"):
         q = q_rows(q, s_q_pad, tokens[0])
         k = k_rows(k, s_k_pad, tokens[1])
@@ -1113,6 +1172,8 @@ def _flash_forward(q, k, v, causal: bool, sm_scale: float, q_offset: int,
                         for x, rank3 in zip(pooled, tokens[1:3]))
             own_spec = [t.pooled_spec(d_k, lay.k and _dense(d_k), False),
                         t.pooled_spec(d_v, lay.v and _dense(d_v), False)]
+        if t.gated:
+            own, own_spec = own + (gate,), own_spec + [t.row_spec(False)]
         def scratch(width):
             return pltpu.VMEM(t.per_head(t.block_q, width), jnp.float32)
         out, lse = pl.pallas_call(
@@ -1334,11 +1395,14 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
                     q_offset: int, k_offset: int, interpret: bool,
                     bd: int = 0, window: int = 0, k_shared=None,
                     lay: Optional[_Layout] = None, pooled=None,
-                    eva: tuple = ()):
+                    eva: tuple = (), gate=None):
     """dq, dk, dv of ``_flash_attention`` from its residuals (``lse`` as the
     forward leaves it: (b*h, 1, s_q) rows) and ``g``; with ``k_shared``, its
     gradient too, summed over the heads; with ``pooled``, the summaries'
-    keys' and values' (last).  Each gradient has the rank of what
+    keys' and values' (last); with ``gate`` (b, s_q, h), the logits of the
+    gates the forward took — ``out`` is the gated output, ``g`` its
+    cotangent — the logits' gradient, (b, s_q, h) in float32 (last).  Each
+    other gradient has the rank of what
     it is the gradient of; one of rank 3 is (b, s, h * width), the heads side
     by side from column 0, whatever columns the operand itself was read at.
 
@@ -1369,14 +1433,7 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
             _bwd_vmem_bytes(whole.group * s_q_pad, d, q.dtype), _VMEM_BYTES)
     q_is, k_is, v_is, o_is = (c is not None for c in lay[1:5])
 
-    def row(x, fill):
-        """(b*h, 1, s_q) -> the kernel's (b, h / n, n, s_q_pad) rows."""
-        if bd:      # a statistic a query, laid out as the queries are
-            x = _halves(x.reshape(b, h, s_q, 1), s_q_pad, fill=fill)
-        else:
-            x = _pad(x, 2, s_q_pad, fill)
-        return x.reshape(b, h // n, n, s_q_pad)
-
+    row = functools.partial(_stat_rows, b=b, h=h, n=n, s_q_pad=s_q_pad, bd=bd)
     rows = functools.partial(_rows, group=n)
     q_rows, k_rows = (_halves, _copies) if bd else (rows, rows)
     delta = out.astype(jnp.float32) * g.astype(jnp.float32)
@@ -1397,6 +1454,23 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
     # exp(s - lse) would blow up: such rows, like the padding rows, get an lse
     # under which every p is zero.
     lse = jnp.where(lse > NEG_INF / 2, lse, -NEG_INF)
+    dgate = ()
+    if gate is not None:
+        # ``out`` is g o and the cotangent handed in is dY, the gated
+        # output's; the ungated output's is g dY.  The kernel takes dY as it
+        # comes and meets the gates in its two rows of statistics:
+        # exp(S - (lse - log g)) = g P, whose transpose by dY is dV, and
+        # g P (dY V^T - delta / g) = P (g dY V^T - delta), which is dS.
+        # delta = rowsum(dY out) = g rowsum(dY o), so the logit's gradient
+        # g (1 - g) rowsum(dY o) is delta (1 - g): nothing is divided for
+        # it.  Where g underflows, P is 0 under its lse and delta / g is
+        # taken for 0.
+        x = gate.astype(jnp.float32).transpose(0, 2, 1)     # as delta
+        gates = jax.nn.sigmoid(x)
+        dgate = ((delta * (1.0 - gates)).transpose(0, 2, 1),)
+        lse = lse - jax.nn.log_sigmoid(x).reshape(lse.shape)
+        seen = gates >= jnp.finfo(jnp.float32).tiny
+        delta = jnp.where(seen, delta / jnp.where(seen, gates, 1.0), 0.0)
     (k_spec,), (v_spec,) = (t.k_specs(d_k, lay.k, True),
                             t.k_specs(d_v, lay.v, True))
     row_spec = t.row_spec(True)
@@ -1433,7 +1507,8 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
     scratches = [scratch(whole.per_head(s_q_pad), d), scratch(tile, d_k),
                  scratch(tile, d_v)]
     operands = [q_rows(q, s_q_pad, q_is), q_rows(g, s_q_pad, o_is),
-                row(lse, -NEG_INF), row(delta.reshape(b * h, 1, s_q), 0.0),
+                row(lse, fill=-NEG_INF),
+                row(delta.reshape(b * h, 1, s_q), fill=0.0),
                 k_rows(k, s_k_pad, k_is), k_rows(v, s_k_pad, v_is)]
     if k_shared is not None:
         # each head's own gradient of the shared part, summed over the heads
@@ -1494,13 +1569,13 @@ def _flash_backward(q, k, v, out, lse, g, causal: bool, sm_scale: float,
     grads = (back(dq, q, s_q, q_is), key_grad(dk, k, k_is),
              key_grad(dv, v, v_is))
     if eva:
-        return grads + tuple(back(dx, x, n_pooled, rank3) for dx, x, rank3
-                             in zip(dks, pooled, (k_is, v_is)))
-    if k_shared is None:
-        return grads
-    return grads + (jnp.sum(
-        _merged(dks[0], False, h)[:, :, :s_k], axis=1, keepdims=True,
-        dtype=jnp.float32).astype(k_shared.dtype),)
+        grads += tuple(back(dx, x, n_pooled, rank3) for dx, x, rank3
+                       in zip(dks, pooled, (k_is, v_is)))
+    if k_shared is not None:
+        grads += (jnp.sum(
+            _merged(dks[0], False, h)[:, :, :s_k], axis=1, keepdims=True,
+            dtype=jnp.float32).astype(k_shared.dtype),)
+    return grads + dgate
 
 
 # ============================================================= public op
@@ -1510,10 +1585,11 @@ def _named_forward(*args):
                      FLASH_RESIDUALS))
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(5, 15)))
-def _flash_attention(q, k, v, k_shared=None, pooled=None, causal=True,
-                     sm_scale=1.0, q_offset=0, k_offset=0, block_q=None,
-                     block_k=None, window=0, bd=0, lay=None, eva=()):
+@functools.partial(jax.custom_vjp, nondiff_argnums=tuple(range(6, 16)))
+def _flash_attention(q, k, v, k_shared=None, pooled=None, gate=None,
+                     causal=True, sm_scale=1.0, q_offset=0, k_offset=0,
+                     block_q=None, block_k=None, window=0, bd=0, lay=None,
+                     eva=()):
     """The flash pair under its one differentiation rule, for every mask and
     layout the kernels take.  ``lay``: the operands' ranks and the output's
     (``_Layout``, from ``flash_attention``).  ``k_shared``: the last
@@ -1529,27 +1605,33 @@ def _flash_attention(q, k, v, k_shared=None, pooled=None, causal=True,
     noised tile (``_Tiles``).  ``eva`` (window, chunk) with ``pooled``, the
     keys and values of one summary a chunk (or None, as ``k_shared``): the
     window's own causal tiles and the summaries of the windows before, in
-    that one softmax."""
-    return _flash_fwd_rule(q, k, v, k_shared, pooled, causal, sm_scale,
+    that one softmax.  ``gate`` (b, s_q, h), or None as ``k_shared``: the
+    logits of a sigmoid gate a head a query on the output, taken inside the
+    kernels — the forward's last division, the backward's two rows of
+    statistics — with the logits' gradient read off the backward's ``delta``;
+    the output, and the residual ``flash_out``, is the gated one."""
+    return _flash_fwd_rule(q, k, v, k_shared, pooled, gate, causal, sm_scale,
                            q_offset, k_offset, block_q, block_k, window, bd,
                            lay, eva)[0]
 
 
-def _flash_fwd_rule(q, k, v, k_shared, pooled, causal, sm_scale, q_offset,
-                    k_offset, block_q, block_k, window, bd, lay, eva):
+def _flash_fwd_rule(q, k, v, k_shared, pooled, gate, causal, sm_scale,
+                    q_offset, k_offset, block_q, block_k, window, bd, lay,
+                    eva):
     out, lse = _named_forward(q, k, v, causal, sm_scale, q_offset, k_offset,
                               block_q, block_k, _interpret(), bd, window,
-                              k_shared, lay, pooled, eva)
-    return out, (q, k, v, k_shared, pooled, out, lse)
+                              k_shared, lay, pooled, eva, gate)
+    return out, (q, k, v, k_shared, pooled, gate, out, lse)
 
 
 def _flash_bwd_rule(causal, sm_scale, q_offset, k_offset, block_q, block_k,
                     window, bd, lay, eva, residuals, g):
-    q, k, v, k_shared, pooled, out, lse = residuals
+    q, k, v, k_shared, pooled, gate, out, lse = residuals
     with jax.named_scope("flash_bwd"):
         dq, dk, dv, *dks = _flash_backward(
             q, k, v, out, lse, g, causal, sm_scale, q_offset, k_offset,
-            _interpret(), bd, window, k_shared, lay, pooled, eva)
+            _interpret(), bd, window, k_shared, lay, pooled, eva, gate)
+        dgate = None if gate is None else dks.pop().astype(gate.dtype)
         # a rank-3 operand read at some columns of its array: the gradient
         # is that array's, zero elsewhere (three operands of one array add up
         # to its whole gradient in one pass)
@@ -1560,8 +1642,8 @@ def _flash_bwd_rule(causal, sm_scale, q_offset, k_offset, block_q, block_k,
                 (dq, dk, dv), (q, k, v), lay[1:4],
                 (lay.heads,) + (lay.heads // lay.rep,) * 2))
     if eva:
-        return dq, dk, dv, None, tuple(dks)
-    return dq, dk, dv, (dks[0] if dks else None), None
+        return dq, dk, dv, None, tuple(dks), dgate
+    return dq, dk, dv, (dks[0] if dks else None), None, dgate
 
 
 _flash_attention.defvjp(_flash_fwd_rule, _flash_bwd_rule)
@@ -1683,7 +1765,7 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
                     head_dim: Optional[int] = None,
                     tokens_out: Optional[bool] = None,
                     eva_window: int = 0, eva_chunk: int = 0,
-                    k_pooled=None, v_pooled=None):
+                    k_pooled=None, v_pooled=None, gate=None):
     """Blockwise (flash) attention.  Each of q, k, v is rank 4, (B, H, S, D),
     or rank 3, (B, S, H * D) as a projection wrote it (or a ``HeadColumns``
     of such an array); a rank-3 q comes with ``head_dim``.  k and v may have
@@ -1723,8 +1805,17 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     window and the visible summaries is visited, and the summaries get their
     gradients.  The window is whole lanes and whole chunks.
 
+    ``gate`` (B, S, H): the logits of a sigmoid gate a head a query; head
+    ``h``'s output at query ``s`` is multiplied by ``sigmoid(gate[b, s, h])``
+    in float32 before the output's one rounding, inside the forward kernel;
+    the backward kernel meets the gates in the logsumexp and ``delta`` it is
+    handed, and the logits' gradient comes off ``delta``
+    (``_flash_backward``) — no pass over the output is made for the gate,
+    forward or backward.
+
     Under an ambient mesh of more than one device the kernel runs inside a
-    ``shard_map`` — batch over dp/fsdp, heads over tp, the sequence whole on
+    ``shard_map`` — batch over dp/fsdp, heads over tp (the gate's as the
+    columns of a rank-3 operand), the sequence whole on
     every device (sequence sharding is ring attention's job).  Without one,
     or on a one-device mesh, it is the plain call.
     """
@@ -1781,13 +1872,14 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     q, k, v = (x if kept is not None else _to_heads(x, c, n)
                for x, c, kept, n in zip((q, k, v), cols, lay[1:4],
                                         (heads, n_kv, n_kv)))
-    operands = (q, k, v) if k_shared is None else (q, k, v, k_shared)
+    pooled = None
     if eva:
         # the summaries that some query sees, laid out as k and v are
         seen = (s_k - 1) // eva[0] * (eva[0] // eva[1])
-        operands = (q, k, v, None, tuple(
-            _pooled_as(x, heads, seen, kept is not None)
-            for x, kept in zip((k_pooled, v_pooled), lay[2:4])))
+        pooled = tuple(_pooled_as(x, heads, seen, kept is not None)
+                       for x, kept in zip((k_pooled, v_pooled), lay[2:4]))
+    # (what a call does not have is None: an empty pytree)
+    operands = (q, k, v, k_shared, pooled, gate)
     # (the block mask is its own: causal is not asked, the offsets not read)
     f = functools.partial(
         _flash_attention, causal=causal and not diffusion_block,
@@ -1797,14 +1889,15 @@ def flash_attention(q, k, v, *, causal: bool = True, sm_scale: Optional[float] =
     if mesh is None:
         out = f(*operands)
     else:
-        # (the shared part's one head: whole on every device of a tp group)
-        in_specs = [_bhsd_spec(mesh, ("dp", "fsdp"), "tp",
-                               tokens=c is not None) for c in lay[1:4]] \
-            + [_bhsd_spec(mesh, ("dp", "fsdp"), None)]
-        if eva:     # the summaries as k and v
-            in_specs[3:] = [None, tuple(in_specs[1:3])]
+        spec = functools.partial(_bhsd_spec, mesh, ("dp", "fsdp"))
+        in_specs = [spec("tp", tokens=c is not None) for c in lay[1:4]]
+        # the shared part's one head: whole on every device of a tp group;
+        # the summaries as k and v; the gate's heads as a rank-3 operand's
+        in_specs += [x if have else None for x, have in zip(
+            (spec(None), tuple(in_specs[1:3]), spec("tp", tokens=True)),
+            (k_shared is not None, eva, gate is not None))]
         out = jax.shard_map(
-            f, mesh=mesh, in_specs=tuple(in_specs[:len(operands)]),
+            f, mesh=mesh, in_specs=tuple(in_specs),
             out_specs=_bhsd_spec(mesh, ("dp", "fsdp"), "tp",
                                  tokens=lay.out is not None),
             check_vma=False)(*operands)
@@ -1931,7 +2024,7 @@ def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
               diffusion_block: int = 0, sm_scale: Optional[float] = None,
               k_shared=None, head_dim: Optional[int] = None,
               ring_axis: str = "sp", eva_window: int = 0, eva_chunk: int = 0,
-              k_pooled=None, v_pooled=None):
+              k_pooled=None, v_pooled=None, gate=None):
     """What a model's attention layer calls.  Each of q, k, v is rank 4,
     (B, H, S, D), or rank 3, (B, S, H * D) as its projection wrote it (or a
     ``HeadColumns`` of a wider array); a rank-3 q comes with ``head_dim``.
@@ -1942,7 +2035,10 @@ def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
     (``diffusion_block`` > 0: ``block_diffusion_mask`` in place of the causal
     mask; ``eva_window`` > 0 with ``eva_chunk`` and the summaries ``k_pooled``,
     ``v_pooled``: ``eva_mask``, and a row of at most one window is the causal
-    call, the summaries unread).  Which implementation runs is decided here
+    call, the summaries unread; ``gate`` (B, S, H): the logits of a sigmoid
+    gate, one a head a query, on the result — the kernels take it in their
+    own passes, "reference" and "ring" as the multiply it is).  Which
+    implementation runs is decided here
     and nowhere else: ``impl`` is a config's ``attention_impl`` — "reference", "ring" or "flash",
     and "flash" under an ambient mesh that shards the sequence
     (``ring_axis`` > 1) is the ring, since the kernels want the sequence whole.
@@ -1978,6 +2074,7 @@ def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
                 q, k, v, causal=causal, sm_scale=sm_scale,
                 diffusion_block=diffusion_block, window=window,
                 k_shared=k_shared, head_dim=head_dim, tokens_out=True,
+                gate=gate,
                 **(dict(eva_window=eva_window, eva_chunk=eva_chunk,
                         k_pooled=k_pooled, v_pooled=v_pooled)
                    if eva_window else {}))
@@ -1991,11 +2088,16 @@ def attention(q, k, v, *, impl: str, causal: bool = True, window: int = 0,
         eva = dict(eva=(eva_window, eva_chunk), pooled=tuple(
             _pooled_as(x, heads, x.shape[1 if x.ndim == 3 else 2], False)
             for x in (k_pooled, v_pooled))) if eva_window else {}
-        return _to_tokens(mha_reference(
+        out = mha_reference(
             q, k, v, causal=causal, sm_scale=sm_scale, mask=mask,
-            window=window, k_shared=k_shared, **eva))
-    if impl == "ring":
-        return _to_tokens(ring_attention_sharded(
-            q, k, v, causal=causal, sm_scale=sm_scale, seq_axis=ring_axis))
-    raise ValueError(f"unknown attention_impl {impl!r} (expected "
-                     "'flash', 'ring' or 'reference')")
+            window=window, k_shared=k_shared, **eva)
+    elif impl == "ring":
+        out = ring_attention_sharded(
+            q, k, v, causal=causal, sm_scale=sm_scale, seq_axis=ring_axis)
+    else:
+        raise ValueError(f"unknown attention_impl {impl!r} (expected "
+                         "'flash', 'ring' or 'reference')")
+    if gate is not None:    # what a gate is: each head's output by its own
+        out = out * jax.nn.sigmoid(gate.astype(jnp.float32)).astype(
+            out.dtype).transpose(0, 2, 1)[..., None]
+    return _to_tokens(out)

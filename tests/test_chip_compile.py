@@ -240,6 +240,13 @@ def _build(case: str, compile_: bool) -> dict:
     out = {"case": case, "lowered_kernels": dict(collections.Counter(
         re.findall(r'kernel_name = "(\w+)"', text))),
         "flash_fwd_calls": _flash_fwd_calls(traced.jaxpr.jaxpr)}
+    if getattr(config, "attn_gate", False):
+        # what the step does under an attention layer's ``gate`` scope, by
+        # the largest operand or result: a value a head a token and no more
+        out["flash_bwd_calls"] = _kernel_calls(
+            traced.jaxpr.jaxpr)["flash_bwd"]
+        out["gate_scope_widest"] = _widest_under(
+            traced.jaxpr.jaxpr, r"/attn/(?:\w+/)*gate(?:/|$)")
     if "mamba" in getattr(config, "layer_types", ()):
         # every array of the module a scan's chunk on a side and square: the
         # decay masks, the scores and their products, where XLA holds them
@@ -257,7 +264,52 @@ def _build(case: str, compile_: bool) -> dict:
             r'custom_call_target="tpu_custom_call"', compiled.as_text()))
         out["temp_bytes"] = int(mem.temp_size_in_bytes)
         out["argument_bytes"] = int(mem.argument_size_in_bytes)
+        if getattr(config, "attn_gate", False):
+            out.update(_attn_ops(compiled.as_text()))
     return out
+
+
+def _widest_under(jaxpr, scope: str, prefix: str = "") -> int:
+    """The most elements of an operand or result of any equation under
+    ``jaxpr`` whose name stack matches ``scope`` (0: there is none)."""
+    import re
+
+    import jax
+
+    widest = 0
+    for eqn in jaxpr.eqns:
+        name = f"{prefix}/{eqn.source_info.name_stack}"
+        subs = list(jax.core.jaxprs_in_params(eqn.params))
+        if eqn.primitive.name != "pallas_call":
+            widest = max([widest] + [_widest_under(sub, scope, name)
+                                     for sub in subs])
+        if not subs and re.search(scope, name):
+            widest = max([widest] + [v.aval.size for v in (
+                *eqn.invars, *eqn.outvars) if hasattr(v.aval, "size")])
+    return widest
+
+
+def _attn_ops(hlo: str) -> dict:
+    """Of a compiled step: the Mosaic calls whose ``op_name`` lies under a
+    block's ``attn``, and the most elements any instruction named under an
+    attention layer's ``gate`` scope reads or writes."""
+    import re
+
+    written, gated, calls = {}, [], 0
+    for m in re.finditer(
+            r"^\s*(?:ROOT )?(%[\w.\-]+) = (.*?) [\w\-]+\((.*?)\)(?:, .*)?"
+            r"op_name=\"([^\"]*)\"", hlo, re.M):
+        name, shapes, operands, path = m.groups()
+        written[name] = _elements(shapes)
+        if "tpu_custom_call" in m.group(0) and re.search(r"/h_\d+/attn/", path):
+            calls += 1
+        if re.search(r"/attn/(?:\w+/)*gate/", path):
+            gated.append((name, operands))
+    return {"attn_custom_calls": calls,
+            "gate_ops_widest": max([0] + [
+                n for name, operands in gated for ref in (
+                    name, *re.findall(r"%[\w.\-]+", operands))
+                for n in written.get(ref, [])])}
 
 
 def _kernel_calls(jaxpr):
@@ -666,7 +718,6 @@ def _build_mlp_block(case: str, device) -> dict:
 
     import jax
     import jax.numpy as jnp
-    import numpy as np
     from jax.sharding import SingleDeviceSharding
 
     from ray_tpu.models.gpt2 import remat_block
@@ -693,11 +744,7 @@ def _build_mlp_block(case: str, device) -> dict:
 
     text = jax.jit(both_ways).lower(params, params, x, x).compile().as_text()
 
-    def elements(shapes: str):
-        return [int(np.prod([int(n) for n in dims.split(",") if n] or [1]))
-                for dims in re.findall(r"\w+\[([\d,]*)\]", shapes)]
-
-    written = {m.group(1): elements(m.group(2)) for m in re.finditer(
+    written = {m.group(1): _elements(m.group(2)) for m in re.finditer(
         r"^\s*(?:ROOT )?(%[\w.\-]+) = (.*?) [\w\-]+\(", text, re.M)}
     fusions = []
     for m in re.finditer(
@@ -707,13 +754,23 @@ def _build_mlp_block(case: str, device) -> dict:
         if "rematted_computation" in m.group(3):
             continue
         fusions.append({
-            "under": m.group(4), "writes": elements(m.group(1)),
+            "under": m.group(4), "writes": _elements(m.group(1)),
             "wide_operands": sum(
                 n == seq * cfg.d_ff for name in re.findall(
                     r"%[\w.\-]+", m.group(2)) for n in written.get(name, [])),
             "cycles": int(m.group(5))})
     return {"case": case, "dx_elements": seq * cfg.d_model,
             "fusions": fusions}
+
+
+def _elements(shapes: str):
+    """The element counts of the arrays an HLO instruction's shapes name."""
+    import re
+
+    import numpy as np
+
+    return [int(np.prod([int(n) for n in dims.split(",") if n] or [1]))
+            for dims in re.findall(r"\w+\[([\d,]*)\]", shapes)]
 
 
 def _child(cases, compile_: bool) -> dict:
@@ -1018,7 +1075,11 @@ def test_laguna_step_lowers_for_one_v5e_chip():
     kernels = row["lowered_kernels"]
     assert kernels.pop("kernel") > 0
     assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens"}, kernels
-    assert row["flash_fwd_calls"] == 5, row
+    assert row["flash_fwd_calls"] == 5 and row["flash_bwd_calls"] == 5, row
+    # the gate's own ops are a sigmoid of one value a head a token (2 rows x
+    # 8192 x 64 heads): the multiply is the kernels', not a pass over
+    # (B, S, H * D)
+    assert 0 < row["gate_scope_widest"] <= 2 * 8192 * 64, row
 
 
 @pytest.mark.slow
@@ -1037,6 +1098,13 @@ def test_laguna_step_compiles_and_fits_the_chip():
     # into tokens, as SDAR's: 5 x 2 + 4 x (12 + 2)
     assert row["tpu_custom_calls"] == 5 * 2 + 4 * (12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
+    # under ``attn`` the flash pair and no call beside it (what the
+    # benchmark's forward selectors take for flash forwards: D12 (0)); and
+    # the gate's four fusions over (B, S, H * D) are gone, not renamed: no
+    # instruction named under a ``gate`` scope is wider than a value a head
+    # a token
+    assert row["attn_custom_calls"] == 10, row
+    assert row["gate_ops_widest"] <= 2 * 8192 * 64, row
 
 
 def test_kimi_vl_step_lowers_for_one_v5e_chip():

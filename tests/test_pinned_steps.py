@@ -23,7 +23,7 @@ PINNED = {
     "toy-granite": "7a646a83d84178cf45adcbe76b26d3fedef41d6e0ef427a125c34ab1cbddb34d",
     "toy-kimi-linear": "5c0d97570c411cf0f592f071ab7dd6ff64b04db88fe014a145253f9c80ce579d",
     "toy-kimi-vl": "5a017906206e0d13c1f718b10c38f6a80c6893aafaa08d252665c2a7a745d148",
-    "toy-laguna": "4d7f074a49c3ae1f68e4b5e66cd1af93e583972f0ae498461edcd62e2babffc0",
+    "toy-laguna": "df60f397d9bdb65309344c8928dad00d38661abd054cc1ee31eeb5407ae51203",
     "toy-lfm2": "ad07a2bf3eae0808f1e15c23cd22a6c700ac2fbf8f402d9cf0bcc4b9ea0af5d6",
     "toy-llama": "3f093a50754c69664f39a2c72264ae3a12b3e0d67a722d3f326ae8d1ef0fb00e",
     "toy-olmoe": "41054e8f510af2a7d7be4f320326eb4237e4c1efc006d16bd81fbd3d9bea858f",
