@@ -1,0 +1,93 @@
+"""The Nemotron-H cell's whole path — ``ray_tpu.init()`` -> ``JaxTrainer`` ->
+one train worker -> ``agreement.check``, warm-up, window, measurements —
+rehearsed on the CPU at a toy size (``toy/toy-nemotron-h.json``: nine
+one-branch layers ``MEMEM*EME``, Mamba-2 in four groups, 8 query heads over
+2, 2 of 8 squared-ReLU experts held beside a shared one, a quarter of the
+vocabulary), and then *refused*: no line is made of a run that had no TPU.
+And what the parent's program does with the new configuration: it fails at
+once."""
+
+import functools
+import json
+import os
+import time
+
+import pytest
+
+from perfbench.harness import driver, manifest
+
+TOY = os.path.join(os.path.dirname(os.path.abspath(__file__)), "toy")
+CELL = "nemotron3-nano-s16k-1chip"
+
+
+def _toy_cell():
+    bench = manifest.benchmark()
+    load = lambda *p: json.load(open(os.path.join(*p)))  # noqa: E731
+    return manifest.Cell(
+        "toy", 1, load(TOY, "toy-nemotron-h.json"), load(TOY, "toy-gen.json"),
+        bench["end_to_end"],
+        [dict(m, file=load(manifest.BENCH_DIR, "layer_metrics",
+                           m["name"] + ".json")) for m in bench["per_layer"]
+         if CELL in m.get("workloads", [CELL])])
+
+
+def test_cpu_rehearsal_runs_and_is_refused(tmp_path, monkeypatch):
+    import ray_tpu.train
+    from ray_tpu.train.jax_config import JaxConfig
+
+    scaling = ray_tpu.train.ScalingConfig
+    monkeypatch.setattr(
+        ray_tpu.train, "ScalingConfig",
+        lambda num_workers, tpus_per_worker: scaling(num_workers=num_workers))
+    monkeypatch.setattr(ray_tpu.train, "JaxTrainer", functools.partial(
+        ray_tpu.train.JaxTrainer,
+        jax_config=JaxConfig(platform="cpu", cpu_devices_per_worker=1)))
+    monkeypatch.setenv("RAY_TPU_TMPDIR", str(tmp_path / "ray_tpu"))
+    cell = _toy_cell()
+    m = driver.run_cell(cell, seed=2 ** 31 + 63, seconds=2.0, trace=False,
+                        t_start=time.time())
+    assert m["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
+    assert m["steps"] > 0 and m["failed"] == 0
+    assert m["tokens"] == m["steps"] * 4 * 64
+    assert m["agreement"]["ok"], m["agreement"]
+    assert m["agreement"]["prefix"] == 32
+    assert not m["compiled_in_window"]
+    assert m["loss_last_tenth"] < m["loss_first_tenth"]
+    checks = driver.verdict(cell, m)
+    assert not checks["device_is_the_cells"]
+    assert all(v for k, v in checks.items() if k != "device_is_the_cells")
+    with pytest.raises(driver.Refused):
+        driver.result_line(cell, m, False)
+    line = driver.result_line(cell, dict(m, device={
+        "platform": "tpu", "kind": "TPU v5 lite", "count": 1}), False)
+    assert set(line["metrics"]) == {e["name"] for e in cell.end_to_end}
+    assert all(v["value"] > 0 for v in line["metrics"].values())
+    assert line["correct"]
+    json.dumps(line)
+
+
+def test_the_parents_program_cannot_build_the_configuration(monkeypatch):
+    """On the parent's checkout ``LlamaBlock`` knows no layer without a
+    mixer: the first forward that ``init_params`` traces raises
+    ``ValueError`` for the layer type ``'none'``, in the worker's first
+    lines, before any device work.  (The parent's block, in small: the kinds
+    it knew.)"""
+    import jax
+
+    import ray_tpu.models.llama as llama
+    from perfbench.harness.families import nemotron_h
+    from ray_tpu.models.pretrain import init_params
+
+    cfg = nemotron_h.model_config(_toy_cell().config, 1)
+    assert cfg.layer_types[1] == "none" and cfg.mlp_types[0] == "none"
+    assert cfg.expert_activation == "relu2"
+    call = llama.LlamaBlock.__call__
+
+    def parents(self, x, positions, carried=None):
+        if self.mixer == "none" or self.mlp == "none":
+            raise ValueError(f"unknown layer type {self.mixer!r}")
+        return call(self, x, positions, carried)
+
+    monkeypatch.setattr(llama.LlamaBlock, "__call__", parents)
+    with pytest.raises(ValueError, match="unknown layer type"):
+        jax.eval_shape(lambda: init_params(cfg)[1])

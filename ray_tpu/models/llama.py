@@ -101,6 +101,18 @@ gates the routed experts by a ReLU (ReGLU) (SmallThinker-21BA3B is the block
 with these: whole-row layers without rotation beside layers under a window
 of 4,096 with it, 28 query heads over 4 key/value heads, 64 softmax-routed
 experts of which a chip holds a part).
+**A layer may be one branch alone**, ``x + f(norm(x))`` behind one norm: an
+entry ``"none"`` of ``mlp_types`` leaves a layer its token mixer and no
+feed-forward (no ``mlp_norm``, no ``mlp`` / ``moe``), an entry ``"none"`` of
+``layer_types`` leaves it its feed-forward and no mixer (no ``attn_norm``);
+``remat_block`` then wraps that one branch.  ``expert_activation="relu2"``
+makes an expert ``W_down relu(W_up n)^2``, two matrices and no gate, the
+shared expert too (``models/moe.py``), and a Mamba-2 layer of
+``mamba_n_groups`` groups norms each group's channels apart after its gate
+(NVIDIA-Nemotron-3-Nano-30B-A3B, ``nemotron_h``, is the stack with these:
+Mamba-2 layers in eight groups, sigmoid-routed squared-ReLU experts beside a
+shared one, and a few layers of 32 query heads over 2 without rotation, each
+layer one of the three).
 Every such field at its default leaves the program the dense Llama it was.
 ``remat`` recomputes each block from its input in the backward; what
 ``remat_policy="full"`` keeps beside that input is each attention layer's
@@ -197,12 +209,14 @@ class LlamaConfig:
     # (models/mamba.py), "conv" (ShortConvMixer) or "kda" (models/kda.py),
     # one entry a layer; empty: attention in every layer.  "mamba1"
     # (Mamba1Mixer) reads mamba_d_state, mamba_d_conv and, as its scan's block
-    # of positions, mamba_chunk; "gmu" and "cross_attention" read a producer
+    # of positions, mamba_chunk; "gmu" and "cross_attention" read a producer;
+    # "none": the layer is its feed-forward alone, behind mlp_norm
     layer_types: Tuple[str, ...] = ()
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
     mamba_d_state: int = 0           # N: a head's state is d_head x d_state
-    mamba_n_groups: int = 1          # groups of heads that share B and C
+    # groups of heads that share B and C, and a gated norm of their own
+    mamba_n_groups: int = 1
     mamba_d_conv: int = 4
     mamba_chunk: int = 256
     rope: bool = True                # False: no position encoding (NoPE)
@@ -229,8 +243,9 @@ class LlamaConfig:
     # named rotates the whole head by rope_theta
     rope_tables: Tuple[Tuple[str, Optional[RopeTable]], ...] = ()
     attn_gate: bool = False          # a sigmoid gate a head on the attention's output
-    # each layer's feed-forward, "dense" or "sparse" (the routed experts), one
-    # entry a layer; empty: by moe_every
+    # each layer's feed-forward, "dense", "sparse" (the routed experts) or
+    # "none" (the layer is its mixer alone, behind attn_norm), one entry a
+    # layer; empty: by moe_every
     mlp_types: Tuple[str, ...] = ()
     router_scoring: str = "softmax"  # or "sigmoid": the experts' scores
     routed_scale: float = 1.0        # x the chosen experts' weights
@@ -278,7 +293,9 @@ class LlamaConfig:
     # the routed layers' routers read the block's first norm's output, the
     # attention's own input, and not the second's (the experts still do)
     router_before_attention: bool = False
-    expert_activation: str = "silu"  # or "relu": the routed experts' gate
+    # the routed experts' gate, "silu" or "relu"; "relu2": experts (the
+    # shared one too) of two matrices and no gate, relu(up) ** 2
+    expert_activation: str = "silu"
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -779,9 +796,10 @@ def carried_plan(cfg: LlamaConfig):
 
 class LlamaBlock(nn.Module):
     config: LlamaConfig
-    routed: bool = False    # this layer's feed-forward: routed experts
+    # this layer's feed-forward: "dense", "sparse" (routed experts) or "none"
+    mlp: str = "dense"
     # this layer's token mixer: "mamba", "conv", "kda", "mamba1", "gmu",
-    # "cross_attention" or attention of a kind
+    # "cross_attention", attention of a kind, or "none"
     mixer: str = "attention"
     n_head: int = 0         # this layer's query heads; 0: config.n_head
     depth: int = 0          # this layer's index in the whole model
@@ -796,14 +814,26 @@ class LlamaBlock(nn.Module):
         producer's."""
         cfg = self.config
         handed = None
+        if self.mlp not in ("dense", "sparse", "none") or (
+                self.mlp == self.mixer == "none"):
+            raise ValueError(
+                f"a layer with the mixer {self.mixer!r} and the feed-forward "
+                f"{self.mlp!r} (expected 'dense', 'sparse' or, for one of "
+                "the two, 'none')")
 
         def add(x, branch):
             if cfg.residual_multiplier != 1.0:
                 branch = branch * cfg.residual_multiplier
             return x + branch
 
-        y = attn_in = rms_norm(cfg, "attn_norm")(x)
-        if self.mixer == "mamba":
+        # (a layer that is its feed-forward alone has no first norm)
+        y = attn_in = None if self.mixer == "none" \
+            else rms_norm(cfg, "attn_norm")(x)
+        if self.mixer == "none":
+            if cfg.router_before_attention:
+                raise ValueError("a layer without a mixer has no attention "
+                                 "input for its router to read")
+        elif self.mixer == "mamba":
             x = add(x, Mamba2Mixer(cfg, name="mamba")(y))
         elif self.mixer == "conv":
             x = add(x, ShortConvMixer(cfg, name="conv")(y))
@@ -826,11 +856,13 @@ class LlamaBlock(nn.Module):
             x = add(x, attn(y, positions))
         else:
             raise ValueError(f"unknown layer type {self.mixer!r} (expected "
-                             "'mamba1', 'gmu', 'cross_attention' (under "
-                             "diff_attn), 'kda', 'mamba', 'conv' or one of "
-                             f"{ATTENTION_KINDS})")
+                             "'none', 'mamba1', 'gmu', 'cross_attention' "
+                             "(under diff_attn), 'kda', 'mamba', 'conv' or "
+                             f"one of {ATTENTION_KINDS})")
+        if self.mlp == "none":      # the layer is its mixer alone
+            return (x, handed) if self.hands_on else x
         y = rms_norm(cfg, "mlp_norm")(x)
-        if self.routed:
+        if self.mlp == "sparse":
             x = add(x, RoutedSwiGLU(RoutedConfig(
                 n_experts=cfg.n_experts, top_k=cfg.moe_top_k,
                 d_model=cfg.d_model, d_ff=cfg.d_expert,
@@ -891,14 +923,14 @@ class LlamaLMModel(nn.Module):
         reads, handed = carried_plan(cfg), {}
         for i in range(cfg.n_layer):
             if cfg.mlp_types:
-                routed = cfg.mlp_types[i] == "sparse"
+                mlp = cfg.mlp_types[i]
             else:
-                routed = cfg.moe_every > 0 \
-                    and i % cfg.moe_every == cfg.moe_every - 1
+                mlp = "sparse" if cfg.moe_every > 0 \
+                    and i % cfg.moe_every == cfg.moe_every - 1 else "dense"
             mixer = cfg.layer_types[i] if cfg.layer_types else "attention"
             n_head = cfg.n_head_per_layer[i] if cfg.n_head_per_layer else 0
             block = block_cls(
-                cfg, routed, mixer, n_head,
+                cfg, mlp, mixer, n_head,
                 cfg.layer_depths[i] if cfg.layer_depths else i,
                 i in cfg.producers, name=f"h_{i}")
             x = block(x, positions) if reads[i] is None \

@@ -7,7 +7,8 @@ inside ``models/llama.py``'s block.  With ``n`` the block's normed input:
     X | B | C = xBC                      X: heads x d_head; B, C: groups x d_state
     dt = softplus(dt + dt_bias);  a_t = exp(dt_t * A),  A = -exp(A_log)
     h_t = a_t h_{t-1} + dt_t X_t B_t^T;  y_t = h_t C_t + D X_t
-    out = W_out RMSNorm_w(y * silu(z))   the norm over all heads, after the gate
+    out = W_out RMSNorm_w(y * silu(z))   the norm after the gate, over each
+                                         group's heads apart (one group: all)
 
 The recurrence is ``ops/ssd.py``'s chunked scan, two Pallas kernels, and the
 convolution with its bias and silu ``ops/conv.py::conv_silu``'s two (here as
@@ -169,9 +170,11 @@ class Mamba2Mixer(nn.Module):
             # float32 inside, as nn.RMSNorm; the gate before the norm
             g = y.reshape(batch, seq, inner).astype(jnp.float32) \
                 * jax.nn.silu(z.astype(jnp.float32))
+            if groups > 1:      # a group of heads' channels are normed apart
+                g = g.reshape(batch, seq, groups, inner // groups)
             g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
                                   + cfg.rms_eps)
-            y = (g * scale).astype(cfg.dtype)
+            y = (g.reshape(batch, seq, inner) * scale).astype(cfg.dtype)
         return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                         name="out_proj")(y)
 

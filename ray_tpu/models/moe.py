@@ -68,7 +68,12 @@ job's rule that this layer does not have.  ``norm_topk_eps`` is added to the
 sum the chosen scores are divided by.  ``activation`` names what gates an
 expert's hidden units: ``"silu"``, or ``"relu"`` (ReGLU, ``relu(gate) * up``:
 SmallThinker's experts), whose derivative the held loop's backward writes
-out.  The router may read another tensor than the experts do
+out; or ``"relu2"``, an expert WITHOUT a gate, ``W_down relu(W_up n)^2`` (the
+squared ReLU of Nemotron-H's experts): two matrices an expert and no
+``gate_proj`` parameter, in the routed layer, in the held loop (two grouped
+matmuls forward where a gated expert has three, four backward where it has
+six, the derivative ``2 relu(a)`` written out) and in the shared expert alike.
+The router may read another tensor than the experts do
 (``RoutedSwiGLU.__call__``'s ``router_input``).
 
 ``MoEMlpBlock`` — the older GShard / Switch form, wired into GPT-2 only
@@ -85,6 +90,7 @@ statistics (``models/pretrain.py``).
 from __future__ import annotations
 
 import functools
+import operator
 from dataclasses import dataclass
 from typing import Any, NamedTuple, Optional, Tuple
 
@@ -227,20 +233,43 @@ class RoutedConfig:
     selection_bias: bool = False
     norm_topk_eps: float = 0.0      # + the chosen scores' sum it divides by
     # what gates an expert's hidden units: "silu" (SwiGLU) or "relu" (ReGLU:
-    # ``relu(gate) * up``, exact zeros where the gate is not positive)
+    # ``relu(gate) * up``, exact zeros where the gate is not positive); or
+    # "relu2", which has no gate: ``relu(up) ** 2``, two matrices an expert
     activation: str = "silu"
+
+
+# the activations of an expert without a gate: ``(up_proj, down_proj)`` are
+# its matrices, where a gated expert has ``(gate_proj, up_proj, down_proj)``
+GATELESS = ("relu2",)
 
 
 def _gated(a, activation: str):
     """``act(a)``: what an expert's ``up`` projection is multiplied by."""
     if activation not in ("silu", "relu"):
         raise ValueError(f"unknown activation {activation!r} (expected "
-                         "'silu' or 'relu')")
+                         "'silu', 'relu' or 'relu2')")
     return jax.nn.silu(a) if activation == "silu" else jax.nn.relu(a)
 
 
-def _glu_bwd(a, b, d_h, activation: str):
-    """``(d_a, d_b)`` of ``_gated(a) * b`` under the cotangent ``d_h``."""
+def _hidden(pre, activation: str):
+    """An expert's hidden units from what its first matrices made of its
+    rows: ``act(gate) * up`` of ``(gate, up)``, ``relu(up) ** 2`` of
+    ``(up,)``."""
+    if activation in GATELESS:
+        (a,) = pre
+        return jnp.square(jax.nn.relu(a))
+    a, b = pre
+    return _gated(a, activation) * b
+
+
+def _hidden_bwd(pre, d_h, activation: str):
+    """``_hidden``'s transpose: a cotangent for each of ``pre`` under
+    ``d_h``."""
+    if activation in GATELESS:
+        # written out: 2 relu(a), which is 0 where a is not positive
+        (a,) = pre
+        return (2 * jax.nn.relu(a) * d_h,)
+    a, b = pre
     if activation == "relu":
         # written out: the gate passes where it is positive (0 at 0, as
         # ``jax.nn.relu``'s own rule has it), ``up`` takes the gated units
@@ -371,11 +400,14 @@ _permute_rows.defvjp(lambda rows, perm, inverse: (rows[perm], inverse),
                      lambda inverse, g: (g[inverse], None, None))
 
 
-def routed_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig):
+def routed_experts(x, weights, idx, mats, cfg: RoutedConfig):
     """One device's tokens through all the experts.  x (..., D); weights, idx
-    (..., k): each token's gate weights and chosen experts; gate, up
-    (E, D, F) and down (E, F, D) in the compute dtype.  Every one of the
-    ``T * k`` buffer rows holds a token: one pass over the whole of it."""
+    (..., k): each token's gate weights and chosen experts; ``mats``: the
+    experts' matrices in the compute dtype, gate, up (E, D, F) and down
+    (E, F, D), or up and down alone under an activation without a gate.
+    Every one of the ``T * k`` buffer rows holds a token: one pass over the
+    whole of it."""
+    *first, down = mats
     lead, d = x.shape[:-1], x.shape[-1]
     k = cfg.top_k
     x = x.reshape(-1, d)
@@ -388,8 +420,13 @@ def routed_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig):
                         dtype=jnp.int32)
         rows = _rows_to_expert_order(x, order, inverse, k)
     with jax.named_scope("experts"):
-        h = _gated(grouped_matmul(rows, gate, sizes), cfg.activation) \
-            * grouped_matmul(rows, up, sizes)
+        if cfg.activation in GATELESS:
+            h = _hidden((grouped_matmul(rows, first[0], sizes),),
+                        cfg.activation)
+        else:   # (traced in the order it always was: gate, act, up)
+            gate, up = first
+            h = _gated(grouped_matmul(rows, gate, sizes), cfg.activation) \
+                * grouped_matmul(rows, up, sizes)
         rows = grouped_matmul(h, down, sizes)
     with jax.named_scope("combine"):
         rows = _permute_rows(rows, inverse, order).reshape(-1, k, d)
@@ -469,16 +506,17 @@ def _beside(scope: str, name: str):
 def _piece_forward(x, weights, route: _Route, i, c: int, k: int, scope: str,
                    activation: str):
     """The piece's rows through their experts: the token rows gathered, the
-    three grouped matmuls over ``c`` rows, ``act(gate) * up``.  Rows past the
-    live ones belong to no group: the kernels leave them unwritten."""
-    gate, up, down = weights
+    grouped matmuls over ``c`` rows (three, or two where the experts have no
+    gate), ``act(gate) * up`` or ``relu(up) ** 2``.  Rows past the live ones
+    belong to no group: the kernels leave them unwritten."""
+    *first, down = weights
     piece = _piece(route, i, c)
     with _beside(scope, "dispatch"):
         rows = x[piece.slots // k]
     with _beside(scope, "experts"):
-        a, b = _gmm(rows, gate, piece.sizes), _gmm(rows, up, piece.sizes)
-        h = _gated(a, activation) * b
-        return piece, rows, (a, b), h, _gmm(h, down, piece.sizes)
+        pre = tuple(_gmm(rows, w, piece.sizes) for w in first)
+        h = _hidden(pre, activation)
+        return piece, rows, pre, h, _gmm(h, down, piece.sizes)
 
 
 def _tile(n: int, most: int) -> int:
@@ -654,13 +692,13 @@ def _through_held_fwd(x, gates, weights, route, c, k, scope, activation):
 
 def _through_held_bwd(c, k, scope, activation, res, g):
     x, gates, weights, route = res
-    gate, up, down = weights
-    n_held = gate.shape[0]
+    *first, down = weights
+    n_held = down.shape[0]
 
     def body(i, carry):
-        dx, dgates, (dgate, dup, ddown) = carry
-        piece, rows, (a, b), h, out = _piece_forward(x, weights, route, i, c,
-                                                     k, scope, activation)
+        dx, dgates, (*d_first, ddown) = carry
+        piece, rows, pre, h, out = _piece_forward(x, weights, route, i, c,
+                                                  k, scope, activation)
         with _beside(scope, "combine"):
             # the transpose of the weighted sum: a live row's gradient is its
             # gate times its token's ``g``, a gate's its row times ``g``
@@ -677,16 +715,17 @@ def _through_held_bwd(c, k, scope, activation, res, g):
             d_h = _gmm(d_out, down, piece.sizes, transpose_rhs=True)
             ddown = _tgmm(h, d_out, piece.sizes, down.dtype, n_held,
                           onto=ddown)
-            d_a, d_b = _glu_bwd(a, b, d_h, activation)
-            d_rows = _gmm(d_a, gate, piece.sizes, transpose_rhs=True) \
-                + _gmm(d_b, up, piece.sizes, transpose_rhs=True)
-            dgate = _tgmm(rows, d_a, piece.sizes, gate.dtype, n_held,
-                          onto=dgate)
-            dup = _tgmm(rows, d_b, piece.sizes, up.dtype, n_held, onto=dup)
+            d_pre = _hidden_bwd(pre, d_h, activation)
+            d_rows = functools.reduce(operator.add, (
+                _gmm(d, w, piece.sizes, transpose_rhs=True)
+                for d, w in zip(d_pre, first)))
+            d_first = tuple(
+                _tgmm(rows, d, piece.sizes, w.dtype, n_held, onto=onto)
+                for d, w, onto in zip(d_pre, first, d_first))
         with _beside(scope, "dispatch"):
             # a token's gradient: the float32 sum of its live rows'
             dx = _onto_tokens(dx, d_rows, None, piece, k)
-        return dx, dgates, (dgate, dup, ddown)
+        return dx, dgates, (*d_first, ddown)
 
     dx, dgates, dweights = jax.lax.fori_loop(
         0, _n_pieces(route, c), body,
@@ -698,11 +737,11 @@ def _through_held_bwd(c, k, scope, activation, res, g):
 _through_held.defvjp(_through_held_fwd, _through_held_bwd)
 
 
-def held_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig,
-                 scope: str = ""):
+def held_experts(x, weights, idx, mats, cfg: RoutedConfig, scope: str = ""):
     """One device's tokens through the experts the layer holds
-    (``cfg.experts_held``) -> (their part of the result, the rows the layer
-    ran at).  The assignments are counted and placed at ``T * k``, as
+    (``cfg.experts_held``; ``mats``: their matrices, as ``routed_experts``
+    takes them) -> (their part of the result, the rows the layer ran at).
+    The assignments are counted and placed at ``T * k``, as
     indices; everything as wide as the model or an expert — the gather of
     token rows, the grouped matmuls, ``act(gate) * up``, the weighted sum into
     the tokens — runs on pieces of ``capacity_ladder``'s first rung, the balance
@@ -719,7 +758,7 @@ def held_experts(x, weights, idx, gate, up, down, cfg: RoutedConfig,
                          flat - first, n_held)
         ladder = capacity_ladder(flat.shape[0], n_held, cfg.n_experts)
         route = _route(flat, n_held, ladder[-1])
-    out = _through_held(x, weights.reshape(-1), (gate, up, down), route,
+    out = _through_held(x, weights.reshape(-1), tuple(mats), route,
                         ladder[0], k, scope, cfg.activation)
     return (out.reshape(*lead, d),
             (_n_pieces(route, ladder[0]) * ladder[0]).astype(
@@ -775,16 +814,21 @@ silu_mul.defvjp(_silu_mul_fwd, _silu_mul_bwd)
 
 
 class SharedSwiGLU(nn.Module):
-    """The expert every token passes: a dense SwiGLU."""
+    """The expert every token passes: a dense SwiGLU, or, under an
+    ``activation`` without a gate, ``down_proj(relu(up_proj x) ** 2)``."""
 
     d_model: int
     d_ff: int
     dtype: Any = jnp.bfloat16
+    activation: str = "silu"
 
     @nn.compact
     def __call__(self, x):
         def dense(n, name):
             return nn.Dense(n, use_bias=False, dtype=self.dtype, name=name)
+        if self.activation in GATELESS:
+            return dense(self.d_model, "down_proj")(
+                _hidden((dense(self.d_ff, "up_proj")(x),), self.activation))
         return dense(self.d_model, "down_proj")(
             silu_mul(dense(self.d_ff, "gate_proj")(x),
                      dense(self.d_ff, "up_proj")(x)))
@@ -878,25 +922,26 @@ balance), ``moe_z`` (mean of ``logsumexp(router logits) ** 2``) and
         init = nn.initializers.lecun_normal(in_axis=-2, out_axis=-1,
                                             batch_axis=(0,))
         n_held = cfg.experts_held[1] if cfg.experts_held else n_experts
-        gate, up, down = (
-            self.param(name, init, shape, jnp.float32)
-            for name, shape in (("gate_proj", (n_held, d, f)),
-                                ("up_proj", (n_held, d, f)),
-                                ("down_proj", (n_held, f, d))))
+        # (an expert without a gate has no gate_proj)
+        names = ("gate_proj", "up_proj", "down_proj")[
+            cfg.activation in GATELESS:]
+        mats = tuple(
+            self.param(name, init, (n_held, f, d) if name == "down_proj"
+                       else (n_held, d, f), jnp.float32) for name in names)
         with jax.named_scope("experts"):    # the casts are the experts' cost
-            gate, up, down = (w.astype(cfg.dtype) for w in (gate, up, down))
+            mats = tuple(w.astype(cfg.dtype) for w in mats)
 
         # a layer that holds all its experts fills its buffer by construction
         held = n_held < n_experts
 
-        def experts(x, weights, idx, gate, up, down):
+        def experts(x, weights, idx, *mats):
             if held:    # (its loop's scopes carry the module's path)
-                return held_experts(x, weights, idx, gate, up, down, cfg,
+                return held_experts(x, weights, idx, mats, cfg,
                                     "/".join(self.path))
-            return routed_experts(x, weights, idx, gate, up, down, cfg)
+            return routed_experts(x, weights, idx, mats, cfg)
 
         if mesh is None or mesh.size == 1:
-            out = experts(x, weights, idx, gate, up, down)
+            out = experts(x, weights, idx, *mats)
         else:
             # each device routes its own tokens through all the experts: the
             # weights come in whole (GSPMD gathers their fsdp / tp shards)
@@ -904,15 +949,16 @@ balance), ``moe_z`` (mean of ``logsumexp(router logits) ** 2``) and
 
             tokens = token_spec(mesh)
             out = jax.shard_map(
-                experts, mesh=mesh, in_specs=(tokens,) * 3 + (P(),) * 3,
+                experts, mesh=mesh,
+                in_specs=(tokens,) * 3 + (P(),) * len(mats),
                 out_specs=(tokens, tokens) if held else tokens,
-                check_vma=False)(x, weights, idx, gate, up, down)
+                check_vma=False)(x, weights, idx, *mats)
         if held:    # every device's own capacity, from its own rows
             out, buffer_rows = out
             self.sow("intermediates", "moe_buffer_rows", jnp.sum(buffer_rows))
         if cfg.d_shared:
             out = out + SharedSwiGLU(d, cfg.d_shared, cfg.dtype,
-                                     name="shared")(x)
+                                     cfg.activation, name="shared")(x)
         return out
 
 

@@ -214,6 +214,24 @@ def _build(case: str, compile_: bool) -> dict:
         assert config.expert_activation == "relu"
         assert (config.d_model, config.n_head, config.n_kv_head,
                 config.sliding_window, seq) == (2560, 28, 4, 4096, 16384)
+    elif case == "nemotron":
+        mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+        (config, seq), rows = _cell("nemotron3-nano-s16k-1chip"), 1
+        assert config.layer_types == (
+            "mamba", "none", "mamba", "none", "mamba", "attention", "none",
+            "mamba", "none")
+        assert config.mlp_types == tuple(
+            "sparse" if kind == "none" else "none"
+            for kind in config.layer_types)
+        assert config.experts_held == (0, 8) and config.n_experts == 128
+        assert (config.expert_activation, config.d_expert,
+                config.d_shared_expert) == ("relu2", 1856, 3712)
+        assert (config.mamba_n_heads, config.mamba_d_head,
+                config.mamba_n_groups, config.mamba_d_state,
+                config.mamba_chunk) == (64, 64, 8, 128, 128)
+        assert not config.rope and config.router_selection_bias
+        assert (config.d_model, config.n_head, config.n_kv_head,
+                config.vocab_size, seq) == (2688, 32, 2, 16384, 16384)
     elif case == "granite":
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
         (config, seq), rows = _cell("granite-h-s8k-1chip"), 1
@@ -1313,6 +1331,69 @@ def test_smallthinker_step_compiles_and_fits_the_chip():
     # experts: twelve grouped-matmul calls and the two that add rows into
     # tokens, as SDAR's and Laguna's
     assert row["tpu_custom_calls"] == 4 * (2 + 12 + 2), row
+    assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
+
+
+def test_nemotron_step_lowers_for_one_v5e_chip():
+    """Tier-1: the one-chip step of NVIDIA-Nemotron-3-Nano-30B-A3B at
+    published widths (published layers 0 to 8, ``MEMEM*EME``, each one branch
+    behind one norm: four Mamba-2 layers in eight groups at chunk 128, four
+    layers of squared-ReLU experts without a gate 1,856 wide of which 8 of
+    128 are held, one attention layer of 32 query heads over 2 without
+    rotation; one row of 16,384) lowers for the TPU with its Mosaic kernels
+    in it: the scan's pair and the convolution's pair for the Mamba layers,
+    the flash pair once, the held experts' grouped matmuls and sums into
+    tokens, and no other; and no chunk-square tensor of the scan is left to
+    XLA."""
+    row = _child(["nemotron"], compile_=False)["nemotron"]
+    kernels = row["lowered_kernels"]
+    assert kernels.pop("kernel") > 0
+    # (a Mamba layer's scan and convolution: forward, the forward again in
+    # the layer's recomputation, backward)
+    assert kernels == {"flash_fwd": 1, "flash_bwd": 1, "onto_tokens": 2,
+                       "ssd_fwd": 2 * 4, "ssd_bwd": 4, "conv_silu_fwd": 2 * 4,
+                       "conv_silu_bwd": 4}, kernels
+    assert row["flash_fwd_calls"] == 1, row
+    # (a row is 128 chunks of 128: dt and the running sums, (1, chunks, chunk,
+    # heads), are no square; a square's last two dimensions are the chunk's)
+    import re
+    assert [t for t in row["chunk_squares"]
+            if re.search(r"x128x128x[a-z]+\d+>$", t)] == [], row
+
+
+def test_flash_backward_sums_a_group_of_sixteens_dk_beside_the_kernel():
+    """Tier-1, a few seconds: Nemotron-H's 32 query heads over 2 key/value
+    heads at 16,384 positions.  A group of sixteen's float32 dQ and its
+    output block would be 16 x 16,384 x 128 x 8 B = 256 MiB, twice the v5e's
+    VMEM: ``_bwd_vmem_bytes``' criterion fails, the backward walks the grid
+    of query heads with one head's dQ in VMEM (32 MiB), writes dK and dV a
+    query head, and the sum over each group of sixteen runs beside the
+    kernel.  The key/value-head grid is not to be had at this length on
+    this chip: the last length at which a group of sixteen's dQ fits is
+    ``(128 MiB - 16 MiB) / (16 x 128 x 8 B)`` = 7,168 positions."""
+    from ray_tpu.ops.attention import _VMEM_BYTES, _bwd_vmem_bytes
+
+    assert _bwd_vmem_bytes(16 * 16384, 128, "bfloat16") > _VMEM_BYTES
+    assert _bwd_vmem_bytes(16 * 7168, 128, "bfloat16") <= _VMEM_BYTES \
+        < _bwd_vmem_bytes(16 * 7168 + 16 * 128, 128, "bfloat16")
+    rows = _child(["flash_s16384_d128_g16_h32"], compile_=True)
+    assert {case: row.get("dk_heads") for case, row in rows.items()} == {
+        "flash_s16384_d128_g16_h32": 32}, rows
+
+
+@pytest.mark.slow
+def test_nemotron_step_compiles_and_fits_the_chip():
+    """The TPU compiler takes the step — the grouped matmuls over experts
+    1,856 wide, 14.5 lanes, their tiles the whole dimension; the scan's two
+    kernels at eight groups of eight heads and chunk 128; the flash backward
+    at a group of sixteen on the query-head grid — and its memory analysis
+    says nine one-branch layers fit one chip at one row of 16,384 beside
+    10.67 GB of state (667.0M parameters x 16 B; PR 63: see PERF.md for the
+    arguments' and the temporaries' bytes)."""
+    row = _child(["nemotron"], compile_=True)["nemotron"]
+    print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused",
+                                   "tpu_custom_calls")})
+    assert "refused" not in row, row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 

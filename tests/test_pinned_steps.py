@@ -26,6 +26,7 @@ PINNED = {
     "toy-laguna": "df60f397d9bdb65309344c8928dad00d38661abd054cc1ee31eeb5407ae51203",
     "toy-lfm2": "ad07a2bf3eae0808f1e15c23cd22a6c700ac2fbf8f402d9cf0bcc4b9ea0af5d6",
     "toy-llama": "3f093a50754c69664f39a2c72264ae3a12b3e0d67a722d3f326ae8d1ef0fb00e",
+    "toy-nemotron-h": "81aec893b1adcc63a48c4d6117e42096e0c376ace04fd8ee2acaf406dd9e6ea4",
     "toy-olmoe": "41054e8f510af2a7d7be4f320326eb4237e4c1efc006d16bd81fbd3d9bea858f",
     "toy-phi4-flash": "79a8ea30f0ff39e594a039012999dd6e27df988b0ecb88a1d11bf6f31d7440d9",
     "toy-sdar": "ff8e09d3e6a65ea9935059aba9f4c46ea2127d572f13434078f2b64b86576cb4",

@@ -455,13 +455,13 @@ def test_p_a_held_layers_gradients_are_the_whole_layers_with_absent_gates_zero()
 
     def held(x, weights, mine):
         return jnp.sum(jnp.sin(moe.held_experts(
-            x, weights, idx, *mine, held_cfg)[0]))
+            x, weights, idx, mine, held_cfg)[0]))
 
     def whole(x, weights, mine):
         return jnp.sum(jnp.sin(moe.routed_experts(
             x, jnp.where(idx < n_held, weights, 0.0), idx,
-            *(jnp.concatenate([m, rest[n_held:]]) for m, rest
-              in zip(mine, mats)), cfg)))
+            tuple(jnp.concatenate([m, rest[n_held:]]) for m, rest
+                  in zip(mine, mats)), cfg)))
 
     mine = tuple(m[:n_held] for m in mats)
     with jax.default_matmul_precision("highest"):

@@ -209,7 +209,7 @@ def test_c_a_kind_with_no_table_turns_nothing_while_another_turns():
     here, later = jnp.arange(32), 2 * jnp.arange(32)
 
     def moved_by_positions(cfg, kind):
-        block = LlamaBlock(cfg, True, kind)
+        block = LlamaBlock(cfg, "sparse", kind)
         params = jax.jit(block.init)(jax.random.PRNGKey(8), x, here)
         a, b = (jax.jit(block.apply)(params, x, p) for p in (here, later))
         return float(jnp.max(jnp.abs(a - b)))
@@ -228,7 +228,7 @@ def test_d_the_four_quarter_shares_add_up_to_the_uncut_layer():
     counted once plus the four parts is the uncut layer; and the held rows'
     logits are the uncut head's on those rows."""
     whole_cfg = toys.config(WHOLE, attention_impl="reference", remat=False)
-    block = LlamaBlock(whole_cfg, True, "sliding_attention")
+    block = LlamaBlock(whole_cfg, "sparse", "sliding_attention")
     x = jax.random.normal(jax.random.PRNGKey(9), (2, 64, 64))
     positions = jnp.arange(64)
     params = toys.moved(jax.jit(block.init)(jax.random.PRNGKey(10), x,
@@ -238,7 +238,7 @@ def test_d_the_four_quarter_shares_add_up_to_the_uncut_layer():
         cfg = dataclasses.replace(whole_cfg, experts_held=(2 * chip, 2))
         moe = {k: v[2 * chip:2 * chip + 2] if k.endswith("_proj") else v
                for k, v in params["moe"].items()}
-        return LlamaBlock(cfg, True, "sliding_attention").apply(
+        return LlamaBlock(cfg, "sparse", "sliding_attention").apply(
             {"params": dict(params, moe=moe)}, x, positions)
 
     with jax.default_matmul_precision("highest"):
