@@ -10,11 +10,13 @@ inside ``models/llama.py``'s block.  With ``n`` the block's normed input:
     out = W_out RMSNorm_w(y * silu(z))   the norm after the gate, over each
                                          group's heads apart (one group: all)
 
-The recurrence is ``ops/ssd.py``'s chunked scan, two Pallas kernels, and the
+The recurrence is ``ops/ssd.py``'s chunked scan, two Pallas kernels, the
 convolution with its bias and silu ``ops/conv.py::conv_silu``'s two (here as
-in ``Mamba1Mixer`` and ``models/kda.py``).  ``causal_conv`` below, the same
-convolution as shifted multiply-adds in plain XLA, is that op's reference
-and its fallback, and what ``gated_short_conv`` is built on.  The
+in ``Mamba1Mixer`` and ``models/kda.py``), and the gated norm a group
+``ops/gated_norm.py::gated_rms_norm``'s two, for every group count.
+``causal_conv`` below, the same convolution as shifted multiply-adds in
+plain XLA, is ``conv_silu``'s reference and its fallback, and what
+``gated_short_conv`` is built on.  The
 scopes ``conv``, ``ssd`` and ``gated_norm`` and the ``Dense`` children
 ``in_proj`` and ``out_proj`` are what the benchmark's per-layer metrics read.
 
@@ -45,6 +47,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.conv import conv_silu
+from ray_tpu.ops.gated_norm import gated_rms_norm
 from ray_tpu.ops.selective_scan import selective_scan
 from ray_tpu.ops.ssd import ssd_scan
 from ray_tpu.parallel.mesh import ambient_mesh
@@ -167,14 +170,8 @@ class Mamba2Mixer(nn.Module):
             y = y + xs * skip.astype(cfg.dtype)[:, None]
         scale = self.param("norm_scale", nn.initializers.ones, (inner,))
         with jax.named_scope("gated_norm"):
-            # float32 inside, as nn.RMSNorm; the gate before the norm
-            g = y.reshape(batch, seq, inner).astype(jnp.float32) \
-                * jax.nn.silu(z.astype(jnp.float32))
-            if groups > 1:      # a group of heads' channels are normed apart
-                g = g.reshape(batch, seq, groups, inner // groups)
-            g = g * jax.lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
-                                  + cfg.rms_eps)
-            y = (g.reshape(batch, seq, inner) * scale).astype(cfg.dtype)
+            y = gated_rms_norm(y.reshape(batch, seq, inner), z, scale,
+                               groups, cfg.rms_eps)
         return nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
                         name="out_proj")(y)
 

@@ -2,8 +2,12 @@
 
 The hot ops of the ML stack: blockwise (flash) attention, ring attention for
 sequence parallelism (absent from the reference — SURVEY §5.7 greenfield), GAE
-scans for RL.  Every op has an XLA fallback used automatically off-TPU and for
-verification.
+scans for RL; and a Mamba-2 mixer's three kernel pairs (``models/mamba.py``):
+the chunked scan (``ssd.ssd_scan``), the short causal convolution with its
+silu (``conv.conv_silu``) and the gated norm a group
+(``gated_norm.gated_rms_norm``), each beside its ``jax.numpy`` form, which
+runs where the op's docstring says the kernels do not apply.  Every op has an
+XLA fallback used automatically off-TPU and for verification.
 """
 
 from ray_tpu.ops.attention import flash_attention, mha_reference, ring_attention

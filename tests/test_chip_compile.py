@@ -132,6 +132,8 @@ def _build(case: str, compile_: bool) -> dict:
         return _build_selective_scan(case, topo.devices[0])
     if case.startswith("conv_s"):
         return _build_conv(case, topo.devices[0])
+    if case.startswith("norm_s"):
+        return _build_gated_norm(case, topo.devices[0])
     if case in PRELUDES:
         return _build_prelude(case, topo.devices[0])
     if case in LAYOUTS:
@@ -493,6 +495,41 @@ def _build_conv(case: str, device) -> dict:
         compiled = jax.jit(grads).lower(
             wide, wide, shape(4, channels),
             *([] if heads else [shape(channels)])).compile()
+    except Exception as e:  # what Mosaic or the TPU compiler refuses
+        return {"case": case, "refused": str(e)[-1500:]}
+    mem = compiled.memory_analysis()
+    return {"case": case, "tpu_custom_calls": len(re.findall(
+        r'custom_call_target="tpu_custom_call"', compiled.as_text())),
+        "temp_bytes": int(mem.temp_size_in_bytes)}
+
+
+def _build_gated_norm(case: str, device) -> dict:
+    """In the child: compile ``ops/gated_norm.py``'s kernels alone, forward
+    and backward, for one chip at ``norm_s<rows>_c<channels>_g<groups>`` in
+    bf16 under a float32 scale."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.gated_norm import gated_rms_norm
+
+    given = {part[0]: int(part[1:]) for part in case.split("_")[1:]}
+
+    def shape(dtype, *dims):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(device))
+
+    def grads(dout, y, z, scale):
+        out, vjp = jax.vjp(functools.partial(
+            gated_rms_norm, groups=given["g"], eps=1e-5), y, z, scale)
+        return out, vjp(dout)
+
+    wide = shape(jnp.bfloat16, 1, given["s"], given["c"])
+    try:
+        compiled = jax.jit(grads).lower(
+            wide, wide, wide, shape(jnp.float32, given["c"])).compile()
     except Exception as e:  # what Mosaic or the TPU compiler refuses
         return {"case": case, "refused": str(e)[-1500:]}
     mem = compiled.memory_analysis()
@@ -1010,20 +1047,22 @@ def test_granite_step_lowers_for_one_v5e_chip():
     """Tier-1: the one-chip Granite-4.0-H-Micro step at published widths
     (five Mamba-2 layers and one attention layer, seq 8192 x 1 row) lowers
     for the TPU with its Mosaic calls in it: the flash kernels, the scan's
-    (``ops/ssd.py``) and the convolution's (``ops/conv.py``), and nothing of
+    (``ops/ssd.py``), the convolution's (``ops/conv.py``) and the gated
+    norm's (``ops/gated_norm.py``, one group of 4,096), and nothing of
     a scan's chunk squares — the decay mask, the scores, their product, the
     cotangents of each — is an array of the XLA program: they live in the
     kernels' VMEM."""
     row = _child(["granite"], compile_=False)["granite"]
     # the one attention layer under remat: one forward, one backward kernel
     # (the block keeps the forward's output and logsumexp); a mamba layer's
-    # scan, and its convolution with the silu: forward, the forward again in
-    # the block's recomputation (the scan's output and the states before
-    # each chunk are the backward's residuals; the convolution's are its
-    # inputs), backward
+    # scan, its convolution with the silu and its gated norm: forward, the
+    # forward again in the block's recomputation (the scan's output and the
+    # states before each chunk are the backward's residuals; the
+    # convolution's and the norm's are their inputs), backward
     assert row["lowered_kernels"] == {
         "flash_fwd": 1, "flash_bwd": 1, "ssd_fwd": 2 * 5, "ssd_bwd": 5,
-        "conv_silu_fwd": 2 * 5, "conv_silu_bwd": 5}, row
+        "conv_silu_fwd": 2 * 5, "conv_silu_bwd": 5,
+        "gated_norm_fwd": 2 * 5, "gated_norm_bwd": 5}, row
     assert row["flash_fwd_calls"] == 1, row
     # (the parent's step had eight kinds of them, up to 32 x 64 x 256 x 256)
     assert row["chunk_squares"] == [], row
@@ -1040,9 +1079,9 @@ def test_granite_step_compiles_and_fits_the_chip():
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused")})
     assert "refused" not in row, row
     # the one attention layer: flash forward and the backward's one kernel;
-    # a mamba layer's scan and its convolution: forward, the forward
-    # recomputed, backward
-    assert row["tpu_custom_calls"] == 2 + 5 * 6, row
+    # a mamba layer's scan, its convolution and its gated norm: forward, the
+    # forward recomputed, backward
+    assert row["tpu_custom_calls"] == 2 + 5 * 9, row
     assert row["argument_bytes"] + row["temp_bytes"] < 14e9, row
 
 
@@ -1260,6 +1299,20 @@ def test_conv_kernels_compile_for_one_v5e_chip():
         assert row["tpu_custom_calls"] == 2, row    # a forward and a backward
 
 
+def test_gated_norm_kernels_compile_for_one_v5e_chip():
+    """Tier-1, ten seconds: Mosaic takes ``ops/gated_norm.py``'s pair at the
+    two cells' shapes — Nemotron-3-Nano's 1 x 16,384 x 4,096 in eight groups
+    of 512 and Granite's 1 x 8,192 x 4,096 in one — the loads and stores
+    from a dynamic row and lane, the one reduction along a group's folded
+    lanes —, which the interpreter on the CPU cannot say; and beside the
+    calls the program holds next to nothing (``dscale``'s eight rows)."""
+    cases = ["norm_s16384_c4096_g8", "norm_s8192_c4096_g1"]
+    for case, row in _child(cases, compile_=True).items():
+        assert "refused" not in row, row
+        assert row["tpu_custom_calls"] == 2, row    # a forward and a backward
+        assert row["temp_bytes"] < 1 << 20, row
+
+
 def test_phi4_flash_step_lowers_for_one_v5e_chip():
     """Tier-1: the one-chip step of Phi-4-mini-flash-reasoning at published
     widths (published layers 14 to 19: Mamba-1, window attention, Mamba-1
@@ -1341,18 +1394,19 @@ def test_nemotron_step_lowers_for_one_v5e_chip():
     layers of squared-ReLU experts without a gate 1,856 wide of which 8 of
     128 are held, one attention layer of 32 query heads over 2 without
     rotation; one row of 16,384) lowers for the TPU with its Mosaic kernels
-    in it: the scan's pair and the convolution's pair for the Mamba layers,
-    the flash pair once, the held experts' grouped matmuls and sums into
+    in it: the scan's pair, the convolution's pair and the gated norm's pair
+    (eight groups of 512) for the Mamba layers, the flash pair once, the held experts' grouped matmuls and sums into
     tokens, and no other; and no chunk-square tensor of the scan is left to
     XLA."""
     row = _child(["nemotron"], compile_=False)["nemotron"]
     kernels = row["lowered_kernels"]
     assert kernels.pop("kernel") > 0
-    # (a Mamba layer's scan and convolution: forward, the forward again in
-    # the layer's recomputation, backward)
+    # (a Mamba layer's scan, convolution and gated norm: forward, the forward
+    # again in the layer's recomputation, backward)
     assert kernels == {"flash_fwd": 1, "flash_bwd": 1, "onto_tokens": 2,
                        "ssd_fwd": 2 * 4, "ssd_bwd": 4, "conv_silu_fwd": 2 * 4,
-                       "conv_silu_bwd": 4}, kernels
+                       "conv_silu_bwd": 4, "gated_norm_fwd": 2 * 4,
+                       "gated_norm_bwd": 4}, kernels
     assert row["flash_fwd_calls"] == 1, row
     # (a row is 128 chunks of 128: dt and the running sums, (1, chunks, chunk,
     # heads), are no square; a square's last two dimensions are the chunk's)
@@ -1394,6 +1448,10 @@ def test_nemotron_step_compiles_and_fits_the_chip():
     print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused",
                                    "tpu_custom_calls")})
     assert "refused" not in row, row
+    # 4 x (3 + 3) scan and convolution calls, the flash pair's 2, 4 x (8 + 2)
+    # grouped-matmul and sum-into-tokens calls: 66 up to PR 63; the gated
+    # norm's 4 x 3
+    assert row["tpu_custom_calls"] == 66 + 12, row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 

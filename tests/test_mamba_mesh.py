@@ -1,8 +1,11 @@
 """Granite 4.0-H's toy through ``ShardedPretrainer`` (split from
 ``tests/test_mamba.py``, which holds the scan, the convolution and the hybrid
 stack against its reference): (i) ``mamba/*`` under the partition rules on a
-virtual mesh, whose steps give one device's losses, run once for both meshes;
-(j) the normal path at toy size learns.
+virtual mesh, whose steps give one device's losses, run once for both meshes
+— the toy's one group of 128 channels takes the gated norm's kernels under
+``fsdp`` and, cut by ``tp``, the reference's lines — and (i2) at eight groups
+of 128, where ``tp`` takes whole groups and the kernels run inside their
+``shard_map``; (j) the normal path at toy size learns.
 """
 
 import jax
@@ -37,6 +40,54 @@ def test_i_a_sharded_mesh_gives_the_single_device_loss(mesh):
         assert spec[leaf] == P(), leaf
     assert many.param_specs["wte"]["embedding"] == P("tp", "fsdp")
     for want in one.losses:     # the second step has been through an update
+        assert float(many.step(one.rows)) == pytest.approx(want, rel=2e-5)
+
+
+def _norm_calls(trainer, rows) -> dict:
+    """{the gated norm takes its kernels, .. inside a ``shard_map``} of the
+    trainer's forward on its mesh."""
+    found = []
+
+    def walk(jaxpr, sharded):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "pallas_call":
+                if eqn.params["name"] == "gated_norm_fwd":
+                    found.append(sharded)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, sharded or eqn.primitive.name == "shard_map")
+
+    with jax.set_mesh(trainer.mesh):
+        walk(jax.make_jaxpr(lambda p: trainer.model.apply(
+            {"params": p}, rows["input_ids"]))(trainer.state[0]).jaxpr, False)
+    return {"kernels": bool(found), "sharded": bool(found) and all(found)}
+
+
+def test_i_the_gated_norm_runs_its_kernels_where_no_group_is_cut():
+    """The toy's one group of 128 channels: the kernels on one device and
+    under ``fsdp``; under ``tp`` = 2 the group would be cut, its statistic
+    needs the other shard, and the reference's lines run (the losses of both
+    meshes are (i)'s)."""
+    one = toys.one_device("toy-granite", 4, 32, 2, seed=4, want=False)
+    assert _norm_calls(one.trainer, one.rows)["kernels"]
+    for mesh, kernels in (({"dp": 1, "fsdp": 2}, True),
+                          ({"dp": 2, "tp": 2}, False)):
+        n = int(np.prod(list(mesh.values())))
+        many = ShardedPretrainer(toys.config("toy-granite"),
+                                 MeshConfig(**mesh), devices=jax.devices()[:n])
+        assert _norm_calls(many, one.rows)["kernels"] == kernels, mesh
+
+
+def test_i2_eight_groups_under_a_tp_that_divides_them_give_one_devices_loss():
+    """Eight groups of 128 channels (64 heads of 16): ``tp`` = 2 takes four
+    whole groups a shard, the norm's kernels run inside their ``shard_map``
+    as the scan's and the convolution's do, and two steps equal the
+    single-device steps.  No chip has run this."""
+    wide = dict(TOY, mamba_expand=16, mamba_n_heads=64, mamba_n_groups=8)
+    one = toys.one_device(wide, 4, 32, 2, seed=4, want=False)
+    many = ShardedPretrainer(toys.config(wide), MeshConfig(dp=2, tp=2),
+                             devices=jax.devices()[:4])
+    assert _norm_calls(many, one.rows) == {"kernels": True, "sharded": True}
+    for want in one.losses:
         assert float(many.step(one.rows)) == pytest.approx(want, rel=2e-5)
 
 

@@ -20,7 +20,7 @@ import toys
 PINNED = {
     "toy-evabyte": "2d776bb3a348b1a6d357fbc10d09c972ef6cd73a1afd586ac3a1f9ccc3c2de59",
     "toy-gpt2": "ba11271144fed4ca835af77562ca30fbb9989cac73bf82a195c1a4e4d5921693",
-    "toy-granite": "7a646a83d84178cf45adcbe76b26d3fedef41d6e0ef427a125c34ab1cbddb34d",
+    "toy-granite": "4055866a1def3c74086a083c0f621efc1c2bee6c785cd361a06910e393a85613",
     "toy-kimi-linear": "5c0d97570c411cf0f592f071ab7dd6ff64b04db88fe014a145253f9c80ce579d",
     "toy-kimi-vl": "5a017906206e0d13c1f718b10c38f6a80c6893aafaa08d252665c2a7a745d148",
     "toy-laguna": "df60f397d9bdb65309344c8928dad00d38661abd054cc1ee31eeb5407ae51203",
