@@ -280,3 +280,16 @@ def shifted_heads_loss(logits, targets, mask, heads: int, vocab: int):
         counted = counted * mask.astype(jnp.float32)[:, ahead]
     return lm_loss(logits, targets[:, ahead],
                    jnp.broadcast_to(counted, (b, s, heads)))
+
+
+def ahead_loss(logits, targets, mask, ahead: int):
+    """``lm_loss`` of logits that score, at position ``t``, ``targets[t +
+    ahead]`` (``targets`` being the row rolled left by one: the token ``ahead
+    + 1`` after ``t``), over the positions where the row has such a token and
+    ``mask[t + ahead]`` counts it."""
+    s = targets.shape[1]
+    counted = (jnp.arange(s) < s - ahead).astype(jnp.float32)
+    if mask is not None:
+        counted = counted * jnp.roll(mask.astype(jnp.float32), -ahead, axis=1)
+    return lm_loss(logits, jnp.roll(targets, -ahead, axis=1),
+                   jnp.broadcast_to(counted, targets.shape))

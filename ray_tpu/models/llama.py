@@ -113,6 +113,23 @@ shared expert too (``models/moe.py``), and a Mamba-2 layer of
 Mamba-2 layers in eight groups, sigmoid-routed squared-ReLU experts beside a
 shared one, and a few layers of 32 query heads over 2 without rotation, each
 layer one of the three).
+``hc_mult`` > 1 makes **the residual path that many streams wide**
+(manifold-constrained hyper-connections, mHC: ``HyperConnection``, the
+modules ``hc_attn`` and ``hc_mlp`` of a block): the stream is (B, S,
+``hc_mult`` x ``d_model``), stream ``j`` the ``j``-th ``d_model`` lanes — the
+embedding copied to each, the streams summed before ``norm_f`` — and each of a
+block's branches reads one ``d_model``-wide mix of them and is written back to
+all while a Sinkhorn-normalised matrix a position mixes the streams among
+themselves; ``q_lora_rank`` > 0 gives latent attention a query latent with a
+norm of its own (``wq_a``, ``q_norm``, ``wq_b``), and a kind's rotary table
+(``rope_tables``) reaches the latent path, YaRN with it; ``n_mtp_modules`` > 0
+puts that many prediction modules behind the trunk (``PredictionModule``,
+DeepSeek-V3's multi-token prediction: a block of the stack's own kind over the
+trunk's output joined with the next token's embedding, the embedding and the
+head shared), which the model returns beside its logits where it is asked
+(``predict_ahead``) and ``models/pretrain.py`` weighs into the objective
+(``MTP_WEIGHT``) (Xing4.0-29B-A4B is the stack with these, a leading dense
+layer and sigmoid-routed experts beside a shared one).
 Every such field at its default leaves the program the dense Llama it was.
 ``remat`` recomputes each block from its input in the backward; what
 ``remat_policy="full"`` keeps beside that input is each attention layer's
@@ -296,6 +313,20 @@ class LlamaConfig:
     # the routed experts' gate, "silu" or "relu"; "relu2": experts (the
     # shared one too) of two matrices and no gate, relu(up) ** 2
     expert_activation: str = "silu"
+    # the residual path's streams (``HyperConnection``: mHC); 1: one stream,
+    # x + f(norm(x)).  Each Sinkhorn iteration divides every row of the
+    # streams' mixing matrix by its sum + hc_eps, then every column; the
+    # matrix's logits are clipped to +- hc_res_clamp before the exp
+    hc_mult: int = 1
+    hc_sinkhorn_iters: int = 20
+    hc_eps: float = 1e-6
+    hc_res_clamp: float = 30.0
+    # latent attention's query latent (wq_a, q_norm, wq_b); 0: wq, whole
+    q_lora_rank: int = 0
+    # prediction modules behind the trunk (``PredictionModule``), module k
+    # scoring the token k + 1 ahead; their mean loss x MTP_WEIGHT is added to
+    # the objective (models/pretrain.py)
+    n_mtp_modules: int = 0
 
     @staticmethod
     def tiny() -> "LlamaConfig":
@@ -653,8 +684,10 @@ class DifferentialAttention(nn.Module):
 
 
 class LatentAttention(nn.Module):
-    """Multi-head latent attention (DeepSeek-V2, section 2.1) with the query
-    projected whole (no query latent): ``wdkv`` takes the layer's input down
+    """Multi-head latent attention (DeepSeek-V2, section 2.1), the query
+    projected whole (``wq``) or, under ``q_lora_rank``, through a latent of
+    that width with a norm of its own (``wq_b(q_norm(wq_a(x)))``): ``wdkv``
+    takes the layer's input down
     to a latent ``c`` of ``kv_lora_rank`` and one rotary key ``kr`` of
     ``qk_rope_head_dim`` a position; ``kv_norm`` norms ``c``; ``wukv`` makes
     each head's key part ``kn`` (``qk_nope_head_dim``) and values
@@ -664,7 +697,8 @@ class LatentAttention(nn.Module):
     values back to the model's width.  The kernels take the key's parts as
     they are (``flash_attention``'s ``k_shared``): nothing is broadcast to
     the heads or joined in HBM, and the scope ``assemble`` that would hold it
-    stays empty."""
+    stays empty.  The rotary table is the kind's own where ``rope_tables``
+    names one (YaRN: the scores' ``mscale ** 2`` comes in ``attn_scale``)."""
     config: LlamaConfig
     kind: str = "attention"     # or "full_attention"
 
@@ -681,7 +715,13 @@ class LatentAttention(nn.Module):
         def heads(a):
             return a.reshape(B, S, H, -1).transpose(0, 2, 1, 3)
 
-        q = heads(dense(H * (dn + dr), "wq")(x))
+        if cfg.q_lora_rank:
+            q = dense(H * (dn + dr), "wq_b")(nn.RMSNorm(
+                epsilon=cfg.rms_eps, dtype=cfg.dtype, name="q_norm")(
+                    dense(cfg.q_lora_rank, "wq_a")(x)))
+        else:
+            q = dense(H * (dn + dr), "wq")(x)
+        q = heads(q)
         down = dense(rank + dr, "wdkv")(x)
         latent = nn.RMSNorm(epsilon=cfg.rms_eps, dtype=cfg.dtype,
                             name="kv_norm")(down[..., :rank])
@@ -691,10 +731,11 @@ class LatentAttention(nn.Module):
         k = HeadColumns(kv, H, dn, first=0, stride=dn + dv)
         v = HeadColumns(kv, H, dv, first=dn, stride=dn + dv)
         kr = down[:, None, :, rank:]
-        if cfg.rope:
+        table = dict(cfg.rope_tables).get(
+            self.kind, RopeTable(theta=cfg.rope_theta)) if cfg.rope else None
+        if table is not None:
             with jax.named_scope("rope"):
-                cos, sin = rope_table(dr, positions,
-                                      RopeTable(theta=cfg.rope_theta))
+                cos, sin = rope_table(dr, positions, table)
                 # the 64 rotary lanes turned as a head of their own and
                 # joined to the rest again: against the whole 192-wide head
                 # through the pass the scope read 7.1 ms a step for 11.7 on
@@ -738,6 +779,107 @@ class ShortConvMixer(nn.Module):
             y = gated_short_conv(b, c, u, kernel.astype(cfg.dtype))
         return nn.Dense(E, use_bias=False, dtype=cfg.dtype,
                         name="out_proj")(y)
+
+
+def sinkhorn(logits, iters: int, eps: float, clamp: float):
+    """``exp(clip(logits))`` made (nearly) doubly stochastic: ``iters`` times
+    every row divided by its sum + ``eps``, then every column.  ``logits``:
+    (rows, columns, ...), a matrix an element of what follows; unrolled, and
+    reverse mode goes through every iteration."""
+    m = jnp.exp(jnp.clip(logits, -clamp, clamp))
+    for _ in range(iters):
+        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
+    return m
+
+
+def _streams(x, n: int):
+    """The ``n`` streams of (..., n x C), each (..., C): whole lanes where
+    ``C`` is."""
+    width = x.shape[-1] // n
+    return [x[..., j * width:(j + 1) * width] for j in range(n)]
+
+
+def _hc_bias_init(n: int):
+    """The coefficients' biases at the start, ``[b_pre ; b_post ; b_res]``:
+    ``H_pre`` near ``1 / n`` a stream and ``H_post`` near 1, so that a block
+    starts near a pre-norm block on the streams' mean, each tilted along the
+    streams (the gates' sums stay ``1`` and ``n``) so that the streams differ
+    from the first branch on; ``H_res`` near the identity (off the diagonal
+    ``exp(-3)`` before the Sinkhorn)."""
+    tilt = np.linspace(-1.0, 1.0, n)
+    return np.concatenate([
+        -math.log(n - 1) - tilt, tilt,
+        (-3.0 * (1.0 - np.eye(n))).reshape(-1)]).astype(np.float32)
+
+
+class HyperConnection(nn.Module):
+    """One sub-layer's manifold-constrained hyper-connection (mHC,
+    arXiv:2512.24880, over Hyper-Connections, arXiv:2409.19606) around a
+    branch ``F`` of a block whose residual stream ``X`` is ``n = hc_mult``
+    streams of ``C = d_model`` side by side, (B, S, n C).  A position:
+
+        x' = RMSNorm(vec(X))                          over all n C values
+        H_pre  = sigmoid(alpha_pre (x' phi_pre) + b_pre)             (n)
+        H_post = 2 sigmoid(alpha_post (x' phi_post) + b_post)        (n)
+        H_res  = Sinkhorn(exp(clip(alpha_res mat(x' phi_res) + b_res)))  (n, n)
+        X <- H_res X + H_post^T F(H_pre X)
+
+    Called on the stream alone it gives ``(H_pre X, (H_post, H_res))`` — the
+    branch's ``C``-wide input in the activations' dtype (scopes ``coeff``,
+    ``sinkhorn``, ``pre``) —, called with the branch's output and those
+    coefficients the stream after it (``post``).  ``phi`` is one (n C, 2 n +
+    n^2) matrix, its columns ``[pre ; post ; res]`` (``res`` row by row),
+    ``bias`` the same, ``alpha`` the three scalars.  The statistic, the
+    projection (``HIGHEST``), the gates and the Sinkhorn are float32 whatever
+    the dtypes around; the coefficients are laid out a coefficient a (B, S)
+    plane, so that a matrix's row and column sums are adds of planes."""
+    config: LlamaConfig
+
+    @nn.compact
+    def __call__(self, x, branch=None, coefficients=None):
+        cfg, n = self.config, self.config.hc_mult
+        xs = [part.astype(jnp.float32) for part in _streams(x, n)]
+
+        def mix(weights, parts):
+            return sum(w[..., None] * part for w, part in zip(weights, parts))
+
+        if branch is not None:
+            post, res = coefficients
+            with jax.named_scope("post"):
+                f = branch.astype(jnp.float32)
+                return jnp.concatenate(
+                    [mix(res[j], xs) + post[j][..., None] * f
+                     for j in range(n)], axis=-1).astype(x.dtype)
+        k = 2 * n + n * n
+        scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
+                           jnp.float32)
+        phi = self.param("phi", nn.initializers.lecun_normal(),
+                         (x.shape[-1], k), jnp.float32)
+        bias = self.param("bias", lambda key: jnp.asarray(_hc_bias_init(n)))
+        alpha = self.param("alpha", nn.initializers.constant(0.01), (3,),
+                           jnp.float32)
+        with jax.named_scope("coeff"):
+            # the norm's scale goes into phi and its statistic multiplies
+            # the 2 n + n^2 products: the stream is read, never rewritten
+            xf = x.astype(jnp.float32)
+            z = jnp.einsum("bsc,ck->kbs", xf, scale[:, None] * phi,
+                           precision=jax.lax.Precision.HIGHEST)
+            z = z * _rstd(xf, cfg.rms_eps)[..., 0]
+            z = z * jnp.repeat(alpha, np.array([n, n, n * n]),
+                               total_repeat_length=k)[:, None, None] \
+                + bias[:, None, None]
+            pre = jax.nn.sigmoid(z[:n])
+            post = 2.0 * jax.nn.sigmoid(z[n:2 * n])
+        with jax.named_scope("sinkhorn"):
+            res = sinkhorn(z[2 * n:].reshape(n, n, *z.shape[1:]),
+                           cfg.hc_sinkhorn_iters, cfg.hc_eps,
+                           cfg.hc_res_clamp)
+        self.sow("intermediates", "hc_res_row_err",
+                 jnp.max(jnp.abs(jnp.sum(res, axis=1) - 1.0)))
+        self.sow("intermediates", "hc_pre_max", jnp.max(pre))
+        with jax.named_scope("pre"):
+            return mix(pre, xs).astype(cfg.dtype), (post, res)
 
 
 class SwiGLU(nn.Module):
@@ -821,14 +963,27 @@ class LlamaBlock(nn.Module):
                 f"{self.mlp!r} (expected 'dense', 'sparse' or, for one of "
                 "the two, 'none')")
 
+        write = None    # the branch at hand's way back onto n streams
+
         def add(x, branch):
             if cfg.residual_multiplier != 1.0:
                 branch = branch * cfg.residual_multiplier
-            return x + branch
+            return x + branch if write is None else write(x, branch)
+
+        def branch_input(x, name):
+            """What the branch's norm reads: the stream, or of ``hc_mult``
+            streams the mix ``H_pre X`` (and then how ``add`` writes back)."""
+            if cfg.hc_mult == 1:
+                return x, None
+            hc = HyperConnection(cfg, name=name)
+            mixed, coefficients = hc(x)
+            return mixed, lambda x, branch: hc(x, branch, coefficients)
 
         # (a layer that is its feed-forward alone has no first norm)
-        y = attn_in = None if self.mixer == "none" \
-            else rms_norm(cfg, "attn_norm")(x)
+        y = attn_in = None
+        if self.mixer != "none":
+            y, write = branch_input(x, "hc_attn")
+            y = attn_in = rms_norm(cfg, "attn_norm")(y)
         if self.mixer == "none":
             if cfg.router_before_attention:
                 raise ValueError("a layer without a mixer has no attention "
@@ -861,7 +1016,8 @@ class LlamaBlock(nn.Module):
                              f"one of {ATTENTION_KINDS})")
         if self.mlp == "none":      # the layer is its mixer alone
             return (x, handed) if self.hands_on else x
-        y = rms_norm(cfg, "mlp_norm")(x)
+        y, write = branch_input(x, "hc_mlp")
+        y = rms_norm(cfg, "mlp_norm")(y)
         if self.mlp == "sparse":
             x = add(x, RoutedSwiGLU(RoutedConfig(
                 n_experts=cfg.n_experts, top_k=cfg.moe_top_k,
@@ -879,14 +1035,62 @@ class LlamaBlock(nn.Module):
         return (x, handed) if self.hands_on else x
 
 
+def _to_streams(x, cfg: LlamaConfig):
+    """One stream copied to ``hc_mult``, side by side."""
+    return x if cfg.hc_mult == 1 else jnp.tile(x, (1, 1, cfg.hc_mult))
+
+
+def _from_streams(x, cfg: LlamaConfig):
+    """``hc_mult`` streams summed to one (in float32, rounded once)."""
+    if cfg.hc_mult == 1:
+        return x
+    return sum(part.astype(jnp.float32)
+               for part in _streams(x, cfg.hc_mult)).astype(x.dtype)
+
+
+class PredictionModule(nn.Module):
+    """One multi-token-prediction module (DeepSeek-V3, arXiv:2412.19437,
+    section 2.2) behind the trunk: ``h' = proj [h_norm(h) ; emb_norm(e)]`` of
+    the trunk's (or the module before's) output ``h`` and the embedding ``e``
+    of the token its depth ahead, then one block of the stack's own kind
+    (``block_cls`` with the last layer's ``mlp``, ``mixer`` and ``n_head``;
+    under ``hc_mult`` ``h'`` is copied to the streams and the streams summed,
+    as the trunk does).  -> (the block's output, which the next module reads;
+    the same through the module's own final norm, which the shared head
+    reads)."""
+    config: LlamaConfig
+    block_cls: Any      # LlamaBlock, or it under remat
+    mlp: str
+    mixer: str
+    n_head: int
+    depth: int
+
+    @nn.compact
+    def __call__(self, h, emb, positions):
+        cfg = self.config
+        joined = jnp.concatenate(
+            [rms_norm(cfg, "h_norm")(h), rms_norm(cfg, "emb_norm")(emb)],
+            axis=-1)
+        x = nn.Dense(cfg.d_model, use_bias=False, dtype=cfg.dtype,
+                     name="proj")(joined).astype(h.dtype)
+        x = constrain_residual(_to_streams(x, cfg))
+        block = self.block_cls(cfg, self.mlp, self.mixer, self.n_head,
+                               self.depth, name="block")
+        x = _from_streams(block(x, positions), cfg)
+        return x, rms_norm(cfg, "norm_f")(x)
+
+
 class LlamaLMModel(nn.Module):
     config: LlamaConfig
 
     @nn.compact
-    def __call__(self, input_ids, *, deterministic: bool = True):
+    def __call__(self, input_ids, *, deterministic: bool = True,
+                 predict_ahead: bool = False):
         """(B, S) token ids -> (B, S, padded vocabulary) logits; under the
         block-diffusion objective ``input_ids`` is ``[x_t ; x]``, (B, 2 L),
-        and the logits are the noised copy's, (B, L, .)."""
+        and the logits are the noised copy's, (B, L, .).  ``predict_ahead``
+        (with ``n_mtp_modules``): -> (logits, the prediction modules' logits,
+        module ``k``'s at position ``t`` scoring the token ``t + k + 2``)."""
         cfg = self.config
         B, S = input_ids.shape
         if cfg.objective not in ("next_token", "block_diffusion"):
@@ -914,7 +1118,7 @@ class LlamaLMModel(nn.Module):
             # every add onto the stream promotes to it; the norms hand the
             # branches their input in ``dtype``
             x = x.astype(cfg.residual_dtype)
-        x = constrain_residual(x)
+        x = constrain_residual(_to_streams(x, cfg))
         # both copies of a row count their positions from 0
         positions = jnp.tile(jnp.arange(S // 2), 2) if two_copies \
             else jnp.arange(S)
@@ -941,30 +1145,55 @@ class LlamaLMModel(nn.Module):
                 x, made = x
                 handed[i] = constrain_residual(made, channels="tp")
             x = constrain_residual(x)
+        x = _from_streams(x, cfg)
         if two_copies:
             x = x[:, :S // 2]       # the head sees the noised copy alone
-        x = rms_norm(cfg, "norm_f")(x)
-        if cfg.logits_scaling != 1.0:
-            # on the narrow side of the head's matmul: the logits stay bf16
-            x = x / cfg.logits_scaling
         columns = cfg.n_pred_heads * cfg.vocab_size
-        if cfg.tie_embeddings:
+        # (n_pred_heads vocabularies of columns side by side, head r the
+        # columns from r * vocab_size)
+        lm_head = None if cfg.tie_embeddings else nn.Dense(
+            padded_vocab(columns), use_bias=False, dtype=cfg.dtype,
+            name="lm_head", **({} if cfg.logits_dtype is None else dict(
+                dot_general=functools.partial(
+                    jax.lax.dot_general,
+                    preferred_element_type=cfg.logits_dtype))))
+
+        def head(x):
+            if cfg.logits_scaling != 1.0:
+                # on the narrow side of the head's matmul: the logits stay
+                # bf16
+                x = x / cfg.logits_scaling
+            if lm_head is not None:
+                return mask_vocab_padding(lm_head(x), columns)
             # the head's matmul against the embedding table itself, under the
             # name path an untied head has; the table's gradient is the sum
             # of the gather's and this matmul's
             with jax.named_scope("lm_head"):
-                logits = jnp.einsum("bsd,vd->bsv", x,
-                                    wte.embedding.astype(cfg.dtype))
-        else:
-            # (n_pred_heads vocabularies of columns side by side, head r the
-            # columns from r * vocab_size)
-            logits = nn.Dense(
-                padded_vocab(columns), use_bias=False, dtype=cfg.dtype,
-                name="lm_head", **({} if cfg.logits_dtype is None else dict(
-                    dot_general=functools.partial(
-                        jax.lax.dot_general,
-                        preferred_element_type=cfg.logits_dtype))))(x)
-        return mask_vocab_padding(logits, columns)
+                return mask_vocab_padding(jnp.einsum(
+                    "bsd,vd->bsv", x, wte.embedding.astype(cfg.dtype)),
+                    columns)
+
+        logits = head(rms_norm(cfg, "norm_f")(x))
+        # the modules are parameters of the model whether or not they are
+        # asked for
+        if not cfg.n_mtp_modules or not (predict_ahead
+                                         or self.is_initializing()):
+            return logits
+        if two_copies or cfg.producers:
+            raise ValueError("prediction modules stand behind a next-token "
+                             "stack whose last block reads no earlier one")
+        ahead = []
+        for k in range(cfg.n_mtp_modules):
+            emb = wte(jnp.roll(input_ids, -(k + 1), axis=1))
+            if cfg.embedding_multiplier != 1.0:
+                emb = emb * cfg.embedding_multiplier
+            # (the last k + 1 positions read a token of the row's start:
+            # ``models/gpt2.py::ahead_loss`` does not count them)
+            x, normed = PredictionModule(
+                cfg, block_cls, mlp, mixer, n_head, cfg.n_layer + k,
+                name=f"mtp_{k}")(x, emb, positions)
+            ahead.append(head(normed))
+        return logits, tuple(ahead)
 
 
 def llama_partition_rules():

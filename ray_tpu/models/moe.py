@@ -985,6 +985,11 @@ def collect_aux(intermediates, aux_weight: float = 0.0, z_weight: float = 0.0):
                 stats[name] = sum(by_name[name]) / n
         total = total + aux_weight * stats["load_balance"] \
             + z_weight * stats["z"]
+    # the hyper-connections' (models/llama.py::HyperConnection): the step's
+    # worst |row sum - 1| of a streams' mixing matrix, and largest H_pre
+    for name in ("hc_res_row_err", "hc_pre_max"):
+        if name in by_name:
+            stats[name] = jnp.max(jnp.stack(by_name[name]))
     return total, stats
 
 

@@ -16,6 +16,10 @@ batch's ``input_ids`` itself, on the device, from a key it folds its own step
 count into (``noise_blocks``), runs the noised and the clean copy through the
 model side by side, and takes the cross entropy of the tokens that were masked,
 each weighted by one over its block's noise level; ``targets`` is not read.
+With ``LlamaConfig.n_mtp_modules`` > 0 the next-token objective is ``L_main +
+MTP_WEIGHT * L_mtp``: ``L_mtp`` the mean over the prediction modules behind
+the trunk of each one's cross entropy, module ``k`` at position ``t`` against
+the token ``t + k + 2`` (``gpt2.ahead_loss``).
 """
 
 from __future__ import annotations
@@ -29,7 +33,7 @@ import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from ray_tpu._private import flight_recorder
-from ray_tpu.models.gpt2 import (GPT2Config, GPT2LMModel, lm_loss,
+from ray_tpu.models.gpt2 import (GPT2Config, GPT2LMModel, ahead_loss, lm_loss,
                                  shifted_heads_loss)
 from ray_tpu.parallel.mesh import MeshConfig, build_mesh
 from ray_tpu.parallel.sharding import (
@@ -38,6 +42,11 @@ from ray_tpu.parallel.sharding import (
     match_partition_rules,
 )
 from ray_tpu.util.tracing import profiler_span
+
+# the prediction modules' weight in the objective (``n_mtp_modules``):
+# DeepSeek-V3's first value, assumed for the one configuration that has
+# modules (Xing4.0 publishes none).  A constant until a second value exists
+MTP_WEIGHT = 0.3
 
 
 def make_optimizer(lr: float = 3e-4, weight_decay: float = 0.1,
@@ -119,10 +128,31 @@ def loss_fn(model: GPT2LMModel, params, batch):
     """The language-model cross entropy, for every model.  Under the
     block-diffusion objective ``batch`` holds ``x_t`` and ``weights`` as
     ``noise_blocks`` gives them, and the loss is
-    ``sum(weights * nll) / input_ids.size``."""
-    inputs, targets, weights, total = _model_inputs(model.config, batch)
-    logits = model.apply({"params": params}, inputs)
-    return _cross_entropy(model.config, logits, targets, weights, total)
+    ``sum(weights * nll) / input_ids.size``.  With prediction modules
+    (``n_mtp_modules``) it is ``L_main + MTP_WEIGHT * L_mtp``."""
+    return _losses(model, params, batch)[0]
+
+
+def _losses(model, params, batch, sown: bool = False):
+    """One forward pass -> (the loss, its terms where it has more than one:
+    ``loss_main`` and ``loss_mtp``, what the modules sowed if ``sown``)."""
+    cfg = model.config
+    inputs, targets, weights, total = _model_inputs(cfg, batch)
+    modules = getattr(cfg, "n_mtp_modules", 0)
+    out = model.apply({"params": params}, inputs,
+                      **(dict(predict_ahead=True) if modules else {}),
+                      **(dict(mutable=["intermediates"]) if sown else {}))
+    out, intermediates = (out[0], out[1]["intermediates"]) if sown \
+        else (out, None)
+    if not modules:
+        return (_cross_entropy(cfg, out, targets, weights, total), {},
+                intermediates)
+    logits, ahead = out
+    main = _cross_entropy(cfg, logits, targets, weights, total)
+    mtp = sum(ahead_loss(a, targets, weights, k + 1)
+              for k, a in enumerate(ahead)) / modules
+    return (main + MTP_WEIGHT * mtp, {"loss_main": main, "loss_mtp": mtp},
+            intermediates)
 
 
 def _cross_entropy(config, logits, targets, weights, total):
@@ -138,25 +168,29 @@ def _cross_entropy(config, logits, targets, weights, total):
 def objective_fn(model, params, batch, key=None):
     """What ``train_step`` differentiates, and what it reports beside it:
     (cross entropy + the MoE layers' auxiliary terms, (cross entropy, the
-    step's MoE statistics)).  A dense model has no such terms and no
-    statistics, and its objective is ``loss_fn``.  Under the block-diffusion
-    objective a batch that brings no ``x_t`` is noised here, from ``key``."""
+    step's MoE statistics)); with prediction modules the objective is
+    ``L_main + MTP_WEIGHT * L_mtp`` (+ the auxiliary terms), what is reported
+    ``L_main``, and both terms are among the statistics (``loss_main``,
+    ``loss_mtp``), as are the hyper-connections' (``hc_res_row_err``,
+    ``hc_pre_max``) where the residual path is more than one stream.  A dense
+    model on one stream has no such terms and no statistics, and its
+    objective is ``loss_fn``.  Under the block-diffusion objective a batch
+    that brings no ``x_t`` is noised here, from ``key``."""
     cfg = model.config
     if _is_block_diffusion(cfg) and "x_t" not in batch:
         batch = _noised(cfg, batch, key)
-    if cfg.moe_every <= 0 and "sparse" not in getattr(cfg, "mlp_types", ()):
-        loss = loss_fn(model, params, batch)
-        return loss, (loss, {})
+    sown = cfg.moe_every > 0 or "sparse" in getattr(cfg, "mlp_types", ()) \
+        or getattr(cfg, "hc_mult", 1) > 1
+    loss, terms, intermediates = _losses(model, params, batch, sown)
+    reported = terms.get("loss_main", loss)
+    if not sown:
+        return loss, (reported, terms)
     from ray_tpu.models.moe import collect_aux
 
-    inputs, targets, weights, total = _model_inputs(cfg, batch)
-    logits, sown = model.apply({"params": params}, inputs,
-                               mutable=["intermediates"])
-    loss = _cross_entropy(cfg, logits, targets, weights, total)
-    aux, stats = collect_aux(sown["intermediates"],
+    aux, stats = collect_aux(intermediates,
                              getattr(cfg, "router_aux_weight", 0.0),
                              getattr(cfg, "router_z_weight", 0.0))
-    return loss + aux, (loss, stats)
+    return loss + aux, (reported, {**stats, **terms})
 
 
 def _hold_state(new, old):

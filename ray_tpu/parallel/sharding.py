@@ -69,6 +69,10 @@ def llama_partition_rules() -> PartitionRules:
     (ray_tpu.models.llama): same recipe as gpt_partition_rules, names
     matched to the RoPE/RMSNorm/SwiGLU module layout."""
     return PartitionRules([
+        # hyper-connections (HyperConnection as h_<n>/hc_attn, hc_mlp; first,
+        # so that no rule for ``attn/`` takes them): the coefficients'
+        # projection is 2 n + n^2 columns, whole on every device
+        (r"hc_(attn|mlp)/", _spec()),
         (r"wte/embedding", _spec("tp", "fsdp")),
         (r"attn/(wq|wk|wv)/kernel", _spec("fsdp", "tp")),
         # the gate on the attention's output: a column a query head
@@ -81,6 +85,11 @@ def llama_partition_rules() -> PartitionRules:
         # heads' (kv_norm's scale: replicated, below)
         (r"attn/wdkv/kernel", _spec("fsdp", None)),
         (r"attn/wukv/kernel", _spec("fsdp", "tp")),
+        # ... and the query's latent likewise (q_norm's scale: replicated)
+        (r"attn/wq_a/kernel", _spec("fsdp", None)),
+        (r"attn/wq_b/kernel", _spec("fsdp", "tp")),
+        # a prediction module's projection of [h ; emb] (mtp_<k>/proj)
+        (r"mtp_\d+/proj/kernel", _spec("fsdp", "tp")),
         (r"mlp/(gate_proj|up_proj)/kernel", _spec("fsdp", "tp")),
         (r"mlp/down_proj/kernel", _spec("tp", "fsdp")),
         # routed layers (models/moe.py::RoutedSwiGLU as h_<n>/moe): the

@@ -234,6 +234,23 @@ def _build(case: str, compile_: bool) -> dict:
         assert not config.rope and config.router_selection_bias
         assert (config.d_model, config.n_head, config.n_kv_head,
                 config.vocab_size, seq) == (2688, 32, 2, 16384, 16384)
+    elif case in ("xing4", "xing4_mtp"):
+        mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
+        (config, seq), rows = _cell("xing4-s8k-1chip"), 1
+        assert config.experts_held == (0, 8) and config.n_experts == 64
+        assert (config.hc_mult, config.hc_sinkhorn_iters,
+                config.hc_res_clamp) == (4, 20, 30.0)
+        assert (config.q_lora_rank, config.kv_lora_rank,
+                config.qk_nope_head_dim, config.qk_rope_head_dim,
+                config.v_head_dim) == (768, 512, 128, 64, 128)
+        assert dict(config.rope_tables)["attention"].factor == 64.0
+        assert config.mlp_types == ("dense",) + ("sparse",) * 4
+        assert (config.d_model, config.n_head, config.d_ff, config.d_expert,
+                config.vocab_size, seq) == (3584, 32, 9216, 1024, 16384, 8192)
+        assert config.n_mtp_modules == 0 and config.residual_dtype is None
+        if case == "xing4_mtp":     # the published module, on this chip too
+            import dataclasses
+            config = dataclasses.replace(config, n_mtp_modules=1)
     elif case == "granite":
         mesh = build_mesh(MeshConfig(), devices=topo.devices[:1])
         (config, seq), rows = _cell("granite-h-s8k-1chip"), 1
@@ -1453,6 +1470,48 @@ def test_nemotron_step_compiles_and_fits_the_chip():
     # norm's 4 x 3
     assert row["tpu_custom_calls"] == 66 + 12, row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
+
+
+def test_xing4_step_lowers_for_one_v5e_chip():
+    """Tier-1: the one-chip step of Xing4.0-29B-A4B at published widths
+    (published layers 1 to 5: a dense layer and four sparse ones over a
+    residual path four streams wide, ``HyperConnection`` around both branches
+    of each; latent attention with a query latent under YaRN at 32 heads,
+    scores 192 wide over values 128; 8 of 64 experts 1,024 wide held under a
+    contraction of 3,584; one row of 8,192) lowers for the TPU with its
+    Mosaic kernels in it — the flash pair five times, the held experts'
+    grouped matmuls and sums into tokens — and no other: the
+    hyper-connections are XLA's."""
+    row = _child(["xing4"], compile_=False)["xing4"]
+    kernels = row["lowered_kernels"]
+    assert kernels.pop("kernel") > 0
+    assert kernels == {"flash_fwd": 5, "flash_bwd": 5, "onto_tokens": 2}, \
+        kernels
+    assert row["flash_fwd_calls"] == 5, row
+
+
+@pytest.mark.slow
+@pytest.mark.parametrize("case,calls,most", [("xing4", 82, 13.6e9),
+                                             ("xing4_mtp", 102, 15.6e9)])
+def test_xing4_step_compiles_and_fits_the_chip(case, calls, most):
+    """The TPU compiler takes the step — the grouped matmuls at a contraction
+    of 3,584 as 2 x 1,792, the flash kernels' two widths at 32 heads, the
+    coefficients' projection of 14,336 values to 24 at ``HIGHEST`` — and its
+    memory analysis says five four-stream layers fit one chip at one row of
+    8,192 beside 12.15 GB of state (759.5M parameters x 16 B): 9.114 GB of
+    arguments (12 B a parameter) + 4.314 GB of temporaries, PR 65.  **And the
+    same step with the published prediction module on this chip**
+    (``xing4_mtp``: 913.6M parameters, 14.6 GB of state): 10.964 GB of
+    arguments + 4.520 GB of temporaries = 15.48 GB, which the compiler still
+    takes and which leaves under 1.5 GB of a 16 GiB chip — no room for the
+    agreement check's float32 reference and its gradients beside it: why the
+    cell leaves the module to the last pipeline stage (PERF.md section 4)."""
+    row = _child([case], compile_=True)[case]
+    print({k: row.get(k) for k in ("argument_bytes", "temp_bytes", "refused",
+                                   "tpu_custom_calls")})
+    assert "refused" not in row, row
+    assert row["tpu_custom_calls"] == calls, row
+    assert row["argument_bytes"] + row["temp_bytes"] < most, row
 
 
 def test_selective_scan_kernels_compile_for_one_v5e_chip():
