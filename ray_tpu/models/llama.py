@@ -163,8 +163,10 @@ from ray_tpu.models.kda import HeadNorm, KDAMixer
 from ray_tpu.models.mamba import (GatedMemoryUnit, Mamba1Mixer, Mamba2Mixer,
                                   SplitDense, _conv_init, gated_short_conv)
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU, silu_mul
-from ray_tpu.ops import pooling
+from ray_tpu.ops import hyper_connection, pooling
 from ray_tpu.ops.attention import HeadColumns, attention
+from ray_tpu.ops.hyper_connection import sinkhorn  # noqa: F401  (its home)
+from ray_tpu.ops.hyper_connection import streams as _streams
 from ray_tpu.parallel.sharding import constrain_residual
 
 
@@ -781,25 +783,6 @@ class ShortConvMixer(nn.Module):
                         name="out_proj")(y)
 
 
-def sinkhorn(logits, iters: int, eps: float, clamp: float):
-    """``exp(clip(logits))`` made (nearly) doubly stochastic: ``iters`` times
-    every row divided by its sum + ``eps``, then every column.  ``logits``:
-    (rows, columns, ...), a matrix an element of what follows; unrolled, and
-    reverse mode goes through every iteration."""
-    m = jnp.exp(jnp.clip(logits, -clamp, clamp))
-    for _ in range(iters):
-        m = m / (jnp.sum(m, axis=1, keepdims=True) + eps)
-        m = m / (jnp.sum(m, axis=0, keepdims=True) + eps)
-    return m
-
-
-def _streams(x, n: int):
-    """The ``n`` streams of (..., n x C), each (..., C): whole lanes where
-    ``C`` is."""
-    width = x.shape[-1] // n
-    return [x[..., j * width:(j + 1) * width] for j in range(n)]
-
-
 def _hc_bias_init(n: int):
     """The coefficients' biases at the start, ``[b_pre ; b_post ; b_res]``:
     ``H_pre`` near ``1 / n`` a stream and ``H_post`` near 1, so that a block
@@ -825,61 +808,46 @@ class HyperConnection(nn.Module):
         H_res  = Sinkhorn(exp(clip(alpha_res mat(x' phi_res) + b_res)))  (n, n)
         X <- H_res X + H_post^T F(H_pre X)
 
-    Called on the stream alone it gives ``(H_pre X, (H_post, H_res))`` — the
+    Called on the stream alone it gives ``(H_pre X, coefficients)`` — the
     branch's ``C``-wide input in the activations' dtype (scopes ``coeff``,
-    ``sinkhorn``, ``pre``) —, called with the branch's output and those
-    coefficients the stream after it (``post``).  ``phi`` is one (n C, 2 n +
-    n^2) matrix, its columns ``[pre ; post ; res]`` (``res`` row by row),
-    ``bias`` the same, ``alpha`` the three scalars.  The statistic, the
-    projection (``HIGHEST``), the gates and the Sinkhorn are float32 whatever
-    the dtypes around; the coefficients are laid out a coefficient a (B, S)
-    plane, so that a matrix's row and column sums are adds of planes."""
+    ``sinkhorn``, ``pre``), and ``H_post`` (n, B, S) and ``H_res`` (n, n, B,
+    S) as the first two of the coefficients —, called with the branch's
+    output and those coefficients the stream after it (``post``).  ``phi`` is
+    one (n C, 2 n + n^2) matrix, its columns ``[pre ; post ; res]`` (``res``
+    row by row), ``bias`` the same, ``alpha`` the three scalars.  The
+    statistic, the projection (``HIGHEST``), the gates and the Sinkhorn are
+    float32 whatever the dtypes around.
+
+    The module keeps the parameters, the two call forms and the two ``sow``s;
+    the arithmetic is ``ops/hyper_connection.py``'s since PR 66: the lines
+    that stood here are ``hyper_connection_xla`` and ``write_back_xla`` there
+    (with ``sinkhorn`` and the streams' slices), and where ``C`` is whole
+    tiles of 128 lanes its two entries take their Pallas passes instead — one over the
+    stream for the coefficients and the mix (under ``coeff``), the
+    write-back's backward and the first call's backward one pass each (under
+    ``post`` and ``coeff``) — and the second call reads the stream as the
+    first handed it on."""
     config: LlamaConfig
 
     @nn.compact
     def __call__(self, x, branch=None, coefficients=None):
         cfg, n = self.config, self.config.hc_mult
-        xs = [part.astype(jnp.float32) for part in _streams(x, n)]
-
-        def mix(weights, parts):
-            return sum(w[..., None] * part for w, part in zip(weights, parts))
-
+        spec = hyper_connection.Spec(n, cfg.hc_sinkhorn_iters, cfg.hc_eps,
+                                     cfg.hc_res_clamp, cfg.rms_eps, cfg.dtype)
         if branch is not None:
-            post, res = coefficients
-            with jax.named_scope("post"):
-                f = branch.astype(jnp.float32)
-                return jnp.concatenate(
-                    [mix(res[j], xs) + post[j][..., None] * f
-                     for j in range(n)], axis=-1).astype(x.dtype)
-        k = 2 * n + n * n
+            return hyper_connection.write_back(x, branch, coefficients, spec)
         scale = self.param("scale", nn.initializers.ones, (x.shape[-1],),
                            jnp.float32)
         phi = self.param("phi", nn.initializers.lecun_normal(),
-                         (x.shape[-1], k), jnp.float32)
+                         (x.shape[-1], spec.k), jnp.float32)
         bias = self.param("bias", lambda key: jnp.asarray(_hc_bias_init(n)))
         alpha = self.param("alpha", nn.initializers.constant(0.01), (3,),
                            jnp.float32)
-        with jax.named_scope("coeff"):
-            # the norm's scale goes into phi and its statistic multiplies
-            # the 2 n + n^2 products: the stream is read, never rewritten
-            xf = x.astype(jnp.float32)
-            z = jnp.einsum("bsc,ck->kbs", xf, scale[:, None] * phi,
-                           precision=jax.lax.Precision.HIGHEST)
-            z = z * _rstd(xf, cfg.rms_eps)[..., 0]
-            z = z * jnp.repeat(alpha, np.array([n, n, n * n]),
-                               total_repeat_length=k)[:, None, None] \
-                + bias[:, None, None]
-            pre = jax.nn.sigmoid(z[:n])
-            post = 2.0 * jax.nn.sigmoid(z[n:2 * n])
-        with jax.named_scope("sinkhorn"):
-            res = sinkhorn(z[2 * n:].reshape(n, n, *z.shape[1:]),
-                           cfg.hc_sinkhorn_iters, cfg.hc_eps,
-                           cfg.hc_res_clamp)
-        self.sow("intermediates", "hc_res_row_err",
-                 jnp.max(jnp.abs(jnp.sum(res, axis=1) - 1.0)))
-        self.sow("intermediates", "hc_pre_max", jnp.max(pre))
-        with jax.named_scope("pre"):
-            return mix(pre, xs).astype(cfg.dtype), (post, res)
+        mixed, coefficients, (row_err, pre_max) = \
+            hyper_connection.hyper_connection(x, scale, phi, bias, alpha, spec)
+        self.sow("intermediates", "hc_res_row_err", row_err)
+        self.sow("intermediates", "hc_pre_max", pre_max)
+        return mixed, coefficients
 
 
 class SwiGLU(nn.Module):

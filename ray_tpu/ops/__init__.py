@@ -5,8 +5,11 @@ sequence parallelism (absent from the reference — SURVEY §5.7 greenfield), GA
 scans for RL; and a Mamba-2 mixer's three kernel pairs (``models/mamba.py``):
 the chunked scan (``ssd.ssd_scan``), the short causal convolution with its
 silu (``conv.conv_silu``) and the gated norm a group
-(``gated_norm.gated_rms_norm``), each beside its ``jax.numpy`` form, which
-runs where the op's docstring says the kernels do not apply.  Every op has an
+(``gated_norm.gated_rms_norm``), and the manifold-constrained
+hyper-connection around a branch of a block whose residual path is several
+streams (``hyper_connection.hyper_connection``: ``models/llama.py``'s
+``HyperConnection``), each beside its ``jax.numpy`` form, which runs where
+the op's docstring says the kernels do not apply.  Every op has an
 XLA fallback used automatically off-TPU and for verification.
 """
 

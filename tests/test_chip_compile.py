@@ -134,6 +134,8 @@ def _build(case: str, compile_: bool) -> dict:
         return _build_conv(case, topo.devices[0])
     if case.startswith("norm_s"):
         return _build_gated_norm(case, topo.devices[0])
+    if case.startswith("mhc_s"):
+        return _build_hyper_connection(case, topo.devices[0])
     if case in PRELUDES:
         return _build_prelude(case, topo.devices[0])
     if case in LAYOUTS:
@@ -553,6 +555,64 @@ def _build_gated_norm(case: str, device) -> dict:
     return {"case": case, "tpu_custom_calls": len(re.findall(
         r'custom_call_target="tpu_custom_call"', compiled.as_text())),
         "temp_bytes": int(mem.temp_size_in_bytes)}
+
+
+def _build_hyper_connection(case: str, device) -> dict:
+    """In the child: compile a sub-layer's hyper-connection alone —
+    ``ops/hyper_connection.py``'s two calls around a branch that scales the
+    mix, forward and backward — for one chip at ``mhc_s<rows>_c<a stream's
+    lanes>_n<streams>`` in bf16; the compiled program's Mosaic calls, and its
+    largest float32 array and its largest elementwise sum beside them."""
+    import math
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.hyper_connection import (Spec, hyper_connection,
+                                              write_back)
+
+    given = {part[0]: int(part[1:]) for part in case.split("_")[1:]}
+    n, c, rows = given["n"], given["c"], given["s"]
+    spec = Spec(n, 20, 1e-6, 30.0, 1e-6, jnp.bfloat16)
+
+    def shape(dtype, *dims):
+        return jax.ShapeDtypeStruct(dims, dtype,
+                                    sharding=SingleDeviceSharding(device))
+
+    def sublayer(x, scale, phi, bias, alpha, w):
+        mixed, coefficients, _ = hyper_connection(x, scale, phi, bias, alpha,
+                                                  spec)
+        return write_back(x, mixed * w, coefficients, spec)
+
+    def grads(dout, *given):
+        out, vjp = jax.vjp(sublayer, *given)
+        return out, vjp(dout)
+
+    wide = shape(jnp.bfloat16, 1, rows, n * c)
+    try:
+        compiled = jax.jit(grads).lower(
+            wide, wide, shape(jnp.float32, n * c),
+            shape(jnp.float32, n * c, spec.k), shape(jnp.float32, spec.k),
+            shape(jnp.float32, 3), shape(jnp.bfloat16, c)).compile()
+    except Exception as e:  # what Mosaic or the TPU compiler refuses
+        return {"case": case, "refused": str(e)[-1500:]}
+    text = compiled.as_text()
+    # what the entry computation's instructions give: the program's arrays
+    # (inside a fusion a float32 value is registers, not memory)
+    results = re.findall(r"^\s+\S+ = (.*?) [\w\-]+\(",
+                         text[text.index("\nENTRY "):], re.M)
+
+    def elements(dims):
+        return math.prod(int(d) for d in dims.split(",") if d)
+
+    return {"case": case, "tpu_custom_calls": len(re.findall(
+        r'custom_call_target="tpu_custom_call"', text)),
+        "temp_bytes": int(compiled.memory_analysis().temp_size_in_bytes),
+        "largest_f32": max(map(elements, re.findall(
+            r"f32\[([\d,]*)\]", " ".join(results)))),
+        "stream": rows * n * c}
 
 
 def _build_prelude(case: str, device) -> dict:
@@ -1330,6 +1390,24 @@ def test_gated_norm_kernels_compile_for_one_v5e_chip():
         assert row["temp_bytes"] < 1 << 20, row
 
 
+def test_hyper_connection_kernels_compile_for_one_v5e_chip():
+    """Tier-1, ten seconds: Mosaic takes ``ops/hyper_connection.py``'s three
+    kernels at Xing4's shape (1 x 8,192 positions, four streams of 3,584
+    lanes, bf16) — blocks of 128 rows by 14,336 lanes, the projection's
+    pieces on the MXU, the planes' single-sublane loads and stores, the
+    square transposes between planes and columns, 100 MiB of VMEM —, which
+    the interpreter on the CPU cannot say; and beside the calls the compiled
+    program holds no float32 array of a stream's size (the widest: the
+    pieces' cotangent, 96 x 14,336) and under two streams of temporaries
+    (``H_res^T dX'`` and the mix's cotangent, in bf16)."""
+    row = _child(["mhc_s8192_c3584_n4"], compile_=True)["mhc_s8192_c3584_n4"]
+    assert "refused" not in row, row
+    # the stream's pass, the write-back's backward, the stream's pass' own
+    assert row["tpu_custom_calls"] == 3, row
+    assert row["largest_f32"] <= 3 * 32 * 4 * 3584 < row["stream"], row
+    assert row["temp_bytes"] < 2 * 2 * row["stream"], row
+
+
 def test_phi4_flash_step_lowers_for_one_v5e_chip():
     """Tier-1: the one-chip step of Phi-4-mini-flash-reasoning at published
     widths (published layers 14 to 19: Mamba-1, window attention, Mamba-1
@@ -1480,23 +1558,28 @@ def test_xing4_step_lowers_for_one_v5e_chip():
     scores 192 wide over values 128; 8 of 64 experts 1,024 wide held under a
     contraction of 3,584; one row of 8,192) lowers for the TPU with its
     Mosaic kernels in it — the flash pair five times, the held experts'
-    grouped matmuls and sums into tokens — and no other: the
-    hyper-connections are XLA's."""
+    grouped matmuls and sums into tokens, and since PR 66 the
+    hyper-connections' three (``ops/hyper_connection.py``): of ten
+    sub-layers the stream's pass forward and again under remat, the
+    write-back's backward and the stream's pass' backward — and no other."""
     row = _child(["xing4"], compile_=False)["xing4"]
     kernels = row["lowered_kernels"]
     assert kernels.pop("kernel") > 0
-    assert kernels == {"flash_fwd": 5, "flash_bwd": 5, "onto_tokens": 2}, \
-        kernels
+    assert kernels == {"flash_fwd": 5, "flash_bwd": 5, "onto_tokens": 2,
+                       "hc_mix_fwd": 20, "hc_write_bwd": 10,
+                       "hc_mix_bwd": 10}, kernels
     assert row["flash_fwd_calls"] == 5, row
 
 
 @pytest.mark.slow
-@pytest.mark.parametrize("case,calls,most", [("xing4", 82, 13.6e9),
-                                             ("xing4_mtp", 102, 15.6e9)])
+@pytest.mark.parametrize("case,calls,most", [("xing4", 82 + 40, 13.6e9),
+                                             ("xing4_mtp", 102 + 48, 15.6e9)])
 def test_xing4_step_compiles_and_fits_the_chip(case, calls, most):
     """The TPU compiler takes the step — the grouped matmuls at a contraction
     of 3,584 as 2 x 1,792, the flash kernels' two widths at 32 heads, the
-    coefficients' projection of 14,336 values to 24 at ``HIGHEST`` — and its
+    hyper-connections' three kernels a sub-layer (since PR 66: 40 calls, 48
+    with the module's block; the temporaries below are PR 65's, with them in
+    plain XLA: 3.781 GB and 4.381 GB now) — and its
     memory analysis says five four-stream layers fit one chip at one row of
     8,192 beside 12.15 GB of state (759.5M parameters x 16 B): 9.114 GB of
     arguments (12 B a parameter) + 4.314 GB of temporaries, PR 65.  **And the
