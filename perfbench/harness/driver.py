@@ -16,7 +16,7 @@ import sys
 from typing import Any, Dict, List, Optional
 
 from perfbench.harness import manifest
-from perfbench.harness.readers import device_busy, memory_peak
+from perfbench.harness.readers import device_busy, memory_peak, rounds
 from perfbench.harness.readers.context import Context
 from perfbench.harness.trace_reduce import (Trace, gaps, label_gaps,
                                             self_seconds)
@@ -163,6 +163,9 @@ def result_line(cell: manifest.Cell, m: Dict[str, Any], trace: bool
         device["busy_s"] = device_busy.mean_busy_s(ctx)
         device["window_s"] = ctx.window_s
         line["breakdown"] = _breakdown(ctx)
+        # read by people: the rounds that are the profiler's calls' and so
+        # in neither round_worst_excess_s nor rounds_stalled
+        line["rounds_left_out"] = rounds.read(ctx, "left_out")
     checks = verdict(cell, m)
     line.update({
         "correct": all(checks.values()),

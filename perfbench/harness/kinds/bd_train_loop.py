@@ -98,7 +98,8 @@ def loop(run: Dict[str, Any]) -> None:
         MeshConfig(**traffic["mesh"]))
     marks.append(("trainer_and_state", time.time()))
     # ids over the rows of the vocabulary that this chip holds
-    stream = ZipfStream(trainer.config.vocab_size, run["seed"])
+    stream = ZipfStream(trainer.config.vocab_size, run["seed"],
+                        window_ids_seed=traffic.get("window_ids_seed"))
     replicas = trainer.mesh.shape["dp"] * trainer.mesh.shape["fsdp"]
     agreed = bd_agreement.check(
         trainer, config, cell.chips,

@@ -144,7 +144,8 @@ def loop(run: Dict[str, Any]) -> None:
 
     losses.clear()
     spans.clear()
-    profiler = _Profiler(traffic["trace"], run["trace_dir"]) \
+    profiler = _Profiler(traffic["trace"], run["trace_dir"], every,
+                         lambda: trainer.moe_stats) \
         if run["trace"] else None
     n_seen = len(seen)
     measuring = True

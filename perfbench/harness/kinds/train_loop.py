@@ -106,13 +106,25 @@ def _batches(traffic, stream: ZipfStream) -> Iterator[Dict[str, Any]]:
 class _Profiler:
     """``jax.profiler`` over steps ``[from_step, from_step + steps)`` of the
     window; the ``bench/window`` span marks the traced window on the trace's
-    own clock."""
+    own clock.  What else only a traced run does lives here, so that the
+    untraced loop is the parent's statement for statement: the two calls of
+    the profiler are stamped on the flight recorder's clock (``calls``: the
+    rounds they fall into are the benchmark's, not the program's, and reader
+    ``rounds`` leaves them out), and with ``counters`` (a function that gives
+    the program's device scalars of the last step, ``trainer.moe_stats``)
+    those are read after every ``every``-th step — a report's step, which the
+    loop has just synchronised on — outside the traced steps, and averaged
+    over the window."""
 
-    def __init__(self, spec: Dict[str, int], out_dir: str):
+    def __init__(self, spec: Dict[str, int], out_dir: str, every: int = 1,
+                 counters=None):
         self.first, self.last = spec["from_step"], spec["from_step"] + spec["steps"]
         self.steps = spec["steps"]
         self.dir = out_dir
         self._window = None
+        self.calls: List[List[float]] = []      # [start, end], time.time()
+        self._every, self._counters = every, counters
+        self._counted: Dict[str, List[float]] = {}
 
     def before_step(self, step: int) -> None:
         if step != self.first:
@@ -123,22 +135,32 @@ class _Profiler:
         options = ProfileOptions()
         options.python_tracer_level = 0     # no Python frames: spans only
         options.host_tracer_level = 2
+        t0 = time.time()
         jax.profiler.start_trace(self.dir, profiler_options=options)
+        self.calls.append([t0, time.time()])
         self._window = TraceAnnotation(trace_reduce.SPAN_PREFIX + "window")
         self._window.__enter__()
 
     def after_step(self, step: int) -> None:
-        if step != self.last or self._window is None:
-            return
         import jax
 
-        self._window.__exit__(None, None, None)
-        self._window = None
-        jax.profiler.stop_trace()
+        if step == self.last and self._window is not None:
+            self._window.__exit__(None, None, None)
+            self._window = None
+            t0 = time.time()
+            jax.profiler.stop_trace()
+            self.calls.append([t0, time.time()])
+        # not while the traced sub-window is open: what the trace shows of
+        # the host loop stays what an untraced step does
+        if self._counters is not None and self._window is None \
+                and step % self._every == 0:
+            for name, value in jax.device_get(self._counters()).items():
+                self._counted.setdefault(name, []).append(float(value))
 
     def reduced(self) -> Optional[Dict[str, Any]]:
         """The traced window, reduced and left beside the profiler's own file
-        (it is too large for a report): where it is, and its steps."""
+        (it is too large for a report): where it is, and its steps; with them
+        the profiler's two calls and the window's means of the counters."""
         if self._window is not None:
             # the window closed before the sub-window did: nothing to read
             self.after_step(self.last)
@@ -153,7 +175,10 @@ class _Profiler:
         path = os.path.join(self.dir, "reduced.json")
         with open(path, "w") as f:
             f.write(trace.clipped(trace.window()).to_json())
-        return {"steps": self.steps, "file": path}
+        return {"steps": self.steps, "file": path,
+                "profiler_calls": self.calls,
+                "counters": {name: float(np.mean(values))
+                             for name, values in self._counted.items()}}
 
 
 def _tenth_means(losses: List[float]):
@@ -230,7 +255,8 @@ def loop(run: Dict[str, Any]) -> None:
 
     losses.clear()
     spans.clear()
-    profiler = _Profiler(traffic["trace"], run["trace_dir"]) \
+    profiler = _Profiler(traffic["trace"], run["trace_dir"], every,
+                         lambda: trainer.moe_stats) \
         if run["trace"] else None
     n_seen = len(seen)
     measuring = True

@@ -17,9 +17,21 @@ share of its steps that lie inside.  ``as_``:
 
 ``worst_excess_s``  the window's longest round less its median round;
 ``stalled``         the ``train.stall`` records that end inside the window;
+``left_out``        how many rounds the two above left out (below);
 ``ms_per_step``     the seconds under the names ``of``, on ``side``
                     (``worker``, or ``driver``: its records by the share of
                     their time inside the window), over the window's steps.
+
+A round in which the benchmark itself called ``jax.profiler.start_trace`` or
+``stop_trace`` is the benchmark's, not the program's (0.4 to 5.6 s of
+``start_trace`` in every traced run: ``PERF.md`` section 6, PR 50).  The
+traced loop stamps the two calls on the recorder's clock (``time.time()``;
+``kinds/train_loop.py::_Profiler.calls``, brought back beside the reduced
+trace), and ``worst_excess_s`` and ``stalled`` leave out every record that
+overlaps one of them — a stalled round is a record of its own; a round that
+was not is left out with the rounds summed beside it.  The seconds by name
+(``ms_per_step``) are left whole: the profiler's calls run under none of the
+program's spans.
 """
 
 from __future__ import annotations
@@ -62,6 +74,17 @@ def window(records: Sequence, steps: int) -> List[Tuple[object, float]]:
     return held[::-1]
 
 
+def profiler_calls(ctx) -> List[Tuple[float, float]]:
+    """The benchmark's own profiler calls in this run, as intervals on the
+    recorder's clock; none where the run was not traced."""
+    traced = ctx.measured.get("trace") or {}
+    return [(float(a), float(b)) for a, b in traced.get("profiler_calls", ())]
+
+
+def _overlaps(record, calls: Sequence[Tuple[float, float]]) -> bool:
+    return any(record.start < b and a < record.end for a, b in calls)
+
+
 def read(ctx, as_: str, of: Sequence[str] = (), side: str = "worker",
          session_dir: Optional[str] = None):
     session_dir = session_dir or newest_session()
@@ -77,17 +100,22 @@ def read(ctx, as_: str, of: Sequence[str] = (), side: str = "worker",
     first, share = held[0]
     opened = first.end - share * (first.end - first.start)
     closed = held[-1][0].end
+    calls = profiler_calls(ctx)
     if as_ == "stalled":
         return float(sum(1 for r in rounds if r.kind == STALL
-                         and r.process == worker and opened < r.end <= closed))
+                         and r.process == worker and opened < r.end <= closed
+                         and not _overlaps(r, calls)))
+    if as_ == "left_out":
+        return float(sum(record.counts.get("rounds", 1)
+                         for record, _ in held if _overlaps(record, calls)))
     if as_ == "worst_excess_s":
-        each = [s for record, share in held
+        each = [s for record, share in held if not _overlaps(record, calls)
                 for s in (record.each() if share == 1.0 else
                           [(record.end - record.start)
                            / record.counts.get("rounds", 1)])]
-        return max(each) - statistics.median(each)
+        return max(each) - statistics.median(each) if each else None
     if as_ != "ms_per_step":
-        raise ValueError(f"as_ must be worst_excess_s, stalled or "
+        raise ValueError(f"as_ must be worst_excess_s, stalled, left_out or "
                          f"ms_per_step, got {as_!r}")
     if side == "worker":
         parts = held

@@ -1,16 +1,16 @@
 """``flash_bwd_calls_per_step`` (PR 24): the Mosaic calls of the attention
 backward, and none of the forward's.  A file of its own beside
-``test_trace_reduce.py::test_flash_forward_metrics_leave_a_backward_kernel_out``
+``test_trace_reduce.py::test_flash_forward_metrics_leave_every_other_kernel_out``
 because a PR that claims a gain edits no file the benchmark has."""
 
 import json
 import os
 
 from perfbench.harness.readers import trace_ops
-from perfbench.harness.trace_reduce import Op, Trace
+from perfbench.harness.trace_reduce import Op
+from perfbench.tests.recorded import mistral_step
 
 HERE = os.path.dirname(os.path.abspath(__file__))
-FIXTURES = os.path.join(os.path.dirname(HERE), "fixtures")
 
 
 def _args(metric):
@@ -23,8 +23,7 @@ def test_flash_backward_calls_are_the_backward_kernels_and_no_forward_one():
     """The recorded Mistral step (PR 22: four forward calls, two of them
     recomputation, and an XLA-scan backward) with the two kernels of a
     rematted and of a plain block added under the name paths JAX gives them."""
-    with open(os.path.join(FIXTURES, "mistral-s8k-1chip.one-step.json")) as f:
-        ops = Trace.from_json(f.read()).ops[0]
+    ops = mistral_step().ops[0]      # its forward calls under today's scope
     end = ops[-1].end
     bwd = [Op(f"attn.{90 + i}", "custom-call:tpu_custom_call", path,
               end + i, end + i + 0.5) for i, path in enumerate([

@@ -10,6 +10,7 @@ import pytest
 
 from perfbench.harness import flops, manifest, window_work
 from perfbench.harness.families import laguna
+from perfbench.tests.manifest_lists import on_at_least
 from perfbench.harness.readers import kernel_roofline, trace_ops
 from perfbench.harness.readers.context import Context
 from perfbench.harness.trace_reduce import Op, Trace
@@ -135,12 +136,14 @@ def test_the_new_metrics_on_a_synthetic_trace():
     assert kernel_roofline.read(ctx, **bwd["args"]) == pytest.approx(
         100 * least / 8e-3)
     ms = {name: trace_ops.read(ctx, **_metric(name)["args"]) for name in (
-        "window_attn_ms_per_step", "attn_gate_rope_ms_per_step",
-        "moe_shared_ms_per_step", "flash_fwd_ms_per_step",
-        "flash_bwd_ms_per_step")}
+        "window_attn_ms_per_step", "attn_rope_norm_ms_per_step",
+        "attn_gate_ms_per_step", "moe_shared_ms_per_step",
+        "flash_fwd_ms_per_step", "flash_bwd_ms_per_step")}
     # the three kernel calls, not the XLA work around the backward kernel
     assert ms["window_attn_ms_per_step"] == pytest.approx(16.0)
-    assert ms["attn_gate_rope_ms_per_step"] == pytest.approx(3.0)
+    # attn_gate_rope_ms_per_step's two halves (that entry went with PR 67)
+    assert ms["attn_rope_norm_ms_per_step"] == pytest.approx(2.0)
+    assert ms["attn_gate_ms_per_step"] == pytest.approx(1.0)
     assert ms["moe_shared_ms_per_step"] == pytest.approx(1.0)
     # the list-less metrics read both kinds of layer as they stand
     assert ms["flash_fwd_ms_per_step"] == pytest.approx(4 + 4 + 14)
@@ -157,7 +160,7 @@ def test_a_program_without_the_scopes_reports_nothing():
     ctx = Context(CELL, PEAK, {}, trace, traced_steps=1)
     for name in ("window_attn_fwd_roofline", "window_attn_bwd_roofline"):
         assert kernel_roofline.read(ctx, **_metric(name)["args"]) is None
-    for name in ("window_attn_ms_per_step", "attn_gate_rope_ms_per_step",
+    for name in ("window_attn_ms_per_step", "attn_gate_ms_per_step",
                  "moe_shared_ms_per_step"):
         assert trace_ops.read(ctx, **_metric(name)["args"]) is None
 
@@ -167,14 +170,12 @@ def test_the_cell_as_the_manifest_has_it():
     entry = next(c for c in bench["configs"] if c["name"] == "laguna-xs.2")
     assert entry["reduced"] == CONFIG["reduced"]
     assert CELL.chips == 1 and CELL.traffic["kind"] == "train_loop"
-    ours = [m["name"] for m in bench["per_layer"]
-            if m.get("workloads") == ["laguna-s8k-1chip"]]
-    assert ours == ["window_attn_fwd_roofline", "window_attn_bwd_roofline",
-                    "window_attn_ms_per_step", "attn_gate_rope_ms_per_step",
-                    "moe_shared_ms_per_step"]
-    # no list of an accepted metric names the new cell
-    assert all("laguna-s8k-1chip" not in m.get("workloads", [])
-               for m in bench["per_layer"] if m["name"] not in ours)
+    # PR 35's five by name (attn_gate_rope_ms_per_step is since PR 67 its two
+    # halves); later PRs list the cell under more
+    on_at_least(bench, "laguna-s8k-1chip", [
+        "window_attn_fwd_roofline", "window_attn_bwd_roofline",
+        "window_attn_ms_per_step", "attn_rope_norm_ms_per_step",
+        "attn_gate_ms_per_step", "moe_shared_ms_per_step"])
     # every catalog number stands in the file; the six cut keys beside their
     # published counts
     assert (CONFIG["hidden_size"], CONFIG["head_dim"],

@@ -6,6 +6,7 @@ the recorded step with the new scopes written into its name paths.
 No time read here is a device's: what is checked is arithmetic and names.
 """
 
+import dataclasses
 import functools
 import json
 import os
@@ -19,8 +20,8 @@ from perfbench.harness import trace_reduce as tr
 from perfbench.harness.readers import program_span
 from perfbench.harness.readers.context import Context
 from perfbench.harness.trace_reduce import Op, Trace
+from perfbench.tests.recorded import mistral_step
 
-FIXTURES = os.path.join(manifest.BENCH_DIR, "fixtures")
 NEW_SPAN_METRICS = {
     "report_handoff_ms_per_step", "report_self_ms_per_step",
     "data_pull_ms_per_step", "data_rebatch_ms_per_step",
@@ -61,8 +62,11 @@ def test_the_new_metrics_are_in_the_benchmark_with_their_readers():
     assert reported("gpt2s-loop-b8-s1k") >= set(IDLE_IN)
     assert reported("gpt2s-b24-s1k") & set(IDLE_IN) == set(IDLE_IN) - {
         "idle_in_data_pct"}
-    assert "kv_repeat_ms_per_step" in reported("mistral-s8k-1chip")
+    # the copy of K and V is since PR 52 the fallback of heads 64 wide: no
+    # Mistral cell enters the scope, and the list says so since PR 67
+    assert "kv_repeat_ms_per_step" not in reported("mistral-s8k-1chip")
     assert "kv_repeat_ms_per_step" not in reported("gpt2s-b24-s1k")
+    assert "kv_repeat_ms_per_step" in reported("lfm2-s16k-1chip")
 
 
 # ------------------------------------------- on a hand-made trace
@@ -185,7 +189,9 @@ def rehearsal(tmp_path_factory):
             jax_config=JaxConfig(platform="cpu", cpu_devices_per_worker=1)))
         patch.setenv("RAY_TPU_TMPDIR",
                      str(tmp_path_factory.mktemp("ray_tpu")))
-        cell = _toy_cell("toy-gpt2", "toy-data", 1)
+        # a name, and so a trace directory, of its own (driver.run_cell)
+        cell = dataclasses.replace(_toy_cell("toy-gpt2", "toy-data", 1),
+                                   name="toy-spans")
         m = driver.run_cell(cell, seed=5, seconds=2.0, trace=True,
                             t_start=time.time())
     with open(m["trace"]["file"]) as f:
@@ -194,8 +200,12 @@ def rehearsal(tmp_path_factory):
 
 
 def test_the_rehearsal_leaves_every_span_and_no_backend(rehearsal):
+    # reading the profiler's file initialises no backend; an earlier JAX
+    # test of this process may have, which is not this test's to judge
+    was = driver.backend_initialized()
+    program_span.load.cache_clear()
     threads = program_span.load(program_span.xplane_of(rehearsal))
-    assert not driver.backend_initialized()
+    assert driver.backend_initialized() == was
     names = {n for spans in threads for n, _, _ in spans}
     assert names == {
         "ray_tpu/train/report", "ray_tpu/train/report/heartbeat",
@@ -275,8 +285,7 @@ def _scoped(path):
 
 @pytest.fixture(scope="module")
 def recorded():
-    with open(os.path.join(FIXTURES, "mistral-s8k-1chip.one-step.json")) as f:
-        before = Trace.from_json(f.read())
+    before = mistral_step()     # its forward calls under today's scope
     after = Trace({0: [Op(o.name, o.kind, _scoped(o.path), o.start, o.end)
                        for o in before.ops[0]]}, before.spans)
     ctx = lambda t: Context(manifest.cell("mistral-s8k-1chip"),  # noqa: E731

@@ -243,7 +243,9 @@ def test_cpu_rehearsal_of_the_cell_runs_and_is_refused(tmp_path, monkeypatch,
         "toy", 1, TOY, _load(HERE, "toy", "toy-gen.json"), bench["end_to_end"],
         [dict(m, file=_load(manifest.BENCH_DIR, "layer_metrics",
                             m["name"] + ".json")) for m in bench["per_layer"]])
-    m = driver.run_cell(cell, seed=2 ** 31 + 7, seconds=2.0, trace=False,
+    # 4 s: with the kernels interpreted (conftest.py, PR 67) a loaded machine
+    # makes too few steps in 2 s for this toy's loss to fall
+    m = driver.run_cell(cell, seed=2 ** 31 + 7, seconds=4.0, trace=False,
                         t_start=time.time())
     assert m["steps"] > 0 and m["failed"] == 0 and m["tokens"] > 0
     assert m["agreement"]["ok"], m["agreement"]

@@ -6,6 +6,7 @@ toy size, and must then *refuse* to make a line of it; ``run.py`` itself, on a
 machine that exposes no TPU chip, must exit non-zero with an empty stdout.
 """
 
+import dataclasses
 import functools
 import json
 import os
@@ -65,7 +66,9 @@ def test_cpu_rehearsal_runs_and_is_refused(config, traffic, chips, trace,
         jax_config=JaxConfig(platform="cpu", cpu_devices_per_worker=chips)))
     monkeypatch.setenv("RAY_TPU_TMPDIR", str(tmp_path / "ray_tpu"))
     cell = _toy_cell(config, traffic, chips)
-    m = driver.run_cell(cell, seed=3, seconds=2.0, trace=trace,
+    if trace:   # its trace directory is its name's: not another rehearsal's
+        cell = dataclasses.replace(cell, name="toy-traced")
+    m = driver.run_cell(cell, seed=3, seconds=4.0, trace=trace,
                         t_start=time.time())
     assert m["device"] == {"platform": "cpu", "kind": "cpu", "count": chips}
     assert m["steps"] > 0 and m["failed"] == 0 and m["tokens"] > 0

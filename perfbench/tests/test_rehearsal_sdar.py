@@ -40,7 +40,7 @@ def test_cpu_rehearsal_runs_and_is_refused(tmp_path, monkeypatch):
         jax_config=JaxConfig(platform="cpu", cpu_devices_per_worker=1)))
     monkeypatch.setenv("RAY_TPU_TMPDIR", str(tmp_path / "ray_tpu"))
     cell = _toy_cell()
-    m = driver.run_cell(cell, seed=2 ** 31 + 5, seconds=2.0, trace=False,
+    m = driver.run_cell(cell, seed=2 ** 31 + 5, seconds=4.0, trace=False,
                         t_start=time.time())
     assert m["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
     assert m["steps"] > 0 and m["failed"] == 0

@@ -32,7 +32,10 @@ def _toy_cell():
          if CELL in m.get("workloads", [CELL])])
 
 
-def test_cpu_rehearsal_runs_and_is_refused(tmp_path, monkeypatch):
+@pytest.fixture
+def on_the_cpu(tmp_path, monkeypatch):
+    """The driver asks for TPU chips and nothing else; only here is it handed
+    a trainer that puts its worker on a virtual CPU device instead."""
     import ray_tpu.train
     from ray_tpu.train.jax_config import JaxConfig
 
@@ -44,8 +47,11 @@ def test_cpu_rehearsal_runs_and_is_refused(tmp_path, monkeypatch):
         ray_tpu.train.JaxTrainer,
         jax_config=JaxConfig(platform="cpu", cpu_devices_per_worker=1)))
     monkeypatch.setenv("RAY_TPU_TMPDIR", str(tmp_path / "ray_tpu"))
+
+
+def test_cpu_rehearsal_runs_and_is_refused(on_the_cpu):
     cell = _toy_cell()
-    m = driver.run_cell(cell, seed=2 ** 31 + 65, seconds=2.0, trace=False,
+    m = driver.run_cell(cell, seed=2 ** 31 + 65, seconds=4.0, trace=False,
                         t_start=time.time())
     assert m["device"] == {"platform": "cpu", "kind": "cpu", "count": 1}
     assert m["steps"] > 0 and m["failed"] == 0
@@ -65,6 +71,34 @@ def test_cpu_rehearsal_runs_and_is_refused(tmp_path, monkeypatch):
     assert all(v["value"] > 0 for v in line["metrics"].values())
     assert line["correct"]
     json.dumps(line)
+
+
+def test_a_traced_rehearsal_brings_back_the_programs_counters(on_the_cpu):
+    """What only a traced run reads (PR 67): whatever
+    ``ShardedPretrainer.moe_stats`` held at the window's reports, averaged,
+    beside the reduced trace — reader ``measured`` finds ``moe_rows_held``
+    and ``hc_res_row_err`` there — and the profiler's two calls stamped on
+    the flight recorder's clock, inside the window."""
+    from perfbench.harness.readers import measured, rounds
+
+    t0 = time.time()
+    # a name, and so a trace directory, of its own (driver.run_cell)
+    m = driver.run_cell(dataclasses.replace(_toy_cell(), name="toy-xing4"),
+                        seed=67, seconds=4.0, trace=True, t_start=t0)
+    assert m["failed"] == 0 and m["trace"] is not None
+    counters = m["trace"]["counters"]
+    assert {"moe_rows_held", "hc_res_row_err", "max_load"} <= set(counters)
+    assert 0 <= counters["hc_res_row_err"] < 0.1
+    assert counters["moe_rows_held"] > 0
+    ctx = type("Ctx", (), {"measured": m})
+    assert measured.read(ctx, key="hc_res_row_err") \
+        == counters["hc_res_row_err"]
+    assert measured.read(ctx, key="moe_rows_held") == counters["moe_rows_held"]
+    assert measured.read(ctx, key="no_such_counter") is None
+    (a, b), (c, d) = rounds.profiler_calls(ctx)
+    assert t0 < a <= b <= c <= d < time.time()
+    # the untraced run reads none of it
+    assert "moe_rows_held" not in m and "counters" not in m
 
 
 def test_the_parents_program_cannot_build_the_configuration(monkeypatch):

@@ -101,8 +101,12 @@ def _ring(session_dir, name, rows):
     fr.shutdown()
 
 
-def _ctx(steps=WINDOW_STEPS):
-    return SimpleNamespace(measured={"steps": steps})
+def _ctx(steps=WINDOW_STEPS, profiler_calls=None):
+    measured = {"steps": steps}
+    if profiler_calls is not None:      # a traced run's
+        measured["trace"] = {"steps": 8, "file": "reduced.json",
+                             "profiler_calls": profiler_calls}
+    return SimpleNamespace(measured=measured)
 
 
 @pytest.fixture
@@ -127,9 +131,7 @@ def _args(name):
 def test_the_manifest_has_the_six():
     entries = {m["name"]: m for m in manifest.benchmark()["per_layer"]
                if m["name"] in SIX}
-    assert set(entries) == set(SIX)
-    assert [m["name"] for m in manifest.benchmark()["per_layer"][-6:]] \
-        == list(SIX)                    # added at the end, nothing moved
+    assert set(entries) == set(SIX)     # by name: later PRs append
     for m in entries.values():
         assert m["moves"] == "tokens_per_s_per_chip"
         assert m["better"] == "lower" and "workloads" not in m
@@ -165,6 +167,45 @@ def test_the_window_is_counted_back_by_the_records_steps(session):
                        **_args("rounds_stalled")) == 1.0
     # longer than the run: everything that has a step, the compile round too
     assert [s for _, s in rounds.window(mine, 500)] == [1.0] * 5
+
+
+@pytest.mark.parametrize("calls,stalled,excess,left_out", [
+    # an untraced run, and a traced one whose calls fell outside the window
+    (None, 1.0, 1.0 - 3.93 / 79, 0.0),
+    ([[1011.0, 1011.5], [1018.5, 1019.0]], 1.0, 1.0 - 3.93 / 79, 0.0),
+    # start_trace inside the stalled round (1016.5 to 1017.5): the round and
+    # its stall record are the benchmark's; what is left is 9 x 0.05, 0.07,
+    # 79 x 3.93 / 79 and 10 x 0.05
+    ([[1016.6, 1017.4], [1018.2, 1018.3]], 0.0, 0.07 - 3.93 / 79, 1.0),
+    # stop_trace in a round that did not stall: the ten rounds summed with
+    # it go too, and the stall stays
+    ([[1011.0, 1011.5], [1017.7, 1017.8]], 1.0, 1.0 - 3.93 / 79, 10.0),
+])
+def test_the_profilers_own_rounds_are_left_out(session, calls, stalled,
+                                               excess, left_out):
+    """A round in which the benchmark called ``start_trace`` or
+    ``stop_trace`` is no round of the program's: neither its length nor its
+    stall record is read, and ``left_out`` says how many went.  The seconds
+    by name are left whole."""
+    read = lambda **args: rounds.read(  # noqa: E731
+        _ctx(profiler_calls=calls), session_dir=session, **args)
+    assert read(**_args("rounds_stalled")) == stalled
+    assert read(**_args("round_worst_excess_s")) == pytest.approx(excess)
+    assert read(as_="left_out") == left_out
+    for name in SIX[2:]:
+        assert read(**_args(name)) == pytest.approx(EXPECTED[name])
+
+
+def test_a_real_stall_beside_the_profilers_calls_is_still_read(session):
+    """The calls take the rounds they overlap and no other: a stall elsewhere
+    in the window is counted as before."""
+    calls = [[1013.0, 1013.2], [1015.0, 1015.1]]    # inside the 80 rounds
+    read = lambda **args: rounds.read(  # noqa: E731
+        _ctx(profiler_calls=calls), session_dir=session, **args)
+    assert read(**_args("rounds_stalled")) == 1.0
+    assert read(as_="left_out") == 80.0
+    # 9 x 0.05, 1.0, 10 x 0.05: the median is 0.05
+    assert read(**_args("round_worst_excess_s")) == pytest.approx(0.95)
 
 
 def test_a_straddling_record_counts_by_its_share(session):

@@ -8,6 +8,7 @@ import pytest
 
 from perfbench.harness import trace_reduce as tr
 from perfbench.harness.trace_reduce import Op, Trace
+from perfbench.tests.recorded import mistral_step
 
 FIXTURES = os.path.join(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))), "fixtures")
@@ -128,35 +129,47 @@ def _flash_forward(metric):
     with open(os.path.join(os.path.dirname(FIXTURES), "layer_metrics",
                            metric + ".json")) as f:
         args = json.load(f)["args"]
-    return {k: args[k] for k in ("op", "path", "not_path")}
+    return {k: args[k] for k in ("op", "path", "not_path") if k in args}
 
 
-def test_flash_forward_metrics_leave_a_backward_kernel_out():
-    """ROADMAP S3/S4 will add a Pallas backward kernel: a Mosaic call in the
-    same ``attn`` scope, under the transpose.  The three forward metrics
-    select the same calls, and not that one — with remat and without."""
+def test_flash_forward_metrics_leave_every_other_kernel_out():
+    """The three forward metrics select the same calls: the Mosaic calls
+    under the scope ``flash_fwd``, with remat and without.  Not the backward
+    kernel, and (PR 67) no other kernel under ``attn``: EvaByte's pooling
+    kernels stood in ``flash_fwd_calls_per_step`` as eight of twelve, and a
+    Pallas prelude beside the flash kernels would have read a roofline over
+    100%."""
     from perfbench.harness.readers import trace_ops
 
-    with open(os.path.join(FIXTURES, "mistral-s8k-1chip.one-step.json")) as f:
-        ops = Trace.from_json(f.read()).ops[0]
+    ops = mistral_step().ops[0]
     end = ops[-1].end
     bwd = [Op(f"attn.{90 + i}", "custom-call:tpu_custom_call", path,
               end + i, end + i + 0.5) for i, path in enumerate([
         # the backward of a rematted block, and of a plain one
         "jit(pretrain_step)/transpose(jvp(LlamaLMModel))/jvp(LlamaLMModel)/"
-        "checkpoint/h_1/attn/pallas_call",
-        "jit(pretrain_step)/transpose(jvp(GPT2LMModel))/h_3/attn/pallas_call",
+        "checkpoint/h_1/attn/flash_bwd/flash_bwd/pallas_call",
+        "jit(pretrain_step)/transpose(jvp(GPT2LMModel))/h_3/attn/flash_bwd/"
+        "flash_bwd/pallas_call",
+        # other kernels of an attention layer: EVA's pooling, forward and
+        # again inside the backward pass, and a prelude of its own
+        "jit(pretrain_step)/jvp(LlamaLMModel)/h_1/attn/pool/pool_fwd/"
+        "pallas_call",
+        "jit(pretrain_step)/transpose(jvp(LlamaLMModel))/jvp(LlamaLMModel)/"
+        "checkpoint/rematted_computation/h_1/attn/pool/pool_fwd/pallas_call",
+        "jit(pretrain_step)/jvp(LlamaLMModel)/h_1/attn/rope/rope_fwd/"
+        "pallas_call",
     ])]
     fwd = Op("attn.99", "custom-call:tpu_custom_call",
-             "jit(pretrain_step)/jvp(GPT2LMModel)/h_3/attn/pallas_call",
-             end + 2, end + 2.5)
+             "jit(pretrain_step)/jvp(GPT2LMModel)/h_3/attn/flash_fwd/"
+             "flash_fwd/pallas_call", end + 5, end + 5.5)
     selections = [_flash_forward(m) for m in (
         "flash_fwd_ms_per_step", "flash_fwd_calls_per_step",
         "flash_fwd_roofline")]
     assert selections[0] == selections[1] == selections[2]
+    assert "not_path" not in selections[0]
     every_call = trace_ops.selected(ops + bwd + [fwd],
                                     op=selections[0]["op"])
-    assert len(every_call) == 4 + 3
+    assert len(every_call) == 4 + 6
     found = trace_ops.selected(ops + bwd + [fwd], **selections[0])
     assert [o.name for o, _ in found] == [
         "attn.4", "attn.5", "attn.6", "attn.7", "attn.99"]
@@ -168,8 +181,7 @@ def test_recorded_step_of_mistral_on_the_chip():
     nesting, real name paths and the real Mosaic calls."""
     from perfbench.harness.readers import trace_ops
 
-    with open(os.path.join(FIXTURES, "mistral-s8k-1chip.one-step.json")) as f:
-        trace = Trace.from_json(f.read())
+    trace = mistral_step()  # its forward calls under today's scope
     ops, window = trace.ops[0], trace.window()
     busy = tr.busy_seconds(ops)
     assert busy == pytest.approx(0.57226, abs=1e-4)
@@ -216,8 +228,7 @@ def test_every_metric_file_reads_the_recorded_step():
     from perfbench.harness.readers.context import Context
 
     cell = manifest.cell("mistral-s8k-1chip")
-    with open(os.path.join(FIXTURES, "mistral-s8k-1chip.one-step.json")) as f:
-        trace = Trace.from_json(f.read())
+    trace = mistral_step()
     measured = {
         "spans_ms": {"input": [0.2, 0.4, 0.3], "report": [0.5]},
         "memory": [{"peak_bytes_in_use": 8e9, "peak_bytes_reserved": 4e9}],
