@@ -2283,7 +2283,7 @@ class CoreWorker:
             self._cancelled_exec.discard(tkey)
 
     def _create_actor_sync(self, spec: TaskSpec) -> dict:
-        t_create = time.perf_counter()
+        t_create = flight_recorder.usage()
         try:
             from ray_tpu import runtime_env as renv
 
@@ -2309,9 +2309,10 @@ class CoreWorker:
             _trace_ctx.reset(trace_token)
         if flight_recorder.RECORDING:
             # the class unpickled — its module's imports, which for a train
-            # worker are the train package's, jax among them — and built
-            flight_recorder.mark("bringup.worker.actor",
-                                 time.perf_counter() - t_create, spec.name)
+            # worker are the train package's, jax among them (the child
+            # ``bringup.worker.jax_import``) — and built
+            flight_recorder.mark_since("bringup.worker.actor", t_create,
+                                       spec.name)
         self.actor_id = spec.actor_creation_id
         self.job_id = spec.job_id
         if spec.max_concurrency > 1:

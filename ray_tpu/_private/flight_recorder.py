@@ -38,6 +38,13 @@ is in docs/ARCHITECTURE.md 5e) and so are the train worker's compile events
 of a session.  Marks made before a process has its ring (a driver before
 its core worker, a worker before its own) wait in a short list and are
 written, with their own stamps, by :func:`init_process`.
+
+A mark made through :func:`timed` or :func:`mark_since` ends its detail with
+what the interval cost — ``cpu=<s> majflt=<n> inblock=<n>``: the calling
+thread's CPU seconds and the process's major page faults and blocks read from
+a disk — so that seconds that were not computed in can be told from seconds
+waited for the disk or for something else.  :func:`bringup_timeline` takes
+that field off again; :func:`start_account` reads it.
 """
 
 from __future__ import annotations
@@ -48,11 +55,12 @@ import glob
 import math
 import mmap
 import os
+import resource
 import statistics
 import struct
 import threading
 import time
-from typing import Dict, Iterator, List, NamedTuple, Optional, Tuple
+from typing import Any, Dict, Iterator, List, NamedTuple, Optional, Tuple
 
 FILE_MAGIC = b"RTFR"
 VERSION = 1
@@ -79,6 +87,10 @@ _PENDING_MAX = 64
 # A mark: (process name, kind, start, end, detail), times on time.time()
 Mark = Tuple[str, str, float, float, str]
 ENTERED = "bringup.worker.train_fn_enter"
+FIRST_REPORT = "bringup.first_report"   # a point: where a start ends
+# where a process is in time and in what it has used: perf_counter, the
+# thread's CPU seconds, the process's major faults and blocks read
+Usage = Tuple[float, float, int, int]
 
 
 def ring_path(session_dir: str, name: str) -> str:
@@ -183,16 +195,34 @@ def mark(kind: str, seconds: float, detail: str = "",
     record(kind, detail, ts)
 
 
+def usage() -> Usage:
+    """Where the calling thread stands: what :func:`mark_since` measures
+    from."""
+    used = resource.getrusage(resource.RUSAGE_SELF)
+    return (time.perf_counter(), time.thread_time(), used.ru_majflt,
+            used.ru_inblock)
+
+
+def mark_since(kind: str, since: Usage, detail: str = "") -> None:
+    """One :func:`mark` of the interval from ``since`` (a :func:`usage` of
+    this thread) to now, its cost behind the detail."""
+    t0, cpu0, faults0, blocks0 = since
+    t1, cpu1, faults1, blocks1 = usage()
+    cost = (f"cpu={cpu1 - cpu0:.6f} majflt={faults1 - faults0} "
+            f"inblock={blocks1 - blocks0}")
+    mark(kind, t1 - t0, f"{detail}|{cost}" if detail else cost)
+
+
 @contextlib.contextmanager
 def timed(kind: str, detail: str = "") -> Iterator[None]:
-    """Time the block (``perf_counter``) and write one :func:`mark` at its
-    exit — also where the block raises: a phase that failed is the one an
-    operator looks for."""
-    t0 = time.perf_counter()
+    """Time the block and write one :func:`mark_since` at its exit — also
+    where the block raises: a phase that failed is the one an operator looks
+    for."""
+    since = usage()
     try:
         yield
     finally:
-        mark(kind, time.perf_counter() - t0, detail)
+        mark_since(kind, since, detail)
 
 
 def shutdown() -> None:
@@ -261,12 +291,23 @@ def harvest_for(session_dir: str, name: str,
     return harvest(ring_path(session_dir, name), limit)
 
 
+class _Timed(NamedTuple):
+    """A :data:`Mark` and, off its detail's end, :func:`mark_since`'s cost
+    (``""`` where the record has none)."""
+    process: str
+    kind: str
+    start: float
+    end: float
+    detail: str
+    cost: str
+
+
 def _timed_records(session_dir: str, kinds: Tuple[str, ...]
-                   ) -> Iterator[Tuple[str, str, float, float, str]]:
+                   ) -> Iterator[_Timed]:
     """Every record of every ring of ``session_dir`` whose kind starts with
-    one of ``kinds``, as ``(process, kind, start, end, rest of the detail)``:
-    a leading duration is taken off the detail and back from the stamp; a
-    record without one is a point."""
+    one of ``kinds``: a leading duration is taken off the detail and back
+    from the stamp (a record without one is a point), and the cost off its
+    end."""
     for path in glob.glob(ring_path(session_dir, "*")):
         name = os.path.basename(path)[:-len(".ring")]
         for r in harvest(path):
@@ -277,7 +318,23 @@ def _timed_records(session_dir: str, kinds: Tuple[str, ...]
                 secs, detail = float(head), rest
             except ValueError:
                 secs, detail = 0.0, r["detail"]
-            yield name, r["kind"], r["ts"] - secs, r["ts"], detail
+            cost = ""
+            if detail.rpartition("|")[2].startswith("cpu="):
+                detail, _, cost = detail.rpartition("|")
+            yield _Timed(name, r["kind"], r["ts"] - secs, r["ts"], detail,
+                         cost)
+
+
+def _values(part: str) -> Dict[str, float]:
+    """``name=<number> ...`` as a table."""
+    found: Dict[str, float] = {}
+    for token in part.split():
+        name, _, value = token.rpartition("=")
+        try:
+            found[name] = float(value)
+        except ValueError:
+            continue    # a record cut at MAX_PAYLOAD ends in half a token
+    return found
 
 
 def bringup_gap(marks: List[Mark]) -> Optional[float]:
@@ -307,9 +364,97 @@ def bringup_timeline(session_dir: str
     them.  A record without a leading duration (``compile.cache|hit``) is a
     point."""
     marks: List[Mark] = sorted(
-        _timed_records(session_dir, ("bringup.", "compile")),
+        (tuple(r[:5]) for r in _timed_records(session_dir,
+                                               ("bringup.", "compile"))),
         key=lambda m: (m[2], m[3]))
     return marks, bringup_gap(marks)
+
+
+def _innermost(spans: List[Tuple[float, float, str]], lo: float, hi: float,
+               named: Dict[str, float]) -> float:
+    """``[lo, hi]`` by the span that covers each moment innermost — of those
+    that cover it the one that began last, and of two that began together
+    the shorter — added into ``named`` by name; returns the seconds that no
+    span covers."""
+    spans = [(max(a, lo), min(b, hi), name) for a, b, name in spans
+             if min(b, hi) > max(a, lo)]
+    edges = sorted({lo, hi} | {t for a, b, _ in spans for t in (a, b)})
+    bare = 0.0
+    for a, b in zip(edges, edges[1:]):
+        over = [s for s in spans if s[0] <= a and s[1] >= b]
+        if not over:
+            bare += b - a
+            continue
+        name = max(over, key=lambda s: (s[0], -s[1]))[2]
+        named[name] = named.get(name, 0.0) + b - a
+    return bare
+
+
+def start_account(session_dir: str, since: float = 0.0
+                  ) -> Optional[Dict[str, Any]]:
+    """A start, accounted: the interval from the beginning of the first
+    ``bringup.*`` mark of ``session_dir`` that ends after ``since`` to the
+    last ``bringup.first_report``, by what it went to.  ``None`` until a
+    train worker has reported.
+
+    ``named``: seconds by name, each moment given to the mark or ``compile``
+    record that covers it innermost (so a mark's entry is what none of its
+    children holds, and a jitted function traced inside another's trace is
+    counted once).  A mark goes by its kind, a ``compile`` record by
+    ``compile|<stage>``.  Up to the last ``train_fn_enter`` every process's
+    marks count, a worker's only where it is of the gang; after it, the
+    gang's workers' alone.  ``unnamed``: the seconds no mark or record
+    covers; with ``named`` it sums to ``total``.  ``unnamed_by``: those
+    seconds ``before_loop`` (the runtime's and the trainer's) and
+    ``in_loop`` (the user's function: what it does between the program's
+    marks, its imports among it).  ``marks``: of every kind
+    written through :func:`mark_since`, the longest mark whole — ``seconds``,
+    ``cpu``, ``off_cpu`` (the seconds its thread did not compute in),
+    ``majflt``, ``inblock``.  ``points``: the details of the records without
+    a duration (``compile.cache_dir``, ``bringup.worker.chip_on_arrival``),
+    in order, by kind."""
+    rows = sorted((r for r in _timed_records(session_dir,
+                                             ("bringup.", "compile"))
+                   if r.end >= since), key=lambda r: (r.start, r.end))
+    reported = [r.end for r in rows if r.kind == FIRST_REPORT]
+    entered = [r for r in rows if r.kind == ENTERED]
+    if not reported or not entered:
+        return None
+    gang = {r.process for r in entered}
+    workers = {r.process for r in rows
+               if r.kind.startswith("bringup.worker.")}
+    begin = min(r.start for r in rows if r.kind.startswith("bringup."))
+    enter, end = max(r.end for r in entered), max(reported)
+    before: List[Tuple[float, float, str]] = []
+    after: List[Tuple[float, float, str]] = []
+    marks: Dict[str, Dict[str, float]] = {}
+    points: Dict[str, List[str]] = {}
+    for process, kind, a, b, detail, cost in rows:
+        if process in workers - gang or (
+                kind == "bringup.worker_spawn" and detail not in gang):
+            continue    # a pooled worker that is not of this gang
+        if b <= a:
+            if kind not in (ENTERED, FIRST_REPORT):
+                points.setdefault(kind, []).append(detail)
+            continue
+        name = kind
+        if kind == "compile":
+            name = f"compile|{detail.partition('|')[0]}"
+        elif not kind.startswith("bringup."):
+            continue
+        before.append((a, b, name))
+        if process in gang:
+            after.append((a, b, name))
+        if cost and b - a >= marks.get(kind, {}).get("seconds", 0.0):
+            used = _values(cost)
+            marks[kind] = {"seconds": b - a, **used,
+                           "off_cpu": max(0.0, b - a - used.get("cpu", 0.0))}
+    named: Dict[str, float] = {}
+    by = {"before_loop": _innermost(before, begin, enter, named),
+          "in_loop": _innermost(after, enter, end, named)}
+    return {"total": end - begin, "unnamed": sum(by.values()),
+            "unnamed_by": by, "named": named, "marks": marks,
+            "points": points}
 
 
 # --- the steady state: spans without a profiler session, rounds ------------
@@ -361,18 +506,8 @@ def round_detail(counts: Dict[str, float], seconds: Dict[str, float]) -> str:
 
 
 def _parse_round(detail: str) -> Tuple[Dict[str, float], Dict[str, float]]:
-    def values(part: str) -> Dict[str, float]:
-        found: Dict[str, float] = {}
-        for token in part.split():
-            name, _, value = token.rpartition("=")
-            try:
-                found[name] = float(value)
-            except ValueError:
-                continue    # a record cut at MAX_PAYLOAD ends in half a token
-        return found
-
     counts, _, seconds = detail.partition(";")
-    return values(counts), values(seconds)
+    return _values(counts), _values(seconds)
 
 
 class RoundLog:
@@ -480,7 +615,7 @@ def round_timeline(session_dir: str) -> List[Round]:
     sibling for what comes after ``train_fn_enter``."""
     rounds = sorted(
         (Round(name, kind, start, end, *_parse_round(detail))
-         for name, kind, start, end, detail in _timed_records(
+         for name, kind, start, end, detail, _ in _timed_records(
              session_dir, (ROUNDS, STALL, DRIVER_ROUNDS))),
         key=lambda r: (r.start, r.end))
     drivers = [r for r in rounds if r.kind == DRIVER_ROUNDS]

@@ -26,8 +26,10 @@ from __future__ import annotations
 import asyncio
 import logging
 import os
+import signal
 import subprocess
 import sys
+import threading
 import time
 from collections import deque
 from concurrent.futures import ThreadPoolExecutor
@@ -182,6 +184,9 @@ class Nodelet:
         # harvested rings of dead workers, oldest first: the newest stay on
         # disk for a reader that comes when the run is over
         self._dead_rings: deque = deque()
+        # TPU workers on their way out: worker id -> monotonic time of its
+        # end's beginning; see _watch_end
+        self._ending: Dict[bytes, float] = {}
 
     # ------------------------------------------------------------------ boot
     async def start(self, host: str = "127.0.0.1", port: int = 0) -> Tuple[str, int]:
@@ -303,11 +308,16 @@ class Nodelet:
         procs = [w.proc for w in self.workers.values() if w.proc is not None]
         for w in list(self.workers.values()):
             self._kill_worker_proc(w)
+            self._watch_end(w)
         # Reap them before this node counts as stopped: a killed worker that
         # held the TPU keeps /dev/vfio busy until the kernel has torn the
         # process down, and the next process on this host needs the chips.
         await asyncio.get_running_loop().run_in_executor(
             None, _reap, procs, 10.0)
+        # a TPU worker that is still ending when this node leaves: what a
+        # start that meets a held chip is laid beside
+        for wid in list(self._ending):
+            self._record_end(wid, "SIGKILL", "|alive")
         await self.server.stop()
         if self.gcs is not None:
             await self.gcs.close()
@@ -1164,6 +1174,7 @@ class Nodelet:
             # have been counting on it; without a re-pump they would wait
             # forever — nothing else spawns until the next register/return.
             self._fulfill_pops()
+        self._watch_end(w)
         if w.lease_id is not None:
             self._release_lease(w.lease_id)
         # Post-mortem harvest BEFORE reporting: the death notify carries the
@@ -1229,6 +1240,41 @@ class Nodelet:
                 gcs.notify("incident_report", rec))
         except RuntimeError:
             pass  # off-loop close: the local ledger keeps the record
+
+    def _watch_end(self, w: WorkerHandle) -> None:
+        """How a worker that held TPU chips ended and when its pid was gone,
+        as one flight-recorder record, ``shutdown.worker|<how>|<seconds>|
+        <worker id>``: ``exit``, or the signal that ended it (``SIGKILL``
+        where this nodelet killed it), and the seconds from here — the kill,
+        or the exit's being noticed — to the process's end, which is when
+        the kernel has let the chip go.  A thread waits for that and nothing
+        waits for the thread; where the nodelet stops first, ``stop`` writes
+        the record with the seconds so far and ``|alive`` behind it."""
+        lease = self.leases.get(w.lease_id)
+        if w.proc is None or not flight_recorder.RECORDING \
+                or not (lease and lease["resources"].get("TPU")):
+            return
+        wid, proc = w.worker_id, w.proc
+        self._ending[wid] = time.monotonic()
+
+        def gone() -> None:
+            try:
+                code = proc.wait(timeout=60.0)
+            except subprocess.TimeoutExpired:
+                return
+            self._record_end(
+                wid, signal.Signals(-code).name if code < 0 else "exit")
+
+        threading.Thread(target=gone, daemon=True,
+                         name="worker-end").start()
+
+    def _record_end(self, wid: bytes, how: str, still: str = "") -> None:
+        """``_watch_end``'s record, once a worker: by its thread when the pid
+        is gone, or by ``stop`` before that."""
+        t_end = self._ending.pop(wid, None)
+        if t_end is not None:
+            flight_recorder.record("shutdown.worker", (
+                f"{how}|{time.monotonic() - t_end:.6f}|{wid.hex()}{still}"))
 
     def _kill_worker_proc(self, w: WorkerHandle):
         if w.proc is not None and w.proc.poll() is None:

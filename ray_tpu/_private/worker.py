@@ -82,8 +82,9 @@ def init(
 
         # the runtime's start on the flight recorder's clock; written into
         # the driver's ring, which its core worker opens below
-        t_init = time.perf_counter()
         from ray_tpu._private import flight_recorder
+
+        t_init = flight_recorder.usage()
         from ray_tpu._private.core_worker import CoreWorker
         from ray_tpu._private.node import Node
 
@@ -137,7 +138,7 @@ def init(
         _global_worker = Worker(core, node=node, namespace=namespace)
         set_global_core(core)
         atexit.register(_atexit_shutdown)
-        flight_recorder.mark("bringup.init", time.perf_counter() - t_init)
+        flight_recorder.mark_since("bringup.init", t_init)
         return _global_worker
 
 

@@ -46,9 +46,10 @@ def main(argv=None):
     from ray_tpu._private.core_worker import CoreWorker
     from ray_tpu._private.ids import NodeID, WorkerID
 
-    # both wait for the ring the core worker opens, and keep their stamps
-    flight_recorder.mark("bringup.worker.imports",
-                         time.perf_counter() - t_main)
+    # both wait for the ring the core worker opens, and keep their stamps;
+    # the imports' cost is the process's so far: what the interpreter used
+    # before ``main`` (some 20 ms) is in it
+    flight_recorder.mark_since("bringup.worker.imports", (t_main, 0.0, 0, 0))
     with flight_recorder.timed("bringup.worker.connect"):
         core = CoreWorker(
             mode="worker",

@@ -274,13 +274,19 @@ class ShardedPretrainer:
 
     def __init__(self, config, mesh_config: Optional[MeshConfig] = None,
                  lr: float = 3e-4, devices=None, total_steps: int = 10_000):
-        self.mesh = build_mesh(mesh_config or MeshConfig(), devices=devices)
-        self.tx = make_optimizer(lr, total_steps=total_steps)
-        self._step, self.model, init_state, layout, self.batch_sharding = \
-            sharded_train_step(config, self.mesh, self.tx)
-        self.config = self.model.config
-        self.param_specs, self.opt_specs = jax.tree_util.tree_map(
-            lambda a: a.sharding.spec, layout)
+        # the mesh, the optimizer, and the step with its layouts from shapes
+        # alone (an eval_shape of the whole state, the partition rules)
+        with flight_recorder.timed("bringup.trainer_build"):
+            self.mesh = build_mesh(mesh_config or MeshConfig(),
+                                   devices=devices)
+            self.tx = make_optimizer(lr, total_steps=total_steps)
+            self._compiled, self.model, init_state, layout, \
+                self.batch_sharding = sharded_train_step(
+                    config, self.mesh, self.tx)
+            self.config = self.model.config
+            self.param_specs, self.opt_specs = jax.tree_util.tree_map(
+                lambda a: a.sharding.spec, layout)
+        self._step = self._first_run
         # Initialized under jit straight into its shards: no device ever
         # holds the whole state, and every process of a multi-host mesh
         # builds only what it addresses (same seed, same values: the RNG
@@ -348,11 +354,21 @@ class ShardedPretrainer:
                                                               batch)
         return loss
 
+    def _first_run(self, state, batch):
+        """The step's first call, as the mark ``bringup.first_run``: its
+        trace, lowering and build or load are ``compile`` records inside it,
+        and what they leave of it is the program's first dispatch (its
+        upload, its arguments' donation); the calls after it go to the
+        jitted function itself."""
+        self._step = self._compiled
+        with flight_recorder.timed("bringup.first_run", "pretrain_step"):
+            return self._compiled(state, batch)
+
     def lower(self, batch: Dict[str, Any]):
         """The step lowered for this batch's shapes: ``.compile()`` it for the
         HLO text and memory analysis of what ``step`` runs."""
         with jax.set_mesh(self.mesh):
-            return self._step.lower(self.state, self.shard_batch(batch))
+            return self._compiled.lower(self.state, self.shard_batch(batch))
 
     def tokens_per_batch(self, batch) -> int:
         return int(batch["input_ids"].size)

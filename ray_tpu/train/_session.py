@@ -215,6 +215,8 @@ class _TrainSession:
             labels = {"experiment": self.context.experiment_name or ""}
             m["reports"].inc(1, labels)
             self._step += 1
+            if self._step == 1 and flight_recorder.RECORDING:
+                self._first_report()
             m["rank_step"].set(self._step, {
                 **labels, "rank": str(self.context.world_rank)})
             with profiler_span("train/report/heartbeat"):
@@ -239,6 +241,16 @@ class _TrainSession:
                 self._consumed.acquire()
         if self._rounds is not None:
             self._close_round()
+
+    @staticmethod
+    def _first_report() -> None:
+        """Where a start ends on the program's clock: the loop's first
+        ``report`` has been called (``flight_recorder.start_account``), and
+        what the compile cache's directory holds by now."""
+        from ray_tpu._private.platform import record_compile_cache
+
+        flight_recorder.record(flight_recorder.FIRST_REPORT)
+        record_compile_cache("first_report")
 
     def _close_round(self) -> None:
         """The round that this ``report`` ends, into the flight recorder: its

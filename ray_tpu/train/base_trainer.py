@@ -151,11 +151,14 @@ _GANG_UP_PHASES = (
     ("worker spawn", "bringup.worker_spawn",
      (("imports", "bringup.worker.imports"),
       ("connect", "bringup.worker.connect"))),
-    ("actor", "bringup.worker.actor", ()),
-    ("jax import", "bringup.worker.jax_import", ()),
+    ("actor", "bringup.worker.actor",
+     (("jax import", "bringup.worker.jax_import"),)),
     ("compile cache", "bringup.worker.compile_cache", ()),
     ("distributed init", "bringup.worker.distributed_init", ()),
-    ("tpu client", "bringup.worker.tpu_client", ()),
+    ("tpu client", "bringup.worker.tpu_client",
+     (("plugin", "bringup.worker.tpu_client.plugin_load"),
+      ("client", "bringup.worker.tpu_client.client"),
+      ("query", "bringup.worker.tpu_client.device_query"))),
     ("session", "bringup.session", ()),
 )
 
@@ -186,6 +189,34 @@ def gang_up_line(marks: List[flight_recorder.Mark]) -> Optional[str]:
     phases = filter(None, (shown(*p) for p in _GANG_UP_PHASES))
     return (f"train gang up in {total:.1f} s: " + " | ".join(phases)
             + f" | uncovered {gap:.1f}")
+
+
+def start_line(account: Dict[str, Any], top: int = 12) -> str:
+    """``flight_recorder.start_account``'s account as one line: the start's
+    seconds to the first ``train.report``, the ``top`` names that hold most
+    of them (each what none of its children holds), the seconds no mark
+    holds, what the TPU client's seconds were (computing, the disk, or
+    neither), the chip's state on arrival and the compile cache's fill."""
+    named = sorted(account["named"].items(), key=lambda kv: -kv[1])
+    parts = [f"{name.removeprefix('bringup.')} {secs:.1f}"
+             for name, secs in named[:top]]
+    if named[top:]:
+        parts.append(f"{len(named) - top} more "
+                     f"{sum(secs for _, secs in named[top:]):.1f}")
+    parts.append(f"unnamed {account['unnamed']:.1f} (in the loop "
+                 f"{account['unnamed_by']['in_loop']:.1f})")
+    client = account["marks"].get("bringup.worker.tpu_client")
+    if client:
+        parts.append(
+            f"tpu client {client['seconds']:.1f} (cpu {client['cpu']:.1f}, "
+            f"major faults {client['majflt']:.0f}, blocks read "
+            f"{client['inblock']:.0f}; chip " + ", ".join(
+                account["points"].get("bringup.worker.chip_on_arrival", "?"))
+            + ")")
+    for cache in account["points"].get("compile.cache_dir", ())[-1:]:
+        parts.append("compile cache " + cache.partition("|")[2])
+    return (f"train start took {account['total']:.1f} s to the first report: "
+            + " | ".join(parts))
 
 
 def rounds_line(timeline: List[flight_recorder.Round]) -> Optional[str]:
@@ -266,7 +297,7 @@ class DataParallelTrainer(BaseTrainer):
         metrics["gang_state"].set(GANG_STATES["STARTING"], mlabels)
         executor = BackendExecutor(self.backend_config, self.scaling_config)
         executor.start()
-        t_session = time.perf_counter()
+        t_session = flight_recorder.usage()
         metrics_history = []
         latest_ckpt: Optional[str] = (
             self.resume_from_checkpoint.path
@@ -293,8 +324,7 @@ class DataParallelTrainer(BaseTrainer):
                 checkpoint_seq_start=_next_checkpoint_seq(trial_dir),
                 dataset_shards=dataset_shards,
             )
-            flight_recorder.mark("bringup.session",
-                                 time.perf_counter() - t_session)
+            flight_recorder.mark_since("bringup.session", t_session)
             metrics["gang_state"].set(GANG_STATES["RUNNING"], mlabels)
             self._log_gang_up(t_loop)
             metrics["gang_workers"].set(n_workers, mlabels)
@@ -303,6 +333,8 @@ class DataParallelTrainer(BaseTrainer):
                     timeout_s=self.run_config.worker_report_timeout_s)
                 if results is None:
                     break
+                if not metrics_history:
+                    self._log_start(t_loop)
                 metrics["report_rounds"].inc(1, mlabels)
                 rank0 = results[0]
                 last_metrics = rank0.metrics
@@ -333,22 +365,39 @@ class DataParallelTrainer(BaseTrainer):
         )
 
     @staticmethod
-    def _log_gang_up(t_loop: float) -> None:
-        """One INFO line from the session's rings, which outlives their
-        wrap in the driver's log; nothing where the recorder is off."""
+    def _gang_marks(t_loop: float):
+        """The session's directory, the time since which its marks are those
+        of the gang that ``training_loop`` began at ``t_loop``, and those
+        marks; ``None`` where the recorder is off."""
         from ray_tpu._private.worker import global_worker_core
 
         core = global_worker_core()
         if core is None or not flight_recorder.RECORDING:
-            return
+            return None
         marks, _ = flight_recorder.bringup_timeline(core.session_dir)
-        if any(m[1] == "bringup.gang" and m[3] < t_loop for m in marks):
-            # not the session's first gang: the runtime's start and the
-            # earlier gangs' marks are not part of this one's
-            marks = [m for m in marks if m[3] >= t_loop]
-        line = gang_up_line(marks)
+        # not the session's first gang: the runtime's start and the earlier
+        # gangs' marks are not part of this one's
+        since = t_loop if any(m[1] == "bringup.gang" and m[3] < t_loop
+                              for m in marks) else 0.0
+        return core.session_dir, since, [m for m in marks if m[3] >= since]
+
+    @classmethod
+    def _log_gang_up(cls, t_loop: float) -> None:
+        """One INFO line from the session's rings, which outlives their
+        wrap in the driver's log; nothing where the recorder is off."""
+        found = cls._gang_marks(t_loop)
+        line = found and gang_up_line(found[2])
         if line:
             logger.info(line)
+
+    @classmethod
+    def _log_start(cls, t_loop: float) -> None:
+        """The start's one INFO line, when the first round of reports is in:
+        ``_log_gang_up``'s sequel, down to the first ``train.report``."""
+        found = cls._gang_marks(t_loop)
+        account = found and flight_recorder.start_account(*found[:2])
+        if account:
+            logger.info(start_line(account))
 
     @staticmethod
     def _log_rounds(t_loop: float) -> None:

@@ -24,6 +24,7 @@ anything else is an error.
 
 from __future__ import annotations
 
+import contextlib
 import re
 from dataclasses import dataclass
 from typing import Optional
@@ -107,7 +108,9 @@ def _setup_jax_distributed(coordinator: Optional[str], num_processes: int,
     if platform not in ("cpu", "tpu"):
         raise ValueError(f"platform must be 'cpu' or 'tpu', got {platform!r}")
 
-    from ray_tpu._private.platform import enable_compile_cache, watch_compiles
+    from ray_tpu._private.platform import (chip_on_arrival, client_seams,
+                                           enable_compile_cache, import_jax,
+                                           watch_compiles)
 
     if platform == "cpu":
         # Replace (not append) any inherited device-count flag: workers
@@ -123,11 +126,9 @@ def _setup_jax_distributed(coordinator: Optional[str], num_processes: int,
     else:
         os.environ["JAX_PLATFORMS"] = "tpu"
         os.environ.pop("RAY_TPU_PALLAS_INTERPRET", None)
-    with flight_recorder.timed("bringup.worker.jax_import"):
-        import jax
-
-    # jax may already be imported in this worker process, where the env var
-    # alone would come too late
+    # already imported where the actor's class load reached it (the mark
+    # is there then), and then the env var alone would come too late
+    jax = import_jax()
     jax.config.update("jax_platforms", platform)
     if platform == "tpu":
         with flight_recorder.timed("bringup.worker.compile_cache"):
@@ -144,19 +145,22 @@ def _setup_jax_distributed(coordinator: Optional[str], num_processes: int,
                                        num_processes=num_processes,
                                        process_id=process_id)
     with flight_recorder.timed("bringup.worker.tpu_client"):
-        # raises if `platform` cannot initialize
-        backend = jax.default_backend()
+        with chip_on_arrival() if platform == "tpu" \
+                else contextlib.nullcontext(), client_seams():
+            # raises if `platform` cannot initialize
+            backend = jax.default_backend()
         if backend != platform:
             raise RuntimeError(
                 f"train worker asked for platform {platform!r} but jax came "
                 f"up on {backend!r}")
-        return {
-            "process_id": jax.process_index(),
-            "process_count": jax.process_count(),
-            "local_device_count": jax.local_device_count(),
-            "global_device_count": jax.device_count(),
-            "platform": backend,
-        }
+        with flight_recorder.timed("bringup.worker.tpu_client.device_query"):
+            return {
+                "process_id": jax.process_index(),
+                "process_count": jax.process_count(),
+                "local_device_count": jax.local_device_count(),
+                "global_device_count": jax.device_count(),
+                "platform": backend,
+            }
 
 
 def _teardown_jax_distributed() -> None:

@@ -14,8 +14,10 @@ Python-level deadline's.
 
 from __future__ import annotations
 
-import jax
-import jax.numpy as jnp
+from ray_tpu._private.platform import import_jax
+
+# a train worker's first import of jax, as its class load reaches this module
+jax = import_jax()
 
 
 def allreduce(x, axis_name: str = "dp", op: str = "sum"):

@@ -99,10 +99,18 @@ def test_timed_writes_one_mark_at_its_exit(own_ring):
             raise KeyError("x")
     fr.mark("bringup.known", 2.5)
     unit, failed, known = fr.harvest_for(sdir, "timed")[-3:]
-    seconds, _, why = unit["detail"].partition("|")
+    # the seconds, the detail, and since PR 68 what the block cost
+    seconds, why, cost = unit["detail"].split("|")
     assert unit["kind"] == "bringup.unit" and why == "why"
     assert 0.0 <= float(seconds) < 60.0
-    assert failed["kind"] == "bringup.failed" and float(failed["detail"]) >= 0
+    assert [t.split("=")[0] for t in cost.split()] == [
+        "cpu", "majflt", "inblock"]
+    seconds, _, cost = failed["detail"].partition("|")
+    assert failed["kind"] == "bringup.failed" and float(seconds) >= 0
+    assert cost.startswith("cpu=")
+    # the timeline's detail is the caller's, without the cost
+    assert [m[4] for m in fr.bringup_timeline(sdir)[0]
+            if m[1] == "bringup.unit"] == ["why"]
     assert (known["kind"], known["detail"]) == ("bringup.known", "2.500000")
     (_, _, start, end, _), = [m for m in fr.bringup_timeline(sdir)[0]
                               if m[1] == "bringup.known"]
