@@ -13,7 +13,9 @@ in every ``moe_every``-th layer, the dropless routed experts of
 query and key projections before the heads are split and rotated
 (OLMoE-1B-7B is this block with both), or, as ``"head"``, over each head's
 ``head_dim`` after the split, one scale shared by the heads, in the rotation's
-own pass (``apply_rope``); ``head_dim`` is
+own pass (``ops/rope.py::rope_qk``: one kernel call a direction over q and k
+where ``wq`` / ``wk`` wrote them; or ``apply_rope`` over their heads turned
+first, where the shapes are no whole tiles); ``head_dim`` is
 a size of its own where it is not ``d_model / n_head``.  A layer's token mixer is a kind
 too: ``layer_types`` names each layer ``"attention"`` (``attn``) or
 ``"mamba"`` (``mamba``: ``models/mamba.py``'s Mamba-2 mixer over the Pallas
@@ -163,7 +165,7 @@ from ray_tpu.models.kda import HeadNorm, KDAMixer
 from ray_tpu.models.mamba import (GatedMemoryUnit, Mamba1Mixer, Mamba2Mixer,
                                   SplitDense, _conv_init, gated_short_conv)
 from ray_tpu.models.moe import RoutedConfig, RoutedSwiGLU, silu_mul
-from ray_tpu.ops import hyper_connection, pooling
+from ray_tpu.ops import hyper_connection, pooling, rope
 from ray_tpu.ops.attention import HeadColumns, attention
 from ray_tpu.ops.hyper_connection import sinkhorn  # noqa: F401  (its home)
 from ray_tpu.ops.hyper_connection import streams as _streams
@@ -464,7 +466,14 @@ def apply_rope(x, cos, sin, norm_scale=None, eps: float = 1e-6):
     whose epilogue the multiply-adds fuse into (``_turn``); the backward is
     the same pass over the cotangent.  With ``norm_scale`` (``D`` wide) each
     head is RMS-normed before it is turned, in that pass: the statistic in
-    float32 from ``x`` as it comes, one rounding at the end."""
+    float32 from ``x`` as it comes, one rounding at the end.
+
+    This is the XLA pass, and the reference of ``ops/rope.py``'s kernels,
+    which do the same arithmetic on q and k as ``(B, S, H * D)``:
+    ``LlamaAttention`` calls those wherever ``ops.rope.takes`` says the shapes
+    allow (heads that fill blocks of 128 lanes whole on every device) and
+    this, after a turn to heads, everywhere else; latent attention's rotary
+    slice and its shared key call this as they did."""
     D, rot = x.shape[-1], 2 * cos.shape[-1]
     lanes = ((0, 0), (0, D - rot))
     C = jnp.pad(jnp.tile(cos, 2), lanes, constant_values=1.0)
@@ -541,6 +550,18 @@ def _attend(cfg: LlamaConfig, kind: str, q, k, v, k_shared=None,
 
 
 class LlamaAttention(nn.Module):
+    """Grouped-query attention over ``wq`` / ``wk`` / ``wv`` / ``wo``.  Between
+    the projections and ``_attend`` (scopes ``q_norm`` / ``k_norm``, an
+    RMSNorm over a whole projection, and ``rope``): the rotation of q and k
+    by the kind's table, each head RMS-normed first under ``qk_norm="head"``.
+    Which pass takes it depends on the operands' shapes and the ambient mesh
+    alone (``ops.rope.takes``): where ``H * D`` and ``KV * D`` are whole
+    blocks of 128 lanes a device (a head of 128, two of 64 a block), the
+    rotary width even and inside the head, no ``sp``, and ``tp`` cuts between
+    blocks, one Pallas call a direction reads and writes q and k as their
+    projections wrote them, (B, S, H * D), and the flash kernels read that;
+    everywhere else q and k are turned to (B, H, S, D) and ``apply_rope``'s
+    XLA pass runs, as for every layer before PR 70."""
     config: LlamaConfig
     kind: str = "attention"     # or "full_attention", "sliding_attention"
     n_head: int = 0             # this layer's query heads; 0: config.n_head
@@ -577,13 +598,23 @@ class LlamaAttention(nn.Module):
             q_scale = HeadNormScale(name="q_norm")(D)
             k_scale = HeadNormScale(name="k_norm")(D)
         if table is not None or cfg.qk_norm == "head":
-            q, k = heads(q), heads(k)   # the pass below is a head's
+            # one kernel pass over q and k where they lie, if their shapes
+            # and the mesh allow; else the XLA pass, which is a head's
+            as_written = rope.takes(
+                q.shape, k.shape, D,
+                int(D * table.rotary_fraction) if table else 0)
+            if not as_written:
+                q, k = heads(q), heads(k)
             with jax.named_scope("rope"):
                 # NoPE: tables of no width turn nothing
                 cos, sin = rope_table(D, positions, table) if table \
                     else (jnp.zeros((S, 0), jnp.float32),) * 2
-                q = apply_rope(q, cos, sin, q_scale, cfg.rms_eps)
-                k = apply_rope(k, cos, sin, k_scale, cfg.rms_eps)
+                if as_written:
+                    q, k = rope.rope_qk(q, k, cos, sin, q_scale, k_scale,
+                                        cfg.rms_eps, head_dim=D)
+                else:
+                    q = apply_rope(q, cos, sin, q_scale, cfg.rms_eps)
+                    k = apply_rope(k, cos, sin, k_scale, cfg.rms_eps)
         # (GQA: k and v go on with the KV heads their projections gave them;
         # ``attention`` reads the group from their shapes)
         pooled = None

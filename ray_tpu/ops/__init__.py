@@ -8,7 +8,11 @@ silu (``conv.conv_silu``) and the gated norm a group
 (``gated_norm.gated_rms_norm``), and the manifold-constrained
 hyper-connection around a branch of a block whose residual path is several
 streams (``hyper_connection.hyper_connection``: ``models/llama.py``'s
-``HyperConnection``), each beside its ``jax.numpy`` form, which runs where
+``HyperConnection``), and the rotary embedding with a per-head norm before
+it over a layer's q and k together as their projections wrote them
+(``rope.rope_qk``: ``models/llama.py``'s ``LlamaAttention``, whose
+``apply_rope`` is its ``jax.numpy`` form), each beside its ``jax.numpy``
+form, which runs where
 the op's docstring says the kernels do not apply.  Every op has an
 XLA fallback used automatically off-TPU and for verification.
 """

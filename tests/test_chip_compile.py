@@ -132,6 +132,8 @@ def _build(case: str, compile_: bool) -> dict:
         return _build_selective_scan(case, topo.devices[0])
     if case.startswith("conv_s"):
         return _build_conv(case, topo.devices[0])
+    if case.startswith("rope_b"):
+        return _build_rope(case, topo.devices)
     if case.startswith("norm_s"):
         return _build_gated_norm(case, topo.devices[0])
     if case.startswith("mhc_s"):
@@ -549,6 +551,58 @@ def _build_gated_norm(case: str, device) -> dict:
     try:
         compiled = jax.jit(grads).lower(
             wide, wide, wide, shape(jnp.float32, given["c"])).compile()
+    except Exception as e:  # what Mosaic or the TPU compiler refuses
+        return {"case": case, "refused": str(e)[-1500:]}
+    mem = compiled.memory_analysis()
+    return {"case": case, "tpu_custom_calls": len(re.findall(
+        r'custom_call_target="tpu_custom_call"', compiled.as_text())),
+        "temp_bytes": int(mem.temp_size_in_bytes)}
+
+
+def _build_rope(case: str, devices) -> dict:
+    """In the child: compile ``ops/rope.py``'s pair alone — q and k of a
+    layer through one call, forward and backward — at ``rope_b<rows>_s<
+    positions>_h<query heads>_k<key heads>_d<head width>_r<percent of a head
+    turned>_n<per-head norm>[_f<fsdp>]`` in bf16: for one chip, or with the
+    rows over ``fsdp`` chips of the topology inside the calls' ``shard_map``."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.models.llama import RopeTable, rope_table
+    from ray_tpu.ops import rope
+    from ray_tpu.parallel.mesh import MeshConfig, build_mesh
+
+    given = {part[0]: int(part[1:]) for part in case.split("_")[1:]}
+    width, seq, fsdp = given["d"], given["s"], given.get("f", 1)
+    mesh = build_mesh(MeshConfig(dp=1, fsdp=fsdp), devices=devices[:fsdp])
+
+    def shape(dtype, *dims):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=NamedSharding(
+            mesh, P(("dp", "fsdp"), *[None] * (len(dims) - 1))
+            if len(dims) == 3 else P()))
+
+    def layer(q, k, scale):
+        cos, sin = rope_table(width, jnp.arange(seq), RopeTable(
+            theta=1e6, rotary_fraction=given["r"] / 100))
+        scale = scale if given["n"] else None
+        return rope.rope_qk(q, k, cos, sin, scale, scale, 1e-6,
+                            head_dim=width)
+
+    def grads(dq, dk, q, k, scale):
+        out, vjp = jax.vjp(layer, q, k, scale)
+        return out, vjp((dq, dk))
+
+    q, k = (shape(jnp.bfloat16, given["b"], seq, given[h] * width)
+            for h in "hk")
+    try:
+        with jax.set_mesh(mesh):
+            assert rope.takes(q.shape, k.shape, width,
+                              width * given["r"] // 100)
+            compiled = jax.jit(grads).lower(
+                q, k, q, k, shape(jnp.float32, width)).compile()
     except Exception as e:  # what Mosaic or the TPU compiler refuses
         return {"case": case, "refused": str(e)[-1500:]}
     mem = compiled.memory_analysis()
@@ -1094,7 +1148,11 @@ def test_olmoe_step_lowers_for_one_v5e_chip():
     # logsumexp are kept) and its backward's one kernel, beside the grouped
     # matmul's (megablox names them "kernel")
     assert kernels.pop("kernel") > 0
-    assert kernels == {"flash_fwd": 1, "flash_bwd": 1}, kernels
+    # and the rotation's call over q and k where ``wq`` / ``wk`` wrote them
+    # (``ops/rope.py``, PR 70): forward, again under remat, and — the
+    # rotation alone being its own transpose at the negated sine — backward
+    assert kernels == {"flash_fwd": 1, "flash_bwd": 1,
+                       "rope_fwd": 3}, kernels
     assert row["flash_fwd_calls"] == 1, row
 
 
@@ -1115,8 +1173,9 @@ def test_olmoe_step_compiles_and_says_how_many_rows_fit():
         # flash forward and the backward's one kernel (no recomputation:
         # the block keeps the forward's output and logsumexp); the grouped
         # matmul: gate, up, down forward, recomputed, and two backward calls
-        # each
-        assert row["tpu_custom_calls"] == 2 + 12, row
+        # each; the rotation's call over q and k: forward, again under
+        # remat, backward (PR 70)
+        assert row["tpu_custom_calls"] == 2 + 12 + 3, row
         assert row["argument_bytes"] + row["temp_bytes"] < 14e9, row
 
 
@@ -1174,7 +1233,11 @@ def test_sdar_step_lowers_for_one_v5e_chip():
     # six layers under remat (a kernel called by every layer through one
     # function is printed once per trace: forward, recomputation, backward);
     # ``onto_tokens`` adds a piece's rows into their tokens (PR 36)
-    assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens"}, kernels
+    # and since PR 70 the norm-and-rotate pass of q and k, one call a
+    # direction (``ops/rope.py``)
+    assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens",
+                            "rope_fwd", "rope_bwd"}, kernels
+    assert (kernels["rope_fwd"], kernels["rope_bwd"]) == (12, 6), kernels
     assert row["flash_fwd_calls"] == 6, row
 
 
@@ -1195,8 +1258,10 @@ def test_sdar_step_compiles_and_fits_the_chip():
     # backward's the three recomputed and two transposes each — the
     # checkpoint's recomputation of the forward's loop is dropped, nothing
     # reads it (PR 32); and in either loop the one call that adds the
-    # piece's rows into their tokens (PR 36): 2 + 12 + 2 a layer
-    assert row["tpu_custom_calls"] == 6 * (2 + 12 + 2), row
+    # piece's rows into their tokens (PR 36): 2 + 12 + 2 a layer; and the
+    # norm-and-rotate pass of q and k as ``ops/rope.py``'s kernels, forward,
+    # again under remat and backward (PR 70): 3 more
+    assert row["tpu_custom_calls"] == 6 * (2 + 12 + 2 + 3), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
@@ -1208,7 +1273,11 @@ def test_laguna_step_lowers_for_one_v5e_chip():
     row = _child(["laguna"], compile_=False)["laguna"]
     kernels = row["lowered_kernels"]
     assert kernels.pop("kernel") > 0
-    assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens"}, kernels
+    # and since PR 70 the rotation of q and k (``ops/rope.py``): the forward
+    # kernel three times a layer, its third call the backward
+    assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens",
+                            "rope_fwd"}, kernels
+    assert kernels["rope_fwd"] == 15, kernels
     assert row["flash_fwd_calls"] == 5 and row["flash_bwd_calls"] == 5, row
     # the gate's own ops are a sigmoid of one value a head a token (2 rows x
     # 8192 x 64 heads): the multiply is the kernels', not a pass over
@@ -1229,15 +1298,16 @@ def test_laguna_step_compiles_and_fits_the_chip():
     assert "refused" not in row, row
     # a layer: flash forward and the backward's one kernel; a sparse layer's
     # held experts: twelve grouped-matmul calls and the two that add rows
-    # into tokens, as SDAR's: 5 x 2 + 4 x (12 + 2)
-    assert row["tpu_custom_calls"] == 5 * 2 + 4 * (12 + 2), row
+    # into tokens, as SDAR's: 5 x 2 + 4 x (12 + 2); the rotation of q and k,
+    # forward, again under remat and backward (PR 70): 5 x 3
+    assert row["tpu_custom_calls"] == 5 * (2 + 3) + 4 * (12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
-    # under ``attn`` the flash pair and no call beside it (what the
-    # benchmark's forward selectors take for flash forwards: D12 (0)); and
-    # the gate's four fusions over (B, S, H * D) are gone, not renamed: no
+    # under ``attn`` the flash pair and the rotation's three calls a layer
+    # (the benchmark's forward selectors take ``flash_fwd``'s scope alone
+    # since PR 67); and the gate's four fusions over (B, S, H * D) are gone, not renamed: no
     # instruction named under a ``gate`` scope is wider than a value a head
     # a token
-    assert row["attn_custom_calls"] == 10, row
+    assert row["attn_custom_calls"] == 5 * (2 + 3), row
     assert row["gate_ops_widest"] <= 2 * 8192 * 64, row
 
 
@@ -1283,7 +1353,11 @@ def test_lfm2_step_lowers_for_one_v5e_chip():
     row = _child(["lfm2"], compile_=False)["lfm2"]
     kernels = row["lowered_kernels"]
     assert kernels.pop("kernel") > 0
-    assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens"}, kernels
+    # and since PR 70 the norm-and-rotate pass of q and k (``ops/rope.py``),
+    # two heads of 64 to a block of lanes
+    assert set(kernels) == {"flash_fwd", "flash_bwd", "onto_tokens",
+                            "rope_fwd", "rope_bwd"}, kernels
+    assert (kernels["rope_fwd"], kernels["rope_bwd"]) == (2, 1), kernels
     assert row["flash_fwd_calls"] == 1, row
 
 
@@ -1298,8 +1372,9 @@ def test_lfm2_step_compiles_and_fits_the_chip():
     assert "refused" not in row, row
     # the attention layer: flash forward and the backward's one kernel; a
     # sparse layer's held experts: twelve grouped-matmul calls and the two
-    # that add rows into tokens, as Kimi's: 2 + 4 x (12 + 2)
-    assert row["tpu_custom_calls"] == 2 + 4 * (12 + 2), row
+    # that add rows into tokens, as Kimi's: 2 + 4 x (12 + 2); the
+    # norm-and-rotate pass of q and k, three times (PR 70): 3
+    assert row["tpu_custom_calls"] == 2 + 3 + 4 * (12 + 2), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
@@ -1390,6 +1465,32 @@ def test_gated_norm_kernels_compile_for_one_v5e_chip():
         assert row["temp_bytes"] < 1 << 20, row
 
 
+def test_rope_kernels_compile_for_one_v5e_chip_and_under_fsdp():
+    """Tier-1, fifteen seconds: Mosaic takes ``ops/rope.py``'s pair for q and
+    k of a layer together at the cells' shapes — SDAR's 2 x 8,192 x 32 / 4
+    heads of 128 under the per-head norm, Laguna's 2 x 8,192 x 64 / 8 with
+    half of each head turned, LFM2's 2 x 16,384 x 32 / 8 heads of 64, two to
+    a block, under the norm, Mistral's 1 x 8,192 x 32 / 8, SmallThinker's 1 x
+    16,384 x 28 / 4 (seven blocks of q to one of k a group) — the loads and
+    stores from a dynamic lane, the pieces side by side along a matmul's
+    contraction, the sum down a block's registers —, which the interpreter on
+    the CPU cannot say; and at ``mistral-fsdp4-s4k``'s four rows of 4,096
+    over ``fsdp=4`` of a ``v5e:2x2``, where the calls stand inside a
+    ``shard_map`` that GSPMD leaves whole.  Beside the calls the program holds
+    the tables and the scales' rows, no copy of an operand."""
+    cases = ["rope_b2_s8192_h32_k4_d128_r100_n1",
+             "rope_b2_s8192_h64_k8_d128_r50_n0",
+             "rope_b2_s16384_h32_k8_d64_r100_n1",
+             "rope_b1_s8192_h32_k8_d128_r100_n0",
+             "rope_b1_s16384_h28_k4_d128_r100_n0",
+             "rope_b4_s4096_h32_k8_d128_r100_n0_f4"]
+    for case, row in _child(cases, compile_=True).items():
+        assert "refused" not in row, row
+        # q and k through one call a direction
+        assert row["tpu_custom_calls"] == 2, row
+        assert row["temp_bytes"] < 24 << 20, row
+
+
 def test_hyper_connection_kernels_compile_for_one_v5e_chip():
     """Tier-1, ten seconds: Mosaic takes ``ops/hyper_connection.py``'s three
     kernels at Xing4's shape (1 x 8,192 positions, four streams of 3,584
@@ -1460,8 +1561,10 @@ def test_smallthinker_step_lowers_for_one_v5e_chip():
     row = _child(["smallthinker"], compile_=False)["smallthinker"]
     kernels = row["lowered_kernels"]
     assert kernels.pop("kernel") > 0
-    assert kernels == {"flash_fwd": 4, "flash_bwd": 4,
-                       "onto_tokens": 2}, kernels
+    # and since PR 70 the rotation of q and k in the three window layers
+    # (``ops/rope.py``): forward, again under remat, backward
+    assert kernels == {"flash_fwd": 4, "flash_bwd": 4, "onto_tokens": 2,
+                       "rope_fwd": 9}, kernels
     assert row["flash_fwd_calls"] == 4, row
 
 
@@ -1477,8 +1580,9 @@ def test_smallthinker_step_compiles_and_fits_the_chip():
     assert "refused" not in row, row
     # a layer: flash forward and the backward's one kernel; its held
     # experts: twelve grouped-matmul calls and the two that add rows into
-    # tokens, as SDAR's and Laguna's
-    assert row["tpu_custom_calls"] == 4 * (2 + 12 + 2), row
+    # tokens, as SDAR's and Laguna's; the three window layers' rotation of
+    # q and k, three times (PR 70)
+    assert row["tpu_custom_calls"] == 4 * (2 + 12 + 2) + 3 * 3, row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 
@@ -1619,7 +1723,9 @@ def test_evabyte_step_lowers_for_one_v5e_chip():
     row = _child(["evabyte"], compile_=False)["evabyte"]
     kernels = row["lowered_kernels"]
     assert set(kernels) == {"flash_fwd", "flash_bwd", "pool_fwd",
-                            "pool_bwd"}, kernels
+                            "pool_bwd", "rope_fwd"}, kernels
+    # (since PR 70 the rotation of q and k, ``ops/rope.py``: three a layer)
+    assert kernels["rope_fwd"] == 12, kernels
     assert kernels["flash_fwd"] == kernels["flash_bwd"] == 4, kernels
     assert row["flash_fwd_calls"] == 4, row
 
@@ -1636,8 +1742,8 @@ def test_evabyte_step_compiles_and_fits_the_chip():
     assert "refused" not in row, row
     # a layer: flash forward and the backward's one kernel, no second
     # forward under remat; the pooling forward, again under remat, and its
-    # backward
-    assert row["tpu_custom_calls"] == 4 * (2 + 3), row
+    # backward; the rotation of q and k, three times (PR 70)
+    assert row["tpu_custom_calls"] == 4 * (2 + 3 + 3), row
     assert row["argument_bytes"] + row["temp_bytes"] < 15.5e9, row
 
 

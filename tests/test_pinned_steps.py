@@ -18,7 +18,7 @@ import pytest
 import toys
 
 PINNED = {
-    "toy-evabyte": "2d776bb3a348b1a6d357fbc10d09c972ef6cd73a1afd586ac3a1f9ccc3c2de59",
+    "toy-evabyte": "d18f329ed9775b3f3d4e4e7c933bc7c33972aed0d4cb5882bea59bcbbcadc56e",
     "toy-gpt2": "ba11271144fed4ca835af77562ca30fbb9989cac73bf82a195c1a4e4d5921693",
     "toy-granite": "4055866a1def3c74086a083c0f621efc1c2bee6c785cd361a06910e393a85613",
     "toy-kimi-linear": "5c0d97570c411cf0f592f071ab7dd6ff64b04db88fe014a145253f9c80ce579d",
