@@ -56,6 +56,7 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
+from perfbench.harness import ssd_work
 from perfbench.harness.families import published
 
 
@@ -68,9 +69,8 @@ def _sizes(config: Dict[str, Any]):
 def scan_flops_per_token(config: Dict[str, Any]) -> int:
     """One Mamba layer's recurrence, forward, one token, as the chunked
     algorithm's matmuls (see the module's docstring)."""
-    heads, p, groups, n = _sizes(config)
-    q = config["mamba_chunk_size"]
-    return groups * 2 * q * n + heads * (2 * q * p + 2 * 2 * n * p)
+    return ssd_work.scan_flops_per_token(*_sizes(config),
+                                         config["mamba_chunk_size"])
 
 
 def shape(config: Dict[str, Any], chips: int) -> Dict[str, int]:
@@ -92,7 +92,11 @@ def shape(config: Dict[str, Any], chips: int) -> Dict[str, int]:
     assert attention > 0 and total % attention == 0
     return {"d_model": d, "n_layer": attention, "n_head": h, "n_kv_head": kv,
             "head_dim": hd, "vocab": config["vocab_size"],
-            "layer_mm_params": total // attention}
+            "layer_mm_params": total // attention,
+            # the scan's own sizes, for ``ssd_work.py``
+            "ssd_heads": heads, "ssd_head_dim": p, "ssd_groups": groups,
+            "ssd_state": n, "ssd_chunk": config["mamba_chunk_size"],
+            "ssd_layers": len(kinds) - attention}
 
 
 def model_config(config: Dict[str, Any], chips: int):

@@ -64,6 +64,8 @@ from __future__ import annotations
 import math
 from typing import Any, Dict, Optional, Tuple
 
+from perfbench.harness.flash_work import band_pairs
+
 WRONG = ("no_window", "window_513", "rope_tables_swapped", "no_gate",
          "softmax_scores", "routed_scale_1", "no_shared_expert", "top_7")
 # Of those, what the comparison on the chip cannot see, though the float32
@@ -100,14 +102,6 @@ def held(config: Dict[str, Any]) -> Tuple[int, int]:
     return config["deployment"]["this_chip"] * count, count
 
 
-def band_pairs(seq: int, window: int) -> int:
-    """Live (query, key) pairs of one row under the window: ``sum_i min(i +
-    1, window)``, the first ``window`` queries' triangle and ``window`` keys
-    for each query after them."""
-    w = min(window, seq)
-    return w * (w + 1) // 2 + (seq - w) * w
-
-
 def shape(config: Dict[str, Any], chips: int) -> Dict[str, int]:
     """(The per-layer lists may be longer than the depth that is run: the
     first ``num_hidden_layers`` entries count, here and in ``_forward``.)"""
@@ -136,7 +130,12 @@ def shape(config: Dict[str, Any], chips: int) -> Dict[str, int]:
     return {"d_model": d, "n_layer": full_width // d,
             "n_head": config["num_attention_heads"],
             "n_kv_head": kv, "head_dim": hd, "vocab": config["vocab_size"],
-            "layer_mm_params": total // (full_width // d)}
+            "layer_mm_params": total // (full_width // d),
+            # a sliding layer's band and its own head count, for
+            # ``flash_work.py``
+            "window": config["sliding_window"],
+            "window_n_head": config["num_attention_heads_per_layer"][
+                config["layer_types"].index("sliding_attention")]}
 
 
 def model_config(config: Dict[str, Any], chips: int):

@@ -84,6 +84,7 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional, Tuple
 
+from perfbench.harness import ssd_work
 # (a shift of positions and a wrong model's running sums, as Granite's
 # reference writes them)
 from perfbench.harness.families.granite_hybrid import (_delayed,
@@ -147,12 +148,9 @@ def mamba_sizes(config: Dict[str, Any]):
 
 def scan_flops_per_token(config: Dict[str, Any]) -> int:
     """One Mamba layer's recurrence, forward, one token, as the chunked
-    algorithm's matmuls: ``2 Q N`` a group for ``C B^T`` inside a chunk of
-    ``Q`` positions, ``2 Q P`` a head for the masked product with ``X``,
-    ``2 N P`` a head each for the chunk's state and its read-out."""
-    heads, p, groups, n = mamba_sizes(config)
-    q = config["chunk_size"]
-    return groups * 2 * q * n + heads * (2 * q * p + 2 * 2 * n * p)
+    algorithm's matmuls (``ssd_work.scan_flops_per_token``)."""
+    return ssd_work.scan_flops_per_token(*mamba_sizes(config),
+                                         config["chunk_size"])
 
 
 def layer_params(config: Dict[str, Any]) -> Dict[str, int]:
@@ -212,12 +210,17 @@ def shape(config: Dict[str, Any], chips: int) -> Dict[str, int]:
     d, seq = config["hidden_size"], config["flops_counted_at_seq"]
     forward = sum(forward_flops_per_token(config).values())
     assert forward % 2 == 0
+    heads, p, groups, n = mamba_sizes(config)
     # (the formula's own second term, 6 * 1 * seq * d_model, is taken off)
     return {"d_model": d, "n_layer": 1,
             "n_head": config["num_attention_heads"],
             "n_kv_head": config["num_key_value_heads"],
             "head_dim": config["head_dim"], "vocab": config["vocab_size"],
-            "layer_mm_params": forward // 2 - seq * d}
+            "layer_mm_params": forward // 2 - seq * d,
+            # the scan's own sizes, for ``ssd_work.py``
+            "ssd_heads": heads, "ssd_head_dim": p, "ssd_groups": groups,
+            "ssd_state": n, "ssd_chunk": config["chunk_size"],
+            "ssd_layers": pattern(config).count("M")}
 
 
 def model_config(config: Dict[str, Any], chips: int):

@@ -203,7 +203,12 @@ def shape(config: Dict[str, Any], chips: int) -> Dict[str, int]:
     return {"d_model": s["e"], "n_layer": n_layer, "n_head": s["h"],
             "n_kv_head": s["kv"], "head_dim": s["hd"],
             "vocab": config["vocab_size"],
-            "layer_mm_params": total // n_layer}
+            "layer_mm_params": total // n_layer,
+            # a sliding layer's kernel call, for ``flash_work.py``: one
+            # softmax of the two, half the heads, values two heads wide
+            "window": config["sliding_window"],
+            "window_n_head": s["h"] // 2, "window_n_kv_head": s["kv"] // 2,
+            "v_head_dim": 2 * s["hd"]}
 
 
 def model_config(config: Dict[str, Any], chips: int):

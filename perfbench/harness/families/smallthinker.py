@@ -131,7 +131,9 @@ def shape(config: Dict[str, Any], chips: int) -> Dict[str, int]:
     # (the formula's own second term, 6 * 1 * seq * d_model, is taken off)
     return {"d_model": d, "n_layer": 1, "n_head": h, "n_kv_head": kv,
             "head_dim": hd, "vocab": config["vocab_size"],
-            "layer_mm_params": layers * matmuls + scores - seq * d}
+            "layer_mm_params": layers * matmuls + scores - seq * d,
+            # a window layer's band, for ``flash_work.py``
+            "window": config["sliding_window_size"]}
 
 
 def layer_kinds(config: Dict[str, Any]) -> Tuple[str, ...]:

@@ -25,7 +25,11 @@ def shape(config: Dict[str, Any], chips: int) -> Dict[str, int]:
     """The sizes the counts below need — ``d_model``, ``n_layer`` (as it is
     cut for ``chips``), ``n_head``, ``n_kv_head``, ``head_dim``, ``vocab``,
     ``layer_mm_params`` — from a configuration file's published keys, by its
-    family's module."""
+    family's module.  A family whose kernels the shared work functions count
+    adds their sizes: for ``flash_work.py`` ``window`` (and, where they differ
+    from ``n_head``, ``n_kv_head`` and ``head_dim``, ``window_n_head``,
+    ``window_n_kv_head`` and ``v_head_dim``), for ``ssd_work.py``
+    ``ssd_heads`` ... ``ssd_layers``."""
     return families.of(config).shape(config, chips)
 
 
@@ -38,19 +42,6 @@ def train_flops_per_token(config: Dict[str, Any], chips: int, seq: int) -> int:
     s = shape(config, chips)
     return (6 * matmul_params(config, chips)
             + 6 * s["n_layer"] * seq * s["d_model"])
-
-
-def flash_fwd_call(config: Dict[str, Any], chips: int, rows: int,
-                   seq: int) -> Dict[str, float]:
-    """One call of the causal flash forward on ``rows`` sequences (one
-    device's share): the FLOPs the algorithm needs (QK^T and PV over half the
-    square) and the bytes it must move (Q in and O out at the query heads, K
-    and V in at the key/value heads, bf16).  A program that hands the kernel
-    K and V repeated to the query heads moves more; that is not counted."""
-    s = shape(config, chips)
-    per_head = rows * seq * s["head_dim"]
-    return {"flops": 2.0 * per_head * s["n_head"] * seq,
-            "bytes": 2.0 * per_head * (2 * s["n_head"] + 2 * s["n_kv_head"])}
 
 
 def roofline_seconds(work: Dict[str, float], peak: Dict[str, float]):

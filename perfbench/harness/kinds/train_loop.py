@@ -11,7 +11,10 @@ in set-up and iterated again when it ends), ``report_every`` (steps between
 ``train.report`` calls; the loss is read on the host only there), ``trace``
 (``from_step``, ``steps``: the sub-window a traced run profiles; both
 multiples of ``report_every``, so it opens and closes on an idle device),
-``loss_fall_min`` (see ``verdict``) and ``why``.
+``loss_fall_min`` (see ``verdict``), ``why`` and, where a cell's work follows
+from the ids (held experts), ``window_ids_seed`` with ``window_ids_why``: the
+generator's warm-up and window then take the same ids for every ``--seed``
+(``tokens.py``), and the seed draws the reference check's rows alone.
 
 Inside the worker, in order: state initialised on the device under ``jit`` (by
 the program), agreement with the plain reference, warm-up of the cell's one
@@ -209,7 +212,8 @@ def loop(run: Dict[str, Any]) -> None:
         families.of(config).model_config(config, cell.chips),
         MeshConfig(**traffic["mesh"]))
     marks.append(("trainer_and_state", time.time()))
-    stream = ZipfStream(config["vocab_size"], run["seed"])
+    stream = ZipfStream(config["vocab_size"], run["seed"],
+                        window_ids_seed=traffic.get("window_ids_seed"))
     replicas = trainer.mesh.shape["dp"] * trainer.mesh.shape["fsdp"]
     agreed = agreement.check(trainer, config,
                              stream.rows(replicas, traffic["seq"]))

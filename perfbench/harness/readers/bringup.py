@@ -9,9 +9,10 @@ has no ring, or no record matches.
 
 ``mark``: a record kind or a list of kinds.  ``as_``: ``seconds`` (the
 seconds of the clock the matching records cover: their union, so a jitted
-function traced inside another's trace is not counted twice), ``count`` (how
-many; 0 where the train worker's ring was found and none matches) or ``gap``
-(the timeline's uncovered seconds).  For ``compile`` records ``stage`` keeps
+function traced inside another's trace is not counted twice) or ``count`` (how
+many; 0 where the train worker's ring was found and none matches).  The
+timeline's uncovered seconds are inside reader ``start_account``'s
+``unnamed``.  For ``compile`` records ``stage`` keeps
 those stages and ``less`` takes the seconds those cover away; for
 ``compile.cache`` records ``cache`` keeps ``hit`` or ``miss``.  Records of a worker process count only from the train worker — the
 process whose ring holds ``train_fn_enter`` — and the nodelet's
@@ -63,9 +64,7 @@ def read(ctx, mark=None, as_: str = "seconds", stage=None, less=None,
     found = timeline(session_dir) if session_dir else None
     if not found or not found[0]:
         return None
-    marks, gap = found
-    if as_ == "gap":
-        return gap
+    marks = found[0]
     workers = [m[0] for m in marks if m[1] == ENTERED]
     worker = workers[-1] if workers else None
     kinds, stages, lessened = _names(mark), _names(stage), _names(less)
@@ -91,5 +90,5 @@ def read(ctx, mark=None, as_: str = "seconds", stage=None, less=None,
     if as_ == "count":
         return float(len(kept)) if worker else None
     if as_ != "seconds":
-        raise ValueError(f"as_ must be seconds, count or gap, got {as_!r}")
+        raise ValueError(f"as_ must be seconds or count, got {as_!r}")
     return _covered(kept) - _covered(taken) if kept else None

@@ -5,7 +5,7 @@ import os
 
 import pytest
 
-from perfbench.harness import flops, manifest
+from perfbench.harness import flash_work, flops, manifest
 
 
 def _config(name):
@@ -43,7 +43,7 @@ def test_mistral_by_depth(chips, layers, seq, gflop):
 
 def test_flash_forward_call_and_roofline():
     config = _config("mistral-7b-v0.3")
-    call = flops.flash_fwd_call(config, 1, rows=1, seq=8192)
+    call = flash_work.fwd_call(config, 1, rows=1, seq=8192)
     # QK^T and PV, 2 FLOPs a multiply-add, half the square, 32 heads of 128
     assert call["flops"] == 2 * 2 * 32 * 8192 * 8192 * 128 / 2
     # Q and O at 32 heads, K and V at 8, bf16

@@ -2,9 +2,11 @@
 counts ``kimi-linear-s16k-1chip`` from), ``scan_flops_per_token`` and
 ``kda_work.py`` against sums written out by hand from the published sizes,
 the equations of the two mixers and the cut, a brute-force count of the
-chunked form's matmuls, and the parameter tree's own matmul leaves; the six
-new metrics on a synthetic trace whose name paths are as the chip's trace
-prints them."""
+chunked form's matmuls, and the parameter tree's own matmul leaves; the new
+metrics on a synthetic trace whose name paths are as the chip's trace prints
+them (five of PR 53's six: ``mla_nope_attn_ms_per_step`` was the cell's one
+flash pair by another path, ``flash_fwd_ms_per_step`` +
+``flash_bwd_ms_per_step``, and went at PR 71)."""
 
 import json
 import os
@@ -23,8 +25,7 @@ CONFIG = CELL.config
 PEAK = manifest.peaks()["TPU v5 lite"]
 SEQ = 16384
 NEW = ["kda_scope_share_pct", "kda_scan_ms_per_step", "kda_scan_roofline",
-       "kda_proj_ms_per_step", "kda_conv_gate_ms_per_step",
-       "mla_nope_attn_ms_per_step"]
+       "kda_proj_ms_per_step", "kda_conv_gate_ms_per_step"]
 
 
 def _matmuls_of_a_chunk(c, dk, dv):
@@ -208,7 +209,6 @@ def test_the_new_metrics_on_a_synthetic_trace():
     assert read("kda_scan_ms_per_step") == pytest.approx(81.0)
     assert read("kda_proj_ms_per_step") == pytest.approx(6.0)
     assert read("kda_conv_gate_ms_per_step") == pytest.approx(8.0)
-    assert read("mla_nope_attn_ms_per_step") == pytest.approx(30.0)
     assert read("kda_scope_share_pct") == pytest.approx(100 * 95 / 131)
     least = flops.roofline_seconds(
         kda_work.scan_step(CONFIG, 1, 1, SEQ), PEAK)[0]

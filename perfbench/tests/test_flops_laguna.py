@@ -1,5 +1,5 @@
 """``families/laguna.py::shape`` (what ``flops.train_flops_per_token`` counts
-``laguna-s8k-1chip`` from) and ``window_work.py`` against sums written out by
+``laguna-s8k-1chip`` from) and ``flash_work.py`` at its sizes against sums written out by
 hand from the published sizes and the cut; the five new metrics on a
 synthetic trace whose name paths are as the chip's trace prints them."""
 
@@ -8,7 +8,7 @@ import os
 
 import pytest
 
-from perfbench.harness import flops, manifest, window_work
+from perfbench.harness import flash_work, flops, manifest
 from perfbench.harness.families import laguna
 from perfbench.tests.manifest_lists import on_at_least
 from perfbench.harness.readers import kernel_roofline, trace_ops
@@ -76,13 +76,17 @@ def test_state_is_11_1_gb_of_the_chip():
     assert 16 * total == pytest.approx(11.07e9, rel=1e-3)
 
 
+WINDOW = "jit(pretrain_step)/jvp(LlamaLMModel)/h_1/attn/window/flash_fwd/" \
+    "flash_fwd/pallas_call"
+
+
 def test_window_kernel_work():
-    fwd = window_work.flash_fwd_call(CONFIG, 1, rows=2, seq=8192)
+    fwd = flash_work.fwd_call(CONFIG, 1, rows=2, seq=8192, path=WINDOW)
     assert fwd["flops"] == 2 * 2 * (2 * 64 * PAIRS) * 128
     # Q, O at 64 heads and K, V at 8, bf16
     assert fwd["bytes"] == 2 * 2 * 8192 * 128 * (64 + 64 + 8 + 8)
     assert flops.roofline_seconds(fwd, PEAK)[1] == "compute"
-    bwd = window_work.flash_bwd_call(CONFIG, 1, rows=2, seq=8192)
+    bwd = flash_work.bwd_call(CONFIG, 1, rows=2, seq=8192, path=WINDOW)
     assert bwd["flops"] == 5 * 2 * (2 * 64 * PAIRS) * 128
     assert bwd["bytes"] == 2 * 2 * 8192 * 128 * (3 * 64 + 4 * 8)
     # an eighth of the causal triangle, less the band's own corner
@@ -126,24 +130,29 @@ def _metric(name):
 def test_the_new_metrics_on_a_synthetic_trace():
     ctx = _ctx()
     fwd = _metric("window_attn_fwd_roofline")
-    least = window_work.flash_fwd_call(CONFIG, 1, 2, 8192)["flops"] / 197e12
+    least = flash_work.fwd_call(CONFIG, 1, 2, 8192, WINDOW)["flops"] / 197e12
     # the sliding layer's forward and its recomputation: not its backward,
     # not the full layer's calls
     assert kernel_roofline.read(ctx, **fwd["args"]) == pytest.approx(
         100 * least / 4e-3)
     bwd = _metric("window_attn_bwd_roofline")
-    least = window_work.flash_bwd_call(CONFIG, 1, 2, 8192)["flops"] / 197e12
+    least = flash_work.bwd_call(CONFIG, 1, 2, 8192, WINDOW)["flops"] / 197e12
     assert kernel_roofline.read(ctx, **bwd["args"]) == pytest.approx(
         100 * least / 8e-3)
     ms = {name: trace_ops.read(ctx, **_metric(name)["args"]) for name in (
         "window_attn_ms_per_step", "attn_rope_norm_ms_per_step",
-        "attn_gate_ms_per_step", "moe_shared_ms_per_step",
-        "flash_fwd_ms_per_step", "flash_bwd_ms_per_step")}
+        "moe_shared_ms_per_step", "flash_fwd_ms_per_step",
+        "flash_bwd_ms_per_step", "attn_outside_kernels_ms_per_step")}
     # the three kernel calls, not the XLA work around the backward kernel
     assert ms["window_attn_ms_per_step"] == pytest.approx(16.0)
-    # attn_gate_rope_ms_per_step's two halves (that entry went with PR 67)
+    # attn_gate_rope_ms_per_step's rotation half (that entry went with PR
+    # 67; the gate's half, attn_gate_ms_per_step, with PR 71: since PR 62 the
+    # multiply is inside the flash kernels and the scope held 0.013 ms a
+    # step); what still runs under gate is in the XLA work under attn
     assert ms["attn_rope_norm_ms_per_step"] == pytest.approx(2.0)
-    assert ms["attn_gate_ms_per_step"] == pytest.approx(1.0)
+    # (the backward's reduce_sum, the rotation, the gate, wg's matmul)
+    assert ms["attn_outside_kernels_ms_per_step"] == pytest.approx(
+        1.0 + 2.0 + 1.0 + 0.5)
     assert ms["moe_shared_ms_per_step"] == pytest.approx(1.0)
     # the list-less metrics read both kinds of layer as they stand
     assert ms["flash_fwd_ms_per_step"] == pytest.approx(4 + 4 + 14)
@@ -160,8 +169,7 @@ def test_a_program_without_the_scopes_reports_nothing():
     ctx = Context(CELL, PEAK, {}, trace, traced_steps=1)
     for name in ("window_attn_fwd_roofline", "window_attn_bwd_roofline"):
         assert kernel_roofline.read(ctx, **_metric(name)["args"]) is None
-    for name in ("window_attn_ms_per_step", "attn_gate_ms_per_step",
-                 "moe_shared_ms_per_step"):
+    for name in ("window_attn_ms_per_step", "moe_shared_ms_per_step"):
         assert trace_ops.read(ctx, **_metric(name)["args"]) is None
 
 
@@ -170,12 +178,13 @@ def test_the_cell_as_the_manifest_has_it():
     entry = next(c for c in bench["configs"] if c["name"] == "laguna-xs.2")
     assert entry["reduced"] == CONFIG["reduced"]
     assert CELL.chips == 1 and CELL.traffic["kind"] == "train_loop"
-    # PR 35's five by name (attn_gate_rope_ms_per_step is since PR 67 its two
-    # halves); later PRs list the cell under more
+    # PR 35's five by name (attn_gate_rope_ms_per_step is since PR 67 its
+    # rotation's half; the gate's went with PR 71); later PRs list the cell
+    # under more
     on_at_least(bench, "laguna-s8k-1chip", [
         "window_attn_fwd_roofline", "window_attn_bwd_roofline",
         "window_attn_ms_per_step", "attn_rope_norm_ms_per_step",
-        "attn_gate_ms_per_step", "moe_shared_ms_per_step"])
+        "moe_shared_ms_per_step"])
     # every catalog number stands in the file; the six cut keys beside their
     # published counts
     assert (CONFIG["hidden_size"], CONFIG["head_dim"],

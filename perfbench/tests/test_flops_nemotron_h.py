@@ -1,8 +1,8 @@
 """``families/nemotron_h.py::shape`` (what ``flops.train_flops_per_token``
 counts ``nemotron3-nano-s16k-1chip`` from) against a hand count and the
-parameter tree's leaves, ``nemotron_h_work.py`` against sums written out by
-hand, and the ten new metrics on a synthetic trace whose name paths are as the
-chip's trace prints them."""
+parameter tree's leaves, ``flash_work.py`` and ``ssd_work.py`` at its sizes
+against sums written out by hand, and the cell's metrics on a synthetic trace
+whose name paths are as the chip's trace prints them."""
 
 import json
 import os
@@ -10,7 +10,7 @@ import os
 import numpy as np
 import pytest
 
-from perfbench.harness import flops, manifest, nemotron_h_work
+from perfbench.harness import flash_work, flops, manifest, ssd_work
 from perfbench.harness.families import nemotron_h
 from perfbench.tests.manifest_lists import on_at_least
 from perfbench.harness.readers import kernel_roofline, scope_roofline, trace_ops
@@ -24,15 +24,19 @@ PEAK = manifest.peaks()["TPU v5 lite"]
 SEQ = 16384
 # PR 63's ten; since PR 67 the five that were copies of an older entry's
 # selection are that entry (mamba8g_* -> mamba_*, ssd8g_scan_ms_per_step ->
-# ssd_scan_ms_per_step, relu2_* -> moe_*), which lists this cell too
+# ssd_scan_ms_per_step, relu2_* -> moe_*), which lists this cell too; since
+# PR 71 its three rooflines are the entries of one work function a kernel
+# family (ssd8g_scan_roofline -> ssd_scan_roofline, gqa16_attn_*_roofline ->
+# flash_*_roofline) and gqa16_attn_ms_per_step, the cell's one flash pair by
+# another path, is gone
 NEW = ["mamba_scope_share_pct", "ssd_scan_ms_per_step",
-       "ssd8g_scan_roofline", "mamba_proj_ms_per_step",
+       "ssd_scan_roofline", "mamba_proj_ms_per_step",
        "grouped_gated_norm_ms_per_step", "moe_experts_ms_per_step",
-       "moe_shared_ms_per_step", "gqa16_attn_ms_per_step",
-       "gqa16_attn_fwd_roofline", "gqa16_attn_bwd_roofline"]
+       "moe_shared_ms_per_step", "flash_fwd_roofline", "flash_bwd_roofline"]
 READERS = {"trace_ops": trace_ops, "kernel_roofline": kernel_roofline,
            "scope_roofline": scope_roofline}
-TRIANGLE = SEQ * (SEQ + 1) // 2
+TRIANGLE = SEQ * (SEQ + 1) // 2    # what mfu_pct charges this family: shape()
+HALF_SQUARE = SEQ * SEQ // 2     # what flash_work.py charges a whole-row call
 D = 2688
 
 
@@ -102,15 +106,15 @@ def test_shape_counts_the_parameter_trees_leaves():
 
 
 def test_kernel_work():
-    fwd = nemotron_h_work.attn_fwd_call(CONFIG, 1, rows=1, seq=SEQ)
-    assert fwd["flops"] == 2 * 2 * 32 * TRIANGLE * 128
+    fwd = flash_work.fwd_call(CONFIG, 1, rows=1, seq=SEQ)
+    assert fwd["flops"] == 2 * 2 * 32 * HALF_SQUARE * 128
     # Q, O at 32 heads and K, V at 2, bf16
     assert fwd["bytes"] == 2 * SEQ * 128 * (32 + 32 + 2 + 2)
     assert flops.roofline_seconds(fwd, PEAK)[1] == "compute"
-    bwd = nemotron_h_work.attn_bwd_call(CONFIG, 1, rows=1, seq=SEQ)
-    assert bwd["flops"] == 5 * 2 * 32 * TRIANGLE * 128
+    bwd = flash_work.bwd_call(CONFIG, 1, rows=1, seq=SEQ)
+    assert bwd["flops"] == 5 * 2 * 32 * HALF_SQUARE * 128
     assert bwd["bytes"] == 2 * SEQ * 128 * (3 * 32 + 4 * 2)
-    scan = nemotron_h_work.scan_step(CONFIG, 1, rows=1, seq=SEQ)
+    scan = ssd_work.scan_step(CONFIG, 1, rows=1, seq=SEQ)
     assert scan["flops"] == 3 * 4 * SEQ * 3_407_872
     # X, y 4096 wide, B, C 1024 wide, dt 64, bf16; a float32 state of 64 x 64
     # x 128 a chunk of 128, written and read
@@ -167,21 +171,20 @@ def test_the_new_metrics_on_a_synthetic_trace():
     assert got["mamba_scope_share_pct"] == pytest.approx(
         100 * 2 * 44e-3 / busy)
     assert got["ssd_scan_ms_per_step"] == pytest.approx(30.0)
-    scan = nemotron_h_work.scan_step(CONFIG, 1, 1, SEQ)
-    assert got["ssd8g_scan_roofline"] == pytest.approx(
+    scan = ssd_work.scan_step(CONFIG, 1, 1, SEQ)
+    assert got["ssd_scan_roofline"] == pytest.approx(
         100 * (scan["bytes"] / PEAK["hbm_bytes_per_s"]) / 30e-3)
     assert got["mamba_proj_ms_per_step"] == pytest.approx(12.0)
     assert got["grouped_gated_norm_ms_per_step"] == pytest.approx(1.0)
     assert got["moe_experts_ms_per_step"] == pytest.approx(4.0)
     assert got["moe_shared_ms_per_step"] == pytest.approx(15.0)
-    # the two kernel calls, not the sum of dK beside the backward kernel
-    assert got["gqa16_attn_ms_per_step"] == pytest.approx(110.0)
-    least = {fn: getattr(nemotron_h_work, fn)(CONFIG, 1, 1, SEQ)["flops"]
-             / 197e12 for fn in ("attn_fwd_call", "attn_bwd_call")}
-    assert got["gqa16_attn_fwd_roofline"] == pytest.approx(
-        100 * least["attn_fwd_call"] / 30e-3)
-    assert got["gqa16_attn_bwd_roofline"] == pytest.approx(
-        100 * least["attn_bwd_call"] / 80e-3)
+    # the kernel call, not the sum of dK beside the backward kernel
+    least = {fn: getattr(flash_work, fn)(CONFIG, 1, 1, SEQ)["flops"]
+             / 197e12 for fn in ("fwd_call", "bwd_call")}
+    assert got["flash_fwd_roofline"] == pytest.approx(
+        100 * least["fwd_call"] / 30e-3)
+    assert got["flash_bwd_roofline"] == pytest.approx(
+        100 * least["bwd_call"] / 80e-3)
     # no roofline over 100% at these times, which are about the chip's
     assert all(0 < v <= 100 for k, v in got.items() if k.endswith("roofline"))
 

@@ -1,8 +1,8 @@
 """``families/smallthinker.py::shape`` (what ``flops.train_flops_per_token``
 counts ``smallthinker-s16k-1chip`` from) against the parameter tree's matmul
-leaves and a brute-force count of live pairs, ``smallthinker_work.py`` against
-sums written out by hand, and the seven new metrics on a synthetic trace whose
-name paths are as the chip's trace prints them."""
+leaves and a brute-force count of live pairs, ``flash_work.py`` at its sizes
+against sums written out by hand, and the cell's metrics on a synthetic trace
+whose name paths are as the chip's trace prints them."""
 
 import json
 import os
@@ -10,7 +10,7 @@ import os
 import numpy as np
 import pytest
 
-from perfbench.harness import flops, manifest, smallthinker_work
+from perfbench.harness import flash_work, flops, manifest
 from perfbench.harness.families import smallthinker
 from perfbench.tests.manifest_lists import on_at_least
 from perfbench.harness.readers import kernel_roofline, trace_ops
@@ -25,15 +25,22 @@ SEQ = 16384
 # PR 59's seven; since PR 67 the three that were copies of an older entry's
 # selection are that entry (band4k_attn_ms_per_step -> window_attn_ms_per_step,
 # pre_router_ms_per_step -> moe_router_ms_per_step, reglu_experts_ms_per_step
-# -> moe_experts_ms_per_step), which lists this cell too
-NEW = ["window_attn_ms_per_step", "band4k_attn_fwd_roofline",
-       "band4k_attn_bwd_roofline", "gqa7_full_attn_fwd_roofline",
-       "gqa7_full_attn_bwd_roofline", "moe_router_ms_per_step",
+# -> moe_experts_ms_per_step), which lists this cell too; since PR 71 its four
+# rooflines are the entries of one work function for every configuration
+# (band4k_attn_*_roofline -> window_attn_*_roofline, gqa7_full_attn_*_roofline
+# -> flash_*_roofline)
+NEW = ["window_attn_ms_per_step", "window_attn_fwd_roofline",
+       "window_attn_bwd_roofline", "flash_fwd_roofline",
+       "flash_bwd_roofline", "moe_router_ms_per_step",
        "moe_experts_ms_per_step"]
+STACK = "jit(pretrain_step)/jvp(LlamaLMModel)/"
+WHOLE = STACK + "h_0/attn/flash_fwd/flash_fwd/pallas_call"
+WINDOW = STACK + "h_1/attn/window/flash_fwd/flash_fwd/pallas_call"
 # a row's live pairs under the window: the first 4,096 queries see 1 .. 4,096
 # keys, the other 12,288 see 4,096
 BAND = 4096 * 4097 // 2 + (SEQ - 4096) * 4096
-TRIANGLE = SEQ * (SEQ + 1) // 2
+TRIANGLE = SEQ * (SEQ + 1) // 2    # what mfu_pct charges this family: shape()
+HALF_SQUARE = SEQ * SEQ // 2     # what flash_work.py charges a whole-row call
 
 
 def test_smallthinker_is_2_12_gflop_a_token_at_the_cut():
@@ -107,19 +114,19 @@ def test_shape_counts_the_parameter_trees_matmul_leaves():
 
 
 def test_kernel_work():
-    fwd = smallthinker_work.window_fwd_call(CONFIG, 1, rows=1, seq=SEQ)
+    fwd = flash_work.fwd_call(CONFIG, 1, rows=1, seq=SEQ, path=WINDOW)
     assert fwd["flops"] == 2 * 2 * 28 * BAND * 128
     # Q, O at 28 heads and K, V at 4, bf16
     assert fwd["bytes"] == 2 * SEQ * 128 * (28 + 28 + 4 + 4)
     assert flops.roofline_seconds(fwd, PEAK)[1] == "compute"
-    bwd = smallthinker_work.window_bwd_call(CONFIG, 1, rows=1, seq=SEQ)
+    bwd = flash_work.bwd_call(CONFIG, 1, rows=1, seq=SEQ, path=WINDOW)
     assert bwd["flops"] == 5 * 2 * 28 * BAND * 128
     assert bwd["bytes"] == 2 * SEQ * 128 * (3 * 28 + 4 * 4)
-    full = smallthinker_work.full_fwd_call(CONFIG, 1, rows=1, seq=SEQ)
-    assert full["flops"] == 2 * 2 * 28 * TRIANGLE * 128
+    full = flash_work.fwd_call(CONFIG, 1, rows=1, seq=SEQ, path=WHOLE)
+    assert full["flops"] == 2 * 2 * 28 * HALF_SQUARE * 128
     assert full["bytes"] == fwd["bytes"]
-    assert smallthinker_work.full_bwd_call(CONFIG, 1, 1, SEQ)["flops"] \
-        == 5 * 2 * 28 * TRIANGLE * 128
+    assert flash_work.bwd_call(CONFIG, 1, 1, SEQ, path=WHOLE)["flops"] \
+        == 5 * 2 * 28 * HALF_SQUARE * 128
     # the band is 7/16 of the triangle at 16,384 under 4,096
     assert fwd["flops"] / full["flops"] == pytest.approx(7 / 16, rel=1e-3)
 
@@ -128,7 +135,7 @@ def _ctx():
     """Two steps on one device: a whole-row and a window layer, each a
     forward call and a backward call, and the routed layer's scopes."""
     ops, t = [], 0.0
-    stack = "jit(pretrain_step)/jvp(LlamaLMModel)/"
+    stack = STACK
     back = "jit(pretrain_step)/transpose(jvp(LlamaLMModel))/" \
         "jvp(LlamaLMModel)/checkpoint/"
     call, fusion = "custom-call:tpu_custom_call", "fusion"
@@ -161,18 +168,19 @@ def test_the_new_metrics_on_a_synthetic_trace():
     got = {name: (kernel_roofline if name.endswith("roofline")
                   else trace_ops).read(ctx, **_metric(name)["args"])
            for name in NEW}
-    least = {fn: getattr(smallthinker_work, fn)(CONFIG, 1, 1, SEQ)["flops"]
-             / 197e12 for fn in ("window_fwd_call", "window_bwd_call",
-                                 "full_fwd_call", "full_bwd_call")}
-    # each kind's calls alone: the window's not the whole-row layer's
-    assert got["band4k_attn_fwd_roofline"] == pytest.approx(
-        100 * least["window_fwd_call"] / 10e-3)
-    assert got["band4k_attn_bwd_roofline"] == pytest.approx(
-        100 * least["window_bwd_call"] / 25e-3)
-    assert got["gqa7_full_attn_fwd_roofline"] == pytest.approx(
-        100 * least["full_fwd_call"] / 20e-3)
-    assert got["gqa7_full_attn_bwd_roofline"] == pytest.approx(
-        100 * least["full_bwd_call"] / 50e-3)
+    least = {(fn, path): getattr(flash_work, fn)(
+        CONFIG, 1, 1, SEQ, path=path)["flops"] / 197e12
+        for fn in ("fwd_call", "bwd_call") for path in (WHOLE, WINDOW)}
+    # each kind's calls alone, each against its own least time: the window's
+    # not the whole-row layer's
+    assert got["window_attn_fwd_roofline"] == pytest.approx(
+        100 * least["fwd_call", WINDOW] / 10e-3)
+    assert got["window_attn_bwd_roofline"] == pytest.approx(
+        100 * least["bwd_call", WINDOW] / 25e-3)
+    assert got["flash_fwd_roofline"] == pytest.approx(
+        100 * least["fwd_call", WHOLE] / 20e-3)
+    assert got["flash_bwd_roofline"] == pytest.approx(
+        100 * least["bwd_call", WHOLE] / 50e-3)
     # the two kernel calls, not the XLA work around the backward kernel
     assert got["window_attn_ms_per_step"] == pytest.approx(35.0)
     assert got["moe_router_ms_per_step"] == pytest.approx(1.0)

@@ -159,7 +159,7 @@ XING4_LISTS = [
     "attn_rope_norm_ms_per_step", "moe_scope_share_pct",
     "moe_router_ms_per_step", "moe_dispatch_ms_per_step",
     "moe_experts_ms_per_step", "moe_shared_ms_per_step",
-    "moe_onto_tokens_ms_per_step", "moe_onto_tokens_calls_per_step",
+    "moe_onto_tokens_calls_per_step",
     "moe_rows_held_per_step", "dense_mlp_ms_per_step", "norm_ms_per_step"]
 
 

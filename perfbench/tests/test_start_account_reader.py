@@ -125,9 +125,11 @@ def test_the_manifest_has_the_five():
     entries = {m["name"]: m for m in bench["per_layer"]
                if m["name"] in EXPECTED}
     assert set(entries) == set(EXPECTED)
-    assert [m["name"] for m in bench["per_layer"][-5:]] == [
-        "time_to_first_report_s", "start_unnamed_s", "tpu_client_off_cpu_s",
-        "setup_program_load_s", "trainer_build_s"]      # appended, in order
+    # by name and in their order among themselves, wherever a later PR's
+    # entries stand (PR 70 appended one behind them, PR 71 retired others)
+    assert [m["name"] for m in bench["per_layer"] if m["name"] in EXPECTED] \
+        == ["time_to_first_report_s", "start_unnamed_s",
+            "tpu_client_off_cpu_s", "setup_program_load_s", "trainer_build_s"]
     for m in entries.values():
         assert (m["moves"], m["better"], m["unit"]) == ("setup_s", "lower",
                                                         "s")
@@ -142,10 +144,11 @@ def test_the_manifest_has_the_five():
         "time_to_first_report_s", "start_unnamed_s", "tpu_client_off_cpu_s"}
 
 
-def test_the_manifest_at_127():
-    """The contract's cap is 128; no two entries are one selection."""
+def test_the_manifest_under_the_cap():
+    """The contract's cap is 128 (127 entries at PR 68); no two entries are
+    one selection."""
     per_layer = manifest.benchmark()["per_layer"]
-    assert len(per_layer) == 127
+    assert len(per_layer) <= 128
     by_selection = collections.defaultdict(list)
     for m in per_layer:
         f = _metric(m["name"])

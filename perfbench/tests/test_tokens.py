@@ -13,6 +13,7 @@ from perfbench.harness import manifest
 from perfbench.harness.tokens import ZipfStream
 
 SEEDS = (5, 2 ** 31 + 9, 2167100011)
+KINDS_THAT_READ_THE_KEY = ("bd_train_loop", "train_loop")
 
 
 def _steps(stream, rows=4, seq=32, n=6):
@@ -106,8 +107,20 @@ def test_the_cells_that_give_the_key():
         with open(path) as f:
             traffic = json.load(f)
         if "window_ids_seed" in traffic:
-            assert traffic["kind"] == "bd_train_loop", path
+            assert traffic["kind"] in KINDS_THAT_READ_THE_KEY, path
             assert isinstance(traffic["window_ids_seed"], int)
             assert traffic["window_ids_why"]
             with_key.add(os.path.basename(path))
-    assert with_key == {"bd-s4k-b2-gen.json"}
+    assert with_key == {"bd-s4k-b2-gen.json", "s16k-b1-gen.json"}
+
+
+@pytest.mark.parametrize("kind", KINDS_THAT_READ_THE_KEY)
+def test_the_kind_hands_the_key_to_its_stream(kind):
+    """PR 71, after the check's refusal over ``smallthinker-s16k-1chip``:
+    ``train_loop`` reads the key as ``bd_train_loop`` has since PR 67."""
+    import importlib
+    import inspect
+
+    loop = importlib.import_module(f"perfbench.harness.kinds.{kind}").loop
+    assert 'window_ids_seed=traffic.get("window_ids_seed")' \
+        in inspect.getsource(loop)
