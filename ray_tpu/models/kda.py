@@ -24,6 +24,10 @@ three convolutions, silu and the two unit norms), ``gate`` (softplus and
 benchmark's per-layer metrics read.  Under ``tp`` everything between the
 projections is a head's own: the projections, the convolutions' kernels,
 ``A_log`` and ``dt_bias`` are cut by head (``parallel/sharding.py``).
+``HeadNorm`` is also differential attention's ``sub_norm``
+(``models/llama.py``) and the Gated DeltaNet mixer's ``o_norm``
+(``models/gdn.py``); ``_unit`` is ``ops/conv.py``'s ``jax.numpy`` unit norm,
+for either mixer.
 """
 
 from __future__ import annotations

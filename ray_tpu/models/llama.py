@@ -132,6 +132,18 @@ head shared), which the model returns beside its logits where it is asked
 (``predict_ahead``) and ``models/pretrain.py`` weighs into the objective
 (``MTP_WEIGHT``) (Xing4.0-29B-A4B is the stack with these, a leading dense
 layer and sigmoid-routed experts beside a shared one).
+A layer of ``layer_types`` may be ``"gdn"`` (``gdn``: ``models/gdn.py``'s
+Gated DeltaNet mixer over ``ops/gdn.py``'s chunked scan, a gated delta rule
+with ONE decay a value head and ``kda_n_heads`` value heads over
+``gdn_key_heads`` key heads); ``attn_gate="channel"`` makes ``wq`` twice as
+wide — a head's query and, beside it, a gate logit for every channel of the
+head's output, whose sigmoid multiplies the kernels' result before ``wo``
+(scope ``gate``), the layer's calls under the scope ``gated``; under
+``norm_unit_offset`` the per-head norm's scale is ``1 + g`` too; and
+``shared_expert_gate`` puts the shared expert under one sigmoid gate a token
+(``models/moe.py``) (Qwen3-Next-80B-A3B is the stack with these: three ``gdn``
+layers to one gated attention layer of heads 256 wide, a quarter of each
+turned, softmax-routed experts beside the gated shared one).
 Every such field at its default leaves the program the dense Llama it was.
 ``remat`` recomputes each block from its input in the backward; what
 ``remat_policy="full"`` keeps beside that input is each attention layer's
@@ -161,6 +173,7 @@ import numpy as np
 
 from ray_tpu.models.gpt2 import (mask_vocab_padding, padded_vocab,
                                  remat_block)
+from ray_tpu.models.gdn import GDNMixer
 from ray_tpu.models.kda import HeadNorm, KDAMixer
 from ray_tpu.models.mamba import (GatedMemoryUnit, Mamba1Mixer, Mamba2Mixer,
                                   SplitDense, _conv_init, gated_short_conv)
@@ -174,7 +187,9 @@ from ray_tpu.parallel.sharding import constrain_residual
 
 @dataclass(frozen=True)
 class RopeTable:
-    """One rotary table: which part of a head turns, and how fast.  The
+    """One rotary table: which part of a head turns, and how fast.  (A head
+    wider than a lane tile of 128 takes ``apply_rope``'s XLA pass:
+    ``ops.rope.takes`` refuses it.)  The
     first ``rotary_fraction`` of each head's ``head_dim`` is rotated
     (rotate-half inside that part), the rest passed through.  ``factor`` > 1:
     YaRN (Peng et al. 2023) over the rotated part's frequencies — those that
@@ -227,7 +242,8 @@ class LlamaConfig:
     router_aux_weight: float = 0.01  # x load-balancing loss, in the objective
     router_z_weight: float = 1e-3    # x router z-loss
     # each layer's token mixer, "attention" (or a kind of it), "mamba"
-    # (models/mamba.py), "conv" (ShortConvMixer) or "kda" (models/kda.py),
+    # (models/mamba.py), "conv" (ShortConvMixer), "kda" (models/kda.py) or
+    # "gdn" (models/gdn.py),
     # one entry a layer; empty: attention in every layer.  "mamba1"
     # (Mamba1Mixer) reads mamba_d_state, mamba_d_conv and, as its scan's block
     # of positions, mamba_chunk; "gmu" and "cross_attention" read a producer;
@@ -263,7 +279,10 @@ class LlamaConfig:
     # of that kind, None for a kind that turns nothing; a kind that is not
     # named rotates the whole head by rope_theta
     rope_tables: Tuple[Tuple[str, Optional[RopeTable]], ...] = ()
-    attn_gate: bool = False          # a sigmoid gate a head on the attention's output
+    # a sigmoid gate on the attention's output: True, a head's, its logit from
+    # a projection of its own (wg); "channel", a channel's, the logits the
+    # second half of each head's columns of a wq twice as wide
+    attn_gate: Any = False
     # each layer's feed-forward, "dense", "sparse" (the routed experts) or
     # "none" (the layer is its mixer alone, behind attn_norm), one entry a
     # layer; empty: by moe_every
@@ -271,6 +290,7 @@ class LlamaConfig:
     router_scoring: str = "softmax"  # or "sigmoid": the experts' scores
     routed_scale: float = 1.0        # x the chosen experts' weights
     d_shared_expert: int = 0         # a SwiGLU every token passes, beside the routed
+    shared_expert_gate: bool = False  # x one sigmoid gate a token (moe/shared/gate)
     # latent attention (``LatentAttention``) in every attention layer: the
     # width of the latent keys and values are made from; 0: none
     kv_lora_rank: int = 0
@@ -295,11 +315,14 @@ class LlamaConfig:
     n_pred_heads: int = 1
     # "kda" layers of layer_types (models/kda.py: Kimi Delta Attention):
     # heads of kda_head_dim for keys and values alike, the width of the three
-    # causal convolutions and the chunk of the scan (ops/kda.py)
+    # causal convolutions and the chunk of the scan (ops/kda.py).  "gdn"
+    # layers (models/gdn.py: Gated DeltaNet) read the same four — kda_n_heads
+    # their VALUE heads — and gdn_key_heads, the key heads those read
     kda_n_heads: int = 0
     kda_head_dim: int = 0
     kda_d_conv: int = 4
     kda_chunk: int = 64
+    gdn_key_heads: int = 0
     norm: str = "rms"                # or "layer": LayerNorm, a scale and a bias
     attn_bias: bool = False          # biases on the attention's projections
     # differential attention in every attention layer (``DifferentialAttention``)
@@ -520,10 +543,15 @@ def _pool_init(key, shape, dtype=jnp.float32):
 class HeadNormScale(nn.Module):
     """The scale of a per-head RMSNorm that ``apply_rope`` applies, under the
     path and with the shape, dtype and start ``nn.RMSNorm`` gives its own
-    (``<name>/scale``, ones): checkpoints load as before."""
+    (``<name>/scale``, ones): checkpoints load as before.  ``unit_offset``:
+    ``1 + scale``, ``scale`` from zeros, as ``UnitOffsetRMSNorm``'s."""
+    unit_offset: bool = False
 
     @nn.compact
     def __call__(self, width: int):
+        if self.unit_offset:
+            return 1.0 + self.param("scale", nn.initializers.zeros, (width,),
+                                    jnp.float32)
         return self.param("scale", nn.initializers.ones, (width,), jnp.float32)
 
 
@@ -577,7 +605,19 @@ class LlamaAttention(nn.Module):
             self.kind, RopeTable(theta=cfg.rope_theta) if cfg.rope else None)
         D = cfg.head_dim or E // H
         assert H % KV == 0, "n_head must be a multiple of n_kv_head"
-        q = nn.Dense(H * D, use_bias=False, dtype=cfg.dtype, name="wq")(x)
+        if cfg.attn_gate not in (False, True, "channel"):
+            raise ValueError(f"unknown attn_gate {cfg.attn_gate!r} (expected "
+                             "False, True or 'channel')")
+        by_channel = None
+        if cfg.attn_gate == "channel":
+            # a head's columns of wq: its query, then its gate's logits
+            q, by_channel = (
+                t.reshape(B, S, H * D) for t in jnp.split(nn.Dense(
+                    2 * H * D, use_bias=False, dtype=cfg.dtype, name="wq")(
+                        x).reshape(B, S, H, 2 * D), 2, axis=-1))
+        else:
+            q = nn.Dense(H * D, use_bias=False, dtype=cfg.dtype,
+                         name="wq")(x)
         k = nn.Dense(KV * D, use_bias=False, dtype=cfg.dtype, name="wk")(x)
         v = nn.Dense(KV * D, use_bias=False, dtype=cfg.dtype, name="wv")(x)
         if cfg.qk_norm not in (False, True, "head"):
@@ -595,8 +635,8 @@ class LlamaAttention(nn.Module):
             q, k = norm("q_norm", q), norm("k_norm", k)
         elif cfg.qk_norm == "head":
             # applied to each head in the rotation's own pass, below
-            q_scale = HeadNormScale(name="q_norm")(D)
-            k_scale = HeadNormScale(name="k_norm")(D)
+            q_scale = HeadNormScale(cfg.norm_unit_offset, name="q_norm")(D)
+            k_scale = HeadNormScale(cfg.norm_unit_offset, name="k_norm")(D)
         if table is not None or cfg.qk_norm == "head":
             # one kernel pass over q and k where they lie, if their shapes
             # and the mesh allow; else the XLA pass, which is a head's
@@ -639,11 +679,20 @@ class LlamaAttention(nn.Module):
         # input: the sigmoid and the multiply are ``attention``'s, inside the
         # kernels' own passes
         gate = nn.Dense(H, use_bias=False, dtype=cfg.dtype, name="wg")(x) \
-            if cfg.attn_gate else None
+            if cfg.attn_gate is True else None
         # what came straight from its projection goes to the kernels as it
         # lies, (B, S, H * D), and so does the result to ``wo``
-        out = _attend(cfg, self.kind, q, k, v, head_dim=D, pooled=pooled,
-                      gate=gate)
+        if by_channel is None:
+            out = _attend(cfg, self.kind, q, k, v, head_dim=D, pooled=pooled,
+                          gate=gate)
+        else:
+            # the scope tells these calls from another kind's in a trace
+            with jax.named_scope("gated"):
+                out = _attend(cfg, self.kind, q, k, v, head_dim=D,
+                              pooled=pooled)
+            with jax.named_scope("gate"):
+                out = out * jax.nn.sigmoid(
+                    by_channel.astype(jnp.float32)).astype(cfg.dtype)
         return nn.Dense(E, use_bias=False, dtype=cfg.dtype, name="wo")(out)
 
 
@@ -939,7 +988,7 @@ class LlamaBlock(nn.Module):
     config: LlamaConfig
     # this layer's feed-forward: "dense", "sparse" (routed experts) or "none"
     mlp: str = "dense"
-    # this layer's token mixer: "mamba", "conv", "kda", "mamba1", "gmu",
+    # this layer's token mixer: "mamba", "conv", "kda", "gdn", "mamba1", "gmu",
     # "cross_attention", attention of a kind, or "none"
     mixer: str = "attention"
     n_head: int = 0         # this layer's query heads; 0: config.n_head
@@ -993,6 +1042,8 @@ class LlamaBlock(nn.Module):
             x = add(x, ShortConvMixer(cfg, name="conv")(y))
         elif self.mixer == "kda":
             x = add(x, KDAMixer(cfg, name="kda")(y))
+        elif self.mixer == "gdn":
+            x = add(x, GDNMixer(cfg, name="gdn")(y))
         elif self.mixer == "mamba1":
             out, handed = Mamba1Mixer(cfg, name="mamba1")(y)
             x = add(x, out)
@@ -1011,7 +1062,8 @@ class LlamaBlock(nn.Module):
         else:
             raise ValueError(f"unknown layer type {self.mixer!r} (expected "
                              "'none', 'mamba1', 'gmu', 'cross_attention' "
-                             "(under diff_attn), 'kda', 'mamba', 'conv' or "
+                             "(under diff_attn), 'kda', 'gdn', 'mamba', "
+                             "'conv' or "
                              f"one of {ATTENTION_KINDS})")
         if self.mlp == "none":      # the layer is its mixer alone
             return (x, handed) if self.hands_on else x
@@ -1025,6 +1077,7 @@ class LlamaBlock(nn.Module):
                 experts_held=cfg.experts_held, scoring=cfg.router_scoring,
                 routed_scale=cfg.routed_scale,
                 d_shared=cfg.d_shared_expert,
+                shared_gate=cfg.shared_expert_gate,
                 selection_bias=cfg.router_selection_bias,
                 norm_topk_eps=cfg.norm_topk_eps,
                 activation=cfg.expert_activation), name="moe")(

@@ -228,6 +228,8 @@ class RoutedConfig:
     scoring: str = "softmax"
     routed_scale: float = 1.0       # x the chosen weights, once normalised
     d_shared: int = 0               # the shared expert's width; 0: none
+    # the shared expert x sigmoid(w_s . x), one gate a token (``shared/gate``)
+    shared_gate: bool = False
     # a bias an expert (``selection_bias``, float32, zeros) added to the
     # scores for the choice of the ``top_k`` alone: state, not a weight
     selection_bias: bool = False
@@ -815,23 +817,31 @@ silu_mul.defvjp(_silu_mul_fwd, _silu_mul_bwd)
 
 class SharedSwiGLU(nn.Module):
     """The expert every token passes: a dense SwiGLU, or, under an
-    ``activation`` without a gate, ``down_proj(relu(up_proj x) ** 2)``."""
+    ``activation`` without a gate, ``down_proj(relu(up_proj x) ** 2)``;
+    ``gated``: times ``sigmoid(gate x)``, one number a token (the sigmoid in
+    float32)."""
 
     d_model: int
     d_ff: int
     dtype: Any = jnp.bfloat16
     activation: str = "silu"
+    gated: bool = False
 
     @nn.compact
     def __call__(self, x):
         def dense(n, name):
             return nn.Dense(n, use_bias=False, dtype=self.dtype, name=name)
         if self.activation in GATELESS:
-            return dense(self.d_model, "down_proj")(
+            out = dense(self.d_model, "down_proj")(
                 _hidden((dense(self.d_ff, "up_proj")(x),), self.activation))
-        return dense(self.d_model, "down_proj")(
-            silu_mul(dense(self.d_ff, "gate_proj")(x),
-                     dense(self.d_ff, "up_proj")(x)))
+        else:
+            out = dense(self.d_model, "down_proj")(
+                silu_mul(dense(self.d_ff, "gate_proj")(x),
+                         dense(self.d_ff, "up_proj")(x)))
+        if self.gated:
+            out = out * jax.nn.sigmoid(
+                dense(1, "gate")(x).astype(jnp.float32)).astype(out.dtype)
+        return out
 
 
 class RoutedSwiGLU(nn.Module):
@@ -958,7 +968,8 @@ balance), ``moe_z`` (mean of ``logsumexp(router logits) ** 2``) and
             self.sow("intermediates", "moe_buffer_rows", jnp.sum(buffer_rows))
         if cfg.d_shared:
             out = out + SharedSwiGLU(d, cfg.d_shared, cfg.dtype,
-                                     cfg.activation, name="shared")(x)
+                                     cfg.activation, cfg.shared_gate,
+                                     name="shared")(x)
         return out
 
 

@@ -8,8 +8,8 @@ caller puts straight after it, as two Pallas (Mosaic) kernels under one
 
 ``x``: (B, S, C), ``kernel``: (width, C), ``bias``: (C,) or none.  The Mamba-2
 and Mamba-1 mixers (``models/mamba.py``) call it with a bias, the Kimi Delta
-Attention mixer (``models/kda.py``) without one and, for q and k, with a
-head's unit norm.  In plain XLA the same arithmetic is four padded slices, a
+Attention mixer (``models/kda.py``) and the Gated DeltaNet mixer
+(``models/gdn.py``) without one and, for q and k, with a head's unit norm.  In plain XLA the same arithmetic is four padded slices, a
 silu and — for the unit norm — two 0 / 1 matmuls at six bf16 passes, forward,
 again under ``remat`` and in a backward of three arrays; here it is one pass
 over ``x`` forward and one over ``x`` and the cotangent backward.

@@ -101,6 +101,7 @@ def llama_partition_rules() -> PartitionRules:
         # the expert every token passes, as the dense MLP
         (r"moe/shared/(gate_proj|up_proj)/kernel", _spec("fsdp", "tp")),
         (r"moe/shared/down_proj/kernel", _spec("tp", "fsdp")),
+        (r"moe/shared/gate/kernel", _spec("fsdp", None)),
         (r"moe/(gate_proj|up_proj)", _spec("ep", "fsdp", "tp")),
         (r"moe/down_proj", _spec("ep", "tp", "fsdp")),
         # mamba layers (models/mamba.py::Mamba2Mixer as h_<n>/mamba): the two
@@ -130,6 +131,17 @@ def llama_partition_rules() -> PartitionRules:
         (r"kda/o_proj/kernel", _spec("tp", "fsdp")),
         (r"kda/(q|k|v)_conv", _spec(None, "tp")),
         (r"kda/(A_log|dt_bias)$", _spec("tp")),
+        # Gated DeltaNet layers (models/gdn.py::GDNMixer as h_<n>/gdn): the two
+        # input projections and the convolution's kernel hold their columns a
+        # key head at a time, (in, key heads, columns a key head), and are cut
+        # between key heads, each with its value heads; A_log and dt_bias are
+        # a value head's, in the key heads' order; the scan needs no
+        # collective under tp (a tp that does not divide the key heads is
+        # refused by the mixer)
+        (r"gdn/(in_proj_qkvz|in_proj_ba)/kernel", _spec("fsdp", "tp", None)),
+        (r"gdn/conv_kernel", _spec(None, "tp", None)),
+        (r"gdn/out_proj/kernel", _spec("tp", "fsdp")),
+        (r"gdn/(A_log|dt_bias)$", _spec("tp")),
         # Mamba-1 layers (models/mamba.py::Mamba1Mixer as h_<n>/mamba1): what
         # lies between in_proj and out_proj is a channel's own — in_proj's
         # kernel is (embed, 2, channels), u and z each cut by channel, and so

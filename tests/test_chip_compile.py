@@ -128,6 +128,8 @@ def _build(case: str, compile_: bool) -> dict:
         return _build_flash(case, topo.devices[0])
     if case.startswith("kda_s"):
         return _build_kda(case, topo.devices[0])
+    if case.startswith("gdn_s"):
+        return _build_gdn(case, topo.devices[0])
     if case.startswith("scan_s"):
         return _build_selective_scan(case, topo.devices[0])
     if case.startswith("conv_s"):
@@ -441,6 +443,43 @@ def _build_kda(case: str, device) -> dict:
         compiled = jax.jit(grads).lower(
             wide, wide, wide, shape(heads * d, jnp.float32),
             shape(heads, jnp.float32), wide).compile()
+    except Exception as e:  # what Mosaic or the TPU compiler refuses
+        return {"case": case, "refused": str(e)[-1500:]}
+    mem = compiled.memory_analysis()
+    return {"case": case, "tpu_custom_calls": len(re.findall(
+        r'custom_call_target="tpu_custom_call"', compiled.as_text())),
+        "temp_bytes": int(mem.temp_size_in_bytes)}
+
+
+def _build_gdn(case: str, device) -> dict:
+    """In the child: compile ``ops/gdn.py``'s kernels alone, forward and
+    backward, for one chip at ``gdn_s<seq>`` in bf16 over 32 value heads and
+    16 key heads of 128 at chunks of 64: the Mosaic calls of the compiled
+    program."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from ray_tpu.ops.gdn import gdn_scan
+
+    seq, keys, heads, d = int(case.split("_s")[1]), 16, 32, 128
+
+    def shape(width, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct((1, seq, width), dtype,
+                                    sharding=SingleDeviceSharding(device))
+
+    def grads(q, k, v, g, beta, do):
+        out, vjp = jax.vjp(functools.partial(gdn_scan, chunk=64),
+                           q, k, v, g, beta)
+        return out, vjp(do)
+
+    try:
+        compiled = jax.jit(grads).lower(
+            shape(keys * d), shape(keys * d), shape(heads * d),
+            shape(heads, jnp.float32), shape(heads, jnp.float32),
+            shape(heads * d)).compile()
     except Exception as e:  # what Mosaic or the TPU compiler refuses
         return {"case": case, "refused": str(e)[-1500:]}
     mem = compiled.memory_analysis()
@@ -1437,6 +1476,34 @@ def test_kda_kernels_compile_for_one_v5e_chip():
     row = _child(["kda_s16384"], compile_=True)["kda_s16384"]
     assert "refused" not in row, row
     assert row["tpu_custom_calls"] == 3, row    # solve, forward, backward
+
+
+def test_gdn_kernels_compile_for_one_v5e_chip():
+    """Tier-1, ten seconds: Mosaic takes ``ops/gdn.py``'s three kernels at
+    ``qwen3-next-s16k-1chip``'s shape (1 x 16,384 x 32 value heads over 16 key
+    heads x 128, chunks of 64, bf16) — a key head's block of q and k beside
+    its two value heads' block of v, the mask of exponents from a column and
+    its transpose through the identity, two value heads' solves in one
+    128-wide matrix —, which the interpreter on the CPU cannot say; beside
+    the calls the program holds the chunks' inverses (0.13 GB) and the states
+    before the chunks (0.54 GB) and no array as wide as ``g`` broadcast to a
+    head's channels (0.27 GB in float32 each time it is made)."""
+    row = _child(["gdn_s16384"], compile_=True)["gdn_s16384"]
+    assert "refused" not in row, row
+    assert row["tpu_custom_calls"] == 3, row    # solve, forward, backward
+    assert row["temp_bytes"] < 0.95e9, row
+
+
+def test_flash_pair_compiles_at_heads_256_wide():
+    """Tier-1, five seconds: Mosaic takes the whole-row causal flash pair at
+    ``qwen3-next-s16k-1chip``'s shape — 16 query heads 256 wide over 2
+    key/value heads, 16,384 positions, the first cell with a head of two lane
+    tiles —, the backward writing dK at the query heads (a group of eight's
+    dQ at 256 does not fit the VMEM) and the sum beside the kernel."""
+    row = _child(["flash_s16384_d256_g8_h16"],
+                 compile_=True)["flash_s16384_d256_g8_h16"]
+    assert "refused" not in row, row
+    assert row["dk_heads"] == 16, row
 
 
 def test_conv_kernels_compile_for_one_v5e_chip():

@@ -29,6 +29,7 @@ PINNED = {
     "toy-nemotron-h": "81aec893b1adcc63a48c4d6117e42096e0c376ace04fd8ee2acaf406dd9e6ea4",
     "toy-olmoe": "41054e8f510af2a7d7be4f320326eb4237e4c1efc006d16bd81fbd3d9bea858f",
     "toy-phi4-flash": "79a8ea30f0ff39e594a039012999dd6e27df988b0ecb88a1d11bf6f31d7440d9",
+    "toy-qwen3-next": "aa54b2aec115ec70d4155b9700fdd5c24d6c40bb142cffd1014f1d398259b780",
     "toy-sdar": "ff8e09d3e6a65ea9935059aba9f4c46ea2127d572f13434078f2b64b86576cb4",
     "toy-smallthinker": "93f22a8b8dd122f0ad365fda5333bd32f284eef760cd37790e1be2d4af71dd0e",
     "toy-xing4": "f9b88bdfcfc9f29b33f7db2d577c04e62fe12c4981e0dd873ec6e2f00cbc7054",
